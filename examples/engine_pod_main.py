@@ -13,6 +13,13 @@ cluster test (tests/test_cluster_e2e.py) without an HTTP stack:
 
 The pod writes ``<control>/<pod-id>.ready`` once serving. SIGTERM exits.
 
+One process per chip: a pod takes the accelerator JAX finds and holds it
+for its lifetime, so a host runs as many pods as it has chips (with
+``JAX_PLATFORMS=cpu`` any number run on the CPU, as the cluster test's
+three do). It starts no children that need a device. Compiled programs go
+to the persistent cache (``JAX_COMPILATION_CACHE_DIR`` if set, else
+``<checkout>/.jax_cache``), so a restarted pod does not recompile.
+
 ``--admin-port`` (off by default; ``auto`` = ephemeral) starts the stdlib
 admin endpoint with the engine-telemetry debug section (``/metrics``,
 ``/debug/vars`` → ``engine``, and — when ``--profile-dir`` is set —
@@ -40,12 +47,15 @@ from llmd_kv_cache_tpu.models.llama import LlamaConfig
 from llmd_kv_cache_tpu.offload.spec import SharedStorageOffloadSpec
 from llmd_kv_cache_tpu.services.admin import AdminServer
 from llmd_kv_cache_tpu.telemetry import EngineTelemetryConfig
+from llmd_kv_cache_tpu.utils.compile_cache import enable_compile_cache
 from llmd_kv_cache_tpu.utils.logging import configure_from_env
 
 
 def main() -> None:
     configure_from_env()
-    parser = argparse.ArgumentParser()
+    parser = argparse.ArgumentParser(
+        description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter)
     parser.add_argument("--pod-id", required=True)
     parser.add_argument("--zmq-endpoint", required=True)
     parser.add_argument("--control-dir", required=True)
@@ -102,6 +112,7 @@ def main() -> None:
                         help="audit ring depth for --audit (default 2048)")
     args = parser.parse_args()
 
+    enable_compile_cache()
     cfg = LlamaConfig.tiny()
     publisher = KVEventPublisher(
         args.zmq_endpoint, pod_identifier=args.pod_id,

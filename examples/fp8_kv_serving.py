@@ -6,7 +6,7 @@ Serves the same prompts through a bf16-cache and an fp8-cache engine
 then prints the pool byte accounting and the token agreement. On a TPU
 the fp8 engine's decode rides the merged flash kernel's quantized arm
 (flat whole-page 1-byte DMAs) — the measured lever for the
-attention-bandwidth-bound long-context shapes (benchmarking/r5-tpu);
+attention-bandwidth-bound long-context shapes (ROADMAP S1, D4);
 on CPU this demo exercises the identical code paths via XLA attention.
 
 Usage:

@@ -52,7 +52,9 @@ def main() -> None:
 
     from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
     from llmd_kv_cache_tpu.models.hf_loader import load_hf_checkpoint
+    from llmd_kv_cache_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     demo_ids = None
     cleanup = contextlib.ExitStack()
     if args.checkpoint is None:

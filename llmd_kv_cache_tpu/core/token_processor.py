@@ -32,8 +32,11 @@ except Exception:  # pragma: no cover - numpy-less envs degrade gracefully
 from ..utils.lockdep import new_lock
 from ..utils.cbor import canonical_cbor_encode
 from ..utils.fnv import fnv1a_64
+from ..utils.logging import get_logger
 from .extra_keys import BlockExtraFeatures
 from .keys import EMPTY_BLOCK_HASH, BlockHash
+
+logger = get_logger("core.token_processor")
 
 DEFAULT_BLOCK_SIZE = 16  # vLLM's default tokens-per-block
 # Prefix-key cache budget in *tokens* (not entries): multi-turn sessions
@@ -236,6 +239,9 @@ class ChunkedTokenDatabase:
         # Per-model seed cache: the init step hashes the model name into the
         # chain once; memoize since model cardinality is tiny.
         self._model_seed_cache: dict[str, int] = {}
+        # The native chain is the serving path; the Python chain is the
+        # reference and the choice for toolchain-less hosts. Which one is
+        # live is said at start-up and readable as ``hash_backend``.
         self._native = None
         if use_native:
             try:
@@ -245,6 +251,7 @@ class ChunkedTokenDatabase:
                     self._native = _native_mod
             except Exception:  # pragma: no cover - toolchain-less envs
                 self._native = None
+        logger.info("block hashing backend: %s", self.hash_backend)
         self._prefix_cache: Optional[PrefixKeyCache] = (
             PrefixKeyCache(cfg.prefix_cache_tokens)
             if cfg.prefix_cache_tokens > 0 else None
@@ -257,6 +264,11 @@ class ChunkedTokenDatabase:
     @property
     def block_size(self) -> int:
         return self._block_size
+
+    @property
+    def hash_backend(self) -> str:
+        """``"native"`` (csrc/kvindex) or ``"python"`` for text blocks."""
+        return "native" if self._native is not None else "python"
 
     def prefix_cache_stats(self) -> Optional[dict]:
         """Hit/miss counters of the prefix-key cache (None when disabled)."""

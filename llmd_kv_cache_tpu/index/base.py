@@ -19,6 +19,9 @@ from dataclasses import dataclass
 from typing import Optional, Sequence
 
 from ..core.keys import BlockHash, KeyType, PodEntry
+from ..utils.logging import get_logger
+
+logger = get_logger("index")
 
 
 class Index(abc.ABC):
@@ -237,11 +240,14 @@ class IndexConfig:
             from . import native
 
             if native.native_available():
+                logger.info("default index backend: native (csrc/kvindex)")
                 return cls(native_config=native.NativeIndexConfig())
         except Exception:  # pragma: no cover - toolchain-less envs  # lint: allow-swallow (fall through to in-memory index)
             pass
         from .in_memory import InMemoryIndexConfig
 
+        logger.warning("default index backend: Python in-memory (the native "
+                       "library did not load)")
         return cls(in_memory_config=InMemoryIndexConfig())
 
 

@@ -151,7 +151,13 @@ def native_available() -> bool:
     try:
         load_library()
         return True
-    except Exception:
+    except Exception as exc:
+        # Said once, with the reason: from here on hashing and the default
+        # index run their Python forms (0.16x the Go reference against the
+        # native 2.4x, benchmarking/README.md).
+        logger.warning("native kvindex library unavailable (%s: %s); the "
+                       "Python hash chain and in-memory index take over",
+                       type(exc).__name__, exc)
         _load_failed = True
         return False
 

@@ -11,9 +11,10 @@ slab back into the paged pools inside one jit with donation.
 Slab layout per offloaded file (dtype = cache dtype):
 ``[num_layers, 2 (K,V), pages_per_file, kv_heads, page_size, head_dim]``
 
-On TPU the host side lands in pinned host memory (`jax.device_get` uses
-the PJRT pinned path); on the CPU backend the same code degrades to plain
-copies, keeping tests hardware-free.
+The device→host leg stages through ``pinned_host`` memory. A runtime that
+refuses that memory kind is an error on a TPU; elsewhere (CPU tests on a
+runtime without memory kinds) transfers go unpinned and
+``pinned_host_active`` says so.
 """
 
 from __future__ import annotations
@@ -74,12 +75,24 @@ class TPUBlockCopier:
         self.slab_shape = lambda n: (layers, self.streams, n, kv_heads,
                                      page_size, head_dim)
         self.dtype = k_cache.dtype
+        devices = sorted(k_cache.devices(), key=lambda d: d.id)
+        # Host slabs follow the pool. A pool committed to one chip (an
+        # engine given ``device=``) gets its slabs on that chip — a replica
+        # on chip k must not bounce its restores through device 0. An
+        # uncommitted pool gets uncommitted slabs: a committed operand
+        # would commit the scattered pool, and the engine's jitted steps
+        # would then re-lower and recompile for the new signature (on a
+        # v5e that turned one restore into minutes, PERF.md PR 22).
+        # Sharded pools leave placement to the scatter program.
+        pinned_to_one = len(devices) == 1 and k_cache.committed
+        self._device = devices[0] if pinned_to_one else None
+        self._on_tpu = devices[0].platform == "tpu"
         try:
             self._pinned_sharding = jax.sharding.SingleDeviceSharding(
-                list(k_cache.devices())[0], memory_kind="pinned_host"
+                devices[0], memory_kind="pinned_host"
             )
-        except Exception:  # pragma: no cover - runtime without memory kinds
-            self._pinned_sharding = None
+        except (ValueError, RuntimeError, NotImplementedError) as exc:
+            self._pinned_refused(devices[0], exc)
 
     def slab_nbytes(self, n_pages: int) -> int:
         return int(np.prod(self.slab_shape(n_pages))) * self.dtype.itemsize
@@ -91,6 +104,17 @@ class TPUBlockCopier:
         DMA path instead of silently degrading."""
         return self._pinned_sharding is not None
 
+    def _pinned_refused(self, where, exc: Exception) -> None:
+        """The runtime refused ``pinned_host``: on a TPU that is a
+        mis-set-up chip, not a mode to serve in; elsewhere transfers go
+        unpinned and ``pinned_host_active`` says so."""
+        if self._on_tpu:
+            raise RuntimeError(
+                f"pinned_host memory unavailable on {where}: {exc}") from exc
+        logger.warning("pinned_host memory unavailable on %s; D2H "
+                       "transfers are unpinned", where)
+        self._pinned_sharding = None
+
     def _to_pinned_host(self, x: jax.Array) -> jax.Array:
         """Route the device→host leg through pinned host memory when the
         runtime supports memory kinds (true DMA staging, the role the
@@ -99,11 +123,8 @@ class TPUBlockCopier:
             return x
         try:
             return jax.device_put(x, self._pinned_sharding)
-        except Exception:  # pragma: no cover - runtime without the kind
-            logger.warning(
-                "pinned_host memory kind unavailable on %s; D2H falls back "
-                "to unpinned transfers", x.devices())
-            self._pinned_sharding = None
+        except (ValueError, RuntimeError, NotImplementedError) as exc:
+            self._pinned_refused(x.devices(), exc)
             return x
 
     def gather_to_host(self, page_ids: list[int]) -> np.ndarray:
@@ -185,7 +206,7 @@ class TPUBlockCopier:
                 )
                 all_ids.extend(page_ids)
             merged = np.concatenate(parts, axis=2)  # page axis
-            device_slab = jax.device_put(merged)
+            device_slab = jax.device_put(merged, self._device)
             self.k_cache, self.v_cache = _scatter_slab(
                 self.k_cache, self.v_cache, device_slab.astype(self.dtype),
                 jnp.asarray(all_ids, jnp.int32), streams=self.streams,

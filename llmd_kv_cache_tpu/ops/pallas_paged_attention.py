@@ -2,10 +2,10 @@
 
 Instead of materializing each sequence's gathered KV **and the fp32
 attention probs** in HBM (which ``ops.paged_attention`` does — the
-dominant excess HBM traffic of the XLA prefill path, see
-benchmarking/r4-mfu/README.md), each (batch, kv_head[, q_tile]) program
-streams the sequence's pages HBM→VMEM with double-buffered async DMA and
-folds them into an online softmax — the ragged-paged-attention recipe.
+dominant excess HBM traffic of the XLA prefill path), each
+(batch, kv_head[, q_tile]) program streams the sequence's pages HBM→VMEM
+with double-buffered async DMA and folds them into an online softmax — the
+ragged-paged-attention recipe.
 
 Pages stream in **superblocks** of ``pages_per_block`` pages (default
 targets 128 keys): each online-softmax round is then a full-width MXU
@@ -47,6 +47,35 @@ def head_dim_supported(head_dim: int) -> bool:
     compiled path only (the engine's backend selection and the kernels'
     own guard both use it, so the rule cannot drift between them)."""
     return head_dim % 128 == 0
+
+
+# Mosaic's default scoped-VMEM budget on a v5e; a kernel that needs more
+# says so through ``vmem_limit_bytes`` (the chip has 128 MiB).
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _compiler_params(vmem_bytes: int):
+    """None while the kernel's estimated VMEM footprint fits the default
+    budget — the served GQA shapes compile exactly as before — and a
+    raised limit with 2x headroom beyond it. Wide latent heads need it:
+    at 16 heads x 640 the prefill kernel's blocks, state and scores come
+    to ~31 MiB and the compiler otherwise refuses ("Ran out of memory in
+    memory space vmem", v5e, PR 22)."""
+    if vmem_bytes <= _DEFAULT_SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(100 * 2 ** 20, 2 * vmem_bytes))
+
+
+def _layer_operand(layer_idx) -> jax.Array:
+    """``layer_idx`` as the kernels' [1] int32 scalar-prefetch operand. It
+    is a traced value, not a static one: the 28 attention calls of a
+    28-layer forward are then one jitted function called 28 times — traced
+    and lowered once — instead of 28 kernels that differ in a constant.
+    On a v5e host that lowering costs 7-9 s per kernel (PERF.md, PR 22).
+    None (an unstacked cache) sends a placeholder the kernel never reads."""
+    return jnp.asarray(0 if layer_idx is None else layer_idx,
+                       jnp.int32).reshape(1)
 
 
 def _check_head_dim_alignment(head_dim: int, interpret: bool) -> None:
@@ -100,8 +129,10 @@ def _superblock_streamer(page_table_ref, b, h, k_hbm, v_hbm, k_scratch,
         return jnp.minimum(idx, pp_seq - 1)
 
     def page_src(hbm, page):
-        # layer_idx: the operand is the engine's full [layers, pages, …]
-        # stack and the kernel indexes the layer itself — slicing the
+        # layer_idx (a scalar read from SMEM, so every layer of a model
+        # shares one traced and lowered kernel): the operand is the
+        # engine's full [layers, pages, …] stack and the kernel indexes
+        # the layer itself — slicing the
         # stack OUTSIDE a pallas_call materializes a full per-layer copy
         # at the custom-call boundary (XLA cannot fuse a producer slice
         # into a custom call; measured ~0.9 ms/layer/step in the decode
@@ -236,6 +267,7 @@ def _decode_kernel(
     page_table_ref,  # [batch, pages_per_seq] int32 (SMEM)
     ctx_lens_ref,  # [batch] int32 (SMEM)
     tail_lens_ref,  # [batch] int32 (SMEM; zeros when has_tail=False)
+    layer_ref,  # [1] int32 (SMEM): layer of a stacked cache, else unused
     # inputs
     q_ref,  # [1, 1, group, head_dim] VMEM block for (b, h)
     k_hbm,  # [num_pages, kv_heads, page_size, head_dim] (ANY/HBM)
@@ -257,7 +289,7 @@ def _decode_kernel(
     shared_kv: bool,
     shared_copy: bool,
     has_tail: bool,
-    layer_idx: int | None,
+    stacked: bool,
 ):
     b = pl.program_id(0)
     h = pl.program_id(1)
@@ -288,7 +320,7 @@ def _decode_kernel(
         page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch, sem,
         kpb=kpb, num_iters=num_iters, first_window=first_window,
         sink_pages=sink_pages, sinks=sinks, shared_kv=shared_kv,
-        layer_idx=layer_idx)
+        layer_idx=layer_ref[0] if stacked else None)
 
     @pl.when(num_sb > 0)
     def _():
@@ -316,7 +348,7 @@ def _decode_kernel(
         k = k_scratch[slot].reshape(kpb * page_size, head_dim)
         if shared_kv and shared_copy:
             # Absorbed MLA measured 2x SLOWER with v aliased to k at
-            # b8/ctx4k (benchmarking/r5-tpu, --mla probe): one buffer
+            # b8/ctx4k (July 2026, ROADMAP D3): one buffer
             # feeding both matmuls — head_dim-contraction for scores,
             # key-contraction for the output — forces Mosaic into
             # per-round relayouts. A local VMEM->VMEM copy gives each
@@ -377,6 +409,7 @@ def _decode_kernel_merged(
     page_table_ref,  # [batch, pages_per_seq] int32 (SMEM)
     ctx_lens_ref,  # [batch] int32 (SMEM)
     tail_lens_ref,  # [batch] int32 (SMEM; zeros when has_tail=False)
+    layer_ref,  # [1] int32 (SMEM): layer of a stacked cache, else unused
     # inputs
     q_ref,  # [1, kv_heads, group, head_dim] VMEM block for (b,)
     k_hbm,  # [num_pages, kv_heads, page_size, head_dim] (ANY/HBM)
@@ -398,7 +431,7 @@ def _decode_kernel_merged(
     shared_kv: bool,
     shared_copy: bool,
     has_tail: bool,
-    layer_idx: int | None,
+    stacked: bool,
     quant: bool = False,
 ):
     """Decode with every kv head — and up to ``batch_rows`` batch items —
@@ -413,7 +446,7 @@ def _decode_kernel_merged(
     The per-head grid (``_decode_kernel``) pays pipeline fill/drain and
     per-page 4 KB DMAs once per (batch, head) program — measured on a
     real v5e at batch 8 / ctx 4k it sustains only ~105 GB/s of the
-    chip's 819 (benchmarking/r4-mfu, "decode" table). Merging heads
+    chip's 819 (July 2026, ROADMAP S1). Merging heads
     makes each sub-page copy one whole-page transfer carrying all kv
     heads (DMA count ÷ kv_heads), computes the position mask once per
     round instead of per head, and amortizes the program overhead over
@@ -453,7 +486,8 @@ def _decode_kernel_merged(
         streamers.append(_superblock_streamer(
             page_table_ref, b, None, k_hbm, v_hbm, k_scratch, v_scratch,
             sem, kpb=kpb, num_iters=ni, first_window=fw, sink_pages=sp,
-            sinks=sinks, shared_kv=shared_kv, layer_idx=layer_idx,
+            sinks=sinks, shared_kv=shared_kv,
+            layer_idx=layer_ref[0] if stacked else None,
             row=r if rows > 1 else None))
     num_sb = num_sb_r[0]
     for r in range(1, rows):
@@ -599,6 +633,7 @@ def _prefill_kernel(
     page_table_ref,  # [batch, pages_per_seq] int32
     ctx_lens_ref,  # [batch] int32 (tokens already cached BEFORE the new ones)
     total_lens_ref,  # [batch] int32 (ctx + new)
+    layer_ref,  # [1] int32: layer of a stacked cache, else unused
     # inputs
     q_ref,  # [1, q_tile, heads_group, head_dim] block for (b, h, qt)
     k_hbm,
@@ -617,7 +652,7 @@ def _prefill_kernel(
     sinks: int,
     pages_per_block: int,
     shared_kv: bool,
-    layer_idx: int | None,
+    stacked: bool,
 ):
     b = pl.program_id(0)
     h = pl.program_id(1)
@@ -654,7 +689,7 @@ def _prefill_kernel(
     # each online-softmax round multiplies [group·q_tile, head_dim] by
     # [head_dim, kpb·page_size] — full 128-wide MXU tiles instead of one
     # page_size-wide sliver per round (the round-2 kernel's 12×-slower
-    # root cause; see benchmarking/r4-mfu/README.md). A superblock may
+    # root cause). A superblock may
     # straddle the sink→window jump: each sub-page's positions come from
     # its own remapped index, so masking stays exact.
     num_sb = (num_iters + kpb - 1) // kpb
@@ -663,7 +698,7 @@ def _prefill_kernel(
         page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch, sem,
         kpb=kpb, num_iters=num_iters, first_window=first_window,
         sink_pages=sink_pages, sinks=sinks, shared_kv=shared_kv,
-        layer_idx=layer_idx)
+        layer_idx=layer_ref[0] if stacked else None)
 
     @pl.when(num_sb > 0)
     def _():
@@ -741,6 +776,7 @@ def _ragged_kernel(
     block_first_ref,  # [num_q_blocks] int32: first row touching each block
     block_rows_ref,  # [num_q_blocks] int32: rows touching each block
     tail_lens_ref,  # [rows] int32 (zeros when has_tail=False)
+    layer_ref,  # [1] int32: layer of a stacked cache, else unused
     # inputs
     q_ref,  # [1, q_tile, kv_heads, group, head_dim] VMEM block for (g,)
     k_hbm,  # [num_pages, kv_heads, page_size, head_dim] (ANY/HBM)
@@ -763,7 +799,7 @@ def _ragged_kernel(
     shared_kv: bool,
     shared_copy: bool,
     has_tail: bool,
-    layer_idx: int | None,
+    stacked: bool,
     quant: bool = False,
 ):
     """One grid over a ragged mixed prefill+decode batch.
@@ -842,7 +878,8 @@ def _ragged_kernel(
         sb_positions, sb_dma = _superblock_streamer(
             page_table_ref, r, None, k_hbm, v_hbm, k_scratch, v_scratch,
             sem, kpb=kpb, num_iters=ni, first_window=fw, sink_pages=sp,
-            sinks=sinks, shared_kv=shared_kv, layer_idx=layer_idx)
+            sinks=sinks, shared_kv=shared_kv,
+            layer_idx=layer_ref[0] if stacked else None)
 
         @pl.when(num_sb > 0)
         def _():
@@ -982,8 +1019,7 @@ def _ragged_kernel(
 @functools.partial(jax.jit,
                    static_argnames=("q_tile", "sliding_window", "sinks",
                                     "pages_per_block", "shared_kv",
-                                    "shared_stream", "layer_idx",
-                                    "interpret"))
+                                    "shared_stream", "interpret"))
 def pallas_paged_ragged_attention(
     q: jax.Array,  # [total_q, q_heads, head_dim] flat mixed batch
     k_cache: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
@@ -1001,7 +1037,7 @@ def pallas_paged_ragged_attention(
     tail_k: jax.Array | None = None,  # [rows, T, kv_heads, head_dim]
     tail_v: jax.Array | None = None,
     tail_lens: jax.Array | None = None,  # [rows] valid tail tokens
-    layer_idx: int | None = None,
+    layer_idx: jax.Array | int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Single-kernel flash attention over a ragged mixed batch.
@@ -1117,7 +1153,7 @@ def pallas_paged_ragged_attention(
         q_tile=q_tile, sliding_window=sliding_window, sinks=int(sinks or 0),
         pages_per_block=pages_per_block, shared_kv=shared_kv,
         shared_copy=shared_kv and shared_stream == "copy",
-        has_tail=has_tail, layer_idx=layer_idx, quant=quant,
+        has_tail=has_tail, stacked=layer_idx is not None, quant=quant,
     )
 
     if quant:
@@ -1128,7 +1164,7 @@ def pallas_paged_ragged_attention(
              if shared_kv and shared_stream != "copy" else k_scr)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(num_blocks,),
         in_specs=[
             pl.BlockSpec(
@@ -1169,7 +1205,7 @@ def pallas_paged_ragged_attention(
         interpret=interpret,
     )(page_table.astype(jnp.int32), row_starts, ctx_lens,
       block_first, block_rows, tail_lens.astype(jnp.int32),
-      q_blocked, k_cache, v_cache,
+      _layer_operand(layer_idx), q_blocked, k_cache, v_cache,
       tail_k.astype(q.dtype), tail_v.astype(q.dtype))
 
     return out.reshape(total_q, q_heads, head_dim)
@@ -1178,7 +1214,7 @@ def pallas_paged_ragged_attention(
 @functools.partial(jax.jit,
                    static_argnames=("q_tile", "sliding_window", "sinks",
                                     "pages_per_block", "shared_kv",
-                                    "layer_idx", "interpret"))
+                                    "interpret"))
 def pallas_paged_prefill_attention(
     q: jax.Array,  # [batch, q_seq, q_heads, head_dim] (new tokens, padded)
     k_cache: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
@@ -1192,7 +1228,7 @@ def pallas_paged_prefill_attention(
     sinks: int | None = None,
     pages_per_block: int | None = None,
     shared_kv: bool = False,
-    layer_idx: int | None = None,
+    layer_idx: jax.Array | int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Flash prefill over paged KV (new tokens' KV already scattered).
@@ -1239,11 +1275,11 @@ def pallas_paged_prefill_attention(
         _prefill_kernel, page_size=page_size, q_tile=q_tile,
         scale=head_dim ** -0.5, sliding_window=sliding_window,
         sinks=int(sinks or 0), pages_per_block=pages_per_block,
-        shared_kv=shared_kv, layer_idx=layer_idx,
+        shared_kv=shared_kv, stacked=layer_idx is not None,
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(batch, kv_heads, q_seq // q_tile),
         in_specs=[
             pl.BlockSpec(
@@ -1269,15 +1305,29 @@ def pallas_paged_prefill_attention(
         ],
     )
 
+    # Per program: q and out blocks (double-buffered by the pipeline), the
+    # K/V staging slots, the online-softmax state and its update, and the
+    # fp32 scores, their exponentials and the cache-dtype probabilities.
+    item = k_cache.dtype.itemsize
+    keys = pages_per_block * page_size
+    rows = group * q_tile
+    vmem_bytes = (
+        4 * rows * head_dim * q.dtype.itemsize
+        + (1 if shared_kv else 2) * 2 * keys * head_dim * item
+        + 2 * rows * (head_dim + 2) * 4
+        + rows * keys * (4 + 4 + item))
+
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(
             (batch, q_seq // q_tile, q_tile, kv_heads, group, head_dim), q.dtype
         ),
         grid_spec=grid_spec,
+        compiler_params=_compiler_params(vmem_bytes),
         interpret=interpret,
     )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      total_lens.astype(jnp.int32), q_blocked, k_cache, v_cache)
+      total_lens.astype(jnp.int32), _layer_operand(layer_idx),
+      q_blocked, k_cache, v_cache)
 
     return out.reshape(batch, q_seq, q_heads, head_dim)
 
@@ -1286,7 +1336,7 @@ def pallas_paged_prefill_attention(
                    static_argnames=("interpret", "sliding_window", "sinks",
                                     "pages_per_block", "shared_kv",
                                     "shared_stream", "merge_heads",
-                                    "layer_idx", "batch_rows"))
+                                    "batch_rows"))
 def pallas_paged_decode_attention(
     q: jax.Array,  # [batch, q_heads, head_dim]
     k_cache: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
@@ -1303,7 +1353,7 @@ def pallas_paged_decode_attention(
     tail_k: jax.Array | None = None,  # [batch, T, kv_heads, head_dim]
     tail_v: jax.Array | None = None,
     tail_lens: jax.Array | None = None,  # [batch] valid tail tokens
-    layer_idx: int | None = None,
+    layer_idx: jax.Array | int | None = None,
     batch_rows: int = 1,
     interpret: bool = False,
 ) -> jax.Array:
@@ -1322,7 +1372,7 @@ def pallas_paged_decode_attention(
     but each matmul gets its own buffer; ``"reuse"`` aliases V to the K
     scratch (no copy, but the one buffer serves a head_dim-contraction
     and a key-contraction, which measured 2x slower at b8/ctx4k on a
-    real v5e — see benchmarking/r5-tpu). Ignored without ``shared_kv``.
+    real v5e — ROADMAP D3). Ignored without ``shared_kv``.
 
     ``merge_heads`` (default: on when ``kv_heads > 1``) runs every kv
     head of a batch item in one program — whole-page DMAs carry all
@@ -1458,7 +1508,7 @@ def pallas_paged_decode_attention(
             sinks=int(sinks or 0), pages_per_block=pages_per_block,
             shared_kv=shared_kv,
             shared_copy=shared_kv and shared_stream == "copy",
-            has_tail=has_tail, layer_idx=layer_idx, quant=quant,
+            has_tail=has_tail, stacked=layer_idx is not None, quant=quant,
         )
         if quant:
             k_scr = ((2, pages_per_block, kv_heads * page_size, head_dim)
@@ -1475,7 +1525,7 @@ def pallas_paged_decode_attention(
         sem_shape = ((2, pages_per_block, 2) if rr == 1
                      else (2, rr, pages_per_block, 2))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(batch // rr,),
             in_specs=[
                 pl.BlockSpec(
@@ -1516,10 +1566,10 @@ def pallas_paged_decode_attention(
             sliding_window=sliding_window, sinks=int(sinks or 0),
             pages_per_block=pages_per_block, shared_kv=shared_kv,
             shared_copy=shared_kv and shared_stream == "copy",
-            has_tail=has_tail, layer_idx=layer_idx,
+            has_tail=has_tail, stacked=layer_idx is not None,
         )
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(batch, kv_heads),
             in_specs=[
                 pl.BlockSpec(
@@ -1565,7 +1615,7 @@ def pallas_paged_decode_attention(
         grid_spec=grid_spec,
         interpret=interpret,
     )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      tail_lens.astype(jnp.int32),
+      tail_lens.astype(jnp.int32), _layer_operand(layer_idx),
       q_blocked, k_cache, v_cache, tail_k.astype(k_cache.dtype),
       tail_v.astype(k_cache.dtype))
 
@@ -1613,7 +1663,7 @@ def sharded_paged_decode_attention(
     single-head MQA/MLA pool replicates and each shard runs its local
     query heads as one group against the full pool).
     """
-    from ..utils.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     has_tail = tail_k is not None
@@ -1661,7 +1711,7 @@ def sharded_paged_prefill_attention(
 ):
     """Flash-prefill over a tp-sharded paged KV cache (see the decode
     wrapper's rationale). q: [batch, q_seq, q_heads, hd], heads sharded."""
-    from ..utils.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(q_, k_, v_, t_, cl_, tl_):

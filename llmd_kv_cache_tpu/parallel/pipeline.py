@@ -248,7 +248,7 @@ def make_pp_pipelined_train_step(mesh: Mesh, cfg: LlamaConfig, params: Params,
     ``make_pp_train_step``; the two produce identical losses/gradients for
     the same params (the schedule changes wall-clock shape, not math).
     """
-    from .ring_attention import shard_map  # jax-version compat shim
+    from jax import shard_map
 
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     if "pp" not in axis_sizes:

@@ -38,13 +38,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig, Params, _mlp, _rms_norm, _rope
 from ..ops.kv_pages import scatter_kv_pages
 from ..ops.paged_attention import paged_attention
 from .pipeline import stack_layer_params
-from .ring_attention import shard_map  # jax-version compat shim
 
 
 def pp_size_of(mesh: Optional[Mesh]) -> int:

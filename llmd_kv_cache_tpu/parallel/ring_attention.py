@@ -19,9 +19,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.shard_map_compat import shard_map  # re-export (pipeline.py uses it)
 
 _NEG_INF = -1e30
 

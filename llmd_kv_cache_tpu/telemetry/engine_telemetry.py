@@ -244,6 +244,9 @@ class EngineTelemetry:
         self._pool_stats: Dict[str, dict] = {}
         self._pool_evictions_seen: Dict[str, int] = {}
         self.profiler = ProfilerCapture(self.cfg.profile_dir)
+        # MiniEngine.attention_backends, set by the owning engine: which
+        # attention backend serves each phase on which device.
+        self.attention_backends: dict = {}
         # Label children resolved once; labels() does a dict lookup + tuple
         # build per call, which the scrape path should not pay repeatedly.
         self._gauge_cache: Dict[str, tuple] = {}
@@ -399,6 +402,7 @@ class EngineTelemetry:
         """The ``engine`` section of ``/debug/vars`` (and kvdiag)."""
         return {
             "group": self.group,
+            "attention_backends": self.attention_backends,
             "pool": {g: dict(s) for g, s in self._pool_stats.items()},
             "requests": {
                 "active": len(self._requests),

@@ -139,55 +139,24 @@ class TestBenchModes:
         assert set(parsed) == {"metric", "value", "unit", "vs_baseline"}
 
 
-class TestGuardedLadder:
-    """The driver entry's fallback ladder: probe -> device TTFT -> CPU-env
-    TTFT -> index micro-bench."""
+class TestRoutingBenchNeedsAChip:
+    """The routing benchmark runs in-process on a TPU or not at all: no
+    probe, no child process, no CPU or index-microbenchmark stand-in."""
 
-    def test_cpu_rung_strips_accelerator_env(self, monkeypatch):
-        import bench
+    def test_default_mode_refuses_without_a_tpu(self):
+        import pytest
 
-        calls = []
+        with pytest.raises(SystemExit) as exc:
+            bench._dispatch(["bench.py"])
+        # SystemExit with a message exits 1 and prints it to stderr; no
+        # result line is produced.
+        assert "needs a TPU" in str(exc.value.code)
+        assert "'cpu'" in str(exc.value.code)
 
-        def fake_ttft(env=None, timeout=900):
-            calls.append(env)
-            if env is None:
-                return None  # device rung fails
-            return '{"metric": "m", "value": 1, "unit": "%", "vs_baseline": 1}'
-
-        monkeypatch.setattr(bench, "_accelerator_healthy", lambda: True)
-        monkeypatch.setattr(bench, "_run_ttft_subprocess", fake_ttft)
-        monkeypatch.setenv("PYTHONPATH", "/some/plugin")
-        line = bench.guarded_main()
-        assert line.startswith('{"metric"')
-        assert calls[0] is None  # device rung ran first
-        cpu_env = calls[1]
-        assert "PYTHONPATH" not in cpu_env
-        assert cpu_env["JAX_PLATFORMS"] == "cpu"
-
-    def test_unhealthy_probe_skips_device_rung(self, monkeypatch):
-        import bench
-
-        calls = []
-
-        def fake_ttft(env=None, timeout=900):
-            calls.append(env)
-            return '{"metric": "m", "value": 1, "unit": "%", "vs_baseline": 1}'
-
-        monkeypatch.setattr(bench, "_accelerator_healthy", lambda: False)
-        monkeypatch.setattr(bench, "_run_ttft_subprocess", fake_ttft)
-        bench.guarded_main()
-        assert len(calls) == 1 and calls[0] is not None  # straight to CPU
-
-    def test_all_ttft_rungs_failing_falls_to_index_bench(self, monkeypatch):
-        import json
-
-        import bench
-
-        monkeypatch.setattr(bench, "_accelerator_healthy", lambda: False)
-        monkeypatch.setattr(bench, "_run_ttft_subprocess",
-                            lambda env=None, timeout=900: None)
-        out = json.loads(bench.guarded_main())
-        assert "value" in out and "vs_baseline" in out
+    def test_the_ladder_is_gone(self):
+        for name in ("guarded_main", "_accelerator_healthy",
+                     "_run_ttft_subprocess"):
+            assert not hasattr(bench, name)
 
 
 class TestPerfSentinel:

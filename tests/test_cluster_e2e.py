@@ -45,7 +45,7 @@ def wait_until(cond, timeout=60.0, interval=0.1):
 
 def spawn(argv, **kw):
     env = dict(os.environ)
-    env.pop("PYTHONPATH", None)
+    # One process per chip: children of a test run stay on the CPU.
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO)
     return subprocess.Popen(

@@ -4,7 +4,7 @@ engine/offload seams.
 The serving-time ``EngineConfig.kv_cache_dtype="f8_e4m3"`` halves KV HBM
 traffic and pool capacity — the decode-bandwidth lever identified by the
 round-5 on-chip sweeps (b32/ctx2048 decode is attention-bandwidth bound,
-benchmarking/r5-tpu). e4m3's per-element exponent means no scale arrays:
+ROADMAP S1). e4m3's per-element exponent means no scale arrays:
 ``scatter_kv_pages`` casts on write, the attention backends upcast on
 read, and the offload plane moves 1-byte elements under a
 dtype-fingerprinted store directory (reference analog: the fingerprint
