@@ -1,0 +1,199 @@
+"""The trace reduction on synthetic planes (each rule of the issue's §7) and
+on a small trace recorded on the chip."""
+
+from pathlib import Path
+
+import pytest
+
+from kvbench.trace import reduce as R
+
+FIXTURE = Path(__file__).with_name("fixture.xplane.pb")
+SPANS = ["ingest", "enqueue", "route", "step", "restore.wait",
+         "replica.idle", "generator.sleep"]
+
+
+def ev(name, start, dur, **stats):
+    return R.Event(name, float(start), float(dur), dict(stats))
+
+
+def device(idx, ops, modules=(), steps=()):
+    return R.Plane(f"/device:TPU:{idx}", {
+        R.OPS_LINE: list(ops), R.MODULES_LINE: list(modules),
+        "Steps": list(steps)})
+
+
+def host(*events):
+    return R.Plane(R.HOST_PLANE, {"python3": list(events)})
+
+
+def test_union_and_gaps():
+    assert R.union([(0, 2), (1, 3), (5, 6), (6, 7)]) == [(0, 3), (5, 7)]
+    assert R.union([(0, 10)], clip=(2, 4)) == [(2, 4)]
+    assert R.union([(0, 1)], clip=(2, 4)) == []
+    assert R.gaps([(1, 2), (4, 5)], (0, 6)) == [(0, 1), (2, 4), (5, 6)]
+    assert R.overlap((0, 10), [(1, 2), (8, 12)]) == 3
+
+
+def test_busy_is_the_union_of_the_ops_line_only():
+    """Ops, modules and steps cover the same time; summing lines or events
+    would pass the window."""
+    ops = [ev("fusion.1", 0, 40), ev("fusion.2", 30, 30),   # overlap
+           ev("copy.3", 80, 10)]
+    mods = [ev("jit_forward_decode_pallas(1)", 0, 95)]
+    steps = [ev("0", 0, 100)]
+    red = R.reduce([device(0, ops, mods, steps),
+                    host(ev("step", 0, 100))], 1, SPANS)
+    assert red.window == (0, 100)
+    assert red.busy["/device:TPU:0"] == [(0, 60), (80, 90)]
+    assert red.busy_s == pytest.approx(70e-9)
+    assert 0 < red.busy_s <= red.window_s
+    assert sum(e.dur for e in ops + mods + steps) > 100  # the wrong sum
+
+
+def test_ops_are_clipped_to_the_window_and_name_their_program():
+    ops = [ev("a", 10, 10), ev("b", 50, 10)]
+    mods = [ev("jit_forward_prefill_pallas(7)", 5, 20),
+            ev("jit_forward_decode_pallas(9)", 45, 20)]
+    red = R.reduce([device(0, ops, mods)], 1, SPANS)
+    assert red.window == (10, 60)
+    assert [e.stats["program"] for e in red.ops["/device:TPU:0"]] == [
+        "jit_forward_prefill_pallas(7)", "jit_forward_decode_pallas(9)"]
+
+
+def test_several_chips_give_the_mean_not_the_sum():
+    planes = [device(0, [ev("a", 0, 100)]), device(1, [ev("a", 0, 50)]),
+              device(2, [ev("a", 0, 100)]), device(3, [ev("a", 50, 50)]),
+              host(ev("step", 0, 100))]
+    red = R.reduce(planes, 4, SPANS)
+    assert red.busy_s == pytest.approx(75e-9)
+    assert red.busy_s <= red.window_s
+    # A one-chip cell on a host with four reads its own chip only.
+    assert R.reduce(planes, 1, SPANS).busy_s == pytest.approx(100e-9)
+
+
+def test_empty_slice_has_no_busy_time():
+    """A slice that fell into a restore holds host spans and no device op:
+    busy is 0 and the run must fail rather than print it."""
+    red = R.reduce([host(ev("restore.wait", 0, 100))], 1, SPANS)
+    assert red.busy_s == 0.0 and red.window_s == pytest.approx(100e-9)
+    with pytest.raises(ValueError):
+        R.reduce([R.Plane(R.HOST_PLANE)], 1, SPANS)
+
+
+def test_window_is_the_traces_own_clock():
+    red = R.reduce([device(0, [ev("a", 1e9 + 10, 10)]),
+                    host(ev("step", 1e9, 100))], 1, SPANS)
+    assert red.window == (1e9, 1e9 + 100)
+    assert red.window_s == pytest.approx(100e-9)
+
+
+def test_idle_gaps_go_to_the_span_that_covered_them():
+    ops = [ev("a", 0, 10), ev("b", 60, 10), ev("c", 90, 10)]
+    spans = host(ev("step", 0, 40), ev("ingest", 20, 10),
+                 ev("generator.sleep", 0, 100), ev("step", 60, 40))
+    red = R.reduce([device(0, ops), spans], 1, SPANS)
+    idle = red.idle_by_span(SPANS)
+    # Gap 10..60: ingest 20..30 (inner wins), step 10..20 and 30..40, the
+    # generator's sleep the rest; gap 70..90 is inside the second step.
+    assert idle["ingest"] == pytest.approx(10e-9)
+    assert idle["step"] == pytest.approx(40e-9)
+    assert idle["generator.sleep"] == pytest.approx(20e-9)
+    assert sum(idle.values()) == pytest.approx(red.window_s - red.busy_s)
+    longest = red.longest_gaps(2)
+    assert longest[0][0] == pytest.approx(50e-9)
+
+
+def test_work_markers_inside_the_window():
+    marks = [ev(R.WORK_MARKER, t, 0, prefill_tokens=16 * i, decode_rows=i)
+             for i, t in enumerate((5, 50, 500))]
+    red = R.reduce([device(0, [ev("a", 0, 100)]), host(*marks)], 1, SPANS)
+    assert [w["decode_rows"] for w in red.work] == [0, 1, 2]
+    assert red.window == (0, 500)
+
+
+def test_cpu_backend_reads_ops_from_the_host_plane():
+    ops = [ev("dot.1", 0, 10, hlo_module="jit_f", run_id=1),
+           ev("add.2", 10, 5, hlo_module="jit_f", run_id=1),
+           ev("dot.1", 40, 10, hlo_module="jit_f", run_id=2)]
+    red = R.reduce([host(*ops, ev("step", 0, 60))], 1, SPANS)
+    assert red.planes == [R.HOST_PLANE]
+    assert red.busy_s == pytest.approx(25e-9)
+    assert sorted(e.dur for e in red.modules[R.HOST_PLANE]) == [10, 15]
+
+
+@pytest.mark.skipif(not FIXTURE.is_file(), reason="no recorded fixture")
+def test_recorded_chip_trace():
+    """A toy engine's slice recorded on one v5e through this harness
+    (``run.py --toy --trace 1``): device plane, ops inside modules, the
+    harness's spans and work markers on the same clock."""
+    planes = R.load(str(FIXTURE), SPANS)
+    assert any(R.DEVICE_PLANE.match(p.name) for p in planes)
+    red = R.reduce(planes, 1, SPANS)
+    assert red.planes == ["/device:TPU:0"]
+    assert 0 < red.busy_s <= red.window_s
+    ops = red.ops["/device:TPU:0"]
+    assert ops and all(e.dur >= 0 for e in ops)
+    by_line = sum(e.dur for e in ops) * 1e-9
+    assert red.busy_s <= by_line + 1e-12
+    programs = {e.stats.get("program", "") for e in ops}
+    assert any("forward_decode_pallas" in p for p in programs)
+    assert red.spans["step"] and red.work
+    assert all("decode_rows" in w for w in red.work)
+    # Host spans and device ops share a clock: device work falls inside
+    # the steps that launched it.
+    inside = sum(R.overlap((e.start, e.end), red.spans["step"]) for e in ops)
+    assert inside >= 0.9 * sum(e.dur for e in ops)
+    idle = red.idle_by_span(SPANS)
+    assert sum(idle.values()) == pytest.approx(red.window_s - red.busy_s,
+                                               rel=1e-6)
+
+
+def test_op_names():
+    text = ("%pallas_paged_decode_attention.30 = bf16[32,8,2,128]{3,2,1,0} "
+            "custom-call(s32[32,264]{1,0} %x), custom_call_target=\"tpu\"")
+    assert R.op_name(text) == "pallas_paged_decode_attention.30"
+    assert R.op_name("dot.1") == "dot.1"
+    assert R.base_name("pallas_paged_decode_attention.30") == (
+        "pallas_paged_decode_attention")
+    assert R.base_name("fusion") == "fusion"
+    assert R.base_name("copy.v2") == "copy.v2"
+    ops = [ev("k.1", 0, 10), ev("k.2", 10, 10), ev("other", 20, 5)]
+    red = R.reduce([device(0, ops)], 1, SPANS)
+    assert red.op_seconds() == {"k": pytest.approx(20e-9),
+                                "other": pytest.approx(5e-9)}
+
+
+def test_threads_of_one_name_keep_their_lines(tmp_path):
+    """Host threads share a name ("python3"); a replica's spans must not
+    be lost to the generator's line."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((64, 64))
+    f(x).block_until_ready()
+    import threading
+
+    def worker(name):
+        for _ in range(3):
+            with jax.profiler.TraceAnnotation(name):
+                f(x).block_until_ready()
+            with jax.profiler.TraceAnnotation(R.WORK_MARKER, decode_rows=1):
+                pass
+
+    jax.profiler.start_trace(str(tmp_path))
+    time.sleep(0.05)
+    threads = [threading.Thread(target=worker, args=(n,))
+               for n in ("step", "route", "ingest")]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    jax.profiler.stop_trace()
+    planes = R.load(R.find_xplane(str(tmp_path)), SPANS)
+    red = R.reduce(planes, 1, SPANS)
+    assert all(red.spans[n] for n in ("step", "route", "ingest"))
+    assert len(red.work) == 9
+    assert int(red.work[0]["decode_rows"]) == 1
