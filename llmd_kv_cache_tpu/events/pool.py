@@ -31,6 +31,7 @@ from ..core.token_processor import ChunkedTokenDatabase
 from ..index.base import Index
 from ..resilience.liveness import PodLivenessTracker
 from ..telemetry import flight_recorder, tracer
+from ..telemetry.tracing import current_traceparent, remote_parent
 from ..telemetry.flight_recorder import KIND_INGEST, KIND_OVERFLOW
 from ..utils.fnv import fnv1a_32
 from ..utils.logging import get_logger
@@ -965,9 +966,12 @@ class _IngestCoalescer:
         self.index = index
         self.saved_ops = 0  # index calls absorbed by merging
         # pending add: [engine_keys, request_keys, entries_sig, entries,
-        #               engine_key → request_key]
+        #               engine_key → request_key, traceparent]
         self._add: Optional[list] = None
-        # pending evict: [(key_type, entries_sig), keys, entries]
+        # pending evict: [(key_type, entries_sig), keys, entries, traceparent]
+        # ``traceparent`` is the span that was ambient when the buffer was
+        # started (the ingest span of its first message): the write happens
+        # at flush, after that span has ended, and is parented under it.
         self._evict: Optional[list] = None
 
     # -- flushing ---------------------------------------------------------
@@ -975,20 +979,22 @@ class _IngestCoalescer:
     def _flush_add(self) -> None:
         if self._add is None:
             return
-        engine_keys, request_keys, _, entries, _ = self._add
+        engine_keys, request_keys, _, entries, _, parent = self._add
         self._add = None
         try:
-            self.index.add(engine_keys, request_keys, entries)
+            with remote_parent(parent):
+                self.index.add(engine_keys, request_keys, entries)
         except Exception:
             logger.exception("coalesced add of %d keys failed", len(request_keys))
 
     def _flush_evict(self) -> None:
         if self._evict is None:
             return
-        (key_type, _), keys, entries = self._evict
+        (key_type, _), keys, entries, parent = self._evict
         self._evict = None
         try:
-            self.index.evict_batch(keys, key_type, entries)
+            with remote_parent(parent):
+                self.index.evict_batch(keys, key_type, entries)
         except Exception:
             logger.exception("coalesced evict of %d keys failed", len(keys))
 
@@ -1008,7 +1014,7 @@ class _IngestCoalescer:
             return
         sig = tuple(entries)
         if self._add is not None:
-            b_ek, b_rk, b_sig, _, b_map = self._add
+            b_ek, b_rk, b_sig, _, b_map, _ = self._add
             if b_sig == sig and not any(ek in b_map for ek in engine_keys):
                 b_ek.extend(engine_keys)
                 b_rk.extend(request_keys)
@@ -1018,7 +1024,7 @@ class _IngestCoalescer:
             self._flush_add()
         self._add = [
             list(engine_keys), list(request_keys), sig, list(entries),
-            dict(zip(engine_keys, request_keys)),
+            dict(zip(engine_keys, request_keys)), current_traceparent(),
         ]
 
     def evict_batch(self, keys, key_type, entries) -> None:
@@ -1030,7 +1036,7 @@ class _IngestCoalescer:
                 self.saved_ops += 1
                 return
             self._flush_evict()
-        self._evict = [sig, list(keys), list(entries)]
+        self._evict = [sig, list(keys), list(entries), current_traceparent()]
 
     def get_request_key(self, engine_key):
         if self._add is not None:

@@ -518,6 +518,17 @@ def bucket_histogram(
     return hist
 
 
+def forget_bucket_histograms(*names: str) -> None:
+    """Drop the named BucketHistograms (all of them when none is named), so
+    that the next ``bucket_histogram()`` of a name starts an empty family.
+    For a process that builds one fleet after another — a test session —
+    and must not hand the later one the earlier one's observations; holders
+    of a dropped instance keep observing into it, unexported."""
+    with _bucket_hist_lock:
+        for name in names or list(_BUCKET_HISTOGRAMS):
+            _BUCKET_HISTOGRAMS.pop(name, None)
+
+
 # --------------------------------------------------------------------------
 # Engine data-plane families (kvtpu_engine_*): KV-pool occupancy, restore
 # outcomes, and request lifecycle counters for the TPU serving engine.

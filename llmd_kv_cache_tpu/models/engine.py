@@ -52,7 +52,25 @@ from ..resilience.shedding import (
     CoDelShedder,
     OverloadShedError,
 )
-from ..telemetry.tracing import tracer
+from ..telemetry.tracing import (
+    PHASE_ENQUEUE_ADMIT,
+    PHASE_ENQUEUE_HASH,
+    PHASE_ENQUEUE_LOOKUP,
+    PHASE_STEP_COMMIT,
+    PHASE_STEP_DISPATCH,
+    PHASE_STEP_EMIT,
+    PHASE_STEP_FETCH,
+    PHASE_STEP_FINISH,
+    PHASE_STEP_INPUTS,
+    PHASE_STEP_OFFLOAD_POLL,
+    PHASE_STEP_SAMPLE,
+    PHASE_STEP_SCHEDULE,
+    NOOP_SPAN,
+    SPAN_ENGINE_DECODE_STEP,
+    EnginePhases,
+    phase,
+    span_event,
+)
 from ..utils.logging import get_logger
 from .llama import (
     LlamaConfig,
@@ -333,6 +351,8 @@ class BlockManager:
         # since last use) — the working-set tracker's eviction-age
         # histogram (engine.attach_workingset wires it).
         self.on_evict: Optional[Callable[[float], None]] = None
+        # The owning engine's EnginePhases (None = phases off).
+        self.phases: Optional[EnginePhases] = None
         if spec_kind is not None:
             self.spec_kind = spec_kind
             self.spec_window = spec_window
@@ -391,7 +411,9 @@ class BlockManager:
 
     def _emit(self, events: list[GenericEvent]) -> None:
         if self.event_sink is not None and events:
-            self.event_sink(events)
+            with phase(self.phases, PHASE_STEP_EMIT) as sp:
+                sp.set_attribute("events", len(events))
+                self.event_sink(events)
 
     # -- prefix cache --
 
@@ -1134,6 +1156,13 @@ class MiniEngine:
             if self.hybrid:
                 self._telemetry_pools.append(("swa", self.swa_manager))
             self.telemetry.scrape_pools(self._telemetry_pools)
+        # Engine phases (telemetry.tracing.phase): on with the telemetry
+        # above, else None — every phase site is then the shared no-op.
+        self._phases: Optional[EnginePhases] = None
+        if self.telemetry is not None:
+            self._phases = EnginePhases(self.cfg.pod_identifier)
+            for _, manager in self._telemetry_pools:
+                manager.phases = self._phases
 
     @property
     def attention_backends(self) -> dict:
@@ -1148,7 +1177,12 @@ class MiniEngine:
     def _to_dev(self, x, dtype=None):
         """Host value → array on this replica's device (JAX's default
         device, uncommitted, when the engine was given none)."""
-        return jax.device_put(np.asarray(x, dtype), self._device)
+        x = np.asarray(x, dtype)
+        ph = self._phases
+        if ph is not None:
+            ph.transfers += 1
+            ph.bytes += x.nbytes
+        return jax.device_put(x, self._device)
 
     # -- admission --
 
@@ -1223,6 +1257,25 @@ class MiniEngine:
         self._finish_prefill(req)
         return req
 
+    def _dispatch_phase(self, req: Optional[Request], rows: int,
+                        tokens: int, padded: int):
+        """The ``step.dispatch`` phase of one jitted call: the transfers of
+        its arguments and the call returning, with the sizes that explain
+        its length. ``req`` is the request whose prefill chunk rides it
+        (None for a pure decode program): its ``traceparent`` makes the
+        phase the trace's ``engine.prefill_chunk`` span."""
+        ph = self._phases
+        traceparent = None if req is None else req.traceparent
+        if ph is None and traceparent is None:
+            return phase(None, PHASE_STEP_DISPATCH)
+        if req is None:
+            return phase(ph, PHASE_STEP_DISPATCH, programs=1, rows=rows,
+                         tokens=tokens, padded=padded)
+        return phase(ph, PHASE_STEP_DISPATCH, traceparent, programs=1,
+                     rows=rows, tokens=tokens, padded=padded,
+                     request_id=req.request_id, prefill_pos=req.prefill_pos,
+                     process=self.cfg.pod_identifier)
+
     def _record_shed(self, outcome: str, priority: int) -> None:
         """Best-effort shed accounting: metric family + flight recorder.
         Never lets telemetry failures interfere with admission."""
@@ -1296,25 +1349,19 @@ class MiniEngine:
             if verdict == BROWNOUT:
                 brownout = True
                 self._record_shed("brownout", priority)
+        with phase(self._phases, PHASE_ENQUEUE_ADMIT, traceparent) as sp:
+            if sp is not NOOP_SPAN:
+                sp.set_attribute("request_id", request_id)
+                sp.set_attribute("prompt_tokens", len(prompt))
+                sp.set_attribute("process", self.cfg.pod_identifier)
+            req = self._admit(request_id, prompt, max_new_tokens,
+                              defer_restore=True)
+            sp.set_attribute("prefix_hit_blocks",
+                             req.cached_len // self.cfg.model.page_size)
         if traceparent is not None:
-            with tracer().span(
-                "llm_d.kv_cache.engine.admission",
-                parent_traceparent=traceparent,
-                request_id=request_id,
-                prompt_tokens=len(prompt),
-                process=self.cfg.pod_identifier,
-            ) as sp:
-                req = self._admit(request_id, prompt, max_new_tokens,
-                                  defer_restore=True)
-                sp.set_attribute(
-                    "prefix_hit_blocks",
-                    req.cached_len // self.cfg.model.page_size)
             req.traceparent = traceparent
             if self.telemetry is not None:
                 self.telemetry.set_traceparent(request_id, traceparent)
-        else:
-            req = self._admit(request_id, prompt, max_new_tokens,
-                              defer_restore=True)
         req.deadline = (
             Deadline.after(deadline_s) if deadline_s is not None
             else current_deadline()
@@ -1355,10 +1402,22 @@ class MiniEngine:
                 f"(prompt {len(prompt)} + {max_new_tokens} new tokens) but "
                 f"max_pages_per_seq is {self.cfg.max_pages_per_seq}"
             )
-        req.block_hashes = self.processor.tokens_to_kv_block_keys(
-            EMPTY_BLOCK_HASH, prompt, self.cfg.model_name
-        )
+        with phase(self._phases, PHASE_ENQUEUE_HASH) as sp:
+            sp.set_attribute("tokens", len(prompt))
+            req.block_hashes = self.processor.tokens_to_kv_block_keys(
+                EMPTY_BLOCK_HASH, prompt, self.cfg.model_name
+            )
+        with phase(self._phases, PHASE_ENQUEUE_LOOKUP) as sp:
+            self._acquire_pages(req, total_needed, defer_restore)
+            sp.set_attribute("blocks", len(req.block_hashes))
+            sp.set_attribute("hit_blocks", req.hbm_hit_blocks)
+        return req
 
+    def _acquire_pages(self, req: Request, total_needed: int,
+                       defer_restore: bool) -> None:
+        """The rest of admission: prefix-cache probe, storage restore (or
+        its deferral), page allocation with eviction, registration."""
+        request_id, page_size = req.request_id, self.cfg.model.page_size
         cached_pages = self.block_manager.acquire_prefix(req.block_hashes)
         if self.hybrid:
             # A resume at depth d needs group 0's FULL chain [0, d) but
@@ -1448,36 +1507,38 @@ class MiniEngine:
         if self.telemetry is not None:
             self.telemetry.on_admitted(
                 request_id, req.cached_len // page_size)
-        return req
 
     def _finish_prefill(self, req: Request) -> None:
         """Prefill done: register the prompt's full blocks in the prefix
         cache and bootstrap decoding with the first generated token (from
         the prefill step's final logits — vLLM semantics: even a
         full-prefix hit recomputes the last prompt token for logits)."""
-        req.table_dev = None  # pages may swap to canonical at commit
-        self._commit_full_blocks(req)
-        first_token = int(np.argmax(req.last_logits))
-        req.output.append(first_token)
-        if self.telemetry is not None:
-            self.telemetry.on_first_token(req.request_id)
-        if self.audit is not None:
-            self._emit_audit_outcome(req)
-        if self.cfg.role == "prefill" and self.handoff is not None:
-            # Prefill pod: the request's life here ends at first token —
-            # every full block is now committed (the final chunk's store
-            # job just entered the plane), the decode pod recomputes the
-            # partial tail and the bootstrap token itself, so this token
-            # is discarded. Mark the transfer complete-when-settled before
-            # finishing so the coordinator flips ``done`` as the last
-            # store job lands.
-            self.handoff.prefill_finished(req.request_id)
-            req.done = True
-            self._finish(req)
-            return
-        if req.max_new_tokens <= 1:
-            req.done = True
-            self._finish(req)
+        before = req.committed_blocks
+        with phase(self._phases, PHASE_STEP_COMMIT) as sp:
+            req.table_dev = None  # pages may swap to canonical at commit
+            self._commit_full_blocks(req)
+            sp.set_attribute("request_id", req.request_id)
+            sp.set_attribute("blocks", req.committed_blocks - before)
+            first_token = int(np.argmax(req.last_logits))
+            req.output.append(first_token)
+            if self.telemetry is not None:
+                self.telemetry.on_first_token(req.request_id)
+            if self.audit is not None:
+                self._emit_audit_outcome(req)
+            if self.cfg.role == "prefill" and self.handoff is not None:
+                # Prefill pod: the request's life here ends at first token —
+                # every full block is now committed (the final chunk's store
+                # job just entered the plane), the decode pod recomputes the
+                # partial tail and the bootstrap token itself, so this token
+                # is discarded. Mark the transfer complete-when-settled
+                # before finishing so the coordinator flips ``done`` as the
+                # last store job lands.
+                self.handoff.prefill_finished(req.request_id)
+                req.done = True
+                self._finish(req)
+            elif req.max_new_tokens <= 1:
+                req.done = True
+                self._finish(req)
 
     def _emit_audit_outcome(self, req: Request) -> None:
         """Best-effort ground-truth emission at prefill finish: the
@@ -1744,8 +1805,11 @@ class MiniEngine:
         """Prefill-role mid-prefill commit: push the blocks this chunk
         completed into the prefix cache and the transfer tier."""
         before = req.committed_blocks
-        self._commit_full_blocks(
-            req, upto=req.computed_len // self.cfg.model.page_size)
+        with phase(self._phases, PHASE_STEP_COMMIT) as sp:
+            self._commit_full_blocks(
+                req, upto=req.computed_len // self.cfg.model.page_size)
+            sp.set_attribute("request_id", req.request_id)
+            sp.set_attribute("blocks", req.committed_blocks - before)
         if req.committed_blocks != before:
             # commit_blocks may have swapped duplicate pages to canonical;
             # the cached device table would keep scattering into the
@@ -1977,71 +2041,80 @@ class MiniEngine:
         only the final chunk's logits are downloaded: a host transfer
         waits for the device)."""
         page_size = self.cfg.model.page_size
-        chunk_cap = max(page_size, self.cfg.max_prefill_tokens
-                        // page_size * page_size)
-        if req.table_dev is None:
-            req.table_dev = self._to_dev(self._page_table_for(req))[None, :]
-        table = req.table_dev
+        ph = self._phases
+        with phase(ph, PHASE_STEP_INPUTS):
+            chunk_cap = max(page_size, self.cfg.max_prefill_tokens
+                            // page_size * page_size)
+            pos = req.prefill_pos
+            chunk = req.prompt[pos:pos + chunk_cap]
+            # Bucket the padded length to powers of two (in pages) so the
+            # jit cache holds O(log max_prefill) shapes instead of one per
+            # suffix length — compiles are 20-40 s each on TPU.
+            pages_needed = max(1, (len(chunk) + page_size - 1) // page_size)
+            bucket = 1
+            while bucket < pages_needed:
+                bucket *= 2
+            seq = bucket * page_size
+            tokens = np.zeros((1, seq), np.int32)
+            tokens[0, : len(chunk)] = chunk
+            if self.hybrid:
+                # SWA pages arrive just-in-time for this chunk's blocks and
+                # out-of-window slots return to the pool after it, so a
+                # long prompt's peak SWA demand is window + chunk.
+                self._swa_ensure(req, (pos + len(chunk) - 1) // page_size)
 
-        pos = req.prefill_pos
-        chunk = req.prompt[pos:pos + chunk_cap]
-        # Bucket the padded length to powers of two (in pages) so the
-        # jit cache holds O(log max_prefill) shapes instead of one per
-        # suffix length — compiles are 20-40 s each on TPU.
-        pages_needed = max(1, (len(chunk) + page_size - 1) // page_size)
-        bucket = 1
-        while bucket < pages_needed:
-            bucket *= 2
-        seq = bucket * page_size
-        tokens = np.zeros((1, seq), np.int32)
-        tokens[0, : len(chunk)] = chunk
-        if self._sp > 1 and seq % self._sp == 0:
-            # Sequence-parallel prefill: place the chunk sharded on seq
-            # in ONE host→device transfer; XLA splits the per-token
-            # compute sp-ways (see __init__).
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        # Every transfer rides the dispatch phase, position and length as
+        # arguments of the call: see _decode_chunk.
+        with self._dispatch_phase(req, 1, len(chunk), seq):
+            if req.table_dev is None:
+                req.table_dev = self._to_dev(
+                    self._page_table_for(req))[None, :]
+            table = req.table_dev
+            if self._sp > 1 and seq % self._sp == 0:
+                # Sequence-parallel prefill: place the chunk sharded on seq
+                # in ONE host→device transfer; XLA splits the per-token
+                # compute sp-ways (see __init__).
+                from jax.sharding import NamedSharding, PartitionSpec as P
 
-            tokens_dev = jax.device_put(
-                tokens, NamedSharding(self.mesh, P(None, "sp")))
-        else:
-            tokens_dev = self._to_dev(tokens)
-
-        if self.hybrid:
-            # SWA pages arrive just-in-time for this chunk's blocks and
-            # out-of-window slots return to the pool after it, so a
-            # long prompt's peak SWA demand is window + chunk.
-            self._swa_ensure(req, (pos + len(chunk) - 1) // page_size)
-            swa_table = self._to_dev(self._swa_table_for(req))[None, :]
-            (logits, self.k_cache, self.v_cache,
-             self.k_swa, self.v_swa) = forward_hybrid(
-                self.params, self.cfg.model,
-                tokens_dev,
-                self.k_cache, self.v_cache, self.k_swa, self.v_swa,
-                table, swa_table,
-                self._to_dev([pos], np.int32),
-                self._to_dev([len(chunk)], np.int32),
-                last_only=True,
-            )
-            req.computed_len = pos + len(chunk)  # _swa_reclaim reads it
-            self._swa_reclaim(req)
-        else:
-            logits, self.k_cache, self.v_cache = self._prefill_forward(
-                self.params, self.cfg.model,
-                tokens_dev,
-                self.k_cache, self.v_cache,
-                table,
-                self._to_dev([pos], np.int32),
-                self._to_dev([len(chunk)], np.int32),
-                last_only=True,
-            )
+                tokens_dev = jax.device_put(
+                    tokens, NamedSharding(self.mesh, P(None, "sp")))
+            else:
+                tokens_dev = self._to_dev(tokens)
+            if self.hybrid:
+                swa_table = self._to_dev(self._swa_table_for(req))[None, :]
+                (logits, self.k_cache, self.v_cache,
+                 self.k_swa, self.v_swa) = forward_hybrid(
+                    self.params, self.cfg.model,
+                    tokens_dev,
+                    self.k_cache, self.v_cache, self.k_swa, self.v_swa,
+                    table, swa_table,
+                    self._to_dev([pos], np.int32),
+                    self._to_dev([len(chunk)], np.int32),
+                    last_only=True,
+                )
+            else:
+                logits, self.k_cache, self.v_cache = self._prefill_forward(
+                    self.params, self.cfg.model,
+                    tokens_dev,
+                    self.k_cache, self.v_cache,
+                    table,
+                    self._to_dev([pos], np.int32),
+                    self._to_dev([len(chunk)], np.int32),
+                    last_only=True,
+                )
         req.computed_len = pos + len(chunk)
+        if self.hybrid:
+            self._swa_reclaim(req)  # reads computed_len
         if self.telemetry is not None:
             # Padding-waste accounting: len(chunk) real tokens rode a
             # seq-token padded dispatch (the power-of-two page bucket).
             self.telemetry.on_dispatch_tokens(len(chunk), seq)
         if pos + len(chunk) >= len(req.prompt):
             # last_only: logits row 0 is the chunk's final valid position.
-            req.last_logits = np.asarray(logits[0, 0])
+            with phase(ph, PHASE_STEP_SAMPLE, programs=1):
+                row = logits[0, 0]
+            with phase(ph, PHASE_STEP_FETCH):
+                req.last_logits = np.asarray(row)
             req.prefill_pos = None
         else:
             req.prefill_pos = pos + len(chunk)
@@ -2138,28 +2211,11 @@ class MiniEngine:
 
     # -- decode --
 
-    def step(self) -> dict[str, int]:
-        """One scheduling step: advance at most one prefill chunk, then one
-        decode step for every decoding request.
-
-        Returns {request_id: newest_token}. Decode is batched into a single
-        jit call with padding up to max_batch; when ``decode_burst > 1``
-        each call may emit a power-of-two burst of tokens per request (all
-        of a request's burst tokens land in ``req.output``; the returned
-        dict carries the newest). ``enqueue``d requests prefill here,
-        chunk-at-a-time — a long prompt delays running decodes by one
-        chunk per step, never its whole prefill.
-        """
+    def _pick_prefill(self) -> Optional[Request]:
+        """The scheduler's pick: the FIFO head among requests still in
+        prefill, or None while its restore or handoff gate holds it."""
         tel = self.telemetry
-        step_t0 = time.monotonic() if tel is not None else 0.0
-        self.poll_offload()
-        emitted: dict[str, int] = {}
-        # Continuous batching: one prefill chunk for the oldest admitted-
-        # but-not-yet-decoding request (FIFO — finish one prefill before
-        # starting the next so TTFTs don't all pay for each other).
-        # Snapshot: _prefill_chunk → _finish_prefill → _finish mutates
-        # self._running for 1-token requests.
-        just_prefilled: Optional[str] = None
+        prefill_req: Optional[Request] = None
         # Start every pending deferred restore up front, not just the FIFO
         # head's: the loads are independent DMA jobs, so a younger request's
         # storage fetch overlaps the older request's restore+prefill instead
@@ -2168,7 +2224,6 @@ class MiniEngine:
             req = self.requests[rid]
             if req.prefill_pos is not None and req.restore_pending:
                 self._start_deferred_restore(req)
-        prefill_req: Optional[Request] = None
         for rid in list(self._running):
             req = self.requests[rid]
             if req.prefill_pos is not None:
@@ -2204,6 +2259,36 @@ class MiniEngine:
                         break
                 prefill_req = req
                 break
+        return prefill_req
+
+    def step(self) -> dict[str, int]:
+        """One scheduling step: advance at most one prefill chunk, then one
+        decode step for every decoding request.
+
+        Returns {request_id: newest_token}. Decode is batched into a single
+        jit call with padding up to max_batch; when ``decode_burst > 1``
+        each call may emit a power-of-two burst of tokens per request (all
+        of a request's burst tokens land in ``req.output``; the returned
+        dict carries the newest). ``enqueue``d requests prefill here,
+        chunk-at-a-time — a long prompt delays running decodes by one
+        chunk per step, never its whole prefill.
+        """
+        tel = self.telemetry
+        step_t0 = time.monotonic() if tel is not None else 0.0
+        ph = self._phases
+        if ph is not None:
+            ph.begin_step()
+        with phase(ph, PHASE_STEP_OFFLOAD_POLL):
+            self.poll_offload()
+        emitted: dict[str, int] = {}
+        # Continuous batching: one prefill chunk for the oldest admitted-
+        # but-not-yet-decoding request (FIFO — finish one prefill before
+        # starting the next so TTFTs don't all pay for each other).
+        # Snapshot: _prefill_chunk → _finish_prefill → _finish mutates
+        # self._running for 1-token requests.
+        just_prefilled: Optional[str] = None
+        with phase(ph, PHASE_STEP_SCHEDULE):
+            prefill_req = self._pick_prefill()
         if self._ragged:
             # Ragged scheduling: the prefill chunk and every active decode
             # row pack into one flat-axis dispatch (the prefill bootstrap
@@ -2212,17 +2297,7 @@ class MiniEngine:
         else:
             if prefill_req is not None:
                 req = prefill_req
-                if req.traceparent is not None:
-                    with tracer().span(
-                        "llm_d.kv_cache.engine.prefill_chunk",
-                        parent_traceparent=req.traceparent,
-                        request_id=req.request_id,
-                        prefill_pos=req.prefill_pos,
-                        process=self.cfg.pod_identifier,
-                    ):
-                        self._prefill_chunk(req)
-                else:
-                    self._prefill_chunk(req)
+                self._prefill_chunk(req)
                 if (req.prefill_pos is not None and self.handoff is not None
                         and self.cfg.role == "prefill"):
                     # Prefill pod: commit this chunk's full blocks NOW so
@@ -2249,13 +2324,17 @@ class MiniEngine:
                     emitted.update(self._decode_chunk_burst(chunk, burst))
                 else:
                     emitted.update(self._decode_chunk(chunk))
-        for rid in list(self._running):
-            req = self.requests[rid]
-            if req.done:
-                self._finish(req)
-        if tel is not None:
-            tel.on_step(time.monotonic() - step_t0, bool(emitted),
-                        self._telemetry_pools)
+        with phase(ph, PHASE_STEP_FINISH) as sp:
+            for rid in list(self._running):
+                req = self.requests[rid]
+                if req.done:
+                    self._finish(req)
+            if tel is not None:
+                tel.on_step(time.monotonic() - step_t0, bool(emitted),
+                            self._telemetry_pools)
+                # The step's counters ride its last phase.
+                sp.set_attribute("programs", ph.programs)
+                sp.set_attribute("transfers", ph.transfers)
         return emitted
 
     def _drain_offload(self, target_job: Optional[int] = None):
@@ -2406,63 +2485,54 @@ class MiniEngine:
         enter the kernel's row loop — the per-token waste the pool
         counters measure is the bucket tail, not ``max_batch`` dead rows.
         """
-        page_size = self.cfg.model.page_size
-        q_lens: list[int] = []
-        ctxs: list[int] = []
-        tables_list: list[np.ndarray] = []
-        flat_tokens: list[int] = []
-        for req in decode_rows:
-            flat_tokens.append(
-                req.output[-1] if req.output else req.prompt[-1])
-            q_lens.append(1)
-            ctxs.append(req.computed_len)
-            tables_list.append(self._page_table_for(req))
-        p_chunk: list[int] = []
-        p_pos = 0
-        if prefill_req is not None:
-            chunk_cap = max(page_size, self.cfg.max_prefill_tokens
-                            // page_size * page_size)
-            p_pos = prefill_req.prefill_pos
-            p_chunk = list(prefill_req.prompt[p_pos:p_pos + chunk_cap])
-            flat_tokens.extend(p_chunk)
-            q_lens.append(len(p_chunk))
-            ctxs.append(p_pos)
-            tables_list.append(self._page_table_for(prefill_req))
+        ph = self._phases
+        with phase(ph, PHASE_STEP_INPUTS):
+            page_size = self.cfg.model.page_size
+            q_lens: list[int] = []
+            ctxs: list[int] = []
+            tables_list: list[np.ndarray] = []
+            flat_tokens: list[int] = []
+            for req in decode_rows:
+                flat_tokens.append(
+                    req.output[-1] if req.output else req.prompt[-1])
+                q_lens.append(1)
+                ctxs.append(req.computed_len)
+                tables_list.append(self._page_table_for(req))
+            p_chunk: list[int] = []
+            p_pos = 0
+            if prefill_req is not None:
+                chunk_cap = max(page_size, self.cfg.max_prefill_tokens
+                                // page_size * page_size)
+                p_pos = prefill_req.prefill_pos
+                p_chunk = list(prefill_req.prompt[p_pos:p_pos + chunk_cap])
+                flat_tokens.extend(p_chunk)
+                q_lens.append(len(p_chunk))
+                ctxs.append(p_pos)
+                tables_list.append(self._page_table_for(prefill_req))
 
-        rows = len(q_lens)
-        t_real = len(flat_tokens)
-        t_pad = 8
-        while t_pad < t_real:
-            t_pad *= 2
-        rows_pad = 1
-        while rows_pad < rows:
-            rows_pad *= 2
+            rows = len(q_lens)
+            t_real = len(flat_tokens)
+            t_pad = 8
+            while t_pad < t_real:
+                t_pad *= 2
+            rows_pad = 1
+            while rows_pad < rows:
+                rows_pad *= 2
 
-        tokens = np.zeros((1, t_pad), np.int32)
-        tokens[0, :t_real] = flat_tokens
-        # Padding rows are empty: start == end == t_real, zero tables,
-        # ctx 0 — the kernel's block metadata never reaches them.
-        row_starts = np.full((rows_pad + 1,), t_real, np.int32)
-        row_starts[:rows + 1] = np.concatenate(
-            [[0], np.cumsum(q_lens)]).astype(np.int32)
-        ctx = np.zeros((rows_pad,), np.int32)
-        ctx[:rows] = ctxs
-        tables = np.zeros((rows_pad, self.cfg.max_pages_per_seq), np.int32)
-        for i, t in enumerate(tables_list):
-            tables[i] = t
+            tokens = np.zeros((1, t_pad), np.int32)
+            tokens[0, :t_real] = flat_tokens
+            # Padding rows are empty: start == end == t_real, zero tables,
+            # ctx 0 — the kernel's block metadata never reaches them.
+            row_starts = np.full((rows_pad + 1,), t_real, np.int32)
+            row_starts[:rows + 1] = np.concatenate(
+                [[0], np.cumsum(q_lens)]).astype(np.int32)
+            ctx = np.zeros((rows_pad,), np.int32)
+            ctx[:rows] = ctxs
+            tables = np.zeros((rows_pad, self.cfg.max_pages_per_seq), np.int32)
+            for i, t in enumerate(tables_list):
+                tables[i] = t
 
-        span_cm = None
-        if prefill_req is not None and prefill_req.traceparent is not None:
-            span_cm = tracer().span(
-                "llm_d.kv_cache.engine.prefill_chunk",
-                parent_traceparent=prefill_req.traceparent,
-                request_id=prefill_req.request_id,
-                prefill_pos=p_pos,
-                process=self.cfg.pod_identifier,
-            )
-        try:
-            if span_cm is not None:
-                span_cm.__enter__()
+        with self._dispatch_phase(prefill_req, rows, t_real, t_pad):
             logits, self.k_cache, self.v_cache = forward_ragged(
                 self.params, self.cfg.model,
                 self._to_dev(tokens),
@@ -2472,46 +2542,38 @@ class MiniEngine:
                 self._to_dev(ctx, np.int32),
                 interpret=self._ragged_interpret,
             )
-        finally:
-            if span_cm is not None:
-                span_cm.__exit__(None, None, None)
 
         tel = self.telemetry
         if tel is not None:
             tel.on_dispatch_tokens(t_real, t_pad)
 
         out: dict[str, int] = {}
+        # The prefill row's logit IS its final valid token's (the ragged
+        # forward returns one row per ragged row).
+        finishing = (prefill_req is not None
+                     and p_pos + len(p_chunk) >= len(prefill_req.prompt))
+        with phase(ph, PHASE_STEP_SAMPLE,
+                   programs=2 * bool(decode_rows) + finishing):
+            picked = (jnp.argmax(logits[:len(decode_rows)], axis=-1)
+                      if decode_rows else None)
+            last_row = logits[rows - 1] if finishing else None
+        with phase(ph, PHASE_STEP_FETCH):
+            next_tokens = np.asarray(picked) if decode_rows else None
+            if finishing:
+                prefill_req.last_logits = np.asarray(last_row)
         if decode_rows:
-            next_tokens = np.asarray(
-                jnp.argmax(logits[:len(decode_rows)], axis=-1))
             now = time.monotonic() if tel is not None else 0.0
             for i, req in enumerate(decode_rows):
                 req.computed_len += 1
                 tok = int(next_tokens[i])
                 req.output.append(tok)
                 out[req.request_id] = tok
-                if tel is not None:
-                    tel.on_decode_tokens(req.request_id, 1, now)
-                if req.traceparent is not None:
-                    with tracer().span(
-                        "llm_d.kv_cache.engine.decode_step",
-                        parent_traceparent=req.traceparent,
-                        request_id=req.request_id,
-                        tokens=1,
-                        computed_len=req.computed_len,
-                        process=self.cfg.pod_identifier,
-                    ):
-                        pass  # event-style span: marks the emission point
-                if len(req.output) >= req.max_new_tokens:
-                    req.done = True
+                self._row_emitted(req, 1, now)
 
         if prefill_req is not None:
             req = prefill_req
             req.computed_len = p_pos + len(p_chunk)
-            if p_pos + len(p_chunk) >= len(req.prompt):
-                # The prefill row's logit IS its final valid token's (the
-                # ragged forward returns one row per ragged row).
-                req.last_logits = np.asarray(logits[rows - 1])
+            if finishing:
                 req.prefill_pos = None
                 self._finish_prefill(req)
                 if req.output:
@@ -2521,6 +2583,19 @@ class MiniEngine:
                 if self.handoff is not None and self.cfg.role == "prefill":
                     self._commit_prefill_chunk(req)
         return out
+
+    def _row_emitted(self, req: Request, taken: int, now: float) -> None:
+        """Bookkeeping of one row's ``taken`` newly decoded tokens."""
+        if self.telemetry is not None:
+            self.telemetry.on_decode_tokens(req.request_id, taken, now)
+        if req.traceparent is not None:
+            # Event-style span: marks the emission point in the trace.
+            span_event(SPAN_ENGINE_DECODE_STEP, req.traceparent,
+                       request_id=req.request_id, tokens=taken,
+                       computed_len=req.computed_len,
+                       process=self.cfg.pod_identifier)
+        if len(req.output) >= req.max_new_tokens:
+            req.done = True
 
     def _decode_batch_arrays(self, chunk: list[Request], rows: int = 0):
         """Padded per-row decode inputs shared by the single-step and burst
@@ -2554,78 +2629,74 @@ class MiniEngine:
         page_size = self.cfg.model.page_size
         if self.hybrid and self._burst_degraded:
             return self._decode_chunk(chunk)
-        last, ctx, tables = self._decode_batch_arrays(chunk)
-        budgets = np.zeros((self.cfg.max_batch,), np.int32)
-        swa_tables = (np.zeros((self.cfg.max_batch, self.cfg.max_pages_per_seq),
-                               np.int32) if self.hybrid else None)
-        for i, req in enumerate(chunk):
-            budgets[i] = req.max_new_tokens - len(req.output)
-            if self.hybrid:
-                taken = min(steps, int(budgets[i]))
-                # The burst writes KV at positions computed_len ..
-                # computed_len+taken-1; every SWA slot it touches needs a
-                # live page before the tables freeze. If the pool cannot
-                # cover the whole batch's burst transient (pool sized to
-                # the single-step bound), latch single-token decoding for
-                # this engine instead of dying mid-decode: the transients
-                # already taken for the chunk are handed back first, so
-                # the single-step path's own page needs are met.
-                try:
-                    self._swa_ensure(
-                        req,
-                        (req.computed_len + max(taken, 1) - 1) // page_size)
-                except RuntimeError:
-                    self._release_burst_transients(chunk)
-                    self._burst_degraded = True
-                    logger.warning(
-                        "SWA pool cannot cover a %d-token burst transient; "
-                        "decoding single-token from now on (size "
-                        "num_swa_pages for window + decode_burst to keep "
-                        "bursts)", steps)
-                    return self._decode_chunk(chunk)
-                swa_tables[i] = self._swa_table_for(req)
+        ph = self._phases
+        degraded = False
+        with phase(ph, PHASE_STEP_INPUTS):
+            last, ctx, tables = self._decode_batch_arrays(chunk)
+            budgets = np.zeros((self.cfg.max_batch,), np.int32)
+            swa_tables = (
+                np.zeros((self.cfg.max_batch, self.cfg.max_pages_per_seq),
+                         np.int32) if self.hybrid else None)
+            for i, req in enumerate(chunk):
+                budgets[i] = req.max_new_tokens - len(req.output)
+                if self.hybrid:
+                    taken = min(steps, int(budgets[i]))
+                    # The burst writes KV at positions computed_len ..
+                    # computed_len+taken-1; every SWA slot it touches needs
+                    # a live page before the tables freeze. If the pool
+                    # cannot cover the whole batch's burst transient (pool
+                    # sized to the single-step bound), latch single-token
+                    # decoding for this engine instead of dying mid-decode:
+                    # the transients already taken for the chunk are handed
+                    # back first, so the single-step path's own page needs
+                    # are met.
+                    try:
+                        self._swa_ensure(
+                            req, (req.computed_len + max(taken, 1) - 1)
+                            // page_size)
+                    except RuntimeError:
+                        self._release_burst_transients(chunk)
+                        self._burst_degraded = degraded = True
+                        logger.warning(
+                            "SWA pool cannot cover a %d-token burst "
+                            "transient; decoding single-token from now on "
+                            "(size num_swa_pages for window + decode_burst "
+                            "to keep bursts)", steps)
+                        break
+                    swa_tables[i] = self._swa_table_for(req)
+        if degraded:
+            return self._decode_chunk(chunk)
 
-        if self.hybrid:
-            (toks, self.k_cache, self.v_cache,
-             self.k_swa, self.v_swa) = self._decode_multi_hybrid(
-                self.params, self.cfg.model,
-                self._to_dev(last),
-                self.k_cache, self.v_cache, self.k_swa, self.v_swa,
-                self._to_dev(tables), self._to_dev(swa_tables),
-                self._to_dev(ctx, np.int32),
-                self._to_dev(budgets), steps=steps,
-            )
-        else:
-            toks, self.k_cache, self.v_cache = self._decode_multi(
-                self.params, self.cfg.model,
-                self._to_dev(last), self.k_cache, self.v_cache,
-                self._to_dev(tables), self._to_dev(ctx, np.int32),
-                self._to_dev(budgets), steps=steps,
-            )
-        toks_host = np.asarray(toks)
+        with self._dispatch_phase(None, len(chunk), len(chunk) * steps,
+                                  self.cfg.max_batch * steps):
+            if self.hybrid:
+                (toks, self.k_cache, self.v_cache,
+                 self.k_swa, self.v_swa) = self._decode_multi_hybrid(
+                    self.params, self.cfg.model,
+                    self._to_dev(last),
+                    self.k_cache, self.v_cache, self.k_swa, self.v_swa,
+                    self._to_dev(tables), self._to_dev(swa_tables),
+                    self._to_dev(ctx, np.int32),
+                    self._to_dev(budgets), steps=steps,
+                )
+            else:
+                toks, self.k_cache, self.v_cache = self._decode_multi(
+                    self.params, self.cfg.model,
+                    self._to_dev(last), self.k_cache, self.v_cache,
+                    self._to_dev(tables), self._to_dev(ctx, np.int32),
+                    self._to_dev(budgets), steps=steps,
+                )
+        with phase(ph, PHASE_STEP_FETCH):
+            toks_host = np.asarray(toks)
         out = {}
-        tel = self.telemetry
-        now = time.monotonic() if tel is not None else 0.0
+        now = time.monotonic() if self.telemetry is not None else 0.0
         for i, req in enumerate(chunk):
             taken = min(steps, int(budgets[i]))
             burst = [int(t) for t in toks_host[i, :taken]]
             req.output.extend(burst)
             req.computed_len += taken
             out[req.request_id] = burst[-1]
-            if tel is not None:
-                tel.on_decode_tokens(req.request_id, taken, now)
-            if req.traceparent is not None:
-                with tracer().span(
-                    "llm_d.kv_cache.engine.decode_step",
-                    parent_traceparent=req.traceparent,
-                    request_id=req.request_id,
-                    tokens=taken,
-                    computed_len=req.computed_len,
-                    process=self.cfg.pod_identifier,
-                ):
-                    pass  # event-style span: marks the emission point
-            if len(req.output) >= req.max_new_tokens:
-                req.done = True
+            self._row_emitted(req, taken, now)
             if self.hybrid:
                 self._swa_reclaim(req)
         return out
@@ -2646,39 +2717,49 @@ class MiniEngine:
             while b < len(chunk):
                 b *= 2
             b = min(b, self.cfg.max_batch)
-        last, ctx, tables = self._decode_batch_arrays(chunk, rows=b)
-        tokens = last[:, None].copy()
-        new_lens = np.zeros((b,), np.int32)
-        swa_tables = np.zeros((b, self.cfg.max_pages_per_seq), np.int32)
-        for i, req in enumerate(chunk):
-            new_lens[i] = 1
-            if self.hybrid:
-                # The new token's KV writes at block computed_len//page —
-                # make sure that SWA slot has a live page.
-                self._swa_ensure(
-                    req, req.computed_len // self.cfg.model.page_size)
-                swa_tables[i] = self._swa_table_for(req)
+        ph = self._phases
+        with phase(ph, PHASE_STEP_INPUTS):
+            last, ctx, tables = self._decode_batch_arrays(chunk, rows=b)
+            tokens = last[:, None].copy()
+            new_lens = np.zeros((b,), np.int32)
+            swa_tables = np.zeros((b, self.cfg.max_pages_per_seq), np.int32)
+            for i, req in enumerate(chunk):
+                new_lens[i] = 1
+                if self.hybrid:
+                    # The new token's KV writes at block computed_len//page
+                    # — make sure that SWA slot has a live page.
+                    self._swa_ensure(
+                        req, req.computed_len // self.cfg.model.page_size)
+                    swa_tables[i] = self._swa_table_for(req)
 
-        if self.hybrid:
-            (logits, self.k_cache, self.v_cache,
-             self.k_swa, self.v_swa) = forward_hybrid(
-                self.params, self.cfg.model,
-                self._to_dev(tokens),
-                self.k_cache, self.v_cache, self.k_swa, self.v_swa,
-                self._to_dev(tables), self._to_dev(swa_tables),
-                self._to_dev(ctx, np.int32),
-                self._to_dev(new_lens),
-            )
-        else:
-            logits, self.k_cache, self.v_cache = self._decode_forward(
-                self.params, self.cfg.model,
-                self._to_dev(tokens), self.k_cache, self.v_cache,
-                self._to_dev(tables),
-                self._to_dev(ctx, np.int32),
-                self._to_dev(new_lens),
-            )
+        # The transfers are arguments of the call, so they ride the
+        # dispatch phase on every path: built first and held in locals, the
+        # same arrays made the first call of every program 0.3 s slower on
+        # the chip (PERF.md §6, PR 25) — why is not known.
+        with self._dispatch_phase(None, len(chunk), len(chunk), b):
+            if self.hybrid:
+                (logits, self.k_cache, self.v_cache,
+                 self.k_swa, self.v_swa) = forward_hybrid(
+                    self.params, self.cfg.model,
+                    self._to_dev(tokens),
+                    self.k_cache, self.v_cache, self.k_swa, self.v_swa,
+                    self._to_dev(tables), self._to_dev(swa_tables),
+                    self._to_dev(ctx, np.int32),
+                    self._to_dev(new_lens),
+                )
+            else:
+                logits, self.k_cache, self.v_cache = self._decode_forward(
+                    self.params, self.cfg.model,
+                    self._to_dev(tokens), self.k_cache, self.v_cache,
+                    self._to_dev(tables),
+                    self._to_dev(ctx, np.int32),
+                    self._to_dev(new_lens),
+                )
         out = {}
-        next_tokens = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        with phase(ph, PHASE_STEP_SAMPLE, programs=2):
+            picked = jnp.argmax(logits[:, 0], axis=-1)
+        with phase(ph, PHASE_STEP_FETCH):
+            next_tokens = np.asarray(picked)
         tel = self.telemetry
         if tel is not None:
             # Padding-waste accounting for the padded path: len(chunk)
@@ -2692,20 +2773,7 @@ class MiniEngine:
             tok = int(next_tokens[i])
             req.output.append(tok)
             out[req.request_id] = tok
-            if tel is not None:
-                tel.on_decode_tokens(req.request_id, 1, now)
-            if req.traceparent is not None:
-                with tracer().span(
-                    "llm_d.kv_cache.engine.decode_step",
-                    parent_traceparent=req.traceparent,
-                    request_id=req.request_id,
-                    tokens=1,
-                    computed_len=req.computed_len,
-                    process=self.cfg.pod_identifier,
-                ):
-                    pass  # event-style span: marks the emission point
-            if len(req.output) >= req.max_new_tokens:
-                req.done = True
+            self._row_emitted(req, 1, now)
             if self.hybrid:
                 self._swa_reclaim(req)
         return out

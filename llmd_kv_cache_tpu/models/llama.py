@@ -26,6 +26,30 @@ from ..ops.paged_attention import paged_attention
 
 Params = dict[str, Any]
 
+# ``jax.named_scope`` names inside every step program: metadata only (the
+# ``op_name`` of each HLO op, which a profiler trace carries as ``tf_op``),
+# so device time can be read by region of the model instead of by the
+# fusion names the compiler chose. Per layer: taking the layer's K/V out
+# of the stacked pool, the projections up to RoPE, writing new K/V into
+# the pool, attention with its output projection, the MLP. Once per
+# program: the embedding, the final norm + lm_head, in-program sampling.
+SCOPE_KV_LAYER = "kv_layer"
+SCOPE_QKV = "qkv"
+SCOPE_KV_WRITE = "kv_write"
+SCOPE_ATTENTION = "attention"
+SCOPE_MLP = "mlp"
+SCOPE_EMBED = "embed"
+SCOPE_LM_HEAD = "lm_head"
+SCOPE_SAMPLE = "sample"
+SCOPES = (SCOPE_KV_LAYER, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION,
+          SCOPE_MLP, SCOPE_EMBED, SCOPE_LM_HEAD, SCOPE_SAMPLE)
+
+# The two step programs' names: what ``jax.jit`` calls the functions below
+# and a trace calls their executions (``jit_<name>``). Readers of traces
+# match these; tests/test_model.py pins both sides.
+PROGRAM_PREFILL = "forward_prefill_pallas"
+PROGRAM_DECODE = "forward_decode_pallas"
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -1046,168 +1070,182 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
         for j, li in enumerate(cfg.group_layers(g)):
             local_idx[li] = (g, j)
 
-    x = params["embed"][tokens]  # [b, s, h]
+    def layer_of(cache, lj):
+        with jax.named_scope(SCOPE_KV_LAYER):
+            return cache[lj]
+
+    def write_layer(cache, lj, new_kv, table):
+        """``cache`` with ``new_kv`` scattered into its layer ``lj``."""
+        old_layer = layer_of(cache, lj)
+        with jax.named_scope(SCOPE_KV_WRITE):
+            return cache.at[lj].set(_scatter(old_layer, new_kv, table))
+
+    def write_tail_layer(buf, lj, new_kv):
+        old_layer = layer_of(buf, lj)
+        with jax.named_scope(SCOPE_KV_WRITE):
+            return buf.at[lj].set(write_tail(old_layer, new_kv))
+
+    with jax.named_scope(SCOPE_EMBED):
+        x = params["embed"][tokens]  # [b, s, h]
 
     k_caches = list(k_caches)
     v_caches = list(v_caches)
     for li, layer in enumerate(params["layers"]):
         g, lj = local_idx[li] if len(k_caches) > 1 else (0, li)
         table = tables[g]
-        attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         if cfg.is_mla:
-            # Absorbed MLA (DeepSeek-V2 §2.1.2, TPU-first formulation):
-            # cache ONLY the latent [c_kv ; rope-key] per token and fold
-            # the per-head up-projections into the query and output — the
-            # attention core is then plain multi-query paged attention
-            # with head_dim = rank+rope over the cache this file already
-            # pages, and HBM traffic per token drops by ~num_heads·2.
-            r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-            if "w_mla_in" in layer:  # fused serving layout (fuse_params)
-                fused = attn_in @ layer["w_mla_in"]
-                qc = fused.shape[-1] - r - dr  # static split point
-                head_in = fused[..., :qc]
-                c_kv = fused[..., qc:qc + r]
-                k_rope_in = fused[..., qc + r:]
-                if "q_latent_norm" in layer:
-                    # q-LoRA: the fused block holds w_dq's output; the
-                    # norm between down- and up-projection stays.
-                    q = _rms_norm(head_in, layer["q_latent_norm"],
-                                  cfg.norm_eps) @ layer["wq"]
+            with jax.named_scope(SCOPE_QKV):
+                attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+                # Absorbed MLA (DeepSeek-V2 §2.1.2, TPU-first formulation):
+                # cache ONLY the latent [c_kv ; rope-key] per token and fold
+                # the per-head up-projections into the query and output — the
+                # attention core is then plain multi-query paged attention
+                # with head_dim = rank+rope over the cache this file already
+                # pages, and HBM traffic per token drops by ~num_heads·2.
+                r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+                if "w_mla_in" in layer:  # fused serving layout (fuse_params)
+                    fused = attn_in @ layer["w_mla_in"]
+                    qc = fused.shape[-1] - r - dr  # static split point
+                    head_in = fused[..., :qc]
+                    c_kv = fused[..., qc:qc + r]
+                    k_rope_in = fused[..., qc + r:]
+                    if "q_latent_norm" in layer:
+                        # q-LoRA: the fused block holds w_dq's output; the
+                        # norm between down- and up-projection stays.
+                        q = _rms_norm(head_in, layer["q_latent_norm"],
+                                      cfg.norm_eps) @ layer["wq"]
+                    else:
+                        q = head_in
                 else:
-                    q = head_in
+                    if "w_dq" in layer:
+                        # DeepSeek q-LoRA: q is down-projected to a compressed
+                        # latent, RMS-normed, then up-projected per head — the
+                        # norm between the two matmuls prevents precomposition.
+                        q_in = _rms_norm(attn_in @ layer["w_dq"],
+                                         layer["q_latent_norm"], cfg.norm_eps)
+                    else:
+                        q_in = attn_in
+                    q = q_in @ layer["wq"]
+                    c_kv = attn_in @ layer["w_dkv"]  # [b, s, r]
+                    k_rope_in = attn_in @ layer["w_kr"]
+                q = q.reshape(batch, seq, cfg.num_heads, cfg.head_dim + dr)
+                q_nope, q_rope = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
+                q_rope = _rope(q_rope, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
+                if "latent_norm" in layer:
+                    # DeepSeek kv_a_layernorm: the latent is RMS-normed before
+                    # the up-projections — cached post-norm, so absorption is
+                    # unchanged (w_uk applies to the normed latent).
+                    c_kv = _rms_norm(c_kv, layer["latent_norm"], cfg.norm_eps)
+                k_rope = _rope(k_rope_in[:, :, None, :],
+                               positions, cfg.rope_theta,
+                               cfg.rope_scaling)  # [b, s, 1, dr]
+                latent = jnp.concatenate(
+                    [c_kv[:, :, None, :], k_rope], axis=-1)  # [b, s, 1, r+dr]
+                # Absorb W_UK: q·(latent@W_UK) == (q@W_UK^T)·latent.
+                q_lat = jnp.einsum("bshd,hrd->bshr", q_nope, layer["w_uk"])
+                q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)
+                if cfg.latent_pad:
+                    # 128-lane alignment pad (see LlamaConfig.latent_pad):
+                    # zero key dims score zero against any query, so the
+                    # attention output only sees the pad through fp rounding
+                    # of the two-step scale factor (~1 ulp).
+                    pad = [(0, 0)] * 3 + [(0, cfg.latent_pad)]
+                    latent = jnp.pad(latent, pad)
+                    q_eff = jnp.pad(q_eff, pad)
+                # The attention backends scale by q.shape[-1]^-0.5 (the padded
+                # cache width); MLA's logical scale is the per-head q/k width
+                # (nope+rope), times the DeepSeek-yarn mscale^2 when set.
+                q_eff = q_eff * (
+                    q_eff.shape[-1] ** 0.5 / (cfg.head_dim + dr) ** 0.5
+                    * cfg.softmax_scale_mult)
+
+            # Values ARE the latent: pass the K pool as both K and V (the
+            # width-0 V pool is never read), then un-absorb W_UV.
+            extra = {}
+            if tails is not None:
+                tail_ks[g] = write_tail_layer(tail_ks[g], lj, latent)
             else:
-                if "w_dq" in layer:
-                    # DeepSeek q-LoRA: q is down-projected to a compressed
-                    # latent, RMS-normed, then up-projected per head — the
-                    # norm between the two matmuls prevents precomposition.
-                    q_in = _rms_norm(attn_in @ layer["w_dq"],
-                                     layer["q_latent_norm"], cfg.norm_eps)
+                k_caches[g] = write_layer(k_caches[g], lj, latent, table)
+            k_l, v_l = layer_of(k_caches[g], lj), layer_of(k_caches[g], lj)
+            if tails is not None:
+                extra = tail_kwargs(layer_of(tail_ks[g], lj),
+                                    layer_of(tail_ks[g], lj))
+            with jax.named_scope(SCOPE_ATTENTION):
+                ctx = attention_fn(
+                    q_eff, k_l, v_l, table, positions, total_lens, None,
+                    k_stack=k_caches[g], v_stack=k_caches[g], layer_idx=lj,
+                    **extra,
+                )
+                attn = jnp.einsum("bshr,hrv->bshv", ctx[..., :r],
+                                  layer["w_uv"])
+        else:
+            with jax.named_scope(SCOPE_QKV):
+                attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+                if "w_qkv" in layer:  # fused serving layout (fuse_params)
+                    qkv = attn_in @ layer["w_qkv"]
+                    if "b_qkv" in layer:
+                        qkv = qkv + layer["b_qkv"]
+                    nq = cfg.num_heads * cfg.head_dim
+                    nk = cfg.num_kv_heads * cfg.head_dim
+                    nv = qkv.shape[-1] - nq - nk
+                    q, k, v = split_fused_out(qkv, (nq, nk, nv),
+                                              cfg.fused_interleave)
                 else:
-                    q_in = attn_in
-                q = q_in @ layer["wq"]
-                c_kv = attn_in @ layer["w_dkv"]  # [b, s, r]
-                k_rope_in = attn_in @ layer["w_kr"]
-            q = q.reshape(batch, seq, cfg.num_heads, cfg.head_dim + dr)
-            q_nope, q_rope = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
-            q_rope = _rope(q_rope, positions, cfg.rope_theta,
-                           cfg.rope_scaling)
-            if "latent_norm" in layer:
-                # DeepSeek kv_a_layernorm: the latent is RMS-normed before
-                # the up-projections — cached post-norm, so absorption is
-                # unchanged (w_uk applies to the normed latent).
-                c_kv = _rms_norm(c_kv, layer["latent_norm"], cfg.norm_eps)
-            k_rope = _rope(k_rope_in[:, :, None, :],
-                           positions, cfg.rope_theta,
-                           cfg.rope_scaling)  # [b, s, 1, dr]
-            latent = jnp.concatenate(
-                [c_kv[:, :, None, :], k_rope], axis=-1)  # [b, s, 1, r+dr]
-            # Absorb W_UK: q·(latent@W_UK) == (q@W_UK^T)·latent.
-            q_lat = jnp.einsum("bshd,hrd->bshr", q_nope, layer["w_uk"])
-            q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)
-            if cfg.latent_pad:
-                # 128-lane alignment pad (see LlamaConfig.latent_pad):
-                # zero key dims score zero against any query, so the
-                # attention output only sees the pad through fp rounding
-                # of the two-step scale factor (~1 ulp).
-                pad = [(0, 0)] * 3 + [(0, cfg.latent_pad)]
-                latent = jnp.pad(latent, pad)
-                q_eff = jnp.pad(q_eff, pad)
-            # The attention backends scale by q.shape[-1]^-0.5 (the padded
-            # cache width); MLA's logical scale is the per-head q/k width
-            # (nope+rope), times the DeepSeek-yarn mscale^2 when set.
-            q_eff = q_eff * (
-                q_eff.shape[-1] ** 0.5 / (cfg.head_dim + dr) ** 0.5
-                * cfg.softmax_scale_mult)
+                    q = attn_in @ layer["wq"]
+                    k = attn_in @ layer["wk"]
+                    v = attn_in @ layer["wv"]
+                    if "bq" in layer:  # Qwen2-lineage QKV projection biases
+                        q = q + layer["bq"]
+                        k = k + layer["bk"]
+                        v = v + layer["bv"]
+                q = q.reshape(batch, seq, cfg.num_heads, cfg.head_dim)
+                k = k.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
+                v = v.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
+                if cfg.qk_norm:  # Qwen3: per-head RMS over head_dim, pre-RoPE
+                    q = _rms_norm(q, layer["q_norm"], cfg.norm_eps)
+                    k = _rms_norm(k, layer["k_norm"], cfg.norm_eps)
+                q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+                k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
+            extra = {}
             if tails is not None:
-                tail_ks[g] = tail_ks[g].at[lj].set(
-                    write_tail(tail_ks[g][lj], latent))
-                ctx = attention_fn(
-                    q_eff, k_caches[g][lj], k_caches[g][lj], table,
-                    positions, total_lens, None,
-                    k_stack=k_caches[g], v_stack=k_caches[g], layer_idx=lj,
-                    **tail_kwargs(tail_ks[g][lj], tail_ks[g][lj]),
-                )
+                tail_ks[g] = write_tail_layer(tail_ks[g], lj, k)
+                tail_vs[g] = write_tail_layer(tail_vs[g], lj, v)
             else:
-                k_caches[g] = k_caches[g].at[lj].set(
-                    _scatter(k_caches[g][lj], latent, table)
-                )
-                # Values ARE the latent: pass the K pool as both K and V
-                # (the width-0 V pool is never read), then un-absorb W_UV.
-                ctx = attention_fn(
-                    q_eff, k_caches[g][lj], k_caches[g][lj], table,
-                    positions, total_lens, None,
-                    k_stack=k_caches[g], v_stack=k_caches[g], layer_idx=lj,
-                )
-            attn = jnp.einsum("bshr,hrv->bshv", ctx[..., :r], layer["w_uv"])
-        else:
-            if "w_qkv" in layer:  # fused serving layout (fuse_params)
-                qkv = attn_in @ layer["w_qkv"]
-                if "b_qkv" in layer:
-                    qkv = qkv + layer["b_qkv"]
-                nq = cfg.num_heads * cfg.head_dim
-                nk = cfg.num_kv_heads * cfg.head_dim
-                nv = qkv.shape[-1] - nq - nk
-                q, k, v = split_fused_out(qkv, (nq, nk, nv),
-                                          cfg.fused_interleave)
-            else:
-                q = attn_in @ layer["wq"]
-                k = attn_in @ layer["wk"]
-                v = attn_in @ layer["wv"]
-                if "bq" in layer:  # Qwen2-lineage QKV projection biases
-                    q = q + layer["bq"]
-                    k = k + layer["bk"]
-                    v = v + layer["bv"]
-            q = q.reshape(batch, seq, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:  # Qwen3: per-head RMS over head_dim, pre-RoPE
-                q = _rms_norm(q, layer["q_norm"], cfg.norm_eps)
-                k = _rms_norm(k, layer["k_norm"], cfg.norm_eps)
-            q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-            k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-
+                k_caches[g] = write_layer(k_caches[g], lj, k, table)
+                v_caches[g] = write_layer(v_caches[g], lj, v, table)
+            k_l, v_l = layer_of(k_caches[g], lj), layer_of(v_caches[g], lj)
             if tails is not None:
-                tail_ks[g] = tail_ks[g].at[lj].set(
-                    write_tail(tail_ks[g][lj], k))
-                tail_vs[g] = tail_vs[g].at[lj].set(
-                    write_tail(tail_vs[g][lj], v))
+                extra = tail_kwargs(layer_of(tail_ks[g], lj),
+                                    layer_of(tail_vs[g], lj))
+            with jax.named_scope(SCOPE_ATTENTION):
                 attn = attention_fn(
-                    q, k_caches[g][lj], v_caches[g][lj], table, positions,
-                    total_lens, cfg.layer_window(li),
+                    q, k_l, v_l, table, positions, total_lens,
+                    cfg.layer_window(li),
                     k_stack=k_caches[g], v_stack=v_caches[g], layer_idx=lj,
-                    **tail_kwargs(tail_ks[g][lj], tail_vs[g][lj]),
+                    **extra,
                 )
+        with jax.named_scope(SCOPE_ATTENTION):
+            x = x + attn.reshape(batch, seq, -1) @ layer["wo"]
+
+        with jax.named_scope(SCOPE_MLP):
+            mlp_in = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            x = x + _mlp(mlp_in, layer, cfg, valid=valid)
+
+    with jax.named_scope(SCOPE_LM_HEAD):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if last_only:
+            if ragged is not None:
+                # One logit row per ragged row: its final flat token
+                # (row_starts[r+1] - 1; empty rows clamp to slot 0 and the
+                # caller ignores them).
+                idx = jnp.maximum(ragged[1:] - 1, 0)[None, :]  # [1, rows]
+                x = jnp.take_along_axis(x, idx[:, :, None], axis=1)
             else:
-                k_caches[g] = k_caches[g].at[lj].set(
-                    _scatter(k_caches[g][lj], k, table)
-                )
-                v_caches[g] = v_caches[g].at[lj].set(
-                    _scatter(v_caches[g][lj], v, table)
-                )
-
-                attn = attention_fn(
-                    q, k_caches[g][lj], v_caches[g][lj], table, positions,
-                    total_lens, cfg.layer_window(li),
-                    k_stack=k_caches[g], v_stack=v_caches[g], layer_idx=lj,
-                )
-        x = x + attn.reshape(batch, seq, -1) @ layer["wo"]
-
-        mlp_in = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(mlp_in, layer, cfg, valid=valid)
-
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if last_only:
-        if ragged is not None:
-            # One logit row per ragged row: its final flat token
-            # (row_starts[r+1] - 1; empty rows clamp to slot 0 and the
-            # caller ignores them).
-            idx = jnp.maximum(ragged[1:] - 1, 0)[None, :]  # [1, rows]
-            x = jnp.take_along_axis(x, idx[:, :, None], axis=1)
-        else:
-            idx = jnp.maximum(new_lens - 1, 0)  # [b]
-            x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+                idx = jnp.maximum(new_lens - 1, 0)  # [b]
+                x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     if tails is not None:
         return logits, tuple(tail_ks), tuple(tail_vs)
     return logits, tuple(k_caches), tuple(v_caches)
@@ -1498,8 +1536,9 @@ def _decode_steps_scan(params, cfg, last_tokens, k_caches, v_caches, tables,
             params, cfg, toks[:, None], k_caches, v_caches, tables, ctx,
             live, attention, tails=(tks, tvs, ctx_lens),
         )
-        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        nxt = jnp.where(live > 0, nxt, toks)
+        with jax.named_scope(SCOPE_SAMPLE):
+            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            nxt = jnp.where(live > 0, nxt, toks)
         return (nxt, tks, tvs, ctx + live), nxt
 
     (_t, tail_ks, tail_vs, _c), toks = jax.lax.scan(
@@ -1514,14 +1553,16 @@ def _decode_steps_scan(params, cfg, last_tokens, k_caches, v_caches, tables,
     tvalid = jnp.arange(steps)[None, :] < jnp.minimum(active, steps)[:, None]
     k_caches = list(k_caches)
     v_caches = list(v_caches)
-    for g in range(len(k_caches)):
-        for lj in range(k_caches[g].shape[0]):
-            k_caches[g] = k_caches[g].at[lj].set(scatter_kv_pages(
-                k_caches[g][lj], tail_ks[g][lj], tables[g], tpos, tvalid))
-            if v_caches[g].shape[-1]:  # MLA's width-0 V pool has no data
-                v_caches[g] = v_caches[g].at[lj].set(scatter_kv_pages(
-                    v_caches[g][lj], tail_vs[g][lj], tables[g], tpos,
+    with jax.named_scope(SCOPE_KV_WRITE):
+        for g in range(len(k_caches)):
+            for lj in range(k_caches[g].shape[0]):
+                k_caches[g] = k_caches[g].at[lj].set(scatter_kv_pages(
+                    k_caches[g][lj], tail_ks[g][lj], tables[g], tpos,
                     tvalid))
+                if v_caches[g].shape[-1]:  # MLA's width-0 V pool: no data
+                    v_caches[g] = v_caches[g].at[lj].set(scatter_kv_pages(
+                        v_caches[g][lj], tail_vs[g][lj], tables[g], tpos,
+                        tvalid))
     return toks.T, tuple(k_caches), tuple(v_caches)  # toks [batch, steps]
 
 

@@ -37,6 +37,18 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
+# The kernels' names as a device trace has them: each kernel's custom call
+# is named after the jitted wrapper below that makes it
+# (``pallas_paged_decode_attention.<n>``, one ``<n>`` per call site), so
+# these are the wrappers' ``__name__``. Readers of traces match them;
+# tests/test_model.py pins both sides. ``pallas_call(name=...)`` is left
+# unset on purpose: it names the Mosaic kernel inside the custom call, and
+# changing that changes the compiled program and its compile-cache key.
+KERNEL_DECODE = "pallas_paged_decode_attention"
+KERNEL_PREFILL = "pallas_paged_prefill_attention"
+KERNEL_RAGGED = "pallas_paged_ragged_attention"
+
+
 def head_dim_supported(head_dim: int) -> bool:
     """Whether these kernels can compile on real TPU for this head size.
 

@@ -24,6 +24,16 @@ Three operating modes, resolved per ``span()`` call in priority order:
 W3C trace-context helpers (:func:`current_traceparent`,
 :func:`parse_traceparent`) are the single source of truth for propagation
 across the gRPC tokenizer hop and the ZMQ event wire.
+
+Engine **phases** (:func:`phase`, :class:`EnginePhases`) are the second
+half of the facade: what ``MiniEngine`` does inside ``enqueue()`` and
+``step()``, cut into named pieces. They are off (the shared no-op, one
+identity check) unless the engine was built with ``EngineConfig.telemetry``;
+on, each phase is a ``jax.profiler.TraceAnnotation``, so a profiler capture
+(``/debug/profile``) shows it on the same clock as the device ops. A phase
+given a request's ``traceparent`` also opens the request's span
+(``engine.admission``, ``engine.prefill_chunk``), whether or not phases are
+on.
 """
 
 from __future__ import annotations
@@ -567,6 +577,46 @@ def current_traceparent() -> Optional[str]:
     return None
 
 
+@contextlib.contextmanager
+def _ambient_parent(parsed: tuple[int, int, int]) -> Iterator[None]:
+    trace_id, span_id, flags = parsed
+    if _recording_exporter is not None:
+        # A stand-in for the remote span: never exported, only read for
+        # its ids by the spans opened inside.
+        token = _CURRENT_SPAN.set(RecordedSpan("", trace_id, span_id, None))
+        try:
+            yield
+        finally:
+            _CURRENT_SPAN.reset(token)
+        return
+    from opentelemetry import context as otel_context
+
+    remote = _otel_trace.SpanContext(
+        trace_id=trace_id, span_id=span_id, is_remote=True,
+        trace_flags=_otel_trace.TraceFlags(flags))
+    token = otel_context.attach(_otel_trace.set_span_in_context(
+        _otel_trace.NonRecordingSpan(remote)))
+    try:
+        yield
+    finally:
+        otel_context.detach(token)
+
+
+def remote_parent(traceparent: Optional[str]):
+    """Context manager making ``traceparent`` the ambient parent of the
+    spans opened inside, without opening one itself: deferred work (the
+    ingest coalescer's flush) joins the span that caused it after that
+    span has ended. The shared no-op for None, a malformed value, or no
+    tracing configured."""
+    if traceparent is None:
+        return _NOOP_CM
+    parsed = parse_traceparent(traceparent)
+    if parsed is None or (_recording_exporter is None
+                          and tracer()._otel_tracer is None):
+        return _NOOP_CM
+    return _ambient_parent(parsed)
+
+
 def install_span_exporter(
     exporter: Optional[InMemorySpanExporter] = None,
 ) -> InMemorySpanExporter:
@@ -640,3 +690,151 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
     _otel_trace.set_tracer_provider(provider)
     _tracer = None  # rebuild against the new provider
     return True
+
+
+# -- engine phases -----------------------------------------------------------
+
+# Every phase the engine opens, in one place. ``enqueue.*`` run inside
+# ``MiniEngine.enqueue``; ``step.*`` inside ``MiniEngine.step`` in this
+# order (inputs → dispatch → sample → fetch once per program whose result
+# the host reads; commit and emit when a prefill finished or blocks were
+# evicted).
+PHASE_ENQUEUE_ADMIT = "enqueue.admit"      # all of admission (nests the two below)
+PHASE_ENQUEUE_HASH = "enqueue.hash"        # tokens → block hashes
+PHASE_ENQUEUE_LOOKUP = "enqueue.lookup"    # prefix probe, page allocation, eviction
+PHASE_STEP_OFFLOAD_POLL = "step.offload_poll"
+PHASE_STEP_SCHEDULE = "step.schedule"      # the pick; restore and handoff gates
+PHASE_STEP_INPUTS = "step.inputs"          # the program's arguments built in numpy
+PHASE_STEP_DISPATCH = "step.dispatch"      # their _to_dev transfers + the jitted call returning
+PHASE_STEP_SAMPLE = "step.sample"          # slice / argmax programs outside the jit
+PHASE_STEP_FETCH = "step.fetch"            # the blocking np.asarray of tokens or logits
+PHASE_STEP_COMMIT = "step.commit"          # _commit_full_blocks → commit_blocks, write-through
+PHASE_STEP_EMIT = "step.emit"              # event batch → sink → Pool/index (nests in commit)
+PHASE_STEP_FINISH = "step.finish"          # release of finished requests; carries the step's counters
+
+PHASE_NAMES = (
+    PHASE_ENQUEUE_ADMIT, PHASE_ENQUEUE_HASH, PHASE_ENQUEUE_LOOKUP,
+    PHASE_STEP_OFFLOAD_POLL, PHASE_STEP_SCHEDULE, PHASE_STEP_INPUTS,
+    PHASE_STEP_DISPATCH, PHASE_STEP_SAMPLE, PHASE_STEP_FETCH,
+    PHASE_STEP_COMMIT, PHASE_STEP_EMIT, PHASE_STEP_FINISH,
+)
+
+SPAN_ENGINE_ADMISSION = "llm_d.kv_cache.engine.admission"
+SPAN_ENGINE_PREFILL_CHUNK = "llm_d.kv_cache.engine.prefill_chunk"
+SPAN_ENGINE_DECODE_STEP = "llm_d.kv_cache.engine.decode_step"
+
+# The request span a phase opens when it is given a ``traceparent``.
+_SPAN_OF_PHASE = {
+    PHASE_ENQUEUE_ADMIT: SPAN_ENGINE_ADMISSION,
+    PHASE_STEP_DISPATCH: SPAN_ENGINE_PREFILL_CHUNK,
+}
+
+# What a phase yields when nothing listens: ``if sp is not NOOP_SPAN``
+# guards attributes that cost something to build.
+NOOP_SPAN = _NOOP_SPAN
+
+
+class EnginePhases:
+    """What one engine's phases share: whose they are, the ordinal of the
+    ``step()`` they run in (0 before the first), and what that step has
+    dispatched and moved to the device so far (``programs``: jitted calls,
+    counted by the phases given ``programs=``; ``transfers``/``bytes``:
+    counted by the engine's ``_to_dev``). Every phase carries ``pod`` and
+    ``step``; ``step.finish`` carries the step's totals. Touched by the
+    one thread that owns the engine.
+    """
+
+    __slots__ = ("pod", "step", "programs", "transfers", "bytes",
+                 "_annotation")
+
+    def __init__(self, pod: str):
+        from jax.profiler import TraceAnnotation
+
+        self.pod = pod
+        self.step = 0
+        self.programs = self.transfers = self.bytes = 0
+        self._annotation = TraceAnnotation
+
+    def begin_step(self) -> None:
+        self.step += 1
+        self.programs = self.transfers = self.bytes = 0
+
+
+class _Phase:
+    """An open phase: a TraceAnnotation (+ the request's span)."""
+
+    __slots__ = ("_phases", "_name", "_attrs", "_ann", "_span_cm", "_span",
+                 "_moved")
+
+    def __init__(self, phases: EnginePhases, name: str, span_cm, attrs: dict):
+        self._phases, self._name, self._attrs = phases, name, attrs
+        self._span_cm, self._span = span_cm, None
+
+    def __enter__(self) -> "_Phase":
+        phases = self._phases
+        self._moved = (phases.transfers, phases.bytes)
+        if self._span_cm is not None:
+            self._span = self._span_cm.__enter__()
+        self._ann = phases._annotation(
+            self._name, pod=phases.pod, step=phases.step, **self._attrs)
+        self._ann.__enter__()
+        return self
+
+    def set_attribute(self, key: str, value) -> "_Phase":
+        """A size known only inside the phase (blocks committed, …)."""
+        self._ann.set_metadata(**{key: value})
+        if self._span is not None:
+            self._span.set_attribute(key, value)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        phases = self._phases
+        transfers = phases.transfers - self._moved[0]
+        if transfers:
+            self._ann.set_metadata(
+                transfers=transfers, bytes=phases.bytes - self._moved[1])
+        self._ann.__exit__(*exc)
+        if self._span_cm is not None:
+            self._span_cm.__exit__(*exc)
+        return False
+
+
+def phase(phases: Optional[EnginePhases], name: str,
+          traceparent: Optional[str] = None, programs: int = 0, **attrs):
+    """Context manager around one engine phase.
+
+    ``phases`` is the engine's :class:`EnginePhases`, or None when the
+    engine was built without ``EngineConfig.telemetry``: then this is the
+    shared no-op (nothing is built, no clock is read) — unless the request
+    carries a ``traceparent`` and the phase has a request span, which is
+    opened as before (itself the no-op without an exporter or provider).
+    On, the phase is a ``jax.profiler.TraceAnnotation`` named ``name``
+    with ``pod``, ``step`` and ``attrs``: it costs a few hundred
+    nanoseconds while no profiler captures and lands on the capture's host
+    plane, on the device ops' clock, while one does. ``programs`` is the
+    number of jitted calls made inside. What is yielded takes
+    ``set_attribute(key, value)`` either way.
+
+    A call site on a hot path passes ``attrs`` only behind its own check
+    of ``phases`` (or sets them inside, through ``set_attribute``), so that
+    off it evaluates and builds nothing.
+    """
+    span_cm = None
+    if traceparent is not None and name in _SPAN_OF_PHASE:
+        span_cm = tracer().span(_SPAN_OF_PHASE[name],
+                                parent_traceparent=traceparent, **attrs)
+    if phases is None:
+        return _NOOP_CM if span_cm is None else span_cm
+    if programs:
+        phases.programs += programs
+        attrs["programs"] = programs
+    if span_cm is not None:
+        attrs["traceparent"] = traceparent
+    return _Phase(phases, name, span_cm, attrs)
+
+
+def span_event(name: str, traceparent: str, **attrs) -> None:
+    """An event-style span under ``traceparent``: opened and closed at
+    once, it marks a point (a decode step's emission) in a request's trace."""
+    with tracer().span(name, parent_traceparent=traceparent, **attrs):
+        pass

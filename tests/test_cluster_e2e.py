@@ -34,6 +34,23 @@ GRPC_PORT = 15911
 ADMIN_PORT = 15912
 
 
+@pytest.fixture(autouse=True)
+def _no_sli_history():
+    """The collectors below scrape this process's own metric registry, and
+    a target's first scrape counts its whole history as one round: slow
+    restores, TTFTs or scores left in the three SLI families by a test file
+    that ran earlier in the same worker fire an alert in a fleet these
+    tests assert is healthy (``kvdiag --fleet`` then exits 3). Each test
+    starts from empty families: the labelled one is cleared, the two
+    get-or-create histograms are forgotten (the services and engines a test
+    builds create them anew)."""
+    from llmd_kv_cache_tpu.metrics import collector
+
+    collector.OFFLOAD_RESTORE_SECONDS.clear()
+    collector.forget_bucket_histograms("kvtpu_engine_ttft_seconds",
+                                       "kvcache_score_latency_seconds")
+
+
 def wait_until(cond, timeout=60.0, interval=0.1):
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:

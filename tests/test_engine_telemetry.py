@@ -80,6 +80,23 @@ class TestBucketHistogram:
         assert 'kvtpu_engine_test_seconds_bucket{le="0.1"}' in text
         assert "kvtpu_engine_test_seconds_count" in text
 
+    def test_forgotten_family_starts_empty(self):
+        from prometheus_client import generate_latest
+
+        name = "kvtpu_engine_forget_test_seconds"
+        old = collector.bucket_histogram(name, "doc", (0.1, 1.0))
+        old.observe(0.05)
+        kept = collector.bucket_histogram("kvtpu_engine_kept_test_seconds",
+                                          "doc", (1.0,))
+        collector.forget_bucket_histograms(name)
+        assert name + "_count" not in generate_latest().decode()
+        new = collector.bucket_histogram(name, "doc", (0.1, 1.0))
+        assert new is not old and new.count == 0
+        old.observe(0.05)      # a holder of the old one: unexported
+        assert f"{name}_count 0.0" in generate_latest().decode()
+        assert collector.bucket_histogram(
+            "kvtpu_engine_kept_test_seconds", "doc", (1.0,)) is kept
+
 
 class TestRequestLifecycle:
     @pytest.fixture(scope="class")

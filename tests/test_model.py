@@ -142,3 +142,73 @@ class TestForward:
         np.testing.assert_allclose(
             np.asarray(logits_a[0, 3]), np.asarray(logits_b[0, 3]), rtol=1e-5
         )
+
+
+class TestTraceNames:
+    """What a device trace calls the step programs, the kernels and the
+    regions inside them: constants in the program (the benchmark's readers
+    are held to them in ``kvbench/tests/test_trace_names.py``)."""
+
+    def test_program_and_kernel_names_are_the_constants(self):
+        from llmd_kv_cache_tpu.models import llama
+        from llmd_kv_cache_tpu.ops import pallas_paged_attention as ppa
+
+        assert llama.forward_prefill_pallas.__name__ == llama.PROGRAM_PREFILL
+        assert llama.forward_decode_pallas.__name__ == llama.PROGRAM_DECODE
+        assert ppa.pallas_paged_decode_attention.__name__ == ppa.KERNEL_DECODE
+        assert ppa.pallas_paged_prefill_attention.__name__ == ppa.KERNEL_PREFILL
+        assert ppa.pallas_paged_ragged_attention.__name__ == ppa.KERNEL_RAGGED
+        # Today's strings: the readers that exist read what they read.
+        assert (llama.PROGRAM_PREFILL, llama.PROGRAM_DECODE) == (
+            "forward_prefill_pallas", "forward_decode_pallas")
+        assert (ppa.KERNEL_DECODE, ppa.KERNEL_PREFILL) == (
+            "pallas_paged_decode_attention", "pallas_paged_prefill_attention")
+
+    def _lowered(self, cfg, scoped):
+        """Compiled HLO of one decode-shaped ``forward`` step, and the
+        set of scopes its op names carry."""
+        import contextlib
+        import re
+        from unittest import mock
+
+        from llmd_kv_cache_tpu.models import llama
+
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        k, v = init_kv_cache(cfg, 8)
+        args = (params, cfg, jnp.zeros((2, 1), jnp.int32), k, v,
+                jnp.zeros((2, 4), jnp.int32), jnp.array([3, 5], jnp.int32),
+                jnp.ones((2,), jnp.int32))
+        # A fresh function under a fresh jit: the patched trace must not
+        # be served from (or left in) forward's own cache.
+        def step(*args):
+            return llama.forward.__wrapped__(*args)
+
+        fn = jax.jit(step, static_argnums=(1,), donate_argnums=(3, 4))
+        patch = (contextlib.nullcontext() if scoped else mock.patch.object(
+            jax, "named_scope", lambda name: contextlib.nullcontext()))
+        with patch:
+            text = fn.lower(*args).compile().as_text()
+        found = {s for s in llama.SCOPES
+                 if re.search(rf'op_name="[^"]*/{s}/', text)}
+        # Without what only describes the source: each op's metadata and
+        # the module's tables of files, functions and stack frames.
+        bare = re.sub(r", metadata=\{[^}]*\}", "", text)
+        tables = ("FileNames", "FunctionNames", "FileLocations",
+                  "StackFrames")
+        bare = "\n\n".join(part for part in bare.split("\n\n")
+                           if not part.lstrip().startswith(tables))
+        return bare, found
+
+    def test_scopes_are_metadata_only(self):
+        from llmd_kv_cache_tpu.models import llama
+
+        cfg = LlamaConfig.tiny()
+        with_scopes, found = self._lowered(cfg, scoped=True)
+        without, none = self._lowered(cfg, scoped=False)
+        assert none == set()
+        assert found == set(llama.SCOPES) - {llama.SCOPE_SAMPLE}
+        # The same instructions under the same names: a scope is a path
+        # in op_name and nothing else, so fusion names, the readers keyed
+        # on them and the compile-cache key (which leaves metadata out)
+        # cannot move.
+        assert with_scopes == without

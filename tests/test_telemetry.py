@@ -182,7 +182,7 @@ class TestFlightRecorder:
         # be whole (the ring stores immutable tuples, never torn state).
         for _ in range(50):
             for r in rec.snapshot():
-                assert set(r) == {"seq", "ts", "kind", "data"}
+                assert set(r) == {"seq", "ts", "mono", "kind", "data"}
                 assert r["kind"] == "ingest"
         for t in threads:
             t.join()
@@ -522,3 +522,275 @@ class TestEndToEndTrace:
                 s.span_id for s in exporter.find("llm_d.kv_cache.events.ingest")
             }
             assert adds[0].parent_span_id in ingest_ids
+
+
+# -- engine phases (telemetry.tracing.phase / EnginePhases) ------------------
+
+from llmd_kv_cache_tpu.telemetry import tracing  # noqa: E402
+
+
+def _phase_engine(telemetry=True, ragged=False, sink=None, **over):
+    from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
+    from llmd_kv_cache_tpu.models.llama import LlamaConfig
+    from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
+        EngineTelemetryConfig,
+    )
+
+    tiny = LlamaConfig.tiny()
+    cfg = dict(model=tiny, num_pages=64, max_pages_per_seq=16, max_batch=4,
+               max_prefill_tokens=2 * tiny.page_size,
+               pod_identifier="pod-x", ragged_attention=ragged,
+               telemetry=EngineTelemetryConfig() if telemetry else None)
+    cfg.update(over)
+    return MiniEngine(EngineConfig(**cfg), event_sink=sink), tiny
+
+
+def _drain(eng, limit=200):
+    steps = 0
+    while eng.requests and steps < limit:
+        eng.step()
+        steps += 1
+    assert not eng.requests
+    return steps
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation`` on an
+    ``EnginePhases``: keeps every phase it was opened for, in the order of
+    their starts, as ``[name, attrs, open?]``."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **attrs):
+        log = self.seen
+
+        class _Ann:
+            def __enter__(self):
+                self.rec = [name, dict(attrs), True]
+                log.append(self.rec)
+
+            def set_metadata(self, **kw):
+                self.rec[1].update(kw)
+
+            def __exit__(self, *exc):
+                self.rec[2] = False
+
+        return _Ann()
+
+
+def _recorded(phases):
+    phases._annotation = ann = _Annotations()
+    return ann.seen
+
+
+class TestPhaseFacade:
+    def test_off_is_the_shared_noop_and_reads_no_clock(self, monkeypatch):
+        assert tracing.phase(None, tracing.PHASE_STEP_INPUTS) is tracing._NOOP_CM
+        assert tracing.phase(None, tracing.PHASE_STEP_SAMPLE,
+                             programs=2) is tracing._NOOP_CM
+        with tracing.phase(None, tracing.PHASE_STEP_COMMIT) as sp:
+            assert sp is tracing.NOOP_SPAN
+            sp.set_attribute("blocks", 3)      # takes it, keeps nothing
+        # A phase without a request span stays the no-op even with a
+        # traceparent; one with a span opens only that span (itself the
+        # no-op here: no exporter, no provider).
+        tp = format_traceparent(7, 9)
+        assert tracing.phase(None, tracing.PHASE_STEP_FETCH, tp) is tracing._NOOP_CM
+        assert tracing.phase(None, tracing.PHASE_STEP_DISPATCH, tp,
+                             request_id="r") is tracing._NOOP_CM
+
+        eng, tiny = _phase_engine(telemetry=False)
+        assert eng._phases is None and eng.block_manager.phases is None
+        reads = []
+        for clock in ("perf_counter_ns", "perf_counter", "time_ns"):
+            real = getattr(time, clock)
+            monkeypatch.setattr(
+                time, clock,
+                lambda real=real, clock=clock: reads.append(clock) or real())
+        opened = []
+        monkeypatch.setattr(tracing, "_Phase",
+                            lambda *a: opened.append(a) or tracing._NOOP_CM)
+        eng.enqueue("a", list(range(1, 3 * tiny.page_size)), max_new_tokens=3)
+        _drain(eng)
+        assert reads == [] and opened == []
+
+    def test_off_sites_build_nothing(self):
+        """The engine's own dispatch site: sizes go in positionally and,
+        off, nothing is made of them."""
+        eng, _ = _phase_engine(telemetry=False)
+        assert eng._dispatch_phase(None, 4, 4, 8) is tracing._NOOP_CM
+        import sys
+
+        eng._dispatch_phase(None, 4, 4, 8)      # warm whatever caches
+        before = sys.getallocatedblocks()
+        for _ in range(1000):
+            with eng._dispatch_phase(None, 4, 4, 8):
+                pass
+            with tracing.phase(None, tracing.PHASE_STEP_SAMPLE, programs=2):
+                pass
+        assert sys.getallocatedblocks() - before <= 2
+
+    def test_on_opens_nested_annotations_in_order_with_attrs(self):
+        phases = tracing.EnginePhases("pod-q")
+        seen = _recorded(phases)
+        phases.begin_step()
+        with tracing.phase(phases, tracing.PHASE_STEP_COMMIT,
+                           request_id="r") as sp:
+            with tracing.phase(phases, tracing.PHASE_STEP_EMIT, events=2):
+                phases.transfers += 1
+                phases.bytes += 64
+                assert [r[2] for r in seen] == [True, True]   # nested
+            sp.set_attribute("blocks", 5)
+        with tracing.phase(phases, tracing.PHASE_STEP_DISPATCH, programs=1):
+            pass
+        assert [r[0] for r in seen] == [
+            tracing.PHASE_STEP_COMMIT, tracing.PHASE_STEP_EMIT,
+            tracing.PHASE_STEP_DISPATCH]
+        assert not any(r[2] for r in seen)
+        commit, emit, dispatch = (r[1] for r in seen)
+        assert emit == {"events": 2, "pod": "pod-q", "step": 1,
+                        "transfers": 1, "bytes": 64}
+        assert commit["blocks"] == 5 and commit["request_id"] == "r"
+        assert commit["transfers"] == 1  # what moved inside it, nested too
+        assert dispatch == {"pod": "pod-q", "step": 1, "programs": 1}
+        assert phases.programs == 1
+        phases.begin_step()
+        assert (phases.step, phases.programs, phases.transfers,
+                phases.bytes) == (2, 0, 0, 0)
+
+    def test_exception_inside_a_phase_still_closes_it(self):
+        phases = tracing.EnginePhases("p")
+        seen = _recorded(phases)
+        with pytest.raises(RuntimeError):
+            with tracing.phase(phases, tracing.PHASE_STEP_FETCH):
+                raise RuntimeError("device lost")
+        assert seen == [[tracing.PHASE_STEP_FETCH,
+                         {"pod": "p", "step": 0}, False]]
+
+    def test_names_are_in_one_place(self):
+        listed = {v for k, v in vars(tracing).items()
+                  if k.startswith("PHASE_") and isinstance(v, str)}
+        assert listed == set(tracing.PHASE_NAMES)
+        assert len(set(tracing.PHASE_NAMES)) == len(tracing.PHASE_NAMES)
+        assert set(tracing._SPAN_OF_PHASE) <= listed
+
+
+class TestEnginePhases:
+    @pytest.mark.parametrize("telemetry", [False, True])
+    def test_traceparent_request_still_yields_engine_spans(self, telemetry):
+        with recording_tracing() as exporter:
+            with tracer().span("llm_d.kv_cache.request") as root:
+                tp = root.traceparent
+            eng, tiny = _phase_engine(telemetry=telemetry)
+            prompt = list(range(300, 300 + 3 * tiny.page_size))
+            eng.enqueue("r1", prompt, max_new_tokens=3, traceparent=tp)
+            eng.enqueue("r2", prompt[:9], max_new_tokens=2)  # untraced
+            _drain(eng)
+            adm = exporter.find("llm_d.kv_cache.engine.admission")
+            chunks = exporter.find("llm_d.kv_cache.engine.prefill_chunk")
+            decodes = exporter.find("llm_d.kv_cache.engine.decode_step")
+        assert len(adm) == 1 and adm[0].attributes["prefix_hit_blocks"] == 0
+        # 3 pages of prompt at 2 pages a chunk: two prefill chunks.
+        assert [c.attributes["prefill_pos"] for c in chunks] == [
+            0, 2 * tiny.page_size]
+        assert len(decodes) == 2      # 3 tokens: the first is the prefill's
+        for sp in adm + chunks + decodes:
+            assert sp.trace_id == root.trace_id
+            assert sp.parent_span_id == root.span_id
+            assert sp.attributes["request_id"] == "r1"
+            assert sp.attributes["process"] == "pod-x"
+
+    @pytest.mark.parametrize("ragged", [False, True])
+    def test_every_phase_once_per_program(self, ragged):
+        events = []
+        eng, tiny = _phase_engine(ragged=ragged, sink=events.extend)
+        seen = _recorded(eng._phases)
+        page = tiny.page_size
+        eng.enqueue("a", list(range(1, 1 + 3 * page)), max_new_tokens=4)
+        eng.enqueue("b", list(range(500, 500 + page + 3)), max_new_tokens=3)
+        steps = _drain(eng)
+        by_step = {}
+        for name, attrs, still_open in seen:
+            assert not still_open and attrs["pod"] == "pod-x"
+            by_step.setdefault(attrs["step"], []).append((name, attrs))
+        # Admission happened before the first step.
+        assert [n for n, _ in by_step.pop(0)] == 2 * [
+            tracing.PHASE_ENQUEUE_ADMIT, tracing.PHASE_ENQUEUE_HASH,
+            tracing.PHASE_ENQUEUE_LOOKUP]
+        assert sorted(by_step) == list(range(1, steps + 1))
+        names_seen = set()
+        for step, phases in by_step.items():
+            names = [n for n, _ in phases]
+            names_seen.update(names)
+            count = names.count
+            # Once per step, in this order around everything else.
+            assert names[:2] == [tracing.PHASE_STEP_OFFLOAD_POLL,
+                                 tracing.PHASE_STEP_SCHEDULE]
+            assert names[-1] == tracing.PHASE_STEP_FINISH
+            assert count(tracing.PHASE_STEP_FINISH) == 1
+            # Once per program dispatched; a program whose result the
+            # host reads is sampled and fetched once.
+            programs = count(tracing.PHASE_STEP_DISPATCH)
+            assert programs >= 1
+            assert count(tracing.PHASE_STEP_INPUTS) == programs
+            assert count(tracing.PHASE_STEP_FETCH) == count(
+                tracing.PHASE_STEP_SAMPLE) <= programs
+            finish = phases[-1][1]
+            assert finish["programs"] == sum(
+                a.get("programs", 0) for _, a in phases[:-1])
+            # Every _to_dev rides a dispatch phase, on every path.
+            assert finish["transfers"] == sum(
+                a.get("transfers", 0) for n, a in phases
+                if n == tracing.PHASE_STEP_DISPATCH) > 0
+            assert not any("transfers" in a for n, a in phases
+                           if n == tracing.PHASE_STEP_INPUTS)
+            for n, a in phases:
+                if n == tracing.PHASE_STEP_DISPATCH:
+                    assert 0 < a["rows"] and 0 < a["tokens"] <= a["padded"]
+                    assert a["bytes"] > 0
+            # A commit emits the stored blocks' event batch inside it.
+            assert count(tracing.PHASE_STEP_EMIT) == count(
+                tracing.PHASE_STEP_COMMIT)
+            for at, n in enumerate(names):
+                if n == tracing.PHASE_STEP_EMIT:
+                    assert names[at - 1] == tracing.PHASE_STEP_COMMIT
+        assert names_seen == {n for n in tracing.PHASE_NAMES
+                              if n.startswith("step.")}
+        assert events                  # the sink did receive the batches
+        commits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_COMMIT]
+        assert sorted(c["blocks"] for c in commits) == [1, 3]
+        assert {c["request_id"] for c in commits} == {"a", "b"}
+        emits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_EMIT]
+        assert all(e["events"] >= 1 for e in emits)
+
+    def test_phases_land_in_a_profiler_capture(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        eng, tiny = _phase_engine()
+        eng.enqueue("warm", list(range(1, 2 * tiny.page_size)),
+                    max_new_tokens=2)
+        _drain(eng)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.enqueue("a", list(range(1, 2 * tiny.page_size)),
+                        max_new_tokens=2)
+            _drain(eng)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        found = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in tracing.PHASE_NAMES:
+                        found.setdefault(ev.name, dict(ev.stats))
+        assert {tracing.PHASE_STEP_INPUTS, tracing.PHASE_STEP_DISPATCH,
+                tracing.PHASE_STEP_FETCH, tracing.PHASE_STEP_FINISH} <= set(found)
+        assert found[tracing.PHASE_STEP_DISPATCH]["pod"] == "pod-x"
+        assert int(found[tracing.PHASE_STEP_DISPATCH]["transfers"]) > 0
