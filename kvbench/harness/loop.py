@@ -92,11 +92,19 @@ class Run:
     inflight_end: int = 0      # and when the window ended
     compiles_in_window: int = 0
     errors: list = field(default_factory=list)
+    # For run.py's "after the window" line. ``stages``: host seconds of
+    # what the run waited for once the window had ended (the joins).
+    # ``tracer``: what its thread did beside the window, in seconds:
+    # ``start_trace``, ``slice`` and what ``ended_by``, ``stop_trace`` and
+    # the part of it that lay past the window's end.
+    stages: dict = field(default_factory=dict)
+    tracer: dict = field(default_factory=dict)
     # Filled by run.py: the model, the data files, the reduced trace.
     cfg: object = None
     traffic: dict = field(default_factory=dict)
     setup_seconds: float = 0.0
     trace: object = None
+    trace_bytes: int = 0       # size of the .xplane.pb
     peaks: dict = field(default_factory=dict)
 
     def span(self, name: str, t0: float, t1: float) -> None:
@@ -347,14 +355,37 @@ def _generate_closed(records, router, fleet, replicas, run, stop,
         send_next(rec.arrival.client)
 
 
+def wait_for_slice(stop: threading.Event, seconds: float,
+                   steps: Optional[int], steps_done) -> str:
+    """Block until ``seconds`` have passed, ``steps_done()`` has grown by
+    ``steps``, or ``stop`` is set, and say which: ``"seconds"``,
+    ``"steps"`` or ``"stop"``. The count is polled every 50 ms: the slice
+    may run a few steps over, never short."""
+    if steps is None:
+        return "stop" if stop.wait(seconds) else "seconds"
+    until, first = now() + seconds, steps_done()
+    while True:
+        left = until - now()
+        if left <= 0:
+            return "seconds"
+        if steps_done() - first >= steps:
+            return "steps"
+        if stop.wait(min(0.05, left)):
+            return "stop"
+
+
 def serve(fleet: Fleet, schedule, traffic: dict, seconds: float,
           compile_count, at_fraction=None) -> Run:
     """Run one window of ``seconds`` over ``schedule.arrivals``.
 
     ``compile_count()`` reads the process's count of compiled programs.
-    ``at_fraction`` is ``(fraction, seconds, start, stop)``: the tracer's
-    two calls, made ``fraction`` into the window and ``seconds`` later from
-    a thread of their own, so that a slow ``stop`` stalls no request.
+    ``at_fraction`` is ``(fraction, seconds, steps, start, stop)``: the
+    tracer's two calls, made ``fraction`` into the window and, from a thread
+    of their own so that a slow ``stop`` stalls no request, ``seconds`` or
+    ``steps`` engine steps later, whichever comes first (``steps`` None:
+    by time alone). ``stop`` costs the profiler time for every device op
+    of the slice, so a slice bounded by time alone costs a faster engine
+    more; bounded by steps as well it costs what it costs today.
     """
     run = Run(seconds=seconds, loop=traffic["loop"], traffic=traffic)
     records = [RequestRecord(idx=i, arrival=a, prompt_len=len(a.prompt),
@@ -391,14 +422,28 @@ def serve(fleet: Fleet, schedule, traffic: dict, seconds: float,
 
     tracer = None
     if at_fraction is not None:
-        fraction, trace_s, start_trace, stop_trace = at_fraction
+        fraction, trace_s, trace_steps, start_trace, stop_trace = at_fraction
+
+        def steps_done() -> int:
+            return sum(len(r.steps) for r in replicas.values())
 
         def trace_slice() -> None:
             if stop.wait(max(0.0, run.t_start + fraction * seconds - now())):
                 return
+            t0 = now()
             start_trace()
-            stop.wait(trace_s)
+            t_on = now()
+            ended_by = wait_for_slice(stop, trace_s, trace_steps, steps_done)
+            t_off = now()
             stop_trace()
+            t1 = now()
+            # The stop is library code, on this thread so that it stalls no
+            # request; what of it lies past the window's end the run waits
+            # for (in its join of this thread).
+            run.tracer.update({
+                "start_trace": t_on - t0, "slice": t_off - t_on,
+                "ended_by": ended_by, "stop_trace": t1 - t_off,
+                "stop_trace.past_end": max(0.0, t1 - max(t_off, run.t_end))})
 
         tracer = threading.Thread(target=trace_slice, name="tracer",
                                   daemon=True)
@@ -416,7 +461,10 @@ def serve(fleet: Fleet, schedule, traffic: dict, seconds: float,
         r.stop.set()
         r.wake.set()
     for t in threads:
+        t0 = now()
         t.join(timeout=120.0)
+        key = "join.tracer" if t is tracer else "join.serving"
+        run.stages[key] = run.stages.get(key, 0.0) + now() - t0
         if t.is_alive():
             run.errors.append(f"thread {t.name} did not stop")
     fleet.on_ingest = None

@@ -120,14 +120,12 @@ def prepare(cell: dict, conf: dict, traffic: dict, schedule_fn, seed: int,
         # Outside the checkout (under TMPDIR) and removed at exit: a store
         # of several GiB left in the tree makes it too large to copy.
         ctx.store_root = Path(tempfile.mkdtemp(prefix="kvbench-store-"))
-    ctx.trace_dir = None
 
     def close() -> None:
         if getattr(ctx, "fleet", None) is not None:
             ctx.fleet.shutdown()
-        for d in (ctx.store_root, ctx.trace_dir):
-            if d is not None:
-                shutil.rmtree(d, ignore_errors=True)
+        if ctx.store_root is not None:
+            shutil.rmtree(ctx.store_root, ignore_errors=True)
 
     ctx.close = close
     try:

@@ -21,12 +21,12 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import atexit  # noqa: E402
+import contextlib  # noqa: E402
 import json  # noqa: E402
 import logging  # noqa: E402
 import os  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
-import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -41,6 +41,21 @@ from kvbench.harness.fleet import log, memory_peak_bytes  # noqa: E402
 # several cover it (inner before outer, a replica's before the generator's).
 SPANS = ["ingest", "enqueue", "route", "step", "restore.wait",
          "replica.idle", "generator.sleep"]
+
+# The check stops a run that is still going after CHECK_LIMIT_S; a run
+# that passes WARN_S says so on stderr (README, "The time budget").
+CHECK_LIMIT_S = 360.0
+WARN_S = 300.0
+
+
+@contextlib.contextmanager
+def stage(after: dict, name: str):
+    """Host seconds of one stage after the window, into ``after``."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        after[name] = after.get(name, 0.0) + time.perf_counter() - t0
 
 
 def parse_args(argv=None):
@@ -63,37 +78,44 @@ def parse_args(argv=None):
 
 
 def tracer_calls(ctx):
-    """(start, stop) for the traced slice: the profiler writes under
-    TMPDIR; host spans come from the harness's TraceAnnotations."""
-    import jax
+    """(start, stop) for the traced slice; ``stop`` leaves the serialized
+    XSpace in ``ctx.xspace``. Host spans come from the harness's
+    TraceAnnotations.
 
-    ctx.trace_dir = Path(tempfile.mkdtemp(prefix="kvbench-trace-"))
+    A session of the profiler itself, not ``jax.profiler.stop_trace``: that
+    also exports, which beside the ``.xplane.pb`` (these same bytes) writes
+    a ``trace.json.gz`` nothing here reads, at a cost that grows faster than
+    the trace (6 s of 34 at 0.29 M device ops, 84 s of 129 at 1.18 M; my
+    chip runs, PR 27). ``stop`` alone takes about 21 s + 21 us per op."""
+    import jax
+    from jax._src.lib import _profiler  # what jax.profiler itself wraps
+
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0   # no per-call Python events: they slow
     opts.host_tracer_level = 2     # the host; TraceMe spans stay
-    state = {}
+    ctx.xspace = b""
+    session = []
 
     def start():
-        jax.profiler.start_trace(str(ctx.trace_dir), profiler_options=opts)
-        state["on"] = True
+        session.append(_profiler.ProfilerSession(opts))
 
     def stop():
-        if state.pop("on", False):
-            jax.profiler.stop_trace()
+        ctx.xspace = session.pop().stop()
 
     return start, stop
 
 
-def measure(ctx, cell, traffic, seconds, traced, keep_trace=""):
-    """One window, and the run record the metric readers take."""
+def measure(ctx, cell, traffic, seconds, traced, after, keep_trace=""):
+    """One window, and the run record the metric readers take. ``after``
+    takes the seconds of every stage past the window's end."""
     from kvbench.harness import loop
     from kvbench.harness.prepare import programs_first_used
     from kvbench.trace import opcount, reduce as trace_reduce
 
     at = None
     if traced:
-        start, stop = tracer_calls(ctx)
-        at = (1.0 / 3.0, float(traffic["trace_seconds"]), start, stop)
+        at = (1.0 / 3.0, float(traffic["trace_seconds"]),
+              traffic.get("trace_steps"), *tracer_calls(ctx))
     setup_seconds = time.perf_counter() - ctx.t_process
     run = loop.serve(ctx.fleet, ctx.schedule, traffic, seconds,
                      lambda: programs_first_used(ctx.stats), at)
@@ -102,19 +124,21 @@ def measure(ctx, cell, traffic, seconds, traced, keep_trace=""):
     run.peaks = (opcount.peaks(ctx.device["kind"])
                  if ctx.device["platform"] == "tpu"
                  else opcount.rehearsal_peaks())
+    after.update(run.stages)
     if traced:
-        stop()
-        path = trace_reduce.find_xplane(str(ctx.trace_dir))
-        t0 = time.perf_counter()
-        planes = trace_reduce.load(path, SPANS)
-        run.trace = trace_reduce.reduce(planes, int(cell["chips"]), SPANS)
-        log(f"trace: {os.path.getsize(path) / 2**20:.1f} MiB reduced "
-                  f"in {time.perf_counter() - t0:.1f}s; window "
+        run.trace_bytes = len(ctx.xspace)
+        with stage(after, "load"):
+            planes = trace_reduce.load(ctx.xspace, SPANS)
+        with stage(after, "reduce"):
+            run.trace = trace_reduce.reduce(planes, int(cell["chips"]), SPANS)
+        log(f"trace: {run.trace_bytes / 2**20:.1f} MiB reduced "
+                  f"in {after['load'] + after['reduce']:.1f}s; window "
                   f"{run.trace.window_s:.3f}s busy {run.trace.busy_s:.3f}s "
                   f"on {run.trace.planes}; {len(run.trace.work)} steps")
         if keep_trace:
             os.makedirs(os.path.dirname(keep_trace) or ".", exist_ok=True)
-            shutil.copy(path, keep_trace)
+            Path(keep_trace).write_bytes(ctx.xspace)
+        ctx.xspace = b""   # the planes are what is read from here on
     return run
 
 
@@ -134,19 +158,22 @@ def correctness(ctx, run) -> list:
     return faults
 
 
-def breakdown(run) -> dict:
-    ops = sorted(run.trace.op_seconds().items(), key=lambda kv: -kv[1])
-    idle = sorted(run.trace.idle_by_span(SPANS).items(),
-                  key=lambda kv: -kv[1])
+def breakdown(run, after) -> dict:
+    with stage(after, "op_seconds"):
+        ops = sorted(run.trace.op_seconds().items(), key=lambda kv: -kv[1])
+    with stage(after, "idle_by_span"):
+        idle = sorted(run.trace.idle_by_span(SPANS).items(),
+                      key=lambda kv: -kv[1])
     return {"device_ops": [[n, s] for n, s in ops[:10]],
             "idle_gaps": [[n, s] for n, s in idle[:10]]}
 
 
-def report(run, readers, expected, toy=False) -> dict:
+def report(run, readers, expected, after, toy=False) -> dict:
     """name -> {value, unit} for every reader that found something."""
     out = {}
     for mod, entry in zip(readers, expected):
-        value = mod.compute(run)
+        with stage(after, f"reader.{mod.NAME}"):
+            value = mod.compute(run)
         if value is None and toy and mod.SOURCE == "device_trace":
             # The interpreter runs no kernel a trace could name: the toy
             # walk-through goes on with 0, a real run stops at the check.
@@ -160,8 +187,44 @@ def report(run, readers, expected, toy=False) -> dict:
     return out
 
 
+def after_the_window(run, after, traced) -> dict:
+    """What the run spent past its window's end, by stage, and the sizes of
+    the trace that drive it. ``unaccounted`` is what no stage timed."""
+    total = time.perf_counter() - run.t_end
+    out = {"total_s": round(total, 3),
+           "unaccounted_s": round(total - sum(after.values()), 3),
+           "stages_s": {name: round(s, 3) for name, s in after.items()}}
+    if traced:
+        from kvbench.trace import reduce as trace_reduce
+
+        tr = run.trace
+        # Beside the window, on the tracer's own thread: what of its stop
+        # lay past the window's end is the wait in ``join.tracer``.
+        out["tracer"] = {k: round(v, 3) if isinstance(v, float) else v
+                         for k, v in run.tracer.items()}
+        out["counts"] = {
+            "trace_bytes": run.trace_bytes,
+            "device_ops": sum(len(tr.ops[p]) for p in tr.planes),
+            "busy_intervals": sum(len(tr.busy[p]) for p in tr.planes),
+            "gaps": sum(len(trace_reduce.gaps(tr.busy[p], tr.window))
+                        for p in tr.planes),
+            "span_intervals": {n: len(ivs) for n, ivs in tr.spans.items()},
+            "step.work": len(tr.work)}
+    return out
+
+
+def say_exit(t_line: list) -> None:
+    if t_line:
+        print(f"[kvbench] exit: {time.perf_counter() - t_line[0]:.1f}s "
+              f"after the last line", file=sys.stderr, flush=True)
+
+
 def main(argv=None) -> int:
     args = parse_args(argv)
+    # Registered before JAX is imported, so it runs after JAX's own exit
+    # handlers: what the runtime's shutdown takes shows on stderr.
+    t_line: list = []
+    atexit.register(say_exit, t_line)
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
     toy = args.rehearse or args.toy
@@ -173,7 +236,6 @@ def main(argv=None) -> int:
     cell = names.workload(bench, args.workload)
     traced = bool(args.trace)
     expected = names.cell_metrics(bench, cell["name"], traced)
-    readers = [names.metric(m["name"]) for m in expected]
     both = (names.cell_metrics(bench, cell["name"], False)
             + names.cell_metrics(bench, cell["name"], True))
     all_readers = [names.metric(m["name"]) for m in both]
@@ -185,18 +247,22 @@ def main(argv=None) -> int:
 
     ctx = prepare(cell, conf, traffic, gen.schedule, args.seed, args.seconds,
                   toy, T_PROCESS)
+    after: dict = {}
     try:
-        run = measure(ctx, cell, traffic, args.seconds, traced,
+        run = measure(ctx, cell, traffic, args.seconds, traced, after,
                       args.keep_trace)
         peak = memory_peak_bytes(ctx.devices)
     finally:
-        ctx.close()
+        with stage(after, "close"):
+            ctx.close()
 
     log(f"window: {run.summary()}")
     # Every metric of the cell on an earlier line, whatever the mode, so
-    # that the cost of tracing can be read against the untraced runs.
+    # that the cost of tracing can be read against the untraced runs; the
+    # last line takes this mode's from the same readings.
+    everything = report(run, all_readers, both, after, toy)
     log("all metrics of this run (the last line holds this mode's): "
-              + json.dumps(report(run, all_readers, both, toy)))
+              + json.dumps(everything))
     faults = correctness(ctx, run)
     if faults:
         log(f"NOT correct: {faults}")
@@ -204,21 +270,37 @@ def main(argv=None) -> int:
     device = dict(ctx.device, memory_peak_bytes=peak)
     line = {"correct": not faults, "attempted": len(run.sampled()),
             "failed": len(run.failed()),
-            "metrics": report(run, readers, expected, toy),
+            "metrics": {m["name"]: everything[m["name"]] for m in expected
+                        if m["name"] in everything},
             "device": device}
     if traced:
         device["busy_s"] = run.trace.busy_s
         device["window_s"] = run.trace.window_s
-        line["breakdown"] = breakdown(run)
+        line["breakdown"] = breakdown(run, after)
+        with stage(after, "longest_gaps"):
+            longest = run.trace.longest_gaps()
         log(f"longest idle gaps (s, plane): "
-                  f"{[(round(s, 4), p) for s, p, _ in run.trace.longest_gaps()]}")
+                  f"{[(round(s, 4), p) for s, p, _ in longest]}")
     try:
-        text = check_line(line, expected, traced)
+        with stage(after, "check_line"):
+            text = check_line(line, expected, traced)
     except BadLine as exc:
         log(f"no result: the line would be refused: {exc}")
         return 1
+    summary = after_the_window(run, after, traced)
+    log("after the window: " + json.dumps(summary))
+    spent = time.perf_counter() - T_PROCESS
+    budget = (f"budget: set-up {run.setup_seconds:.1f}s + window "
+              f"{args.seconds:.1f}s + after the window "
+              f"{summary['total_s']:.1f}s = {spent:.1f}s of the check's "
+              f"{CHECK_LIMIT_S:.0f}s")
+    log(budget)
+    if spent > WARN_S:
+        print(f"[kvbench] WARNING {budget}: over {WARN_S:.0f}s",
+              file=sys.stderr, flush=True)
     sys.stdout.flush()
     print(text, flush=True)
+    t_line.append(time.perf_counter())
     return 0
 
 

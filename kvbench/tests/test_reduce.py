@@ -1,6 +1,7 @@
 """The trace reduction on synthetic planes (each rule of the issue's §7) and
 on a small trace recorded on the chip."""
 
+import random
 from pathlib import Path
 
 import pytest
@@ -146,6 +147,191 @@ def test_recorded_chip_trace():
     idle = red.idle_by_span(SPANS)
     assert sum(idle.values()) == pytest.approx(red.window_s - red.busy_s,
                                                rel=1e-6)
+
+
+# -- idle_by_span: the sweep against the version it replaced ----------------
+
+
+def idle_by_span_unswept(red, priority):
+    """``Reduced.idle_by_span`` as it stood until PR 27, verbatim: every gap
+    against every interval of every span name. The reference the sweep is
+    held to; its cost is gaps x intervals."""
+    out: dict = {}
+    for p in red.planes:
+        for gap in R.gaps(red.busy[p], red.window):
+            left = [gap]
+            for name in priority:
+                cover = red.spans.get(name, [])
+                if not cover:
+                    continue
+                rest = []
+                for piece in left:
+                    got = R.overlap(piece, cover)
+                    if got <= 0:
+                        rest.append(piece)
+                        continue
+                    out[name] = out.get(name, 0.0) + got
+                    rest.extend(R.gaps(R.union(cover, clip=piece), piece))
+                left = rest
+            if left:
+                out["(no span)"] = out.get("(no span)", 0.0) + R.total(left)
+    n = max(1, len(red.planes))
+    return {k: v * R.NS / n for k, v in out.items()}
+
+
+def same_idle(red):
+    new, old = red.idle_by_span(SPANS), idle_by_span_unswept(red, SPANS)
+    assert set(new) == set(old)
+    for name in old:
+        assert new[name] == pytest.approx(old[name], rel=0, abs=1e-9), name
+    return new, old
+
+
+def random_planes(rng):
+    """A few chips, each with ops in bursts; host threads whose spans nest
+    (``ingest`` and ``enqueue`` inside ``step``), stand side by side (two
+    replicas' steps overlap), end inside gaps and inside ops, share edges
+    with them, and leave some names of SPANS empty."""
+    horizon = rng.choice([200, 2_000, 50_000])
+    t0 = rng.choice([0, 1_700_000_000_000_000_000])  # a trace's own epoch
+    grid = rng.choice([1, 1, 7])      # 1: many shared edges; 7: fewer
+
+    def at(x):
+        return float(t0 + grid * int(x))
+
+    planes = []
+    for chip in range(rng.randint(1, 3)):
+        ops, t = [], rng.randint(0, 20)
+        while t < horizon:
+            dur = rng.randint(0, 12)
+            ops.append(ev(f"fusion.{len(ops)}", at(t), grid * dur))
+            t += rng.choice([dur, dur, rng.randint(0, 40)])  # abut or gap
+        planes.append(device(chip, ops))
+    names = [n for n in SPANS if rng.random() < 0.75]
+    threads = []
+    for _ in range(rng.randint(1, 3)):
+        evs, t = [], rng.randint(0, 30)
+        while t < horizon and names:
+            dur = rng.randint(1, 90)
+            outer = rng.choice(names)
+            evs.append(ev(outer, at(t), grid * dur))
+            for _ in range(rng.randint(0, 3)):       # nested, or straddling
+                a = t + rng.randint(0, dur)
+                evs.append(ev(rng.choice(names), at(a),
+                              grid * rng.randint(0, dur)))
+            t += rng.choice([dur, rng.randint(1, 150)])
+        threads.append(evs)
+    planes.append(R.Plane(R.HOST_PLANE, {f"python3#{i}": evs
+                                         for i, evs in enumerate(threads)}))
+    return planes, len(planes) - 1
+
+
+@pytest.mark.parametrize("block", range(8))
+def test_sweep_equals_the_unswept_on_random_planes(block):
+    """Forty seeded cases a block (320 in all): the same names in the same
+    order and every value within 1e-9 s; the sums go in the same order, so
+    they are in fact the same to the last bit."""
+    for seed in range(block * 40, block * 40 + 40):
+        rng = random.Random(seed)
+        planes, chips = random_planes(rng)
+        try:
+            red = R.reduce(planes, rng.randint(1, chips), SPANS)
+        except ValueError:      # nothing at all in the trace
+            continue
+        new, old = same_idle(red)
+        assert new == old, seed
+        assert sum(new.values()) == pytest.approx(
+            red.window_s - red.busy_s, rel=1e-9, abs=1e-15)
+
+
+def test_sweep_on_the_corners():
+    """A gap that one span covers exactly, spans that only touch a gap's
+    ends, a span over the whole window, a plane with no gap at all."""
+    ops = [ev("a", 0, 10), ev("b", 20, 10), ev("c", 50, 10)]
+    spans = host(ev("enqueue", 10, 10),             # exactly the first gap
+                 ev("route", 0, 10), ev("route", 30, 0), ev("route", 60, 5),
+                 ev("ingest", 28, 4),               # 2 busy, 2 idle
+                 ev("ingest", 48, 2),               # ends where an op starts
+                 ev("generator.sleep", 0, 65))
+    red = R.reduce([device(0, ops), spans], 1, SPANS)
+    new, _ = same_idle(red)
+    assert new == {"enqueue": pytest.approx(10e-9),
+                   "ingest": pytest.approx(4e-9),
+                   "route": pytest.approx(5e-9),
+                   "generator.sleep": pytest.approx(16e-9)}
+    full = R.reduce([device(0, [ev("a", 0, 100)]), host(ev("step", 0, 100))],
+                    1, SPANS)
+    assert full.idle_by_span(SPANS) == {} == idle_by_span_unswept(full, SPANS)
+
+
+class Counted(list):
+    """A list that counts every item handed out, by index (``bisect`` and
+    ``cover[i]``) or by iteration: one interval looked at is one count."""
+
+    looked = 0
+
+    def __getitem__(self, i):
+        Counted.looked += 1
+        return list.__getitem__(self, i)
+
+    def __iter__(self):
+        for x in list.__iter__(self):
+            Counted.looked += 1
+            yield x
+
+
+def slice_like(n_gaps, n_steps):
+    """A traced slice in the proportions of the chip's: ``n_gaps`` short
+    gaps between ops, ``n_steps`` steps of two replicas side by side that
+    cover nearly all of it, an ``enqueue`` in every fourth step, ``ingest``
+    inside those, a generator asleep over most of it."""
+    span = 1000.0 * n_gaps
+    busy = [(1000.0 * i, 1000.0 * i + 800.0) for i in range(n_gaps)]
+    step = span / n_steps
+    spans = {
+        "step": R.union((i * step + 10, (i + 1) * step - 10)
+                        for i in range(n_steps)),
+        "enqueue": R.union((i * step, i * step + 9) for i in range(0, n_steps, 4)),
+        "ingest": R.union((i * step + 2, i * step + 5)
+                          for i in range(0, n_steps, 4)),
+        "route": [], "restore.wait": [], "replica.idle": [],
+        "generator.sleep": R.union((i * 50 * step, (i * 50 + 49) * step)
+                                   for i in range(max(1, n_steps // 50))),
+    }
+    return R.Reduced(window=(0.0, span), planes=["/device:TPU:0"],
+                     busy={"/device:TPU:0": busy}, ops={}, modules={},
+                     spans=spans, work=[])
+
+
+def looked_at(red, fn):
+    red.spans = {n: Counted(ivs) for n, ivs in red.spans.items()}
+    Counted.looked = 0
+    fn(red)
+    return Counted.looked
+
+
+def test_sweep_work_grows_with_the_trace_not_its_square():
+    """Counted work, not the clock: intervals looked at. Twice the gaps and
+    twice the spans at most double it (and a logarithm's step); from PR 26's
+    parent slice (70k gaps, 110 steps) to its change's (300k, 460) it stays
+    within twice the growth of the trace. The unswept version quadruples,
+    which is what this test would say of the parent's code."""
+    def sweep(red):
+        return red.idle_by_span(SPANS)
+
+    def unswept(red):
+        return idle_by_span_unswept(red, SPANS)
+
+    small = looked_at(slice_like(2_000, 40), sweep)
+    double = looked_at(slice_like(4_000, 80), sweep)
+    assert double <= 2.0 * small * 1.25
+    assert (looked_at(slice_like(2_000, 80), unswept)
+            >= 3.5 * looked_at(slice_like(1_000, 40), unswept))
+    parent = looked_at(slice_like(70_000, 110), sweep)
+    change = looked_at(slice_like(300_000, 460), sweep)
+    assert change <= 2.0 * (300_000 / 70_000) * parent
+    # And not by doing less: the two agree where the unswept can be afforded.
+    same_idle(slice_like(3_000, 60))
 
 
 def test_op_names():

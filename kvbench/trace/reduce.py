@@ -67,15 +67,17 @@ def base_name(name: str) -> str:
     return head if dot and tail.isdigit() else name
 
 
-def load(path: str, span_names: Iterable[str]) -> list:
-    """Planes of a trace file, keeping what the reduction reads: every
+def load(source, span_names: Iterable[str]) -> list:
+    """Planes of a trace (the path of an ``.xplane.pb``, or the serialized
+    XSpace itself as bytes), keeping what the reduction reads: every
     event of a device plane's ops and modules lines, and of the host plane
     the harness's spans and (on a backend with no device plane, the CPU of
     the rehearsal) the events that carry an ``hlo_module``."""
     from jax.profiler import ProfileData
 
     keep = set(span_names) | {WORK_MARKER}
-    data = ProfileData.from_file(path)
+    data = (ProfileData.from_serialized_xspace(source)
+            if isinstance(source, bytes) else ProfileData.from_file(source))
     has_device = any(DEVICE_PLANE.match(pl.name) for pl in data.planes)
     planes = []
     for pl in data.planes:
@@ -156,6 +158,38 @@ def overlap(a: tuple, cover: list) -> float:
                if d > a[0] and c < a[1])
 
 
+def take(pieces: list, cover: list, acc: float = 0.0) -> tuple:
+    """``(acc + length of pieces inside cover, pieces outside it)``; both
+    lists sorted and disjoint, and so is the result. Each piece bisects to
+    the first interval that can reach it and walks on while they touch, so
+    an interval is looked at once per piece it touches and once more at
+    most: O(pieces * log(cover) + cover). ``acc`` grows piece by piece, a
+    piece's intervals summed first: the order that keeps a sum over many
+    pieces the same to the last bit however the pieces were found."""
+    rest = []
+    n = len(cover)
+    for a, b in pieces:
+        # The last interval that starts at or before ``a`` may reach into
+        # the piece; none before it can (they are disjoint).
+        i = bisect.bisect_right(cover, (a, float("inf"))) - 1
+        if i < 0 or cover[i][1] <= a:
+            i += 1
+        got, at = 0, a
+        while i < n:
+            c, d = cover[i]
+            if c >= b:
+                break
+            if c > at:
+                rest.append((at, c))
+            got += min(b, d) - max(a, c)
+            at = d
+            i += 1
+        acc += got
+        if b > at:
+            rest.append((at, b))
+    return acc, rest
+
+
 # -- the reduction -----------------------------------------------------------
 
 
@@ -196,26 +230,23 @@ class Reduced:
         """Idle seconds (mean over chips) by what the host was doing: each
         gap is split over the spans that covered it; where spans nest or
         run side by side the one earlier in ``priority`` takes the time,
-        and what no span covered is ``"(no span)"``."""
+        and what no span covered is ``"(no span)"``.
+
+        One sweep per name over the sorted pieces still unclaimed and the
+        name's sorted, disjoint intervals (:func:`take`): the work grows
+        with the gaps plus the intervals, not with their product."""
         out: dict = {}
         for p in self.planes:
-            for gap in gaps(self.busy[p], self.window):
-                left = [gap]
-                for name in priority:
-                    cover = self.spans.get(name, [])
-                    if not cover:
-                        continue
-                    rest = []
-                    for piece in left:
-                        got = overlap(piece, cover)
-                        if got <= 0:
-                            rest.append(piece)
-                            continue
-                        out[name] = out.get(name, 0.0) + got
-                        rest.extend(gaps(union(cover, clip=piece), piece))
-                    left = rest
-                if left:
-                    out["(no span)"] = out.get("(no span)", 0.0) + total(left)
+            left = gaps(self.busy[p], self.window)
+            for name in priority:
+                cover = self.spans.get(name, [])
+                if not cover or not left:
+                    continue
+                got, left = take(left, cover, out.get(name, 0.0))
+                if got:
+                    out[name] = got
+            if left:
+                out["(no span)"] = out.get("(no span)", 0.0) + total(left)
         n = max(1, len(self.planes))
         return {k: v * NS / n for k, v in out.items()}
 
