@@ -21,7 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..ops.kv_pages import scatter_kv_pages, scatter_kv_pages_ragged
+from ..ops.kv_pages import page_writes, write_kv_pages
 from ..ops.paged_attention import paged_attention
 
 Params = dict[str, Any]
@@ -29,11 +29,10 @@ Params = dict[str, Any]
 # ``jax.named_scope`` names inside every step program: metadata only (the
 # ``op_name`` of each HLO op, which a profiler trace carries as ``tf_op``),
 # so device time can be read by region of the model instead of by the
-# fusion names the compiler chose. Per layer: taking the layer's K/V out
-# of the stacked pool, the projections up to RoPE, writing new K/V into
-# the pool, attention with its output projection, the MLP. Once per
-# program: the embedding, the final norm + lm_head, in-program sampling.
-SCOPE_KV_LAYER = "kv_layer"
+# fusion names the compiler chose. Per layer: the projections up to RoPE,
+# writing new K/V into the pool, attention with its output projection,
+# the MLP. Once per program: the embedding, the final norm + lm_head,
+# in-program sampling.
 SCOPE_QKV = "qkv"
 SCOPE_KV_WRITE = "kv_write"
 SCOPE_ATTENTION = "attention"
@@ -41,8 +40,8 @@ SCOPE_MLP = "mlp"
 SCOPE_EMBED = "embed"
 SCOPE_LM_HEAD = "lm_head"
 SCOPE_SAMPLE = "sample"
-SCOPES = (SCOPE_KV_LAYER, SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION,
-          SCOPE_MLP, SCOPE_EMBED, SCOPE_LM_HEAD, SCOPE_SAMPLE)
+SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_MLP,
+          SCOPE_EMBED, SCOPE_LM_HEAD, SCOPE_SAMPLE)
 
 # The two step programs' names: what ``jax.jit`` calls the functions below
 # and a trace calls their executions (``jit_<name>``). Readers of traces
@@ -979,8 +978,12 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     ``k_caches[g]`` holds group g's layers stacked in ``cfg.group_layers(g)``
     order with its own page pool; ``tables[g]`` is that pool's page table.
     The non-hybrid case is the 1-tuple degenerate form. ``attention_fn(q,
-    k_l, v_l, page_table, positions, total_lens, window) -> [b, seq, heads,
-    hd]`` picks the backend.
+    k_stack, v_stack, layer_idx, page_table, positions, total_lens, window)
+    -> [b, seq, heads, hd]`` picks the backend. It is handed the group's
+    whole stack and the layer's index in it, and new K/V rows are written
+    at ``[layer_idx, page, :, slot, :]`` of the donated stack: no step
+    program takes a layer out of a pool or puts one back, which moved the
+    pool around every layer where the step's own rows are a few KiB.
 
     ``last_only=True`` computes logits only for each sequence's final valid
     token (``new_lens - 1``) — the prefill-chunk case, where the full
@@ -1022,16 +1025,11 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
             jnp.searchsorted(ragged, flat, side="right") - 1, 0, rows - 1)
         positions = (ctx_lens[row_of] + flat - ragged[row_of])[None, :]
         valid = (flat < ragged[-1])[None, :]
-
-        def _scatter(cache, new_kv, table):
-            return scatter_kv_pages_ragged(
-                cache, new_kv[0], table, row_of, positions[0], valid[0])
+        new_tokens = (positions[0], valid[0], row_of)
     else:
         positions = ctx_lens[:, None] + jnp.arange(seq)[None, :]  # [b, s]
         valid = jnp.arange(seq)[None, :] < new_lens[:, None]
-
-        def _scatter(cache, new_kv, table):
-            return scatter_kv_pages(cache, new_kv, table, positions, valid)
+        new_tokens = (positions, valid)
     total_lens = ctx_lens + new_lens
     if tails is not None:
         # The burst path is single-token-per-tick: tmask broadcasts
@@ -1070,20 +1068,24 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
         for j, li in enumerate(cfg.group_layers(g)):
             local_idx[li] = (g, j)
 
-    def layer_of(cache, lj):
-        with jax.named_scope(SCOPE_KV_LAYER):
-            return cache[lj]
-
-    def write_layer(cache, lj, new_kv, table):
-        """``cache`` with ``new_kv`` scattered into its layer ``lj``."""
-        old_layer = layer_of(cache, lj)
+    if tails is None:
+        # Where the step's tokens land in each group's pool: the same for
+        # every layer of the group and for K and V.
         with jax.named_scope(SCOPE_KV_WRITE):
-            return cache.at[lj].set(_scatter(old_layer, new_kv, table))
+            writes = [page_writes(cfg.page_size, table, *new_tokens)
+                      for table in tables]
+
+    def write_layer(cache, g, lj, new_kv):
+        """Group ``g``'s stack ``cache`` with ``new_kv`` written into its
+        layer ``lj``, in place on the donated buffer."""
+        with jax.named_scope(SCOPE_KV_WRITE):
+            return write_kv_pages(cache, writes[g], new_kv, layer_idx=lj)
 
     def write_tail_layer(buf, lj, new_kv):
-        old_layer = layer_of(buf, lj)
+        # The tails are burst-sized, not pools: a where over the layer's
+        # [b, T, ...] buffer beats a scatter (see ``write_tail``).
         with jax.named_scope(SCOPE_KV_WRITE):
-            return buf.at[lj].set(write_tail(old_layer, new_kv))
+            return buf.at[lj].set(write_tail(buf[lj], new_kv))
 
     with jax.named_scope(SCOPE_EMBED):
         x = params["embed"][tokens]  # [b, s, h]
@@ -1166,16 +1168,13 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
             if tails is not None:
                 tail_ks[g] = write_tail_layer(tail_ks[g], lj, latent)
             else:
-                k_caches[g] = write_layer(k_caches[g], lj, latent, table)
-            k_l, v_l = layer_of(k_caches[g], lj), layer_of(k_caches[g], lj)
-            if tails is not None:
-                extra = tail_kwargs(layer_of(tail_ks[g], lj),
-                                    layer_of(tail_ks[g], lj))
+                k_caches[g] = write_layer(k_caches[g], g, lj, latent)
             with jax.named_scope(SCOPE_ATTENTION):
+                if tails is not None:
+                    extra = tail_kwargs(tail_ks[g][lj], tail_ks[g][lj])
                 ctx = attention_fn(
-                    q_eff, k_l, v_l, table, positions, total_lens, None,
-                    k_stack=k_caches[g], v_stack=k_caches[g], layer_idx=lj,
-                    **extra,
+                    q_eff, k_caches[g], k_caches[g], lj, table, positions,
+                    total_lens, None, **extra,
                 )
                 attn = jnp.einsum("bshr,hrv->bshv", ctx[..., :r],
                                   layer["w_uv"])
@@ -1213,18 +1212,14 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                 tail_ks[g] = write_tail_layer(tail_ks[g], lj, k)
                 tail_vs[g] = write_tail_layer(tail_vs[g], lj, v)
             else:
-                k_caches[g] = write_layer(k_caches[g], lj, k, table)
-                v_caches[g] = write_layer(v_caches[g], lj, v, table)
-            k_l, v_l = layer_of(k_caches[g], lj), layer_of(v_caches[g], lj)
-            if tails is not None:
-                extra = tail_kwargs(layer_of(tail_ks[g], lj),
-                                    layer_of(tail_vs[g], lj))
+                k_caches[g] = write_layer(k_caches[g], g, lj, k)
+                v_caches[g] = write_layer(v_caches[g], g, lj, v)
             with jax.named_scope(SCOPE_ATTENTION):
+                if tails is not None:
+                    extra = tail_kwargs(tail_ks[g][lj], tail_vs[g][lj])
                 attn = attention_fn(
-                    q, k_l, v_l, table, positions, total_lens,
-                    cfg.layer_window(li),
-                    k_stack=k_caches[g], v_stack=v_caches[g], layer_idx=lj,
-                    **extra,
+                    q, k_caches[g], v_caches[g], lj, table, positions,
+                    total_lens, cfg.layer_window(li), **extra,
                 )
         with jax.named_scope(SCOPE_ATTENTION):
             x = x + attn.reshape(batch, seq, -1) @ layer["wo"]
@@ -1249,6 +1244,18 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     if tails is not None:
         return logits, tuple(tail_ks), tuple(tail_vs)
     return logits, tuple(k_caches), tuple(v_caches)
+
+
+def _xla_attention(cfg):
+    """The XLA attention backend of ``forward`` and ``forward_hybrid``."""
+    def attention(q, k_stack, v_stack, layer_idx, table, positions,
+                  total_lens, window):
+        return paged_attention(
+            q, k_stack, v_stack, table, positions, total_lens,
+            sliding_window=window,
+            attention_sinks=cfg.attention_sinks or None, layer_idx=layer_idx,
+        )
+    return attention
 
 
 def _forward_impl(params, cfg, tokens, k_cache, v_cache, page_table,
@@ -1281,16 +1288,9 @@ def forward(
     page. ``last_only=True`` → logits is [b, 1, vocab], the final valid
     position of each row (prefill chunks; see ``_forward_impl_grouped``).
     """
-    def xla_attention(q, k_l, v_l, table, positions, total_lens, window,
-                      **_stack_kw):  # slices fuse into XLA's gather
-        return paged_attention(
-            q, k_l, v_l, table, positions, total_lens, sliding_window=window,
-            attention_sinks=cfg.attention_sinks or None,
-        )
-
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
-        xla_attention, last_only=last_only,
+        _xla_attention(cfg), last_only=last_only,
     )
 
 
@@ -1312,16 +1312,9 @@ def forward_hybrid(
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """One model step for a hybrid (mixed full/SWA) model over two
     separately-paged cache groups. XLA attention backend."""
-    def xla_attention(q, k_l, v_l, table, positions, total_lens, window,
-                      **_stack_kw):  # slices fuse into XLA's gather
-        return paged_attention(
-            q, k_l, v_l, table, positions, total_lens, sliding_window=window,
-            attention_sinks=cfg.attention_sinks or None,
-        )
-
     logits, ks, vs = _forward_impl_grouped(
         params, cfg, tokens, (k0, k1), (v0, v1), (table0, table1),
-        ctx_lens, new_lens, xla_attention, last_only=last_only,
+        ctx_lens, new_lens, _xla_attention(cfg), last_only=last_only,
     )
     return logits, ks[0], vs[0], ks[1], vs[1]
 
@@ -1356,23 +1349,21 @@ def forward_decode_pallas(
 
     sinks = cfg.attention_sinks or None
 
-    def pallas_attention(q, k_l, v_l, table, _positions, total_lens, window,
-                         k_stack=None, v_stack=None, layer_idx=None):
-        # Prefer the stacked operand + in-kernel layer index: a sliced
-        # cache materializes a per-layer copy at the pallas custom-call
+    def pallas_attention(q, k_stack, v_stack, layer_idx, table, _positions,
+                         total_lens, window):
+        # The stacked operand + in-kernel layer index: a sliced cache
+        # materializes a per-layer copy at the pallas custom-call
         # boundary (see ops.pallas_paged_attention._superblock_streamer).
-        if k_stack is not None:
-            k_l, v_l = k_stack, v_stack
         if mesh is not None:
             out = sharded_paged_decode_attention(
-                mesh, q[:, 0], k_l, v_l, table, total_lens,
+                mesh, q[:, 0], k_stack, v_stack, table, total_lens,
                 sliding_window=window, sinks=sinks, shared_kv=cfg.is_mla,
                 shared_stream=cfg.mla_decode_stream,
                 layer_idx=layer_idx, interpret=interpret,
             )
         else:
             out = pallas_paged_decode_attention(
-                q[:, 0], k_l, v_l, table, total_lens,
+                q[:, 0], k_stack, v_stack, table, total_lens,
                 sliding_window=window, sinks=sinks, shared_kv=cfg.is_mla,
                 shared_stream=cfg.mla_decode_stream,
                 layer_idx=layer_idx, batch_rows=batch_rows,
@@ -1400,22 +1391,17 @@ def _decode_step_attention(use_pallas: bool, interpret: bool, mesh,
     from ..ops.pallas_paged_attention import (
         pallas_paged_decode_attention, sharded_paged_decode_attention)
 
-    def attention(q, k_l, v_l, table, positions, total_lens, window,
-                  tail_k=None, tail_v=None, tail_lens=None, ctx_base=None,
-                  k_stack=None, v_stack=None, layer_idx=None):
+    def attention(q, k_stack, v_stack, layer_idx, table, positions,
+                  total_lens, window,
+                  tail_k=None, tail_v=None, tail_lens=None, ctx_base=None):
         # Burst-tail mode: the paged cache covers only ctx_base keys; the
-        # tail holds the burst's tokens (see _forward_impl_grouped).
+        # tail holds the burst's tokens (see _forward_impl_grouped). Every
+        # backend reads the stack at layer_idx: the kernels in-kernel, the
+        # XLA path in its page gather.
         base_lens = total_lens if ctx_base is None else ctx_base
-        if use_pallas and k_stack is not None:
-            # Stacked operand + in-kernel layer index: a sliced cache
-            # materializes a per-layer copy at the pallas custom-call
-            # boundary.
-            k_l, v_l = k_stack, v_stack
-        else:
-            layer_idx = None
         if use_pallas and mesh is not None:
             out = sharded_paged_decode_attention(
-                mesh, q[:, 0], k_l, v_l, table, base_lens,
+                mesh, q[:, 0], k_stack, v_stack, table, base_lens,
                 sliding_window=window, sinks=sinks, shared_kv=shared_kv,
                 shared_stream=shared_stream,
                 tail_k=tail_k, tail_v=tail_v, tail_lens=tail_lens,
@@ -1424,7 +1410,7 @@ def _decode_step_attention(use_pallas: bool, interpret: bool, mesh,
             return out[:, None]
         if use_pallas:
             out = pallas_paged_decode_attention(
-                q[:, 0], k_l, v_l, table, base_lens,
+                q[:, 0], k_stack, v_stack, table, base_lens,
                 sliding_window=window, sinks=sinks, shared_kv=shared_kv,
                 shared_stream=shared_stream,
                 tail_k=tail_k, tail_v=tail_v, tail_lens=tail_lens,
@@ -1433,9 +1419,9 @@ def _decode_step_attention(use_pallas: bool, interpret: bool, mesh,
             )
             return out[:, None]
         return paged_attention(
-            q, k_l, v_l, table, positions, base_lens, sliding_window=window,
-            attention_sinks=sinks, tail_k=tail_k, tail_v=tail_v,
-            tail_lens=tail_lens,
+            q, k_stack, v_stack, table, positions, base_lens,
+            sliding_window=window, attention_sinks=sinks, tail_k=tail_k,
+            tail_v=tail_v, tail_lens=tail_lens, layer_idx=layer_idx,
         )
 
     return attention
@@ -1555,14 +1541,13 @@ def _decode_steps_scan(params, cfg, last_tokens, k_caches, v_caches, tables,
     v_caches = list(v_caches)
     with jax.named_scope(SCOPE_KV_WRITE):
         for g in range(len(k_caches)):
+            writes = page_writes(cfg.page_size, tables[g], tpos, tvalid)
             for lj in range(k_caches[g].shape[0]):
-                k_caches[g] = k_caches[g].at[lj].set(scatter_kv_pages(
-                    k_caches[g][lj], tail_ks[g][lj], tables[g], tpos,
-                    tvalid))
+                k_caches[g] = write_kv_pages(
+                    k_caches[g], writes, tail_ks[g][lj], layer_idx=lj)
                 if v_caches[g].shape[-1]:  # MLA's width-0 V pool: no data
-                    v_caches[g] = v_caches[g].at[lj].set(scatter_kv_pages(
-                        v_caches[g][lj], tail_vs[g][lj], tables[g], tpos,
-                        tvalid))
+                    v_caches[g] = write_kv_pages(
+                        v_caches[g], writes, tail_vs[g][lj], layer_idx=lj)
     return toks.T, tuple(k_caches), tuple(v_caches)  # toks [batch, steps]
 
 
@@ -1654,22 +1639,20 @@ def forward_prefill_pallas(
 
     sinks = cfg.attention_sinks or None
 
-    def attention_fn(q, k_l, v_l, table, positions, total_lens, window,
-                     k_stack=None, v_stack=None, layer_idx=None):
+    def attention_fn(q, k_stack, v_stack, layer_idx, table, positions,
+                     total_lens, window):
         # Stacked operand + in-kernel layer index: a sliced cache
         # materializes a per-layer copy at the pallas custom-call
         # boundary (see ops.pallas_paged_attention._superblock_streamer).
-        if k_stack is not None:
-            k_l, v_l = k_stack, v_stack
         if mesh is not None:
             return sharded_paged_prefill_attention(
-                mesh, q, k_l, v_l, table, ctx_lens, total_lens,
+                mesh, q, k_stack, v_stack, table, ctx_lens, total_lens,
                 q_tile=q_tile, sliding_window=window,
                 sinks=sinks, shared_kv=cfg.is_mla, layer_idx=layer_idx,
                 interpret=interpret,
             )
         return pallas_paged_prefill_attention(
-            q, k_l, v_l, table, ctx_lens, total_lens,
+            q, k_stack, v_stack, table, ctx_lens, total_lens,
             q_tile=q_tile, sliding_window=window,
             sinks=sinks, shared_kv=cfg.is_mla, layer_idx=layer_idx,
             interpret=interpret,
@@ -1722,12 +1705,10 @@ def forward_ragged(
 
     sinks = cfg.attention_sinks or None
 
-    def attention_fn(q, k_l, v_l, table, positions, total_lens, window,
-                     k_stack=None, v_stack=None, layer_idx=None):
-        if k_stack is not None:
-            k_l, v_l = k_stack, v_stack
+    def attention_fn(q, k_stack, v_stack, layer_idx, table, positions,
+                     total_lens, window):
         out = pallas_paged_ragged_attention(
-            q[0], k_l, v_l, table, row_starts, ctx_lens,
+            q[0], k_stack, v_stack, table, row_starts, ctx_lens,
             q_tile=q_tile, sliding_window=window, sinks=sinks,
             shared_kv=cfg.is_mla, layer_idx=layer_idx, interpret=interpret,
         )

@@ -20,6 +20,8 @@ role ``tensor_copier.cu`` plays in the reference — see SURVEY.md §2.2).
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -28,35 +30,104 @@ import jax.numpy as jnp
 GARBAGE_PAGE = 0
 
 
+class PageWrites(NamedTuple):
+    """Where a step's new tokens land, a page at a time (``page_writes``)."""
+
+    pages: jax.Array  # [runs] the physical page of each run of tokens
+    src: jax.Array  # [runs, page_size] the token landing in each slot, or -1
+
+
+def page_writes(
+    page_size: int,
+    page_table: jax.Array,  # [rows, pages_per_seq] int32 (physical page ids)
+    positions: jax.Array,  # [batch, seq] int32, or ragged: [total_q]
+    valid: jax.Array,  # bool, as ``positions``
+    row_of: jax.Array | None = None,  # ragged: [total_q] owning row
+) -> PageWrites:
+    """The pages a step's new tokens touch, and which token lands in each
+    slot of each: the same for every layer and for K and V, so a step
+    program works it out once.
+
+    Token ``[b, s]`` belongs to row ``b`` of ``page_table``; a ragged flat
+    token to row ``row_of``. A row's valid tokens come first and sit at
+    consecutive positions (a chunk after its context), which bounds the
+    pages it touches. Positions past the page table are clamped (their
+    writes are invalid anyway) and invalid tokens route to slot 0 of the
+    garbage page; of tokens with one target the last wins.
+    """
+    logical_page = jnp.minimum(positions // page_size, page_table.shape[1] - 1)
+    if row_of is None:
+        seq = positions.shape[1]
+        page = jnp.take_along_axis(page_table, logical_page, axis=1)
+        # seq consecutive positions span at most this many pages; one more
+        # run for the row's invalid tail.
+        runs = positions.shape[0] * min(
+            seq, (seq + page_size - 2) // page_size + 2)
+    else:
+        total_q = positions.shape[0]
+        page = page_table[
+            jnp.clip(row_of, 0, page_table.shape[0] - 1), logical_page]
+        # A row of n tokens spans under n / page_size + 2 pages; one more
+        # run for the padding behind the last row.
+        runs = min(total_q,
+                   total_q // page_size + 2 * page_table.shape[0] + 1)
+    page = jnp.where(valid, page, GARBAGE_PAGE).reshape(-1)
+    slot = jnp.where(valid, positions % page_size, 0).reshape(-1)
+
+    # One page per run of equal pages along the flat token axis; entries
+    # past the last run name no page and are dropped on the write. Two runs
+    # of one page (the garbage page after every row) get the same ``src``.
+    opens = jnp.concatenate([jnp.ones((1,), bool), page[1:] != page[:-1]])
+    pages = jnp.full((runs,), jnp.iinfo(page.dtype).max, page.dtype).at[
+        jnp.cumsum(opens) - 1].set(page, mode="drop")
+    hits = ((pages[:, None, None] == page)
+            & (jnp.arange(page_size)[None, :, None] == slot))
+    src = jnp.max(jnp.where(hits, jnp.arange(page.shape[0]), -1), axis=-1)
+    return PageWrites(pages, src)
+
+
+def write_kv_pages(
+    cache: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
+    writes: PageWrites,
+    new_kv: jax.Array,  # [batch, seq, kv_heads, head_dim] or [total_q, ..]
+    layer_idx: int | None = None,
+) -> jax.Array:
+    """``cache`` with the tokens of ``new_kv`` (cast to the pool's dtype)
+    where ``writes`` puts them. With ``layer_idx``, ``cache`` is the whole
+    ``[layers, num_pages, ...]`` stack and they land in that layer of it.
+    Donate ``cache`` under jit for an in-place update.
+
+    Written a page at a time: the touched pages are read, the tokens' rows
+    set in them, and whole pages written back, so the cost is the pages
+    touched. A scatter of the rows themselves has the window ``[kv_heads,
+    head_dim]``, which is not minor in ``[.., kv_heads, page_size,
+    head_dim]``, and the TPU compiler re-lays the whole operand around it
+    (a v5e, 28 layers of 2560 pages: 30 ms a step for 8 rows); indexed by
+    kv head too it stays in place but pays per 256-byte update (33 ms for
+    1024 rows). A page is the pool's own unit and its window is minor:
+    1.2-1.6 ms for 8-32 rows, 2.6 ms for 1024.
+    """
+    index = (writes.pages,) if layer_idx is None else (layer_idx, writes.pages)
+    rows = new_kv.astype(cache.dtype).reshape((-1,) + new_kv.shape[-2:])
+    rows = rows[jnp.maximum(writes.src, 0)]  # [runs, page_size, kvh, hd]
+    pages = jnp.where((writes.src >= 0)[:, None, :, None],
+                      rows.transpose(0, 2, 1, 3), cache[index])
+    return cache.at[index].set(pages, mode="drop", unique_indices=False)
+
+
 def scatter_kv_pages(
     cache: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
     new_kv: jax.Array,  # [batch, seq, kv_heads, head_dim]
     page_table: jax.Array,  # [batch, pages_per_seq] int32 (physical page ids)
     positions: jax.Array,  # [batch, seq] int32 logical positions
     valid: jax.Array,  # [batch, seq] bool
+    layer_idx: int | None = None,
 ) -> jax.Array:
-    """Write new K or V vectors into their pages; returns the updated cache.
-
-    Invalid slots scatter into the garbage page. Donate ``cache`` under jit
-    for an in-place update.
-    """
-    num_pages, kv_heads, page_size, head_dim = cache.shape
-    batch, seq = positions.shape
-    # Clamp: padded positions can point past the page table (their writes
-    # are redirected to the garbage page below anyway).
-    logical_page = jnp.minimum(positions // page_size, page_table.shape[1] - 1)
-    slot = positions % page_size
-    phys_page = jnp.take_along_axis(page_table, logical_page, axis=1)
-    phys_page = jnp.where(valid, phys_page, GARBAGE_PAGE)
-    slot = jnp.where(valid, slot, 0)
-
-    flat_page = phys_page.reshape(batch * seq)
-    flat_slot = slot.reshape(batch * seq)
-    # [batch*seq, kv_heads, head_dim] values scattered on dims (0, 2).
-    vals = new_kv.astype(cache.dtype).reshape(batch * seq, kv_heads, head_dim)
-    return cache.at[flat_page, :, flat_slot, :].set(
-        vals, mode="drop", unique_indices=False
-    )
+    """Write new K or V vectors into their pages; returns the updated cache
+    (``page_writes`` then ``write_kv_pages``, which a caller with several
+    caches to write at the same places calls itself)."""
+    writes = page_writes(cache.shape[-2], page_table, positions, valid)
+    return write_kv_pages(cache, writes, new_kv, layer_idx)
 
 
 def scatter_kv_pages_ragged(
@@ -66,39 +137,31 @@ def scatter_kv_pages_ragged(
     row_of: jax.Array,  # [total_q] int32 owning row per flat token
     positions: jax.Array,  # [total_q] int32 logical positions
     valid: jax.Array,  # [total_q] bool
+    layer_idx: int | None = None,
 ) -> jax.Array:
-    """`scatter_kv_pages` over a ragged flat token axis.
-
-    The mixed prefill+decode batch is one flat axis where each token knows
-    its owning row (``row_of``) and logical position; the page lookup is
-    a 2-D gather on ``(row, logical_page)`` instead of a per-row
-    take_along_axis. Padded slots route to the garbage page exactly like
-    the padded scatter.
-    """
-    page_size = cache.shape[2]
-    logical_page = jnp.minimum(positions // page_size, page_table.shape[1] - 1)
-    slot = positions % page_size
-    row = jnp.clip(row_of, 0, page_table.shape[0] - 1)
-    phys_page = page_table[row, logical_page]
-    phys_page = jnp.where(valid, phys_page, GARBAGE_PAGE)
-    slot = jnp.where(valid, slot, 0)
-    vals = new_kv.astype(cache.dtype)
-    return cache.at[phys_page, :, slot, :].set(
-        vals, mode="drop", unique_indices=False
-    )
+    """`scatter_kv_pages` over a ragged flat token axis: the mixed
+    prefill+decode batch is one flat axis where each token knows its owning
+    row (``row_of``) and logical position, and a row's tokens are adjacent."""
+    writes = page_writes(cache.shape[-2], page_table, positions, valid, row_of)
+    return write_kv_pages(cache, writes, new_kv, layer_idx)
 
 
 def gather_kv_pages(
     cache: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
     page_table: jax.Array,  # [batch, pages_per_seq] int32
+    layer_idx: int | None = None,
 ) -> jax.Array:
     """Gather each sequence's pages into logical order.
 
     Returns ``[batch, pages_per_seq * page_size, kv_heads, head_dim]``.
+    With ``layer_idx``, ``cache`` is the ``[layers, num_pages, ...]`` stack
+    and the layer is one more index of the same gather.
     """
     batch, pages_per_seq = page_table.shape
-    _, kv_heads, page_size, head_dim = cache.shape
-    gathered = cache[page_table]  # [batch, pages_per_seq, kv, page_size, hd]
+    kv_heads, page_size, head_dim = cache.shape[-3:]
+    # [batch, pages_per_seq, kv, page_size, hd]
+    gathered = (cache[page_table] if layer_idx is None
+                else cache[layer_idx, page_table])
     return gathered.transpose(0, 1, 3, 2, 4).reshape(
         batch, pages_per_seq * page_size, kv_heads, head_dim
     )

@@ -34,6 +34,7 @@ def paged_attention(
     tail_k: jax.Array | None = None,  # [batch, T, kv_heads, head_dim]
     tail_v: jax.Array | None = None,
     tail_lens: jax.Array | None = None,  # [batch] valid tail tokens
+    layer_idx: int | None = None,
 ) -> jax.Array:
     """Causal attention of new queries against paged KV (cached + new).
 
@@ -53,15 +54,19 @@ def paged_attention(
     ``forward_decode_steps``) and only the ≤steps-token tail is carried.
     With a tail, ``total_lens`` is the FROZEN base length and queries sit
     at ``q_positions ≥ total_lens``.
+
+    ``layer_idx`` makes ``k_cache``/``v_cache`` the ``[layers, num_pages,
+    ...]`` stacks: the page gather takes the layer as one more index, so
+    no layer of a pool is ever sliced out.
     """
     batch, q_seq, q_heads, head_dim = q.shape
-    _, kv_heads, page_size, _ = k_cache.shape
+    kv_heads = k_cache.shape[-3]
     if scale is None:
         scale = head_dim ** -0.5
     group = q_heads // kv_heads
 
-    k = gather_kv_pages(k_cache, page_table)  # [b, kv_len, kvh, hd]
-    v = gather_kv_pages(v_cache, page_table)
+    k = gather_kv_pages(k_cache, page_table, layer_idx)  # [b, kv_len, kvh, hd]
+    v = gather_kv_pages(v_cache, page_table, layer_idx)
     if k.dtype.itemsize == 1:
         # Quantized (fp8 e4m3) cache: the HBM read above moved 1-byte
         # elements — the bandwidth/capacity win — and the upcast to the

@@ -1,0 +1,106 @@
+"""The step programs of ``models/llama.py`` at a tiny size, one table of
+cases for the tests that hold every program to the same rule: the
+structure test in ``test_model.py`` (no program moves a layer of a pool)
+and the equality test in ``test_kv_pages.py`` (writing rows into the
+stack in place gives what taking the layer out and putting it back gave).
+
+A pool layer here is ``[11, 2, 4, 16]`` (the hybrid SWA group's
+``[7, 2, 4, 16]``): no activation, weight or tail buffer of these
+configurations has that shape, so an op of that shape is an op on a layer.
+"""
+
+from typing import Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from llmd_kv_cache_tpu.models import llama
+
+PAGES, SWA_PAGES = 11, 7
+TABLE = np.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
+SWA_TABLE = np.asarray([[1, 2, 3], [4, 5, 6]], np.int32)
+TOKENS = np.asarray([[[5, 9, 2, 7], [3, 8, 1, 6]],
+                      [[4, 4, 9, 1], [7, 2, 2, 5]]], np.int32)
+
+
+def _i32(*xs):
+    return np.asarray(xs, np.int32)
+
+
+def _chunk(step):
+    """Two padded chunks per row: 3 and 2 new tokens, then 4 and 1 more."""
+    ctx, new = ((_i32(0, 0), _i32(3, 2)), (_i32(3, 2), _i32(4, 1)))[step]
+    return TOKENS[step], ctx, new
+
+
+def _decode(step):
+    """Two decode rows, then one live row and one row of padding."""
+    ctx, new = ((_i32(3, 5), _i32(1, 1)), (_i32(4, 6), _i32(1, 0)))[step]
+    return TOKENS[step][:, :1], ctx, new
+
+
+def _padded(rows):
+    def args(params, cfg, pools, step):
+        tokens, ctx, new = rows(step)
+        tables = (TABLE, SWA_TABLE)[:len(pools) // 2]
+        return (params, cfg, tokens, *pools, *tables, ctx, new)
+    return args
+
+
+def _ragged(params, cfg, pools, step):
+    row_starts, ctx = ((_i32(0, 3, 5), _i32(0, 0)),
+                       (_i32(0, 4, 5), _i32(3, 2)))[step]
+    return (params, cfg, TOKENS[step].reshape(1, 8), *pools, TABLE,
+            row_starts, ctx)
+
+
+def _burst(params, cfg, pools, step):
+    """Bursts of two ticks: budgets 2 and 1, then 1 and none."""
+    ctx, active = ((_i32(3, 5), _i32(2, 1)), (_i32(5, 6), _i32(1, 0)))[step]
+    tables = (TABLE, SWA_TABLE)[:len(pools) // 2]
+    return (params, cfg, TOKENS[step][:, 0], *pools, *tables, ctx, active)
+
+
+class Program(NamedTuple):
+    fn: Callable  # the jitted program; returns (out, *pools)
+    cfg: llama.LlamaConfig
+    args: Callable  # (params, cfg, pools, step) -> positional arguments
+    static: dict  # its static keyword arguments
+    pallas: bool  # attention is a kernel: ``interpret=True`` to run here
+
+
+_GQA = llama.LlamaConfig.tiny()
+_HYBRID = llama.LlamaConfig.gemma_tiny()
+_MLA = llama.LlamaConfig.deepseek_tiny()
+PROGRAMS = {
+    "forward": Program(llama.forward, _GQA, _padded(_chunk), {}, False),
+    "forward_mla": Program(llama.forward, _MLA, _padded(_chunk), {}, False),
+    "forward_hybrid": Program(
+        llama.forward_hybrid, _HYBRID, _padded(_chunk), {}, False),
+    "forward_decode_pallas": Program(
+        llama.forward_decode_pallas, _GQA, _padded(_decode), {}, True),
+    "forward_prefill_pallas": Program(
+        llama.forward_prefill_pallas, _GQA, _padded(_chunk), {}, True),
+    "forward_ragged": Program(llama.forward_ragged, _GQA, _ragged, {}, True),
+    "forward_decode_steps": Program(
+        llama.forward_decode_steps, _GQA, _burst,
+        dict(steps=2, use_pallas=True), True),
+    "forward_decode_steps_xla_mla": Program(
+        llama.forward_decode_steps, _MLA, _burst, dict(steps=2), False),
+    "forward_decode_steps_hybrid": Program(
+        llama.forward_decode_steps_hybrid, _HYBRID, _burst,
+        dict(steps=2, use_pallas=True), True),
+}
+
+
+def init_pools(cfg, dtype=None):
+    """The program's pools, filled: attention reads what a step did not
+    write, so a write that lands elsewhere shows in the logits too."""
+    if cfg.is_hybrid:
+        pools = llama.init_kv_cache_hybrid(cfg, PAGES, SWA_PAGES, dtype)
+    else:
+        pools = llama.init_kv_cache(cfg, PAGES, dtype)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(pools))
+    return tuple(jax.random.normal(k, p.shape, jnp.float32).astype(p.dtype)
+                 for k, p in zip(keys, pools))

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import step_programs as sp
 
 from llmd_kv_cache_tpu.models.llama import (
     LlamaConfig,
@@ -212,3 +213,60 @@ class TestTraceNames:
         # on them and the compile-cache key (which leaves metadata out)
         # cannot move.
         assert with_scopes == without
+
+
+class TestNoLayerOfAPoolMoves:
+    """A step's K/V rows go into the donated stack at ``[layer, page, :,
+    slot, :]`` and every backend reads the stack at a layer index. What
+    took a layer out (a slice), wrote into the copy (a scatter on a layer)
+    and put it back (a dynamic-update-slice of a layer) moved the pool
+    around every layer of every step: 70% of the device's time on a v5e.
+
+    Read off the module as lowered for the TPU (the kernels as Mosaic
+    custom calls, not the interpreter's emulation of their DMAs), before
+    any backend: it holds here, on the CPU."""
+
+    @staticmethod
+    def _ops(module):
+        def walk(op):
+            yield op
+            for region in op.regions:
+                for block in region:
+                    for child in block:
+                        yield from walk(child.operation)
+        return walk(module.operation)
+
+    @pytest.mark.parametrize("name", list(sp.PROGRAMS))
+    def test_program_touches_rows_not_layers(self, name):
+        prog = sp.PROGRAMS[name]
+        params = init_params(jax.random.PRNGKey(0), prog.cfg)
+        pools = sp.init_pools(prog.cfg)
+        module = prog.fn.trace(
+            *prog.args(params, prog.cfg, pools, 0), **prog.static,
+        ).lower(lowering_platforms=("tpu",)).compiler_ir()
+
+        stacks = {tuple(p.shape) for p in pools if p.shape[-1]}
+        # One layer, as a slice of the stack leaves it or squeezed.
+        layers = {s[1:] for s in stacks} | {(1,) + s[1:] for s in stacks}
+
+        def shape(value):
+            return tuple(getattr(value.type, "shape", ()))
+
+        seen, moved = set(), []
+        for op in self._ops(module):
+            seen.add(op.name)
+            if op.name in ("stablehlo.slice", "stablehlo.dynamic_slice",
+                           "stablehlo.gather"):
+                hit = shape(op.results[0]) in layers
+            elif op.name == "stablehlo.dynamic_update_slice":
+                hit = shape(op.operands[1]) in layers
+            elif op.name == "stablehlo.scatter":
+                hit = shape(op.operands[0]) in layers
+            else:
+                continue
+            if hit:
+                moved.append(f"{op.name} {shape(op.results[0])}")
+        assert not moved
+        # The walk saw the program: its writes, and its kernel if it has one.
+        assert "stablehlo.scatter" in seen
+        assert ("stablehlo.custom_call" in seen) == prog.pallas
