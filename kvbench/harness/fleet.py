@@ -124,11 +124,22 @@ def key_for(seed: int):
                               seed >> 31)
 
 
+def cache_payload(cfg) -> tuple[int, int, int]:
+    """(streams, heads, width) of one token in one layer of the page pool,
+    as the model's config says its cache holds them: K and V of
+    ``num_kv_heads`` x ``head_dim`` for GQA; for a latent one stream (the
+    pool has no V, ``llama.init_kv_cache``) of one shared head as wide as
+    rank + rope key + pad."""
+    return (1 if cfg.is_mla else 2, cfg.kv_cache_heads,
+            cfg.kv_cache_head_dim)
+
+
 def build_model(conf: dict, seed: int, device=None):
     """Random weights in the served type, made on the device from the seed,
     fused where the program's own gate says fusing pays (one shared fused
     tree, or each engine would make its own copy)."""
     import jax
+    import jax.numpy as jnp
 
     from llmd_kv_cache_tpu.models.llama import init_params, maybe_fuse_params
 
@@ -139,13 +150,16 @@ def build_model(conf: dict, seed: int, device=None):
     # A fused tree also holds a plain number (its column interleave).
     n_params = sum(getattr(x, "size", 0)
                    for x in jax.tree_util.tree_leaves(params))
-    kv_per_token = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim * 2
+    streams, heads, width = cache_payload(cfg)
+    kv_per_token = (streams * cfg.num_layers * heads * width
+                    * jnp.dtype(cfg.dtype).itemsize)
+    fused = any(k in params["layers"][0] for k in ("w_qkv", "w_mla_in"))
     log(f"model: {conf['kvbench']['model_name']} layers={cfg.num_layers} "
         f"hidden={cfg.hidden_size} heads={cfg.num_heads}/{cfg.num_kv_heads}"
         f"x{cfg.head_dim} mlp={cfg.intermediate_size} vocab={cfg.vocab_size}"
-        f" qk_norm={cfg.qk_norm} window={cfg.sliding_window} fused="
-        f"{'w_qkv' in params['layers'][0]} | {n_params / 1e9:.2f}B params, "
-        f"{kv_per_token // 1024} KiB KV/token")
+        f" qk_norm={cfg.qk_norm} window={cfg.sliding_window} fused={fused}"
+        f" cache={streams}x{heads}x{width} | {n_params / 1e9:.2f}B params, "
+        f"{kv_per_token / 1024:g} KiB KV/token")
     return cfg, params
 
 
@@ -206,10 +220,11 @@ def build_fleet(conf: dict, cfg, params, devices, store_root,
 
         spec = None
         if kv.get("storage"):
+            streams, heads, width = cache_payload(cfg)
             spec = SharedStorageOffloadSpec(
                 root=str(store_root), model_name=fleet.model_name,
                 page_size=page, num_layers=cfg.num_layers,
-                kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+                kv_heads=heads, head_dim=width, kv_streams=streams,
                 io_threads=int(kv["storage"].get("io_threads", 4)),
                 parallel_agnostic=True)
         fleet.engines[name] = MiniEngine(

@@ -99,8 +99,10 @@ class Run:
     # the part of it that lay past the window's end.
     stages: dict = field(default_factory=dict)
     tracer: dict = field(default_factory=dict)
-    # Filled by run.py: the model, the data files, the reduced trace.
+    # Filled by run.py: the model, its counts (``names.counts``), the data
+    # files, the reduced trace.
     cfg: object = None
+    counts: object = None
     traffic: dict = field(default_factory=dict)
     setup_seconds: float = 0.0
     trace: object = None

@@ -1,9 +1,10 @@
 """Names to files.
 
 ``BENCHMARK.json`` names every workload, configuration, traffic mix and
-metric; each name is one file under ``kvbench/``. A later PR adds a file and
-an entry, and edits nothing here. A name with no file fails with the path
-that was looked for.
+metric; each name is one file under ``kvbench/``. A configuration's file may
+name two more: its plain reference and its counts. A later PR adds a file
+and an entry, and edits nothing here. A name with no file fails with the
+path that was looked for.
 """
 
 from __future__ import annotations
@@ -110,6 +111,38 @@ def metric(name: str):
     if mod.NAME != name:
         raise ValueError(f"{mod.__file__} calls itself {mod.NAME!r}")
     return mod
+
+
+def _configuration_module(conf: dict, key: str, default: str, needs):
+    """The module a configuration's ``kvbench`` group names under ``key``:
+    a file under ``kvbench/`` (by convention ``references/<config>.py``,
+    ``counts/<config>.py``), or ``default`` where it names none."""
+    rel = conf["kvbench"].get(key) or default
+    path = (KVBENCH / rel).resolve()
+    if KVBENCH not in path.parents:
+        raise ValueError(f"a configuration's {key} is a file under "
+                         f"{KVBENCH}, not {rel!r}")
+    mod = load_module(path, f"{key} {rel!r} of the configuration for "
+                            f"{conf['kvbench'].get('model_name')!r}")
+    for attr in needs:
+        if not hasattr(mod, attr):
+            raise AttributeError(f"{mod.__file__} has no {attr}")
+    return mod
+
+
+def reference(conf: dict):
+    """The plain forward this configuration's model is checked against:
+    ``logits_at(params, cfg, tokens, positions)`` and ``TOLERANCE``."""
+    return _configuration_module(conf, "reference", "reference.py",
+                                 ("logits_at", "TOLERANCE"))
+
+
+def counts(conf: dict):
+    """What this configuration's model needs, from shapes alone:
+    ``prefill_flops(cfg, pos, n)`` and ``decode_attention_bytes(cfg,
+    keys)``, which the roofline readers divide by."""
+    return _configuration_module(conf, "counts", "trace/opcount.py",
+                                 ("prefill_flops", "decode_attention_bytes"))
 
 
 def cell_metrics(bench: dict, cell: str, traced: bool) -> list[dict]:

@@ -17,7 +17,7 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from . import correct, fleet as fleet_mod
+from . import correct, fleet as fleet_mod, names
 from .fleet import log
 from .loop import now, serve_to_completion
 
@@ -79,11 +79,15 @@ def prepare(cell: dict, conf: dict, traffic: dict, schedule_fn, seed: int,
             seconds: float, toy: bool, t_process: float):
     """Build, probe and warm the system for one cell. Returns a namespace
     with ``fleet``, ``params``, ``stats``, ``phases``, ``schedule``,
-    ``probe``, ``device``, ``devices``, ``served_faults`` and
-    ``close()``."""
+    ``probe``, ``device``, ``devices``, ``served_faults``, the
+    configuration's ``reference`` and ``counts`` modules and ``close()``."""
     import jax
 
     ctx = SimpleNamespace(phases={}, stats=None, t_process=t_process)
+    # Before anything is built: a configuration that names a file which is
+    # not there stops here, with the path.
+    ctx.reference = names.reference(conf)
+    ctx.counts = names.counts(conf)
     chips = int(cell["chips"])
     with phase(ctx, "device"):
         ctx.device = fleet_mod.find_device(chips, toy)
@@ -145,10 +149,8 @@ def prepare(cell: dict, conf: dict, traffic: dict, schedule_fn, seed: int,
             ctx.served_faults = fleet_mod.what_serves(
                 ctx.fleet, interpret=not on_tpu)
         with phase(ctx, "probe"):
-            from kvbench import reference
-
             ctx.probe = correct.probe(
-                ctx.fleet, ctx.params, reference, seed,
+                ctx.fleet, ctx.params, ctx.reference, seed,
                 int(kv["probe"]["prompt_tokens"]),
                 int(kv["probe"]["decode_tokens"]))
         with phase(ctx, "warm-up"):

@@ -8,7 +8,7 @@ from kvbench.harness.stats import percentile
 NAME = "commit_step_ms_p95"
 UNIT = "ms"
 LAYER = "block manager + offload"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "program_span"
 
 

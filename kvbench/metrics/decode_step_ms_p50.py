@@ -8,7 +8,7 @@ from kvbench.harness.stats import percentile
 NAME = "decode_step_ms_p50"
 UNIT = "ms"
 LAYER = "model step"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 
 

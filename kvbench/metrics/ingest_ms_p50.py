@@ -7,7 +7,7 @@ from kvbench.harness.stats import percentile
 NAME = "ingest_ms_p50"
 UNIT = "ms"
 LAYER = "event ingest"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "program_span"
 
 

@@ -9,7 +9,7 @@ device behind whatever the other replica queued."""
 NAME = "programs_per_step"
 UNIT = "count"
 LAYER = "scheduler"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 
 

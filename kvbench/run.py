@@ -23,6 +23,7 @@ T_PROCESS = time.perf_counter()
 import argparse  # noqa: E402
 import atexit  # noqa: E402
 import contextlib  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import logging  # noqa: E402
 import os  # noqa: E402
@@ -121,6 +122,7 @@ def measure(ctx, cell, traffic, seconds, traced, after, keep_trace=""):
                      lambda: programs_first_used(ctx.stats), at)
     run.setup_seconds = setup_seconds
     run.cfg = ctx.cfg
+    run.counts = ctx.counts
     run.peaks = (opcount.peaks(ctx.device["kind"])
                  if ctx.device["platform"] == "tpu"
                  else opcount.rehearsal_peaks())
@@ -213,13 +215,52 @@ def after_the_window(run, after, traced) -> dict:
     return out
 
 
+class GcPauses:
+    """The collector's pauses while it is registered (``gc.callbacks``):
+    a collection stops every Python thread, the generator's included, so a
+    run whose generator was seconds late can be told from one the host
+    stalled (PERF.md, PR 29)."""
+
+    def __init__(self):
+        self.t0, self.pauses = None, []   # pauses: (generation, seconds)
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self.t0 = time.perf_counter()
+        elif self.t0 is not None:
+            self.pauses.append((info["generation"],
+                                time.perf_counter() - self.t0))
+
+    def summary(self) -> dict:
+        by_gen = {g: [s for gen, s in self.pauses if gen == g]
+                  for g in (0, 1, 2)}
+        return {f"gen{g}": {"n": len(v), "longest_ms": round(max(v) * 1e3, 2),
+                            "total_ms": round(sum(v) * 1e3, 1)}
+                for g, v in by_gen.items() if v}
+
+
+def gap_summary(run) -> dict:
+    """Where the gaps between tokens lie: which percentile sits on an edge
+    between two kinds of gap shows here, run by run (PERF.md, PR 29)."""
+    from kvbench.harness.stats import mean, percentile
+    from kvbench.metrics import _read
+
+    gaps = _read.token_gaps_ms(run)
+    out = {"n": len(gaps), "mean": mean(gaps)}
+    out.update({f"p{q}": percentile(gaps, q) for q in (50, 90, 95, 99)})
+    return {k: round(v, 3) if isinstance(v, float) else v
+            for k, v in out.items()}
+
+
 def say_exit(t_line: list) -> None:
     if t_line:
         print(f"[kvbench] exit: {time.perf_counter() - t_line[0]:.1f}s "
               f"after the last line", file=sys.stderr, flush=True)
 
 
-def main(argv=None) -> int:
+def main(argv=None, bench=None) -> int:
+    """``bench``: the contract to run a cell of, where it is not the
+    checkout's ``BENCHMARK.json`` (the tests' fixture cell)."""
     args = parse_args(argv)
     # Registered before JAX is imported, so it runs after JAX's own exit
     # handlers: what the runtime's shutdown takes shows on stderr.
@@ -232,7 +273,8 @@ def main(argv=None) -> int:
                         format="%(levelname)s %(name)s: %(message)s")
     from kvbench.harness.prepare import prepare
 
-    bench = names.benchmark()
+    if bench is None:
+        bench = names.benchmark()
     cell = names.workload(bench, args.workload)
     traced = bool(args.trace)
     expected = names.cell_metrics(bench, cell["name"], traced)
@@ -248,15 +290,20 @@ def main(argv=None) -> int:
     ctx = prepare(cell, conf, traffic, gen.schedule, args.seed, args.seconds,
                   toy, T_PROCESS)
     after: dict = {}
+    pauses = GcPauses()
+    gc.callbacks.append(pauses)
     try:
         run = measure(ctx, cell, traffic, args.seconds, traced, after,
                       args.keep_trace)
         peak = memory_peak_bytes(ctx.devices)
     finally:
+        gc.callbacks.remove(pauses)
         with stage(after, "close"):
             ctx.close()
 
     log(f"window: {run.summary()}")
+    log(f"token gaps of the sampled requests, ms: {gap_summary(run)}")
+    log(f"collector's pauses from the window's start: {pauses.summary()}")
     # Every metric of the cell on an earlier line, whatever the mode, so
     # that the cost of tracing can be read against the untraced runs; the
     # last line takes this mode's from the same readings.

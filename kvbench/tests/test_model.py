@@ -56,11 +56,11 @@ def test_reference_agrees_with_the_engine_at_toy_width(config, tmp_path):
     """The probe of ``correct`` (prefill logits, tokens decoded through the
     paged cache, prefix hit, the other replica) against the plain float32
     forward, with the Pallas kernels interpreted; the fused layout too."""
-    from kvbench import reference
     from kvbench.harness import correct
 
     bench = names.benchmark()
     conf = names.config_for_run(bench, config, True)
+    reference = names.reference(conf)
     cfg, params = fleet.build_model(conf, 2 ** 31 + 11)
     store = tmp_path if conf["kvbench"].get("storage") else None
     fl = fleet.build_fleet(conf, cfg, params, [None, None], store,
@@ -82,13 +82,23 @@ def test_reference_agrees_with_the_engine_at_toy_width(config, tmp_path):
     np.testing.assert_allclose(plain, fused, rtol=1e-5, atol=1e-5)
 
 
-def test_reference_sees_a_wrong_cache():
+LATENT = names.KVBENCH / "tests" / "fixtures" / "latent-toy.json"
+
+
+def toy_conf(config: str) -> dict:
+    """A configuration of the benchmark by name, or the tests' fixture with
+    a latent cache (its own reference and counts), at toy widths."""
+    if config == "latent-toy":
+        return names.as_run(names.load_json(LATENT, "the fixture"), True)
+    return names.config_for_run(names.benchmark(), config, True)
+
+
+@pytest.mark.parametrize("config", ["qwen3-1.7b", "latent-toy"])
+def test_reference_sees_a_wrong_cache(config):
     """Logits of another context are far outside the tolerance: the bound
     is tight enough to tell a wrong page from bf16 rounding."""
-    from kvbench import reference
-
-    bench = names.benchmark()
-    conf = names.config_for_run(bench, "qwen3-1.7b", True)
+    conf = toy_conf(config)
+    reference = names.reference(conf)
     cfg, params = fleet.build_model(conf, 3)
     a = reference.logits_at(params, cfg, list(range(1, 41)), [39])[0]
     b = reference.logits_at(params, cfg, list(range(2, 42)), [39])[0]
@@ -116,11 +126,11 @@ def test_opcount_and_peaks():
 def test_fused_tree_builds_and_matches_the_reference():
     """At hidden 4096 the program's own gate fuses the projections; the
     harness shares that one fused tree and the reference reads it."""
-    from kvbench import reference
     from kvbench.harness import correct
 
     bench = names.benchmark()
     conf = names.config_for_run(bench, "mistral-7b-l16", True)
+    reference = names.reference(conf)
     conf.update(hidden_size=4096, num_hidden_layers=1)
     cfg, params = fleet.build_model(conf, 5)
     assert "w_qkv" in params["layers"][0] and "w_gate_up" in params[
@@ -133,18 +143,25 @@ def test_fused_tree_builds_and_matches_the_reference():
     assert report["ok"], report
 
 
-def test_storage_tier_deployment_at_toy_width(tmp_path):
+@pytest.mark.parametrize("config", ["mistral-7b-l16-store", "latent-toy"])
+def test_storage_tier_deployment_at_toy_width(config, tmp_path):
     """The proposed deployment with the shared-storage tier: the probe's
     second replica is served from what the first wrote through, and the
-    warm-up walks every gather and scatter size of the copier."""
+    warm-up walks every gather and scatter size of the copier. Behind the
+    fixture's latent cache too: the offload spec takes the pool's payload
+    (one stream of one head 96 wide) from the model's config; sized as
+    dense GQA the engine refuses it."""
     from types import SimpleNamespace
 
-    from kvbench import reference
     from kvbench.harness import correct, prepare
 
-    conf = names.as_run(names.load_json(
-        names.KVBENCH / "proposed" / "mistral-7b-l16-store.json", "config"),
-        True)
+    if config == "latent-toy":
+        conf = toy_conf(config)
+        conf["kvbench"]["storage"] = {"io_threads": 2}
+    else:
+        conf = names.as_run(names.load_json(
+            names.KVBENCH / "proposed" / f"{config}.json", "config"), True)
+    reference = names.reference(conf)
     assert conf["kvbench"]["storage"]
     cfg, params = fleet.build_model(conf, 9)
     fl = fleet.build_fleet(conf, cfg, params, [None, None], tmp_path,

@@ -95,6 +95,33 @@ def test_harness_names_no_cell():
                                  text), (path.name, word)
 
 
+def test_harness_and_readers_reach_reference_and_counts_by_name_only():
+    """The plain reference and the plain counts are the defaults of
+    ``names.reference`` / ``names.counts``: nothing else in the command, the
+    harness or the readers imports the one or calls the other's counting
+    functions, so a configuration that names its own is served by its own.
+    (``opcount.peaks`` is the table of the chip, not a count.)"""
+    sources = [names.KVBENCH / "run.py", names.KVBENCH / "sweep.py",
+               *sorted((names.KVBENCH / "harness").glob("*.py")),
+               *sorted((names.KVBENCH / "metrics").glob("*.py"))]
+    assert len(sources) > 25
+    counting = ("prefill_flops|decode_attention_bytes|dense_flops_per_token"
+                "|attention_flops|head_flops|keys_attended")
+    banned = [r"kvbench\.reference\b", r"kvbench\s+import\s+[^\n]*\breference",
+              r"^\s*(from|import)\s+reference\b",
+              rf"opcount\.({counting})\b",
+              rf"trace\.opcount\s+import\s+[^\n]*\b({counting})\b"]
+    for path in sources:
+        text = path.read_text()
+        for pattern in banned:
+            assert not re.search(pattern, text, re.M), (path.name, pattern)
+        if path.parent.name == "metrics":
+            assert "opcount" not in text, path.name
+    # The one way in.
+    text = (names.KVBENCH / "harness" / "names.py").read_text()
+    assert '"reference.py"' in text and '"trace/opcount.py"' in text
+
+
 def test_rehearsal_groups_replace_values():
     doc = {"rate": 8.0, "params": {"a": 1, "b": 2},
            "rehearse": {"rate": 2.0, "params": {"b": 3}}}

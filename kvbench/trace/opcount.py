@@ -1,8 +1,14 @@
-"""Operations and bytes a dense GQA decoder needs, from shapes alone.
+"""Operations and bytes a dense GQA decoder needs, from shapes alone, and
+the table of peaks.
 
 What the algorithm requires, not what a kernel happens to do: padded rows
 and padded tokens do no useful work and are not counted, so a share of a
 peak computed from these can only be lowered by padding, never raised.
+
+These are the counts of a configuration that names none
+(``harness/names.py: counts``): ``prefill_flops`` and
+``decode_attention_bytes`` are what the roofline readers call, and they
+refuse a model they would count wrong.
 """
 
 from __future__ import annotations
@@ -27,6 +33,14 @@ def rehearsal_peaks() -> dict:
     measurement, and its line says ``platform: "cpu"``."""
     table = json.loads((Path(__file__).with_name("peaks.json")).read_text())
     return next(iter(table.values()))
+
+
+def _dense_gqa_only(cfg) -> None:
+    if cfg.num_experts or cfg.is_mla or cfg.is_hybrid or cfg.rope_scaling:
+        raise NotImplementedError(
+            "the plain counts cover dense GQA models with plain RoPE "
+            "and at most a uniform window; a configuration beyond that "
+            "brings its own counts")
 
 
 def dense_flops_per_token(cfg) -> float:
@@ -67,6 +81,7 @@ def attention_flops(cfg, pos: int, n: int) -> float:
 
 def prefill_flops(cfg, pos: int, n: int) -> float:
     """One prefill chunk of ``n`` real tokens after ``pos`` cached ones."""
+    _dense_gqa_only(cfg)
     if n <= 0:
         return 0.0
     return (n * dense_flops_per_token(cfg) + attention_flops(cfg, pos, n)
@@ -76,5 +91,6 @@ def prefill_flops(cfg, pos: int, n: int) -> float:
 def decode_attention_bytes(cfg, keys: int, kv_itemsize: int = 2) -> float:
     """Bytes of K and V one decode step must read for rows that attend
     ``keys`` cached keys in all (already window-capped), over all layers."""
+    _dense_gqa_only(cfg)
     return (2.0 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim
             * kv_itemsize * keys)
