@@ -2,8 +2,9 @@
 
 One function holds the shape the driver reads: exactly the keys ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` only in
-a traced run); every metric the cell lists for the mode, each a finite number
-with its unit; the device as JAX reports it, and in a traced run
+a traced run), and last ``compared``, which the driver does not read: every
+number that decided ``correct`` beside its limit; every metric the cell
+lists for the mode, each a finite number with its unit; the device as JAX reports it, and in a traced run
 ``0 < busy_s <= window_s``. ``run.py`` calls it on its own line; a faulty
 line is never printed.
 """
@@ -18,6 +19,8 @@ DEVICE_KEYS = ("platform", "kind", "count", "memory_peak_bytes")
 TRACE_DEVICE_KEYS = ("busy_s", "window_s")
 BREAKDOWN_KEYS = ("device_ops", "idle_gaps")
 BREAKDOWN_MAX = 10
+# Optional, and the line's last key: name -> [number, limit].
+COMPARED = "compared"
 
 
 class BadLine(ValueError):
@@ -35,7 +38,7 @@ def faults(line: dict, expected: list[dict], traced: bool) -> list[str]:
     bad: list[str] = []
     if not isinstance(line, dict):
         return [f"the line is a {type(line).__name__}, not an object"]
-    allowed = set(KEYS) | ({"breakdown"} if traced else set())
+    allowed = set(KEYS) | {COMPARED} | ({"breakdown"} if traced else set())
     for key in KEYS:
         if key not in line:
             bad.append(f"key {key!r} is missing")
@@ -104,6 +107,15 @@ def faults(line: dict, expected: list[dict], traced: bool) -> list[str]:
         elif not 0 < busy <= window:
             bad.append(f"device.busy_s {busy!r} is not above 0 and at most "
                        f"window_s {window!r}")
+
+    if COMPARED in line:
+        rows = line[COMPARED]
+        if list(line)[-1] != COMPARED:
+            bad.append("compared is not the line's last key")
+        if not isinstance(rows, dict) or not all(
+                isinstance(r, list) and len(r) == 2
+                and all(_is_number(x) for x in r) for r in rows.values()):
+            bad.append("compared is not an object of name: [number, limit]")
 
     if "breakdown" in line:
         bd = line["breakdown"]

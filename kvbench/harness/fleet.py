@@ -184,9 +184,12 @@ class Fleet:
 
 
 def build_fleet(conf: dict, cfg, params, devices, store_root,
-                force_pallas: bool) -> Fleet:
+                force_pallas: bool, traced: bool = False) -> Fleet:
     """One engine per entry of ``devices`` (None = JAX's default device),
-    wired as ``chip_smoke.build_fleet`` wires them."""
+    wired as ``chip_smoke.build_fleet`` wires them. ``traced``: the engines
+    are built with ``EngineConfig.telemetry``, which switches their phases
+    on (``telemetry/tracing.py``: a ``TraceAnnotation`` each, on the
+    profiler's clock); an untraced run builds them without, as before."""
     from llmd_kv_cache_tpu.core import TokenProcessorConfig
     from llmd_kv_cache_tpu.events.model import EventBatch
     from llmd_kv_cache_tpu.events.pool import Pool, PoolConfig
@@ -194,6 +197,8 @@ def build_fleet(conf: dict, cfg, params, devices, store_root,
     from llmd_kv_cache_tpu.offload.spec import SharedStorageOffloadSpec
     from llmd_kv_cache_tpu.scoring import Indexer, IndexerConfig
     from llmd_kv_cache_tpu.scoring.router import KVAwareRouter
+    from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
+        EngineTelemetryConfig)
 
     kv = conf["kvbench"]
     ecfg = kv["engine"]
@@ -234,7 +239,9 @@ def build_fleet(conf: dict, cfg, params, devices, store_root,
                          max_pages_per_seq=int(ecfg["max_pages_per_seq"]),
                          max_batch=int(ecfg["max_batch"]),
                          max_prefill_tokens=int(ecfg["max_prefill_tokens"]),
-                         use_pallas_decode=pallas, use_pallas_prefill=pallas),
+                         use_pallas_decode=pallas, use_pallas_prefill=pallas,
+                         telemetry=(EngineTelemetryConfig() if traced
+                                    else None)),
             event_sink=sink, params=params, offload_spec=spec, device=dev)
     fleet.indexer, fleet.pool = indexer, pool
     fleet.router = KVAwareRouter(indexer, list(fleet.engines))

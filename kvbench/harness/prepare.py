@@ -76,8 +76,10 @@ def warm_up(ctx) -> None:
 
 
 def prepare(cell: dict, conf: dict, traffic: dict, schedule_fn, seed: int,
-            seconds: float, toy: bool, t_process: float):
-    """Build, probe and warm the system for one cell. Returns a namespace
+            seconds: float, toy: bool, t_process: float,
+            traced: bool = False):
+    """Build, probe and warm the system for one cell (``traced``: with the
+    engines' phases on, ``fleet.build_fleet``). Returns a namespace
     with ``fleet``, ``params``, ``stats``, ``phases``, ``schedule``,
     ``probe``, ``device``, ``devices``, ``served_faults``, the
     configuration's ``reference`` and ``counts`` modules and ``close()``."""
@@ -145,7 +147,8 @@ def prepare(cell: dict, conf: dict, traffic: dict, schedule_fn, seed: int,
         with phase(ctx, "fleet"):
             ctx.fleet = fleet_mod.build_fleet(conf, ctx.cfg, ctx.params,
                                               devices, ctx.store_root,
-                                              force_pallas=toy)
+                                              force_pallas=toy,
+                                              traced=traced)
             ctx.served_faults = fleet_mod.what_serves(
                 ctx.fleet, interpret=not on_tpu)
         with phase(ctx, "probe"):

@@ -39,6 +39,20 @@ def sampled_seconds(run) -> float:
     return run.t_end - run.t_sample
 
 
+def phase_events(run, name: str) -> list:
+    """The events of one host span in the traced slice, in start order:
+    each has ``start`` and ``dur`` in ns on the trace's clock and ``stats``,
+    its attributes. For an engine phase (``telemetry/tracing.py:
+    PHASE_NAMES``; on in a traced run) those are ``pod``, ``step`` (the
+    ordinal of the ``step()`` it ran in) and whatever the phase carries:
+    ``step.finish`` the step's ``programs`` and ``transfers``,
+    ``step.dispatch`` its ``rows``, ``step.emit`` its ``events``. Nothing
+    where the run was not traced or the trace holds no such event."""
+    if run.trace is None:
+        return []
+    return list(run.trace.events.get(name, []))
+
+
 def module_events(run, pattern: str) -> list:
     """Device events of the programs whose name matches, over the cell's
     chips (the trace's modules line)."""

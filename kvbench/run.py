@@ -26,6 +26,7 @@ import contextlib  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import logging  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -38,10 +39,27 @@ from kvbench.harness import names  # noqa: E402
 from kvbench.harness.check_line import BadLine, check_line  # noqa: E402
 from kvbench.harness.fleet import log, memory_peak_bytes  # noqa: E402
 
-# Harness spans, in the order in which one takes an idle gap's time where
-# several cover it (inner before outer, a replica's before the generator's).
-SPANS = ["ingest", "enqueue", "route", "step", "restore.wait",
-         "replica.idle", "generator.sleep"]
+# Host spans, in the order in which one takes an idle gap's time where
+# several cover it: inner before outer, work before wait (a gap in which
+# one replica builds inputs while the other waits in ``step.fetch`` goes to
+# the one working), a replica's before the generator's. The dotted names
+# are the engine's phases (``telemetry/tracing.py: PHASE_NAMES``), which a
+# traced run switches on; the outer spans keep what no phase covers.
+SPANS = ["ingest", "step.emit", "step.commit", "step.inputs", "step.dispatch",
+         "step.sample", "step.schedule", "step.offload_poll", "step.finish",
+         "enqueue.hash", "enqueue.lookup", "enqueue.admit", "step.fetch",
+         "enqueue", "route", "step", "restore.wait", "replica.idle",
+         "generator.sleep"]
+
+
+def spans_of(program_phases) -> list:
+    """``SPANS`` with every phase the program names that ``SPANS`` does not
+    (a later PR's) among the work phases, before the waits: such a phase
+    reaches the readers and the breakdown with no edit here."""
+    new = [n for n in program_phases if n not in SPANS]
+    at = SPANS.index("step.fetch")
+    return SPANS[:at] + new + SPANS[at:]
+
 
 # The check stops a run that is still going after CHECK_LIMIT_S; a run
 # that passes WARN_S says so on stderr (README, "The time budget").
@@ -112,7 +130,9 @@ def measure(ctx, cell, traffic, seconds, traced, after, keep_trace=""):
     from kvbench.harness import loop
     from kvbench.harness.prepare import programs_first_used
     from kvbench.trace import opcount, reduce as trace_reduce
+    from llmd_kv_cache_tpu.telemetry.tracing import PHASE_NAMES
 
+    spans = spans_of(PHASE_NAMES)
     at = None
     if traced:
         at = (1.0 / 3.0, float(traffic["trace_seconds"]),
@@ -130,9 +150,9 @@ def measure(ctx, cell, traffic, seconds, traced, after, keep_trace=""):
     if traced:
         run.trace_bytes = len(ctx.xspace)
         with stage(after, "load"):
-            planes = trace_reduce.load(ctx.xspace, SPANS)
+            planes = trace_reduce.load(ctx.xspace, spans)
         with stage(after, "reduce"):
-            run.trace = trace_reduce.reduce(planes, int(cell["chips"]), SPANS)
+            run.trace = trace_reduce.reduce(planes, int(cell["chips"]), spans)
         log(f"trace: {run.trace_bytes / 2**20:.1f} MiB reduced "
                   f"in {after['load'] + after['reduce']:.1f}s; window "
                   f"{run.trace.window_s:.3f}s busy {run.trace.busy_s:.3f}s "
@@ -160,11 +180,37 @@ def correctness(ctx, run) -> list:
     return faults
 
 
+def compared(ctx, run) -> dict:
+    """name -> [number, limit]: every number ``correctness`` compares,
+    beside its limit, for the line's last key and the last lines on stderr
+    (what the driver's record keeps of a run that is not correct)."""
+    from kvbench.harness.correct import MAX_ALTERNATIVES
+
+    probe = ctx.probe
+    tol = probe["tolerance"]
+    bad_tokens = sum(1 for r in run.requests
+                     if r.done and not r.failed and not r.tokens_ok)
+    out = {"prefill_err": (probe["prefill_rel_err"], tol),
+           "decode_shortfall": (probe["decode_worst_shortfall"], tol),
+           "hit_err": (probe["hit_rel_err"], tol),
+           "replica_err": (probe["other_replicas_rel_err"], tol),
+           "alternatives_max": (max(probe["alternatives"]),
+                                MAX_ALTERNATIVES),
+           "probe_faults": (len(probe["faults"]), 0),
+           "bad_token_requests": (bad_tokens, 0),
+           "serving_faults": (len(ctx.served_faults) + len(run.errors), 0),
+           "programs_first_used_in_window": (run.compiles_in_window, 0)}
+    # A number that is not one (NaN logits) shows as a probe fault.
+    return {k: [float(v), float(lim)] for k, (v, lim) in out.items()
+            if math.isfinite(v)}
+
+
 def breakdown(run, after) -> dict:
     with stage(after, "op_seconds"):
         ops = sorted(run.trace.op_seconds().items(), key=lambda kv: -kv[1])
     with stage(after, "idle_by_span"):
-        idle = sorted(run.trace.idle_by_span(SPANS).items(),
+        # ``spans`` holds the names in the order they were reduced in.
+        idle = sorted(run.trace.idle_by_span(list(run.trace.spans)).items(),
                       key=lambda kv: -kv[1])
     return {"device_ops": [[n, s] for n, s in ops[:10]],
             "idle_gaps": [[n, s] for n, s in idle[:10]]}
@@ -211,6 +257,7 @@ def after_the_window(run, after, traced) -> dict:
             "gaps": sum(len(trace_reduce.gaps(tr.busy[p], tr.window))
                         for p in tr.planes),
             "span_intervals": {n: len(ivs) for n, ivs in tr.spans.items()},
+            "span_events": {n: len(evs) for n, evs in tr.events.items()},
             "step.work": len(tr.work)}
     return out
 
@@ -253,9 +300,15 @@ def gap_summary(run) -> dict:
 
 
 def say_exit(t_line: list) -> None:
+    """``t_line``: when the last line was printed, and what was compared.
+    The numbers beside their limits are the last lines on stderr."""
     if t_line:
         print(f"[kvbench] exit: {time.perf_counter() - t_line[0]:.1f}s "
-              f"after the last line", file=sys.stderr, flush=True)
+              f"after the last line", file=sys.stderr)
+        for name, (value, limit) in t_line[1].items():
+            print(f"[kvbench] compared: {name} {value!r} limit {limit!r}",
+                  file=sys.stderr)
+        sys.stderr.flush()
 
 
 def main(argv=None, bench=None) -> int:
@@ -288,7 +341,7 @@ def main(argv=None, bench=None) -> int:
     gen = names.generator(traffic["generator"])
 
     ctx = prepare(cell, conf, traffic, gen.schedule, args.seed, args.seconds,
-                  toy, T_PROCESS)
+                  toy, T_PROCESS, traced)
     after: dict = {}
     pauses = GcPauses()
     gc.callbacks.append(pauses)
@@ -328,6 +381,7 @@ def main(argv=None, bench=None) -> int:
             longest = run.trace.longest_gaps()
         log(f"longest idle gaps (s, plane): "
                   f"{[(round(s, 4), p) for s, p, _ in longest]}")
+    line["compared"] = compared(ctx, run)
     try:
         with stage(after, "check_line"):
             text = check_line(line, expected, traced)
@@ -347,7 +401,7 @@ def main(argv=None, bench=None) -> int:
               file=sys.stderr, flush=True)
     sys.stdout.flush()
     print(text, flush=True)
-    t_line.append(time.perf_counter())
+    t_line += [time.perf_counter(), line["compared"]]
     return 0
 
 

@@ -1,4 +1,10 @@
-"""The probe that decides ``correct``, run in set-up outside the window.
+"""``harness/correct.py`` as it stood at PR 29 (commit 5ef9041), kept word for
+word but for its two imports: the probe that compares ONE answer of the
+reference. ``test_routed_probe.py`` holds today's probe to it: equal to the
+last bit where a reference admits one answer, and failing a built near-tie
+that today's passes.
+
+The probe that decides ``correct``, run in set-up outside the window.
 
 (a) One seeded prompt: the engine's last-position logits after prefill must
 agree with the plain reference; then the engine decodes further tokens
@@ -11,22 +17,14 @@ equality). (b) The same prompt again on the first replica, which now serves
 it as a prefix hit, and on every other replica, where it is cold or, with a
 shared storage tier, restored from what the first wrote through: the same
 bound.
-
-A reference may admit more than one answer at a position
-(``alternatives_at``: a model that routes picks other experts where two
-scores lie closer than the rounding of what they are computed from, and
-both choices are right). Every comparison then takes, for its position, the
-alternative nearest to what the program gave, each scaled by its own
-largest logit. A reference without ``alternatives_at`` admits one answer a
-position, and every number is what it was before there were alternatives.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from .fleet import Fleet, log
-from .loop import now
+from kvbench.harness.fleet import Fleet, log
+from kvbench.harness.loop import now
 
 
 def _run(eng, rid: str, prompt, max_new: int):
@@ -40,38 +38,6 @@ def _run(eng, rid: str, prompt, max_new: int):
         if now() > deadline:
             raise TimeoutError(f"probe {rid} is stuck")
     return req, logits
-
-
-# A reference that admits more answers than this at one position admits
-# too much to decide anything: a fault of its own.
-MAX_ALTERNATIVES = 8
-
-
-def alternatives(reference, params, cfg, tokens, positions) -> list:
-    """One float32 array ``[A_i, vocab]`` per position: the reference's
-    ``alternatives_at`` where it has one, else ``logits_at``'s one row."""
-    if hasattr(reference, "alternatives_at"):
-        return [np.asarray(a, np.float32) for a in
-                reference.alternatives_at(params, cfg, tokens, positions)]
-    ref = reference.logits_at(params, cfg, tokens, positions)
-    return [ref[i:i + 1] for i in range(len(positions))]
-
-
-def nearest(alts, got) -> tuple:
-    """``(index, error)`` of the alternative nearest to the logits ``got``:
-    the largest difference over the alternative's own largest logit."""
-    errs = [float(np.abs(got - a).max() / float(np.abs(a).max()))
-            for a in alts]
-    best = int(np.nanargmin(errs)) if np.isfinite(errs).any() else 0
-    return best, errs[best]
-
-
-def least_short(alts, token: int) -> tuple:
-    """``(index, shortfall)`` of the alternative under which ``token`` lies
-    least below the best, over that alternative's largest logit."""
-    short = [float((a.max() - a[token]) / np.abs(a).max()) for a in alts]
-    best = int(np.argmin(short))
-    return best, short[best]
 
 
 def probe(fleet: Fleet, params, reference, seed: int, prompt_len: int,
@@ -92,37 +58,30 @@ def probe(fleet: Fleet, params, reference, seed: int, prompt_len: int,
                       f"{req.cached_len}")
     out = list(req.output)
     positions = [prompt_len - 1 + i for i in range(decode_tokens + 1)]
-    ref = alternatives(reference, params, cfg, prompt + out[:decode_tokens],
-                       positions)
-    report = {"tolerance": tol, "alternatives": [len(a) for a in ref]}
-    if max(report["alternatives"]) > MAX_ALTERNATIVES:
-        faults.append(f"the reference admits {max(report['alternatives'])} "
-                      f"answers at one position, over {MAX_ALTERNATIVES}")
-    chosen = report["chosen"] = {}
+    ref = reference.logits_at(params, cfg, prompt + out[:decode_tokens],
+                              positions)
+    scale = float(np.abs(ref[0]).max())
 
-    def rel(got, what) -> float:
-        chosen[what], err = nearest(ref[0], got)
-        return err
+    def rel(got) -> float:
+        return float(np.abs(got - ref[0]).max() / scale)
 
-    report["prefill_rel_err"] = rel(logits, "prefill")
+    report = {"tolerance": tol, "prefill_rel_err": rel(logits)}
     if not np.isfinite(logits).all() or report["prefill_rel_err"] > tol:
         faults.append(f"prefill logits differ from the reference by "
                       f"{report['prefill_rel_err']:.3e} of its largest")
     # Token i of the output was chosen from the logits at positions[i].
-    took = [least_short(ref[i], out[i]) for i in range(decode_tokens + 1)]
-    chosen["tokens"] = [i for i, _ in took]
-    short = [s for _, s in took]
+    short = [float((ref[i].max() - ref[i][out[i]]) / np.abs(ref[i]).max())
+             for i in range(decode_tokens + 1)]
     report["decode_worst_shortfall"] = max(short[1:], default=0.0)
     report["decode_tokens_equal"] = sum(
-        int(np.argmax(ref[i][took[i][0]])) == out[i]
-        for i in range(1, len(out)))
+        int(np.argmax(ref[i])) == out[i] for i in range(1, len(out)))
     if max(short) > tol:
         faults.append(f"a token decoded through the cache is {max(short):.3e}"
                       f" of the reference's largest logit below its best")
 
     req, logits = _run(first, "probe-hit", prompt, 1)
     report["hit_cached_len"] = req.cached_len
-    report["hit_rel_err"] = rel(logits, "hit")
+    report["hit_rel_err"] = rel(logits)
     if req.cached_len < prompt_len - cfg.page_size:
         faults.append(f"the repeated probe was admitted with cached_len "
                       f"{req.cached_len}, not as a prefix hit")
@@ -135,7 +94,7 @@ def probe(fleet: Fleet, params, reference, seed: int, prompt_len: int,
     report["other_replicas_cached_len"] = []
     for pod in pods[1:]:
         req, logits = _run(fleet.engines[pod], f"probe-{pod}", prompt, 1)
-        worst = max(worst, rel(logits, pod))
+        worst = max(worst, rel(logits))
         report["other_replicas_cached_len"].append(req.cached_len)
     report["other_replicas_rel_err"] = worst
     if worst > tol:

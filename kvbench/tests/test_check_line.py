@@ -93,3 +93,20 @@ def test_each_fault_is_refused(traced, change, word):
     assert found and any(word in f for f in found), found
     with pytest.raises(BadLine):
         check_line(line, expected, traced)
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_compared_is_optional_last_and_pairs_of_numbers(traced):
+    """The numbers that decided ``correct`` beside their limits: a key of
+    its own that comes last; the driver ignores it."""
+    expected = LAYER if traced else E2E
+    line = good(traced)
+    line["compared"] = {"prefill_err": [0.021, 0.05], "probe_faults": [0, 0]}
+    assert '"compared"' in check_line(line, expected, traced)
+    first = {"compared": line["compared"], **good(traced)}
+    assert "last key" in "; ".join(faults(first, expected, traced))
+    for wrong in ({"prefill_err": 0.021}, {"prefill_err": [0.021]},
+                  {"prefill_err": [math.nan, 0.05]}, [0.021, 0.05]):
+        line["compared"] = wrong
+        assert "name: [number, limit]" in "; ".join(
+            faults(line, expected, traced))

@@ -6,6 +6,7 @@ from pathlib import Path
 
 import pytest
 
+from kvbench.harness import names
 from kvbench.trace import reduce as R
 
 FIXTURE = Path(__file__).with_name("fixture.xplane.pb")
@@ -383,3 +384,130 @@ def test_threads_of_one_name_keep_their_lines(tmp_path):
     assert all(red.spans[n] for n in ("step", "route", "ingest"))
     assert len(red.work) == 9
     assert int(red.work[0]["decode_rows"]) == 1
+
+
+# -- the engine's phases -------------------------------------------------------
+
+
+def phase(name, start, dur, pod="pod-0", step=1, **stats):
+    return ev(name, start, dur, pod=pod, step=step, **stats)
+
+
+def phased_run(*events, names=None):
+    """A run whose trace holds one device op and the given host events."""
+    from kvbench.harness.loop import Run
+    from kvbench.run import SPANS as RUN_SPANS
+
+    run = Run(seconds=1.0)
+    run.trace = R.reduce(
+        [device(0, [ev("fusion.1", 0, 10)]), host(*events)], 1,
+        names or RUN_SPANS)
+    return run
+
+
+def test_run_spans_hold_every_phase_inner_before_outer_work_before_wait():
+    from kvbench import run as run_py
+    from llmd_kv_cache_tpu.telemetry.tracing import PHASE_NAMES
+
+    spans = run_py.SPANS
+    assert set(PHASE_NAMES) <= set(spans) and len(set(spans)) == len(spans)
+    assert run_py.spans_of(PHASE_NAMES) == spans
+    at = spans.index
+    assert at("step.emit") < at("step.commit") < at("step.fetch") < at("step")
+    assert at("enqueue.lookup") < at("enqueue.admit") < at("enqueue")
+    assert at("ingest") < at("step.emit")      # the sink runs inside emit
+    # A phase a later PR's program names goes among the work, before the
+    # waits, with no edit.
+    later = run_py.spans_of((*PHASE_NAMES, "step.experts"))
+    assert later.index("step.finish") < later.index("step.experts") < (
+        later.index("step.fetch"))
+    assert [n for n in later if n != "step.experts"] == spans
+
+
+def test_phase_events_keep_their_attributes_in_start_order():
+    from kvbench.metrics import _read
+
+    run = phased_run(
+        phase("step.finish", 90, 5, programs=4, transfers=6),
+        phase("step.finish", 40, 5, step=0, programs=1, transfers=3),
+        phase("step.dispatch", 20, 10, rows=2, programs=1),
+        ev("step", 0, 100, pod="pod-0"),
+        ev("step.work", 99, 0, pod="pod-0", decode_rows=2))
+    got = _read.phase_events(run, "step.finish")
+    assert [(e.start, e.dur, e.stats["programs"], e.stats["transfers"],
+             e.stats["step"]) for e in got] == [(40, 5, 1, 3, 0),
+                                                (90, 5, 4, 6, 1)]
+    assert _read.phase_events(run, "step.dispatch")[0].stats["rows"] == 2
+    assert [e.dur for e in _read.phase_events(run, "step")] == [100]
+    assert _read.phase_events(run, "step.commit") == []
+    # The work marker is handed on as before, not as an event.
+    assert _read.phase_events(run, "step.work") == []
+    assert run.trace.work == [{"pod": "pod-0", "decode_rows": 2}]
+    run.trace = None
+    assert _read.phase_events(run, "step.finish") == []
+
+
+def test_idle_goes_to_the_phase_before_the_step_around_it():
+    """Work before wait: while one replica builds inputs and the other
+    waits in its fetch, the gap is the inputs'."""
+    from kvbench.run import SPANS as RUN_SPANS
+
+    events = [ev("step", 10, 90, pod="pod-0"), ev("step", 10, 90, pod="pod-1"),
+              phase("step.inputs", 10, 30),
+              phase("step.fetch", 10, 80, pod="pod-1"),
+              phase("step.fetch", 40, 50)]
+    red = R.reduce([device(0, [ev("fusion.1", 0, 10), ev("fusion.2", 95, 5)]),
+                    host(*events)], 1, RUN_SPANS)
+    idle = red.idle_by_span(list(red.spans))
+    assert idle == pytest.approx({"step.inputs": 30e-9, "step.fetch": 50e-9,
+                                  "step": 5e-9})
+
+
+def test_step_host_ms_is_the_union_of_a_steps_phases_without_the_fetch():
+    reader = names.metric("step_host_ms_p50")
+    ms = 1e6
+    one = [phase("step.offload_poll", 0 * ms, 0.1 * ms),
+           phase("step.schedule", 0.1 * ms, 0.1 * ms),
+           phase("step.inputs", 0.2 * ms, 1.0 * ms),
+           phase("step.dispatch", 1.2 * ms, 0.5 * ms, programs=1),
+           phase("step.sample", 1.7 * ms, 1.0 * ms, programs=1),
+           phase("step.fetch", 2.7 * ms, 6.0 * ms),
+           phase("step.commit", 8.7 * ms, 1.0 * ms),
+           phase("step.emit", 8.9 * ms, 0.5 * ms, events=3),   # nested
+           phase("step.finish", 9.7 * ms, 0.3 * ms, programs=2)]
+    # An eviction's emit inside enqueue() carries the last step's ordinal
+    # and lies outside the step; a step the slice cut has no finish.
+    stray = [phase("step.emit", 12 * ms, 2 * ms, events=1),
+             phase("enqueue.lookup", 11 * ms, 4 * ms),
+             phase("step.offload_poll", 20 * ms, 0.1 * ms, step=2),
+             phase("step.inputs", 20.1 * ms, 3 * ms, step=2)]
+    other = [phase("step.offload_poll", 1 * ms, 0.5 * ms, pod="pod-1"),
+             phase("step.fetch", 1.5 * ms, 3 * ms, pod="pod-1"),
+             phase("step.finish", 4.5 * ms, 0.5 * ms, pod="pod-1")]
+    run = phased_run(*one, *stray, *other)
+    assert sorted(reader.step_host_ms(run)) == pytest.approx([1.0, 4.0])
+    assert reader.compute(run) == pytest.approx(2.5)
+    assert (reader.SOURCE, reader.UNIT, reader.MOVES) == (
+        "program_span", "ms", "itl_mean_ms")
+
+
+@pytest.mark.skipif(not FIXTURE.is_file(), reason="no recorded fixture")
+def test_recorded_trace_holds_no_phase_and_the_reader_finds_nothing():
+    """Recorded before a traced run switched the phases on: the accessor
+    returns nothing and the reader, finding nothing, returns None."""
+    from kvbench.harness.loop import Run
+    from kvbench.metrics import _read
+    from kvbench.run import SPANS as RUN_SPANS
+    from llmd_kv_cache_tpu.telemetry.tracing import PHASE_NAMES
+
+    run = Run(seconds=1.0)
+    run.trace = R.reduce(R.load(str(FIXTURE), RUN_SPANS), 1, RUN_SPANS)
+    for name in PHASE_NAMES:
+        assert _read.phase_events(run, name) == []
+        assert run.trace.spans[name] == []
+    assert len(_read.phase_events(run, "step")) == len(run.trace.work) > 0
+    assert names.metric("step_host_ms_p50").compute(run) is None
+    # What the old names read is what they read before.
+    old = R.reduce(R.load(str(FIXTURE), SPANS), 1, SPANS)
+    assert old.window == run.trace.window and old.busy == run.trace.busy
+    assert old.idle_by_span(SPANS) == run.trace.idle_by_span(RUN_SPANS)

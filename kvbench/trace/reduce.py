@@ -10,8 +10,11 @@ The rules, each pinned by a test on a recorded or synthetic plane:
   each plane's union, never their sum.
 - The window is the span of the trace's own timestamps (device ops and the
   harness's host spans), never the host's clock.
-- An idle gap is attributed to the harness spans that covered it, by
-  overlap.
+- An idle gap is attributed to the host spans that covered it (the
+  harness's, and in a traced run the engine's phases), by overlap.
+- Every kept host event that is not the work marker is also handed on whole
+  (``Reduced.events``: name, start, duration, attributes), so that a reader
+  reaches a counter that rides a phase.
 
 ``load`` turns a file into plain :class:`Plane` objects; everything else
 works on those, so a synthetic plane is three lists.
@@ -202,6 +205,9 @@ class Reduced:
     modules: dict                      # plane -> [Event]
     spans: dict                        # span name -> disjoint intervals
     work: list                         # stats of the work markers inside
+    # span name -> its host events in start order, attributes in ``stats``
+    # (an engine phase's: ``pod``, ``step`` and what the phase carries).
+    events: dict = field(default_factory=dict)
 
     @property
     def window_s(self) -> float:
@@ -298,15 +304,20 @@ def reduce(planes: list, chips: int, span_names: Iterable[str]) -> Reduced:
                                    for (prog, _), (lo, hi) in runs.items()]
     names = list(ops)
 
-    raw_spans: dict = {n: [] for n in span_names}
+    kept = set(span_names)
+    events: dict = {}
     work = []
     for pl in host:
         for line in pl.lines.values():
             for e in line:
                 if e.name == WORK_MARKER:
                     work.append((e.start, e.stats))
-                elif e.name in raw_spans:
-                    raw_spans[e.name].append((e.start, e.end))
+                elif e.name in kept:
+                    events.setdefault(e.name, []).append(e)
+    for evs in events.values():
+        evs.sort(key=lambda e: e.start)
+    raw_spans = {n: [(e.start, e.end) for e in events.get(n, [])]
+                 for n in span_names}
 
     marks = [t for p in names for e in ops[p] for t in (e.start, e.end)]
     marks += [t for ivs in raw_spans.values() for iv in ivs for t in iv]
@@ -320,7 +331,7 @@ def reduce(planes: list, chips: int, span_names: Iterable[str]) -> Reduced:
     inside = [s for t, s in sorted(work, key=lambda w: w[0])
               if window[0] <= t <= window[1]]
     return Reduced(window=window, planes=names, busy=busy, ops=ops,
-                   modules=modules, spans=spans, work=inside)
+                   modules=modules, spans=spans, work=inside, events=events)
 
 
 def find_xplane(trace_dir: str) -> str:
