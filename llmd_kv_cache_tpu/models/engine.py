@@ -63,7 +63,6 @@ from ..telemetry.tracing import (
     PHASE_STEP_FINISH,
     PHASE_STEP_INPUTS,
     PHASE_STEP_OFFLOAD_POLL,
-    PHASE_STEP_SAMPLE,
     PHASE_STEP_SCHEDULE,
     NOOP_SPAN,
     SPAN_ENGINE_DECODE_STEP,
@@ -74,16 +73,18 @@ from ..telemetry.tracing import (
 from ..utils.logging import get_logger
 from .llama import (
     LlamaConfig,
-    forward,
-    forward_decode_pallas,
-    forward_decode_steps,
-    forward_decode_steps_hybrid,
-    forward_hybrid,
-    forward_prefill_pallas,
-    forward_ragged,
     init_kv_cache,
     init_kv_cache_hybrid,
     init_params,
+    pack_inputs,
+    step_decode_pallas,
+    step_decode_steps,
+    step_decode_steps_hybrid,
+    step_forward,
+    step_forward_hybrid,
+    step_prefill_pallas,
+    step_program,
+    step_ragged,
 )
 
 logger = get_logger("models.engine")
@@ -262,7 +263,9 @@ class Request:
     block_hashes: list[int] = field(default_factory=list)  # hash-chained, per full block
     cached_len: int = 0  # tokens skipped via prefix cache at admission
     computed_len: int = 0  # tokens with KV resident (cached + prefilled + decoded)
-    last_logits: Optional[np.ndarray] = None
+    # The last prompt position's float32 logits ``[vocab]``, left on the
+    # device by the prefill's last chunk (``np.asarray`` fetches them).
+    last_logits: Any = None
     done: bool = False
     # Continuous batching: next prompt index to prefill, or None once the
     # request is decoding. ``enqueue`` admits with this set; ``step``
@@ -290,10 +293,6 @@ class Request:
     # were never committed, and release()ing unknown hashes would silently
     # leak their pages.
     committed_blocks: int = 0
-    # Device-resident page table, cached across prefill chunks (pages are
-    # fixed from admission until commit; each upload is a host→device
-    # round trip). Cleared at prefill finish.
-    table_dev: Any = None
     # Decode-side handoff wait (enqueue(handoff=True) on a decode-role
     # engine): monotonic deadline until which step() holds this request's
     # local prefill, polling the transfer tier for the prefill peer's
@@ -880,12 +879,12 @@ class MiniEngine:
             if pallas_mesh is not None:
                 rows = 1  # sharded path keeps one row per program
             self._decode_forward = functools.partial(
-                forward_decode_pallas, interpret=interpret,
+                step_decode_pallas, interpret=interpret,
                 mesh=pallas_mesh, batch_rows=rows,
             )
         else:
             pallas_mesh = None
-            self._decode_forward = forward
+            self._decode_forward = step_forward
         # Prefill backend is independent of decode: auto (None) follows
         # the Pallas backend's platform/head-dim gating — the flash
         # kernel measured 1.9× faster than XLA attention at production
@@ -908,7 +907,7 @@ class MiniEngine:
             prefill_pallas = False
         if prefill_pallas and use_pallas:
             self._prefill_forward = functools.partial(
-                forward_prefill_pallas, interpret=interpret, mesh=pallas_mesh
+                step_prefill_pallas, interpret=interpret, mesh=pallas_mesh
             )
         else:
             if self.cfg.use_pallas_prefill and not use_pallas:
@@ -916,47 +915,40 @@ class MiniEngine:
                     "use_pallas_prefill=True ignored: the Pallas backend is "
                     "inactive (platform/head-dim/hybrid gating above); using "
                     "XLA prefill")
-            self._prefill_forward = forward
+            self._prefill_forward = step_forward
+        if self.hybrid:
+            # Single-token hybrid steps and hybrid prefill run the XLA
+            # grouped forward over both pools.
+            self._decode_forward = self._prefill_forward = step_forward_hybrid
         self._decode_multi = functools.partial(
-            forward_decode_steps, use_pallas=use_pallas,
+            step_decode_steps, use_pallas=use_pallas,
             interpret=use_pallas and interpret, mesh=pallas_mesh,
             batch_rows=rows if use_pallas else 1,
         )
-        hybrid_mesh = (mesh if hybrid_burst_pallas and self._tp > 1
-                       else None)
-        self._decode_multi_hybrid = functools.partial(
-            forward_decode_steps_hybrid, use_pallas=hybrid_burst_pallas,
-            interpret=hybrid_burst_pallas and interpret,
-            mesh=hybrid_mesh,
-            batch_rows=(rows if hybrid_burst_pallas and hybrid_mesh is None
-                        else 1),
-        )
+        if self.hybrid:
+            hybrid_mesh = (mesh if hybrid_burst_pallas and self._tp > 1
+                           else None)
+            self._decode_multi = functools.partial(
+                step_decode_steps_hybrid, use_pallas=hybrid_burst_pallas,
+                interpret=hybrid_burst_pallas and interpret,
+                mesh=hybrid_mesh,
+                batch_rows=(rows if hybrid_burst_pallas
+                            and hybrid_mesh is None else 1),
+            )
         if self._pp > 1:
             from ..parallel.pp_serve import make_pp_serve_forward
 
             # Prefill runs per request (batch 1 → the sequential M=1
             # schedule); decode pads to max_batch and streams pp
             # microbatches through the stages.
-            pp_prefill_fn = make_pp_serve_forward(mesh, mcfg, self.params,
-                                                  microbatches=1)
-            pp_decode_fn = (pp_prefill_fn if self._pp_decode_mb == 1
-                            else make_pp_serve_forward(
-                                mesh, mcfg, self.params,
-                                microbatches=self._pp_decode_mb))
-
-            def pp_prefill(params, _cfg, tokens, k, v, table, ctx, new,
-                           last_only=True):
-                logits, k, v = pp_prefill_fn(params, k, v, tokens, table,
-                                             ctx, new)
-                return logits[:, None, :], k, v
-
-            def pp_decode(params, _cfg, tokens, k, v, tables, ctx, new):
-                logits, k, v = pp_decode_fn(params, k, v, tokens, tables,
-                                            ctx, new)
-                return logits[:, None, :], k, v
-
-            self._prefill_forward = pp_prefill
-            self._decode_forward = pp_decode
+            self._prefill_forward = step_program(
+                make_pp_serve_forward(mesh, mcfg, self.params,
+                                      microbatches=1), ("last_only",))
+            self._decode_forward = (
+                self._prefill_forward if self._pp_decode_mb == 1
+                else step_program(make_pp_serve_forward(
+                    mesh, mcfg, self.params,
+                    microbatches=self._pp_decode_mb)))
             if self.cfg.decode_burst > 1:
                 logger.warning("pp serving v1 decodes single-token; "
                                "decode_burst=%d clamped to 1",
@@ -1184,6 +1176,18 @@ class MiniEngine:
             ph.bytes += x.nbytes
         return jax.device_put(x, self._device)
 
+    def _pools(self) -> tuple:
+        """The page pools a step program is handed (and donated)."""
+        if self.hybrid:
+            return (self.k_cache, self.v_cache, self.k_swa, self.v_swa)
+        return (self.k_cache, self.v_cache)
+
+    def _take_pools(self, pools: tuple) -> None:
+        """The pools a step program handed back, in ``_pools``'s order."""
+        self.k_cache, self.v_cache = pools[:2]
+        if self.hybrid:
+            self.k_swa, self.v_swa = pools[2:]
+
     # -- admission --
 
     def attach_handoff(self, coordinator) -> None:
@@ -1253,17 +1257,16 @@ class MiniEngine:
         and run the prefill step for the uncached suffix (synchronously —
         the request returns ready to decode)."""
         req = self._admit(request_id, prompt, max_new_tokens)
-        self._prefill(req)
-        self._finish_prefill(req)
+        self._finish_prefill(req, self._prefill(req))
         return req
 
     def _dispatch_phase(self, req: Optional[Request], rows: int,
                         tokens: int, padded: int):
-        """The ``step.dispatch`` phase of one jitted call: the transfers of
-        its arguments and the call returning, with the sizes that explain
-        its length. ``req`` is the request whose prefill chunk rides it
-        (None for a pure decode program): its ``traceparent`` makes the
-        phase the trace's ``engine.prefill_chunk`` span."""
+        """The ``step.dispatch`` phase of one jitted call: the transfer of
+        its packed inputs and the call returning, with the sizes that
+        explain its length. ``req`` is the request whose prefill chunk
+        rides it (None for a pure decode program): its ``traceparent``
+        makes the phase the trace's ``engine.prefill_chunk`` span."""
         ph = self._phases
         traceparent = None if req is None else req.traceparent
         if ph is None and traceparent is None:
@@ -1508,18 +1511,17 @@ class MiniEngine:
             self.telemetry.on_admitted(
                 request_id, req.cached_len // page_size)
 
-    def _finish_prefill(self, req: Request) -> None:
+    def _finish_prefill(self, req: Request, first_token: int) -> None:
         """Prefill done: register the prompt's full blocks in the prefix
-        cache and bootstrap decoding with the first generated token (from
-        the prefill step's final logits — vLLM semantics: even a
-        full-prefix hit recomputes the last prompt token for logits)."""
+        cache and bootstrap decoding with the first generated token (the
+        last chunk's program sampled it from its final logits — vLLM
+        semantics: even a full-prefix hit recomputes the last prompt token
+        for logits)."""
         before = req.committed_blocks
         with phase(self._phases, PHASE_STEP_COMMIT) as sp:
-            req.table_dev = None  # pages may swap to canonical at commit
             self._commit_full_blocks(req)
             sp.set_attribute("request_id", req.request_id)
             sp.set_attribute("blocks", req.committed_blocks - before)
-            first_token = int(np.argmax(req.last_logits))
             req.output.append(first_token)
             if self.telemetry is not None:
                 self.telemetry.on_first_token(req.request_id)
@@ -1798,7 +1800,6 @@ class MiniEngine:
         req.committed_blocks = max(req.committed_blocks,
                                    first_missing + len(canonical))
         req.prefill_pos = min(req.cached_len, len(req.prompt) - 1)
-        req.table_dev = None  # pages may have swapped to canonical
         return True
 
     def _commit_prefill_chunk(self, req: Request) -> None:
@@ -1810,11 +1811,6 @@ class MiniEngine:
                 req, upto=req.computed_len // self.cfg.model.page_size)
             sp.set_attribute("request_id", req.request_id)
             sp.set_attribute("blocks", req.committed_blocks - before)
-        if req.committed_blocks != before:
-            # commit_blocks may have swapped duplicate pages to canonical;
-            # the cached device table would keep scattering into the
-            # abandoned copies.
-            req.table_dev = None
 
     def _handoff_gate(self, req: Request) -> bool:
         """Decide whether a handoff-admitted request may prefill locally.
@@ -2025,21 +2021,24 @@ class MiniEngine:
             self.swa_manager.release(committed, [])
         req.swa_acquired_from = limit
 
-    def _prefill(self, req: Request) -> None:
-        """Run the model over the whole uncached prompt suffix, chunked.
+    def _prefill(self, req: Request) -> int:
+        """Run the model over the whole uncached prompt suffix, chunked;
+        returns the first generated token.
 
         Chunks of at most ``max_prefill_tokens`` bound activation memory on
         long prompts (vLLM-style chunked prefill); each chunk's KV lands in
         the paged cache so the next chunk attends over it.
         """
         while req.prefill_pos is not None:
-            self._prefill_chunk(req)
+            first_token = self._prefill_chunk(req)
+        return first_token
 
-    def _prefill_chunk(self, req: Request) -> None:
+    def _prefill_chunk(self, req: Request) -> Optional[int]:
         """One prefill chunk at ``req.prefill_pos``; advances it (None once
-        the prompt is fully prefilled, with ``last_logits`` populated —
-        only the final chunk's logits are downloaded: a host transfer
-        waits for the device)."""
+        the prompt is fully prefilled). The chunk's program samples its
+        last position; only the last chunk's token is read, and returned:
+        a host read waits for the device. That chunk also leaves the
+        position's logits row in ``req.last_logits``, on the device."""
         page_size = self.cfg.model.page_size
         ph = self._phases
         with phase(ph, PHASE_STEP_INPUTS):
@@ -2057,51 +2056,44 @@ class MiniEngine:
             seq = bucket * page_size
             tokens = np.zeros((1, seq), np.int32)
             tokens[0, : len(chunk)] = chunk
+            tables = [self._page_table_for(req)[None, :]]
             if self.hybrid:
                 # SWA pages arrive just-in-time for this chunk's blocks and
                 # out-of-window slots return to the pool after it, so a
                 # long prompt's peak SWA demand is window + chunk.
                 self._swa_ensure(req, (pos + len(chunk) - 1) // page_size)
-
-        # Every transfer rides the dispatch phase, position and length as
-        # arguments of the call: see _decode_chunk.
-        with self._dispatch_phase(req, 1, len(chunk), seq):
-            if req.table_dev is None:
-                req.table_dev = self._to_dev(
-                    self._page_table_for(req))[None, :]
-            table = req.table_dev
+                tables.append(self._swa_table_for(req)[None, :])
+            last = pos + len(chunk) >= len(req.prompt)
+            token_sharding = None
             if self._sp > 1 and seq % self._sp == 0:
-                # Sequence-parallel prefill: place the chunk sharded on seq
-                # in ONE host→device transfer; XLA splits the per-token
-                # compute sp-ways (see __init__).
+                # Sequence-parallel prefill: the chunk's tokens are held
+                # sharded on seq inside the program; XLA splits the
+                # per-token compute sp-ways (see __init__).
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
-                tokens_dev = jax.device_put(
-                    tokens, NamedSharding(self.mesh, P(None, "sp")))
-            else:
-                tokens_dev = self._to_dev(tokens)
-            if self.hybrid:
-                swa_table = self._to_dev(self._swa_table_for(req))[None, :]
-                (logits, self.k_cache, self.v_cache,
-                 self.k_swa, self.v_swa) = forward_hybrid(
-                    self.params, self.cfg.model,
-                    tokens_dev,
-                    self.k_cache, self.v_cache, self.k_swa, self.v_swa,
-                    table, swa_table,
-                    self._to_dev([pos], np.int32),
-                    self._to_dev([len(chunk)], np.int32),
-                    last_only=True,
-                )
-            else:
-                logits, self.k_cache, self.v_cache = self._prefill_forward(
-                    self.params, self.cfg.model,
-                    tokens_dev,
-                    self.k_cache, self.v_cache,
-                    table,
-                    self._to_dev([pos], np.int32),
-                    self._to_dev([len(chunk)], np.int32),
-                    last_only=True,
-                )
+                token_sharding = NamedSharding(self.mesh, P(None, "sp"))
+            packed, shapes = pack_inputs(
+                (tokens, *tables, [pos], [len(chunk)]))
+
+        # Every program of a step is dispatched the same way, inside its
+        # dispatch phase: its inputs go in one transfer (an argument of the
+        # call: built first and held in a local, the arrays made the first
+        # call of every program 0.3 s slower on the chip, PERF.md §6, PR 25
+        # — why is not known), the pools are donated and taken back, and
+        # the copy of the tokens to the host is started behind the call
+        # where the host will read them, so that the blocking read in
+        # ``step.fetch`` finds it under way. The call stands at each site,
+        # not in a helper: through one (a frame more, the pools splatted)
+        # every 28-layer program took 1.5-2 s longer to trace and lower on
+        # the chip's host (PERF.md §6, PR 31) — why is not known either.
+        with self._dispatch_phase(req, 1, len(chunk), seq):
+            token, row, pools = self._prefill_forward(
+                self.params, self.cfg.model, self._to_dev(packed),
+                self._pools(), shapes=shapes, last_only=True, keep_row=True,
+                token_sharding=token_sharding)
+            self._take_pools(pools)
+            if last:
+                token.copy_to_host_async()
         req.computed_len = pos + len(chunk)
         if self.hybrid:
             self._swa_reclaim(req)  # reads computed_len
@@ -2109,15 +2101,13 @@ class MiniEngine:
             # Padding-waste accounting: len(chunk) real tokens rode a
             # seq-token padded dispatch (the power-of-two page bucket).
             self.telemetry.on_dispatch_tokens(len(chunk), seq)
-        if pos + len(chunk) >= len(req.prompt):
-            # last_only: logits row 0 is the chunk's final valid position.
-            with phase(ph, PHASE_STEP_SAMPLE, programs=1):
-                row = logits[0, 0]
-            with phase(ph, PHASE_STEP_FETCH):
-                req.last_logits = np.asarray(row)
-            req.prefill_pos = None
-        else:
+        if not last:
             req.prefill_pos = pos + len(chunk)
+            return None
+        req.last_logits = row
+        req.prefill_pos = None
+        with phase(ph, PHASE_STEP_FETCH):
+            return int(np.asarray(token)[0])
 
     def _commit_full_blocks(self, req: Request,
                             upto: Optional[int] = None) -> None:
@@ -2297,7 +2287,7 @@ class MiniEngine:
         else:
             if prefill_req is not None:
                 req = prefill_req
-                self._prefill_chunk(req)
+                first_token = self._prefill_chunk(req)
                 if (req.prefill_pos is not None and self.handoff is not None
                         and self.cfg.role == "prefill"):
                     # Prefill pod: commit this chunk's full blocks NOW so
@@ -2305,7 +2295,7 @@ class MiniEngine:
                     # chunk commits in _finish_prefill as usual).
                     self._commit_prefill_chunk(req)
                 if req.prefill_pos is None:
-                    self._finish_prefill(req)
+                    self._finish_prefill(req, first_token)
                     if req.output:
                         emitted[req.request_id] = req.output[-1]
                         # Its decode starts next step: including it in this
@@ -2531,36 +2521,32 @@ class MiniEngine:
             tables = np.zeros((rows_pad, self.cfg.max_pages_per_seq), np.int32)
             for i, t in enumerate(tables_list):
                 tables[i] = t
+            packed, shapes = pack_inputs((tokens, tables, row_starts, ctx))
 
+        # The prefill row's logits ARE its final valid token's (the ragged
+        # forward computes one row per ragged row), so its token is the
+        # row's like any other's.
+        finishing = (prefill_req is not None
+                     and p_pos + len(p_chunk) >= len(prefill_req.prompt))
+        # Dispatched as every program of a step is: see _prefill_chunk.
         with self._dispatch_phase(prefill_req, rows, t_real, t_pad):
-            logits, self.k_cache, self.v_cache = forward_ragged(
-                self.params, self.cfg.model,
-                self._to_dev(tokens),
-                self.k_cache, self.v_cache,
-                self._to_dev(tables),
-                self._to_dev(row_starts),
-                self._to_dev(ctx, np.int32),
-                interpret=self._ragged_interpret,
-            )
+            picked, last_row, pools = step_ragged(
+                self.params, self.cfg.model, self._to_dev(packed),
+                self._pools(), shapes=shapes,
+                keep_row=prefill_req is not None,
+                interpret=self._ragged_interpret)
+            self._take_pools(pools)
+            if decode_rows or finishing:
+                picked.copy_to_host_async()
 
         tel = self.telemetry
         if tel is not None:
             tel.on_dispatch_tokens(t_real, t_pad)
 
         out: dict[str, int] = {}
-        # The prefill row's logit IS its final valid token's (the ragged
-        # forward returns one row per ragged row).
-        finishing = (prefill_req is not None
-                     and p_pos + len(p_chunk) >= len(prefill_req.prompt))
-        with phase(ph, PHASE_STEP_SAMPLE,
-                   programs=2 * bool(decode_rows) + finishing):
-            picked = (jnp.argmax(logits[:len(decode_rows)], axis=-1)
-                      if decode_rows else None)
-            last_row = logits[rows - 1] if finishing else None
-        with phase(ph, PHASE_STEP_FETCH):
-            next_tokens = np.asarray(picked) if decode_rows else None
-            if finishing:
-                prefill_req.last_logits = np.asarray(last_row)
+        if decode_rows or finishing:
+            with phase(ph, PHASE_STEP_FETCH):
+                next_tokens = np.asarray(picked)
         if decode_rows:
             now = time.monotonic() if tel is not None else 0.0
             for i, req in enumerate(decode_rows):
@@ -2575,7 +2561,8 @@ class MiniEngine:
             req.computed_len = p_pos + len(p_chunk)
             if finishing:
                 req.prefill_pos = None
-                self._finish_prefill(req)
+                req.last_logits = last_row
+                self._finish_prefill(req, int(next_tokens[rows - 1]))
                 if req.output:
                     out[req.request_id] = req.output[-1]
             else:
@@ -2634,9 +2621,7 @@ class MiniEngine:
         with phase(ph, PHASE_STEP_INPUTS):
             last, ctx, tables = self._decode_batch_arrays(chunk)
             budgets = np.zeros((self.cfg.max_batch,), np.int32)
-            swa_tables = (
-                np.zeros((self.cfg.max_batch, self.cfg.max_pages_per_seq),
-                         np.int32) if self.hybrid else None)
+            swa_tables = [np.zeros_like(tables)] if self.hybrid else []
             for i, req in enumerate(chunk):
                 budgets[i] = req.max_new_tokens - len(req.output)
                 if self.hybrid:
@@ -2663,29 +2648,20 @@ class MiniEngine:
                             "(size num_swa_pages for window + decode_burst "
                             "to keep bursts)", steps)
                         break
-                    swa_tables[i] = self._swa_table_for(req)
+                    swa_tables[0][i] = self._swa_table_for(req)
+            packed, shapes = pack_inputs(
+                (last, tables, *swa_tables, ctx, budgets))
         if degraded:
             return self._decode_chunk(chunk)
 
+        # Dispatched as every program of a step is: see _prefill_chunk.
         with self._dispatch_phase(None, len(chunk), len(chunk) * steps,
                                   self.cfg.max_batch * steps):
-            if self.hybrid:
-                (toks, self.k_cache, self.v_cache,
-                 self.k_swa, self.v_swa) = self._decode_multi_hybrid(
-                    self.params, self.cfg.model,
-                    self._to_dev(last),
-                    self.k_cache, self.v_cache, self.k_swa, self.v_swa,
-                    self._to_dev(tables), self._to_dev(swa_tables),
-                    self._to_dev(ctx, np.int32),
-                    self._to_dev(budgets), steps=steps,
-                )
-            else:
-                toks, self.k_cache, self.v_cache = self._decode_multi(
-                    self.params, self.cfg.model,
-                    self._to_dev(last), self.k_cache, self.v_cache,
-                    self._to_dev(tables), self._to_dev(ctx, np.int32),
-                    self._to_dev(budgets), steps=steps,
-                )
+            toks, _, pools = self._decode_multi(
+                self.params, self.cfg.model, self._to_dev(packed),
+                self._pools(), shapes=shapes, steps=steps)
+            self._take_pools(pools)
+            toks.copy_to_host_async()
         with phase(ph, PHASE_STEP_FETCH):
             toks_host = np.asarray(toks)
         out = {}
@@ -2720,9 +2696,8 @@ class MiniEngine:
         ph = self._phases
         with phase(ph, PHASE_STEP_INPUTS):
             last, ctx, tables = self._decode_batch_arrays(chunk, rows=b)
-            tokens = last[:, None].copy()
             new_lens = np.zeros((b,), np.int32)
-            swa_tables = np.zeros((b, self.cfg.max_pages_per_seq), np.int32)
+            swa_tables = [np.zeros_like(tables)] if self.hybrid else []
             for i, req in enumerate(chunk):
                 new_lens[i] = 1
                 if self.hybrid:
@@ -2730,34 +2705,18 @@ class MiniEngine:
                     # — make sure that SWA slot has a live page.
                     self._swa_ensure(
                         req, req.computed_len // self.cfg.model.page_size)
-                    swa_tables[i] = self._swa_table_for(req)
+                    swa_tables[0][i] = self._swa_table_for(req)
+            packed, shapes = pack_inputs(
+                (last[:, None], tables, *swa_tables, ctx, new_lens))
 
-        # The transfers are arguments of the call, so they ride the
-        # dispatch phase on every path: built first and held in locals, the
-        # same arrays made the first call of every program 0.3 s slower on
-        # the chip (PERF.md §6, PR 25) — why is not known.
+        # Dispatched as every program of a step is: see _prefill_chunk.
         with self._dispatch_phase(None, len(chunk), len(chunk), b):
-            if self.hybrid:
-                (logits, self.k_cache, self.v_cache,
-                 self.k_swa, self.v_swa) = forward_hybrid(
-                    self.params, self.cfg.model,
-                    self._to_dev(tokens),
-                    self.k_cache, self.v_cache, self.k_swa, self.v_swa,
-                    self._to_dev(tables), self._to_dev(swa_tables),
-                    self._to_dev(ctx, np.int32),
-                    self._to_dev(new_lens),
-                )
-            else:
-                logits, self.k_cache, self.v_cache = self._decode_forward(
-                    self.params, self.cfg.model,
-                    self._to_dev(tokens), self.k_cache, self.v_cache,
-                    self._to_dev(tables),
-                    self._to_dev(ctx, np.int32),
-                    self._to_dev(new_lens),
-                )
+            picked, _, pools = self._decode_forward(
+                self.params, self.cfg.model, self._to_dev(packed),
+                self._pools(), shapes=shapes)
+            self._take_pools(pools)
+            picked.copy_to_host_async()
         out = {}
-        with phase(ph, PHASE_STEP_SAMPLE, programs=2):
-            picked = jnp.argmax(logits[:, 0], axis=-1)
         with phase(ph, PHASE_STEP_FETCH):
             next_tokens = np.asarray(picked)
         tel = self.telemetry

@@ -20,6 +20,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.kv_pages import page_writes, write_kv_pages
 from ..ops.paged_attention import paged_attention
@@ -44,8 +45,9 @@ SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_MLP,
           SCOPE_EMBED, SCOPE_LM_HEAD, SCOPE_SAMPLE)
 
 # The two step programs' names: what ``jax.jit`` calls the functions below
-# and a trace calls their executions (``jit_<name>``). Readers of traces
-# match these; tests/test_model.py pins both sides.
+# and a trace calls their executions (``jit_<name>``). A forward's step form
+# (``step_program``: what the engine dispatches) keeps its forward's name.
+# Readers of traces match these; tests/test_model.py pins both sides.
 PROGRAM_PREFILL = "forward_prefill_pallas"
 PROGRAM_DECODE = "forward_decode_pallas"
 
@@ -1246,6 +1248,13 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     return logits, tuple(k_caches), tuple(v_caches)
 
 
+def greedy_tokens(logits: jax.Array) -> jax.Array:
+    """The tail of every program that samples: the best token of each row
+    of float32 ``logits [rows, vocab]``, as ``int32 [rows]``."""
+    with jax.named_scope(SCOPE_SAMPLE):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _xla_attention(cfg):
     """The XLA attention backend of ``forward`` and ``forward_hybrid``."""
     def attention(q, k_stack, v_stack, layer_idx, table, positions,
@@ -1522,9 +1531,7 @@ def _decode_steps_scan(params, cfg, last_tokens, k_caches, v_caches, tables,
             params, cfg, toks[:, None], k_caches, v_caches, tables, ctx,
             live, attention, tails=(tks, tvs, ctx_lens),
         )
-        with jax.named_scope(SCOPE_SAMPLE):
-            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            nxt = jnp.where(live > 0, nxt, toks)
+        nxt = jnp.where(live > 0, greedy_tokens(logits[:, 0]), toks)
         return (nxt, tks, tvs, ctx + live), nxt
 
     (_t, tail_ks, tail_vs, _c), toks = jax.lax.scan(
@@ -1720,3 +1727,95 @@ def forward_ragged(
         ragged=row_starts,
     )
     return logits[0], ks[0], vs[0]
+
+
+# -- step forms: a forward as the engine's ``step()`` dispatches it ----------
+
+
+def pack_inputs(arrays) -> tuple[np.ndarray, tuple]:
+    """A program's per-step inputs as one flat ``int32`` array, so that they
+    reach the device in one transfer where each array was one, and the
+    shapes that take it apart again."""
+    arrays = [np.asarray(a, np.int32) for a in arrays]
+    return (np.concatenate([a.ravel() for a in arrays]),
+            tuple(a.shape for a in arrays))
+
+
+def unpack_inputs(packed, shapes: tuple) -> list:
+    """``pack_inputs`` undone: static slices, inside a program or out."""
+    arrays, at = [], 0
+    for shape in shapes:
+        size = math.prod(shape)
+        arrays.append(packed[at:at + size].reshape(shape))
+        at += size
+    return arrays
+
+
+def step_program(body, static=(), tokens_out: bool = False, kept_row=None):
+    """The step form of a forward: the same body with its per-step inputs
+    in one array and the sampling as its tail, jitted under the same name.
+
+    ``body(params, cfg, tokens, *pools, *rest, **static) -> (logits,
+    *pools)`` is a forward as written above, ``rest`` its other per-step
+    arrays in its own order (the page tables, ``ctx_lens``, ``new_lens``;
+    the ragged forward's ``row_starts``). The step form is called
+    ``(params, cfg, packed, pools, shapes=, **static)`` with ``packed,
+    shapes = pack_inputs((tokens, *rest))`` and ``pools`` the tuple of the
+    body's pools (donated), and returns ``(tokens, row, pools)``: ``int32
+    [rows]``, the greedy choice over the float32 logits of each row's one
+    position (``[rows, 1, vocab]``: a decode step, or a chunk with
+    ``last_only=True``). No logits leave the program but, with
+    ``keep_row=True``, one row ``[vocab]`` (``kept_row(*rest)``'s, else
+    row 0: what the last chunk of a prefill leaves in
+    ``Request.last_logits``); otherwise ``row`` is None.
+    ``tokens_out``: the body samples for itself (a burst) and its tokens
+    pass through. ``token_sharding`` constrains the unpacked tokens
+    (sequence-parallel prefill: the compute follows them).
+    """
+    def program(params, cfg, packed, pools, shapes, keep_row=False,
+                token_sharding=None, **kw):
+        tokens, *rest = unpack_inputs(packed, shapes)
+        if token_sharding is not None:
+            tokens = jax.lax.with_sharding_constraint(tokens, token_sharding)
+        out, *pools = body(params, cfg, tokens, *pools, *rest, **kw)
+        if tokens_out:
+            return out, None, tuple(pools)
+        if out.ndim == 3:
+            if out.shape[1] != 1:
+                raise ValueError(
+                    f"a step form samples one position a row, got logits "
+                    f"{out.shape}: pass last_only=True with a chunk")
+            out = out[:, 0]
+        row = None
+        if keep_row:
+            row = out[0 if kept_row is None else kept_row(*rest)]
+        return greedy_tokens(out), row, tuple(pools)
+
+    program.__name__ = program.__qualname__ = body.__name__
+    return jax.jit(
+        program,
+        static_argnames=("cfg", "shapes", "keep_row", "token_sharding",
+                         *static),
+        donate_argnames=("pools",))
+
+
+def _last_ragged_row(_table, row_starts, _ctx_lens):
+    """The last row that holds tokens: the prefill chunk, when a ragged
+    step carries one."""
+    return jnp.sum(row_starts[1:] > row_starts[:-1]) - 1
+
+
+step_forward = step_program(forward.__wrapped__, ("last_only",))
+step_forward_hybrid = step_program(
+    forward_hybrid.__wrapped__, ("last_only",))
+step_decode_pallas = step_program(
+    forward_decode_pallas.__wrapped__, ("interpret", "mesh", "batch_rows"))
+step_prefill_pallas = step_program(
+    forward_prefill_pallas.__wrapped__, ("interpret", "mesh", "last_only"))
+step_ragged = step_program(
+    forward_ragged.__wrapped__, ("interpret",), kept_row=_last_ragged_row)
+_BURST_STATIC = ("steps", "use_pallas", "interpret", "mesh", "batch_rows")
+step_decode_steps = step_program(
+    forward_decode_steps.__wrapped__, _BURST_STATIC, tokens_out=True)
+step_decode_steps_hybrid = step_program(
+    forward_decode_steps_hybrid.__wrapped__, _BURST_STATIC, tokens_out=True)

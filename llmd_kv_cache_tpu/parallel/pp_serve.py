@@ -33,7 +33,6 @@ TPU-first design notes:
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
@@ -198,8 +197,10 @@ def _pp_layer(x, layer, cfg, k_layer, v_layer, table, positions,
 def make_pp_serve_forward(mesh: Mesh, cfg: LlamaConfig,
                           stacked_params: dict,
                           microbatches: Optional[int] = None):
-    """Jitted pp forward: ``fn(sp, k, v, tokens, table, ctx, new) ->
-    (last_logits [b, vocab], k, v)``.
+    """The pp forward, in ``llama.forward``'s convention and not yet
+    jitted: ``forward_pp(sp, cfg, tokens, k, v, table, ctx, new,
+    last_only=True) -> (last_logits [b, 1, vocab], k, v)``. The engine
+    jits its step form (``llama.step_program``), which donates the pools.
 
     One call serves a prefill chunk (seq > 1) or a decode step (seq == 1)
     for the whole batch; the batch is split into ``microbatches`` (default
@@ -292,9 +293,13 @@ def make_pp_serve_forward(mesh: Mesh, cfg: LlamaConfig,
         check_vma=False,
     )
 
-    @partial(jax.jit, donate_argnums=(1, 2))
-    def fn(sp, k, v, tokens, table, ctx_lens, new_lens):
-        return mapped(sp, k, v, tokens, table,
-                      ctx_lens.astype(jnp.int32), new_lens.astype(jnp.int32))
+    def forward_pp(sp, _cfg, tokens, k, v, table, ctx_lens, new_lens,
+                   last_only=True):
+        if not last_only:
+            raise ValueError("the pp forward computes last-position logits")
+        logits, k, v = mapped(sp, k, v, tokens, table,
+                              ctx_lens.astype(jnp.int32),
+                              new_lens.astype(jnp.int32))
+        return logits[:, None, :], k, v
 
-    return fn
+    return forward_pp

@@ -696,18 +696,19 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
 
 # Every phase the engine opens, in one place. ``enqueue.*`` run inside
 # ``MiniEngine.enqueue``; ``step.*`` inside ``MiniEngine.step`` in this
-# order (inputs → dispatch → sample → fetch once per program whose result
-# the host reads; commit and emit when a prefill finished or blocks were
-# evicted).
+# order (inputs → dispatch once per program, → fetch where the host reads
+# its tokens; commit and emit when a prefill finished or blocks were
+# evicted). Every step program samples as its own tail, so no path opens
+# ``step.sample``; the name stays for the readers that list the phases.
 PHASE_ENQUEUE_ADMIT = "enqueue.admit"      # all of admission (nests the two below)
 PHASE_ENQUEUE_HASH = "enqueue.hash"        # tokens → block hashes
 PHASE_ENQUEUE_LOOKUP = "enqueue.lookup"    # prefix probe, page allocation, eviction
 PHASE_STEP_OFFLOAD_POLL = "step.offload_poll"
 PHASE_STEP_SCHEDULE = "step.schedule"      # the pick; restore and handoff gates
-PHASE_STEP_INPUTS = "step.inputs"          # the program's arguments built in numpy
-PHASE_STEP_DISPATCH = "step.dispatch"      # their _to_dev transfers + the jitted call returning
-PHASE_STEP_SAMPLE = "step.sample"          # slice / argmax programs outside the jit
-PHASE_STEP_FETCH = "step.fetch"            # the blocking np.asarray of tokens or logits
+PHASE_STEP_INPUTS = "step.inputs"          # the program's arguments built in numpy, packed into one array
+PHASE_STEP_DISPATCH = "step.dispatch"      # its one _to_dev transfer + the jitted call returning + the tokens' copy back started
+PHASE_STEP_SAMPLE = "step.sample"          # programs outside the jit that pick tokens: none, sampling is a program's tail
+PHASE_STEP_FETCH = "step.fetch"            # the blocking np.asarray of the program's tokens
 PHASE_STEP_COMMIT = "step.commit"          # _commit_full_blocks → commit_blocks, write-through
 PHASE_STEP_EMIT = "step.emit"              # event batch → sink → Pool/index (nests in commit)
 PHASE_STEP_FINISH = "step.finish"          # release of finished requests; carries the step's counters

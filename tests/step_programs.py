@@ -1,8 +1,10 @@
 """The step programs of ``models/llama.py`` at a tiny size, one table of
 cases for the tests that hold every program to the same rule: the
-structure test in ``test_model.py`` (no program moves a layer of a pool)
-and the equality test in ``test_kv_pages.py`` (writing rows into the
-stack in place gives what taking the layer out and putting it back gave).
+structure test in ``test_model.py`` (no program moves a layer of a pool),
+the equality test in ``test_kv_pages.py`` (writing rows into the stack in
+place gives what taking the layer out and putting it back gave) and the
+step-form tests in ``test_model.py`` (a forward's step form, which the
+engine dispatches, gives the tokens its logits give).
 
 A pool layer here is ``[11, 2, 4, 16]`` (the hybrid SWA group's
 ``[7, 2, 4, 16]``): no activation, weight or tail buffer of these
@@ -68,30 +70,58 @@ class Program(NamedTuple):
     args: Callable  # (params, cfg, pools, step) -> positional arguments
     static: dict  # its static keyword arguments
     pallas: bool  # attention is a kernel: ``interpret=True`` to run here
+    # Its step form (``llama.step_program``): (tokens, row, pools) from
+    # (params, cfg, packed, pools, shapes=, **static).
+    step: Callable
+    # What the engine adds to ``static`` when it dispatches the step form:
+    # a chunk's logits are its last position's.
+    chunk: bool = False
+
+
 
 
 _GQA = llama.LlamaConfig.tiny()
 _HYBRID = llama.LlamaConfig.gemma_tiny()
 _MLA = llama.LlamaConfig.deepseek_tiny()
 PROGRAMS = {
-    "forward": Program(llama.forward, _GQA, _padded(_chunk), {}, False),
-    "forward_mla": Program(llama.forward, _MLA, _padded(_chunk), {}, False),
+    "forward": Program(llama.forward, _GQA, _padded(_chunk), {}, False,
+                       llama.step_forward, True),
+    "forward_mla": Program(llama.forward, _MLA, _padded(_chunk), {}, False,
+                           llama.step_forward, True),
+    "forward_decode": Program(llama.forward, _GQA, _padded(_decode), {},
+                              False, llama.step_forward),
     "forward_hybrid": Program(
-        llama.forward_hybrid, _HYBRID, _padded(_chunk), {}, False),
+        llama.forward_hybrid, _HYBRID, _padded(_chunk), {}, False,
+        llama.step_forward_hybrid, True),
+    "forward_hybrid_decode": Program(
+        llama.forward_hybrid, _HYBRID, _padded(_decode), {}, False,
+        llama.step_forward_hybrid),
     "forward_decode_pallas": Program(
-        llama.forward_decode_pallas, _GQA, _padded(_decode), {}, True),
+        llama.forward_decode_pallas, _GQA, _padded(_decode), {}, True,
+        llama.step_decode_pallas),
     "forward_prefill_pallas": Program(
-        llama.forward_prefill_pallas, _GQA, _padded(_chunk), {}, True),
-    "forward_ragged": Program(llama.forward_ragged, _GQA, _ragged, {}, True),
+        llama.forward_prefill_pallas, _GQA, _padded(_chunk), {}, True,
+        llama.step_prefill_pallas, True),
+    "forward_ragged": Program(llama.forward_ragged, _GQA, _ragged, {}, True,
+                              llama.step_ragged),
     "forward_decode_steps": Program(
         llama.forward_decode_steps, _GQA, _burst,
-        dict(steps=2, use_pallas=True), True),
+        dict(steps=2, use_pallas=True), True, llama.step_decode_steps),
     "forward_decode_steps_xla_mla": Program(
-        llama.forward_decode_steps, _MLA, _burst, dict(steps=2), False),
+        llama.forward_decode_steps, _MLA, _burst, dict(steps=2), False,
+        llama.step_decode_steps),
     "forward_decode_steps_hybrid": Program(
         llama.forward_decode_steps_hybrid, _HYBRID, _burst,
-        dict(steps=2, use_pallas=True), True),
+        dict(steps=2, use_pallas=True), True,
+        llama.step_decode_steps_hybrid),
 }
+
+
+def step_inputs(args, pools):
+    """The per-step arrays among a program's positional arguments, in the
+    order its step form packs them: the tokens, then what follows the
+    pools."""
+    return (args[2], *args[3 + len(pools):])
 
 
 def init_pools(cfg, dtype=None):

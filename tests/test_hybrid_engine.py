@@ -351,12 +351,14 @@ class TestHybridScoringE2E:
             prompt = list(range(1, 33))  # 8 engine blocks = 4 canonical
             eng.generate("warm", prompt, max_new_tokens=2)
 
-            # republish-until-observed: PUB/SUB joins are slow
+            # republish-until-observed: PUB/SUB joins are slow, and the
+            # two groups' events are separate messages (a score can show
+            # before the SWA group's batch was ingested).
             deadline = time.monotonic() + 10
             scores = {}
             while time.monotonic() < deadline:
                 scores = indexer.score_tokens(prompt, "tiny-hybrid")
-                if scores:
+                if scores and pool.group_catalog.get("pod-h", 1) is not None:
                     break
                 publisher.publish(
                     [e for e in eng_events if isinstance(e, BlockStoredEvent)])

@@ -340,8 +340,8 @@ class TestMeshComposition:
         the local shape (a global-shape gate would admit configs whose
         shards then raise inside the kernel), and the interpret-mode
         kernel must reproduce the XLA tokens over the same fp8 bytes."""
-        from llmd_kv_cache_tpu.models.llama import (forward_decode_pallas,
-                                                    init_params)
+        from llmd_kv_cache_tpu.models.llama import (init_params,
+                                                    step_decode_pallas)
 
         cfg = LlamaConfig(vocab_size=256, hidden_size=64, num_layers=2,
                           num_heads=4, num_kv_heads=4, head_dim=128,
@@ -355,7 +355,7 @@ class TestMeshComposition:
                                         use_pallas_decode=pallas)
             if pallas:
                 fwd = getattr(e._decode_forward, "func", e._decode_forward)
-                assert fwd is forward_decode_pallas, \
+                assert fwd is step_decode_pallas, \
                     "quant kernel arm did not engage under tp"
         assert outs[True] == outs[False]
 
@@ -365,7 +365,7 @@ class TestMeshComposition:
         e, out = self._gen(mesh=self._mesh({"tp": 4}), cfg=cfg,
                            seed_params=params, use_pallas_decode=True)
         fwd = getattr(e._decode_forward, "func", e._decode_forward)
-        assert fwd is not forward_decode_pallas
+        assert fwd is not step_decode_pallas
         assert out == outs[False]
 
 

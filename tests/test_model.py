@@ -156,6 +156,9 @@ class TestTraceNames:
 
         assert llama.forward_prefill_pallas.__name__ == llama.PROGRAM_PREFILL
         assert llama.forward_decode_pallas.__name__ == llama.PROGRAM_DECODE
+        # What the engine dispatches, and so what a trace of it shows.
+        assert llama.step_prefill_pallas.__name__ == llama.PROGRAM_PREFILL
+        assert llama.step_decode_pallas.__name__ == llama.PROGRAM_DECODE
         assert ppa.pallas_paged_decode_attention.__name__ == ppa.KERNEL_DECODE
         assert ppa.pallas_paged_prefill_attention.__name__ == ppa.KERNEL_PREFILL
         assert ppa.pallas_paged_ragged_attention.__name__ == ppa.KERNEL_RAGGED
@@ -270,3 +273,126 @@ class TestNoLayerOfAPoolMoves:
         # The walk saw the program: its writes, and its kernel if it has one.
         assert "stablehlo.scatter" in seen
         assert ("stablehlo.custom_call" in seen) == prog.pallas
+
+
+class TestStepForms:
+    """A forward's step form (``llama.step_program``) is what the engine's
+    ``step()`` dispatches: the same body, its per-step inputs in one packed
+    array, the greedy sampling as its tail. One case per program of the
+    table, padding rows included."""
+
+    @staticmethod
+    def _case(name):
+        prog = sp.PROGRAMS[name]
+        params = init_params(jax.random.PRNGKey(0), prog.cfg)
+        static = dict(prog.static, interpret=True) if prog.pallas else dict(
+            prog.static)
+        if prog.chunk:
+            static["last_only"] = True
+        return prog, params, static
+
+    @pytest.mark.parametrize("name", list(sp.PROGRAMS))
+    def test_packed_inputs_unpack_to_the_arrays_they_replaced(self, name):
+        from llmd_kv_cache_tpu.models import llama
+
+        prog, params, _ = self._case(name)
+        pools = sp.init_pools(prog.cfg)
+        arrays = sp.step_inputs(prog.args(params, prog.cfg, pools, 1), pools)
+        packed, shapes = llama.pack_inputs(arrays)
+        assert packed.dtype == np.int32 and packed.ndim == 1
+        assert packed.size == sum(np.asarray(a).size for a in arrays)
+        on_host = llama.unpack_inputs(packed, shapes)
+        in_program = jax.jit(llama.unpack_inputs, static_argnums=1)(
+            packed, shapes)
+        assert len(on_host) == len(in_program) == len(arrays) >= 4
+        for want, a, b in zip(arrays, on_host, in_program):
+            assert a.shape == b.shape == np.shape(want)
+            assert b.dtype == jnp.int32
+            np.testing.assert_array_equal(a, want)
+            np.testing.assert_array_equal(np.asarray(b), want)
+
+    @pytest.mark.parametrize("name", list(sp.PROGRAMS))
+    def test_tokens_are_the_argmax_of_the_logits_form(self, name):
+        from llmd_kv_cache_tpu.models import llama
+
+        prog, params, static = self._case(name)
+        burst = "steps" in static
+        want_pools = sp.init_pools(prog.cfg)
+        got_pools = sp.init_pools(prog.cfg)
+        for step in range(2):
+            args = prog.args(params, prog.cfg, want_pools, step)
+            out, *want_pools = prog.fn(*args, **static)
+            packed, shapes = llama.pack_inputs(
+                sp.step_inputs(args, got_pools))
+            tokens, row, got_pools = prog.step(
+                params, prog.cfg, packed, got_pools, shapes=shapes,
+                keep_row=prog.chunk, **static)
+            if burst:
+                want = np.asarray(out)           # it samples for itself
+            else:
+                logits = np.asarray(out, np.float32)
+                logits = logits.reshape(-1, logits.shape[-1])
+                want = logits.argmax(-1)
+            assert tokens.dtype == jnp.int32 and tokens.shape == want.shape
+            np.testing.assert_array_equal(np.asarray(tokens), want)
+            if prog.chunk:
+                assert row.dtype == jnp.float32
+                np.testing.assert_array_equal(np.asarray(row), logits[0])
+            else:
+                assert row is None
+            for got, want_pool in zip(got_pools, want_pools):
+                np.testing.assert_array_equal(
+                    np.asarray(got, np.float32),
+                    np.asarray(want_pool, np.float32))
+
+    def test_ragged_keeps_the_last_row_that_holds_tokens(self):
+        from llmd_kv_cache_tpu.models import llama
+
+        prog, params, static = self._case("forward_ragged")
+        pools = sp.init_pools(prog.cfg)
+        args = list(prog.args(params, prog.cfg, pools, 0))
+        # Two rows of tokens and two of padding: the prefill chunk is row 1.
+        args[-3] = np.zeros((4, sp.TABLE.shape[1]), np.int32)
+        args[-3][:2] = sp.TABLE
+        args[-2] = np.asarray([0, 3, 5, 5, 5], np.int32)
+        args[-1] = np.asarray([0, 0, 0, 0], np.int32)
+        logits, *_ = prog.fn(*args, **static)
+        packed, shapes = llama.pack_inputs(sp.step_inputs(args, pools))
+        tokens, row, _ = prog.step(
+            params, prog.cfg, packed, sp.init_pools(prog.cfg),
+            shapes=shapes, keep_row=True, **static)
+        np.testing.assert_array_equal(
+            np.asarray(tokens), np.asarray(logits).argmax(-1))
+        np.testing.assert_array_equal(np.asarray(row), np.asarray(logits)[1])
+
+    @pytest.mark.parametrize("name", list(sp.PROGRAMS))
+    def test_no_logits_leave_the_program(self, name):
+        """An output is materialised: ``[rows, 1, vocab]`` in float32 is 19
+        MB a step at a vocabulary of 152k. The step form hands back tokens
+        and pools, and one ``[vocab]`` row where the caller keeps it."""
+        from llmd_kv_cache_tpu.models import llama
+
+        prog, params, static = self._case(name)
+        static.pop("interpret", None)
+        pools = sp.init_pools(prog.cfg)
+        args = prog.args(params, prog.cfg, pools, 0)
+        packed, shapes = llama.pack_inputs(sp.step_inputs(args, pools))
+        vocab = prog.cfg.vocab_size
+
+        def outputs(fn, *a, **kw):
+            lowered = fn.trace(*a, **kw).lower(lowering_platforms=("tpu",))
+            return [tuple(o.shape) for o in jax.tree.leaves(lowered.out_info)]
+
+        assert prog.step.__name__ == prog.fn.__name__
+        for keep in (False, True):
+            shapes_out = outputs(
+                prog.step, params, prog.cfg, packed, pools, shapes=shapes,
+                keep_row=keep, **static)
+            with_vocab = [s for s in shapes_out if vocab in s]
+            burst = "steps" in static
+            assert with_vocab == ([(vocab,)] if keep and not burst else [])
+            assert shapes_out[len(with_vocab) + 1:] == [
+                tuple(p.shape) for p in pools]
+        # The form tests and references call does hand its logits back.
+        if "steps" not in static:
+            assert any(vocab in s for s in outputs(prog.fn, *args, **static))

@@ -8,6 +8,9 @@ collectives (cache-scatter all-gathers, logits reduce) derived from the
 shardings. Verified two ways: token identity vs the single-device engine,
 and the compiled HLO predominantly carrying seq-sharded intermediates
 (i.e. the FLOPs really split — not an all-gather-then-replicate program).
+The engine dispatches the forward's step form: the chunk arrives packed in
+one replicated array, and the program holds the unpacked tokens to the
+sequence sharding (``token_sharding``), which the compute follows.
 
 Runs on the virtual 8-device CPU mesh (conftest).
 """
@@ -117,3 +120,28 @@ def test_sp_compute_actually_shards(setup):
     full = txt.count("[1,64,")
     assert sharded > 2 * full, (sharded, full)
     assert re.search("all-gather", txt), "expected scatter all-gathers"
+
+
+def test_sp_step_form_shards_from_one_packed_array(setup):
+    """What the engine dispatches: the packed inputs arrive replicated in
+    one transfer, ``token_sharding`` holds the unpacked tokens to the seq
+    sharding, and the program's intermediates are predominantly seq shards
+    as with tokens placed sharded by hand."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llmd_kv_cache_tpu.models.llama import (init_kv_cache, pack_inputs,
+                                                step_forward)
+
+    cfg, params = setup
+    mesh = make_mesh({"sp": 4}, jax.devices()[:4])
+    packed, shapes = pack_inputs((
+        np.arange(60, 124)[None, :], 1 + np.arange(16)[None, :], [0], [64]))
+    k, v = init_kv_cache(cfg, 64)
+
+    txt = step_forward.lower(
+        params, cfg, jnp.asarray(packed), (k, v), shapes=shapes,
+        last_only=True, keep_row=True,
+        token_sharding=NamedSharding(mesh, P(None, "sp"))).compile().as_text()
+    sharded, full = txt.count("[1,16,"), txt.count("[1,64,")
+    assert sharded > 2 * full, (sharded, full)
+    assert "all-gather" in txt

@@ -16,6 +16,7 @@ import time
 import urllib.request
 
 import msgpack
+import numpy as np
 import pytest
 
 from llmd_kv_cache_tpu.telemetry import (
@@ -729,20 +730,21 @@ class TestEnginePhases:
                                  tracing.PHASE_STEP_SCHEDULE]
             assert names[-1] == tracing.PHASE_STEP_FINISH
             assert count(tracing.PHASE_STEP_FINISH) == 1
-            # Once per program dispatched; a program whose result the
-            # host reads is sampled and fetched once.
+            # Once per program dispatched; a program whose tokens the
+            # host reads is fetched once. Every program samples inside
+            # itself: nothing is dispatched besides the step programs.
             programs = count(tracing.PHASE_STEP_DISPATCH)
             assert programs >= 1
             assert count(tracing.PHASE_STEP_INPUTS) == programs
-            assert count(tracing.PHASE_STEP_FETCH) == count(
-                tracing.PHASE_STEP_SAMPLE) <= programs
+            assert count(tracing.PHASE_STEP_FETCH) <= programs
+            assert count(tracing.PHASE_STEP_SAMPLE) == 0
             finish = phases[-1][1]
-            assert finish["programs"] == sum(
+            assert finish["programs"] == programs == sum(
                 a.get("programs", 0) for _, a in phases[:-1])
-            # Every _to_dev rides a dispatch phase, on every path.
-            assert finish["transfers"] == sum(
+            # One transfer a program, and it rides the dispatch phase.
+            assert finish["transfers"] == programs == sum(
                 a.get("transfers", 0) for n, a in phases
-                if n == tracing.PHASE_STEP_DISPATCH) > 0
+                if n == tracing.PHASE_STEP_DISPATCH)
             assert not any("transfers" in a for n, a in phases
                            if n == tracing.PHASE_STEP_INPUTS)
             for n, a in phases:
@@ -755,14 +757,68 @@ class TestEnginePhases:
             for at, n in enumerate(names):
                 if n == tracing.PHASE_STEP_EMIT:
                     assert names[at - 1] == tracing.PHASE_STEP_COMMIT
+        # ``step.sample`` keeps its name (the benchmark lists the phases)
+        # and no path opens it: sampling is the tail of every program.
         assert names_seen == {n for n in tracing.PHASE_NAMES
-                              if n.startswith("step.")}
+                              if n.startswith("step.")
+                              } - {tracing.PHASE_STEP_SAMPLE}
         assert events                  # the sink did receive the batches
         commits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_COMMIT]
         assert sorted(c["blocks"] for c in commits) == [1, 3]
         assert {c["request_id"] for c in commits} == {"a", "b"}
         emits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_EMIT]
         assert all(e["events"] >= 1 for e in emits)
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas", "ragged"])
+    def test_a_step_is_one_program_and_one_transfer(self, backend):
+        """A decode-only ``step()`` dispatches one program fed by one
+        transfer; a step in which a prefill finishes, two and two on the
+        padded path (the chunk's program, then the decode program), one and
+        one on the ragged path. After a prefill ``last_logits`` is the
+        ``[vocab]`` float32 row the benchmark's probe reads."""
+        over = dict(xla={}, ragged=dict(ragged=True), pallas=dict(
+            use_pallas_decode=True, use_pallas_prefill=True))[backend]
+        eng, tiny = _phase_engine(**over)
+        want = {"xla": "xla", "pallas": "pallas", "ragged": "xla"}[backend]
+        assert eng.attention_backends["decode"]["backend"] == want
+        seen = _recorded(eng._phases)
+        page = tiny.page_size
+
+        def finish_of_step():
+            del seen[:]
+            emitted = eng.step()
+            (finish,) = [a for n, a, _ in seen
+                         if n == tracing.PHASE_STEP_FINISH]
+            moved = [a.get("transfers", 0) for n, a, _ in seen
+                     if n == tracing.PHASE_STEP_DISPATCH]
+            return emitted, finish["programs"], finish["transfers"], moved
+
+        a = eng.enqueue("a", list(range(1, 1 + page + 3)), max_new_tokens=6)
+        # Step 1: a's one chunk, nothing to decode yet.
+        emitted, programs, transfers, moved = finish_of_step()
+        assert (programs, transfers, moved) == (1, 1, [1])
+        row = np.asarray(a.last_logits, np.float32)
+        assert row.shape == (tiny.vocab_size,) and np.isfinite(row).all()
+        assert emitted == {"a": int(row.argmax())} and a.output == [
+            emitted["a"]]
+        # Step 2: decode only.
+        emitted, programs, transfers, moved = finish_of_step()
+        assert (programs, transfers, moved) == (1, 1, [1])
+        assert list(emitted) == ["a"] and len(a.output) == 2
+        # Step 3: b's prefill finishes beside a's decode.
+        b = eng.enqueue("b", list(range(700, 700 + page)), max_new_tokens=2)
+        emitted, programs, transfers, moved = finish_of_step()
+        both = 1 if backend == "ragged" else 2
+        assert (programs, transfers, moved) == (both, both, [1] * both)
+        assert set(emitted) == {"a", "b"}
+        row_b = np.asarray(b.last_logits, np.float32)
+        assert row_b.shape == (tiny.vocab_size,)
+        assert b.output == [int(row_b.argmax())]
+        # a's row is still the one its prefill left.
+        np.testing.assert_array_equal(
+            np.asarray(a.last_logits, np.float32), row)
+        _drain(eng)
+        assert len(a.output) == 6 and len(b.output) == 2
 
     def test_phases_land_in_a_profiler_capture(self, tmp_path):
         import glob
