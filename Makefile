@@ -1,14 +1,14 @@
 # Developer entry points. Tests and the host-side overhead checks run on
-# the CPU (CPU_ENV; tests/conftest.py forces it too). `make bench` and
-# `make chip-smoke` need a TPU and fail without one; from a machine with
-# no chip, send them through the chip tool
-# (`chiprun -- python3 chip_smoke.py`).
+# the CPU (CPU_ENV; tests/conftest.py forces it too). `make chip-smoke`
+# needs a TPU and fails without one; from a machine with no chip, send it
+# through the chip tool (`chiprun -- python3 chip_smoke.py`). The device
+# benchmark is kvbench/run.py (BENCHMARK.json).
 
 PY := python
 CPU_ENV := PYTHONPATH=. JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: test unit-test-race tsan asan native bench chip-smoke bench-hotpath bench-hotpath-fleet bench-engine-telemetry bench-shard bench-ragged bench-fp8 bench-disagg bench-fleet bench-pyprof bench-workingset bench-controller bench-graytail bench-fencing bench-incident perf-check verify graft-check verify-examples chaos lint clean
+.PHONY: test unit-test-race tsan asan native chip-smoke bench-hotpath bench-hotpath-fleet bench-engine-telemetry bench-shard bench-fleet bench-pyprof bench-workingset bench-controller bench-graytail bench-fencing bench-incident perf-check verify graft-check verify-examples chaos lint clean
 
 test: native
 	$(CPU_ENV) $(PY) -m pytest tests/ -q
@@ -60,9 +60,6 @@ native:
 	$(MAKE) -s -C csrc/kvio
 	$(MAKE) -s -C csrc/kvindex
 
-bench: native
-	$(PY) bench.py
-
 # The quickest proof that the routed serving path still starts on the
 # chip (it rebuilds the native libraries itself).
 chip-smoke:
@@ -70,7 +67,7 @@ chip-smoke:
 
 # Score/ingest hot-path microbenchmark (prefix cache, early-exit lookup,
 # batched ingestion) — pure CPU scheduling-path work, so it pins the CPU
-# backend unlike `make bench`.
+# backend.
 bench-hotpath: native
 	$(CPU_ENV) $(PY) hack/bench_hotpath.py
 
@@ -91,24 +88,6 @@ bench-engine-telemetry: native
 # the single-shard baseline (bench_shard_fanout).
 bench-shard: native
 	$(CPU_ENV) $(PY) bench.py --shards 4
-
-# Ragged single-kernel mixed prefill+decode dispatch vs the padded
-# two-kernel path: on CPU an interpret-mode equivalence smoke + padding
-# waste comparison; on a real TPU the >=1.5x decode-throughput gate.
-bench-ragged: native
-	$(CPU_ENV) $(PY) bench.py --ragged
-
-# fp8 vs bf16 decode KV-bandwidth probe (VERDICT r5 item 1); analytic
-# bytes/step + interpret smoke on CPU, measured ms/step on a real chip.
-bench-fp8: native
-	$(CPU_ENV) $(PY) bench.py --fp8-bandwidth
-
-# Prefill/decode disaggregation gate (offload/handoff): decode-heavy
-# replay where a prefill pod + decode pod pair hands KV off over the
-# transfer tier vs a monolithic baseline; on CPU a correctness + trace-
-# continuity smoke, on a real chip the out_tok/s-at-fixed-TTFT gate.
-bench-disagg: native
-	$(CPU_ENV) $(PY) bench.py --disagg
 
 # Fleet-telemetry overhead gate (telemetry/ + services/telemetry_
 # collector): per-span export cost (identity stamp + seq + ring append)

@@ -5,8 +5,7 @@ Two complementary pieces of the sharded control plane:
 - :class:`ShardedIndex` — an :class:`~llmd_kv_cache_tpu.index.base.Index`
   over N child backends routed by the consistent-hash ring. One event
   pool writes through it and every block key lands on its owning child
-  — the single-process form of sharded ingestion (also what bench.py
-  uses to populate a toy cluster deterministically). The pool's
+  — the single-process form of sharded ingestion. The pool's
   write-combining ``_IngestCoalescer`` sits above it per drained batch;
   routed writes arrive already batched and are re-grouped per shard
   here, so each child sees one call per (shard, op) instead of one per

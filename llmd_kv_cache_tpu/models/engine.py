@@ -128,25 +128,17 @@ class EngineConfig:
     use_pallas_decode: Optional[bool] = None
     # Prefill attention backend: None = auto — the Pallas flash-prefill
     # kernel whenever the Pallas backend is active (TPU + aligned
-    # head_dim), XLA paged attention otherwise. Measured on a real v5e
-    # at the bench's production shapes (0.9B model, 2048-token chunks,
-    # in-jit so dispatch is excluded — hack/mfu_probe.py): the superblock
-    # flash kernel runs 1.9 ms/layer vs XLA's 3.5 ms — the fp32
-    # logits/probs tensor XLA materializes per layer costs more HBM
-    # round-trips than the kernel's streamed online softmax. (The
-    # pre-superblock kernel this default once gated off was 12× *slower*:
-    # 16-token DMAs and 16×128 tiles cannot feed the 128×128 MXU.)
-    # False forces XLA prefill; True insists and warns if the Pallas
-    # backend is inactive.
+    # head_dim), XLA paged attention otherwise. False forces XLA prefill;
+    # True insists and warns if the Pallas backend is inactive. Pallas
+    # prefill and decode serve every accepted cell of PERF_LEDGER.jsonl;
+    # the ledger has no pair against the XLA path.
     use_pallas_prefill: Optional[bool] = None
     # Fuse QKV (and gate+up, MLA input) projections into single wider
     # matmuls at startup (models.llama.fuse_params). None = auto: fused
-    # wherever the shape profits (llama.fuse_profitable — measured v5e
-    # crossover: hidden 4096 gains ~7% prefill MFU, hidden 2048 loses
-    # ~8%; ROADMAP aim 1). The gate evaluates PER-SHARD widths
-    # (hidden_size / tp): tp narrows each rank's matmul columns, so
-    # hidden 4096 at tp=2 is gated off like the regressing hidden-2048
-    # single-shard case. Under a tp mesh the engine fuses in
+    # where llama.fuse_profitable says so, a gate on the PER-SHARD width
+    # (hidden_size / tp >= 4096). It puts mistral-7b-l16 on the fused
+    # path and qwen3-1.7b on the unfused; the ledger has no pair across
+    # it. Under a tp mesh the engine fuses in
     # the per-rank INTERLEAVED column order (LlamaConfig.fused_interleave
     # = tp) so the fused leaves stay Megatron-column-shardable; auto
     # additionally requires the projection widths to divide tp and
@@ -160,11 +152,9 @@ class EngineConfig:
     # (models.checkpoint unfuses on save).
     fuse_projections: Optional[bool] = None
     # Paged KV pool element type: None (default — the model's dtype),
-    # "bf16", or "f8_e4m3" (float8_e4m3fn). fp8 halves KV HBM traffic
-    # and capacity — the decode-bandwidth lever at long context
-    # (b32/ctx2048 decode is attention-bandwidth bound,
-    # ROADMAP S1) — with ~2^-3 relative quantization error per
-    # element (the established fp8-KV serving trade). e4m3's per-element
+    # "bf16", or "f8_e4m3" (float8_e4m3fn). fp8 halves the KV bytes a
+    # decode step reads and a token holds, at ~2^-3 relative quantization
+    # error per element; it has no line on the ledger. e4m3's per-element
     # exponent needs no scale arrays: the cache keeps its layout,
     # scatter casts on write, attention upcasts on read,
     # offload/checkpoint move 1-byte elements (the store fingerprint's
@@ -179,10 +169,10 @@ class EngineConfig:
     kv_cache_dtype: Optional[str] = None
     # Batch rows co-scheduled per flash-decode program (merged-heads
     # kernel): each round issues every row's page DMAs together and the
-    # pipeline fills once per program instead of once per batch item —
-    # the decode-bandwidth lever (VERDICT r4 #1). 1 = one program per
-    # batch item (round-4 behavior). Single-shard Pallas decode only;
-    # ignored under tp sharding and on the XLA backend.
+    # pipeline fills once per program instead of once per batch item.
+    # 1 = one program per batch item, which is what every cell runs; a
+    # value above 1 has no line on the ledger. Single-shard Pallas decode
+    # only; ignored under tp sharding and on the XLA backend.
     decode_batch_rows: int = 1
     # Chunked prefill: the uncached suffix is processed in chunks of at
     # most this many tokens (vLLM-style), bounding per-step activation
@@ -193,7 +183,7 @@ class EngineConfig:
     # finest-grained continuous batching; larger values amortize dispatch
     # overhead at the cost of admitting new requests only between bursts.
     # Bursts are bucketed to powers of two so the jit cache stays
-    # O(log burst).
+    # O(log burst). Every cell runs 1; a burst has no line on the ledger.
     decode_burst: int = 1
     # Ragged single-kernel attention: pack the step's admitted prefill
     # chunk and every active decode row into ONE flat-token-axis dispatch
@@ -207,6 +197,7 @@ class EngineConfig:
     # padded two-kernel path; the same fallback serves shapes the kernel
     # cannot take (unaligned head_dim on real TPU, fp8 pages whose
     # kv_heads*page_size is not a 32 multiple). Runs interpreted on CPU.
+    # Every cell runs the padded path; ragged has no line on the ledger.
     ragged_attention: bool = False
     # Engine data-plane telemetry (telemetry/engine_telemetry.py): an
     # EngineTelemetryConfig enables TTFT/ITL/TPOT histograms, KV-pool
@@ -886,9 +877,8 @@ class MiniEngine:
             pallas_mesh = None
             self._decode_forward = step_forward
         # Prefill backend is independent of decode: auto (None) follows
-        # the Pallas backend's platform/head-dim gating — the flash
-        # kernel measured 1.9× faster than XLA attention at production
-        # chunks on a real v5e (see EngineConfig.use_pallas_prefill).
+        # the Pallas backend's platform/head-dim gating
+        # (EngineConfig.use_pallas_prefill).
         # Auto engages only on real TPU: interpret-mode flash prefill on
         # CPU is orders slower than XLA with no fidelity gain (tests that
         # want it opt in with use_pallas_prefill=True).
@@ -2608,7 +2598,7 @@ class MiniEngine:
         budget and freezes after.
 
         Hybrid models run the two-pool scan with freeze-and-reclaim SWA
-        paging (VERDICT r2 #4): the SWA table is pre-extended through every
+        paging: the SWA table is pre-extended through every
         page the burst will touch, frozen for the scan, and slots that
         slid out of the window are reclaimed once per burst on the host —
         so SWA families keep the burst's dispatch-amortization win at the

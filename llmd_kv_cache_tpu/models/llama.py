@@ -526,14 +526,9 @@ def fuse_params(params: Params, cfg: LlamaConfig) -> Params:
 
     Serving-time transform (applied once at engine startup): one
     [h, Nq+Nk+Nv] product reads the activations once and replaces three
-    back-to-back [h, N] products. Measured on a real v5e
-    (July 2026, ROADMAP aim 1), the trade is
-    shape-dependent: at hidden 4096 (3.1B model) the fused 4k prefill is
-    ~7% faster (210 ms / 64.0% MFU vs 227 ms / 59.4%), while at hidden
-    2048 (the 0.9B bench model) it is ~8% SLOWER (112 ms vs 103 ms) —
-    XLA already overlaps the narrow products there and the fused wide-N
-    output only adds slice boundaries. ``fuse_profitable`` encodes the
-    measured crossover; the engine's auto default consults it.
+    back-to-back [h, N] products. The trade depends on the width:
+    ``fuse_profitable`` holds the rule and the engine's auto default
+    consults it.
 
     - ``wq/wk/wv`` (+ ``bq/bk/bv``) → ``w_qkv`` (+ ``b_qkv``)
     - MLA: ``wq|w_dq`` + ``w_dkv`` + ``w_kr`` → ``w_mla_in``
@@ -594,26 +589,25 @@ def fuse_params(params: Params, cfg: LlamaConfig) -> Params:
 def maybe_fuse_params(params: Params, cfg: LlamaConfig) -> Params:
     """``fuse_params`` iff ``fuse_profitable(cfg)`` — the one place the
     profit gate composes with the transform, shared by the engine's
-    auto default and the bench's shared-tree path."""
+    auto default and whoever shares one tree between engines."""
     return fuse_params(params, cfg) if fuse_profitable(cfg) else params
 
 
 def fuse_profitable(cfg: LlamaConfig, tp: int = 1) -> bool:
     """Whether ``fuse_params`` is expected to help this model on TPU.
 
-    The measured crossover (real v5e, 4k flash prefill, July 2026;
-    ROADMAP aim 1): hidden 4096 gains ~7%
-    (59.4% → 64.0% MFU), hidden 2048 loses ~8% (38.4% → 35.5%). The
-    boundary sits somewhere in (2048, 4096]; models below it keep the
-    unfused layout so narrow-hidden serving never regresses. Engines
-    with ``fuse_projections=None`` and the bench's shared-tree path both
-    consult this.
+    The rule: fuse from a per-shard hidden width of 4096 up. It puts
+    ``mistral-7b-l16`` on the fused path and ``qwen3-1.7b`` on the
+    unfused in every accepted cell of ``PERF_LEDGER.jsonl``; the ledger
+    has no pair across the gate, and the July 2026 reading it was set
+    from (a gain at 4096, a loss at 2048) has no record left. Engines
+    with ``fuse_projections=None`` and ``maybe_fuse_params`` consult this.
 
     ``tp`` scales the gate to PER-SHARD widths: under Megatron column
     sharding each rank multiplies into 1/tp of the fused output columns,
-    so a hidden-4096 model at tp=2 runs the same narrow per-core products
-    the hidden-2048 measurement showed REGRESSING. The profit boundary
-    therefore applies to ``hidden_size / tp``, not the full-model width.
+    so a hidden-4096 model at tp=2 runs the narrow per-core products of a
+    hidden-2048 one. The boundary therefore applies to
+    ``hidden_size / tp``, not the full-model width.
     """
     return cfg.hidden_size // max(1, tp) >= 4096
 
@@ -990,8 +984,7 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     ``last_only=True`` computes logits only for each sequence's final valid
     token (``new_lens - 1``) — the prefill-chunk case, where the full
     [seq, vocab] lm_head matmul and its fp32 materialization are pure waste
-    (a 2048-token chunk of the bench model otherwise burns 0.27 TFLOP and a
-    262 MB HBM write per chunk on logits nobody reads).
+    (chunk × vocab of matmul and of HBM write on logits nobody reads).
 
     ``tails=(tail_ks, tail_vs, ctx_base)`` is the fused-decode-burst mode
     (seq == 1): the paged caches are READ-ONLY (XLA copies large scan
@@ -1582,7 +1575,7 @@ def forward_decode_steps_hybrid(
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused multi-token decode over the hybrid two-pool layout.
 
-    The freeze-and-reclaim half of the SWA burst design (VERDICT r2 #4):
+    The freeze-and-reclaim half of the SWA burst design:
     the engine pre-extends each request's SWA table through the pages the
     whole burst will touch, the scan runs ``steps`` device-resident ticks
     against the frozen tables (same per-row budget semantics as
@@ -1637,10 +1630,9 @@ def forward_prefill_pallas(
     seq = tokens.shape[1]
     # Query rows per program: target group·q_tile ≈ 1024 so each
     # online-softmax round is a [~1024, head_dim]×[head_dim, keys]
-    # matmul. Measured on a real v5e at the bench's 2048-token chunks
-    # (hack/mfu_probe.py in-jit sweep): q_tile 512 at group 2 runs
-    # 1.9 ms/layer vs 3.0 ms at q_tile 128 — bigger tiles re-stream the
-    # KV fewer times. Tiny test seqs fall back to their gcd.
+    # matmul: bigger tiles re-stream the KV fewer times. Every accepted
+    # cell runs this rule; the ledger has no pair across tile sizes.
+    # Tiny test seqs fall back to their gcd.
     group = cfg.num_heads // max(1, cfg.kv_cache_heads)
     q_tile = math.gcd(seq, max(128, 1024 // max(1, group)))
 

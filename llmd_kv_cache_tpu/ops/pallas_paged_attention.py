@@ -456,9 +456,7 @@ def _decode_kernel_merged(
     upcast value — HBM moved half the bytes, the MXU still sees bf16.
 
     The per-head grid (``_decode_kernel``) pays pipeline fill/drain and
-    per-page 4 KB DMAs once per (batch, head) program — measured on a
-    real v5e at batch 8 / ctx 4k it sustains only ~105 GB/s of the
-    chip's 819 (July 2026, ROADMAP S1). Merging heads
+    per-page 4 KB DMAs once per (batch, head) program. Merging heads
     makes each sub-page copy one whole-page transfer carrying all kv
     heads (DMA count ÷ kv_heads), computes the position mask once per
     round instead of per head, and amortizes the program overhead over
@@ -1254,11 +1252,10 @@ def pallas_paged_prefill_attention(
     first S positions attendable past the window (StreamingLLM; needs a
     window). ``pages_per_block`` sets the keys per online-softmax round
     (``pages_per_block * page_size``); the default targets 1024 keys per
-    round — measured on a real v5e (hack/mfu_probe.py, in-jit sweep at
-    the bench's 2048-token chunks) round width beyond one MXU tile keeps
-    paying until ~1024: 128-key rounds ran 3.0 ms/layer vs 1.9 ms at
-    1024 keys — clamped so the fp32 scores tile [group, q_tile, keys]
-    stays within a few MB of VMEM.
+    round (wider rounds re-stream the queries fewer times), clamped so
+    the fp32 scores tile [group, q_tile, keys] stays within a few MB of
+    VMEM. Every accepted cell runs this default; the ledger has no pair
+    across round widths.
     """
     batch, q_seq, q_heads, head_dim = q.shape
     # layer_idx: caches are the engine's full [layers, pages, …] stack and
@@ -1418,10 +1415,9 @@ def pallas_paged_decode_attention(
         raise ValueError("batch_rows > 1 requires the merged-heads kernel")
     batch_rows = max(1, min(batch_rows, batch))
     if pages_per_block is None:
-        # ~1024 keys per online-softmax round: measured on a real v5e at
-        # batch 8 / ctx 4k (hack/mfu_probe.py), widening rounds from 128
-        # to 1024-2048 keys cut the step from 2.5 ms to ~1.3 ms — fewer
-        # DMA waits and per-round fixed costs against the same bytes.
+        # ~1024 keys per online-softmax round: fewer DMA waits and
+        # per-round fixed costs against the same bytes. Every accepted
+        # cell runs this default; the ledger has no pair across widths.
         # The decode scores tile [group, keys] is small; the merged
         # kernel's scratch carries every head per key, so its keys/round
         # are clamped to keep the double-buffered K+V staging ≤ ~8 MB of

@@ -1,7 +1,6 @@
 """Pipeline-parallel SERVING over a ``pp`` mesh axis.
 
-The last parallelism mode the serving engine lacked (VERDICT r4 weak
-#7). Training pp exists in two schedules (``parallel.pipeline``); this
+Training pp exists in two schedules (``parallel.pipeline``); this
 module adds the inference counterpart: layer blocks sharded across
 stages, **paged KV caches sharded on their layer axis** (each stage owns
 the cache slabs for its layers — the memory reason pp exists: a model +

@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives.
 
-Every entry point that compiles (``chip_smoke.py``, ``bench.py``, the
+Every entry point that compiles (``chip_smoke.py``, ``kvbench``, the
 example servers) calls :func:`enable_compile_cache` before its first
 compile, so a second process or a second run finds the first one's
 programs. The directory is part of JAX's cache key, hence a fixed path:

@@ -2,9 +2,10 @@
 
 Tests run on the CPU backend with a virtual 8-device mesh, so multi-chip
 sharding logic is exercised without hardware and Pallas kernels run in the
-interpreter. The chip is reached through ``chip_smoke.py`` and ``bench.py``,
-never through pytest. The environment must be set before the first ``jax``
-import, hence module level.
+interpreter. The chip is reached through ``kvbench/run.py`` and
+``chip_smoke.py``, never through pytest (``tests/test_kvbench_rehearsal.py``
+walks the benchmark's cells at toy widths on the CPU). The environment must
+be set before the first ``jax`` import, hence module level.
 """
 
 import os

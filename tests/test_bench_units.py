@@ -1,119 +1,18 @@
-"""Cheap regression cover for bench.py helpers (the slow arms run under
-the driver; these keep the harness itself from rotting)."""
+"""Cheap regression cover for bench.py's host modes, its refusal of
+anything else, and the perf sentinel that reads them."""
 
 import json
+import pathlib
+import re
 import subprocess
 import sys
 
-sys.path.insert(0, "/root/repo")
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
 
 import bench
-
-
-class TestWorkload:
-    def test_deterministic(self):
-        import numpy as np
-
-        a = bench.build_workload(np.random.default_rng(42), n_requests=8)
-        b = bench.build_workload(np.random.default_rng(42), n_requests=8)
-        assert a == b
-
-    def test_shared_prefixes(self):
-        import numpy as np
-
-        wl = bench.build_workload(np.random.default_rng(0), n_requests=32,
-                                  n_prefixes=4, prefix_len=16, suffix_len=4)
-        prefixes = {tuple(p[:16]) for p in wl}
-        assert len(prefixes) <= 4  # requests reuse the prefix pool
-        assert all(len(p) == 20 for p in wl)
-
-
-class TestQueueingTTFTs:
-    def test_no_arrivals_returns_bare_service(self):
-        assert bench.queueing_ttfts([1.0, 2.0], ["a", "b"], None) == [1.0, 2.0]
-
-    def test_fifo_queue_wait_accumulates_per_pod(self):
-        # Both requests hit pod "a"; the second arrives at t=0 but waits
-        # for the first's service to finish.
-        ttfts = bench.queueing_ttfts([1.0, 1.0], ["a", "a"], [0.0, 0.0])
-        assert ttfts == [1.0, 2.0]
-
-    def test_independent_pods_do_not_queue(self):
-        ttfts = bench.queueing_ttfts([1.0, 1.0], ["a", "b"], [0.0, 0.0])
-        assert ttfts == [1.0, 1.0]
-
-    def test_idle_gap_resets_queue(self):
-        # Second arrival lands after the first completes: no wait.
-        ttfts = bench.queueing_ttfts([1.0, 1.0], ["a", "a"], [0.0, 5.0])
-        assert ttfts == [1.0, 1.0]
-
-
-class TestRunConcurrent:
-    """The concurrent arm against real tiny engines: every request gets a
-    TTFT, queueing shows up, and decode load is served to completion."""
-
-    @staticmethod
-    def _fleet(n_pods=2, num_pages=64):
-        from llmd_kv_cache_tpu.core import TokenProcessorConfig
-        from llmd_kv_cache_tpu.models import engine as engine_mod
-        from llmd_kv_cache_tpu.models.llama import LlamaConfig
-        from llmd_kv_cache_tpu.scoring import Indexer, IndexerConfig
-
-        cfg = LlamaConfig.tiny()
-        indexer = Indexer(IndexerConfig(
-            token_processor_config=TokenProcessorConfig(
-                block_size_tokens=cfg.page_size)))
-        pods = bench.make_pods(
-            n_pods, cfg, engine_mod, indexer,
-            pod_kw={"num_pages": num_pages, "max_pages_per_seq": 16})
-        return pods, indexer
-
-    def test_all_requests_served_with_queueing(self):
-        import numpy as np
-
-        pods, _ = self._fleet()
-        wl = bench.build_workload(np.random.default_rng(3), n_requests=8,
-                                  n_prefixes=2, prefix_len=12, suffix_len=4,
-                                  vocab=200)
-        # Two bursts: 4 requests at t=0 (they must queue behind each
-        # other's service) and 4 long after (no queueing).
-        arrivals = [0.0, 0.0, 0.0, 0.0, 1e6, 1e6 + 1, 1e6 + 2, 1e6 + 3]
-        ttfts, hit, out_tps, decode = bench.run_concurrent(
-            pods, wl, bench.make_rr_router(), arrivals,
-            max_new_tokens=4)
-        assert len(ttfts) == 8 and all(t > 0 for t in ttfts)
-        assert 0.0 <= hit <= 1.0
-        # 8 requests x 4 decoded tokens over a positive makespan.
-        assert out_tps > 0
-        # Decode latency accounting: 3 inter-token gaps per request (4
-        # tokens), one TPOT per request, all positive virtual times.
-        assert len(decode["itl"]) == 8 * 3
-        assert len(decode["tpot"]) == 8
-        assert all(g > 0 for g in decode["itl"])
-        assert all(t > 0 for t in decode["tpot"])
-        # Every request decoded to completion through step().
-        for p in pods.values():
-            assert not p._running
-        # The t=0 burst on each pod queues: later requests of the burst
-        # wait for earlier ones, so the burst's worst TTFT strictly
-        # exceeds its best (same pods serve one prefill at a time).
-        burst = sorted(ttfts[:4])
-        assert burst[-1] > burst[0]
-
-    def test_page_pressure_defers_admission(self):
-        import numpy as np
-
-        # A pool sized for ~1.5 in-flight requests: the second concurrent
-        # admission must retry until the first finishes, not crash.
-        pods, _ = self._fleet(n_pods=1, num_pages=24)
-        wl = bench.build_workload(np.random.default_rng(4), n_requests=4,
-                                  n_prefixes=1, prefix_len=12, suffix_len=4,
-                                  vocab=200)
-        arrivals = [0.0, 0.0, 0.0, 0.0]
-        ttfts, _, _, _ = bench.run_concurrent(
-            pods, wl, lambda *_a, **_kw: "pod-0", arrivals,
-            max_new_tokens=4)
-        assert len(ttfts) == 4 and all(t > 0 for t in ttfts)
 
 
 class TestBenchModes:
@@ -139,24 +38,46 @@ class TestBenchModes:
         assert set(parsed) == {"metric", "value", "unit", "vs_baseline"}
 
 
-class TestRoutingBenchNeedsAChip:
-    """The routing benchmark runs in-process on a TPU or not at all: no
-    probe, no child process, no CPU or index-microbenchmark stand-in."""
+class TestNoDeviceModes:
+    """``bench.py`` asks the device nothing: with no mode, or with a flag
+    of one of the device modes it used to have, it exits non-zero with no
+    result line and points at the benchmark that does."""
 
-    def test_default_mode_refuses_without_a_tpu(self):
-        import pytest
+    @pytest.mark.parametrize("flag", [
+        None, "--ttft", "--ttft-load", "--offload", "--decode",
+        "--decode-hybrid", "--ragged", "--fp8-bandwidth", "--disagg"])
+    def test_refuses_and_names_kvbench(self, flag):
+        out = subprocess.run(
+            [sys.executable, "bench.py"] + ([flag] if flag else []),
+            capture_output=True, text=True, timeout=60, cwd=ROOT,
+            env={"PATH": "/usr/bin:/bin:/opt/venv/bin"})
+        assert out.returncode != 0
+        assert out.stdout.strip() == ""
+        assert "kvbench/run.py" in out.stderr
 
-        with pytest.raises(SystemExit) as exc:
-            bench._dispatch(["bench.py"])
-        # SystemExit with a message exits 1 and prints it to stderr; no
-        # result line is produced.
-        assert "needs a TPU" in str(exc.value.code)
-        assert "'cpu'" in str(exc.value.code)
 
-    def test_the_ladder_is_gone(self):
-        for name in ("guarded_main", "_accelerator_healthy",
-                     "_run_ttft_subprocess"):
-            assert not hasattr(bench, name)
+def _documents():
+    return [p.relative_to(ROOT).as_posix() for p in (
+        ROOT / "Makefile", ROOT / "README.md",
+        ROOT / "benchmarking" / "perf_baseline.json",
+        ROOT / "benchmarking" / "README.md", ROOT / "examples" / "README.md",
+        *sorted((ROOT / "docs").glob("*.md")),
+        *sorted((ROOT / ".github" / "workflows").glob("*.yaml")))]
+
+
+class TestDocumentsNameLiveModes:
+    """Every ``bench.py --<mode>`` and ``make bench-<target>`` a document
+    names exists: the documents cannot drift from the files again."""
+
+    @pytest.mark.parametrize("document", _documents())
+    def test_named_modes_and_targets_exist(self, document):
+        text = (ROOT / document).read_text()
+        modes = set(re.findall(r"bench\.py`*\s+`*(--[a-z][a-z-]*)", text))
+        assert modes <= set(bench.MODES), sorted(modes - set(bench.MODES))
+        makefile = (ROOT / "Makefile").read_text()
+        targets = set(re.findall(r"^([a-z][a-z0-9-]*):", makefile, re.M))
+        named = set(re.findall(r"make\s+(bench[a-z0-9-]*)", text))
+        assert named <= targets, sorted(named - targets)
 
 
 class TestPerfSentinel:

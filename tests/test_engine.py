@@ -177,6 +177,30 @@ class TestPageAccounting:
             info.ref_count == 0 for info in engine.block_manager.blocks.values()
         )
 
+    def test_page_pressure_defers_admission(self):
+        # 23 usable pages hold two of these requests at once ((28+4+3)//4+1
+        # = 9 pages each): an enqueue the pool cannot hold raises, leaves
+        # the engine serving, and succeeds once steps have freed pages.
+        engine = make_engine(num_pages=24)
+        prompts = [list(range(100 * i, 100 * i + 28)) for i in range(1, 5)]
+        waiting = list(enumerate(prompts))
+        requests, deferred = [], 0
+        while waiting or engine._running:
+            while waiting:
+                i, prompt = waiting[0]
+                try:
+                    requests.append(
+                        engine.enqueue(f"r{i}", prompt, max_new_tokens=4))
+                except RuntimeError:
+                    assert engine._running  # or nothing would free pages
+                    deferred += 1
+                    break
+                waiting.pop(0)
+            engine.step()
+        assert deferred > 0
+        assert len(requests) == 4
+        assert all(r.done and len(r.output) == 4 for r in requests)
+
     def test_reset_with_inflight_requests_frees_all_pages(self):
         engine = make_engine()
         free_before = engine.block_manager.num_free()

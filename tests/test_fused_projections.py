@@ -101,10 +101,9 @@ class TestFusedParity:
 
 class TestEngineFusion:
     def test_engine_auto_fusion_is_shape_aware(self):
-        # Auto (fuse_projections=None) consults fuse_profitable: the v5e
-        # measured fusion ~8% SLOWER at hidden 2048 and ~7% faster at
-        # hidden 4096 (ROADMAP aim 1), so narrow test/bench models
-        # stay unfused and wide single-shard engines fuse.
+        # Auto (fuse_projections=None) consults fuse_profitable (fuse
+        # from a per-shard hidden width of 4096 up), so narrow test
+        # models stay unfused and wide single-shard engines fuse.
         from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
         from llmd_kv_cache_tpu.models.llama import fuse_profitable
 

@@ -205,8 +205,8 @@ def test_engine_mixed_batch_matches_padded_path():
     exactly the padded two-kernel fallback's greedy streams (fp32 model —
     bf16 hits top-2 logit ties that flip on program-level rounding).
 
-    ~50 s of jit compiles (both dispatch programs at fp32), so tier-1
-    relies on ``make bench-ragged`` for the same engine-level gate."""
+    ~50 s of jit compiles (both dispatch programs at fp32), so it is
+    marked slow and tier-1 does not run it."""
     from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
     from llmd_kv_cache_tpu.models.llama import LlamaConfig, init_params
 
