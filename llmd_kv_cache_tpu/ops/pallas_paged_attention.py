@@ -8,11 +8,16 @@ with double-buffered async DMA and folds them into an online softmax — the
 ragged-paged-attention recipe.
 
 Pages stream in **superblocks** of ``pages_per_block`` pages (default
-targets 128 keys): each online-softmax round is then a full-width MXU
-matmul and a 64 KB-class DMA batch, instead of one page_size-wide sliver
-per round. Matmul operands stay in the cache dtype (bf16×bf16, fp32
-accumulate — the MXU fast path) with the softmax scale applied to the
-fp32 scores, matching the XLA reference's numerics.
+targets 1024 keys): a superblock is one batch of DMAs in flight, and an
+online-softmax round over it is a full-width MXU matmul instead of one
+page_size-wide sliver per page. The prefill and ragged kernels stream and
+fold every superblock whole. The decode kernels stream and fold a row's
+*live* keys: of a row's last superblock only the 128-key granules that
+hold keys of the row are copied, waited for and folded, so a row of 330
+keys under a 1024-key superblock pays for 384 (``_live_granules``).
+Matmul operands stay in the cache dtype (bf16×bf16, fp32 accumulate — the
+MXU fast path) with the softmax scale applied to the fp32 scores,
+matching the XLA reference's numerics.
 
 Grid: ``(batch, kv_heads)`` for decode, ``(batch, kv_heads, q_blocks)``
 for prefill. Scalar-prefetched page table + context lengths drive the DMA
@@ -28,6 +33,7 @@ in interpreter mode against it).
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -108,11 +114,15 @@ def _superblock_streamer(page_table_ref, b, h, k_hbm, v_hbm, k_scratch,
     ``page_for`` (internal) maps a loop counter to a page-table index —
     sink pages ([0, sink_pages)) first, then window pages
     ([first_window, …)) — with DMA-safe clamping for sub-pages past
-    ``num_iters`` (their garbage loads are masked out by position).
+    ``num_iters``: they copy the row's last page again and are masked
+    out by position.
     Returns ``(positions, sb_dma)``: the flat-lane key-position builder
-    for the mask and the double-buffered DMA batch. One definition for
-    both kernels so the clamp/remap subtleties cannot drift between
-    them.
+    for the mask and the double-buffered DMA batch. Both take the whole
+    superblock by default, which is what the prefill and ragged kernels
+    stream and fold every round; the decode kernels ask for one granule
+    of it at a time (``_live_granules``) and leave what lies past the
+    row's keys alone. One definition for every kernel so the clamp/remap
+    subtleties cannot drift between them.
 
     ``shared_kv`` (absorbed MLA: values ARE the latent keys) streams each
     page ONCE into the K scratch and skips the V stream entirely —
@@ -153,9 +163,11 @@ def _superblock_streamer(page_table_ref, b, h, k_hbm, v_hbm, k_scratch,
         src = hbm if layer_idx is None else hbm.at[layer_idx]
         return src.at[page] if h is None else src.at[page, h]
 
-    def sb_dma(slot, sb):
+    def sb_dma(slot, sb, pages=range(kpb)):
+        """The K (and V) copies of superblock ``sb``'s sub-pages ``pages``
+        into staging slot ``slot``; a sub-page may be a traced index."""
         copies = []
-        for t in range(kpb):
+        for t in pages:
             page = page_table_ref[b, page_for(sb * kpb + t)]
             copies.append(pltpu.make_async_copy(
                 page_src(k_hbm, page), dst(k_scratch, slot, t),
@@ -168,8 +180,10 @@ def _superblock_streamer(page_table_ref, b, h, k_hbm, v_hbm, k_scratch,
                 ))
         return copies
 
-    def positions(sb, park, page_size):
-        """Key positions for superblock ``sb`` as [1, kpb*page_size] i32.
+    def positions(sb, park, page_size, first=0, pages=kpb):
+        """Key positions of ``pages`` sub-pages of superblock ``sb`` from
+        its sub-page ``first`` on (the whole superblock by default), as
+        [1, pages*page_size] i32.
 
         Built directly in the flat lane layout — Mosaic's
         infer-vector-layout rejects the (kpb, page_size) →
@@ -178,9 +192,9 @@ def _superblock_streamer(page_table_ref, b, h, k_hbm, v_hbm, k_scratch,
         Sub-pages past ``num_iters`` park at ``park`` (a position every
         mask term rejects: ctx_len for decode, total_len for prefill).
         """
-        j = jax.lax.broadcasted_iota(jnp.int32, (1, kpb * page_size), 1)
+        j = jax.lax.broadcasted_iota(jnp.int32, (1, pages * page_size), 1)
         jp = j // page_size
-        sub = sb * kpb + jp
+        sub = sb * kpb + first + jp
         pos = page_for(sub) * page_size + (j - jp * page_size)
         return jnp.where(sub < num_iters, pos, park)
 
@@ -274,6 +288,175 @@ def _tail_fold(q_h, k_t, v_t, tail_len, ctx_len, m, l, acc, *,
     return m_new, l_new, acc_new
 
 
+# Keys of a decode granule: the width of one MXU pass on a v5e, and eight
+# pages of 16 tokens.
+_GRANULE_KEYS = 128
+
+
+def _granule_pages(pages_per_block: int, page_size: int) -> int:
+    """Pages a decode granule holds: ``_GRANULE_KEYS`` keys where the
+    superblock is a whole number of such granules, else the superblock
+    (one narrower than a granule, or an explicit ``pages_per_block`` that
+    granules do not divide)."""
+    pages = max(1, _GRANULE_KEYS // page_size)
+    return pages if pages_per_block % pages == 0 else pages_per_block
+
+
+class _LiveGranules(NamedTuple):
+    """What a decode kernel asks of one row's stream (``_live_granules``)."""
+    num_sb: jax.Array  # rounds the row takes
+    start: Callable  # (slot, sb): start round sb's live copies, if any
+    fold_round: Callable  # (slot, sb, state, fold) -> state after round sb
+    staged: Callable  # (scratch, slot, g) -> ref of the pages staged there
+    mask: Callable  # (sb, g) -> [1, keys] attendability of those pages' keys
+
+
+def _live_granules(page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch,
+                   sem, *, ctx_len, q_end, page_size, kpb, sliding_window,
+                   sinks, shared_kv, shared_copy, layer_idx, row=None):
+    """One decode row's stream over keys [0, ctx_len), cut to the keys it
+    has. The superblock stays the DMA batch (``kpb`` pages a round, double
+    buffered); inside it a *granule* of ``_granule_pages`` pages is the
+    unit that is copied, waited for and folded. Granule ``g`` of round
+    ``sb`` is live while ``sb*kpb + g*pages < num_iters``. A live granule
+    is streamed whole (its sub-pages past ``num_iters`` are the streamer's
+    clamped copies, so every key a fold reads was written by a copy of
+    this round); a dead one is neither started, nor waited for, nor
+    folded: ``live(sb)`` is the one bound of all three. A row of 330 keys
+    therefore moves and multiplies 384, not the 1024 of its superblock,
+    and what an earlier row left in the rest of the scratch is never
+    read. Past a row's last round ``live`` is 0, which is the per-row
+    guard of a multi-row program: the row's state passes through.
+
+    ``fold_round`` is the schedule. A round whose granules are all live
+    (every round but the last of a long row) waits for the batch and
+    folds it as one ``kpb*page_size``-wide round, as it always did: one
+    wide fold costs less per key than eight narrow ones (v5e, PR 33:
+    8.2 against 11.2 us per 1024 keys of 8 kv heads). A round cut short
+    folds its live granules one by one in a ``fori_loop`` — lowered once,
+    whatever the count — waiting for each as it comes, so a fold runs
+    under the copies of the granules behind it. ``fold(state, g)`` is the
+    kernel's: fold granule ``g``, or the whole superblock when ``g`` is
+    None, read through ``staged`` and ``mask``.
+
+    With ``shared_copy`` what has landed is mirrored from the K scratch
+    into the V scratch before its fold (absorbed MLA measured 2x SLOWER
+    with v aliased to k at b8/ctx4k, July 2026, ROADMAP D3: one buffer
+    feeding a head_dim-contraction and a key-contraction forces Mosaic
+    into per-round relayouts; a local VMEM->VMEM copy gives each matmul
+    its own buffer while HBM still sees ONE latent read)."""
+    pages = _granule_pages(kpb, page_size)
+    per_round = kpb // pages
+    first_window, sink_pages, num_iters = _decode_stream_bounds(
+        ctx_len, q_end, page_size, sliding_window, sinks)
+    positions, sb_dma = _superblock_streamer(
+        page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch, sem,
+        kpb=kpb, num_iters=num_iters, first_window=first_window,
+        sink_pages=sink_pages, sinks=sinks, shared_kv=shared_kv,
+        layer_idx=layer_idx, row=row)
+
+    def live(sb):
+        return jnp.clip((num_iters - sb * kpb + pages - 1) // pages,
+                        0, per_round)
+
+    def copies(slot, sb, g):
+        return sb_dma(slot, sb, [g * pages + t for t in range(pages)])
+
+    def start(slot, sb):
+        def granule(g, carry):
+            for c in copies(slot, sb, g):
+                c.start()
+            return carry
+
+        jax.lax.fori_loop(0, live(sb), granule, None)
+
+    def staged(buf, slot, g):
+        view = buf.at[slot] if row is None else buf.at[slot, row]
+        return view if g is None else view.at[pl.ds(g * pages, pages)]
+
+    def mirror(slot, g):
+        if shared_copy:
+            # The V semaphore of the first page mirrored: the V stream is
+            # skipped, so nothing else signals it.
+            done = sem.at[slot] if row is None else sem.at[slot, row]
+            cp = pltpu.make_async_copy(
+                staged(k_scratch, slot, g), staged(v_scratch, slot, g),
+                done.at[0 if g is None else g * pages, 1])
+            cp.start()
+            cp.wait()
+
+    def mask(sb, g):
+        # The pages may straddle the sink→window jump; per-sub-page
+        # positions keep the mask exact. Sub-pages past num_iters park at
+        # ctx_len so every mask term rejects them.
+        first, n = (0, kpb) if g is None else (g * pages, pages)
+        return _decode_mask(
+            positions(sb, ctx_len, page_size, first=first, pages=n),
+            ctx_len, q_end, sliding_window, sinks)
+
+    def fold_round(slot, sb, state, fold):
+        n = live(sb)
+
+        def landed(g):
+            for c in copies(slot, sb, g):
+                c.wait()
+
+        def granule(g, state):
+            landed(g)
+            mirror(slot, g)
+            return fold(state, g)
+
+        def cut(state):
+            return jax.lax.fori_loop(0, n, granule, state)
+
+        def whole(state):
+            jax.lax.fori_loop(0, per_round, lambda g, _: landed(g), None)
+            mirror(slot, None)
+            return fold(state, None)
+
+        if per_round == 1:  # the granule is the superblock
+            return cut(state)
+        return jax.lax.cond(n == per_round, whole, cut, state)
+
+    return _LiveGranules((num_iters + kpb - 1) // kpb, start, fold_round,
+                         staged, mask)
+
+
+def _fold(q_h, k, v, ok, m, l, acc, *, scale):
+    """One online-softmax round of one head: fold keys ``k`` / values
+    ``v`` [keys, head_dim], attendable where ``ok`` [1, keys], into the
+    state of queries ``q_h`` [group, head_dim]. Operands stay in the
+    cache dtype with the scale applied to the fp32 scores after the
+    matmul: bf16×bf16 + fp32 accumulate is the MXU fast path and matches
+    the XLA reference's numerics. At least one key of a first fold must
+    be attendable (an all-masked round with m still at -inf would turn
+    exp(scores - m) into exp(0) garbage): every page a row streams holds
+    one."""
+    scores = jax.lax.dot_general(
+        q_h, k, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [group, keys]
+    scores = jnp.where(ok, scores, _NEG_INF)
+
+    m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+    p = jnp.exp(scores - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_new = acc * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l_new, acc_new
+
+
+def _fold_state(group, head_dim):
+    """(m, l, acc) of one head before its first fold."""
+    return (jnp.full((group, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((group, 1), jnp.float32),
+            jnp.zeros((group, head_dim), jnp.float32))
+
+
 def _decode_kernel(
     # scalar prefetch
     page_table_ref,  # [batch, pages_per_seq] int32 (SMEM)
@@ -306,11 +489,9 @@ def _decode_kernel(
     b = pl.program_id(0)
     h = pl.program_id(1)
     group, head_dim = q_ref.shape[2], q_ref.shape[3]
-    kpb = pages_per_block
 
     ctx_len = ctx_lens_ref[b]
     tail_len = tail_lens_ref[b] if has_tail else jnp.int32(0)
-    q_end = ctx_len + tail_len
     # SWA: pages entirely outside the window are skipped, so long contexts
     # stream only ~window/page_size pages. Attention sinks (StreamingLLM,
     # reference events.go:40 sink_full_attention) additionally stream the
@@ -318,93 +499,40 @@ def _decode_kernel(
     # page index — sink pages [0, sink_pages) first, then window pages
     # [first_window, num_pages) — so the double-buffered DMA pipeline is
     # unchanged and the skipped middle costs nothing.
-    first_window, sink_pages, num_iters = _decode_stream_bounds(
-        ctx_len, q_end, page_size, sliding_window, sinks)
-    # Pages stream in superblocks of ``kpb``: each round waits on one
-    # batch of kpb in-flight DMAs (4 KB single-page transfers underuse
-    # HBM bandwidth; a 128-key superblock moves 64 KB per K/V round) and
-    # feeds the MXU a [head_dim, kpb·page_size] operand instead of a
-    # page_size-wide sliver. A superblock may straddle the sink→window
-    # jump; per-sub-page positions keep the mask exact.
-    num_sb = (num_iters + kpb - 1) // kpb
-
-    sb_positions, sb_dma = _superblock_streamer(
+    #
+    # Pages stream in superblocks of ``pages_per_block``: a round is one
+    # batch of in-flight DMAs (4 KB single-page transfers underuse HBM
+    # bandwidth) and feeds the MXU a [head_dim, kpb·page_size] operand
+    # instead of a page_size-wide sliver; a round the row does not fill
+    # is cut to its live 128-key granules (``_live_granules``).
+    st = _live_granules(
         page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch, sem,
-        kpb=kpb, num_iters=num_iters, first_window=first_window,
-        sink_pages=sink_pages, sinks=sinks, shared_kv=shared_kv,
+        ctx_len=ctx_len, q_end=ctx_len + tail_len, page_size=page_size,
+        kpb=pages_per_block, sliding_window=sliding_window, sinks=sinks,
+        shared_kv=shared_kv, shared_copy=shared_copy,
         layer_idx=layer_ref[0] if stacked else None)
 
-    @pl.when(num_sb > 0)
-    def _():
-        for c in sb_dma(0, 0):
-            c.start()
+    st.start(0, 0)
+    q = q_ref[0, 0]  # [group, head_dim], cache dtype (see ``_fold``)
 
-    # Cache-dtype q, scale applied to the fp32 scores after the matmul:
-    # bf16×bf16 + fp32 accumulate is the MXU fast path and matches the
-    # XLA reference's numerics.
-    q = q_ref[0, 0]  # [group, head_dim]
-
-    def body(sb, carry):
-        m_prev, l_prev, acc_prev = carry
+    def body(sb, state):
         slot = sb % 2
-        next_slot = (sb + 1) % 2
+        st.start((sb + 1) % 2, sb + 1)  # nothing past the last round
 
-        @pl.when(sb + 1 < num_sb)
-        def _():
-            for c in sb_dma(next_slot, sb + 1):
-                c.start()
+        def fold(state, g):
+            # [pages, page_size, head_dim] → leading-collapse reshape
+            # (lane dim unchanged).
+            k = st.staged(k_scratch, slot, g)[...].reshape(-1, head_dim)
+            if shared_kv and not shared_copy:
+                v = k
+            else:
+                v = st.staged(v_scratch, slot, g)[...].reshape(-1, head_dim)
+            return _fold(q, k, v, st.mask(sb, g), *state, scale=scale)
 
-        for c in sb_dma(slot, sb):
-            c.wait()
+        return st.fold_round(slot, sb, state, fold)
 
-        k = k_scratch[slot].reshape(kpb * page_size, head_dim)
-        if shared_kv and shared_copy:
-            # Absorbed MLA measured 2x SLOWER with v aliased to k at
-            # b8/ctx4k (July 2026, ROADMAP D3): one buffer
-            # feeding both matmuls — head_dim-contraction for scores,
-            # key-contraction for the output — forces Mosaic into
-            # per-round relayouts. A local VMEM->VMEM copy gives each
-            # matmul its own buffer while HBM still sees ONE latent
-            # read (the point of caching only the latent).
-            cp = pltpu.make_async_copy(
-                k_scratch.at[slot], v_scratch.at[slot], sem.at[slot, 0, 1])
-            cp.start()
-            cp.wait()
-            v = v_scratch[slot].reshape(kpb * page_size, head_dim)
-        else:
-            v = k if shared_kv else v_scratch[slot].reshape(
-                kpb * page_size, head_dim)
-
-        scores = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [group, kpb*page_size]
-
-        # mask slots beyond the context length on the last page (and, for
-        # SWA, positions that fell out of the window — unless they are
-        # sink positions, which stay attendable forever); sub-pages past
-        # num_iters park at ctx_len so every mask term rejects them.
-        positions = sb_positions(sb, ctx_len, page_size)
-        in_bounds = _decode_mask(positions, ctx_len, q_end, sliding_window,
-                                 sinks)
-        scores = jnp.where(in_bounds, scores, _NEG_INF)
-
-        m_cur = jnp.max(scores, axis=1, keepdims=True)  # [group, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(scores - m_new)  # [group, kpb*page_size]
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc_prev * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((group, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((group, 1), jnp.float32)
-    acc0 = jnp.zeros((group, head_dim), jnp.float32)
-    m_fin, l_fin, acc = jax.lax.fori_loop(0, num_sb, body, (m0, l0, acc0))
+    m_fin, l_fin, acc = jax.lax.fori_loop(
+        0, st.num_sb, body, _fold_state(group, head_dim))
     if has_tail:
         k_t = tail_k_ref[0, 0]  # [T, head_dim]; head picked by the block
         v_t = k_t if shared_kv else tail_v_ref[0, 0]
@@ -451,190 +579,110 @@ def _decode_kernel_merged(
 
     ``quant``: the cache operands/scratch hold 1-byte (fp8 e4m3) pages
     in the flat whole-page layout ``[.., kv_heads*page_size, head_dim]``
-    (see the wrapper's quant arm); each round upcasts the staged
-    superblock to the query dtype once and the head loop slices the
-    upcast value — HBM moved half the bytes, the MXU still sees bf16.
+    (see the wrapper's quant arm); each fold upcasts the staged granule
+    to the query dtype once and the head loop slices the upcast value —
+    HBM moved half the bytes, the MXU still sees bf16.
 
     The per-head grid (``_decode_kernel``) pays pipeline fill/drain and
     per-page 4 KB DMAs once per (batch, head) program. Merging heads
     makes each sub-page copy one whole-page transfer carrying all kv
     heads (DMA count ÷ kv_heads), computes the position mask once per
-    round instead of per head, and amortizes the program overhead over
+    granule instead of per head, and amortizes the program overhead over
     kv_heads× more work. The head loop is a static Python unroll of
     per-head [group, head_dim]×[head_dim, keys] matmuls over the shared
-    streamed superblock.
+    streamed granule; the loop over a round's live granules is a
+    ``fori_loop`` (``_live_granules``), so the body is lowered once.
 
     ``batch_rows > 1`` additionally co-schedules several batch items per
-    program: each round issues every row's superblock DMAs together
-    (more copies in flight against the same HBM latency) and the
-    pipeline fills/drains once per program instead of once per batch
-    item. Rows already out of rounds skip their DMAs and carry their
-    state through unchanged; ragged contexts therefore cost bandwidth
-    only up to each row's own length. VMEM budgeting in the wrapper
-    divides the superblock across rows, so keys-per-round shrinks as
-    rows grow — the on-chip sweep picks the operating point.
+    program: each round issues every row's live copies together (more
+    copies in flight against the same HBM latency) and the pipeline
+    fills/drains once per program instead of once per batch item. A row
+    out of rounds has no live granule: it starts, waits and folds
+    nothing and its state passes through; ragged contexts therefore cost
+    bandwidth only up to each row's own length. VMEM budgeting in the
+    wrapper divides the superblock across rows, so keys-per-round
+    shrinks as rows grow — the on-chip sweep picks the operating point.
     """
     b0 = pl.program_id(0)
     rows, kv_heads, group = q_ref.shape[0], q_ref.shape[1], q_ref.shape[2]
     head_dim = q_ref.shape[3]
-    kpb = pages_per_block
 
-    ctx_len, tail_len, q_end = [], [], []
-    num_iters, num_sb_r, streamers = [], [], []
+    ctx_len, tail_len, streams = [], [], []
     for r in range(rows):
         b = b0 * rows + r
-        cl = ctx_lens_ref[b]
-        tl = tail_lens_ref[b] if has_tail else jnp.int32(0)
-        qe = cl + tl
-        fw, sp, ni = _decode_stream_bounds(
-            cl, qe, page_size, sliding_window, sinks)
-        ctx_len.append(cl)
-        tail_len.append(tl)
-        q_end.append(qe)
-        num_iters.append(ni)
-        num_sb_r.append((ni + kpb - 1) // kpb)
-        streamers.append(_superblock_streamer(
+        ctx_len.append(ctx_lens_ref[b])
+        tail_len.append(tail_lens_ref[b] if has_tail else jnp.int32(0))
+        streams.append(_live_granules(
             page_table_ref, b, None, k_hbm, v_hbm, k_scratch, v_scratch,
-            sem, kpb=kpb, num_iters=ni, first_window=fw, sink_pages=sp,
-            sinks=sinks, shared_kv=shared_kv,
+            sem, ctx_len=ctx_len[r], q_end=ctx_len[r] + tail_len[r],
+            page_size=page_size, kpb=pages_per_block,
+            sliding_window=sliding_window, sinks=sinks, shared_kv=shared_kv,
+            shared_copy=shared_copy,
             layer_idx=layer_ref[0] if stacked else None,
             row=r if rows > 1 else None))
-    num_sb = num_sb_r[0]
-    for r in range(1, rows):
-        num_sb = jnp.maximum(num_sb, num_sb_r[r])
+    num_sb = functools.reduce(jnp.maximum, [st.num_sb for st in streams])
 
     def start_round(slot, sb):
-        # Per-row guard: a row past its rounds neither starts nor waits
-        # its copies (the same predicate gates both, below).
-        for r in range(rows):
-            @pl.when(sb < num_sb_r[r])
-            def _(r=r):
-                for c in streamers[r][1](slot, sb):
-                    c.start()
+        for st in streams:
+            st.start(slot, sb)
 
-    @pl.when(num_sb > 0)
-    def _():
-        start_round(0, 0)
-
+    start_round(0, 0)
     # qs[r][h]: [group, head_dim]
     qs = [[q_ref[r, h] for h in range(kv_heads)] for r in range(rows)]
 
     def body(sb, carry):
-        ms, ls, accs = carry
         slot = sb % 2
-        next_slot = (sb + 1) % 2
+        start_round((sb + 1) % 2, sb + 1)  # nothing past a last round
 
-        @pl.when(sb + 1 < num_sb)
-        def _():
-            start_round(next_slot, sb + 1)
-
-        new_ms = [list(row_m) for row_m in ms]
-        new_ls = [list(row_l) for row_l in ls]
-        new_accs = [list(row_a) for row_a in accs]
-        for r in range(rows):
-            @pl.when(sb < num_sb_r[r])
-            def _(r=r):
-                for c in streamers[r][1](slot, sb):
-                    c.wait()
-                if shared_copy:
-                    # Same rationale as _decode_kernel: mirror the row's
-                    # K superblock into the V scratch locally so each
-                    # matmul gets its own buffer (one HBM read).
-                    cp = pltpu.make_async_copy(
-                        k_scratch.at[slot] if rows == 1
-                        else k_scratch.at[slot, r],
-                        v_scratch.at[slot] if rows == 1
-                        else v_scratch.at[slot, r],
-                        sem.at[slot, 0, 1] if rows == 1
-                        else sem.at[slot, r, 0, 1])
-                    cp.start()
-                    cp.wait()
-
+        def fold(state, g, r, st):
             # Shared mask for every head: positions depend only on the
             # row's pages — the per-head grid recomputed this kv_heads×.
-            positions = streamers[r][0](sb, ctx_len[r], page_size)
-            in_bounds = _decode_mask(positions, ctx_len[r], q_end[r],
-                                     sliding_window, sinks)
-            # Row liveness: past its last round the row's state must pass
-            # through untouched (an all-masked round with m still at
-            # -inf would turn exp(scores - m) into exp(0) garbage).
-            live = sb * kpb < num_iters[r]
-
+            ok = st.mask(sb, g)
+            k_g = st.staged(k_scratch, slot, g)
+            v_g = k_g if shared_kv and not shared_copy else st.staged(
+                v_scratch, slot, g)
             if quant:
-                # One upcast of the whole staged superblock (the fp8→bf16
-                # convert is exact); every head slices the same value.
-                kq = (k_scratch[slot] if rows == 1
-                      else k_scratch[slot, r]).astype(q_ref.dtype)
-                vq = (v_scratch[slot] if rows == 1
-                      else v_scratch[slot, r]).astype(q_ref.dtype)
+                # One upcast of what was staged (the fp8→bf16 convert is
+                # exact); every head slices the same value.
+                k_g = k_g[...].astype(q_ref.dtype)
+                v_g = v_g[...].astype(q_ref.dtype)
 
+            def head(staged, h):
+                # This head's [pages, page_size, head_dim] → leading-
+                # collapse reshape (lane dim unchanged).
+                x = (staged[:, h * page_size:(h + 1) * page_size] if quant
+                     else staged[:, h])
+                return x.reshape(-1, head_dim)
+
+            new = []
             for h in range(kv_heads):
-                # [kpb, page_size, head_dim] slice of this head's keys →
-                # leading-collapse reshape (lane dim unchanged).
-                if quant:
-                    k = kq[:, h * page_size:(h + 1) * page_size, :].reshape(
-                        kpb * page_size, head_dim)
-                    v = vq[:, h * page_size:(h + 1) * page_size, :].reshape(
-                        kpb * page_size, head_dim)
-                elif shared_kv and not shared_copy:
-                    ks = k_scratch[slot, :, h] if rows == 1 else \
-                        k_scratch[slot, r, :, h]
-                    k = v = ks.reshape(kpb * page_size, head_dim)
-                else:
-                    ks = k_scratch[slot, :, h] if rows == 1 else \
-                        k_scratch[slot, r, :, h]
-                    k = ks.reshape(kpb * page_size, head_dim)
-                    vs = v_scratch[slot, :, h] if rows == 1 else \
-                        v_scratch[slot, r, :, h]
-                    v = vs.reshape(kpb * page_size, head_dim)
-                scores = jax.lax.dot_general(
-                    qs[r][h], k,
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale  # [group, kpb*page_size]
-                scores = jnp.where(in_bounds, scores, _NEG_INF)
+                k = head(k_g, h)
+                v = k if v_g is k_g else head(v_g, h)
+                new.append(_fold(qs[r][h], k, v, ok, *state[h], scale=scale))
+            return tuple(new)
 
-                m_cur = jnp.max(scores, axis=1, keepdims=True)
-                m_new = jnp.maximum(ms[r][h], m_cur)
-                p = jnp.exp(scores - m_new)
-                alpha = jnp.exp(ms[r][h] - m_new)
-                l_new = ls[r][h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-                acc_new = accs[r][h] * alpha + jax.lax.dot_general(
-                    p.astype(v.dtype), v,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                new_ms[r][h] = jnp.where(live, m_new, ms[r][h])
-                new_ls[r][h] = jnp.where(live, l_new, ls[r][h])
-                new_accs[r][h] = jnp.where(live, acc_new, accs[r][h])
-        to_t = lambda rows_list: tuple(tuple(x) for x in rows_list)
-        return to_t(new_ms), to_t(new_ls), to_t(new_accs)
+        return tuple(
+            st.fold_round(slot, sb, carry[r],
+                          functools.partial(fold, r=r, st=st))
+            for r, st in enumerate(streams))
 
-    m0 = tuple(tuple(jnp.full((group, 1), _NEG_INF, jnp.float32)
-                     for _ in range(kv_heads)) for _ in range(rows))
-    l0 = tuple(tuple(jnp.zeros((group, 1), jnp.float32)
-                     for _ in range(kv_heads)) for _ in range(rows))
-    acc0 = tuple(tuple(jnp.zeros((group, head_dim), jnp.float32)
-                       for _ in range(kv_heads)) for _ in range(rows))
-    ms, l_fin, accs = jax.lax.fori_loop(0, num_sb, body, (m0, l0, acc0))
-    ms = [list(x) for x in ms]
-    l_fin = [list(x) for x in l_fin]
-    accs = [list(x) for x in accs]
-
-    if has_tail:
-        for r in range(rows):
-            for h in range(kv_heads):
-                ms[r][h], l_fin[r][h], accs[r][h] = _tail_fold(
-                    qs[r][h], tail_k_ref[r, :, h],
-                    tail_k_ref[r, :, h] if shared_kv
-                    else tail_v_ref[r, :, h],
-                    tail_len[r], ctx_len[r], ms[r][h], l_fin[r][h],
-                    accs[r][h], scale=scale, sliding_window=sliding_window,
-                    sinks=sinks)
+    # state[r][h]: (m, l, acc)
+    state = jax.lax.fori_loop(
+        0, num_sb, body,
+        tuple(tuple(_fold_state(group, head_dim) for _ in range(kv_heads))
+              for _ in range(rows)))
 
     for r in range(rows):
         for h in range(kv_heads):
-            out = accs[r][h] / jnp.maximum(l_fin[r][h], 1e-30)
+            m_fin, l_fin, acc = state[r][h]
+            if has_tail:
+                m_fin, l_fin, acc = _tail_fold(
+                    qs[r][h], tail_k_ref[r, :, h],
+                    tail_k_ref[r, :, h] if shared_kv
+                    else tail_v_ref[r, :, h],
+                    tail_len[r], ctx_len[r], m_fin, l_fin, acc, scale=scale,
+                    sliding_window=sliding_window, sinks=sinks)
+            out = acc / jnp.maximum(l_fin, 1e-30)
             o_ref[r, h] = out.astype(o_ref.dtype)
 
 
@@ -1415,27 +1463,33 @@ def pallas_paged_decode_attention(
         raise ValueError("batch_rows > 1 requires the merged-heads kernel")
     batch_rows = max(1, min(batch_rows, batch))
     if pages_per_block is None:
-        # ~1024 keys per online-softmax round: fewer DMA waits and
-        # per-round fixed costs against the same bytes. Every accepted
-        # cell runs this default; the ledger has no pair across widths.
-        # The decode scores tile [group, keys] is small; the merged
-        # kernel's scratch carries every head per key, so its keys/round
-        # are clamped to keep the double-buffered K+V staging ≤ ~8 MB of
-        # VMEM. Clamped to the table's static page capacity so
-        # short-context configs don't pay for redundant clamped copies.
+        # ~1024 keys per DMA batch: a long row's round has that many keys
+        # in flight behind the one being folded. What a row pays follows
+        # its own keys, not this width: the kernels copy and fold a
+        # round's live 128-key granules only (``_live_granules``), so a
+        # short row under a wide superblock costs what it holds. Every
+        # accepted cell runs this default; the ledger has no pair across
+        # widths. The merged kernel's scratch carries every head per
+        # key, so its keys/round are clamped to keep the double-buffered
+        # K+V staging ≤ ~8 MB of VMEM; clamped to the table's static
+        # page capacity (no row has more to stream), and to a whole
+        # number of granules.
         keys = 1024
         if merge_heads:
             kv_streams = 1 if shared_kv else 2
-            # Quantized caches stage 1-byte pages but the per-round
-            # upcast materializes bf16 values of the same superblock, so
-            # budget as if 2-byte — the explicit pages_per_block knob
-            # (and the on-chip sweep) can still push wider.
+            # Quantized caches stage 1-byte pages but the per-granule
+            # upcast materializes bf16 values, so budget as if 2-byte —
+            # the explicit pages_per_block knob (and the on-chip sweep)
+            # can still push wider.
             budget = (8 * 2 ** 20) // (
                 2 * batch_rows * kv_heads * head_dim
                 * max(k_cache.dtype.itemsize, 2) * kv_streams)
             keys = min(keys, max(page_size, budget))
         pages_per_block = max(1, min(keys // page_size,
                                      page_table.shape[1]))
+        granule = max(1, _GRANULE_KEYS // page_size)
+        if pages_per_block > granule:
+            pages_per_block -= pages_per_block % granule
 
     q_blocked = q.reshape(batch, kv_heads, group, head_dim)
 

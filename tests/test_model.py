@@ -1,5 +1,9 @@
 """Paged-Llama model and ops tests (CPU backend, 8 virtual devices)."""
 
+import json
+from pathlib import Path
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -273,6 +277,53 @@ class TestNoLayerOfAPoolMoves:
         # The walk saw the program: its writes, and its kernel if it has one.
         assert "stablehlo.scatter" in seen
         assert ("stablehlo.custom_call" in seen) == prog.pallas
+
+
+def _benchmark_configs():
+    """name -> file of every configuration ``BENCHMARK.json`` runs."""
+    root = Path(__file__).resolve().parents[1]
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    return {c["name"]: root / c["file"] for c in bench["configs"]}
+
+
+class TestDecodeKernelLowersForTheChip:
+    """``forward_decode_pallas`` lowered for the TPU, here on the CPU, at
+    each benchmark configuration's rehearsal shape (one kv head: the
+    per-head kernel) and at that depth with the published heads and the
+    served page table (the merged kernel, 64 pages a superblock, eight
+    granules a round): what Pallas cannot hand to Mosaic (a guard on a
+    copy, a slice of the scratch at a traced page) fails here and not on
+    the chip."""
+
+    @pytest.mark.parametrize("served", [False, True],
+                             ids=["rehearsal", "served_heads"])
+    @pytest.mark.parametrize("name", list(_benchmark_configs()))
+    def test_decode_program_lowers(self, name, served):
+        from llmd_kv_cache_tpu.models.hf_loader import config_from_hf
+        from llmd_kv_cache_tpu.models.llama import forward_decode_pallas
+
+        conf = json.loads(_benchmark_configs()[name].read_text())
+        kv = conf.pop("kvbench")
+        toy = kv["rehearse"]
+        model = {**conf, **toy["model"]}
+        engine = {**kv["engine"], **toy["engine"]}
+        if served:
+            for key in ("num_attention_heads", "num_key_value_heads"):
+                model[key] = conf[key]
+            for key in ("max_pages_per_seq", "max_batch"):
+                engine[key] = kv["engine"][key]
+        cfg = config_from_hf(SimpleNamespace(**model),
+                             page_size=engine["page_size"],
+                             dtype=jnp.bfloat16)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        k_cache, v_cache = init_kv_cache(cfg, engine["num_pages"])
+        rows, width = engine["max_batch"], engine["max_pages_per_seq"]
+        text = forward_decode_pallas.trace(
+            params, cfg, jnp.zeros((rows, 1), jnp.int32), k_cache, v_cache,
+            jnp.zeros((rows, width), jnp.int32),
+            jnp.ones((rows,), jnp.int32), jnp.ones((rows,), jnp.int32),
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
 
 
 class TestStepForms:
