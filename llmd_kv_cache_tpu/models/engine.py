@@ -687,7 +687,24 @@ class MiniEngine:
                 raise ValueError(
                     "kv_cache_dtype=f8_e4m3 does not support MLA latent "
                     "pools yet (absorbed-attention latents are more "
-                    "quantization-sensitive; keep bf16)")
+                    "quantization-sensitive; keep bf16); an indexer's key "
+                    "stream shares the pool's type and stays bf16 with it")
+        if mcfg.is_dsa:
+            # A page of this model is two slabs (the latent and the
+            # indexer's key) under one id; what cannot carry both refuses
+            # here rather than serve half a page.
+            if offload_spec is not None:
+                raise ValueError(
+                    "a model with an indexer keeps two streams a page "
+                    f"(latent {mcfg.kv_cache_head_dim} + index key "
+                    f"{mcfg.index_head_dim} lanes); the storage tier's "
+                    "blocks hold one, so a restored block would come back "
+                    "without its index keys: serve it without an offload "
+                    "spec")
+            if mesh is not None:
+                raise ValueError(
+                    "learned sparse attention (index_topk) is served on "
+                    "one device: selection is not sharded over a mesh")
         if self.hybrid:
             num_swa = self.cfg.num_swa_pages or self.cfg.num_pages
             self.block_manager = BlockManager(
@@ -809,8 +826,10 @@ class MiniEngine:
             # Mosaic lane-tiling constraint (see ops.pallas_paged_attention
             # .head_dim_supported); interpreter-mode tests still cover such
             # shapes, on-chip serving falls back to XLA paged attention.
-            hint = (" (set LlamaConfig.latent_pad to align the latent "
-                    "width)" if mcfg.is_mla else "")
+            hint = (" (LlamaConfig.latent_pad aligns the latent width; "
+                    "hf_loader.config_from_hf sets it for a DeepSeek "
+                    "config, a LlamaConfig built by hand sets its own)"
+                    if mcfg.is_mla else "")
             logger.warning(
                 "cache payload width %d is not 128-aligned: Pallas "
                 "paged attention cannot compile on TPU, using XLA "
@@ -952,7 +971,13 @@ class MiniEngine:
         # smaller bucket mid-serving, cratering steady-state decode on
         # short generations.
         self._burst = 1
-        while self._burst * 2 <= self.cfg.decode_burst and self._pp == 1:
+        if mcfg.is_dsa and self.cfg.decode_burst > 1:
+            logger.warning(
+                "learned sparse attention decodes single-token (a burst's "
+                "tail has no index keys); decode_burst=%d clamped to 1",
+                self.cfg.decode_burst)
+        while (self._burst * 2 <= self.cfg.decode_burst and self._pp == 1
+               and not mcfg.is_dsa):
             self._burst *= 2
         # Latched when the SWA pool proves too small for burst transients:
         # the engine then decodes single-token for its lifetime (warned
@@ -970,6 +995,9 @@ class MiniEngine:
             blockers = []
             if self.hybrid:
                 blockers.append("hybrid attention groups (two page pools)")
+            if mcfg.is_dsa:
+                blockers.append("learned sparse attention (selection runs "
+                                "in the padded step programs)")
             if mesh is not None:
                 blockers.append("mesh-sharded serving (tp/sp/pp)")
             if self._burst != 1:
@@ -1268,6 +1296,19 @@ class MiniEngine:
                      rows=rows, tokens=tokens, padded=padded,
                      request_id=req.request_id, prefill_pos=req.prefill_pos,
                      process=self.cfg.pod_identifier)
+
+    def _device_counts(self, sp, counts: np.ndarray, tokens: int,
+                       program: str) -> None:
+        """What a step program counted on the device (the model's
+        ``step_counters``, behind its sampled tokens in the same array)
+        onto the phase that read them, with the program (``decode`` or
+        ``prefill``: of a prefill only the last chunk's tokens are read)
+        and the real tokens they are counts of."""
+        if len(counts) and sp is not NOOP_SPAN:
+            sp.set_attribute("counted_program", program)
+            sp.set_attribute("counted_tokens", tokens)
+            for name, n in zip(self.cfg.model.step_counters, counts):
+                sp.set_attribute(name, int(n))
 
     def _record_shed(self, outcome: str, priority: int) -> None:
         """Best-effort shed accounting: metric family + flight recorder.
@@ -2096,8 +2137,10 @@ class MiniEngine:
             return None
         req.last_logits = row
         req.prefill_pos = None
-        with phase(ph, PHASE_STEP_FETCH):
-            return int(np.asarray(token)[0])
+        with phase(ph, PHASE_STEP_FETCH) as sp:
+            picked = np.asarray(token)
+            self._device_counts(sp, picked[1:], len(chunk), "prefill")
+            return int(picked[0])
 
     def _commit_full_blocks(self, req: Request,
                             upto: Optional[int] = None) -> None:
@@ -2700,15 +2743,26 @@ class MiniEngine:
                 (last[:, None], tables, *swa_tables, ctx, new_lens))
 
         # Dispatched as every program of a step is: see _prefill_chunk.
-        with self._dispatch_phase(None, len(chunk), len(chunk), b):
+        with self._dispatch_phase(None, len(chunk), len(chunk), b) as sp:
             picked, _, pools = self._decode_forward(
                 self.params, self.cfg.model, self._to_dev(packed),
                 self._pools(), shapes=shapes)
             self._take_pools(pools)
             picked.copy_to_host_async()
+            topk = self.cfg.model.index_topk
+            if topk and sp is not NOOP_SPAN:
+                # What the step's selection reads a layer, from the rows'
+                # lengths: the index keys of the rows that score (more
+                # than index_topk keys) and the latents every row attends.
+                keys = ctx[:len(chunk)] + 1
+                sp.set_attribute(
+                    "index_keys", int(keys[keys > topk].sum()))
+                sp.set_attribute(
+                    "selected_keys", int(np.minimum(keys, topk).sum()))
         out = {}
-        with phase(ph, PHASE_STEP_FETCH):
+        with phase(ph, PHASE_STEP_FETCH) as sp:
             next_tokens = np.asarray(picked)
+            self._device_counts(sp, next_tokens[b:], len(chunk), "decode")
         tel = self.telemetry
         if tel is not None:
             # Padding-waste accounting for the padded path: len(chunk)

@@ -102,11 +102,14 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     # Phi's partial rotary, …) must refuse rather than convert to
     # silently-wrong logits.
     supported = ("llama", "mistral", "mixtral", "qwen2", "qwen3",
-                 "qwen3_moe", "deepseek_v2", "deepseek_v3")
+                 "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32")
     if hf_cfg.model_type not in supported:
         raise NotImplementedError(
             f"model_type {hf_cfg.model_type!r} is not supported "
-            f"(supported: {supported})")
+            f"(supported: {supported}); of the DeepSeek line what is still "
+            f"refused is a step of more than one token (the multi-token-"
+            f"prediction module is never built) and fp8 latent or index "
+            f"streams")
     act = getattr(hf_cfg, "hidden_act", "silu")
     if act not in ("silu", "swish"):
         raise NotImplementedError(
@@ -196,14 +199,34 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
 
 def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
                           rope_scaling: tuple = ()) -> LlamaConfig:
-    """DeepSeek-V2/V3 → absorbed-MLA config.
+    """DeepSeek-V2/V3/V3.2 → absorbed-MLA config.
 
-    Supported subset: dense MLP layers only (``num_hidden_layers <=
-    first_k_dense_replace``) and ``v_head_dim == qk_nope_head_dim`` (the
-    shared head_dim here); q-LoRA (the full V2/V3 form) and the direct q
-    projection (V2-lite) both convert. The parity test pins our
-    *absorbed* attention against HF's materialized MLA — a
-    cross-implementation check of the absorption algebra.
+    ``v_head_dim == qk_nope_head_dim`` (the shared head_dim here); q-LoRA
+    (the full V2/V3 form) and the direct q projection (V2-lite) both
+    convert. The parity test pins our *absorbed* attention against HF's
+    materialized MLA — a cross-implementation check of the absorption
+    algebra. Routed layers convert for V3's router (sigmoid, noaux_tc,
+    group-limited), served by the exact grouped dispatch; V2's softmax /
+    greedy router is still refused. ``deepseek_v32`` adds the lightning
+    indexer (``index_n_heads``, ``index_head_dim``, ``index_topk``): a
+    second stream in every page and top-k selection inside paged attention.
+
+    Two things are set here and nowhere else, so that every engine gets
+    them from the model's keys: ``latent_pad``, which brings the latent's
+    width (rank + rope) to the next multiple of the 128 lanes the Pallas
+    kernels copy by, and one chip's share of the expert layers: a top-level
+    ``layer_share`` of ``{"chips", "rank", "n_routed_experts"}`` says that
+    ``n_routed_experts`` (the key) counts the experts HELD, contiguous from
+    ``rank * held``, of a router that stays ``layer_share
+    ["n_routed_experts"]`` wide (``LlamaConfig.experts_held``). A sliced
+    vocabulary needs nothing: ``vocab_size`` rows are the slice. A top-level
+    ``embed_init_scale`` (no checkpoint has one) is what ``init_params``
+    draws the embedding at: ``LlamaConfig.embed_init_scale``.
+
+    Not built: ``num_nextn_predict_layers`` (multi-token prediction is a
+    step that yields more than one token a sequence; the model's own
+    inference code serves without it), and ``params_from_hf`` maps no
+    indexer tensor yet (random weights serve; a checkpoint refuses there).
     """
     if hf_cfg.v_head_dim != hf_cfg.qk_nope_head_dim:
         raise NotImplementedError(
@@ -213,16 +236,27 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
     moe_kw = {}
     first_dense = getattr(hf_cfg, "first_k_dense_replace", 0)
     if getattr(hf_cfg, "n_routed_experts", None) and n_layers > first_dense:
-        if hf_cfg.model_type != "deepseek_v3":
+        if hf_cfg.model_type not in ("deepseek_v3", "deepseek_v32"):
             raise NotImplementedError(
-                "MoE conversion is implemented for deepseek_v3 only "
-                "(V2's softmax/greedy router differs)")
+                "MoE conversion is implemented for deepseek_v3 and "
+                "deepseek_v32 (V2's softmax/greedy router differs)")
         if getattr(hf_cfg, "topk_method", "noaux_tc") not in (
                 "noaux_tc", None):
             raise NotImplementedError(
                 f"topk_method {hf_cfg.topk_method!r} unsupported")
+        share = getattr(hf_cfg, "layer_share", None)
+        if share:
+            held = int(hf_cfg.n_routed_experts)
+            if held * int(share["chips"]) != int(share["n_routed_experts"]):
+                raise ValueError(
+                    f"layer_share: {held} experts held x {share['chips']} "
+                    f"chips is not the router's "
+                    f"{share['n_routed_experts']}")
+            moe_kw["experts_held"] = (int(share["rank"]) * held, held)
         moe_kw = dict(
-            num_experts=hf_cfg.n_routed_experts,
+            moe_kw,
+            num_experts=int(share["n_routed_experts"]) if share
+            else hf_cfg.n_routed_experts,
             num_experts_per_token=hf_cfg.num_experts_per_tok,
             moe_layers=tuple(range(first_dense, n_layers)),
             n_shared_experts=hf_cfg.n_shared_experts,
@@ -231,7 +265,7 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
                         hf_cfg.topk_group,
                         int(bool(hf_cfg.norm_topk_prob)),
                         float(hf_cfg.routed_scaling_factor)),
-            moe_dispatch="dense",
+            moe_dispatch="grouped",
         )
     # DeepSeek yarn: the generic cos/sin attention factor applies via
     # rope_scaling; for deepseek_v3 ONLY, mscale_all_dim ADDITIONALLY
@@ -239,9 +273,14 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
     # DeepseekV3Attention.__init__ — DeepseekV2Attention has no such
     # term, verified against transformers 4.57; the V2 parity test pins
     # it).
+    index_kw = {}
+    if hf_cfg.model_type == "deepseek_v32":
+        index_kw = dict(index_n_heads=int(hf_cfg.index_n_heads),
+                        index_head_dim=int(hf_cfg.index_head_dim),
+                        index_topk=int(hf_cfg.index_topk))
     scale_mult = 1.0
     hf_rs = getattr(hf_cfg, "rope_scaling", None)
-    if (rope_scaling and hf_cfg.model_type == "deepseek_v3"
+    if (rope_scaling and hf_cfg.model_type in ("deepseek_v3", "deepseek_v32")
             and hf_rs and hf_rs.get("mscale_all_dim")):
         m = _yarn_get_mscale(float(hf_rs["factor"]),
                              float(hf_rs["mscale_all_dim"]))
@@ -260,10 +299,23 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
         dtype=dtype,
         kv_lora_rank=hf_cfg.kv_lora_rank,
         qk_rope_head_dim=hf_cfg.qk_rope_head_dim,
+        q_lora_rank=int(getattr(hf_cfg, "q_lora_rank", None) or 0),
+        latent_pad=_latent_pad(hf_cfg.kv_lora_rank
+                               + hf_cfg.qk_rope_head_dim),
         rope_scaling=rope_scaling,
         softmax_scale_mult=scale_mult,
+        embed_init_scale=float(getattr(hf_cfg, "embed_init_scale", 0.02)),
+        **index_kw,
         **moe_kw,
     )
+
+
+def _latent_pad(width: int) -> int:
+    """Zero lanes behind a latent of ``width`` (rank + rope) values: up to
+    the next multiple of the 128 lanes the Pallas kernels copy by (DeepSeek's
+    512 + 64 → 64). A latent narrower than one tile (test models) would be
+    mostly padding and never reaches a compiled kernel: none."""
+    return -width % 128 if width >= 128 else 0
 
 
 def _deinterleave(w: np.ndarray, dr: int) -> np.ndarray:

@@ -13,6 +13,7 @@ token step is one XLA program; donate the caches for in-place updates.
 
 from __future__ import annotations
 
+import inspect
 import math
 from dataclasses import dataclass
 from functools import partial
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import sparse_index
 from ..ops.kv_pages import page_writes, write_kv_pages
 from ..ops.paged_attention import paged_attention
 
@@ -43,6 +45,19 @@ SCOPE_LM_HEAD = "lm_head"
 SCOPE_SAMPLE = "sample"
 SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_MLP,
           SCOPE_EMBED, SCOPE_LM_HEAD, SCOPE_SAMPLE)
+# Inside ``attention`` and ``mlp``, in the programs of a model that has the
+# mechanism: the indexer's projections and its scores over a row's pages,
+# the choice of the keys a query keeps, attention over the chosen keys
+# alone (a decode step; a prefill chunk masks inside ``attention``), and
+# the routed experts' grouped matmuls with the sort before and the
+# combination after. No metric reads them yet (``kvbench/trace/reduce.py``
+# keeps an op's name and drops its scope: ROADMAP S0, device time by scope);
+# they are for whoever opens a profiler capture of a step, where every op's
+# name carries its scopes.
+SCOPE_INDEX = "dsa_index"
+SCOPE_SELECT = "dsa_select"
+SCOPE_SPARSE_ATTENTION = "dsa_attend"
+SCOPE_MOE_DISPATCH = "moe_dispatch"
 
 # The two step programs' names: what ``jax.jit`` calls the functions below
 # and a trace calls their executions (``jit_<name>``). A forward's step form
@@ -82,7 +97,10 @@ class LlamaConfig:
     # scales with tokens, not num_experts; overflow tokens lose their MoE
     # contribution (residual passes through). "dense" = every expert over
     # every token with a one-hot mix (exact, O(E) compute; useful as the
-    # reference formulation and for tiny models).
+    # reference formulation and for tiny models). "grouped" (the
+    # deepseek_v3 router only) = exact too: the tokens x k assignments that
+    # fall to the experts held are grouped by expert and run through one
+    # grouped matmul, so the work follows the assignments, not the experts.
     moe_dispatch: str = "capacity"
     moe_capacity_factor: float = 2.0
     # DeepSeek-style MoE extensions (all () /0 for the classic Mixtral
@@ -99,6 +117,13 @@ class LlamaConfig:
     n_shared_experts: int = 0
     moe_intermediate_size: int = 0
     moe_router: tuple = ()
+    # One chip's share of an expert layer (deepseek_v3 router):
+    # ``(first, count)`` = this layer holds experts ``[first, first +
+    # count)`` of ``num_experts``, which stays the router's width. It
+    # routes over all of them, computes its own experts' terms (weights
+    # normalised over all chosen experts, held or not) and adds the shared
+    # expert; what the absent experts would add is left out. () = all.
+    experts_held: tuple = ()
     # Multi-head latent attention (DeepSeek-V2/V3): KV is cached as one
     # per-token latent of ``kv_lora_rank`` dims plus a decoupled-RoPE key
     # of ``qk_rope_head_dim`` dims SHARED across heads — ~an order of
@@ -111,6 +136,27 @@ class LlamaConfig:
     # (reference events.go:34 KVCacheSpecKindMlaAttention).
     kv_lora_rank: int = 0
     qk_rope_head_dim: int = 0
+    # DeepSeek q-LoRA: > 0 → ``init_params`` makes the query's down
+    # projection ``w_dq`` with its norm (and the latent's norm,
+    # kv_a_layernorm, which every q-LoRA DeepSeek model has). The forward
+    # reads the tree, not this field: a checkpoint decides by its tensors.
+    q_lora_rank: int = 0
+    # Learned sparse attention (DeepSeek-V3.2's DSA; needs MLA with q-LoRA):
+    # every page holds a second stream, the indexer's key of
+    # ``index_head_dim`` values a token (the pool's V stack, which plain
+    # MLA leaves empty); a query scores its row's keys with
+    # ``index_n_heads`` light heads and attends the ``index_topk`` best
+    # (``ops.sparse_index``). 0 → every key is attended.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # Standard deviation ``init_params`` draws the embedding at (random
+    # weights only; a checkpoint brings its own). Every other matrix is
+    # drawn at 0.02. At 0.02 a first layer's input is smaller than what its
+    # attention adds, an average of random values over the keys attended;
+    # a configuration whose attention selects keys says here how large the
+    # residual it selects into is (its file's ``assumed``).
+    embed_init_scale: float = 0.02
     # Zero-padding appended to the MLA latent cache payload so its width
     # (rank + rope + pad) hits the Mosaic 128-lane alignment the Pallas
     # kernels need on real TPU — DeepSeek-V2 shapes set 64 (512+64+64=640).
@@ -174,10 +220,10 @@ class LlamaConfig:
         if self.moe_router:
             kind = self.moe_router[0]
             if kind == "deepseek_v3" and len(self.moe_router) == 5:
-                if self.moe_dispatch != "dense":
+                if self.moe_dispatch not in ("dense", "grouped"):
                     raise ValueError(
                         "the deepseek_v3 router is implemented for the "
-                        "exact 'dense' dispatch only")
+                        "exact dispatches only ('grouped', 'dense')")
                 n_group = self.moe_router[1]
                 if n_group < 1 or self.num_experts % n_group != 0:
                     raise ValueError(
@@ -197,6 +243,32 @@ class LlamaConfig:
         if self.moe_layers and not all(
                 0 <= i < self.num_layers for i in self.moe_layers):
             raise ValueError("moe_layers indices out of range")
+        if self.moe_dispatch == "grouped" and not (
+                self.moe_router and self.moe_router[0] == "deepseek_v3"):
+            raise ValueError(
+                "moe_dispatch='grouped' serves the deepseek_v3 router only")
+        if self.experts_held:
+            if not (self.moe_router and self.moe_router[0] == "deepseek_v3"):
+                raise ValueError(
+                    "experts_held (a chip's share of an expert layer) is "
+                    "implemented for the deepseek_v3 router")
+            first, count = self.experts_held
+            if count < 1 or first < 0 or first + count > self.num_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held!r} is not a range of "
+                    f"the router's {self.num_experts} experts")
+        if self.index_topk:
+            if not (self.is_mla and self.q_lora_rank > 0):
+                raise ValueError(
+                    "the indexer's queries come from the q latent: "
+                    "index_topk needs MLA with q_lora_rank > 0")
+            if (self.index_n_heads < 1
+                    or self.index_head_dim < self.qk_rope_head_dim):
+                raise ValueError(
+                    "index_topk needs index_n_heads >= 1 and "
+                    "index_head_dim >= qk_rope_head_dim (its rope dims)")
+        if self.q_lora_rank and not self.is_mla:
+            raise ValueError("q_lora_rank is an MLA knob")
         if self.rope_scaling:
             ok = (self.rope_scaling[0] == "llama3"
                   and len(self.rope_scaling) == 5) or (
@@ -268,6 +340,27 @@ class LlamaConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def is_dsa(self) -> bool:
+        """Learned sparse attention: pages hold the indexer's key stream
+        beside the latent, and a query attends its ``index_topk`` best."""
+        return self.index_topk > 0
+
+    @property
+    def num_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.num_experts
+
+    @property
+    def step_counters(self) -> tuple:
+        """What a step program of this model counts on the device and
+        hands back behind its sampled tokens (``step_program``): of a
+        routed model, the assignments (token x chosen expert, real tokens
+        only) that fell to the experts held, and the experts they touched,
+        both summed over the routed layers."""
+        routed = (self.num_experts > 0 and self.moe_router
+                  and self.moe_router[0] == "deepseek_v3")
+        return ("assignments_held", "experts_touched") if routed else ()
 
     @property
     def kv_cache_heads(self) -> int:
@@ -378,7 +471,8 @@ def _init_top_jit(embed_key: jax.Array, head_key: jax.Array,
                   cfg: LlamaConfig) -> Params:
     h = cfg.hidden_size
     return {
-        "embed": _dense_init(embed_key, (cfg.vocab_size, h), cfg.dtype),
+        "embed": _dense_init(embed_key, (cfg.vocab_size, h), cfg.dtype,
+                             cfg.embed_init_scale),
         "final_norm": jnp.ones((h,), jnp.float32),
         "lm_head": _dense_init(head_key, (h, cfg.vocab_size), cfg.dtype),
     }
@@ -401,16 +495,36 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
     }
     if cfg.is_mla:
         r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        qr = cfg.q_lora_rank
         layer.update({
             # q carries nope (head_dim) + decoupled-rope dims per head;
             # KV is down-projected to the shared latent, with per-head
             # up-projections absorbed into the attention at serve time.
-            "wq": dense(lk[0], (h, cfg.num_heads * (hd + dr))),
+            "wq": dense(lk[0], (qr or h, cfg.num_heads * (hd + dr))),
             "w_dkv": dense(lk[1], (h, r)),
             "w_kr": dense(lk[2], (h, dr)),
             "w_uk": dense(lk[8], (cfg.num_heads, r, hd)),
             "w_uv": dense(lk[9], (cfg.num_heads, r, hd)),
         })
+        if qr:
+            ik = jax.random.split(lk[0], 5)
+            layer.update({
+                "w_dq": dense(ik[1], (h, qr)),
+                "q_latent_norm": jnp.ones((qr,), jnp.float32),
+                "latent_norm": jnp.ones((r,), jnp.float32),
+            })
+        if cfg.is_dsa:
+            # The indexer: per-head queries from the q latent, one key
+            # (LayerNorm, weight and bias) and the heads' weights from
+            # the layer's input.
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            layer.update({
+                "w_iq": dense(ik[2], (qr, hi * di)),
+                "w_ik": dense(ik[3], (h, di)),
+                "w_iw": dense(ik[4], (h, hi)),
+                "index_norm": jnp.ones((di,), jnp.float32),
+                "index_norm_bias": jnp.zeros((di,), jnp.float32),
+            })
     else:
         layer.update({
             "wq": dense(lk[0], (h, cfg.num_heads * hd)),
@@ -422,19 +536,27 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
         layer["k_norm"] = jnp.ones((hd,), jnp.float32)
     if is_moe_layer:
         e = cfg.num_experts
+        held = cfg.num_experts_held  # the router keeps its width
         inter = cfg.moe_intermediate_size or cfg.intermediate_size
         layer.update({
             "router": dense(lk[7], (h, e)),
-            "w_gate": dense(lk[4], (e, h, inter)),
-            "w_up": dense(lk[5], (e, h, inter)),
-            "w_down": dense(lk[6], (e, inter, h)),
+            "w_gate": dense(lk[4], (held, h, inter)),
+            "w_up": dense(lk[5], (held, h, inter)),
+            "w_down": dense(lk[6], (held, inter, h)),
         })
         if cfg.moe_router and cfg.moe_router[0] == "deepseek_v3":
             # deepseek_v3: bias + shared expert
             sh = inter * max(cfg.n_shared_experts, 1)
             skeys = jax.random.split(lk[7], 4)
             layer.update({
-                "router_bias": jnp.zeros((e,), jnp.float32),
+                # e_score_correction_bias: zero for a whole layer (what its
+                # tests and the benchmark's routed fixture pin); a chip's
+                # share draws it small and not zero, so that a forward
+                # that left it out would send other tokens to the experts
+                # held.
+                "router_bias": (_dense_init(skeys[0], (e,), jnp.float32)
+                                if cfg.experts_held
+                                else jnp.zeros((e,), jnp.float32)),
                 "w_gate_sh": dense(skeys[1], (h, sh)),
                 "w_up_sh": dense(skeys[2], (h, sh)),
                 "w_down_sh": dense(skeys[3], (sh, h)),
@@ -685,6 +807,9 @@ def init_kv_cache(cfg: LlamaConfig, num_pages: int,
     head; the V pool is width-0 — attention reads values from the same
     latent, so a separate V cache would double the memory MLA exists to
     save. The zero-width array keeps every donation/offload seam shaped.
+    A model with an indexer (``cfg.is_dsa``) keeps its key stream there,
+    ``index_head_dim`` wide: allocated, written and donated with the
+    latent under one page id.
 
     ``dtype`` overrides the pool element type (serving-time choice —
     ``float8_e4m3fn`` halves KV HBM traffic and capacity; e4m3's
@@ -696,7 +821,11 @@ def init_kv_cache(cfg: LlamaConfig, num_pages: int,
     dtype = cfg.dtype if dtype is None else dtype
     shape = (cfg.num_layers, num_pages, cfg.kv_cache_heads, cfg.page_size,
              cfg.kv_cache_head_dim)
-    v_width = 0 if cfg.is_mla else cfg.kv_cache_head_dim
+    v_width = cfg.kv_cache_head_dim
+    if cfg.is_mla:
+        # The second stack is the indexer's key stream where the model has
+        # one: under the same page ids, so a page is both or neither.
+        v_width = cfg.index_head_dim if cfg.is_dsa else 0
     return jnp.zeros(shape, dtype), jnp.zeros(shape[:-1] + (v_width,), dtype)
 
 
@@ -828,20 +957,16 @@ def _moe_capacity(mlp_in, layer, cfg, aux_out, valid=None):
     return y.reshape(batch, seq, hidden).astype(mlp_in.dtype)
 
 
-def _moe_deepseek(mlp_in, layer, cfg):
-    """DeepSeek-V3 MoE, exact dense form (DeepseekV3TopkRouter +
-    DeepseekV3MoE semantics): sigmoid scores; top-k SELECTION uses
-    bias-corrected scores restricted to the best ``topk_group`` of
-    ``n_group`` expert groups (group score = sum of its top-2 corrected
-    scores); mix WEIGHTS are the unbiased sigmoid scores of the chosen
-    experts, optionally renormalized, times the routed scaling factor;
-    a shared expert always adds in."""
+def _deepseek_route(x, layer, cfg):
+    """DeepseekV3TopkRouter over the router's whole width: ``(idx [T, k],
+    w [T, k])``. Sigmoid scores; top-k SELECTION uses bias-corrected
+    scores restricted to the best ``topk_group`` of ``n_group`` expert
+    groups (group score = sum of its top-2 corrected scores); mix WEIGHTS
+    are the unbiased sigmoid scores of the chosen experts, optionally
+    renormalized over all of them, times the routed scaling factor."""
     _kind, n_group, topk_group, norm_flag, factor = cfg.moe_router
-    b, s, h = mlp_in.shape
-    e = layer["w_gate"].shape[0]
+    e = layer["router"].shape[1]
     k = cfg.num_experts_per_token
-    x = mlp_in.reshape(b * s, h)
-
     logits = x.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
     scores = jax.nn.sigmoid(logits)  # [T, E]
     choice = scores + layer["router_bias"][None, :].astype(jnp.float32)
@@ -855,17 +980,112 @@ def _moe_deepseek(mlp_in, layer, cfg):
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_flag:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    w = w * factor
-    mix_w = jnp.einsum(
-        "tk,tke->te", w, jax.nn.one_hot(idx, e, dtype=jnp.float32))
+    return idx, w * factor
 
+
+def _experts_dense(x, layer, idx, w, first):
+    """Every expert held over every token, mixed by the router's weights:
+    exact, O(experts held) work and a ``[T, E, I]`` intermediate. The form
+    the grouped dispatch is tested against."""
+    held = layer["w_gate"].shape[0]
+    mix_w = jnp.einsum(
+        "tk,tke->te", w,
+        jax.nn.one_hot(idx - first, held, dtype=jnp.float32))
     gate = jax.nn.silu(jnp.einsum(
         "th,ehi->tei", x, layer["w_gate"]).astype(jnp.float32))
     up = jnp.einsum("th,ehi->tei", x, layer["w_up"]).astype(jnp.float32)
     expert_out = jnp.einsum(
         "tei,eih->teh", (gate * up).astype(x.dtype), layer["w_down"]
     ).astype(jnp.float32)
-    out = jnp.einsum("te,teh->th", mix_w, expert_out).astype(mlp_in.dtype)
+    return jnp.einsum("te,teh->th", mix_w, expert_out)
+
+
+# Rows a grouped matmul's tile holds: the assignments are padded to a
+# whole number of them.
+_GMM_ROWS = 128
+
+
+def _grouped_matmul(lhs, rhs, group_sizes, kernel):
+    """``lhs [M, K]`` times ``rhs [G, K, N]``, rows grouped by ``rhs``'s
+    first axis: the first ``group_sizes[0]`` rows take ``rhs[0]`` and so
+    on; rows past the groups come out as anything. ``kernel``: None → XLA's
+    ``ragged_dot`` (the XLA programs), else ``{"interpret": bool}`` → the
+    Pallas grouped matmul (megablox ``gmm``), which visits the tiles that
+    hold rows of a group and reads the weights of the groups they touch:
+    work and bytes follow the assignments. On one v5e at DeepSeek-V3.2's
+    widths with 16 experts held the three matmuls of a layer take 0.53 ms
+    with ``gmm`` and 0.63 with ``ragged_dot`` for a decode step's 8 tokens,
+    2.8 and 5.2 ms for a chunk of 512 (PERF.md, PR 34); off the chip
+    ``gmm`` would run interpreted."""
+    if kernel is None:
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=jnp.float32)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    k, n = rhs.shape[1:]
+    tiling = (_GMM_ROWS, math.gcd(k, 512), math.gcd(n, 1024))
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
+               tiling=tiling, interpret=kernel["interpret"])
+
+
+def _experts_grouped(x, layer, idx, w, first, valid, kernel, counters):
+    """The routed experts' part of a layer, exactly, with work that grows
+    with the assignments held: the ``T x k`` assignments are sorted by
+    expert (a counting sort: those that fall to an expert not held, or
+    belong to a padded token, go last), the tokens gathered in that order,
+    gate, up and down run as grouped matmuls over the experts held, and
+    every token sums its own assignments' rows. No token is dropped and
+    nothing is ``[T, E, I]``."""
+    t, k = idx.shape
+    held = layer["w_gate"].shape[0]
+    local = (idx - first).reshape(t * k)
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine = mine & jnp.repeat(valid.reshape(t), k)
+    group = jnp.where(mine, local, held)                          # [A]
+    onehot = jax.nn.one_hot(group, held + 1, dtype=jnp.int32)    # [A, H+1]
+    sizes = jnp.sum(onehot, axis=0)                               # [H+1]
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    dest = (jnp.cumsum(sizes) - sizes)[group] + rank   # a permutation of A
+    n_rows = -(-t * k // _GMM_ROWS) * _GMM_ROWS
+    token_at = jnp.zeros((n_rows,), jnp.int32).at[dest].set(
+        jnp.repeat(jnp.arange(t, dtype=jnp.int32), k))
+    rows = x[token_at]                                            # [M, h]
+    sizes = sizes[:held]
+    if counters is not None:
+        for name, n in (("assignments_held", jnp.sum(sizes)),
+                        ("experts_touched", jnp.sum(sizes > 0))):
+            counters[name] = counters.get(name, 0) + n
+    gate = jax.nn.silu(_grouped_matmul(rows, layer["w_gate"], sizes, kernel))
+    up = _grouped_matmul(rows, layer["w_up"], sizes, kernel)
+    out = _grouped_matmul((gate * up).astype(x.dtype), layer["w_down"],
+                          sizes, kernel)                          # [M, h] f32
+    # Rows past the held assignments hold nothing that was computed.
+    out = jnp.where((jnp.arange(n_rows) < jnp.sum(sizes))[:, None], out, 0.0)
+    mine_w = jnp.where(mine.reshape(t, k), w, 0.0)
+    return jnp.einsum("tk,tkh->th", mine_w,
+                      out[dest].reshape(t, k, out.shape[-1]))
+
+
+def _moe_deepseek(mlp_in, layer, cfg, valid=None, kernel=None,
+                  counters=None):
+    """DeepSeek-V3 MoE (DeepseekV3TopkRouter + DeepseekV3MoE semantics):
+    ``_deepseek_route`` over all ``cfg.num_experts``, the routed experts
+    this layer holds (``cfg.experts_held``; all without it) by
+    ``cfg.moe_dispatch`` (``_experts_grouped`` or ``_experts_dense``, both
+    exact), and a shared expert that always adds in. An expert chosen and
+    not held adds nothing here: its chip adds it."""
+    b, s, h = mlp_in.shape
+    x = mlp_in.reshape(b * s, h)
+    idx, w = _deepseek_route(x, layer, cfg)
+    first = cfg.experts_held[0] if cfg.experts_held else 0
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        if cfg.moe_dispatch == "grouped":
+            out = _experts_grouped(x, layer, idx, w, first, valid, kernel,
+                                   counters)
+        else:
+            out = _experts_dense(x, layer, idx, w, first)
+    out = out.astype(mlp_in.dtype)
 
     if "w_gate_up_sh" in layer:  # fused serving layout (fuse_params)
         sh_gu = (x @ layer["w_gate_up_sh"]).astype(jnp.float32)
@@ -881,7 +1101,8 @@ def _moe_deepseek(mlp_in, layer, cfg):
 
 
 def _mlp(mlp_in: jax.Array, layer: dict, cfg: "LlamaConfig",
-         aux_out: Any = None, valid: Any = None) -> jax.Array:
+         aux_out: Any = None, valid: Any = None, kernel: Any = None,
+         counters: Any = None) -> jax.Array:
     """MLP block: dense SwiGLU or top-k MoE (capacity dispatch by default,
     dense reference formulation via ``cfg.moe_dispatch="dense"``; the
     deepseek_v3 router when ``cfg.moe_router`` selects it).
@@ -890,11 +1111,14 @@ def _mlp(mlp_in: jax.Array, layer: dict, cfg: "LlamaConfig",
     MoE), so dense-first_k DeepSeek layouts mix layer kinds in one model.
     Expert matmuls stay in the model dtype (bf16 MXU path, like the dense
     branch); only router/softmax/mix math runs in f32. ``valid`` ([b, s]
-    bool) excludes padded positions from capacity routing.
+    bool) excludes padded positions from capacity routing and from the
+    grouped dispatch; ``kernel`` and ``counters`` as ``_experts_grouped``
+    takes them.
     """
     if "router" in layer:
         if cfg.moe_router and cfg.moe_router[0] == "deepseek_v3":
-            return _moe_deepseek(mlp_in, layer, cfg)
+            return _moe_deepseek(mlp_in, layer, cfg, valid=valid,
+                                 kernel=kernel, counters=counters)
         if cfg.moe_dispatch == "capacity":
             return _moe_capacity(mlp_in, layer, cfg, aux_out, valid=valid)
         if cfg.moe_dispatch == "dense":
@@ -966,9 +1190,27 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
     ).astype(x.dtype)
 
 
+def _layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+                eps: float) -> jax.Array:
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps) * weight + bias).astype(x.dtype)
+
+
+def _rope_leading(x: jax.Array, dr: int, positions: jax.Array,
+                  cfg: "LlamaConfig") -> jax.Array:
+    """RoPE on the first ``dr`` dims of ``x [b, s, heads, d]`` (the
+    indexer's layout: rope dims first), the rest as they are."""
+    return jnp.concatenate(
+        [_rope(x[..., :dr], positions, cfg.rope_theta, cfg.rope_scaling),
+         x[..., dr:]], axis=-1)
+
+
 def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                           ctx_lens, new_lens, attention_fn, last_only=False,
-                          tails=None, ragged=None):
+                          tails=None, ragged=None, kernel=None,
+                          counters=None):
     """Shared transformer body over grouped KV pools.
 
     ``k_caches[g]`` holds group g's layers stacked in ``cfg.group_layers(g)``
@@ -1004,8 +1246,20 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     understand the ragged layout (``pallas_paged_ragged_attention``).
     ``last_only=True`` then returns one logit row per ragged row (each
     row's final token) — logits [1, rows, vocab].
+
+    A model with an indexer (``cfg.is_dsa``) writes its index keys into
+    ``v_caches[g]`` beside the latent and hands ``attention_fn`` one more
+    argument, ``index=(q_idx, w_idx)``: the indexer's queries ``[b, seq,
+    heads, width]`` and head weights ``[b, seq, heads]`` (float32), with
+    which the backend scores the row's pages of ``v_stack`` and selects
+    (``ops.sparse_index``). ``kernel`` and ``counters`` go to the routed
+    layers (``_experts_grouped``).
     """
     batch, seq = tokens.shape
+    if cfg.is_dsa and (tails is not None or ragged is not None):
+        raise NotImplementedError(
+            "learned sparse attention is served by the padded step "
+            "programs: no fused decode bursts, no ragged batches")
     if ragged is not None:
         if tails is not None:
             raise ValueError("ragged mode is scatter-then-attend; "
@@ -1109,8 +1363,9 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                     if "q_latent_norm" in layer:
                         # q-LoRA: the fused block holds w_dq's output; the
                         # norm between down- and up-projection stays.
-                        q = _rms_norm(head_in, layer["q_latent_norm"],
-                                      cfg.norm_eps) @ layer["wq"]
+                        q_in = _rms_norm(head_in, layer["q_latent_norm"],
+                                         cfg.norm_eps)
+                        q = q_in @ layer["wq"]
                     else:
                         q = head_in
                 else:
@@ -1160,15 +1415,47 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
             # Values ARE the latent: pass the K pool as both K and V (the
             # width-0 V pool is never read), then un-absorb W_UV.
             extra = {}
+            if cfg.is_dsa:
+                with jax.named_scope(SCOPE_INDEX):
+                    # The lightning indexer: light per-head queries from
+                    # the q latent, one key a token (LayerNorm, RoPE on its
+                    # leading rope dims) cached beside the latent, and the
+                    # heads' weights from the layer's input.
+                    # Kept in float32 from the projections to the one
+                    # rounding each takes (the key's into the pool, the
+                    # query's into the kernel): which keys a query keeps
+                    # turns on differences of a rounding's size, and these
+                    # three matmuls are a hundredth of the layer's.
+                    hi, di = cfg.index_n_heads, cfg.index_head_dim
+
+                    def proj(a, w):
+                        return jnp.matmul(
+                            a, w, preferred_element_type=jnp.float32)
+
+                    q_idx = _rope_leading(
+                        proj(q_in, layer["w_iq"]).reshape(
+                            batch, seq, hi, di),
+                        dr, positions, cfg).astype(x.dtype)
+                    k_idx = _rope_leading(
+                        _layer_norm(proj(attn_in, layer["w_ik"]),
+                                    layer["index_norm"],
+                                    layer["index_norm_bias"],
+                                    cfg.norm_eps)[:, :, None, :],
+                        dr, positions, cfg)                # [b, s, 1, di]
+                    w_idx = proj(attn_in, layer["w_iw"]) * (
+                        hi ** -0.5 * di ** -0.5)
+                v_caches[g] = write_layer(v_caches[g], g, lj, k_idx)
+                extra = {"index": (q_idx, w_idx)}
             if tails is not None:
                 tail_ks[g] = write_tail_layer(tail_ks[g], lj, latent)
             else:
                 k_caches[g] = write_layer(k_caches[g], g, lj, latent)
+            v_stack = v_caches[g] if cfg.is_dsa else k_caches[g]
             with jax.named_scope(SCOPE_ATTENTION):
                 if tails is not None:
                     extra = tail_kwargs(tail_ks[g][lj], tail_ks[g][lj])
                 ctx = attention_fn(
-                    q_eff, k_caches[g], k_caches[g], lj, table, positions,
+                    q_eff, k_caches[g], v_stack, lj, table, positions,
                     total_lens, None, **extra,
                 )
                 attn = jnp.einsum("bshr,hrv->bshv", ctx[..., :r],
@@ -1221,7 +1508,8 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
 
         with jax.named_scope(SCOPE_MLP):
             mlp_in = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp(mlp_in, layer, cfg, valid=valid)
+            x = x + _mlp(mlp_in, layer, cfg, valid=valid, kernel=kernel,
+                         counters=counters)
 
     with jax.named_scope(SCOPE_LM_HEAD):
         x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -1251,20 +1539,35 @@ def greedy_tokens(logits: jax.Array) -> jax.Array:
 def _xla_attention(cfg):
     """The XLA attention backend of ``forward`` and ``forward_hybrid``."""
     def attention(q, k_stack, v_stack, layer_idx, table, positions,
-                  total_lens, window):
+                  total_lens, window, index=None):
+        keep = None
+        if index is not None:
+            # Learned sparse attention as a mask over dense attention: the
+            # index stack is v_stack, the values are the latent (k_stack).
+            with jax.named_scope(SCOPE_INDEX):
+                scores = sparse_index.index_scores_xla(
+                    *index, sparse_index.gather_index_keys(
+                        v_stack, layer_idx, table))
+            with jax.named_scope(SCOPE_SELECT):
+                keep = sparse_index.keep_mask(scores, positions, total_lens,
+                                              cfg.index_topk)
+            v_stack = k_stack
         return paged_attention(
             q, k_stack, v_stack, table, positions, total_lens,
             sliding_window=window,
             attention_sinks=cfg.attention_sinks or None, layer_idx=layer_idx,
+            keep=keep,
         )
     return attention
 
 
 def _forward_impl(params, cfg, tokens, k_cache, v_cache, page_table,
-                  ctx_lens, new_lens, attention_fn, last_only=False):
+                  ctx_lens, new_lens, attention_fn, last_only=False,
+                  kernel=None, counters=None):
     logits, ks, vs = _forward_impl_grouped(
         params, cfg, tokens, (k_cache,), (v_cache,), (page_table,),
         ctx_lens, new_lens, attention_fn, last_only=last_only,
+        kernel=kernel, counters=counters,
     )
     return logits, ks[0], vs[0]
 
@@ -1281,6 +1584,7 @@ def forward(
     ctx_lens: jax.Array,  # [batch] tokens already cached before this call
     new_lens: jax.Array,  # [batch] valid new tokens in `tokens`
     last_only: bool = False,
+    counters: dict | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One model step (prefill or decode), XLA attention backend.
 
@@ -1289,10 +1593,12 @@ def forward(
     positions (``i >= new_lens[b]``) are masked and scatter to the garbage
     page. ``last_only=True`` → logits is [b, 1, vocab], the final valid
     position of each row (prefill chunks; see ``_forward_impl_grouped``).
+    ``counters``: a dict the step form hands in, filled while tracing with
+    ``cfg.step_counters`` (``step_program``).
     """
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
-        _xla_attention(cfg), last_only=last_only,
+        _xla_attention(cfg), last_only=last_only, counters=counters,
     )
 
 
@@ -1338,6 +1644,7 @@ def forward_decode_pallas(
     interpret: bool = False,
     mesh=None,
     batch_rows: int = 1,
+    counters: dict | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Decode step (seq == 1) using the Pallas flash-decode kernel.
 
@@ -1350,12 +1657,55 @@ def forward_decode_pallas(
         pallas_paged_decode_attention, sharded_paged_decode_attention)
 
     sinks = cfg.attention_sinks or None
+    if cfg.is_dsa and mesh is not None:
+        raise NotImplementedError(
+            "learned sparse attention is not sharded over a mesh")
+
+    def dense(q, k_stack, table, lens, layer_idx):
+        return pallas_paged_decode_attention(
+            q[:, 0], k_stack, k_stack, table, lens, shared_kv=True,
+            shared_stream=cfg.mla_decode_stream, layer_idx=layer_idx,
+            interpret=interpret)
+
+    def sparse(q, k_stack, idx_stack, table, lens, layer_idx, index):
+        """Score the rows that hold more than ``index_topk`` keys over
+        their own pages of the index stream, choose, gather the chosen
+        latents (every key of a shorter row) into a pool of their own and
+        attend that: a row of n keys reads n index keys and min(n, topk)
+        latents."""
+        topk = cfg.index_topk
+        with jax.named_scope(SCOPE_INDEX):
+            scores = sparse_index.dsa_index_scores(
+                *index, sparse_index.gather_index_keys(
+                    idx_stack, layer_idx, table),
+                jnp.where(lens > topk, lens, 0), interpret=interpret)[:, 0]
+        with jax.named_scope(SCOPE_SELECT):
+            picked, count = sparse_index.select_topk(scores, lens, topk)
+            chosen = sparse_index.gather_selected(
+                k_stack, layer_idx, table, picked)
+        with jax.named_scope(SCOPE_SPARSE_ATTENTION):
+            pages = chosen.shape[0] // lens.shape[0]
+            own = jnp.arange(chosen.shape[0], dtype=jnp.int32).reshape(
+                lens.shape[0], pages)
+            return dense(q, chosen, own, count, None)
 
     def pallas_attention(q, k_stack, v_stack, layer_idx, table, _positions,
-                         total_lens, window):
+                         total_lens, window, index=None):
         # The stacked operand + in-kernel layer index: a sliced cache
         # materializes a per-layer copy at the pallas custom-call
         # boundary (see ops.pallas_paged_attention._superblock_streamer).
+        if index is not None:
+            # Rows of at most index_topk keys attend all of them: while
+            # the whole batch is such rows, today's kernel whole.
+            if table.shape[1] * cfg.page_size <= cfg.index_topk:
+                out = dense(q, k_stack, table, total_lens, layer_idx)
+            else:
+                out = jax.lax.cond(
+                    jnp.max(total_lens) > cfg.index_topk,
+                    lambda: sparse(q, k_stack, v_stack, table, total_lens,
+                                   layer_idx, index),
+                    lambda: dense(q, k_stack, table, total_lens, layer_idx))
+            return out[:, None]
         if mesh is not None:
             out = sharded_paged_decode_attention(
                 mesh, q[:, 0], k_stack, v_stack, table, total_lens,
@@ -1375,7 +1725,7 @@ def forward_decode_pallas(
 
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
-        pallas_attention,
+        pallas_attention, kernel={"interpret": interpret}, counters=counters,
     )
 
 
@@ -1615,6 +1965,7 @@ def forward_prefill_pallas(
     interpret: bool = False,
     mesh=None,
     last_only: bool = False,
+    counters: dict | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Prefill using the Pallas flash-prefill kernel.
 
@@ -1635,14 +1986,40 @@ def forward_prefill_pallas(
     # Tiny test seqs fall back to their gcd.
     group = cfg.num_heads // max(1, cfg.kv_cache_heads)
     q_tile = math.gcd(seq, max(128, 1024 // max(1, group)))
+    if group * q_tile > 4096:
+        # One latent head serves every query head (absorbed MLA at 128
+        # heads): 128 query rows of it are 16k rows of 640 lanes, beyond
+        # what a program's blocks, state and scores may hold. 2048 rows.
+        q_tile = math.gcd(seq, max(16, 2048 // group))
 
     sinks = cfg.attention_sinks or None
+    if cfg.is_dsa and mesh is not None:
+        raise NotImplementedError(
+            "learned sparse attention is not sharded over a mesh")
 
     def attention_fn(q, k_stack, v_stack, layer_idx, table, positions,
-                     total_lens, window):
+                     total_lens, window, index=None):
         # Stacked operand + in-kernel layer index: a sliced cache
         # materializes a per-layer copy at the pallas custom-call
         # boundary (see ops.pallas_paged_attention._superblock_streamer).
+        bias = None
+        if index is not None:
+            # Every query of the chunk scores its row's keys and keeps its
+            # index_topk best: dense latent attention, masked (as the
+            # model's own prefill does). v_stack is the index stream; the
+            # values are the latent.
+            if table.shape[1] * cfg.page_size > cfg.index_topk:
+                with jax.named_scope(SCOPE_INDEX):
+                    scores = sparse_index.dsa_index_scores(
+                        *index, sparse_index.gather_index_keys(
+                            v_stack, layer_idx, table),
+                        total_lens, interpret=interpret)
+                with jax.named_scope(SCOPE_SELECT):
+                    bias = jnp.where(
+                        sparse_index.keep_mask(scores, positions, total_lens,
+                                               cfg.index_topk),
+                        0.0, sparse_index.DROPPED)
+            v_stack = k_stack
         if mesh is not None:
             return sharded_paged_prefill_attention(
                 mesh, q, k_stack, v_stack, table, ctx_lens, total_lens,
@@ -1654,12 +2031,13 @@ def forward_prefill_pallas(
             q, k_stack, v_stack, table, ctx_lens, total_lens,
             q_tile=q_tile, sliding_window=window,
             sinks=sinks, shared_kv=cfg.is_mla, layer_idx=layer_idx,
-            interpret=interpret,
+            bias=bias, interpret=interpret,
         )
 
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
-        attention_fn, last_only=last_only,
+        attention_fn, last_only=last_only, kernel={"interpret": interpret},
+        counters=counters,
     )
 
 
@@ -1763,12 +2141,21 @@ def step_program(body, static=(), tokens_out: bool = False, kept_row=None):
     ``tokens_out``: the body samples for itself (a burst) and its tokens
     pass through. ``token_sharding`` constrains the unpacked tokens
     (sequence-parallel prefill: the compute follows them).
+    Where the body takes ``counters`` and the model counts anything on the
+    device (``cfg.step_counters``), the counts follow the tokens in the
+    same array, ``int32 [rows + len(cfg.step_counters)]``, so that they
+    reach the host in the tokens' own transfer.
     """
+    counted = "counters" in inspect.signature(body).parameters
+
     def program(params, cfg, packed, pools, shapes, keep_row=False,
                 token_sharding=None, **kw):
         tokens, *rest = unpack_inputs(packed, shapes)
         if token_sharding is not None:
             tokens = jax.lax.with_sharding_constraint(tokens, token_sharding)
+        counters = {} if counted and cfg.step_counters else None
+        if counters is not None:
+            kw["counters"] = counters
         out, *pools = body(params, cfg, tokens, *pools, *rest, **kw)
         if tokens_out:
             return out, None, tuple(pools)
@@ -1781,7 +2168,12 @@ def step_program(body, static=(), tokens_out: bool = False, kept_row=None):
         row = None
         if keep_row:
             row = out[0 if kept_row is None else kept_row(*rest)]
-        return greedy_tokens(out), row, tuple(pools)
+        picked = greedy_tokens(out)
+        if counters is not None:
+            picked = jnp.concatenate([picked, jnp.stack(
+                [jnp.asarray(counters.get(name, 0), jnp.int32)
+                 for name in cfg.step_counters])])
+        return picked, row, tuple(pools)
 
     program.__name__ = program.__qualname__ = body.__name__
     return jax.jit(

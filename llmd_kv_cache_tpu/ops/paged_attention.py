@@ -35,6 +35,7 @@ def paged_attention(
     tail_v: jax.Array | None = None,
     tail_lens: jax.Array | None = None,  # [batch] valid tail tokens
     layer_idx: int | None = None,
+    keep: jax.Array | None = None,  # [batch, q_seq, kv_len] bool
 ) -> jax.Array:
     """Causal attention of new queries against paged KV (cached + new).
 
@@ -58,6 +59,9 @@ def paged_attention(
     ``layer_idx`` makes ``k_cache``/``v_cache`` the ``[layers, num_pages,
     ...]`` stacks: the page gather takes the layer as one more index, so
     no layer of a pool is ever sliced out.
+
+    ``keep`` (learned sparse attention) further restricts each query to
+    the keys it selected, by position in the row's pages; not with a tail.
     """
     batch, q_seq, q_heads, head_dim = q.shape
     kv_heads = k_cache.shape[-3]
@@ -108,6 +112,8 @@ def paged_attention(
         if attention_sinks:
             in_window = in_window | (k_pos < attention_sinks)
         mask = mask & in_window
+    if keep is not None:
+        mask = mask & keep[:, None, None, :, :]
     logits = jnp.where(mask, logits, _NEG_INF)
 
     probs = jax.nn.softmax(logits, axis=-1)

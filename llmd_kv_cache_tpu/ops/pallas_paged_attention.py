@@ -696,13 +696,8 @@ def _prefill_kernel(
     q_ref,  # [1, q_tile, heads_group, head_dim] block for (b, h, qt)
     k_hbm,
     v_hbm,
-    # output
-    o_ref,
-    # scratch
-    k_scratch,  # [2, pages_per_block, page_size, head_dim]
-    v_scratch,
-    sem,  # [2, pages_per_block, 2]
-    *,
+    # then: [bias_ref,] o_ref, k_scratch, v_scratch, sem
+    *refs,
     page_size: int,
     q_tile: int,
     scale: float,
@@ -711,7 +706,14 @@ def _prefill_kernel(
     pages_per_block: int,
     shared_kv: bool,
     stacked: bool,
+    has_bias: bool = False,
 ):
+    # bias_ref: [1, q_tile, keys] float32 added to the tile's scaled scores,
+    # key position = lane (0 keeps a key, -1e30 drops it: a query's learned
+    # selection). Scratch: k [2, pages_per_block, page_size, head_dim], v
+    # likewise, sem [2, pages_per_block, 2].
+    bias_ref = refs[0] if has_bias else None
+    o_ref, k_scratch, v_scratch, sem = refs[1:] if has_bias else refs
     b = pl.program_id(0)
     h = pl.program_id(1)
     qt = pl.program_id(2)
@@ -803,11 +805,23 @@ def _prefill_kernel(
             if sinks:
                 in_window = in_window | (k_pos < sinks)
             mask = mask & in_window
+        if has_bias:
+            # No window, no sinks (the wrapper checks): superblock sb holds
+            # the keys at [sb * keys, (sb + 1) * keys).
+            keys = kpb * page_size
+            bias = bias_ref[0, :, pl.ds(pl.multiple_of(sb * keys, keys),
+                                        keys)]
+            mask = mask & (bias > 0.5 * _NEG_INF)
         scores = jnp.where(mask[None], scores, _NEG_INF)
 
         m_cur = jnp.max(scores, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(scores - m_new)
+        if has_bias:
+            # A superblock in which a query keeps nothing must add nothing
+            # (without a selection a streamed superblock always holds a
+            # key the query sees, so exp(-1e30 - m) is 0 there by itself).
+            p = jnp.where(mask[None], p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc_prev * alpha + jax.lax.dot_general(
@@ -1287,6 +1301,7 @@ def pallas_paged_prefill_attention(
     pages_per_block: int | None = None,
     shared_kv: bool = False,
     layer_idx: jax.Array | int | None = None,
+    bias: jax.Array | None = None,  # [batch, q_seq, keys] float32
     interpret: bool = False,
 ) -> jax.Array:
     """Flash prefill over paged KV (new tokens' KV already scattered).
@@ -1304,6 +1319,12 @@ def pallas_paged_prefill_attention(
     the fp32 scores tile [group, q_tile, keys] stays within a few MB of
     VMEM. Every accepted cell runs this default; the ledger has no pair
     across round widths.
+
+    ``bias`` (learned sparse attention: a query's selection among its
+    keys) is float32 ``[batch, q_seq, keys]`` over key positions ``[0,
+    pages_per_seq * page_size)``: a key whose entry is ``-1e30`` is dropped
+    for that query, any other kept. The causal mask still applies. Not
+    with a sliding window.
     """
     batch, q_seq, q_heads, head_dim = q.shape
     # layer_idx: caches are the engine's full [layers, pages, …] stack and
@@ -1333,7 +1354,23 @@ def pallas_paged_prefill_attention(
         scale=head_dim ** -0.5, sliding_window=sliding_window,
         sinks=int(sinks or 0), pages_per_block=pages_per_block,
         shared_kv=shared_kv, stacked=layer_idx is not None,
+        **({"has_bias": True} if bias is not None else {}),
     )
+    bias_operand, bias_spec, bias_bytes = (), [], 0
+    if bias is not None:
+        if sliding_window is not None:
+            raise ValueError("a selection bias needs full attention: the "
+                             "kernel reads it by superblock, in key order")
+        # Whole superblocks: the last one is read to its end.
+        keys = pages_per_block * page_size
+        n_keys = -(-page_table.shape[1] // pages_per_block) * keys
+        bias = jnp.pad(bias.astype(jnp.float32),
+                       [(0, 0), (0, 0), (0, n_keys - bias.shape[2])],
+                       constant_values=_NEG_INF)
+        bias_operand = (bias,)
+        bias_spec = [pl.BlockSpec((1, q_tile, n_keys),
+                                  lambda b, h, qt, *_p: (b, qt, 0))]
+        bias_bytes = 2 * q_tile * n_keys * 4
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -1345,6 +1382,7 @@ def pallas_paged_prefill_attention(
             ),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
+            *bias_spec,
         ],
         out_specs=pl.BlockSpec(
             (1, 1, q_tile, 1, group, head_dim),
@@ -1372,7 +1410,8 @@ def pallas_paged_prefill_attention(
         4 * rows * head_dim * q.dtype.itemsize
         + (1 if shared_kv else 2) * 2 * keys * head_dim * item
         + 2 * rows * (head_dim + 2) * 4
-        + rows * keys * (4 + 4 + item))
+        + rows * keys * (4 + 4 + item)
+        + bias_bytes)
 
     out = pl.pallas_call(
         kernel,
@@ -1384,7 +1423,7 @@ def pallas_paged_prefill_attention(
         interpret=interpret,
     )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       total_lens.astype(jnp.int32), _layer_operand(layer_idx),
-      q_blocked, k_cache, v_cache)
+      q_blocked, k_cache, v_cache, *bias_operand)
 
     return out.reshape(batch, q_seq, q_heads, head_dim)
 

@@ -1,0 +1,486 @@
+"""Learned sparse attention with a lightning indexer, latent attention with
+q-LoRA and one chip's share of a routed layer, served through the paged
+cache, against the configuration's plain reference
+(``kvbench/references/deepseek-v3.2-exp-ep16-l5.py``): toy widths, seeded
+random weights, ``index_topk`` below the sequences' lengths so that the
+selection is live. Logits are compared, not tokens.
+"""
+
+import dataclasses
+import json
+from pathlib import Path
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kvbench.harness import names
+from llmd_kv_cache_tpu.models import llama
+from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
+from llmd_kv_cache_tpu.models.hf_loader import config_from_hf
+from llmd_kv_cache_tpu.models.llama import LlamaConfig, init_params
+from llmd_kv_cache_tpu.ops import sparse_index
+from llmd_kv_cache_tpu.telemetry.engine_telemetry import EngineTelemetryConfig
+
+ROOT = Path(__file__).resolve().parents[1]
+TOPK = 32
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return names.load_module(
+        names.KVBENCH / "references" / "deepseek-v3.2-exp-ep16-l5.py",
+        "the configuration's reference")
+
+
+def toy(dtype=jnp.float32, **over) -> LlamaConfig:
+    """4 chips share each routed layer: 32 experts, 8 held, 4 a token."""
+    return LlamaConfig(**{**dict(
+        vocab_size=256, hidden_size=128, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=32, intermediate_size=256, page_size=16,
+        dtype=dtype, norm_eps=1e-6, num_experts=32, num_experts_per_token=4,
+        moe_dispatch="grouped", moe_layers=(1, 2), n_shared_experts=1,
+        moe_intermediate_size=64, moe_router=("deepseek_v3", 4, 2, 1, 2.5),
+        experts_held=(8, 8), kv_lora_rank=64, qk_rope_head_dim=32,
+        q_lora_rank=48, index_n_heads=16, index_head_dim=64, index_topk=TOPK,
+        latent_pad=32, rope_scaling=("yarn", 40.0, 32.0, 1.0, 64.0, 1.0),
+        softmax_scale_mult=1.3), **over})
+
+
+def engine(cfg, params, pallas=False, chunk=32, **kw) -> MiniEngine:
+    return MiniEngine(EngineConfig(
+        model=cfg, num_pages=64, max_pages_per_seq=16, max_batch=2,
+        max_prefill_tokens=chunk, use_pallas_decode=pallas or None,
+        use_pallas_prefill=pallas or None, **kw), params=params)
+
+
+def serve(eng, rid, prompt, max_new):
+    """``(request, last-prompt-position logits)``."""
+    req = eng.enqueue(rid, prompt, max_new_tokens=max_new)
+    logits = None
+    while not req.done:
+        eng.step()
+        if logits is None and req.last_logits is not None:
+            logits = np.asarray(req.last_logits, np.float32)
+    return req, logits
+
+
+def rel(got, want) -> float:
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def shortfalls(ref_rows, out) -> list:
+    """How far below the reference's best each chosen token lies."""
+    return [float((r.max() - r[t]) / np.abs(r).max())
+            for r, t in zip(ref_rows, out)]
+
+
+PROMPT = np.random.default_rng(0).integers(1, 256, 100).tolist()
+# float32 against float32 "highest": what is left is the CPU's default
+# matmul precision and, rarely, a key that changes sides at the
+# selection's edge (one of 32 here, one of 2048 at the published widths).
+F32_TOL = 5e-3
+
+
+@pytest.fixture(scope="module")
+def served(ref):
+    """One model, its reference logits over prompt + 6 decoded tokens."""
+    cfg = toy()
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    eng = engine(cfg, params)
+    req, logits = serve(eng, "cold", PROMPT, 7)
+    out = list(req.output)
+    positions = [len(PROMPT) - 1 + i for i in range(7)]
+    want = ref.logits_at(params, cfg, PROMPT + out[:6], positions)
+    return SimpleNamespace(cfg=cfg, params=params, eng=eng, logits=logits,
+                           out=out, want=want)
+
+
+class TestThroughThePagedCache:
+    def test_prefill_then_decode_match_the_reference(self, served):
+        assert rel(served.logits, served.want[0]) < F32_TOL
+        assert max(shortfalls(served.want, served.out)) < F32_TOL
+
+    def test_a_prefix_hit_brings_the_index_keys_back(self, served):
+        req, logits = serve(served.eng, "hit", PROMPT, 1)
+        assert req.cached_len == len(PROMPT) // 16 * 16
+        assert rel(logits, served.want[0]) < F32_TOL
+
+    def test_a_hit_without_its_index_keys_is_wrong(self, served):
+        """The comparison sees stream 2: with the index keys of the cached
+        pages gone, the same hit selects other keys and misses."""
+        eng = served.eng
+        keep = eng.v_cache
+        eng.v_cache = jnp.zeros_like(keep)
+        try:
+            req, logits = serve(eng, "hit-blind", PROMPT, 1)
+        finally:
+            eng.v_cache = keep
+        assert req.cached_len > 0
+        assert rel(logits, served.want[0]) > 10 * F32_TOL
+
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_the_kernels_serve_the_same_function(self, served, fused):
+        """The Pallas step programs (interpreted here): a masked latent
+        prefill, a decode step that scores, selects, gathers and attends,
+        the grouped matmul kernel; on the fused tree too."""
+        params = (llama.fuse_params(served.params, served.cfg) if fused
+                  else served.params)
+        eng = engine(served.cfg, params, pallas=True)
+        assert eng.attention_backends["decode"]["backend"] == "pallas"
+        assert eng.attention_backends["prefill"]["backend"] == "pallas"
+        req, logits = serve(eng, "cold", PROMPT, 7)
+        assert rel(logits, served.want[0]) < F32_TOL
+        assert list(req.output) == served.out
+
+    def test_chunked_prefill_is_one_chunk(self, served):
+        whole = engine(served.cfg, served.params, chunk=128)
+        _, logits = serve(whole, "one-chunk", PROMPT, 1)
+        assert rel(logits, served.logits) < F32_TOL
+
+    @pytest.mark.parametrize("pallas", [False, True])
+    def test_a_row_crosses_topk_while_it_decodes(self, ref, served, pallas):
+        """29 keys after prefill, 37 after the last step: the first steps
+        attend every key, the later ones select."""
+        prompt = PROMPT[:TOPK - 3]
+        eng = engine(served.cfg, served.params, pallas=pallas)
+        req, logits = serve(eng, "crossing", prompt, 9)
+        out = list(req.output)
+        positions = [len(prompt) - 1 + i for i in range(9)]
+        want = ref.logits_at(served.params, served.cfg, prompt + out[:8],
+                             positions)
+        assert rel(logits, want[0]) < F32_TOL
+        assert max(shortfalls(want, out)) < F32_TOL
+
+    def test_a_wrong_selection_fails(self, ref, served, monkeypatch):
+        """The first ``topk`` keys instead of the best: the comparison that
+        passes the program must not pass this."""
+        def first_keys(scores, q_positions, total_lens, topk):
+            pos = jnp.arange(scores.shape[-1])[None, None, :]
+            return (pos <= q_positions[:, :, None]) & (pos < topk)
+
+        monkeypatch.setattr(sparse_index, "keep_mask", first_keys)
+        jax.clear_caches()
+        try:
+            _, logits = serve(engine(served.cfg, served.params), "wrong",
+                              PROMPT, 1)
+        finally:
+            monkeypatch.undo()
+            jax.clear_caches()
+        assert rel(logits, served.want[0]) > 10 * F32_TOL
+
+
+class TestAnswers:
+    """The reference admits more than one answer where a router's deciding
+    scores lie within ``MARGIN``: the nearest first, ``LIMIT`` at most."""
+
+    ROUTER = ("deepseek_v3", 4, 2, 1, 2.5)
+
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    def test_choices_come_nearest_first(self, ref, seed):
+        rng = np.random.default_rng(seed)
+        scores = 1 / (1 + np.exp(-rng.standard_normal(32) * 1.5))
+        bias = rng.standard_normal(32) * 0.02
+        got = ref.choices(scores, bias, self.ROUTER, 4, 0.05, (8, 8))
+        needs = [need for need, _ in got]
+        assert needs[0] == -np.inf and needs == sorted(needs)
+        assert all(0 <= need < 0.05 for need in needs[1:])
+        held = [tuple(e for e in experts if 8 <= e < 16)
+                for _, experts in got]
+        assert len(set(held)) == len(held)  # one answer a set held here
+        assert ref.choices(scores, bias, self.ROUTER, 4, 0.0, (8, 8)) == [
+            got[0]]
+
+    def test_a_position_gets_the_nearest_and_no_more(self, ref, served,
+                                                     monkeypatch):
+        """With a margin that admits far more than ``LIMIT`` answers: row
+        0 is ``logits_at``'s, the rows stop at ``LIMIT``, and the second is
+        the forward under the nearest other choice of the first routed
+        layer that has one (the other positions' choices, run in the same
+        forwards, reach it as one key among the attended)."""
+        monkeypatch.setattr(ref, "MARGIN", 0.2)
+        tokens = PROMPT + served.out[:6]
+        positions = [len(PROMPT) - 1, len(PROMPT) + 2]
+        rows = ref.alternatives_at(served.params, served.cfg, tokens,
+                                   positions)
+        _, ties, _ = ref._forward(served.params, served.cfg, tokens,
+                                  positions)
+        for i, p in enumerate(positions):
+            assert 1 < len(rows[i]) <= ref.LIMIT
+            np.testing.assert_array_equal(rows[i][0],
+                                          served.want[p - positions[0]])
+            others = sorted((ties[li][p][1][0], li) for li in ties
+                            if len(ties[li][p]) > 1)
+            _, li = others[0]
+            alone = ref._forward(served.params, served.cfg, tokens, [p],
+                                 {li: {p: ties[li][p][1][1]}})[0][0]
+            assert rel(rows[i][1], alone) < 2e-3
+            assert rel(rows[i][1], rows[i][0]) > 10 * rel(rows[i][1], alone)
+
+
+class TestSelection:
+    def test_the_programs_set_is_the_references(self, ref):
+        """bfloat16 program against float32 reference, every query of a
+        prompt, every layer: the sets overlap by at least 0.98. What is
+        left are keys whose index scores lie within bfloat16's rounding of
+        the query's ``topk``-th: program and reference rank them
+        differently, both by right."""
+        cfg = toy(jnp.bfloat16, index_topk=64)
+        params = init_params(jax.random.PRNGKey(11), cfg)
+        tokens = np.random.default_rng(5).integers(1, 256, 256).tolist()
+        masks = []
+        real = sparse_index.keep_mask
+
+        def recording(scores, q_positions, total_lens, topk):
+            keep = real(scores, q_positions, total_lens, topk)
+            masks.append(np.asarray(keep[0]))
+            return keep
+
+        k, v = llama.init_kv_cache(cfg, 20)
+        table = jnp.arange(1, 17, dtype=jnp.int32)[None, :]
+        sparse_index.keep_mask = recording
+        try:
+            with jax.disable_jit():
+                llama.forward.__wrapped__(
+                    params, cfg, jnp.asarray([tokens], jnp.int32), k, v,
+                    table, jnp.zeros((1,), jnp.int32),
+                    jnp.asarray([256], jnp.int32), last_only=True)
+        finally:
+            sparse_index.keep_mask = real
+        want = ref.kept_keys(params, cfg, tokens)
+        assert len(masks) == cfg.num_layers
+        # Layer 0 sees the same input in both; deeper layers' inputs
+        # already differ by the rounding of what came before.
+        shared = [(masks[li] & want[li]).sum(1) / want[li].sum(1)
+                  for li in range(cfg.num_layers)]
+        for li, share in enumerate(shared):
+            assert want[li][100].sum() == 64 and masks[li][100].sum() == 64
+            assert share[64:].mean() >= 0.98, (li, share[64:].mean())
+
+    def test_kth_largest_is_exact(self):
+        rng = np.random.default_rng(0)
+        x = rng.standard_normal((5, 300)).astype(np.float32)
+        x[0, :50] = -np.inf
+        x[1] = np.abs(x[1])
+        x[2, ::3] = 0.0
+        for k in (1, 7, 64, 300):
+            thr = sparse_index.kth_largest(jnp.asarray(x), k)
+            keep = np.asarray(sparse_index._ordered_bits(jnp.asarray(x))
+                              >= thr)
+            want = np.sort(x, axis=1)[:, -k][:, None]
+            np.testing.assert_array_equal(keep, x >= want)
+
+    def test_select_topk_is_exact_and_takes_short_rows_whole(self):
+        rng = np.random.default_rng(1)
+        scores = jnp.asarray(rng.standard_normal((3, 96)), jnp.float32)
+        lens = jnp.asarray([96, 20, 33], jnp.int32)
+        picked, count = sparse_index.select_topk(scores, lens, 32)
+        assert count.tolist() == [32, 20, 32]
+        best = np.argsort(-np.asarray(scores[0]))[:32]
+        assert set(picked[0].tolist()) == set(best.tolist())
+        assert sorted(picked[1, :20].tolist()) == list(range(20))
+        best = np.argsort(-np.asarray(scores[2, :33]))[:32]
+        assert set(picked[2].tolist()) == set(best.tolist())
+
+    @pytest.mark.parametrize("q_seq", [1, 32])
+    def test_the_scoring_kernel_is_the_function(self, q_seq):
+        rng = np.random.default_rng(2)
+        q = jnp.asarray(rng.standard_normal((2, q_seq, 4, 64)), jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((2, q_seq, 4)), jnp.float32)
+        keys = jnp.asarray(rng.standard_normal((2, 256, 64)), jnp.bfloat16)
+        lens = jnp.asarray([256, 70], jnp.int32)
+        got = np.asarray(sparse_index.dsa_index_scores(
+            q, w, keys, lens, interpret=True))
+        want = np.asarray(sparse_index.index_scores_xla(q, w, keys))
+        np.testing.assert_allclose(got[0], want[0], rtol=2e-2, atol=2e-2)
+        np.testing.assert_allclose(got[1, :, :70], want[1, :, :70],
+                                   rtol=2e-2, atol=2e-2)
+
+
+class TestExpertLayer:
+    @pytest.fixture(scope="class")
+    def layer(self):
+        cfg = toy(experts_held=())
+        params = init_params(jax.random.PRNGKey(7), cfg)
+        x = jax.random.normal(jax.random.PRNGKey(8), (2, 24, 128),
+                              jnp.float32)
+        # A whole layer is drawn with a zero bias; the shares' is not.
+        bias = 0.02 * jax.random.normal(jax.random.PRNGKey(9), (32,))
+        return cfg, {**params["layers"][1], "router_bias": bias}, x
+
+    @pytest.mark.parametrize("kernel", [None, {"interpret": True}])
+    def test_the_grouped_dispatch_is_the_dense_form(self, layer, kernel):
+        cfg, lyr, x = layer
+        dense = llama._moe_deepseek(
+            x, lyr, dataclasses.replace(cfg, moe_dispatch="dense"))
+        counters = {}
+        grouped = llama._moe_deepseek(x, lyr, cfg, kernel=kernel,
+                                      counters=counters)
+        np.testing.assert_allclose(grouped, dense, rtol=2e-5, atol=2e-5)
+        assert int(counters["assignments_held"]) == 2 * 24 * 4
+
+    def test_padded_tokens_are_not_dispatched(self, layer):
+        cfg, lyr, x = layer
+        valid = jnp.arange(24)[None, :] < jnp.asarray([[24], [5]])
+        counters = {}
+        llama._moe_deepseek(x, lyr, cfg, valid=valid, counters=counters)
+        assert int(counters["assignments_held"]) == (24 + 5) * 4
+
+    def test_the_shares_add_up_to_the_uncut_layer(self, layer, ref):
+        """The guide's share test: the routed parts that the 4 shares
+        give, with the shared expert counted once, are the uncut layer, in
+        the program and against the reference's uncut layer."""
+        cfg, lyr, x = layer
+        whole = llama._moe_deepseek(x, lyr, cfg)
+        flat = x.reshape(-1, 128)
+        shared = (jax.nn.silu(flat @ lyr["w_gate_sh"])
+                  * (flat @ lyr["w_up_sh"])) @ lyr["w_down_sh"]
+        total = shared.reshape(x.shape)
+        held_sum = 0
+        for rank in range(4):
+            part_cfg = dataclasses.replace(cfg, experts_held=(8 * rank, 8))
+            part = {**lyr, **{k: lyr[k][8 * rank:8 * rank + 8]
+                              for k in ("w_gate", "w_up", "w_down")}}
+            counters = {}
+            total = total + (llama._moe_deepseek(
+                x, part, part_cfg, counters=counters)
+                - shared.reshape(x.shape))
+            held_sum += int(counters["assignments_held"])
+        assert held_sum == 2 * 24 * 4  # every assignment fell to one share
+        np.testing.assert_allclose(total, whole, rtol=1e-4, atol=1e-5)
+        with jax.default_matmul_precision("highest"):
+            want = ref._routed(jnp.asarray(flat), lyr, cfg, 1, [], {}, {},
+                               {}, 0.0)
+        np.testing.assert_allclose(total.reshape(-1, 128), want,
+                                   rtol=2e-3, atol=2e-4)
+
+    def test_a_share_routes_over_the_whole_width(self):
+        cfg = toy()
+        lyr = init_params(jax.random.PRNGKey(7), cfg)["layers"][1]
+        assert lyr["router"].shape == (128, 32)
+        assert lyr["w_gate"].shape == (8, 128, 64)
+        assert float(jnp.abs(lyr["router_bias"]).max()) > 0
+
+
+class TestConfiguration:
+    def published(self, **over):
+        conf = json.loads((ROOT / "kvbench" / "configs"
+                           / "deepseek-v3.2-exp-ep16-l5.json").read_text())
+        conf.pop("kvbench")
+        return SimpleNamespace(**{**conf, **over})
+
+    def test_the_loader_takes_the_published_keys(self):
+        cfg = config_from_hf(self.published(), page_size=64)
+        assert cfg.is_dsa and cfg.is_mla
+        assert (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk) == (
+            64, 128, 2048)
+        assert cfg.q_lora_rank == 1536 and cfg.latent_pad == 64
+        assert cfg.kv_cache_head_dim == 640
+        assert cfg.num_experts == 256 and cfg.experts_held == (0, 16)
+        assert cfg.moe_dispatch == "grouped" and cfg.moe_layers == (1, 2, 3, 4)
+        assert cfg.rope_scaling[0] == "yarn"
+        assert cfg.softmax_scale_mult == pytest.approx(
+            (0.1 * np.log(40.0) + 1.0) ** 2)
+
+    def test_a_share_that_does_not_add_up_is_refused(self):
+        share = {"chips": 8, "rank": 0, "n_routed_experts": 256}
+        with pytest.raises(ValueError, match="layer_share"):
+            config_from_hf(self.published(layer_share=share), page_size=64)
+
+    def test_init_params_makes_what_the_model_has(self):
+        cfg = toy()
+        layer = init_params(jax.random.PRNGKey(0), cfg)["layers"][0]
+        assert layer["w_dq"].shape == (128, 48)
+        assert layer["wq"].shape == (48, 4 * 64)
+        assert layer["w_iq"].shape == (48, 16 * 64)
+        assert layer["w_ik"].shape == (128, 64)
+        assert layer["w_iw"].shape == (128, 16)
+        assert {"q_latent_norm", "latent_norm", "index_norm",
+                "index_norm_bias"} <= set(layer)
+
+    def test_the_pool_holds_two_streams_under_one_page_id(self):
+        k, v = llama.init_kv_cache(toy(), 10)
+        assert k.shape == (3, 10, 1, 16, 128) and v.shape == (3, 10, 1, 16, 64)
+        k, v = llama.init_kv_cache(toy(index_topk=0), 10)
+        assert v.shape[-1] == 0
+
+    def test_an_indexer_needs_its_q_latent(self):
+        with pytest.raises(ValueError, match="q latent"):
+            toy(q_lora_rank=0)
+
+    def test_a_storage_tier_is_refused_at_construction(self):
+        from llmd_kv_cache_tpu.offload.spec import SharedStorageOffloadSpec
+
+        cfg = toy()
+        spec = SharedStorageOffloadSpec(
+            root="/tmp/never-made", model_name="m", page_size=16,
+            num_layers=3, kv_heads=1, head_dim=128, kv_streams=1)
+        with pytest.raises(ValueError, match="two streams a page"):
+            MiniEngine(EngineConfig(model=cfg, num_pages=16,
+                                    max_pages_per_seq=4, max_batch=2),
+                       offload_spec=spec)
+
+
+class TestCounters:
+    def test_the_phases_carry_the_counts(self, served):
+        from tests.test_telemetry import _recorded
+
+        eng = engine(served.cfg, served.params,
+                     telemetry=EngineTelemetryConfig())
+        seen = _recorded(eng._phases)
+        serve(eng, "counted", PROMPT[:40], 4)
+        dispatch = [a for n, a, _ in seen if n == "step.dispatch"
+                    and "selected_keys" in a]
+        # Prefill emits token 1; three decode steps at 41, 42, 43 keys.
+        assert [a["selected_keys"] for a in dispatch] == [TOPK] * 3
+        assert [a["index_keys"] for a in dispatch] == [41, 42, 43]
+        fetch = [a for n, a, _ in seen if n == "step.fetch"
+                 and "assignments_held" in a]
+        assert [a["counted_program"] for a in fetch] == (
+            ["prefill"] + ["decode"] * 3)
+        assert fetch[0]["counted_tokens"] == 8  # the last chunk's tokens
+        for a in fetch:
+            made = a["counted_tokens"] * 4 * 2
+            assert 0 <= a["assignments_held"] <= made
+            assert a["experts_touched"] <= min(16, a["assignments_held"])
+        finish = [a for n, a, _ in seen if n == "step.finish"]
+        assert all(a["programs"] == a["transfers"] for a in finish)
+
+
+class TestCounts:
+    @pytest.fixture(scope="class")
+    def real(self):
+        conf = json.loads((ROOT / "kvbench" / "configs"
+                           / "deepseek-v3.2-exp-ep16-l5.json").read_text())
+        counts = names.counts(conf)
+        conf.pop("kvbench")
+        return counts, config_from_hf(SimpleNamespace(**conf), page_size=64)
+
+    def test_a_tokens_matmuls_are_the_cuts_parameters(self, real):
+        """2 FLOPs a parameter a token: attention 187.1 M and the indexer
+        14.0 M in all 5 layers, the dense layer's 396.4 M, and in 4 layers
+        the gate 1.8 M, the shared expert 44.0 M and half an expert held."""
+        counts, cfg = real
+        per_layer = counts.flops_per_token(cfg) / cfg.num_layers
+        assert per_layer == pytest.approx(6.7e8, rel=0.01)
+
+    def test_a_pair_pays_the_indexer_over_all_and_attention_over_kept(
+            self, real):
+        counts, cfg = real
+        base = counts.prefill_flops(cfg, 0, 1) - counts.prefill_flops(
+            cfg, 0, 0)
+        one = counts.prefill_flops(cfg, 9999, 1) - base
+        # One query at 10,000 keys: 64 x 128 over all, 128 heads x (2 x
+        # 128 + 64) over the 2048 kept; its own key was in ``base``.
+        want = 5 * 2.0 * (64 * 128 * 9999 + 128 * 320 * 2047)
+        assert one == pytest.approx(want, rel=1e-6)
+
+    def test_decode_bytes_are_a_floor(self, real):
+        counts, cfg = real
+        for n in (100, 2048, 8192, 33792):
+            true = counts.latent_bytes(cfg, min(n, 2048))
+            assert counts.decode_attention_bytes(cfg, n) <= true
+        assert counts.latent_bytes(cfg, 1) == 5 * 640 * 2
+        assert counts.index_bytes(cfg, 1) == 5 * 128 * 2

@@ -1,0 +1,104 @@
+"""The kernels that serve ``deepseek-v3.2-exp-ep16-l5``, compiled for a TPU
+v5e at the configuration's real widths, with no chip: the TPU's compiler is
+installed here and compiles for a chip that is described and not attached.
+It refuses what the interpreter lets through (a slice off the tiling, more
+fast memory than a kernel may use). Nothing runs: a compile that passes is
+not a chip run.
+
+The topology is described inside a fixture and never at import: only one
+process may load the TPU's library, and every xdist worker imports every
+test file. All of these tests stay in this one file for the same reason.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from llmd_kv_cache_tpu.models import llama
+from llmd_kv_cache_tpu.ops import sparse_index
+from llmd_kv_cache_tpu.ops.pallas_paged_attention import (
+    pallas_paged_decode_attention, pallas_paged_prefill_attention)
+
+# The configuration's engines: 5 layers, 2500 pages of 64 tokens a pool,
+# 528 pages a row at most, 8 decoding rows, chunks of 512.
+LAYERS, PAGES, PAGE, ROWS, ROW_PAGES, CHUNK = 5, 2500, 64, 8, 528, 512
+KEYS = ROW_PAGES * PAGE
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shaped(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def compiles(fn, *args) -> None:
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,q_seq", [(ROWS, 1), (1, CHUNK)],
+                         ids=["decode", "prefill"])
+def test_index_scores(shaped, rows, q_seq):
+    compiles(sparse_index.dsa_index_scores,
+             shaped((rows, q_seq, 64, 128)),
+             shaped((rows, q_seq, 64), jnp.float32),
+             shaped((rows, KEYS, 128)), shaped((rows,), jnp.int32))
+
+
+def test_masked_latent_prefill(shaped):
+    """128 query heads on one 640-lane latent head, 16 query rows a
+    program, a selection bias over every key of the row."""
+    def prefill(q, k, v, table, ctx, total, bias):
+        return pallas_paged_prefill_attention(
+            q, k, v, table, ctx, total, q_tile=16, shared_kv=True,
+            layer_idx=2, bias=bias)
+
+    compiles(prefill, shaped((1, CHUNK, 128, 640)),
+             shaped((LAYERS, PAGES, 1, PAGE, 640)),
+             shaped((LAYERS, PAGES, 1, PAGE, 640)),
+             shaped((1, ROW_PAGES), jnp.int32), shaped((1,), jnp.int32),
+             shaped((1,), jnp.int32),
+             shaped((1, CHUNK, KEYS), jnp.float32))
+
+
+@pytest.mark.parametrize("gathered", [False, True],
+                         ids=["the-pool", "the-chosen"])
+def test_latent_decode(shaped, gathered):
+    """The decode kernel over the latent pool (rows that attend every key)
+    and over the pool of a step's chosen latents (2048 a row)."""
+    pool = ((ROWS * 2048 // PAGE, 1, PAGE, 640) if gathered
+            else (LAYERS, PAGES, 1, PAGE, 640))
+    pages = 2048 // PAGE if gathered else ROW_PAGES
+    compiles(functools.partial(pallas_paged_decode_attention, shared_kv=True,
+                               layer_idx=None if gathered else 2),
+             shaped((ROWS, 128, 640)), shaped(pool), shaped(pool),
+             shaped((ROWS, pages), jnp.int32), shaped((ROWS,), jnp.int32))
+
+
+@pytest.mark.parametrize("m,k,n", [(4096, 7168, 2048), (128, 2048, 7168)],
+                         ids=["up-of-a-chunk", "down-of-a-step"])
+def test_grouped_matmul(shaped, m, k, n):
+    compiles(lambda x, w, sizes: llama._grouped_matmul(
+        x, w, sizes, {"interpret": False}),
+        shaped((m, k)), shaped((16, k, n)), shaped((16,), jnp.int32))
