@@ -8,7 +8,7 @@ PY := python
 CPU_ENV := PYTHONPATH=. JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: test unit-test-race tsan asan native chip-smoke bench-hotpath bench-hotpath-fleet bench-engine-telemetry bench-shard bench-fleet bench-pyprof bench-workingset bench-controller bench-graytail bench-fencing bench-incident perf-check verify graft-check verify-examples chaos lint clean
+.PHONY: test unit-test-race tsan asan native chip-smoke bench-hotpath bench-hotpath-fleet bench-hotpath-evict bench-engine-telemetry bench-shard bench-fleet bench-pyprof bench-workingset bench-controller bench-graytail bench-fencing bench-incident perf-check verify graft-check verify-examples chaos lint clean
 
 test: native
 	$(CPU_ENV) $(PY) -m pytest tests/ -q
@@ -77,6 +77,12 @@ bench-hotpath: native
 # and the ingest-lag staleness bound internally.
 bench-hotpath-fleet: native
 	$(CPU_ENV) $(PY) hack/bench_hotpath.py --fleet
+
+# Eviction arm: the pages one admission takes from a full pool (45 of
+# 2,559 cached blocks), through BlockManager's kept order and through the
+# scan it replaced. Host time; the ratio is the sentinel's value.
+bench-hotpath-evict: native
+	$(CPU_ENV) $(PY) hack/bench_hotpath.py --evict
 
 # Engine-telemetry overhead gate: asserts the per-step hook cost stays
 # under 1% of the decode-step p50 (telemetry/engine_telemetry.py).
@@ -161,6 +167,7 @@ perf-check: native
 	$(CPU_ENV) $(PY) bench.py --fencing > /tmp/kvtpu_fencing_bench.json
 	$(CPU_ENV) $(PY) bench.py --incident > /tmp/kvtpu_incident_bench.json
 	$(CPU_ENV) $(PY) hack/bench_hotpath.py --fleet > /tmp/kvtpu_fleet_bench.json
+	$(CPU_ENV) $(PY) hack/bench_hotpath.py --evict > /tmp/kvtpu_evict_bench.json
 	$(PY) hack/perf_sentinel.py --baseline benchmarking/perf_baseline.json \
 	  --results pyprof-overhead=/tmp/kvtpu_pyprof_bench.json \
 	  --results workingset=/tmp/kvtpu_workingset_bench.json \
@@ -169,7 +176,8 @@ perf-check: native
 	  --results audit=/tmp/kvtpu_audit_bench.json \
 	  --results fencing=/tmp/kvtpu_fencing_bench.json \
 	  --results incident=/tmp/kvtpu_incident_bench.json \
-	  --results hotpath-fleet=/tmp/kvtpu_fleet_bench.json
+	  --results hotpath-fleet=/tmp/kvtpu_fleet_bench.json \
+	  --results hotpath-evict=/tmp/kvtpu_evict_bench.json
 
 # The pre-merge bundle: conventions lint + the perf sentinel.
 verify: lint perf-check

@@ -30,6 +30,15 @@ for both wires, ingest lag percentiles, and the sampled hot-function
 shares; the JSON ``value`` is the batched/per-chunk throughput ratio
 (the ≥5x acceptance gate of ISSUE 17, hard-asserted here too).
 
+``--evict`` times the engine's other host hook on the way to a first
+token: the pages an admission takes from a full pool (45 pages from 2,559
+cached, unreferenced blocks: what a `sessions` admission evicts), through
+``BlockManager.allocate_pages`` (order kept in a heap, one event batch)
+and through the walk it replaced (every victim found by a scan of the
+pool, one event a victim), into a sink that does nothing. The JSON
+``value`` is the scan's p50 over the kept order's. A host time on this
+sandbox's CPU: no device runs and no device metric is named.
+
 Pure CPU scheduling-path work; run it pinned (`taskset`) for stable
 numbers. The ≥5x acceptance gate of ISSUE 2 applies to repeat_prefix.
 """
@@ -419,6 +428,79 @@ def bench_fleet(args) -> dict:
     }
 
 
+def _scan_allocate(blocks: dict, free_pages: list, n: int, sink) -> list:
+    """``n`` pages the way ``BlockManager`` found them before its order was
+    kept (``blocks``: hash -> [page, ref_count, last_used]): a walk of the
+    whole pool for each victim, one BlockRemoved a victim."""
+    pages = []
+    for _ in range(n):
+        if not free_pages:
+            victim, victim_time = None, float("inf")
+            for h, info in blocks.items():
+                if info[1] == 0 and info[2] < victim_time:
+                    victim, victim_time = h, info[2]
+            if victim is None:
+                break
+            free_pages.append(blocks.pop(victim)[0])
+            sink([BlockRemovedEvent(block_hashes=[victim], group_idx=0)])
+        pages.append(free_pages.pop())
+    return pages
+
+
+def bench_evict(*, pool_pages: int, pages: int, rounds: int) -> dict:
+    """An admission's pages from a pool full of idle cached blocks."""
+    from llmd_kv_cache_tpu.models.engine import BlockManager, EngineConfig
+    from llmd_kv_cache_tpu.models.llama import LlamaConfig
+
+    proc = ChunkedTokenDatabase(TokenProcessorConfig(block_size_tokens=BLOCK))
+
+    def sink(events):       # the manager alone: nothing ingests
+        pass
+
+    bm = BlockManager(
+        EngineConfig(model=LlamaConfig.tiny(), num_pages=pool_pages),
+        proc, event_sink=sink)
+    toks = [[0] * BLOCK]
+    next_hash = iter(range(1, 10**9))
+
+    def commit_and_release(free):
+        # Sessions of 20 blocks: one last_used a session, as in serving.
+        for lo in range(0, len(free), 20):
+            run = free[lo:lo + 20]
+            hashes = [next(next_hash) for _ in run]
+            bm.commit_blocks(hashes, run, toks * len(run), 0)
+            bm.release(hashes, [])
+
+    commit_and_release(bm.allocate_pages(pool_pages - 1))
+    scan_blocks = {h: [i.page, 0, i.last_used] for h, i in bm.blocks.items()}
+    scan_free: list = []
+    kept, scan = [], []
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        got = bm.allocate_pages(pages)
+        kept.append(time.perf_counter() - t0)
+        assert len(got) == pages
+        commit_and_release(got)
+
+        t0 = time.perf_counter()
+        got = _scan_allocate(scan_blocks, scan_free, pages, sink)
+        scan.append(time.perf_counter() - t0)
+        assert len(got) == pages
+        now = time.monotonic()
+        for page in got:
+            scan_blocks[next(next_hash)] = [page, 0, now]
+    assert bm.evictions == rounds * pages
+    return {
+        "bench": "hotpath-evict",
+        "cached_blocks": pool_pages - 1, "pages": pages, "rounds": rounds,
+        "platform": "cpu-host",
+        "scan": pcts(scan), "kept_order": pcts(kept),
+        "value": round(statistics.median(scan) / statistics.median(kept), 1),
+        "unit": "scan p50 / kept-order p50, host time of one admission's "
+                "pages",
+    }
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     # 100k tokens is the ISSUE's motivating scenario: a multi-turn session
@@ -434,6 +516,9 @@ def main():
                     help="run the fleet-scale data-plane arm instead "
                          "(4 shards, batched vs per-chunk fan-out, "
                          "concurrent zero-copy ingest)")
+    ap.add_argument("--evict", action="store_true",
+                    help="run the eviction arm instead: one admission's "
+                         "pages from a full pool, kept order vs the scan")
     ap.add_argument("--fleet-prompt-tokens", type=int, default=32 * 1024)
     ap.add_argument("--fleet-chunk", type=int, default=16,
                     help="fanoutChunkBlocks for both wires (fine-grained "
@@ -456,6 +541,10 @@ def main():
 
     if args.fleet:
         print(json.dumps(bench_fleet(args)))
+        return
+    if args.evict:
+        # cell 1's pool (2,560 pages a replica) and its 45 victims a request
+        print(json.dumps(bench_evict(pool_pages=2560, pages=45, rounds=200)))
         return
 
     result = {"bench": "hotpath", "prompt_tokens": args.prompt_tokens,

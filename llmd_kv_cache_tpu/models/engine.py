@@ -18,6 +18,7 @@ from. Evictions are LRU over unreferenced pages and emit BlockRemoved.
 from __future__ import annotations
 
 import functools
+import heapq
 import time
 import dataclasses
 from dataclasses import dataclass, field
@@ -237,6 +238,9 @@ class _BlockInfo:
     last_used: float = 0.0
     parent_hash: int = 0
     tokens: tuple[int, ...] = ()
+    # Place in ``BlockManager.blocks``' insertion order: among unreferenced
+    # blocks of one ``last_used`` the one that entered first leaves first.
+    entry_seq: int = 0
 
 
 @dataclass
@@ -334,6 +338,15 @@ class BlockManager:
         self.free_pages: list[int] = list(range(1, pool))  # 0 reserved
         self.blocks: dict[int, _BlockInfo] = {}  # block_hash → info
         self.page_to_hash: dict[int, int] = {}
+        # Eviction order, kept and not found: a heap of (last_used,
+        # entry_seq, hash) with one entry pushed each time a block's
+        # ref_count falls to 0. An entry goes stale when its block is
+        # referenced again, evicted or cleared; stale entries are thrown
+        # away as they are popped, and the heap is rebuilt from ``blocks``
+        # once they outnumber the ``_idle`` (ref_count == 0) blocks.
+        self._idle_heap: list[tuple[float, int, int]] = []
+        self._idle = 0
+        self._entry_seq = 0
         # Lifetime eviction count: a plain int (one add per eviction) that
         # telemetry turns into kvtpu_engine_kv_pool_evictions_total deltas.
         self.evictions = 0
@@ -417,14 +430,18 @@ class BlockManager:
             pages.append(info.page)
         return pages
 
+    def _reference(self, info: _BlockInfo, now: float) -> None:
+        if info.ref_count == 0:
+            self._idle -= 1  # its heap entry is stale from here on
+        info.ref_count += 1
+        info.last_used = now
+
     def acquire_prefix(self, block_hashes: Sequence[int]) -> list[int]:
         """Reference the longest resident prefix; bumps ref counts."""
         pages = self.match_prefix(block_hashes)
         now = time.monotonic()
         for h in block_hashes[: len(pages)]:
-            info = self.blocks[h]
-            info.ref_count += 1
-            info.last_used = now
+            self._reference(self.blocks[h], now)
         return pages
 
     def try_acquire_blocks(self, block_hashes: Sequence[int]) -> Optional[list[int]]:
@@ -438,41 +455,67 @@ class BlockManager:
             infos.append(info)
         now = time.monotonic()
         for info in infos:
-            info.ref_count += 1
-            info.last_used = now
+            self._reference(info, now)
         return [info.page for info in infos]
 
     def allocate_page(self) -> Optional[int]:
         """Pop a free page, evicting LRU unreferenced blocks if needed."""
-        if not self.free_pages and not self._evict_one():
-            return None
-        return self.free_pages.pop()
+        pages = self.allocate_pages(1)
+        return pages[0] if pages else None
 
-    def _evict_one(self) -> bool:
-        victim_hash = None
-        victim_time = float("inf")
-        for h, info in self.blocks.items():
-            if info.ref_count == 0 and info.last_used < victim_time:
-                victim_time = info.last_used
-                victim_hash = h
-        if victim_hash is None:
-            return False
-        info = self.blocks.pop(victim_hash)
-        self.page_to_hash.pop(info.page, None)
-        self.free_pages.append(info.page)
-        self.evictions += 1
+    def allocate_pages(self, n: int) -> list[int]:
+        """``n`` pages as ``n`` ``allocate_page`` calls would hand them out:
+        free pages first (from the end of ``free_pages``), then the pages
+        of as many LRU victims as are still needed, told to the sink in
+        one batch. A shorter list means the pool ran dry: what was evicted
+        stays evicted and reported, and the caller hands the pages back
+        (``free_pages``).
+        """
+        free = self.free_pages
+        had = len(free)
+        if n <= had:
+            pages = free[had - n:][::-1]
+            del free[had - n:]
+            return pages
+        self._evict(n - had)  # the victims' pages follow the free ones
+        pages = free[:had][::-1] + free[had:]
+        free.clear()
+        return pages
+
+    def _evict(self, n: int) -> None:
+        """Evict up to ``n`` unreferenced blocks, least recently used first
+        (ties: first into ``blocks``); their pages go onto ``free_pages``
+        in that order and one BlockRemoved names them all."""
+        heap, blocks, free = self._idle_heap, self.blocks, self.free_pages
+        victims: list[int] = []
+        last_uses: list[float] = []
+        while len(victims) < n and heap:
+            last_used, seq, h = heapq.heappop(heap)
+            info = blocks.get(h)
+            if (info is None or info.entry_seq != seq or info.ref_count
+                    or info.last_used != last_used):
+                continue  # stale: referenced again, or already gone
+            del blocks[h]
+            self.page_to_hash.pop(info.page, None)
+            free.append(info.page)
+            victims.append(h)
+            last_uses.append(last_used)
+        if not victims:
+            return
+        self._idle -= len(victims)
+        self.evictions += len(victims)
         if self.on_evict is not None:
-            try:
-                self.on_evict(time.monotonic() - victim_time)
-            except Exception:  # pragma: no cover  # lint: allow-swallow
-                pass
+            now = time.monotonic()
+            for last_used in last_uses:
+                try:
+                    self.on_evict(now - last_used)
+                except Exception:  # pragma: no cover  # lint: allow-swallow
+                    pass
         # Must carry the same group tag as the BlockStored that created the
-        # entry, or the index's entry-match eviction is a silent no-op.
+        # entries, or the index's entry-match eviction is a silent no-op.
         self._emit([
-            BlockRemovedEvent(block_hashes=[victim_hash],
-                              group_idx=self.group_idx)
+            BlockRemovedEvent(block_hashes=victims, group_idx=self.group_idx)
         ])
-        return True
 
     def commit_blocks(
         self,
@@ -519,9 +562,11 @@ class BlockManager:
         for h, page, toks in zip(block_hashes, pages, tokens_per_block):
             existing = self.blocks.get(h)
             if existing is None:
+                self._entry_seq += 1
                 self.blocks[h] = _BlockInfo(
                     page=page, ref_count=1, last_used=now,
                     parent_hash=parent, tokens=tuple(toks),
+                    entry_seq=self._entry_seq,
                 )
                 self.page_to_hash[page] = h
                 if not run_hashes:
@@ -531,8 +576,7 @@ class BlockManager:
                 canonical_pages.append(page)
             else:
                 # Recomputed duplicate: adopt the resident page, free ours.
-                existing.ref_count += 1
-                existing.last_used = now
+                self._reference(existing, now)
                 if page != existing.page:
                     self.free_pages.append(page)
                 canonical_pages.append(existing.page)
@@ -544,11 +588,22 @@ class BlockManager:
 
     def release(self, block_hashes: Sequence[int], orphan_pages: Sequence[int]) -> None:
         """Drop a finished request's references; free unhashed pages."""
+        heap = self._idle_heap
         for h in block_hashes:
             info = self.blocks.get(h)
             if info is not None and info.ref_count > 0:
                 info.ref_count -= 1
+                if info.ref_count == 0:
+                    self._idle += 1
+                    heapq.heappush(
+                        heap, (info.last_used, info.entry_seq, h))
         self.free_pages.extend(orphan_pages)
+        if len(heap) > 2 * self._idle + 64:
+            # More stale entries than live ones: start over from the blocks.
+            heap[:] = [(info.last_used, info.entry_seq, h)
+                       for h, info in self.blocks.items()
+                       if info.ref_count == 0]
+            heapq.heapify(heap)
 
     def clear(self, emit: bool = True) -> None:
         """Drop the whole prefix cache (weight rollout) and emit the reset.
@@ -561,6 +616,8 @@ class BlockManager:
             self.free_pages.append(info.page)
         self.blocks.clear()
         self.page_to_hash.clear()
+        self._idle_heap.clear()
+        self._idle = 0
         if emit:
             self._emit([AllBlocksClearedEvent()])
 
@@ -1442,9 +1499,12 @@ class MiniEngine:
                 EMPTY_BLOCK_HASH, prompt, self.cfg.model_name
             )
         with phase(self._phases, PHASE_ENQUEUE_LOOKUP) as sp:
+            evictions = self.block_manager.evictions
             self._acquire_pages(req, total_needed, defer_restore)
             sp.set_attribute("blocks", len(req.block_hashes))
             sp.set_attribute("hit_blocks", req.hbm_hit_blocks)
+            sp.set_attribute(
+                "evicted", self.block_manager.evictions - evictions)
         return req
 
     def _acquire_pages(self, req: Request, total_needed: int,
@@ -1507,9 +1567,12 @@ class MiniEngine:
         # decode allocate them lazily per chunk and reclaim out-of-window
         # slots as the context advances, so peak SWA-pool demand stays
         # window-bounded instead of prompt-length-bounded.
-        new_pages: list[int] = []
-
-        def rollback():
+        # One call for all of them: what it evicts leaves in one ordered
+        # pop and reaches the index as one batch, ahead of every
+        # BlockStored this request will commit.
+        lacking = max(total_needed - len(req.pages), 0)
+        new_pages = self.block_manager.allocate_pages(lacking)
+        if len(new_pages) < lacking:
             # Return popped pages and drop the refs on every block this
             # request holds — the HBM prefix AND any blocks just restored
             # from storage — so a failed admission cannot shrink the pool
@@ -1520,13 +1583,7 @@ class MiniEngine:
             if self.hybrid:
                 self.swa_manager.release(
                     req.block_hashes[req.swa_acquired_from:n_cached], [])
-
-        while len(req.pages) + len(new_pages) < total_needed:
-            page = self.block_manager.allocate_page()
-            if page is None:
-                rollback()
-                raise RuntimeError("out of KV pages")
-            new_pages.append(page)
+            raise RuntimeError("out of KV pages")
         req.pages.extend(new_pages)
 
         # Everything acquired/restored so far is registered+refcounted in

@@ -204,6 +204,7 @@ class TestPerfSentinel:
         assert "fencing" in manifest["benches"]
         assert "hotpath-fleet" in manifest["benches"]
         assert "incident" in manifest["benches"]
+        assert "hotpath-evict" in manifest["benches"]
         sentinel = self._sentinel()
         nominal = {
             "pyprof-overhead": {
@@ -231,6 +232,10 @@ class TestPerfSentinel:
             "incident": {
                 "metric": "incident_trigger_overhead_pct", "value": 0.55,
                 "unit": "% of score p50", "vs_baseline": 1.0},
+            "hotpath-evict": {
+                "bench": "hotpath-evict", "value": 57.0,
+                "unit": "scan p50 / kept-order p50, host time of one "
+                        "admission's pages"},
         }
         # The nominal set must cover the whole committed manifest — a
         # bench added to the baseline without a result arm here is the

@@ -769,6 +769,40 @@ class TestEnginePhases:
         emits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_EMIT]
         assert all(e["events"] >= 1 for e in emits)
 
+    def test_lookup_counts_its_victims_and_emits_them_once(self):
+        """An admission that has to evict carries ``evicted`` on
+        ``enqueue.lookup`` and opens one ``step.emit`` inside it, however
+        many blocks leave."""
+        events = []
+        eng, tiny = _phase_engine(sink=events.extend, num_pages=12)
+        page = tiny.page_size
+        for r in range(3):     # nine idle blocks, two free pages
+            eng.enqueue(f"r{r}", list(range(100 * r, 100 * r + 3 * page)),
+                        max_new_tokens=1)
+            _drain(eng)
+        before = eng.block_manager.evictions
+        seen = _recorded(eng._phases)
+        eng.enqueue("big", list(range(900, 900 + 6 * page + 1)),
+                    max_new_tokens=1)
+        names = [n for n, _, _ in seen]
+        assert names == [tracing.PHASE_ENQUEUE_ADMIT,
+                         tracing.PHASE_ENQUEUE_HASH,
+                         tracing.PHASE_ENQUEUE_LOOKUP,
+                         tracing.PHASE_STEP_EMIT]
+        lookup, emit = seen[2][1], seen[3][1]
+        assert lookup["blocks"] == 6 and lookup["hit_blocks"] == 0
+        # 7 pages + 1 of room against 2 free: 6 victims, one event.
+        assert lookup["evicted"] == 6 == eng.block_manager.evictions - before
+        assert emit["events"] == 1
+        # One that evicts nothing says so.
+        del seen[:]
+        _drain(eng)
+        del seen[:]
+        eng.enqueue("hit", list(range(900, 900 + 2 * page)), max_new_tokens=1)
+        (lookup,) = [a for n, a, _ in seen
+                     if n == tracing.PHASE_ENQUEUE_LOOKUP]
+        assert lookup["evicted"] == 0 and lookup["hit_blocks"] == 2
+
     @pytest.mark.parametrize("backend", ["xla", "pallas", "ragged"])
     def test_a_step_is_one_program_and_one_transfer(self, backend):
         """A decode-only ``step()`` dispatches one program fed by one
