@@ -10,9 +10,16 @@ chip (``chiprun -- python3 hack/kvbench_probe_readings.py ...``) or, with
   --margins            also the reference's ``margin_readings`` (how far
                        bfloat16 moves a router's deciding gaps)
   --control TYPE       the probe against the reference's own ``Control``
-                       (its forward rounded to TYPE, e.g. float8_e4m3fn)
-                       in the engine's place: it has to come out not ok
+                       (its forward rounded to TYPE, e.g. float8_e4m3fn;
+                       ``state:bfloat16`` where the reference keeps a
+                       sequence state) in the engine's place: it has to
+                       come out not ok
   --set KEY=VALUE      a published key of the configuration replaced
+  --serve KEY=VALUE    a key replaced in what the engine SERVES only (the
+                       reference keeps the configuration's): a planted
+                       fault, e.g. ``swiglu_limit=0``
+  --fault NAME         a fault planted in the serving program
+                       (``FAULTS``); it has to come out not ok
 """
 
 from __future__ import annotations
@@ -30,6 +37,55 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from kvbench.harness import correct, fleet as F, names  # noqa: E402
 
 
+def _stale_state() -> None:
+    """A prefix hit takes the pages and not the snapshot: the row's working
+    slot keeps what its last owner left in it."""
+    from llmd_kv_cache_tpu.models import engine
+
+    engine.copy_state_slot = lambda state, _src_dst: state
+
+
+def _conv_tail_dropped() -> None:
+    """Every prefill chunk starts its conv from zeros, as if the row had
+    no earlier tokens."""
+    import jax.numpy as jnp
+
+    from llmd_kv_cache_tpu.models import llama
+
+    served = llama._gated_deltanet
+
+    def dropped(x, layer, cfg, lj, state, *rest):
+        recurrent, conv, slots, snap = state
+        if x.shape[1] == 1:
+            return served(x, layer, cfg, lj, state, *rest)
+        out, (recurrent, new, slots, snap) = served(
+            x, layer, cfg, lj, (recurrent, jnp.zeros_like(conv), slots,
+                                snap), *rest)
+        return out, (recurrent, conv.at[lj].set(new[lj]), slots, snap)
+
+    llama._gated_deltanet = dropped
+
+
+def _gate_left_out() -> None:
+    """Attention's heads reach ``wo`` ungated (the forward reads the gate
+    off the weight tree, so the tree is shown without it)."""
+    from llmd_kv_cache_tpu.models import llama
+
+    served = llama._sublayer_out
+
+    def ungated(out, gate_in, layer, *rest):
+        return served(out, gate_in,
+                      {k: v for k, v in layer.items() if k != "w_og"}, *rest)
+
+    llama._sublayer_out = ungated
+
+
+# Faults planted in the program, by name. Each replaces something the step
+# programs look up when they are first traced.
+FAULTS = {"stale-state": _stale_state, "conv-tail": _conv_tail_dropped,
+          "no-gate": _gate_left_out}
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="deepseek-v3.2-exp-ep16-l5")
@@ -38,7 +94,11 @@ def main() -> None:
     ap.add_argument("--control", default="")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--set", action="append", default=[])
+    ap.add_argument("--serve", action="append", default=[])
+    ap.add_argument("--fault", default="", choices=["", *FAULTS])
     args = ap.parse_args()
+    if args.fault:
+        FAULTS[args.fault]()
 
     import jax
 
@@ -48,6 +108,10 @@ def main() -> None:
     for pair in args.set:
         key, value = pair.split("=", 1)
         conf[key] = json.loads(value)
+    served_conf = dict(conf)
+    for pair in args.serve:
+        key, value = pair.split("=", 1)
+        served_conf[key] = json.loads(value)
     ref = names.reference(conf)
     eng = conf["kvbench"]["engine"]
     sizes = conf["kvbench"]["probe"]
@@ -62,7 +126,8 @@ def main() -> None:
             fl.engines["pod-0"] = ref.Control(params, cfg, args.control)
         else:
             fl.engines["pod-0"] = MiniEngine(EngineConfig(
-                model=cfg, model_name="m", pod_identifier="pod-0",
+                model=F.model_config(served_conf), model_name="m",
+                pod_identifier="pod-0",
                 num_pages=int(eng["num_pages"]),
                 max_pages_per_seq=int(eng["max_pages_per_seq"]),
                 max_batch=int(eng["max_batch"]),
@@ -70,7 +135,9 @@ def main() -> None:
                 params=params)
         rep = correct.probe(fl, params, ref, seed, n, new)
         stats = jax.devices()[0].memory_stats() or {}
-        print(f"READING seed {seed} {args.control or 'served'}: ok "
+        what = " ".join(filter(None, [args.control or "served", args.fault,
+                                      *args.serve]))
+        print(f"READING seed {seed} {what}: ok "
               f"{rep['ok']} prefill_err {rep['prefill_rel_err']:.4f} "
               f"shortfall {rep['decode_worst_shortfall']:.4f} hit "
               f"{rep['hit_rel_err']:.4f} alternatives "

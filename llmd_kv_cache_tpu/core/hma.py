@@ -42,10 +42,17 @@ class GroupCatalog:
     def __init__(self) -> None:
         self._lock = new_lock()
         self._entries: dict[str, dict[int, GroupMetadata]] = {}
+        # pod -> the group whose blocks are sequence-state snapshots (kind
+        # ``mamba``). Replaced whole when it changes, so a scorer reads it
+        # without the lock; empty while no pod has such a group.
+        self.state_groups: dict[str, int] = {}
 
     def learn(self, pod_id: str, group_idx: int, meta: GroupMetadata) -> None:
         with self._lock:
             self._entries.setdefault(pod_id, {})[group_idx] = meta
+            if (meta.kind == SPEC_MAMBA
+                    and self.state_groups.get(pod_id) != group_idx):
+                self.state_groups = {**self.state_groups, pod_id: group_idx}
 
     def get(self, pod_id: str, group_idx: int) -> Optional[GroupMetadata]:
         with self._lock:

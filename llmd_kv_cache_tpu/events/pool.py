@@ -167,7 +167,11 @@ class Pool:
         self.index = index
         self.token_processor = token_processor
         self.adapter = adapter if adapter is not None else create_adapter(self.cfg.engine_type)
-        self.group_catalog = GroupCatalog()
+        # Shared with the index (``Index.group_catalog``): entries carry a
+        # group's number, and whoever scores them needs its kind.
+        self.group_catalog = getattr(index, "group_catalog", None)
+        if self.group_catalog is None:
+            self.group_catalog = index.group_catalog = GroupCatalog()
         # Per-pod last-event tracking; scorers attached to this pool (via
         # Indexer.attach_liveness) demote pods whose index view went stale.
         self.liveness: Optional[PodLivenessTracker] = None

@@ -27,6 +27,12 @@ logger = get_logger("index")
 class Index(abc.ABC):
     """Thread-safe KV-block index backend contract."""
 
+    # What the groups of this index's entries mean, pod by pod (a
+    # ``core.hma.GroupCatalog``): the event pool that fills the index
+    # learns it from the same events and leaves it here, where the scorer
+    # of the indexer that reads the index finds it. None until a pool does.
+    group_catalog = None
+
     @abc.abstractmethod
     def lookup(
         self,

@@ -65,6 +65,7 @@ from ..telemetry.tracing import (
     PHASE_STEP_INPUTS,
     PHASE_STEP_OFFLOAD_POLL,
     PHASE_STEP_SCHEDULE,
+    PHASE_STEP_SNAPSHOT,
     NOOP_SPAN,
     SPAN_ENGINE_DECODE_STEP,
     EnginePhases,
@@ -74,23 +75,44 @@ from ..telemetry.tracing import (
 from ..utils.logging import get_logger
 from .llama import (
     LlamaConfig,
+    copy_state_slot,
     init_kv_cache,
     init_kv_cache_hybrid,
     init_params,
+    init_state_pool,
     pack_inputs,
     step_decode_pallas,
+    step_decode_pallas_state,
     step_decode_steps,
     step_decode_steps_hybrid,
     step_forward,
     step_forward_hybrid,
+    step_forward_state,
     step_prefill_pallas,
+    step_prefill_pallas_state,
     step_program,
     step_ragged,
 )
+from .state_pool import StatePool
 
 logger = get_logger("models.engine")
 
 EventSink = Callable[[list[GenericEvent]], None]
+
+# A step program and its form for a model with linear layers (the state pool
+# behind the page pools, the rows' slots behind the per-step arrays).
+_WITH_STATE = {
+    step_forward: step_forward_state,
+    step_decode_pallas: step_decode_pallas_state,
+    step_prefill_pallas: step_prefill_pallas_state,
+}
+
+
+def _with_state(program):
+    if isinstance(program, functools.partial):
+        return functools.partial(_WITH_STATE[program.func],
+                                 **program.keywords)
+    return _WITH_STATE[program]
 
 
 def _resolve_kv_dtype(name: str):
@@ -310,6 +332,14 @@ class Request:
     feedback: Any = None
     hbm_hit_blocks: int = 0
     restored_blocks: int = 0
+    # A model with linear layers: the row's working slot in the state pool
+    # (0: none), the blocks whose pages matched at admission (the hit is
+    # cut back to the deepest snapshot among them; passing their end, the
+    # prefill leaves a snapshot there for whoever shares as much), and the
+    # block hashes its prefill has left snapshots on, not yet announced.
+    state_slot: int = 0
+    page_hit_blocks: int = 0
+    snapshots: list[int] = field(default_factory=list)
 
     @property
     def total_len(self) -> int:
@@ -356,6 +386,10 @@ class BlockManager:
         self.on_evict: Optional[Callable[[float], None]] = None
         # The owning engine's EnginePhases (None = phases off).
         self.phases: Optional[EnginePhases] = None
+        # The engine's StatePool where the model keeps sequence states
+        # beside these pages: snapshots stand on blocks, so an eviction
+        # here takes the snapshots on or after its victims, in its batch.
+        self.state_pool: Optional[StatePool] = None
         if spec_kind is not None:
             self.spec_kind = spec_kind
             self.spec_window = spec_window
@@ -410,6 +444,8 @@ class BlockManager:
             # Page 0 is the reserved garbage page.
             "orphan_pages": max((self.num_pages - 1) - free - cached_pages, 0),
             "evictions": self.evictions,
+            **(self.state_pool.stats() if self.state_pool is not None
+               else {}),
         }
 
     def _emit(self, events: list[GenericEvent]) -> None:
@@ -513,9 +549,13 @@ class BlockManager:
                     pass
         # Must carry the same group tag as the BlockStored that created the
         # entries, or the index's entry-match eviction is a silent no-op.
-        self._emit([
+        events: list[GenericEvent] = [
             BlockRemovedEvent(block_hashes=victims, group_idx=self.group_idx)
-        ])
+        ]
+        if self.state_pool is not None:
+            self.state_pool.drop_dependents(victims)
+            events += self.state_pool.drain()
+        self._emit(events)
 
     def commit_blocks(
         self,
@@ -618,6 +658,8 @@ class BlockManager:
         self.page_to_hash.clear()
         self._idle_heap.clear()
         self._idle = 0
+        if self.state_pool is not None:
+            self.state_pool.clear()
         if emit:
             self._emit([AllBlocksClearedEvent()])
 
@@ -762,6 +804,39 @@ class MiniEngine:
                 raise ValueError(
                     "learned sparse attention (index_topk) is served on "
                     "one device: selection is not sharded over a mesh")
+        self.state_pool: Optional[StatePool] = None
+        self.state: tuple = ()
+        if mcfg.linear_layers:
+            # A sequence of this model is pages and a state; what cannot
+            # carry both refuses here rather than serve half of it.
+            for unfit, why in (
+                    (offload_spec is not None,
+                     "an offload spec (the storage tier's blocks are pages; "
+                     "a restored prefix would come back without its state)"),
+                    (mesh is not None,
+                     "a mesh (the state pool is not sharded)"),
+                    (self.cfg.decode_burst > 1,
+                     "decode_burst > 1 (a fused burst does not carry the "
+                     "states through its scan)"),
+                    (self.cfg.ragged_attention,
+                     "ragged_attention (the recurrence is served by the "
+                     "padded step programs)"),
+                    (self._fp8_cache,
+                     "an fp8 cache (the states are float32)"),
+                    (mcfg.state_slots <= self.cfg.max_batch,
+                     f"state_slots {mcfg.state_slots} for max_batch "
+                     f"{self.cfg.max_batch} (every running row holds a "
+                     f"working slot, and a snapshot needs one more)")):
+                if unfit:
+                    raise ValueError(
+                        "a model with linear layers keeps a state a "
+                        "sequence beside its pages and is not served "
+                        "with " + why)
+            self.state_pool = StatePool(mcfg.state_slots, mcfg.page_size)
+            with jax.default_device(device):
+                self.state = init_state_pool(mcfg)
+            if device is not None:
+                self.state = jax.device_put(self.state, device)
         if self.hybrid:
             num_swa = self.cfg.num_swa_pages or self.cfg.num_pages
             self.block_manager = BlockManager(
@@ -778,6 +853,7 @@ class MiniEngine:
                                              num_swa, dtype=kv_dtype)
         else:
             self.block_manager = BlockManager(self.cfg, self.processor, event_sink)
+            self.block_manager.state_pool = self.state_pool
             with jax.default_device(device):
                 pools = init_kv_cache(mcfg, self.cfg.num_pages,
                                       dtype=kv_dtype) + (None, None)
@@ -986,6 +1062,9 @@ class MiniEngine:
             # Single-token hybrid steps and hybrid prefill run the XLA
             # grouped forward over both pools.
             self._decode_forward = self._prefill_forward = step_forward_hybrid
+        if self.state_pool is not None:
+            self._decode_forward = _with_state(self._decode_forward)
+            self._prefill_forward = _with_state(self._prefill_forward)
         self._decode_multi = functools.partial(
             step_decode_steps, use_pallas=use_pallas,
             interpret=use_pallas and interpret, mesh=pallas_mesh,
@@ -1255,13 +1334,15 @@ class MiniEngine:
         """The page pools a step program is handed (and donated)."""
         if self.hybrid:
             return (self.k_cache, self.v_cache, self.k_swa, self.v_swa)
-        return (self.k_cache, self.v_cache)
+        return (self.k_cache, self.v_cache, *self.state)
 
     def _take_pools(self, pools: tuple) -> None:
         """The pools a step program handed back, in ``_pools``'s order."""
         self.k_cache, self.v_cache = pools[:2]
         if self.hybrid:
             self.k_swa, self.v_swa = pools[2:]
+        elif self.state:
+            self.state = pools[2:]
 
     # -- admission --
 
@@ -1505,6 +1586,10 @@ class MiniEngine:
             sp.set_attribute("hit_blocks", req.hbm_hit_blocks)
             sp.set_attribute(
                 "evicted", self.block_manager.evictions - evictions)
+            if self.state_pool is not None:
+                page = page_size * req.page_hit_blocks
+                sp.set_attribute("page_hit_tokens", page)
+                sp.set_attribute("state_hit_tokens", req.cached_len)
         return req
 
     def _acquire_pages(self, req: Request, total_needed: int,
@@ -1538,6 +1623,25 @@ class MiniEngine:
             cached_pages = cached_pages[:d]
             req.swa_pages = [swa_map.get(i, 0) for i in range(d)]
             req.swa_acquired_from = start_blk if d > 0 else 0
+        snapshot = None
+        if self.state_pool is not None:
+            # The hit is the deepest snapshot standing inside the matched
+            # pages (and short of the prompt's last token, whose logits
+            # need a step): pages beyond it are given back and computed
+            # again, which the commit finds resident.
+            req.page_hit_blocks = matched = len(cached_pages)
+            depth, snapshot = self.state_pool.lookup(
+                req.block_hashes,
+                min(matched, (len(req.prompt) - 1) // page_size))
+            self.block_manager.release(req.block_hashes[depth:matched], [])
+            cached_pages = cached_pages[:depth]
+            try:
+                req.state_slot = self.state_pool.acquire(
+                    request_id,
+                    keep=req.block_hashes[depth - 1] if depth else None)
+            except RuntimeError:
+                self.block_manager.release(req.block_hashes[:depth], [])
+                raise
         req.pages = list(cached_pages)
         req.cached_len = len(cached_pages) * page_size
         req.computed_len = req.cached_len
@@ -1583,8 +1687,19 @@ class MiniEngine:
             if self.hybrid:
                 self.swa_manager.release(
                     req.block_hashes[req.swa_acquired_from:n_cached], [])
+            if self.state_pool is not None:
+                self.state_pool.release(request_id)
+                self._emit_state_events()
             raise RuntimeError("out of KV pages")
         req.pages.extend(new_pages)
+        if self.state_pool is not None:
+            # What the working slot's eviction removed, if the pages'
+            # eviction has not already taken it along in its batch.
+            self._emit_state_events()
+            if snapshot is not None:
+                self.state = copy_state_slot(
+                    self.state, self._to_dev([snapshot, req.state_slot],
+                                             np.int32))
 
         # Everything acquired/restored so far is registered+refcounted in
         # the block manager; later pages stay private until commit.
@@ -1599,6 +1714,52 @@ class MiniEngine:
             self.telemetry.on_admitted(
                 request_id, req.cached_len // page_size)
 
+    def _emit_state_events(self) -> None:
+        """What the state pool has to tell the index, as one batch."""
+        events = self.state_pool.drain()
+        if events:
+            self.block_manager._emit(events)
+
+    def _plan_snapshots(self, req: Request, pos: int, n: int) -> tuple:
+        """The snapshots the chunk ``[pos, pos + n)`` of ``req``'s prefill
+        leaves: ``(snap, taken)`` with ``snap = [block, slot, end_slot]``
+        as the step program takes it (``llama.with_state``) and ``taken``
+        the ``(boundary, slot)`` pairs to register once it has run. Wanted
+        are the boundaries in ``(pos, pos + n]`` that are (a) the last one
+        a repeat of this prompt could resume from, (c) the end of the pages
+        that matched at admission beyond the snapshot it was admitted on,
+        (b) a multiple of ``state_checkpoint_tokens``. The chunk's end is
+        free (the state is there); of the boundaries inside it the scan
+        gives one, the first of a, c, b."""
+        page = self.cfg.model.page_size
+        pool = self.state_pool
+        every = self.cfg.model.state_checkpoint_tokens
+        last, end = (len(req.prompt) - 1) // page * page, pos + n
+        shared = req.page_hit_blocks * page
+        wanted = [b for b in (
+            last, shared if shared > req.cached_len else 0,
+            *(range(-(-(pos + 1) // every) * every, end + 1, every)
+              if every else ()))
+            if pos < b <= end and b % page == 0]
+        snap, taken = [-1, 0, 0], []
+        with phase(self._phases, PHASE_STEP_SNAPSHOT) as sp:
+            evicted = pool.evictions
+            for b in dict.fromkeys(wanted):
+                inner = b != end
+                if inner and snap[1]:
+                    continue  # the scan gives one state inside a chunk
+                slot = pool.reserve(req.block_hashes[b // page - 1])
+                if slot is None:
+                    continue
+                taken.append((b, slot))
+                if inner:
+                    snap[0], snap[1] = (b - pos) // page - 1, slot
+                else:
+                    snap[2] = slot
+            sp.set_attribute("snapshots", len(taken))
+            sp.set_attribute("state_evicted", pool.evictions - evicted)
+        return snap, taken
+
     def _finish_prefill(self, req: Request, first_token: int) -> None:
         """Prefill done: register the prompt's full blocks in the prefix
         cache and bootstrap decoding with the first generated token (the
@@ -1608,6 +1769,11 @@ class MiniEngine:
         before = req.committed_blocks
         with phase(self._phases, PHASE_STEP_COMMIT) as sp:
             self._commit_full_blocks(req)
+            if req.snapshots:
+                # The blocks they stand on are in the index from here on.
+                self.state_pool.announce(req.snapshots)
+                req.snapshots = []
+                self._emit_state_events()
             sp.set_attribute("request_id", req.request_id)
             sp.set_attribute("blocks", req.committed_blocks - before)
             req.output.append(first_token)
@@ -2160,8 +2326,12 @@ class MiniEngine:
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
                 token_sharding = NamedSharding(self.mesh, P(None, "sp"))
+            state_args, taken = (), ()
+            if self.state_pool is not None:
+                snap, taken = self._plan_snapshots(req, pos, len(chunk))
+                state_args = ([req.state_slot], snap)
             packed, shapes = pack_inputs(
-                (tokens, *tables, [pos], [len(chunk)]))
+                (tokens, *tables, [pos], [len(chunk)], *state_args))
 
         # Every program of a step is dispatched the same way, inside its
         # dispatch phase: its inputs go in one transfer (an argument of the
@@ -2174,7 +2344,7 @@ class MiniEngine:
         # not in a helper: through one (a frame more, the pools splatted)
         # every 28-layer program took 1.5-2 s longer to trace and lower on
         # the chip's host (PERF.md §6, PR 31) — why is not known either.
-        with self._dispatch_phase(req, 1, len(chunk), seq):
+        with self._dispatch_phase(req, 1, len(chunk), seq) as sp:
             token, row, pools = self._prefill_forward(
                 self.params, self.cfg.model, self._to_dev(packed),
                 self._pools(), shapes=shapes, last_only=True, keep_row=True,
@@ -2182,6 +2352,17 @@ class MiniEngine:
             self._take_pools(pools)
             if last:
                 token.copy_to_host_async()
+            if self.state_pool is not None:
+                sp.set_attribute("scan_tokens", len(chunk))
+        for boundary, slot in taken:
+            blocks = boundary // page_size
+            self.state_pool.store(
+                req.block_hashes[blocks - 1], slot,
+                req.block_hashes[:blocks],
+                req.block_hashes[blocks - 2] if blocks > 1
+                else EMPTY_BLOCK_HASH,
+                req.prompt[boundary - page_size:boundary])
+            req.snapshots.append(req.block_hashes[blocks - 1])
         req.computed_len = pos + len(chunk)
         if self.hybrid:
             self._swa_reclaim(req)  # reads computed_len
@@ -2796,8 +2977,14 @@ class MiniEngine:
                     self._swa_ensure(
                         req, req.computed_len // self.cfg.model.page_size)
                     swa_tables[0][i] = self._swa_table_for(req)
+            state_args = ()
+            if self.state_pool is not None:
+                slots = np.zeros((b,), np.int32)  # rows without: the spare
+                slots[:len(chunk)] = [req.state_slot for req in chunk]
+                state_args = (slots, [0, 0, 0])
             packed, shapes = pack_inputs(
-                (last[:, None], tables, *swa_tables, ctx, new_lens))
+                (last[:, None], tables, *swa_tables, ctx, new_lens,
+                 *state_args))
 
         # Dispatched as every program of a step is: see _prefill_chunk.
         with self._dispatch_phase(None, len(chunk), len(chunk), b) as sp:
@@ -2806,6 +2993,8 @@ class MiniEngine:
                 self._pools(), shapes=shapes)
             self._take_pools(pools)
             picked.copy_to_host_async()
+            if self.state_pool is not None:
+                sp.set_attribute("state_rows", len(chunk))
             topk = self.cfg.model.index_topk
             if topk and sp is not NOOP_SPAN:
                 # What the step's selection reads a layer, from the rows'
@@ -2849,6 +3038,10 @@ class MiniEngine:
         committed_pages = set(req.pages[:n_comm])
         orphans = [p for p in req.pages[n_comm:] if p not in committed_pages]
         self.block_manager.release(req.block_hashes[:n_comm], orphans)
+        if self.state_pool is not None:
+            self.state_pool.release(req.request_id)
+            # Left by a prefill that never committed the blocks under them.
+            self.state_pool.forget(req.snapshots)
         if self.hybrid:
             # SWA group: this request references blocks from
             # swa_acquired_from onward (earlier slots were reclaimed as

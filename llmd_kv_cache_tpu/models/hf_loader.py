@@ -26,6 +26,7 @@ no loader; this is part of the in-tree serving engine
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 from typing import Any, Mapping
@@ -33,7 +34,7 @@ from typing import Any, Mapping
 import jax.numpy as jnp
 import numpy as np
 
-from .llama import LlamaConfig, Params
+from .llama import LinearAttention, LlamaConfig, Params
 
 
 def _yarn_get_mscale(scale: float, m: float = 1.0) -> float:
@@ -102,7 +103,8 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     # Phi's partial rotary, …) must refuse rather than convert to
     # silently-wrong logits.
     supported = ("llama", "mistral", "mixtral", "qwen2", "qwen3",
-                 "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32")
+                 "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32",
+                 "gigachat3_5")
     if hf_cfg.model_type not in supported:
         raise NotImplementedError(
             f"model_type {hf_cfg.model_type!r} is not supported "
@@ -119,6 +121,8 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     if hf_cfg.model_type.startswith("deepseek"):
         return _config_from_deepseek(hf_cfg, page_size, dtype,
                                      rope_scaling)
+    if hf_cfg.model_type == "gigachat3_5":
+        return _config_from_gigachat(hf_cfg, page_size, dtype, rope_scaling)
     if getattr(hf_cfg, "mlp_bias", False):
         raise NotImplementedError(
             "MLP biases are not implemented; a bias-free conversion "
@@ -236,7 +240,7 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
     moe_kw = {}
     first_dense = getattr(hf_cfg, "first_k_dense_replace", 0)
     if getattr(hf_cfg, "n_routed_experts", None) and n_layers > first_dense:
-        if hf_cfg.model_type not in ("deepseek_v3", "deepseek_v32"):
+        if hf_cfg.model_type not in _V3_ROUTED:
             raise NotImplementedError(
                 "MoE conversion is implemented for deepseek_v3 and "
                 "deepseek_v32 (V2's softmax/greedy router differs)")
@@ -280,7 +284,8 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
                         index_topk=int(hf_cfg.index_topk))
     scale_mult = 1.0
     hf_rs = getattr(hf_cfg, "rope_scaling", None)
-    if (rope_scaling and hf_cfg.model_type in ("deepseek_v3", "deepseek_v32")
+    if (rope_scaling and hf_cfg.model_type in _V3_ROUTED
+            and getattr(hf_cfg, "use_mla_scaling_factor", True)
             and hf_rs and hf_rs.get("mscale_all_dim")):
         m = _yarn_get_mscale(float(hf_rs["factor"]),
                              float(hf_rs["mscale_all_dim"]))
@@ -305,8 +310,75 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
         rope_scaling=rope_scaling,
         softmax_scale_mult=scale_mult,
         embed_init_scale=float(getattr(hf_cfg, "embed_init_scale", 0.02)),
+        mlp_init_scale=float(getattr(hf_cfg, "mlp_init_scale", 0.02)),
+        router_bias_init_scale=float(getattr(
+            hf_cfg, "router_bias_init_scale", 0.02)),
         **index_kw,
         **moe_kw,
+    )
+
+
+# The model types whose routed layers are DeepSeek-V3's (sigmoid scores, a
+# correction bias, a shared expert) and whose yarn raises the softmax scale.
+_V3_ROUTED = ("deepseek_v3", "deepseek_v32", "gigachat3_5")
+
+# The one reading of each of GigaChat3.5's keys that name a form and do not
+# define it: what ``llama`` implements. Another value is another model.
+_GIGACHAT_FORMS = {
+    "norm_type": "ZeroCenteredGatedNorm",
+    "layernorm_type": "pre_post",
+    "linear_gating_type": "gated_rmsnorm_sigmoid_zero_centered",
+    "linear_attention_type": "GigaChat35GatedDeltaNet",
+}
+
+
+def _config_from_gigachat(hf_cfg: Any, page_size: int, dtype: Any,
+                          rope_scaling: tuple = ()) -> LlamaConfig:
+    """GigaChat3.5 (``model_type: gigachat3_5``): DeepSeek-V3's latent
+    attention and expert layers (``_config_from_deepseek`` reads those
+    keys, ``layer_share`` and ``embed_init_scale`` included) in the layers
+    ``full_attention_layers`` lists, Gated DeltaNet (the ``linear_*`` keys)
+    in every other, and the block's form: zero-centred norms before and
+    after every sub-layer, a sigmoid gate on attention's output, a clamped
+    SwiGLU. A key that names one of those forms is refused, by name, when
+    it names another than the one built. ``state_slots`` and
+    ``state_checkpoint_tokens`` (top-level keys no checkpoint has, as
+    ``layer_share``) size the engine's pool of sequence states and space
+    its snapshots; ``mlp_init_scale`` and ``router_bias_init_scale`` are
+    what ``init_params`` draws a feed-forward's gate and up matrices and
+    a router's correction bias at."""
+    for key, built in _GIGACHAT_FORMS.items():
+        got = getattr(hf_cfg, key, built)
+        if got != built:
+            raise NotImplementedError(
+                f"{key} {got!r}: the form built is {built!r}, and serving "
+                f"this model as that one would be silently wrong")
+    if getattr(hf_cfg, "use_shared_expert_sigmoid", False):
+        raise NotImplementedError(
+            "use_shared_expert_sigmoid: a gated shared expert is not built")
+    base = _config_from_deepseek(hf_cfg, page_size, dtype, rope_scaling)
+    full = set(hf_cfg.full_attention_layers)
+    return dataclasses.replace(
+        base,
+        linear_layers=tuple(i for i in range(base.num_layers)
+                            if i not in full),
+        linear=LinearAttention(
+            key_heads=int(hf_cfg.linear_num_key_heads),
+            value_heads=int(hf_cfg.linear_num_value_heads),
+            key_dim=int(hf_cfg.linear_key_head_dim),
+            value_dim=int(hf_cfg.linear_value_head_dim),
+            conv_kernel=int(hf_cfg.linear_conv_kernel_dim),
+            gate_scale=float(getattr(hf_cfg, "linear_sigmoid_gate_scale",
+                                     2.0)),
+            norm_eps=float(getattr(hf_cfg, "linear_attn_o_norm_eps",
+                                   hf_cfg.rms_norm_eps))),
+        state_slots=int(getattr(hf_cfg, "state_slots", 64)),
+        state_checkpoint_tokens=int(getattr(
+            hf_cfg, "state_checkpoint_tokens", 4096)),
+        norm_offset=1.0,
+        post_norms=True,
+        attn_output_gate=bool(getattr(hf_cfg, "gated_attention", False)),
+        swiglu_limit=float(getattr(hf_cfg, "swiglu_limit", 0) or 0),
     )
 
 
@@ -346,6 +418,11 @@ def params_from_hf(state_dict: Mapping[str, Any], cfg: LlamaConfig,
     this repo's half-split rotary reproduces HF's interleaved one (see
     ``_deinterleave``).
     """
+    if cfg.linear_layers:
+        raise NotImplementedError(
+            "no checkpoint's tensors are mapped for linear layers (nor for "
+            "the gates and post-norms of their model): random weights "
+            "serve (llama.init_params)")
     consumed: set = set()
 
     def get(name):

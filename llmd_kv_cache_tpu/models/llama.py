@@ -68,6 +68,34 @@ PROGRAM_DECODE = "forward_decode_pallas"
 
 
 @dataclass(frozen=True)
+class LinearAttention:
+    """The sizes of a model's Gated DeltaNet layers (``LlamaConfig.linear``):
+    ``key_heads`` query/key heads of ``key_dim``, each serving ``value_heads
+    / key_heads`` value heads of ``value_dim``; a depthwise causal conv of
+    ``conv_kernel`` taps over the q, k and v channels; the output norm's
+    gate ``gate_scale * sigmoid(z)`` and its eps. A sequence's cache in
+    such a layer is one float32 state ``[value_heads, key_dim, value_dim]``
+    and the conv's last ``conv_kernel - 1`` inputs (``ops.gated_deltanet``).
+    """
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int = 4
+    gate_scale: float = 2.0
+    norm_eps: float = 1e-6
+
+    @property
+    def conv_channels(self) -> int:
+        return 2 * self.key_heads * self.key_dim + self.inner
+
+    @property
+    def inner(self) -> int:
+        return self.value_heads * self.value_dim
+
+
+@dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -199,8 +227,65 @@ class LlamaConfig:
     # Supported for uniform-SWA models (every layer in swa_layers); the
     # hybrid two-pool reclamation would free sink blocks.
     attention_sinks: int = 0
+    # Layer kinds. ``linear_layers`` lists the layers whose mixer is a
+    # Gated DeltaNet of the sizes in ``linear`` (a ``LinearAttention``);
+    # every other layer attends (``layer_kind``). A linear layer keeps no
+    # pages: the page pools hold ``page_layers`` only, and a sequence's
+    # states live in a pool of ``state_slots`` slots beside them
+    # (``init_state_pool``), snapshotted at block boundaries so that a
+    # prefix hit finds the state its pages end on: at least every
+    # ``state_checkpoint_tokens`` tokens of a prefill (``engine.StatePool``).
+    linear_layers: tuple = ()
+    linear: Any = None  # Optional[LinearAttention]
+    state_slots: int = 0
+    state_checkpoint_tokens: int = 0
+    # The block's form (GigaChat3.5: ``norm_type`` zero-centred,
+    # ``layernorm_type`` pre_post, ``gated_attention``, ``swiglu_limit``):
+    # every norm scales by ``norm_offset + weight``; with ``post_norms`` a
+    # sub-layer's output is normed too before it joins the residual; the
+    # attention heads' outputs are gated by ``sigmoid(x W_g)`` ahead of
+    # ``wo``; a SwiGLU's gate is capped at ``swiglu_limit`` before its SiLU
+    # and its up branch clipped to +-that (0 = neither).
+    norm_offset: float = 0.0
+    post_norms: bool = False
+    attn_output_gate: bool = False
+    swiglu_limit: float = 0.0
+    # Standard deviation ``init_params`` draws a feed-forward's gate and up
+    # matrices at (random weights only): a configuration whose SwiGLU
+    # clamps says here how often its pre-activations pass the limit.
+    mlp_init_scale: float = 0.02
+    # Standard deviation ``init_params`` draws a chip's share of a router's
+    # ``e_score_correction_bias`` at (random weights only). A trained bias
+    # balances the experts' load; a random one unbalances it, so the share
+    # of tokens the experts held receive (and the grouped matmuls' time)
+    # differs from seed to seed by about six times this value over the
+    # score gaps it competes with (at 0.02: +-25% around ``held /
+    # experts``; at 0.002: the sampling noise of a chunk).
+    router_bias_init_scale: float = 0.02
 
     def __post_init__(self):
+        if self.linear_layers:
+            if self.linear is None or not self.is_mla:
+                raise ValueError(
+                    "linear_layers need their sizes (linear) and latent "
+                    "attention in the layers that attend: the one hybrid "
+                    "of states and pages that is built")
+            if not all(0 <= i < self.num_layers for i in self.linear_layers):
+                raise ValueError("linear_layers indices out of range")
+            if len(set(self.linear_layers)) == self.num_layers:
+                raise ValueError(
+                    "a model of linear layers alone has no pages for a "
+                    "snapshot to stand on")
+            if self.is_dsa or self.sliding_window is not None:
+                raise ValueError(
+                    "linear layers beside an indexer or a window are not "
+                    "built")
+            if self.linear.value_heads % self.linear.key_heads:
+                raise ValueError("value heads must divide by key heads")
+            if self.state_slots < 2:
+                raise ValueError(
+                    "a model with linear layers needs state_slots >= 2 (a "
+                    "working slot and a snapshot)")
         if self.num_experts > 0 and self.num_experts_per_token > self.num_experts:
             raise ValueError(
                 f"num_experts_per_token ({self.num_experts_per_token}) exceeds "
@@ -341,6 +426,16 @@ class LlamaConfig:
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
 
+    def layer_kind(self, layer_idx: int) -> str:
+        """``"linear"`` (a state a sequence) or ``"attention"`` (pages)."""
+        return "linear" if layer_idx in self.linear_layers else "attention"
+
+    @property
+    def page_layers(self) -> tuple:
+        """The layers that keep pages, in order: a page pool's layer axis."""
+        return tuple(i for i in range(self.num_layers)
+                     if i not in self.linear_layers)
+
     @property
     def is_dsa(self) -> bool:
         """Learned sparse attention: pages hold the indexer's key stream
@@ -455,7 +550,8 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
         _init_layer_jit(
             keys[2 + i], cfg,
             cfg.num_experts > 0 and (not cfg.moe_layers
-                                     or i in cfg.moe_layers))
+                                     or i in cfg.moe_layers),
+            cfg.layer_kind(i) == "linear")
         for i in range(cfg.num_layers)
     ]
     return {"layers": layers, **_init_top_jit(keys[0], keys[1], cfg)}
@@ -466,6 +562,15 @@ def _dense_init(k, shape, dt, scale=0.02):
             * scale).astype(dt)
 
 
+def _norm_init(k, n: int, cfg: "LlamaConfig"):
+    """A norm's weight: ones, or for a zero-centred norm (``norm_offset``)
+    a draw around zero, so that a forward which scaled by the weight alone,
+    or by one plus twice it, would not pass for this one."""
+    if not cfg.norm_offset:
+        return jnp.ones((n,), jnp.float32)
+    return _dense_init(jax.random.fold_in(k, n), (n,), jnp.float32, 0.1)
+
+
 @partial(jax.jit, static_argnames=("cfg",))
 def _init_top_jit(embed_key: jax.Array, head_key: jax.Array,
                   cfg: LlamaConfig) -> Params:
@@ -473,27 +578,57 @@ def _init_top_jit(embed_key: jax.Array, head_key: jax.Array,
     return {
         "embed": _dense_init(embed_key, (cfg.vocab_size, h), cfg.dtype,
                              cfg.embed_init_scale),
-        "final_norm": jnp.ones((h,), jnp.float32),
+        "final_norm": _norm_init(head_key, h, cfg),
         "lm_head": _dense_init(head_key, (h, cfg.vocab_size), cfg.dtype),
     }
 
 
-@partial(jax.jit, static_argnames=("cfg", "is_moe_layer"))
+@partial(jax.jit, static_argnames=("cfg", "is_moe_layer", "linear"))
 def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
-                    is_moe_layer: bool) -> Params:
+                    is_moe_layer: bool, linear: bool = False) -> Params:
     dt = cfg.dtype
     h, hd = cfg.hidden_size, cfg.head_dim
 
     def dense(k, shape):
         return _dense_init(k, shape, dt)
 
+    def ffn(k, shape):
+        return _dense_init(k, shape, dt, cfg.mlp_init_scale)
+
     lk = jax.random.split(key, 10)
     layer = {
-        "attn_norm": jnp.ones((h,), jnp.float32),
-        "wo": dense(lk[3], (cfg.num_heads * hd, h)),
-        "mlp_norm": jnp.ones((h,), jnp.float32),
+        "attn_norm": _norm_init(lk[3], h, cfg),
+        "wo": dense(lk[3], ((cfg.linear.inner if linear
+                             else cfg.num_heads * hd), h)),
+        "mlp_norm": _norm_init(lk[4], h, cfg),
     }
-    if cfg.is_mla:
+    if cfg.post_norms:
+        layer["attn_post_norm"] = _norm_init(lk[5], h, cfg)
+        layer["mlp_post_norm"] = _norm_init(lk[6], h, cfg)
+    if cfg.attn_output_gate and not linear:
+        layer["w_og"] = dense(jax.random.fold_in(lk[3], 1),
+                              (h, cfg.num_heads * hd))
+    if linear:
+        la = cfg.linear
+        ck = jax.random.split(lk[0], 6)
+        # The decay's parameters as the family initialises them: A in
+        # [1, 16), a step dt log-uniform in [1e-3, 1e-1] through its
+        # inverse softplus. With the token's own term (x W_a, about +-1.7)
+        # the heads' memories then run from a few tokens to a thousand.
+        step = jnp.exp(jax.random.uniform(
+            ck[4], (la.value_heads,), jnp.float32, math.log(1e-3),
+            math.log(1e-1)))
+        layer.update({
+            "w_qkvz": dense(ck[0], (h, la.conv_channels + la.inner)),
+            "w_ba": dense(ck[1], (h, 2 * la.value_heads)),
+            "conv_w": _dense_init(ck[2], (la.conv_kernel, la.conv_channels),
+                                  jnp.float32, 0.5),
+            "A_log": jnp.log(jax.random.uniform(
+                ck[3], (la.value_heads,), jnp.float32, 1.0, 16.0)),
+            "dt_bias": jnp.log(jnp.expm1(step)),
+            "o_norm": _norm_init(ck[5], la.value_dim, cfg),
+        })
+    elif cfg.is_mla:
         r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         qr = cfg.q_lora_rank
         layer.update({
@@ -510,8 +645,8 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
             ik = jax.random.split(lk[0], 5)
             layer.update({
                 "w_dq": dense(ik[1], (h, qr)),
-                "q_latent_norm": jnp.ones((qr,), jnp.float32),
-                "latent_norm": jnp.ones((r,), jnp.float32),
+                "q_latent_norm": _norm_init(ik[1], qr, cfg),
+                "latent_norm": _norm_init(ik[1], r, cfg),
             })
         if cfg.is_dsa:
             # The indexer: per-head queries from the q latent, one key
@@ -540,8 +675,8 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
         inter = cfg.moe_intermediate_size or cfg.intermediate_size
         layer.update({
             "router": dense(lk[7], (h, e)),
-            "w_gate": dense(lk[4], (held, h, inter)),
-            "w_up": dense(lk[5], (held, h, inter)),
+            "w_gate": ffn(lk[4], (held, h, inter)),
+            "w_up": ffn(lk[5], (held, h, inter)),
             "w_down": dense(lk[6], (held, inter, h)),
         })
         if cfg.moe_router and cfg.moe_router[0] == "deepseek_v3":
@@ -554,17 +689,18 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
                 # share draws it small and not zero, so that a forward
                 # that left it out would send other tokens to the experts
                 # held.
-                "router_bias": (_dense_init(skeys[0], (e,), jnp.float32)
+                "router_bias": (_dense_init(skeys[0], (e,), jnp.float32,
+                                            cfg.router_bias_init_scale)
                                 if cfg.experts_held
                                 else jnp.zeros((e,), jnp.float32)),
-                "w_gate_sh": dense(skeys[1], (h, sh)),
-                "w_up_sh": dense(skeys[2], (h, sh)),
+                "w_gate_sh": ffn(skeys[1], (h, sh)),
+                "w_up_sh": ffn(skeys[2], (h, sh)),
                 "w_down_sh": dense(skeys[3], (sh, h)),
             })
     else:
         layer.update({
-            "w_gate": dense(lk[4], (h, cfg.intermediate_size)),
-            "w_up": dense(lk[5], (h, cfg.intermediate_size)),
+            "w_gate": ffn(lk[4], (h, cfg.intermediate_size)),
+            "w_up": ffn(lk[5], (h, cfg.intermediate_size)),
             "w_down": dense(lk[6], (cfg.intermediate_size, h)),
         })
     return layer
@@ -819,14 +955,29 @@ def init_kv_cache(cfg: LlamaConfig, num_pages: int,
     backends upcast on read.
     """
     dtype = cfg.dtype if dtype is None else dtype
-    shape = (cfg.num_layers, num_pages, cfg.kv_cache_heads, cfg.page_size,
-             cfg.kv_cache_head_dim)
+    shape = (len(cfg.page_layers), num_pages, cfg.kv_cache_heads,
+             cfg.page_size, cfg.kv_cache_head_dim)
     v_width = cfg.kv_cache_head_dim
     if cfg.is_mla:
         # The second stack is the indexer's key stream where the model has
         # one: under the same page ids, so a page is both or neither.
         v_width = cfg.index_head_dim if cfg.is_dsa else 0
     return jnp.zeros(shape, dtype), jnp.zeros(shape[:-1] + (v_width,), dtype)
+
+
+def init_state_pool(cfg: LlamaConfig) -> tuple[jax.Array, jax.Array]:
+    """The pool of sequence states of a model with linear layers, beside
+    its page pools: ``(recurrent [linear layers, slots + 1, value heads,
+    key_dim, value_dim] float32, conv [linear layers, slots + 1, conv_kernel
+    - 1, conv channels]`` in the model's type``)``. A slot holds one
+    sequence's state in every linear layer: a running row's, or a snapshot
+    at a block boundary. Slot 0 is the spare one (as page 0 is): rows that
+    decode nothing and snapshots nobody asked for are written there."""
+    la, n = cfg.linear, len(cfg.linear_layers)
+    return (jnp.zeros((n, cfg.state_slots + 1, la.value_heads, la.key_dim,
+                       la.value_dim), jnp.float32),
+            jnp.zeros((n, cfg.state_slots + 1, la.conv_kernel - 1,
+                       la.conv_channels), cfg.dtype))
 
 
 def init_kv_cache_hybrid(
@@ -853,10 +1004,22 @@ def init_kv_cache_hybrid(
     )
 
 
-def _rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+def _rms_norm(x: jax.Array, weight: jax.Array, eps: float,
+              offset: float = 0.0) -> jax.Array:
+    """``offset``: a zero-centred norm scales by ``offset + weight``."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    if offset:
+        weight = weight + offset
     return (xf * jax.lax.rsqrt(var + eps) * weight).astype(x.dtype)
+
+
+def _swiglu(gate: jax.Array, up: jax.Array, limit: float) -> jax.Array:
+    """``silu(gate) * up`` in float32; with a ``limit`` the gate is capped
+    at it before its SiLU and the up branch clipped to +-it."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
 
 
 def _moe_router(mlp_in: jax.Array, layer: dict, cfg: "LlamaConfig",
@@ -983,7 +1146,7 @@ def _deepseek_route(x, layer, cfg):
     return idx, w * factor
 
 
-def _experts_dense(x, layer, idx, w, first):
+def _experts_dense(x, layer, idx, w, first, limit=0.0):
     """Every expert held over every token, mixed by the router's weights:
     exact, O(experts held) work and a ``[T, E, I]`` intermediate. The form
     the grouped dispatch is tested against."""
@@ -991,12 +1154,12 @@ def _experts_dense(x, layer, idx, w, first):
     mix_w = jnp.einsum(
         "tk,tke->te", w,
         jax.nn.one_hot(idx - first, held, dtype=jnp.float32))
-    gate = jax.nn.silu(jnp.einsum(
-        "th,ehi->tei", x, layer["w_gate"]).astype(jnp.float32))
+    gate = jnp.einsum(
+        "th,ehi->tei", x, layer["w_gate"]).astype(jnp.float32)
     up = jnp.einsum("th,ehi->tei", x, layer["w_up"]).astype(jnp.float32)
     expert_out = jnp.einsum(
-        "tei,eih->teh", (gate * up).astype(x.dtype), layer["w_down"]
-    ).astype(jnp.float32)
+        "tei,eih->teh", _swiglu(gate, up, limit).astype(x.dtype),
+        layer["w_down"]).astype(jnp.float32)
     return jnp.einsum("te,teh->th", mix_w, expert_out)
 
 
@@ -1028,7 +1191,8 @@ def _grouped_matmul(lhs, rhs, group_sizes, kernel):
                tiling=tiling, interpret=kernel["interpret"])
 
 
-def _experts_grouped(x, layer, idx, w, first, valid, kernel, counters):
+def _experts_grouped(x, layer, idx, w, first, valid, kernel, counters,
+                     limit=0.0):
     """The routed experts' part of a layer, exactly, with work that grows
     with the assignments held: the ``T x k`` assignments are sorted by
     expert (a counting sort: those that fall to an expert not held, or
@@ -1056,10 +1220,10 @@ def _experts_grouped(x, layer, idx, w, first, valid, kernel, counters):
         for name, n in (("assignments_held", jnp.sum(sizes)),
                         ("experts_touched", jnp.sum(sizes > 0))):
             counters[name] = counters.get(name, 0) + n
-    gate = jax.nn.silu(_grouped_matmul(rows, layer["w_gate"], sizes, kernel))
+    gate = _grouped_matmul(rows, layer["w_gate"], sizes, kernel)
     up = _grouped_matmul(rows, layer["w_up"], sizes, kernel)
-    out = _grouped_matmul((gate * up).astype(x.dtype), layer["w_down"],
-                          sizes, kernel)                          # [M, h] f32
+    out = _grouped_matmul(_swiglu(gate, up, limit).astype(x.dtype),
+                          layer["w_down"], sizes, kernel)         # [M, h] f32
     # Rows past the held assignments hold nothing that was computed.
     out = jnp.where((jnp.arange(n_rows) < jnp.sum(sizes))[:, None], out, 0.0)
     mine_w = jnp.where(mine.reshape(t, k), w, 0.0)
@@ -1082,21 +1246,21 @@ def _moe_deepseek(mlp_in, layer, cfg, valid=None, kernel=None,
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         if cfg.moe_dispatch == "grouped":
             out = _experts_grouped(x, layer, idx, w, first, valid, kernel,
-                                   counters)
+                                   counters, cfg.swiglu_limit)
         else:
-            out = _experts_dense(x, layer, idx, w, first)
+            out = _experts_dense(x, layer, idx, w, first, cfg.swiglu_limit)
     out = out.astype(mlp_in.dtype)
 
     if "w_gate_up_sh" in layer:  # fused serving layout (fuse_params)
         sh_gu = (x @ layer["w_gate_up_sh"]).astype(jnp.float32)
         sh_i = sh_gu.shape[-1] // 2
-        sh_gate_out, sh_up = split_fused_out(sh_gu, (sh_i, sh_i),
-                                             cfg.fused_interleave)
-        sh_gate = jax.nn.silu(sh_gate_out)
+        sh_gate, sh_up = split_fused_out(sh_gu, (sh_i, sh_i),
+                                         cfg.fused_interleave)
     else:
-        sh_gate = jax.nn.silu((x @ layer["w_gate_sh"]).astype(jnp.float32))
+        sh_gate = (x @ layer["w_gate_sh"]).astype(jnp.float32)
         sh_up = (x @ layer["w_up_sh"]).astype(jnp.float32)
-    shared = (sh_gate * sh_up).astype(x.dtype) @ layer["w_down_sh"]
+    shared = _swiglu(sh_gate, sh_up, cfg.swiglu_limit).astype(
+        x.dtype) @ layer["w_down_sh"]
     return (out + shared).reshape(b, s, h)
 
 
@@ -1128,13 +1292,13 @@ def _mlp(mlp_in: jax.Array, layer: dict, cfg: "LlamaConfig",
     if "w_gate_up" in layer:  # fused serving layout (fuse_params)
         gu = (mlp_in @ layer["w_gate_up"]).astype(jnp.float32)
         inter = gu.shape[-1] // 2
-        gate_out, up = split_fused_out(gu, (inter, inter),
-                                       cfg.fused_interleave)
-        gate = jax.nn.silu(gate_out)
+        gate, up = split_fused_out(gu, (inter, inter),
+                                   cfg.fused_interleave)
     else:
-        gate = jax.nn.silu((mlp_in @ layer["w_gate"]).astype(jnp.float32))
+        gate = (mlp_in @ layer["w_gate"]).astype(jnp.float32)
         up = (mlp_in @ layer["w_up"]).astype(jnp.float32)
-    return (gate * up).astype(mlp_in.dtype) @ layer["w_down"]
+    return _swiglu(gate, up, cfg.swiglu_limit).astype(
+        mlp_in.dtype) @ layer["w_down"]
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float,
@@ -1207,10 +1371,118 @@ def _rope_leading(x: jax.Array, dr: int, positions: jax.Array,
          x[..., dr:]], axis=-1)
 
 
+def _sublayer_out(out, gate_in, layer, cfg, which: str) -> jax.Array:
+    """What a sub-layer (``which``: ``attn`` or ``mlp``) adds to the
+    residual. A mixer's output ``out`` goes through its output projection,
+    gated first by ``sigmoid(gate_in W_g)`` where the layer has that gate
+    (attention's: the heads' outputs, from the layer's normed input); with
+    ``cfg.post_norms`` the result is normed."""
+    if which == "attn":
+        if "w_og" in layer:
+            gate = jax.nn.sigmoid((gate_in @ layer["w_og"]).astype(
+                jnp.float32))
+            out = (out * gate).astype(out.dtype)
+        out = out @ layer["wo"]
+    if cfg.post_norms:
+        out = _rms_norm(out, layer[which + "_post_norm"], cfg.norm_eps,
+                        cfg.norm_offset)
+    return out
+
+
+def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
+                    kernel):
+    """A Gated DeltaNet mixer over ``x [b, s, h]`` (the layer's normed
+    input): ``(heads' outputs [b, s, value heads x value_dim] before the
+    output projection, the state as handed in with this layer's part
+    updated)``. ``lj`` is the layer's index in the state pool; ``state``,
+    ``kernel`` as ``_forward_impl_grouped`` takes them.
+
+    ``[q, k, v, z] = x W_qkvz`` and ``[b, a] = x W_ba``; a depthwise causal
+    conv over q, k, v (its first taps read the row's conv state: the last
+    inputs of what came before) and SiLU; q, k of unit length per head, q
+    times ``key_dim^-1/2``; ``beta = sigmoid(b)``, ``log alpha =
+    -exp(A_log) softplus(a + dt_bias)``; the recurrence (``ops.
+    gated_deltanet``: a chunk is scanned in blocks of a page, a decode
+    step updates every row's state in place); the heads' outputs normed
+    per head and gated by ``gate_scale * sigmoid(z)``."""
+    from ..ops.gated_deltanet import (
+        KERNEL_SCAN, KERNEL_STEP, gdn_scan, gdn_step)
+
+    la = cfg.linear
+    f32 = jnp.float32
+    b, s, _ = x.shape
+    recurrent, conv, slots, snap = state
+    taps, chans = la.conv_kernel, la.conv_channels
+    nk = la.key_heads * la.key_dim
+    use = dict(kernel=kernel is not None,
+               interpret=bool(kernel and kernel["interpret"]))
+
+    qkvz = x @ layer["w_qkvz"]
+    ba = (x @ layer["w_ba"]).astype(f32)
+    mixed, z = qkvz[..., :chans], qkvz[..., chans:]
+    fresh = ctx_lens == 0                                          # [b]
+    tail = jnp.where(fresh[:, None, None], 0, conv[lj, slots])
+    window = jnp.concatenate([tail.astype(mixed.dtype), mixed], axis=1)
+
+    def tail_at(n):
+        """The conv state after a row's first ``n [b]`` tokens."""
+        at = n[:, None] + jnp.arange(taps - 1)[None, :]
+        return jnp.take_along_axis(window, at[:, :, None], axis=1)
+
+    mixed = jax.nn.silu(sum(
+        window[:, j:j + s].astype(f32) * layer["conv_w"][j]
+        for j in range(taps))).astype(x.dtype)
+    new_tail = tail_at(new_lens).astype(conv.dtype)
+    conv = conv.at[lj, slots].set(new_tail)
+
+    def unit(t, heads):
+        t = t.reshape(b, s, heads, -1).astype(f32)
+        return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+    q = unit(mixed[..., :nk], la.key_heads) * la.key_dim ** -0.5
+    k = unit(mixed[..., nk:2 * nk], la.key_heads)
+    v = mixed[..., 2 * nk:].reshape(b, s, la.value_heads, la.value_dim)
+    live = valid[..., None]
+    beta = jnp.where(live, jax.nn.sigmoid(ba[..., :la.value_heads]), 0.0)
+    g = jnp.where(live, -jnp.exp(layer["A_log"]) * jax.nn.softplus(
+        ba[..., la.value_heads:] + layer["dt_bias"]), 0.0)
+
+    if s == 1:
+        with jax.named_scope(KERNEL_STEP):
+            o, recurrent = gdn_step(recurrent, lj, slots, q[:, 0], k[:, 0],
+                                    v[:, 0], g[:, 0], beta[:, 0], **use)
+        o = o[:, None]
+    else:
+        if snap is not None and b != 1:
+            raise ValueError("a chunk that leaves snapshots is one row")
+        outs = []
+        with jax.named_scope(KERNEL_SCAN):
+            for i in range(b):
+                first = jnp.where(fresh[i], 0.0, recurrent[lj, slots[i]])
+                o_i, end, inner = gdn_scan(
+                    q[i], k[i], v[i], g[i], beta[i], first,
+                    -1 if snap is None else snap[0], block=cfg.page_size,
+                    **use)
+                recurrent = recurrent.at[lj, slots[i]].set(end)
+                outs.append(o_i)
+            if snap is not None:
+                recurrent = recurrent.at[lj, snap[1]].set(inner)
+                recurrent = recurrent.at[lj, snap[2]].set(end)
+                conv = conv.at[lj, snap[1]].set(tail_at(
+                    (snap[0:1] + 1) * cfg.page_size)[0].astype(conv.dtype))
+                conv = conv.at[lj, snap[2]].set(new_tail[0])
+        o = jnp.stack(outs)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + la.norm_eps)
+    o = o * (cfg.norm_offset + layer["o_norm"]) * (
+        la.gate_scale * jax.nn.sigmoid(z.reshape(o.shape).astype(f32)))
+    return (o.astype(x.dtype).reshape(b, s, la.inner),
+            (recurrent, conv, slots, snap))
+
+
 def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                           ctx_lens, new_lens, attention_fn, last_only=False,
                           tails=None, ragged=None, kernel=None,
-                          counters=None):
+                          counters=None, state=None):
     """Shared transformer body over grouped KV pools.
 
     ``k_caches[g]`` holds group g's layers stacked in ``cfg.group_layers(g)``
@@ -1254,8 +1526,22 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     which the backend scores the row's pages of ``v_stack`` and selects
     (``ops.sparse_index``). ``kernel`` and ``counters`` go to the routed
     layers (``_experts_grouped``).
+
+    A model with linear layers (``cfg.linear_layers``) is handed ``state =
+    (recurrent, conv, slots, snap)``: its state pool (``init_state_pool``),
+    each row's slot in it, and for a prefill chunk ``snap = [block, slot,
+    end_slot]``: the state after the chunk's block ``block`` is also written
+    to ``slot`` and the state at the chunk's end to ``end_slot`` (the spare
+    slot 0 where nothing is wanted). A row at position 0 starts from no
+    state whatever its slot holds. The pools come back as a fourth result.
+    ``kernel`` also picks the recurrence's Pallas kernels.
     """
     batch, seq = tokens.shape
+    if cfg.linear_layers and (state is None or tails is not None
+                              or ragged is not None):
+        raise NotImplementedError(
+            "linear layers are served by the padded step programs, handed "
+            "their state pool: no fused decode bursts, no ragged batches")
     if cfg.is_dsa and (tails is not None or ragged is not None):
         raise NotImplementedError(
             "learned sparse attention is served by the padded step "
@@ -1311,11 +1597,13 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
             return dict(tail_k=tk_l, tail_v=tv_l, tail_lens=tail_lens,
                         ctx_base=ctx_base)
 
-    # Static layer→(group, local index) map, resolved at trace time.
-    local_idx = {}
-    for g in range(len(k_caches)):
-        for j, li in enumerate(cfg.group_layers(g)):
-            local_idx[li] = (g, j)
+    # Static layer→(group, local index) map, resolved at trace time. One
+    # pool holds the layers that keep pages, in order.
+    local_idx = {li: (0, j) for j, li in enumerate(cfg.page_layers)}
+    if len(k_caches) > 1:
+        for g in range(len(k_caches)):
+            for j, li in enumerate(cfg.group_layers(g)):
+                local_idx[li] = (g, j)
 
     if tails is None:
         # Where the step's tokens land in each group's pool: the same for
@@ -1342,11 +1630,28 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     k_caches = list(k_caches)
     v_caches = list(v_caches)
     for li, layer in enumerate(params["layers"]):
-        g, lj = local_idx[li] if len(k_caches) > 1 else (0, li)
+        if cfg.layer_kind(li) == "linear":
+            with jax.named_scope(SCOPE_QKV):
+                attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps,
+                                    cfg.norm_offset)
+            with jax.named_scope(SCOPE_ATTENTION):
+                attn, state = _gated_deltanet(
+                    attn_in, layer, cfg, cfg.linear_layers.index(li), state,
+                    valid, ctx_lens, new_lens, kernel)
+                x = x + _sublayer_out(attn, attn_in, layer, cfg, "attn")
+            with jax.named_scope(SCOPE_MLP):
+                mlp_in = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps,
+                                   cfg.norm_offset)
+                x = x + _sublayer_out(
+                    _mlp(mlp_in, layer, cfg, valid=valid, kernel=kernel,
+                         counters=counters), None, layer, cfg, "mlp")
+            continue
+        g, lj = local_idx[li]
         table = tables[g]
         if cfg.is_mla:
             with jax.named_scope(SCOPE_QKV):
-                attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+                attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps,
+                                    cfg.norm_offset)
                 # Absorbed MLA (DeepSeek-V2 §2.1.2, TPU-first formulation):
                 # cache ONLY the latent [c_kv ; rope-key] per token and fold
                 # the per-head up-projections into the query and output — the
@@ -1364,7 +1669,7 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                         # q-LoRA: the fused block holds w_dq's output; the
                         # norm between down- and up-projection stays.
                         q_in = _rms_norm(head_in, layer["q_latent_norm"],
-                                         cfg.norm_eps)
+                                         cfg.norm_eps, cfg.norm_offset)
                         q = q_in @ layer["wq"]
                     else:
                         q = head_in
@@ -1374,7 +1679,8 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                         # latent, RMS-normed, then up-projected per head — the
                         # norm between the two matmuls prevents precomposition.
                         q_in = _rms_norm(attn_in @ layer["w_dq"],
-                                         layer["q_latent_norm"], cfg.norm_eps)
+                                         layer["q_latent_norm"], cfg.norm_eps,
+                                         cfg.norm_offset)
                     else:
                         q_in = attn_in
                     q = q_in @ layer["wq"]
@@ -1388,7 +1694,8 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                     # DeepSeek kv_a_layernorm: the latent is RMS-normed before
                     # the up-projections — cached post-norm, so absorption is
                     # unchanged (w_uk applies to the normed latent).
-                    c_kv = _rms_norm(c_kv, layer["latent_norm"], cfg.norm_eps)
+                    c_kv = _rms_norm(c_kv, layer["latent_norm"], cfg.norm_eps,
+                                     cfg.norm_offset)
                 k_rope = _rope(k_rope_in[:, :, None, :],
                                positions, cfg.rope_theta,
                                cfg.rope_scaling)  # [b, s, 1, dr]
@@ -1462,7 +1769,8 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                                   layer["w_uv"])
         else:
             with jax.named_scope(SCOPE_QKV):
-                attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+                attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps,
+                                    cfg.norm_offset)
                 if "w_qkv" in layer:  # fused serving layout (fuse_params)
                     qkv = attn_in @ layer["w_qkv"]
                     if "b_qkv" in layer:
@@ -1484,8 +1792,10 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                 k = k.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
                 v = v.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
                 if cfg.qk_norm:  # Qwen3: per-head RMS over head_dim, pre-RoPE
-                    q = _rms_norm(q, layer["q_norm"], cfg.norm_eps)
-                    k = _rms_norm(k, layer["k_norm"], cfg.norm_eps)
+                    q = _rms_norm(q, layer["q_norm"], cfg.norm_eps,
+                                  cfg.norm_offset)
+                    k = _rms_norm(k, layer["k_norm"], cfg.norm_eps,
+                                  cfg.norm_offset)
                 q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
                 k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
@@ -1504,15 +1814,18 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                     total_lens, cfg.layer_window(li), **extra,
                 )
         with jax.named_scope(SCOPE_ATTENTION):
-            x = x + attn.reshape(batch, seq, -1) @ layer["wo"]
+            x = x + _sublayer_out(attn.reshape(batch, seq, -1), attn_in,
+                                  layer, cfg, "attn")
 
         with jax.named_scope(SCOPE_MLP):
-            mlp_in = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp(mlp_in, layer, cfg, valid=valid, kernel=kernel,
-                         counters=counters)
+            mlp_in = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps,
+                               cfg.norm_offset)
+            x = x + _sublayer_out(
+                _mlp(mlp_in, layer, cfg, valid=valid, kernel=kernel,
+                     counters=counters), None, layer, cfg, "mlp")
 
     with jax.named_scope(SCOPE_LM_HEAD):
-        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_offset)
         if last_only:
             if ragged is not None:
                 # One logit row per ragged row: its final flat token
@@ -1526,6 +1839,8 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
         logits = (x @ params["lm_head"]).astype(jnp.float32)
     if tails is not None:
         return logits, tuple(tail_ks), tuple(tail_vs)
+    if state is not None:
+        return logits, tuple(k_caches), tuple(v_caches), state[:2]
     return logits, tuple(k_caches), tuple(v_caches)
 
 
@@ -1563,13 +1878,13 @@ def _xla_attention(cfg):
 
 def _forward_impl(params, cfg, tokens, k_cache, v_cache, page_table,
                   ctx_lens, new_lens, attention_fn, last_only=False,
-                  kernel=None, counters=None):
-    logits, ks, vs = _forward_impl_grouped(
+                  kernel=None, counters=None, state=None):
+    logits, ks, vs, *rest = _forward_impl_grouped(
         params, cfg, tokens, (k_cache,), (v_cache,), (page_table,),
         ctx_lens, new_lens, attention_fn, last_only=last_only,
-        kernel=kernel, counters=counters,
+        kernel=kernel, counters=counters, state=state,
     )
-    return logits, ks[0], vs[0]
+    return (logits, ks[0], vs[0], *rest)
 
 
 @partial(jax.jit, static_argnames=("cfg", "last_only"),
@@ -1585,6 +1900,7 @@ def forward(
     new_lens: jax.Array,  # [batch] valid new tokens in `tokens`
     last_only: bool = False,
     counters: dict | None = None,
+    state: tuple | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One model step (prefill or decode), XLA attention backend.
 
@@ -1594,11 +1910,14 @@ def forward(
     page. ``last_only=True`` → logits is [b, 1, vocab], the final valid
     position of each row (prefill chunks; see ``_forward_impl_grouped``).
     ``counters``: a dict the step form hands in, filled while tracing with
-    ``cfg.step_counters`` (``step_program``).
+    ``cfg.step_counters`` (``step_program``). ``state``: a model with
+    linear layers' state pool, slots and snapshot request
+    (``_forward_impl_grouped``); the pool comes back as a fourth result.
     """
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
         _xla_attention(cfg), last_only=last_only, counters=counters,
+        state=state,
     )
 
 
@@ -1645,6 +1964,7 @@ def forward_decode_pallas(
     mesh=None,
     batch_rows: int = 1,
     counters: dict | None = None,
+    state: tuple | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Decode step (seq == 1) using the Pallas flash-decode kernel.
 
@@ -1726,6 +2046,7 @@ def forward_decode_pallas(
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
         pallas_attention, kernel={"interpret": interpret}, counters=counters,
+        state=state,
     )
 
 
@@ -1966,6 +2287,7 @@ def forward_prefill_pallas(
     mesh=None,
     last_only: bool = False,
     counters: dict | None = None,
+    state: tuple | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Prefill using the Pallas flash-prefill kernel.
 
@@ -2037,7 +2359,7 @@ def forward_prefill_pallas(
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
         attention_fn, last_only=last_only, kernel={"interpret": interpret},
-        counters=counters,
+        counters=counters, state=state,
     )
 
 
@@ -2189,7 +2511,44 @@ def _last_ragged_row(_table, row_starts, _ctx_lens):
     return jnp.sum(row_starts[1:] > row_starts[:-1]) - 1
 
 
+def with_state(body):
+    """``body`` (a forward above) as a model with linear layers is stepped:
+    its state pool behind the page pools (donated with them), each row's
+    slot and the chunk's snapshot request (``[block, slot, end_slot]``;
+    zeros on a decode step) behind its per-step arrays. Under the body's
+    own name: a trace tells programs by it."""
+    def stateful(params, cfg, tokens, k_cache, v_cache, recurrent, conv,
+                 page_table, ctx_lens, new_lens, slots, snap, counters=None,
+                 **kw):
+        logits, k_cache, v_cache, (recurrent, conv) = body(
+            params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens,
+            new_lens, counters=counters,
+            state=(recurrent, conv, slots,
+                   snap if tokens.shape[1] > 1 else None), **kw)
+        return logits, k_cache, v_cache, recurrent, conv
+
+    stateful.__name__ = stateful.__qualname__ = body.__name__
+    return stateful
+
+
+@partial(jax.jit, donate_argnames=("state",))
+def copy_state_slot(state: tuple, src_dst: jax.Array) -> tuple:
+    """Slot ``src_dst[0]`` of every pool of ``state`` (``init_state_pool``)
+    copied over slot ``src_dst[1]``, in place: a snapshot into the working
+    slot of the row that was admitted on it."""
+    return tuple(pool.at[:, src_dst[1]].set(pool[:, src_dst[0]])
+                 for pool in state)
+
+
 step_forward = step_program(forward.__wrapped__, ("last_only",))
+step_forward_state = step_program(
+    with_state(forward.__wrapped__), ("last_only",))
+step_decode_pallas_state = step_program(
+    with_state(forward_decode_pallas.__wrapped__),
+    ("interpret", "mesh", "batch_rows"))
+step_prefill_pallas_state = step_program(
+    with_state(forward_prefill_pallas.__wrapped__),
+    ("interpret", "mesh", "last_only"))
 step_forward_hybrid = step_program(
     forward_hybrid.__wrapped__, ("last_only",))
 step_decode_pallas = step_program(

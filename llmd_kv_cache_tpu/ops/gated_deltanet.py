@@ -1,0 +1,321 @@
+"""Gated DeltaNet: the recurrence of a linear-attention layer whose cache is
+one state a sequence, not a page a block of tokens.
+
+Per value head, with ``k`` of unit length, ``beta`` in (0, 1) and ``alpha =
+exp(g)`` in (0, 1]::
+
+    S_t = alpha_t S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
+    o_t = S_t q_t
+
+The state is kept transposed, ``St = S^T [dk, dv]`` in float32, so that a
+token's read is a row times a matrix and its write an outer product of a
+column and a row. Two forms, each in XLA (what the CPU serves and the
+kernels are tested against) and as a Pallas kernel (what a TPU serves):
+
+- ``gdn_scan``: a chunk of tokens of one sequence, in blocks of ``block``
+  tokens (the page). Inside a block the ``block`` rank-one updates are
+  folded into matrix products (the WY form of the delta rule: ``U = (I +
+  A)^-1 beta V`` with ``A`` strictly lower-triangular, inverted by products
+  of powers, 16 tokens at a time: ``_unit_lower_inverse``); the state
+  passes from block to block. It returns the state at the chunk's end and
+  at the end of one requested block: a snapshot at a block boundary costs
+  no second pass and splits no chunk.
+- ``gdn_step``: one token of every row of a decode batch, the rows' states
+  updated in place in the (donated) pool under their slot ids.
+
+The jitted wrappers' names are what a device trace calls the kernels
+(``gdn_scan.<n>``, ``gdn_step.<n>``); readers of traces match them.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+KERNEL_SCAN = "gdn_scan"
+KERNEL_STEP = "gdn_step"
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):  # a @ b.T
+    return _dot(a, b, (((1,), (1,)), ((), ())))
+
+
+def _dot_tn(a, b):  # a.T @ b
+    return _dot(a, b, (((0,), (0,)), ((), ())))
+
+
+def _column(eye, row):
+    """``row [1, n]`` as a column ``[n, 1]``: a lane-to-sublane move made
+    of a multiply and a lane reduction, which every backend lowers."""
+    return jnp.sum(eye * row, axis=1, keepdims=True)
+
+
+# Tokens whose triangular system is inverted by a product of powers.
+_SUB = 16
+
+
+def _neumann(n, eye, steps: int):
+    """``(I - n)^-1 = (I + n)(I + n^2)(I + n^4)...`` for ``n`` with ``n^(2
+    ** steps) = 0``."""
+    t, p = eye + n, n
+    for _ in range(steps - 1):
+        p = _dot(p, p)
+        t = t + _dot(t, p)
+    return t
+
+
+def _unit_lower_inverse(n, eye, row, col):
+    """``(I - n)^-1`` for a strictly lower-triangular ``n [c, c]``, by
+    matrix products alone. The product of powers is exact (``n`` is
+    nilpotent) and cheap, and over a whole block of 64 it is useless: keys
+    that lie close to each other (a conv and a SiLU leave them all in one
+    orthant) make ``n``'s entries a half or more, its 32nd power 1e8, and
+    the inverse, whose entries are of order one, the difference of such
+    terms. So the diagonal sub-blocks of ``_SUB`` tokens are inverted that
+    way (powers up to the 8th: some thousands at worst), ``D = (I -
+    n_d)^-1``, and the rest, ``n_o``, which lies below them, is folded in
+    as ``(I - n)^-1 = (I - D n_o)^-1 D``: ``D n_o`` is nilpotent in as many
+    steps as there are sub-blocks (4 of 16 in a block of 64). Whole-matrix
+    operations under masks: no slice that a kernel would have to cut."""
+    c = n.shape[0]
+    sub = min(_SUB, c)
+    if c % sub:
+        raise ValueError(f"a block of {c} tokens is not sub-blocks of {sub}")
+    within = (row // sub) == (col // sub)
+    d = _neumann(jnp.where(within, n, 0.0), eye, (sub - 1).bit_length())
+    if c == sub:
+        return d
+    p = _dot(d, jnp.where(within, 0.0, n))
+    return _dot(_neumann(p, eye, (c // sub - 1).bit_length()), d)
+
+
+def _block_update(q, k, v, gc, beta, st):
+    """One block of one head. ``q, k [c, dk]``, ``v [c, dv]`` float32;
+    ``gc [1, c]`` the log-decay summed from the block's first token to each
+    token (inclusive), ``beta [1, c]``; ``st [dk, dv]`` the state before the
+    block. Returns ``(o [c, dv], st')``. A padded token has ``g = 0`` and
+    ``beta = 0``: it leaves the state as it was."""
+    c = q.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = (row == col).astype(jnp.float32)
+    gcol, bcol = _column(eye, gc), _column(eye, beta)
+    g_last = jnp.sum(jnp.where(col[:1] == c - 1, gc, 0.0), axis=1,
+                     keepdims=True)                               # [1, 1]
+    # exp(g_i - g_j) for j <= i, else 0 (never exp of a positive number).
+    decay = jnp.exp(jnp.where(row >= col, gcol - gc, -jnp.inf))
+    n = jnp.where(row > col, -(bcol * _dot_nt(k, k)) * decay, 0.0)
+    t = _unit_lower_inverse(n, eye, row, col)
+    u = _dot(t, v * bcol)                                         # [c, dv]
+    w = _dot(t, k * (bcol * jnp.exp(gcol)))                       # [c, dk]
+    v_new = u - _dot(w, st)
+    inside = jnp.where(row >= col, _dot_nt(q, k) * decay, 0.0)
+    o = _dot(q * jnp.exp(gcol), st) + _dot(inside, v_new)
+    st = st * jnp.exp(g_last) + _dot_tn(k * jnp.exp(g_last - gcol), v_new)
+    return o, st
+
+
+def _blocks(q, k, v, g, beta, block):
+    """Head-major blocks and the in-block running log-decay:
+    ``q, k [Hk, T, dk] -> same``, ``gb [Hv, T / block, 2, block]``."""
+    t, hv = g.shape
+    nb = t // block
+    gc = jnp.cumsum(g.astype(jnp.float32).reshape(nb, block, hv), axis=1)
+    gb = jnp.stack([gc, beta.astype(jnp.float32).reshape(nb, block, hv)],
+                   axis=2)                                # [nb, block, 2, hv]
+    return (q.transpose(1, 0, 2), k.transpose(1, 0, 2), v.transpose(1, 0, 2),
+            gb.transpose(3, 0, 2, 1))
+
+
+def _scan_xla(qh, kh, vh, gb, st0, snap_block):
+    hv, nb = gb.shape[:2]
+    rep = hv // qh.shape[0]
+    block = gb.shape[-1]
+
+    def split(x):  # [H, T, d] -> [nb, H, block, d]
+        return x.reshape(x.shape[0], nb, block, -1).transpose(1, 0, 2, 3)
+
+    per_head = jax.vmap(_block_update)
+
+    def body(carry, xs):
+        st, snap = carry
+        i, qb, kb, vb, gbb = xs
+        f32 = jnp.float32
+        o, st = per_head(jnp.repeat(qb, rep, 0).astype(f32),
+                         jnp.repeat(kb, rep, 0).astype(f32), vb.astype(f32),
+                         gbb[:, 0:1], gbb[:, 1:2], st)
+        return (st, jnp.where(i == snap_block, st, snap)), o
+
+    (st, snap), o = jax.lax.scan(
+        body, (st0, st0),
+        (jnp.arange(nb), split(qh), split(kh), split(vh),
+         gb.transpose(1, 0, 2, 3)))
+    return o.transpose(1, 0, 2, 3).reshape(hv, nb * block, -1), st, snap
+
+
+def _scan_kernel(snap_ref, q_ref, k_ref, v_ref, gb_ref, st0_ref,
+                 o_ref, end_ref, snap_out_ref, st_scr, *, nb):
+    b = pl.program_id(1)
+
+    @pl.when(b == 0)
+    def _():
+        st_scr[...] = st0_ref[0]
+        snap_out_ref[0] = st0_ref[0]
+
+    f32 = jnp.float32
+    o, st = _block_update(q_ref[0].astype(f32), k_ref[0].astype(f32),
+                          v_ref[0].astype(f32), gb_ref[0, 0, 0:1, :],
+                          gb_ref[0, 0, 1:2, :], st_scr[...])
+    o_ref[0] = o.astype(o_ref.dtype)
+    st_scr[...] = st
+
+    @pl.when(b == snap_ref[0])
+    def _():
+        snap_out_ref[0] = st
+
+    @pl.when(b == nb - 1)
+    def _():
+        end_ref[0] = st
+
+
+def _scan_pallas(qh, kh, vh, gb, st0, snap_block, interpret):
+    hk, t, dk = qh.shape
+    hv, nb, _, block = gb.shape
+    dv = vh.shape[-1]
+    rep = hv // hk
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(hv, nb),
+        in_specs=[
+            pl.BlockSpec((1, block, dk), lambda h, b, *_: (h // rep, b, 0)),
+            pl.BlockSpec((1, block, dk), lambda h, b, *_: (h // rep, b, 0)),
+            pl.BlockSpec((1, block, dv), lambda h, b, *_: (h, b, 0)),
+            pl.BlockSpec((1, 1, 2, block), lambda h, b, *_: (h, b, 0, 0)),
+            pl.BlockSpec((1, dk, dv), lambda h, b, *_: (h, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block, dv), lambda h, b, *_: (h, b, 0)),
+            pl.BlockSpec((1, dk, dv), lambda h, b, *_: (h, 0, 0)),
+            pl.BlockSpec((1, dk, dv), lambda h, b, *_: (h, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_scan_kernel, nb=nb),
+        out_shape=[jax.ShapeDtypeStruct((hv, t, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((hv, dk, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((hv, dk, dv), jnp.float32)],
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(snap_block, (1,)).astype(jnp.int32), qh, kh, vh, gb, st0)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "kernel", "interpret"))
+def gdn_scan(q, k, v, g, beta, state, snap_block, block: int,
+             kernel: bool = False, interpret: bool = False):
+    """A chunk of one sequence. ``q, k [T, Hk, dk]`` (unit-length keys,
+    scaled queries), ``v [T, Hv, dv]``, ``g, beta [T, Hv]`` (both 0 at a
+    padded token), ``state [Hv, dk, dv]`` float32 before the chunk; ``T`` a
+    whole number of blocks. Returns ``(o [T, Hv, dv] float32, the state
+    after the chunk, the state after block snap_block)``; the last is the
+    state before the chunk where ``snap_block`` names no block."""
+    if q.shape[0] % block:
+        raise ValueError(f"a chunk of {q.shape[0]} tokens is not a whole "
+                         f"number of blocks of {block}")
+    qh, kh, vh, gb = _blocks(q, k, v, g, beta, block)
+    if kernel:
+        o, st, snap = _scan_pallas(qh, kh, vh, gb, state, snap_block,
+                                   interpret)
+    else:
+        o, st, snap = _scan_xla(qh, kh, vh, gb, state, snap_block)
+    return o.transpose(1, 0, 2), st, snap
+
+
+def _step_kernel(slot_ref, layer_ref, q_ref, k_ref, v_ref, a_ref, b_ref,
+                 pool_ref, o_ref, out_ref, *, heads):
+    del slot_ref, layer_ref  # the index maps read them
+    dk = q_ref.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
+    eye = (row == col).astype(jnp.float32)
+    for h in range(heads):
+        at = slice(h, h + 1)
+        st = pool_ref[0, 0, h] * a_ref[0, at, :]                 # decay
+        kcol = _column(eye, k_ref[0, at, :])
+        sk = jnp.sum(kcol * st, axis=0, keepdims=True)           # [1, dv]
+        st = st + kcol * (b_ref[0, at, :] * (v_ref[0, at, :] - sk))
+        out_ref[0, 0, h] = st
+        o_ref[0, at, :] = jnp.sum(_column(eye, q_ref[0, at, :]) * st,
+                                  axis=0, keepdims=True)
+
+
+def _step_pallas(pool, layer, slots, q, k, v, alpha, beta, interpret):
+    rows, hv, dk = q.shape
+    dv = v.shape[-1]
+    heads = 8 if hv % 8 == 0 else hv
+
+    def vec(width):
+        return pl.BlockSpec((1, heads, width), lambda r, j, *_: (r, j, 0))
+
+    def state(r, j, slot_ref, layer_ref):
+        return (layer_ref[0], slot_ref[r], j, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(rows, hv // heads),
+        in_specs=[vec(dk), vec(dk), vec(dv), vec(dv), vec(dv),
+                  pl.BlockSpec((1, 1, heads, dk, dv), state)],
+        out_specs=[vec(dv), pl.BlockSpec((1, 1, heads, dk, dv), state)],
+    )
+    o, pool = pl.pallas_call(
+        functools.partial(_step_kernel, heads=heads),
+        out_shape=[jax.ShapeDtypeStruct((rows, hv, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        grid_spec=grid_spec,
+        # Operand 7 (behind the two scalars) is the pool: updated in place.
+        input_output_aliases={7: 1},
+        interpret=interpret,
+    )(slots.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q, k, v, jnp.broadcast_to(alpha[..., None], v.shape),
+      jnp.broadcast_to(beta[..., None], v.shape), pool)
+    return o, pool
+
+
+def _step_xla(pool, layer, slots, q, k, v, alpha, beta):
+    st = pool[layer, slots] * alpha[..., None, None]    # [rows, Hv, dk, dv]
+    sk = jnp.einsum("rhk,rhkv->rhv", k, st, precision=_HIGHEST)
+    st = st + k[..., :, None] * (beta[..., None] * (v - sk))[..., None, :]
+    o = jnp.einsum("rhk,rhkv->rhv", q, st, precision=_HIGHEST)
+    return o, pool.at[layer, slots].set(st)
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "interpret"),
+                   donate_argnames=("pool",))
+def gdn_step(pool, layer, slots, q, k, v, g, beta, kernel: bool = False,
+             interpret: bool = False):
+    """One token of every row. ``pool [layers, slots, Hv, dk, dv]``
+    float32 (donated; row ``r``'s state is ``pool[layer, slots[r]]``, and
+    rows that decode nothing share the spare slot 0), ``q, k [rows, Hk,
+    dk]``, ``v [rows, Hv, dv]``, ``g, beta [rows, Hv]``. Returns ``(o
+    [rows, Hv, dv] float32, pool)``."""
+    f32 = jnp.float32
+    rep = v.shape[1] // q.shape[1]
+    q, k = (jnp.repeat(x.astype(f32), rep, axis=1) for x in (q, k))
+    args = (pool, layer, slots, q, k, v.astype(f32),
+            jnp.exp(g.astype(f32)), beta.astype(f32))
+    if kernel:
+        return _step_pallas(*args, interpret)
+    return _step_xla(*args)

@@ -233,6 +233,9 @@ class Indexer:
             self.config.scorer_config,
             block_size_tokens=self.token_processor.block_size,
         )
+        # The scorer finds what the entries' groups mean where the event
+        # pool that fills this index leaves it (``Index.group_catalog``).
+        self.scorer.index = self.kv_block_index
         self._tracer = tracer()
         # Score-path latency histogram, exemplar-linked to the request's
         # trace so a slow bucket on /metrics points at a retained trace in
@@ -448,14 +451,17 @@ class Indexer:
             if dl is not None:
                 dl.check("scoring.index_lookup")
 
-            if self._native_score_chunked is not None:
+            # The fused native paths count every entry as pages; where a
+            # pod keeps sequence states the Python scorer reads the groups.
+            native = not self.scorer.state_groups()
+            if native and self._native_score_chunked is not None:
                 return self._score_native_chunked(
                     keys_arr if keys_arr is not None else block_keys,
                     block_keys, model_name, pod_identifiers, role, detail,
                     span,
                 )
 
-            if self._native_score is not None:
+            if native and self._native_score is not None:
                 scores, hit_count = self._native_score(
                     keys_arr if keys_arr is not None else block_keys,
                     self.scorer.medium_weights, pod_identifiers,

@@ -85,6 +85,10 @@ class LongestPrefixScorer:
         # the host (Indexer.attach_liveness): demotes pods whose event
         # stream — and therefore whose index view — has gone stale.
         self.liveness = None
+        # The index the scored entries come from (``Indexer`` sets it): its
+        # ``group_catalog`` says which pods keep sequence states beside
+        # their pages, and in which group (``state_groups``).
+        self.index = None
 
     @property
     def strategy(self) -> str:
@@ -104,6 +108,60 @@ class LongestPrefixScorer:
                 out[pod] = s * f
         return out
 
+    def state_groups(self) -> dict[str, int]:
+        """pod -> the cache group whose blocks are snapshots of a sequence
+        state (kind ``mamba``), for the pods that have one: empty for a
+        fleet of pages alone, and then nothing below changes."""
+        catalog = getattr(self.index, "group_catalog", None)
+        return catalog.state_groups if catalog is not None else {}
+
+    def _score_with_states(
+        self,
+        keys: Sequence[BlockHash],
+        key_to_pods: dict[BlockHash, list[PodEntry]],
+        state_groups: dict[str, int],
+    ) -> dict[str, float]:
+        """The longest-prefix rule where some pods keep a sequence state
+        beside their pages: such a pod can resume only where a snapshot
+        stands, so its score is its pages' weights up to the deepest block
+        that has a snapshot and all of whose predecessors have pages;
+        pages beyond it would be computed again. An entry without a group
+        (a router's speculative one, a tier update) speaks for both kinds.
+        The other pods score as ``score`` scores them."""
+        sums: dict[str, float] = {}
+        usable: dict[str, float] = {}
+        active: set = set()
+        for i, key in enumerate(keys):
+            pages: dict[str, float] = {}
+            states = set()
+            for e in key_to_pods.get(key, []):
+                pod = e.pod_identifier
+                group = state_groups.get(pod)
+                if group is not None:
+                    if e.has_group and e.group_idx == group:
+                        states.add(pod)
+                        continue  # a snapshot is no page
+                    if not e.has_group:
+                        states.add(pod)
+                w = self.medium_weights.get(e.device_tier, 1.0)
+                if w > pages.get(pod, -1.0):
+                    pages[pod] = w
+            if i == 0:
+                sums, active = dict(pages), set(pages)
+            else:
+                for pod in list(active):
+                    w = pages.get(pod)
+                    if w is None:
+                        active.discard(pod)
+                    else:
+                        sums[pod] += w
+            for pod in active & states:
+                usable[pod] = sums[pod]
+            if not active:
+                break
+        return {pod: (usable.get(pod, 0.0) if pod in state_groups else s)
+                for pod, s in sums.items()}
+
     def _fill_max_weights(
         self, entries: Sequence[PodEntry]
     ) -> dict[str, float]:
@@ -122,6 +180,10 @@ class LongestPrefixScorer:
     ) -> dict[str, float]:
         if not keys:
             return {}
+        state_groups = self.state_groups()
+        if state_groups:
+            return self._apply_liveness(
+                self._score_with_states(keys, key_to_pods, state_groups))
 
         cur_weights = self._fill_max_weights(key_to_pods.get(keys[0], []))
         pod_scores = dict(cur_weights)

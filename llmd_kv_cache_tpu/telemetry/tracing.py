@@ -712,12 +712,14 @@ PHASE_STEP_FETCH = "step.fetch"            # the blocking np.asarray of the prog
 PHASE_STEP_COMMIT = "step.commit"          # _commit_full_blocks → commit_blocks, write-through
 PHASE_STEP_EMIT = "step.emit"              # event batch → sink → Pool/index (nests in commit)
 PHASE_STEP_FINISH = "step.finish"          # release of finished requests; carries the step's counters
+PHASE_STEP_SNAPSHOT = "step.snapshot"      # a prefill chunk's snapshots planned: slots reserved in the state pool, what they evicted
 
 PHASE_NAMES = (
     PHASE_ENQUEUE_ADMIT, PHASE_ENQUEUE_HASH, PHASE_ENQUEUE_LOOKUP,
     PHASE_STEP_OFFLOAD_POLL, PHASE_STEP_SCHEDULE, PHASE_STEP_INPUTS,
     PHASE_STEP_DISPATCH, PHASE_STEP_SAMPLE, PHASE_STEP_FETCH,
     PHASE_STEP_COMMIT, PHASE_STEP_EMIT, PHASE_STEP_FINISH,
+    PHASE_STEP_SNAPSHOT,
 )
 
 SPAN_ENGINE_ADMISSION = "llm_d.kv_cache.engine.admission"

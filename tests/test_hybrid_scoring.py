@@ -205,3 +205,115 @@ class TestHybridEndToEnd:
                             break
                     assert s._window_value(blocks, n, wb) == pytest.approx(brute), (
                         n, wb, mask)
+
+
+# -- pods that keep a sequence state beside their pages -----------------------
+
+
+def page(name):
+    return PodEntry(name, "tpu-hbm", has_group=True, group_idx=0)
+
+
+def state(name):
+    return PodEntry(name, "tpu-hbm", has_group=True, group_idx=1)
+
+
+def stateful_scorer(*pods):
+    """The DEFAULT scorer, finding the catalog where an event pool leaves
+    it: on the index it reads (no ``attach_group_catalog``)."""
+    from types import SimpleNamespace
+
+    from llmd_kv_cache_tpu.scoring.scorer import LongestPrefixScorer
+
+    catalog = GroupCatalog()
+    for pod in pods:
+        catalog.learn(pod, 0, GroupMetadata("mla_attention", BLOCK))
+        catalog.learn(pod, 1, GroupMetadata("mamba", BLOCK))
+    scorer = LongestPrefixScorer({"tpu-hbm": 1.0, "cpu": 0.8})
+    scorer.index = SimpleNamespace(group_catalog=catalog)
+    return scorer
+
+
+class TestStateGroups:
+    KEYS = [1, 2, 3, 4, 5, 6]
+
+    def test_the_pod_with_the_snapshot_beats_the_pod_with_more_pages(self):
+        """``lost`` holds all six blocks' pages and no state any more:
+        sent there, the turn would be computed from its first token.
+        ``kept`` holds four and a snapshot on the fourth."""
+        s = stateful_scorer("kept", "lost")
+        key_to_pods = {k: [page("lost")] for k in self.KEYS}
+        for k in self.KEYS[:4]:
+            key_to_pods[k].append(page("kept"))
+        key_to_pods[4].append(state("kept"))
+        assert s.score(self.KEYS, key_to_pods) == {"kept": 4.0, "lost": 0.0}
+
+    def test_pages_beyond_the_deepest_snapshot_do_not_count(self):
+        s = stateful_scorer("p")
+        key_to_pods = {k: [page("p")] for k in self.KEYS}
+        key_to_pods[2].append(state("p"))
+        key_to_pods[5].append(state("p"))
+        assert s.score(self.KEYS, key_to_pods) == {"p": 5.0}
+
+    def test_a_snapshot_behind_a_missing_page_is_out_of_reach(self):
+        s = stateful_scorer("p")
+        key_to_pods = {k: [page("p")] for k in (1, 2, 4, 5)}
+        key_to_pods[2].append(state("p"))
+        key_to_pods[5] = [page("p"), state("p")]
+        assert s.score(self.KEYS, key_to_pods) == {"p": 2.0}
+
+    def test_an_entry_without_a_group_speaks_for_pages_and_state(self):
+        """A router's speculative entry: the pod was just sent this."""
+        s = stateful_scorer("p")
+        spec = PodEntry("p", "tpu-hbm", speculative=True)
+        assert s.score(self.KEYS, {k: [spec] for k in self.KEYS[:3]}) == {
+            "p": 3.0}
+
+    def test_pods_without_a_state_group_score_as_before(self):
+        s = stateful_scorer("stateful")
+        key_to_pods = {k: [page("plain"), page("stateful")]
+                       for k in self.KEYS[:3]}
+        assert s.score(self.KEYS, key_to_pods) == {"plain": 3.0,
+                                                   "stateful": 0.0}
+        plain = stateful_scorer()             # nobody keeps states
+        assert plain.state_groups() == {}
+        assert plain.score(self.KEYS, key_to_pods) == {"plain": 3.0,
+                                                       "stateful": 3.0}
+
+    def test_as_the_harness_wires_it_the_router_follows_the_snapshot(self):
+        """``Indexer``, ``Pool`` and ``KVAwareRouter`` built with defaults
+        and never handed a catalog (``kvbench/harness/fleet.py``): pages as
+        group 0 of kind ``mla_attention``, snapshots as group 1 of kind
+        ``mamba``."""
+        from llmd_kv_cache_tpu.events.model import BlockRemovedEvent
+        from llmd_kv_cache_tpu.scoring.router import KVAwareRouter
+
+        indexer = Indexer(IndexerConfig(
+            token_processor_config=TokenProcessorConfig(
+                block_size_tokens=BLOCK)))
+        pool = Pool(PoolConfig(concurrency=1), indexer.kv_block_index,
+                    indexer.token_processor)
+        tokens = list(range(1, 1 + 6 * BLOCK))
+        keys = indexer.compute_block_keys(tokens, "m")
+
+        def stored(pod, first, count, group, kind):
+            pool.process_event_batch(EventBatch(0.0, [BlockStoredEvent(
+                block_hashes=keys[first:first + count],
+                tokens=tokens[first * BLOCK:(first + count) * BLOCK],
+                parent_hash=keys[first - 1] if first else 0,
+                block_size=BLOCK, group_idx=group,
+                kv_cache_spec_kind=kind)]), pod, "m")
+
+        stored("pod-0", 0, 6, 0, "mla_attention")
+        stored("pod-1", 0, 4, 0, "mla_attention")
+        stored("pod-0", 5, 1, 1, "mamba")
+        stored("pod-1", 3, 1, 1, "mamba")
+        router = KVAwareRouter(indexer, ["pod-0", "pod-1"])
+        assert indexer.score_tokens(tokens, "m") == {"pod-0": 6.0,
+                                                     "pod-1": 4.0}
+        # pod-0 loses its state and keeps every page.
+        pool.process_event_batch(EventBatch(0.0, [BlockRemovedEvent(
+            block_hashes=[keys[5]], group_idx=1)]), "pod-0", "m")
+        assert indexer.score_tokens(tokens, "m") == {"pod-0": 0.0,
+                                                     "pod-1": 4.0}
+        assert router.route(tokens, "m") == "pod-1"

@@ -318,10 +318,17 @@ class TestDecodeKernelLowersForTheChip:
         params = init_params(jax.random.PRNGKey(0), cfg)
         k_cache, v_cache = init_kv_cache(cfg, engine["num_pages"])
         rows, width = engine["max_batch"], engine["max_pages_per_seq"]
+        state = {}
+        if cfg.linear_layers:  # its state pool and the rows' slots in it
+            from llmd_kv_cache_tpu.models.llama import init_state_pool
+
+            state = {"state": (*init_state_pool(cfg),
+                               jnp.zeros((rows,), jnp.int32), None)}
         text = forward_decode_pallas.trace(
             params, cfg, jnp.zeros((rows, 1), jnp.int32), k_cache, v_cache,
             jnp.zeros((rows, width), jnp.int32),
             jnp.ones((rows,), jnp.int32), jnp.ones((rows,), jnp.int32),
+            **state,
         ).lower(lowering_platforms=("tpu",)).as_text()
         assert "tpu_custom_call" in text
 

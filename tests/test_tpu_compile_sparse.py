@@ -1,5 +1,6 @@
-"""The kernels that serve ``deepseek-v3.2-exp-ep16-l5``, compiled for a TPU
-v5e at the configuration's real widths, with no chip: the TPU's compiler is
+"""The kernels that serve ``deepseek-v3.2-exp-ep16-l5`` and the recurrence
+kernels of ``gigachat3.5-ep16-l5``, compiled for a TPU v5e at the
+configurations' real widths, with no chip: the TPU's compiler is
 installed here and compiles for a chip that is described and not attached.
 It refuses what the interpreter lets through (a slice off the tiling, more
 fast memory than a kernel may use). Nothing runs: a compile that passes is
@@ -102,3 +103,41 @@ def test_grouped_matmul(shaped, m, k, n):
     compiles(lambda x, w, sizes: llama._grouped_matmul(
         x, w, sizes, {"interpret": False}),
         shaped((m, k)), shaped((16, k, n)), shaped((16,), jnp.int32))
+
+
+# gigachat3.5-ep16-l5: 32 key heads serving 64 value heads of 128 x 128, 4
+# linear layers, 64 state slots and the spare one, blocks of a page.
+GDN_LAYERS, GDN_SLOTS, GDN_KEY_HEADS, GDN_VALUE_HEADS, GDN_DIM = (
+    4, 65, 32, 64, 128)
+
+
+def test_delta_rule_scan(shaped):
+    """A chunk of 512 tokens in blocks of 64: the state carried in fast
+    memory from block to block, the in-block inverse as matrix products."""
+    from llmd_kv_cache_tpu.ops.gated_deltanet import gdn_scan
+
+    f32 = jnp.float32
+    compiles(functools.partial(gdn_scan, block=PAGE, kernel=True),
+             shaped((CHUNK, GDN_KEY_HEADS, GDN_DIM), f32),
+             shaped((CHUNK, GDN_KEY_HEADS, GDN_DIM), f32),
+             shaped((CHUNK, GDN_VALUE_HEADS, GDN_DIM)),
+             shaped((CHUNK, GDN_VALUE_HEADS), f32),
+             shaped((CHUNK, GDN_VALUE_HEADS), f32),
+             shaped((GDN_VALUE_HEADS, GDN_DIM, GDN_DIM), f32),
+             shaped((), jnp.int32))
+
+
+def test_recurrence_step(shaped):
+    """8 rows' states updated in place in the pool, under their slots."""
+    from llmd_kv_cache_tpu.ops.gated_deltanet import gdn_step
+
+    f32 = jnp.float32
+    compiles(functools.partial(gdn_step, kernel=True),
+             shaped((GDN_LAYERS, GDN_SLOTS, GDN_VALUE_HEADS, GDN_DIM,
+                     GDN_DIM), f32),
+             shaped((), jnp.int32), shaped((ROWS,), jnp.int32),
+             shaped((ROWS, GDN_KEY_HEADS, GDN_DIM), f32),
+             shaped((ROWS, GDN_KEY_HEADS, GDN_DIM), f32),
+             shaped((ROWS, GDN_VALUE_HEADS, GDN_DIM)),
+             shaped((ROWS, GDN_VALUE_HEADS), f32),
+             shaped((ROWS, GDN_VALUE_HEADS), f32))
