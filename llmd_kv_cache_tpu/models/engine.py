@@ -42,6 +42,7 @@ from ..events.model import (
     BlockStoredEvent,
     GenericEvent,
 )
+from ..ops import sparse_index
 from ..ops.pallas_paged_attention import (
     head_dim_supported as _pallas_head_dim_supported,
 )
@@ -2354,6 +2355,15 @@ class MiniEngine:
                 token.copy_to_host_async()
             if self.state_pool is not None:
                 sp.set_attribute("scan_tokens", len(chunk))
+            topk = self.cfg.model.index_topk
+            n_keys = self.cfg.max_pages_per_seq * page_size
+            if (topk and n_keys > topk and sp is not NOOP_SPAN
+                    and self._attention_backends["prefill"]["backend"]
+                    == "pallas"):
+                # What the chunk's selection counts a layer to find its
+                # queries' thresholds (``kth_largest``: seq * n_keys).
+                sp.set_attribute("threshold_keys", sparse_index.threshold_keys(
+                    pos, len(chunk), seq, n_keys, topk))
         for boundary, slot in taken:
             blocks = boundary // page_size
             self.state_pool.store(

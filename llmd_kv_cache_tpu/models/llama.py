@@ -2337,10 +2337,9 @@ def forward_prefill_pallas(
                             v_stack, layer_idx, table),
                         total_lens, interpret=interpret)
                 with jax.named_scope(SCOPE_SELECT):
-                    bias = jnp.where(
-                        sparse_index.keep_mask(scores, positions, total_lens,
-                                               cfg.index_topk),
-                        0.0, sparse_index.DROPPED)
+                    bias = sparse_index.dsa_keep_bias(
+                        scores, positions, total_lens,
+                        topk=cfg.index_topk, interpret=interpret)
             v_stack = k_stack
         if mesh is not None:
             return sharded_paged_prefill_attention(

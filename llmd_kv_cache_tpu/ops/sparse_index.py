@@ -21,7 +21,10 @@ XLA and the Pallas step programs:
   topk-th largest``. Keys tied with the topk-th are all kept (with real
   scores a tie is a key whose 64 heads all score below zero, twice).
   A prefill chunk masks dense latent attention with it, as the model's own
-  prefill does.
+  prefill does. ``dsa_keep_bias`` is the same selection as one kernel, for
+  the Pallas prefill program: a tile of queries reads its live scores once
+  and bisects on the copy in VMEM, where ``kth_largest`` passes 32 times
+  over the whole padded width in HBM.
 - ``select_topk``: the positions themselves, exact (``jax.lax.top_k``;
   ``approx_max_k`` would be another model), for a decode row, which then
   gathers the selected latents and attends them alone.
@@ -43,6 +46,7 @@ DROPPED = -1e30
 # The scoring kernel's name as a device trace has it (its jitted wrapper's
 # ``__name__``, as ``ops.pallas_paged_attention`` names its kernels).
 KERNEL_INDEX = "dsa_index_scores"
+KERNEL_KEEP = "dsa_keep_bias"
 
 
 def gather_index_keys(idx_stack: jax.Array, layer_idx, page_table: jax.Array
@@ -175,6 +179,152 @@ def keep_mask(scores: jax.Array, q_positions: jax.Array,
     if scores.shape[-1] <= topk:
         return cand
     return cand & (_ordered_bits(scores) >= kth_largest(scores, topk))
+
+
+# Float bits as int32 in the floats' order: ``_ordered_bits`` with the top
+# bit flipped, so that signed compares order them. -inf's, which a key that
+# is no candidate of its query counts as (``keep_mask`` masks to -inf):
+_MASKED = (0xFF800000 ^ 0x7FFFFFFF) - 2 ** 32
+
+
+def _keep_tiles(q_seq: int, n_keys: int) -> tuple[int, int]:
+    """``dsa_keep_bias``'s tile: (queries a program, keys a block)."""
+    return _tile(q_seq, 16), _tile(n_keys, 1024)
+
+
+def _keep_kernel(qpos_ref, lens_ref, s_hbm, o_ref, landed, buf, sem, *, tq,
+                 tk, topk, q_seq):
+    # qpos_ref [rows * q_seq], lens_ref [rows] (SMEM); s_hbm the scores
+    # [rows, q_seq, keys], left in HBM; o_ref [1, tq, keys]; landed and buf
+    # [keys // tk, tq, tk], the tile's live blocks in order: as float32
+    # they arrive, as ordered int32 they are counted.
+    b, qt = pl.program_id(0), pl.program_id(1)
+    total = lens_ref[b]
+    first = b * q_seq + qt * tq
+    row = jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    last, q_pos = qpos_ref[first], jnp.zeros((tq, 1), jnp.int32)
+    for i in range(tq):
+        at = qpos_ref[first + i]
+        last = jnp.maximum(last, at)
+        q_pos = jnp.where(row == i, at, q_pos)
+    # The tile's candidates lie in [0, reach): whole blocks up to there are
+    # copied in and counted. No query of a tile that reaches ``topk`` keys
+    # at most drops one: nothing is copied or counted for it, its threshold
+    # stays below every key and what ``buf`` holds does not matter.
+    reach = jnp.minimum(last, total - 1) + 1
+    live = (reach + tk - 1) // tk
+    counted = jnp.where(reach > topk, live, 0)
+    lane = min(tk, 128)
+
+    def copy(j):
+        return pltpu.make_async_copy(
+            s_hbm.at[b, pl.ds(qt * tq, tq),
+                     pl.ds(pl.multiple_of(j * tk, tk), tk)],
+            landed.at[j], sem.at[0])
+
+    def candidates(j):
+        k_pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+        return (k_pos <= q_pos) & (k_pos < total)
+
+    @pl.loop(0, counted)
+    def _(j):
+        copy(j).start()
+
+    @pl.loop(0, counted)
+    def _(j):
+        copy(j).wait()
+        bits = jax.lax.bitcast_convert_type(landed[j], jnp.int32)
+        ordered = bits ^ ((bits >> 31) & 0x7FFFFFFF)
+        buf[j] = jnp.where(candidates(j), ordered, _MASKED)
+
+    def step(i, thr):
+        # ``kth_largest``'s step on signed keys: or-ing a bit into the
+        # unsigned threshold is flipping it here (the top one from set).
+        trial = thr ^ (jnp.int32(1) << (31 - i))
+        wide = jnp.broadcast_to(trial, (tq, lane))
+
+        def count(j, acc):
+            block = buf[j]
+            for c in range(tk // lane):
+                acc = acc + (block[:, c * lane:(c + 1) * lane] >= wide
+                             ).astype(jnp.int32)
+            return acc
+
+        acc = jax.lax.fori_loop(0, counted, count,
+                                jnp.zeros((tq, lane), jnp.int32))
+        enough = jnp.sum(acc, axis=1, keepdims=True) >= topk
+        return jnp.where(enough, trial, thr)
+
+    thr = jax.lax.fori_loop(0, jnp.where(counted > 0, 32, 0), step,
+                            jnp.full((tq, 1), jnp.iinfo(jnp.int32).min,
+                                     jnp.int32))
+
+    @pl.loop(0, live)
+    def _(j):
+        keep = candidates(j) & (buf[j] >= thr)
+        o_ref[0, :, pl.ds(pl.multiple_of(j * tk, tk), tk)] = jnp.where(
+            keep, 0.0, DROPPED)
+
+    @pl.loop(live, o_ref.shape[2] // tk)
+    def _(j):
+        o_ref[0, :, pl.ds(pl.multiple_of(j * tk, tk), tk)] = jnp.full(
+            (tq, tk), DROPPED, jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "interpret"))
+def dsa_keep_bias(scores: jax.Array, q_positions: jax.Array,
+                  total_lens: jax.Array, *, topk: int,
+                  interpret: bool = False) -> jax.Array:
+    """``jnp.where(keep_mask(...), 0.0, DROPPED)`` as one kernel: the
+    float32 bias ``[batch, q_seq, keys]`` that the prefill attention kernel
+    reads, the same selection bit for bit (ties with the ``topk``-th kept,
+    a query of at most ``topk`` candidates keeps them all).
+
+    Grid (row, query tile). A program copies its tile's scores from HBM
+    once, in blocks of keys and only as far as the tile's last candidate
+    (``min(its highest q_position, total_len - 1)``), masks and orders them
+    as ``keep_mask`` and ``_ordered_bits`` do, and runs ``kth_largest``'s 32
+    steps on the copy in VMEM: counts add up lane-wise and are reduced
+    across lanes once a step. A tile whose reach is at most ``topk`` keys
+    copies and counts nothing. Then one pass writes the bias: ``DROPPED``
+    past the tile's reach too, where the attention kernel never looks."""
+    batch, q_seq, n_keys = scores.shape
+    tq, tk = _keep_tiles(q_seq, n_keys)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch, q_seq // tq),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, tq, n_keys),
+                               lambda b, qt, *_p: (b, qt, 0)),
+        scratch_shapes=[pltpu.VMEM((n_keys // tk, tq, tk), jnp.float32),
+                        pltpu.VMEM((n_keys // tk, tq, tk), jnp.int32),
+                        pltpu.SemaphoreType.DMA((1,))],
+    )
+    return pl.pallas_call(
+        functools.partial(_keep_kernel, tq=tq, tk=tk, topk=topk,
+                          q_seq=q_seq),
+        out_shape=jax.ShapeDtypeStruct((batch, q_seq, n_keys), jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret,
+    )(q_positions.astype(jnp.int32).reshape(-1),
+      total_lens.astype(jnp.int32), scores.astype(jnp.float32))
+
+
+def threshold_keys(ctx_len: int, new_len: int, q_seq: int, n_keys: int,
+                   topk: int) -> int:
+    """The scores ``dsa_keep_bias`` counts a layer for one row's chunk of
+    ``q_seq`` queries (``new_len`` of them real) behind ``ctx_len`` cached
+    tokens: over its query tiles, queries x the keys of the blocks copied
+    in. ``kth_largest`` counts ``q_seq * n_keys``."""
+    tq, tk = _keep_tiles(q_seq, n_keys)
+    counted = 0
+    for start in range(0, q_seq, tq):
+        reach = min(ctx_len + start + tq - 1, ctx_len + new_len - 1) + 1
+        if reach > topk:
+            counted += tq * -(-reach // tk) * tk
+    return counted
 
 
 def select_topk(scores: jax.Array, total_lens: jax.Array, topk: int

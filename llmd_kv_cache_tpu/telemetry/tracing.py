@@ -700,6 +700,11 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
 # its tokens; commit and emit when a prefill finished or blocks were
 # evicted). Every step program samples as its own tail, so no path opens
 # ``step.sample``; the name stays for the readers that list the phases.
+# ``step.dispatch`` carries its program's ``rows``, ``tokens`` and ``padded``
+# and, where the model has them, what the program reads a layer: a decode
+# step that selects ``index_keys`` and ``selected_keys``, a prefill chunk
+# that selects ``threshold_keys`` (``ops.sparse_index``); with linear layers
+# a decode step ``state_rows``, a prefill chunk ``scan_tokens``.
 PHASE_ENQUEUE_ADMIT = "enqueue.admit"      # all of admission (nests the two below)
 PHASE_ENQUEUE_HASH = "enqueue.hash"        # tokens → block hashes
 PHASE_ENQUEUE_LOOKUP = "enqueue.lookup"    # prefix probe, page allocation, eviction

@@ -272,6 +272,71 @@ class TestSelection:
             want = np.sort(x, axis=1)[:, -k][:, None]
             np.testing.assert_array_equal(keep, x >= want)
 
+    def test_the_kernels_names_are_their_wrappers(self):
+        """A device trace names a kernel's ops after its jitted wrapper."""
+        assert (sparse_index.dsa_index_scores.__name__
+                == sparse_index.KERNEL_INDEX == "dsa_index_scores")
+        assert (sparse_index.dsa_keep_bias.__name__
+                == sparse_index.KERNEL_KEEP == "dsa_keep_bias")
+
+    # (scores' shape, ctx_lens, new_lens, topk, what the scores hold). 32
+    # queries a chunk in tiles of 16; keys in blocks of 256, 128 or 64.
+    KEEP_CASES = {
+        "rows-shorter-than-topk": ((1, 32, 256), [10], [32], 64, "normal"),
+        "a-row-of-topk-keys": ((1, 32, 256), [32], [32], 64, "normal"),
+        "rows-longer-than-topk": ((1, 32, 256), [150], [32], 64, "normal"),
+        "a-chunk-crosses-topk": ((1, 32, 256), [50], [32], 64, "normal"),
+        "a-tile-crosses-topk": ((1, 32, 256), [56], [32], 64, "normal"),
+        "two-unlike-rows": ((2, 32, 256), [200, 8], [32, 20], 64, "normal"),
+        "ties-at-the-topk-th": ((2, 32, 256), [200, 70], [32, 32], 64,
+                                "ties"),
+        "ties-everywhere": ((1, 32, 256), [120], [32], 7, "zeros"),
+        "minus-infinity": ((2, 32, 256), [200, 90], [32, 32], 64, "-inf"),
+        "all-negative": ((2, 32, 256), [200, 60], [32, 17], 64, "negative"),
+        "live-keys-end-mid-block": ((1, 32, 384), [140], [27], 64, "normal"),
+        "a-padded-chunk": ((1, 32, 256), [100], [3], 64, "normal"),
+        "an-empty-row": ((2, 32, 256), [0, 100], [0, 32], 64, "normal"),
+        "more-keys-than-a-block": ((1, 32, 2048 + 1024), [2500], [32], 64,
+                                   "normal"),
+        "topk-is-one": ((1, 32, 192), [100], [32], 1, "ties"),
+    }
+
+    @pytest.mark.parametrize("case", KEEP_CASES)
+    def test_the_threshold_kernel_keeps_what_keep_mask_keeps(self, case):
+        """``dsa_keep_bias`` (interpreted) against ``keep_mask`` +
+        ``jnp.where``: the bias equal element for element."""
+        shape, ctx, new, topk, holds = self.KEEP_CASES[case]
+        rng = np.random.default_rng(len(case))
+        x = rng.standard_normal(shape).astype(np.float32)
+        if holds == "ties":
+            x[:, :, ::3] = 0.0
+            x[:, 5] = np.round(x[:, 5])
+        elif holds == "zeros":
+            x[:] = 0.0
+        elif holds == "-inf":
+            x[:, :, :150:2] = -np.inf
+            x[0, 3, :] = -np.inf
+            x[0, 4, 7:] = -np.inf
+        elif holds == "negative":
+            x = -np.abs(x) - 1e-3
+            x[:, ::2] *= 1e-30
+        ctx, new = np.asarray(ctx), np.asarray(new)
+        q_positions = jnp.asarray(ctx[:, None] + np.arange(shape[1])[None])
+        total_lens = jnp.asarray(ctx + new)
+        want = jnp.where(
+            sparse_index.keep_mask(jnp.asarray(x), q_positions, total_lens,
+                                   topk), 0.0, sparse_index.DROPPED)
+        got = sparse_index.dsa_keep_bias(
+            jnp.asarray(x), q_positions, total_lens, topk=topk,
+            interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # The case is what its name says: a candidate is dropped where a
+        # row is longer than topk (and not all ties), none where none is.
+        candidates = np.asarray(sparse_index.keep_mask(
+            jnp.asarray(x), q_positions, total_lens, shape[2]))
+        assert (candidates & (np.asarray(want) != 0.0)).any() == (
+            int((ctx + new).max()) > topk and holds != "zeros")
+
     def test_select_topk_is_exact_and_takes_short_rows_whole(self):
         rng = np.random.default_rng(1)
         scores = jnp.asarray(rng.standard_normal((3, 96)), jnp.float32)
@@ -447,6 +512,30 @@ class TestCounters:
             assert a["experts_touched"] <= min(16, a["assignments_held"])
         finish = [a for n, a, _ in seen if n == "step.finish"]
         assert all(a["programs"] == a["transfers"] for a in finish)
+        # The XLA prefill finds its thresholds with ``kth_largest``.
+        assert not any("threshold_keys" in a for _, a, _ in seen)
+
+    def test_a_prefill_chunk_carries_what_its_threshold_counts(self, served):
+        """Through the Pallas prefill: 24 pages a row = 384 keys in blocks
+        of 128, chunks of 32 queries in tiles of 16, ``topk`` 32. A tile
+        counts 16 x the blocks up to its last candidate; one that reaches
+        32 keys at most counts none. 150 tokens: the chunk at 0 reaches 16
+        and 32 keys; at 32, 64 and 96 one block a tile; at 128 (22 tokens,
+        padded to 32) its tiles reach 144 and 150 keys, two blocks each."""
+        from tests.test_telemetry import _recorded
+
+        eng = MiniEngine(EngineConfig(
+            model=served.cfg, num_pages=64, max_pages_per_seq=24,
+            max_batch=2, max_prefill_tokens=32, use_pallas_decode=True,
+            use_pallas_prefill=True, telemetry=EngineTelemetryConfig()),
+            params=served.params)
+        seen = _recorded(eng._phases)
+        prompt = (PROMPT + PROMPT)[:150]
+        serve(eng, "counted", prompt, 1)
+        counted = [a["threshold_keys"] for n, a, _ in seen
+                   if n == "step.dispatch" and "threshold_keys" in a]
+        assert counted == [0, 2 * 16 * 128, 2 * 16 * 128, 2 * 16 * 128,
+                           2 * 16 * 256]
 
 
 class TestCounts:

@@ -67,6 +67,14 @@ def test_index_scores(shaped, rows, q_seq):
              shaped((rows, KEYS, 128)), shaped((rows,), jnp.int32))
 
 
+def test_keep_bias(shaped):
+    """A chunk's thresholds: 16 queries x 33 blocks of 1024 scores a
+    program in fast memory, twice (as they land, as they are counted)."""
+    compiles(functools.partial(sparse_index.dsa_keep_bias, topk=2048),
+             shaped((1, CHUNK, KEYS), jnp.float32),
+             shaped((1, CHUNK), jnp.int32), shaped((1,), jnp.int32))
+
+
 def test_masked_latent_prefill(shaped):
     """128 query heads on one 640-lane latent head, 16 query rows a
     program, a selection bias over every key of the row."""

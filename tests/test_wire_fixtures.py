@@ -467,8 +467,10 @@ class TestWireToIndex:
         pool.start()
         ctx = zmq.Context.instance()
         pub = ctx.socket(zmq.PUB)
-        endpoint = "tcp://127.0.0.1:15733"
-        pub.bind(endpoint)
+        # A free port: tests/test_telemetry.py binds 15733 too, and the two
+        # files run in different workers at once.
+        port = pub.bind_to_random_port("tcp://127.0.0.1")
+        endpoint = f"tcp://127.0.0.1:{port}"
         sub = ZMQSubscriber(endpoint, "kv@", pool.add_task, bind=False)
         sub.start()
         time.sleep(0.3)  # PUB/SUB slow-joiner settle
