@@ -39,6 +39,13 @@ pool, one event a victim), into a sink that does nothing. The JSON
 ``value`` is the scan's p50 over the kept order's. A host time on this
 sandbox's CPU: no device runs and no device metric is named.
 
+``--phases`` times what the router's and the pool's phases
+(``telemetry/tracing.py``: ``route.*``, ``ingest``) cost a call: one site
+bare, and a whole ``KVAwareRouter.route`` of a `sessions`-sized prompt, off
+(no engine of the process has phases: the shared no-op) and on (a
+``TraceAnnotation`` a site, no capture running). The JSON ``value`` is what
+a route gains on, in microseconds. Host times on this sandbox's CPU.
+
 Pure CPU scheduling-path work; run it pinned (`taskset`) for stable
 numbers. The ≥5x acceptance gate of ISSUE 2 applies to repeat_prefix.
 """
@@ -501,6 +508,46 @@ def bench_evict(*, pool_pages: int, pages: int, rounds: int) -> dict:
     }
 
 
+def bench_phases(*, routes: int, prompt_tokens: int, sites: int) -> dict:
+    """The control plane's phase sites off and on: two routers over two
+    indexes that see the same prompts in turn, one under an owner of its
+    own (on), one under the process's (off: no engine here has phases)."""
+    from llmd_kv_cache_tpu.scoring.router import KVAwareRouter
+    from llmd_kv_cache_tpu.telemetry import tracing
+
+    rng = random.Random(7)
+    assert tracing.process_phases() is None
+    owner = tracing.Phases()
+    routers = {"off": KVAwareRouter(make_indexer(True), PODS),
+               "on": KVAwareRouter(make_indexer(True), PODS, phases=owner)}
+    prompts = [[rng.randrange(1, 30000) for _ in range(prompt_tokens)]
+               for _ in range(16)]
+
+    def site_us(phases) -> float:
+        t0 = time.perf_counter()
+        for _ in range(sites):
+            with tracing.phase(phases, tracing.PHASE_ROUTE_HASH):
+                pass
+        return round((time.perf_counter() - t0) / sites * 1e6, 3)
+
+    took = {"off": [], "on": []}
+    for i in range(-50, routes):                # 50 to warm the caches
+        for side in (("off", "on") if i % 2 else ("on", "off")):
+            t0 = time.perf_counter()
+            routers[side].route(prompts[i % len(prompts)], MODEL)
+            if i >= 0:
+                took[side].append(time.perf_counter() - t0)
+    out = {side: {"site_us": site_us(owner if side == "on" else None),
+                  "route": pcts(took[side])} for side in took}
+    return {
+        "bench": "hotpath-phases", "platform": "cpu-host",
+        "routes": routes, "prompt_tokens": prompt_tokens, **out,
+        "value": round(out["on"]["route"]["p50_us"]
+                       - out["off"]["route"]["p50_us"], 2),
+        "unit": "us a route() gains with its six phases on, no capture",
+    }
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     # 100k tokens is the ISSUE's motivating scenario: a multi-turn session
@@ -519,6 +566,9 @@ def main():
     ap.add_argument("--evict", action="store_true",
                     help="run the eviction arm instead: one admission's "
                          "pages from a full pool, kept order vs the scan")
+    ap.add_argument("--phases", action="store_true",
+                    help="run the phases arm instead: the router's phase "
+                         "sites and a whole route(), off and on")
     ap.add_argument("--fleet-prompt-tokens", type=int, default=32 * 1024)
     ap.add_argument("--fleet-chunk", type=int, default=16,
                     help="fanoutChunkBlocks for both wires (fine-grained "
@@ -541,6 +591,11 @@ def main():
 
     if args.fleet:
         print(json.dumps(bench_fleet(args)))
+        return
+    if args.phases:
+        # a `sessions` prompt: 2 k tokens, 128 keys
+        print(json.dumps(bench_phases(routes=2000, prompt_tokens=2048,
+                                      sites=200_000)))
         return
     if args.evict:
         # cell 1's pool (2,560 pages a replica) and its 45 victims a request

@@ -31,7 +31,14 @@ from ..core.token_processor import ChunkedTokenDatabase
 from ..index.base import Index
 from ..resilience.liveness import PodLivenessTracker
 from ..telemetry import flight_recorder, tracer
-from ..telemetry.tracing import current_traceparent, remote_parent
+from ..telemetry.tracing import (
+    NOOP_SPAN,
+    PHASE_INGEST,
+    current_traceparent,
+    phase,
+    process_phases,
+    remote_parent,
+)
 from ..telemetry.flight_recorder import KIND_INGEST, KIND_OVERFLOW
 from ..utils.fnv import fnv1a_32
 from ..utils.logging import get_logger
@@ -762,30 +769,41 @@ class Pool:
         ):
             pod_identifier = f"{pod_identifier}|dp{batch.data_parallel_rank}"
 
-        # Any event from a pod proves its publisher (and thus our view of
-        # it) is alive; touch AFTER dp-rank suffixing so routing-visible
-        # identifiers are the ones tracked.
-        if self.liveness is not None:
-            self.liveness.touch(pod_identifier)
-
-        ops = sink if sink is not None else self.index
-        for event in batch.events:
-            if isinstance(event, BlockStoredEvent):
-                self._handle_block_stored(event, pod_identifier, model_name, ops)
-            elif isinstance(event, BlockRemovedEvent):
-                self._handle_block_removed(event, pod_identifier, ops)
-            elif isinstance(event, AllBlocksClearedEvent):
-                # Pod-wide: engines emit this with no tier; a tier-scoped
-                # clear is unsupported and would over-wipe.
-                try:
-                    ops.clear(pod_identifier)
-                except Exception:
-                    logger.exception("failed to clear pod %s", pod_identifier)
-                else:
-                    if self.ledger is not None:
-                        self.ledger.record_clear(pod_identifier)
-            else:  # pragma: no cover - adapter produces only known events
-                logger.debug("unknown event from pod %s: %r", pod_identifier, event)
+        # The profiler's view of the batch (``telemetry.tracing``: on with
+        # the phases of an engine of this process, else the shared no-op);
+        # the ZMQ path's request trace is ``_process_message``'s span.
+        with phase(process_phases(), PHASE_INGEST) as sp:
+            if sp is not NOOP_SPAN:
+                sp.set_attribute("pod", pod_identifier)
+                sp.set_attribute("events", len(batch.events))
+                sp.set_attribute("keys", sum(
+                    len(getattr(ev, "block_hashes", ()))
+                    for ev in batch.events))
+            # Any event from a pod proves its publisher (and thus our view
+            # of it) is alive; touch AFTER dp-rank suffixing so
+            # routing-visible identifiers are the ones tracked.
+            if self.liveness is not None:
+                self.liveness.touch(pod_identifier)
+            ops = sink if sink is not None else self.index
+            for event in batch.events:
+                if isinstance(event, BlockStoredEvent):
+                    self._handle_block_stored(
+                        event, pod_identifier, model_name, ops)
+                elif isinstance(event, BlockRemovedEvent):
+                    self._handle_block_removed(event, pod_identifier, ops)
+                elif isinstance(event, AllBlocksClearedEvent):
+                    # Pod-wide: engines emit this with no tier; a
+                    # tier-scoped clear is unsupported and would over-wipe.
+                    try:
+                        ops.clear(pod_identifier)
+                    except Exception:
+                        logger.exception("failed to clear pod %s", pod_identifier)
+                    else:
+                        if self.ledger is not None:
+                            self.ledger.record_clear(pod_identifier)
+                else:  # pragma: no cover - adapter produces only known events
+                    logger.debug("unknown event from pod %s: %r",
+                                 pod_identifier, event)
 
     def _handle_block_stored(
         self, ev: BlockStoredEvent, pod_identifier: str, model_name: str,

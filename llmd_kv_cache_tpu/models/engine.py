@@ -1307,7 +1307,7 @@ class MiniEngine:
         # above, else None — every phase site is then the shared no-op.
         self._phases: Optional[EnginePhases] = None
         if self.telemetry is not None:
-            self._phases = EnginePhases(self.cfg.pod_identifier)
+            self._phases = EnginePhases(self.cfg.pod_identifier, self._device)
             for _, manager in self._telemetry_pools:
                 manager.phases = self._phases
 
@@ -1418,23 +1418,48 @@ class MiniEngine:
         return req
 
     def _dispatch_phase(self, req: Optional[Request], rows: int,
-                        tokens: int, padded: int):
+                        tokens: int, padded: int, program=None):
         """The ``step.dispatch`` phase of one jitted call: the transfer of
         its packed inputs and the call returning, with the sizes that
-        explain its length. ``req`` is the request whose prefill chunk
-        rides it (None for a pure decode program): its ``traceparent``
-        makes the phase the trace's ``engine.prefill_chunk`` span."""
+        explain its length and, as ``program``, the name the jit gives
+        ``program`` (the callable about to be called: a device trace calls
+        its execution ``jit_<name>``). ``req`` is the request whose
+        prefill chunk rides it (None for a pure decode program): its
+        ``traceparent`` makes the phase the trace's
+        ``engine.prefill_chunk`` span."""
         ph = self._phases
         traceparent = None if req is None else req.traceparent
         if ph is None and traceparent is None:
             return phase(None, PHASE_STEP_DISPATCH)
+        named = {} if ph is None or program is None else {
+            "program": getattr(program, "func", program).__name__}
         if req is None:
             return phase(ph, PHASE_STEP_DISPATCH, programs=1, rows=rows,
-                         tokens=tokens, padded=padded)
+                         tokens=tokens, padded=padded, **named)
         return phase(ph, PHASE_STEP_DISPATCH, traceparent, programs=1,
                      rows=rows, tokens=tokens, padded=padded,
                      request_id=req.request_id, prefill_pos=req.prefill_pos,
-                     process=self.cfg.pod_identifier)
+                     process=self.cfg.pod_identifier, **named)
+
+    def _launch_input(self, packed, sp):
+        """A step program's packed inputs on the device and, on its
+        dispatch phase ``sp``, the ``launch`` it is numbered with. An
+        argument of the jitted call, so the number is taken as the last
+        thing before the call: the device runs what one process sends it
+        in that order, whichever replica sent it."""
+        x = self._to_dev(packed)
+        ph = self._phases
+        if ph is not None:
+            sp.set_attribute("launch", ph.next_launch())
+        return x
+
+    def _fetch_phase(self):
+        """The ``step.fetch`` phase of the program this engine launched
+        last: the blocking read of its tokens, named by its ``launch``."""
+        ph = self._phases
+        if ph is None:
+            return phase(None, PHASE_STEP_FETCH)
+        return phase(ph, PHASE_STEP_FETCH, launch=ph.launch)
 
     def _device_counts(self, sp, counts: np.ndarray, tokens: int,
                        program: str) -> None:
@@ -2345,9 +2370,10 @@ class MiniEngine:
         # not in a helper: through one (a frame more, the pools splatted)
         # every 28-layer program took 1.5-2 s longer to trace and lower on
         # the chip's host (PERF.md §6, PR 31) — why is not known either.
-        with self._dispatch_phase(req, 1, len(chunk), seq) as sp:
+        with self._dispatch_phase(req, 1, len(chunk), seq,
+                                  self._prefill_forward) as sp:
             token, row, pools = self._prefill_forward(
-                self.params, self.cfg.model, self._to_dev(packed),
+                self.params, self.cfg.model, self._launch_input(packed, sp),
                 self._pools(), shapes=shapes, last_only=True, keep_row=True,
                 token_sharding=token_sharding)
             self._take_pools(pools)
@@ -2385,7 +2411,7 @@ class MiniEngine:
             return None
         req.last_logits = row
         req.prefill_pos = None
-        with phase(ph, PHASE_STEP_FETCH) as sp:
+        with self._fetch_phase() as sp:
             picked = np.asarray(token)
             self._device_counts(sp, picked[1:], len(chunk), "prefill")
             return int(picked[0])
@@ -2810,9 +2836,10 @@ class MiniEngine:
         finishing = (prefill_req is not None
                      and p_pos + len(p_chunk) >= len(prefill_req.prompt))
         # Dispatched as every program of a step is: see _prefill_chunk.
-        with self._dispatch_phase(prefill_req, rows, t_real, t_pad):
+        with self._dispatch_phase(prefill_req, rows, t_real, t_pad,
+                                  step_ragged) as sp:
             picked, last_row, pools = step_ragged(
-                self.params, self.cfg.model, self._to_dev(packed),
+                self.params, self.cfg.model, self._launch_input(packed, sp),
                 self._pools(), shapes=shapes,
                 keep_row=prefill_req is not None,
                 interpret=self._ragged_interpret)
@@ -2826,7 +2853,7 @@ class MiniEngine:
 
         out: dict[str, int] = {}
         if decode_rows or finishing:
-            with phase(ph, PHASE_STEP_FETCH):
+            with self._fetch_phase():
                 next_tokens = np.asarray(picked)
         if decode_rows:
             now = time.monotonic() if tel is not None else 0.0
@@ -2937,13 +2964,14 @@ class MiniEngine:
 
         # Dispatched as every program of a step is: see _prefill_chunk.
         with self._dispatch_phase(None, len(chunk), len(chunk) * steps,
-                                  self.cfg.max_batch * steps):
+                                  self.cfg.max_batch * steps,
+                                  self._decode_multi) as sp:
             toks, _, pools = self._decode_multi(
-                self.params, self.cfg.model, self._to_dev(packed),
+                self.params, self.cfg.model, self._launch_input(packed, sp),
                 self._pools(), shapes=shapes, steps=steps)
             self._take_pools(pools)
             toks.copy_to_host_async()
-        with phase(ph, PHASE_STEP_FETCH):
+        with self._fetch_phase():
             toks_host = np.asarray(toks)
         out = {}
         now = time.monotonic() if self.telemetry is not None else 0.0
@@ -2997,9 +3025,10 @@ class MiniEngine:
                  *state_args))
 
         # Dispatched as every program of a step is: see _prefill_chunk.
-        with self._dispatch_phase(None, len(chunk), len(chunk), b) as sp:
+        with self._dispatch_phase(None, len(chunk), len(chunk), b,
+                                  self._decode_forward) as sp:
             picked, _, pools = self._decode_forward(
-                self.params, self.cfg.model, self._to_dev(packed),
+                self.params, self.cfg.model, self._launch_input(packed, sp),
                 self._pools(), shapes=shapes)
             self._take_pools(pools)
             picked.copy_to_host_async()
@@ -3016,7 +3045,7 @@ class MiniEngine:
                 sp.set_attribute(
                     "selected_keys", int(np.minimum(keys, topk).sum()))
         out = {}
-        with phase(ph, PHASE_STEP_FETCH) as sp:
+        with self._fetch_phase() as sp:
             next_tokens = np.asarray(picked)
             self._device_counts(sp, next_tokens[b:], len(chunk), "decode")
         tel = self.telemetry

@@ -18,6 +18,18 @@ from typing import Optional, Sequence
 
 from ..utils.lockdep import new_lock
 from ..core.keys import TIER_TPU_HBM, KeyType, PodEntry
+from ..telemetry.tracing import (
+    NOOP_SPAN,
+    PHASE_ROUTE_DECIDE,
+    PHASE_ROUTE_EXPIRE,
+    PHASE_ROUTE_HASH,
+    PHASE_ROUTE_LOOKUP,
+    PHASE_ROUTE_SCORE,
+    PHASE_ROUTE_SPECULATE,
+    Phases,
+    phase,
+    process_phases,
+)
 from ..utils.logging import get_logger
 from .indexer import Indexer
 
@@ -40,10 +52,16 @@ class KVAwareRouter:
     """Routes requests to the pod holding the longest cached prefix."""
 
     def __init__(self, indexer: Indexer, pods: Sequence[str],
-                 config: Optional[RouterConfig] = None):
+                 config: Optional[RouterConfig] = None,
+                 phases: Optional[Phases] = None):
         self.indexer = indexer
         self.pods = list(pods)
         self.config = config or RouterConfig()
+        # What ``route``'s phases are opened under (``telemetry.tracing``):
+        # a caller's own (a router in a process without engines), else
+        # whatever the process has at each call — None, and every site the
+        # shared no-op, until an engine beside it switches its phases on.
+        self._phases = phases
         self._rr_counter = 0
         self._lock = new_lock()
         # (pod, block-key) → expiry of outstanding speculative inserts;
@@ -62,16 +80,36 @@ class KVAwareRouter:
             # Must fail loudly: an empty filter set means "all pods" to the
             # index, which would happily route to a drained pod.
             raise RuntimeError("no candidate pods")
-        self._expire_speculative()
-        # Hash once; reuse the key chain for lookup, scoring, and the
-        # speculative insert.
-        keys = self.indexer.compute_block_keys(tokens, model_name)
-        scores: dict[str, float] = {}
-        if keys:
-            key_to_pods = self.indexer.kv_block_index.lookup(keys, set(self.pods))
-            scores = self.indexer.scorer.score(keys, key_to_pods)
-        pod = self._pick(scores)
-        self._add_speculative(keys, pod)
+        ph = self._phases or process_phases()
+        with phase(ph, PHASE_ROUTE_DECIDE) as sp:
+            with phase(ph, PHASE_ROUTE_EXPIRE):
+                expired = self._expire_speculative()
+            # Hash once; reuse the key chain for lookup, scoring, and the
+            # speculative insert.
+            with phase(ph, PHASE_ROUTE_HASH):
+                keys = self.indexer.compute_block_keys(tokens, model_name)
+            scores: dict[str, float] = {}
+            if keys:
+                with phase(ph, PHASE_ROUTE_LOOKUP):
+                    key_to_pods = self.indexer.kv_block_index.lookup(
+                        keys, set(self.pods))
+            with phase(ph, PHASE_ROUTE_SCORE):
+                if keys:
+                    scores = self.indexer.scorer.score(keys, key_to_pods)
+                pod = self._pick(scores)
+            with phase(ph, PHASE_ROUTE_SPECULATE):
+                self._add_speculative(keys, pod)
+            if sp is not NOOP_SPAN:
+                # ``best``: the score that won, 0 when round-robin decided.
+                best = scores.get(pod, 0.0)
+                if best < self.config.min_score_to_prefer:
+                    best = 0.0
+                sp.set_attribute("keys", len(keys))
+                sp.set_attribute("pods", len(self.pods))
+                sp.set_attribute("pod", pod)
+                sp.set_attribute("best", best)
+                sp.set_attribute("speculative", len(self._speculative))
+                sp.set_attribute("expired", expired)
         return pod
 
     def scores(self, tokens: Sequence[int], model_name: str) -> dict[str, float]:
@@ -106,7 +144,8 @@ class KVAwareRouter:
             for key in keys:
                 self._speculative[(pod, key)] = expiry
 
-    def _expire_speculative(self) -> None:
+    def _expire_speculative(self) -> int:
+        """Drop what is past its TTL; how many entries that was."""
         now = time.monotonic()
         with self._lock:
             expired = [k for k, expiry in self._speculative.items() if expiry <= now]
@@ -119,3 +158,4 @@ class KVAwareRouter:
                 self.indexer.kv_block_index.evict(key, KeyType.REQUEST, [entry])
             except Exception:
                 logger.debug("speculative evict failed for key %d", key)
+        return len(expired)

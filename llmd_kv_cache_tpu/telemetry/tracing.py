@@ -33,12 +33,16 @@ on, each phase is a ``jax.profiler.TraceAnnotation``, so a profiler capture
 (``/debug/profile``) shows it on the same clock as the device ops. A phase
 given a request's ``traceparent`` also opens the request's span
 (``engine.admission``, ``engine.prefill_chunk``), whether or not phases are
-on.
+on. The router's and the event pool's phases (``route.*``, ``ingest``) go
+through the same :func:`phase` under an owner that is no engine
+(:class:`Phases`, :func:`process_phases`): on once an engine of the process
+has its phases on.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
 import random
 import re
@@ -694,17 +698,28 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
 
 # -- engine phases -----------------------------------------------------------
 
-# Every phase the engine opens, in one place. ``enqueue.*`` run inside
+# Every phase the program opens, in one place. ``enqueue.*`` run inside
 # ``MiniEngine.enqueue``; ``step.*`` inside ``MiniEngine.step`` in this
 # order (inputs → dispatch once per program, → fetch where the host reads
 # its tokens; commit and emit when a prefill finished or blocks were
-# evicted). Every step program samples as its own tail, so no path opens
-# ``step.sample``; the name stays for the readers that list the phases.
+# evicted). Every step program samples as its own tail.
 # ``step.dispatch`` carries its program's ``rows``, ``tokens`` and ``padded``
 # and, where the model has them, what the program reads a layer: a decode
 # step that selects ``index_keys`` and ``selected_keys``, a prefill chunk
 # that selects ``threshold_keys`` (``ops.sparse_index``); with linear layers
-# a decode step ``state_rows``, a prefill chunk ``scan_tokens``.
+# a decode step ``state_rows``, a prefill chunk ``scan_tokens``. It names
+# what it launched: ``program``, the jitted function's name (a device
+# trace calls the execution ``jit_<program>``), and ``launch``, the
+# program's ordinal among all that this process sent to the same device
+# (``EnginePhases.next_launch``); the ``step.fetch`` that waits for a
+# program's tokens carries that ``launch``, and a prefill chunk whose token
+# nobody reads has no fetch.
+# ``route.*`` run inside ``KVAwareRouter.route`` and ``ingest`` inside
+# ``Pool.process_event_batch``, under an owner that is no engine
+# (``process_phases``), so they carry neither ``pod`` nor ``step`` of their
+# own: ``route.decide`` (all of a routing decision; nests the five below)
+# carries ``keys``, ``pods``, ``pod``, ``best``, ``speculative`` and
+# ``expired``; ``ingest`` carries ``pod``, ``events`` and ``keys``.
 PHASE_ENQUEUE_ADMIT = "enqueue.admit"      # all of admission (nests the two below)
 PHASE_ENQUEUE_HASH = "enqueue.hash"        # tokens → block hashes
 PHASE_ENQUEUE_LOOKUP = "enqueue.lookup"    # prefix probe, page allocation, eviction
@@ -712,19 +727,28 @@ PHASE_STEP_OFFLOAD_POLL = "step.offload_poll"
 PHASE_STEP_SCHEDULE = "step.schedule"      # the pick; restore and handoff gates
 PHASE_STEP_INPUTS = "step.inputs"          # the program's arguments built in numpy, packed into one array
 PHASE_STEP_DISPATCH = "step.dispatch"      # its one _to_dev transfer + the jitted call returning + the tokens' copy back started
-PHASE_STEP_SAMPLE = "step.sample"          # programs outside the jit that pick tokens: none, sampling is a program's tail
 PHASE_STEP_FETCH = "step.fetch"            # the blocking np.asarray of the program's tokens
 PHASE_STEP_COMMIT = "step.commit"          # _commit_full_blocks → commit_blocks, write-through
 PHASE_STEP_EMIT = "step.emit"              # event batch → sink → Pool/index (nests in commit)
 PHASE_STEP_FINISH = "step.finish"          # release of finished requests; carries the step's counters
 PHASE_STEP_SNAPSHOT = "step.snapshot"      # a prefill chunk's snapshots planned: slots reserved in the state pool, what they evicted
+PHASE_ROUTE_DECIDE = "route.decide"        # all of KVAwareRouter.route (nests the five below)
+PHASE_ROUTE_EXPIRE = "route.expire"        # speculative entries past their TTL dropped
+PHASE_ROUTE_HASH = "route.hash"            # prompt tokens → block keys
+PHASE_ROUTE_LOOKUP = "route.lookup"        # the chain looked up in the index
+PHASE_ROUTE_SCORE = "route.score"          # the scorer over what was found, and the pick
+PHASE_ROUTE_SPECULATE = "route.speculate"  # speculative entries for the chosen pod
+PHASE_INGEST = "ingest"                    # Pool.process_event_batch: one batch applied to the index
 
 PHASE_NAMES = (
     PHASE_ENQUEUE_ADMIT, PHASE_ENQUEUE_HASH, PHASE_ENQUEUE_LOOKUP,
     PHASE_STEP_OFFLOAD_POLL, PHASE_STEP_SCHEDULE, PHASE_STEP_INPUTS,
-    PHASE_STEP_DISPATCH, PHASE_STEP_SAMPLE, PHASE_STEP_FETCH,
+    PHASE_STEP_DISPATCH, PHASE_STEP_FETCH,
     PHASE_STEP_COMMIT, PHASE_STEP_EMIT, PHASE_STEP_FINISH,
     PHASE_STEP_SNAPSHOT,
+    PHASE_ROUTE_EXPIRE, PHASE_ROUTE_HASH, PHASE_ROUTE_LOOKUP,
+    PHASE_ROUTE_SCORE, PHASE_ROUTE_SPECULATE, PHASE_ROUTE_DECIDE,
+    PHASE_INGEST,
 )
 
 SPAN_ENGINE_ADMISSION = "llm_d.kv_cache.engine.admission"
@@ -742,30 +766,78 @@ _SPAN_OF_PHASE = {
 NOOP_SPAN = _NOOP_SPAN
 
 
-class EnginePhases:
+class Phases:
+    """An owner of phases that is no engine (the router's, the pool's):
+    it has no ``step()`` to count, so its phases carry neither ``pod`` nor
+    ``step`` but what their call sites give them. Nothing on it changes
+    once built, so any thread may open a phase under it."""
+
+    __slots__ = ("_annotation",)
+    step = None               # no ordinal: `_Phase` stamps nothing of its own
+    transfers = bytes = 0     # and it moves nothing to a device
+
+    def __init__(self):
+        # Reached here, not at import: ``scoring/`` and ``events/`` import
+        # this module and must not need JAX to.
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+
+
+# What the phases of the router and the pool are opened under, or None
+# while they are off: set when the first engine of this process switches
+# its phases on (they share its profiler capture), never unset.
+_process_phases: Optional[Phases] = None
+
+
+def process_phases() -> Optional[Phases]:
+    """The owner of the phases that belong to no engine, or None while no
+    engine of this process has phases: read at each call (a router is
+    built after the engines, a pool before them), so off such a site costs
+    this read and ``phase``'s identity check."""
+    return _process_phases
+
+
+# Device (as an engine was given it; None: JAX's default) → the count its
+# launches are numbered from. ``next()`` on it is one C call: no lock.
+_launch_counts: dict = {}
+
+
+class EnginePhases(Phases):
     """What one engine's phases share: whose they are, the ordinal of the
     ``step()`` they run in (0 before the first), and what that step has
     dispatched and moved to the device so far (``programs``: jitted calls,
     counted by the phases given ``programs=``; ``transfers``/``bytes``:
     counted by the engine's ``_to_dev``). Every phase carries ``pod`` and
-    ``step``; ``step.finish`` carries the step's totals. Touched by the
-    one thread that owns the engine.
+    ``step``; ``step.finish`` carries the step's totals. ``launch`` is the
+    ordinal of the last program this engine sent to ``device``, counted
+    over every engine of the process on that device. Touched by the one
+    thread that owns the engine.
     """
 
-    __slots__ = ("pod", "step", "programs", "transfers", "bytes",
-                 "_annotation")
+    __slots__ = ("pod", "step", "programs", "transfers", "bytes", "launch",
+                 "_launches")
 
-    def __init__(self, pod: str):
-        from jax.profiler import TraceAnnotation
-
+    def __init__(self, pod: str, device=None):
+        global _process_phases
+        super().__init__()
         self.pod = pod
         self.step = 0
-        self.programs = self.transfers = self.bytes = 0
-        self._annotation = TraceAnnotation
+        self.programs = self.transfers = self.bytes = self.launch = 0
+        self._launches = _launch_counts.setdefault(device, itertools.count(1))
+        if _process_phases is None:
+            _process_phases = Phases()
 
     def begin_step(self) -> None:
         self.step += 1
         self.programs = self.transfers = self.bytes = 0
+
+    def next_launch(self) -> int:
+        """Number the program about to be launched: called as the last
+        thing before the jitted call, so that programs reach the device
+        in the order of their ordinals."""
+        self.launch = next(self._launches)
+        return self.launch
 
 
 class _Phase:
@@ -774,7 +846,7 @@ class _Phase:
     __slots__ = ("_phases", "_name", "_attrs", "_ann", "_span_cm", "_span",
                  "_moved")
 
-    def __init__(self, phases: EnginePhases, name: str, span_cm, attrs: dict):
+    def __init__(self, phases: Phases, name: str, span_cm, attrs: dict):
         self._phases, self._name, self._attrs = phases, name, attrs
         self._span_cm, self._span = span_cm, None
 
@@ -783,8 +855,11 @@ class _Phase:
         self._moved = (phases.transfers, phases.bytes)
         if self._span_cm is not None:
             self._span = self._span_cm.__enter__()
-        self._ann = phases._annotation(
-            self._name, pod=phases.pod, step=phases.step, **self._attrs)
+        if phases.step is None:
+            self._ann = phases._annotation(self._name, **self._attrs)
+        else:
+            self._ann = phases._annotation(
+                self._name, pod=phases.pod, step=phases.step, **self._attrs)
         self._ann.__enter__()
         return self
 
@@ -807,21 +882,23 @@ class _Phase:
         return False
 
 
-def phase(phases: Optional[EnginePhases], name: str,
+def phase(phases: Optional[Phases], name: str,
           traceparent: Optional[str] = None, programs: int = 0, **attrs):
-    """Context manager around one engine phase.
+    """Context manager around one phase.
 
-    ``phases`` is the engine's :class:`EnginePhases`, or None when the
-    engine was built without ``EngineConfig.telemetry``: then this is the
-    shared no-op (nothing is built, no clock is read) — unless the request
-    carries a ``traceparent`` and the phase has a request span, which is
-    opened as before (itself the no-op without an exporter or provider).
-    On, the phase is a ``jax.profiler.TraceAnnotation`` named ``name``
-    with ``pod``, ``step`` and ``attrs``: it costs a few hundred
-    nanoseconds while no profiler captures and lands on the capture's host
-    plane, on the device ops' clock, while one does. ``programs`` is the
-    number of jitted calls made inside. What is yielded takes
-    ``set_attribute(key, value)`` either way.
+    ``phases`` is the engine's :class:`EnginePhases` (the router's and the
+    pool's sites pass :func:`process_phases`), or None when the engine was
+    built without ``EngineConfig.telemetry``: then this is the shared no-op
+    (nothing is built, no clock is read) — unless the request carries a
+    ``traceparent`` and the phase has a request span, which is opened as
+    before (itself the no-op without an exporter or provider). On, the
+    phase is a ``jax.profiler.TraceAnnotation`` named ``name`` with
+    ``attrs`` and, of an engine, ``pod`` and ``step``: it costs a few
+    hundred nanoseconds while no profiler captures and lands on the
+    capture's host plane while one does, beside the device's ops (the
+    profiler lines the two clocks up to about a millisecond: PERF.md §6,
+    PR 38). ``programs`` is the number of jitted calls made inside. What
+    is yielded takes ``set_attribute(key, value)`` either way.
 
     A call site on a hot path passes ``attrs`` only behind its own check
     of ``phases`` (or sets them inside, through ``set_attribute``), so that

@@ -588,7 +588,7 @@ def _recorded(phases):
 class TestPhaseFacade:
     def test_off_is_the_shared_noop_and_reads_no_clock(self, monkeypatch):
         assert tracing.phase(None, tracing.PHASE_STEP_INPUTS) is tracing._NOOP_CM
-        assert tracing.phase(None, tracing.PHASE_STEP_SAMPLE,
+        assert tracing.phase(None, tracing.PHASE_STEP_SNAPSHOT,
                              programs=2) is tracing._NOOP_CM
         with tracing.phase(None, tracing.PHASE_STEP_COMMIT) as sp:
             assert sp is tracing.NOOP_SPAN
@@ -628,7 +628,7 @@ class TestPhaseFacade:
         for _ in range(1000):
             with eng._dispatch_phase(None, 4, 4, 8):
                 pass
-            with tracing.phase(None, tracing.PHASE_STEP_SAMPLE, programs=2):
+            with tracing.phase(None, tracing.PHASE_STEP_SNAPSHOT, programs=2):
                 pass
         assert sys.getallocatedblocks() - before <= 2
 
@@ -737,7 +737,7 @@ class TestEnginePhases:
             assert programs >= 1
             assert count(tracing.PHASE_STEP_INPUTS) == programs
             assert count(tracing.PHASE_STEP_FETCH) <= programs
-            assert count(tracing.PHASE_STEP_SAMPLE) == 0
+            assert set(names) <= set(tracing.PHASE_NAMES)
             finish = phases[-1][1]
             assert finish["programs"] == programs == sum(
                 a.get("programs", 0) for _, a in phases[:-1])
@@ -757,14 +757,11 @@ class TestEnginePhases:
             for at, n in enumerate(names):
                 if n == tracing.PHASE_STEP_EMIT:
                     assert names[at - 1] == tracing.PHASE_STEP_COMMIT
-        # ``step.sample`` keeps its name (the benchmark lists the phases)
-        # and no path opens it: sampling is the tail of every program.
         # ``step.snapshot`` is opened for a model that keeps a sequence
         # state beside its pages only (tests/test_gated_deltanet.py).
         assert names_seen == {n for n in tracing.PHASE_NAMES
                               if n.startswith("step.")
-                              } - {tracing.PHASE_STEP_SAMPLE,
-                                   tracing.PHASE_STEP_SNAPSHOT}
+                              } - {tracing.PHASE_STEP_SNAPSHOT}
         assert events                  # the sink did receive the batches
         commits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_COMMIT]
         assert sorted(c["blocks"] for c in commits) == [1, 3]
@@ -887,3 +884,234 @@ class TestEnginePhases:
                 tracing.PHASE_STEP_FETCH, tracing.PHASE_STEP_FINISH} <= set(found)
         assert found[tracing.PHASE_STEP_DISPATCH]["pod"] == "pod-x"
         assert int(found[tracing.PHASE_STEP_DISPATCH]["transfers"]) > 0
+        # A program's owner rides the capture as the other attributes do.
+        assert found[tracing.PHASE_STEP_DISPATCH]["program"] == "forward"
+        assert int(found[tracing.PHASE_STEP_FETCH]["launch"]) >= int(
+            found[tracing.PHASE_STEP_DISPATCH]["launch"]) > 0
+
+
+# -- launches: a program's owner (step.dispatch / step.fetch) -----------------
+
+
+class TestLaunches:
+    @pytest.mark.parametrize("backend", ["xla", "pallas", "ragged"])
+    def test_programs_name_and_number_themselves_across_engines(
+            self, backend, monkeypatch):
+        """Two replicas on one device stepped in turn: every dispatch takes
+        the next ``launch`` of the device, whichever engine it is; a fetch
+        names the launch it waits for; a chunk nobody reads has none;
+        ``program`` is the name the jit gives the module."""
+        from llmd_kv_cache_tpu.models import llama
+
+        monkeypatch.setattr(tracing, "_launch_counts", {})
+        over = dict(xla={}, ragged=dict(ragged=True), pallas=dict(
+            use_pallas_decode=True, use_pallas_prefill=True))[backend]
+        a, tiny = _phase_engine(pod_identifier="pod-a", **over)
+        b, _ = _phase_engine(pod_identifier="pod-b", **over)
+        assert a._phases._launches is b._phases._launches
+        a._phases._annotation = b._phases._annotation = ann = _Annotations()
+        page = tiny.page_size
+        # Three pages at two a chunk: the first chunk's token is not read.
+        a.enqueue("a", list(range(1, 1 + 3 * page)), max_new_tokens=3)
+        b.enqueue("b", list(range(500, 500 + 3 * page)), max_new_tokens=3)
+        while a.requests or b.requests:
+            for eng in (a, b):
+                if eng.requests:
+                    eng.step()
+        dispatches = [r[1] for r in ann.seen
+                      if r[0] == tracing.PHASE_STEP_DISPATCH]
+        fetches = [r[1] for r in ann.seen if r[0] == tracing.PHASE_STEP_FETCH]
+        launches = [d["launch"] for d in dispatches]
+        assert launches == list(range(1, len(dispatches) + 1))
+        assert {d["pod"] for d in dispatches} == {"pod-a", "pod-b"}
+        by_launch = {d["launch"]: d for d in dispatches}
+        for f in fetches:        # the same engine's, in the same step
+            d = by_launch[f["launch"]]
+            assert (d["pod"], d["step"]) == (f["pod"], f["step"])
+        assert len({f["launch"] for f in fetches}) == len(fetches)
+        unread = [d for d in dispatches
+                  if d["launch"] not in {f["launch"] for f in fetches}]
+        if backend == "ragged":
+            want = {llama.forward_ragged.__name__}
+        else:
+            # Each engine's first chunk, and nothing else.
+            assert [(d["pod"], d["prefill_pos"]) for d in unread] == [
+                ("pod-a", 0), ("pod-b", 0)]
+            want = ({llama.PROGRAM_PREFILL, llama.PROGRAM_DECODE}
+                    if backend == "pallas" else {llama.forward.__name__})
+        assert {d["program"] for d in dispatches} == want
+        if backend == "pallas":
+            assert all(
+                d["program"] == (llama.PROGRAM_PREFILL if "prefill_pos" in d
+                                 else llama.PROGRAM_DECODE)
+                for d in dispatches)
+
+    def test_a_device_has_its_own_count(self, monkeypatch):
+        monkeypatch.setattr(tracing, "_launch_counts", {})
+        one, other = tracing.EnginePhases("p", "chip-0"), tracing.EnginePhases(
+            "q", "chip-1")
+        same = tracing.EnginePhases("r", "chip-0")
+        assert [one.next_launch(), other.next_launch(), same.next_launch(),
+                one.next_launch()] == [1, 1, 2, 3]
+        assert (one.launch, other.launch, same.launch) == (3, 1, 2)
+
+    def test_off_counts_nothing(self, monkeypatch):
+        monkeypatch.setattr(tracing, "_launch_counts", {})
+        eng, tiny = _phase_engine(telemetry=False)
+        eng.enqueue("a", list(range(1, 2 * tiny.page_size)), max_new_tokens=2)
+        _drain(eng)
+        assert tracing._launch_counts == {}
+        assert eng._fetch_phase() is tracing._NOOP_CM
+
+
+# -- the router's and the pool's phases ---------------------------------------
+
+
+def _router_and_pool(pods=("pod-0", "pod-1")):
+    from llmd_kv_cache_tpu.core import TokenProcessorConfig
+    from llmd_kv_cache_tpu.events.pool import Pool, PoolConfig
+    from llmd_kv_cache_tpu.scoring import Indexer, IndexerConfig
+    from llmd_kv_cache_tpu.scoring.router import KVAwareRouter
+
+    indexer = Indexer(IndexerConfig(
+        token_processor_config=TokenProcessorConfig(block_size_tokens=4)))
+    pool = Pool(PoolConfig(concurrency=1), indexer.kv_block_index,
+                indexer.token_processor)
+    return KVAwareRouter(indexer, list(pods)), pool
+
+
+def _store(pool, pod, tokens, first_hash=100):
+    from llmd_kv_cache_tpu.events.model import BlockStoredEvent, EventBatch
+
+    blocks = len(tokens) // 4
+    pool.process_event_batch(EventBatch(timestamp=time.time(), events=[
+        BlockStoredEvent(block_hashes=list(range(first_hash,
+                                                 first_hash + blocks)),
+                         tokens=list(tokens), block_size=4)]), pod, "m")
+
+
+class TestControlPlanePhases:
+    ROUTE = (tracing.PHASE_ROUTE_DECIDE, tracing.PHASE_ROUTE_EXPIRE,
+             tracing.PHASE_ROUTE_HASH, tracing.PHASE_ROUTE_LOOKUP,
+             tracing.PHASE_ROUTE_SCORE, tracing.PHASE_ROUTE_SPECULATE)
+
+    @pytest.fixture
+    def recorded(self, monkeypatch):
+        """The process's owner switched on, its annotations recorded."""
+        owner = tracing.Phases()
+        owner._annotation = ann = _Annotations()
+        monkeypatch.setattr(tracing, "_process_phases", owner)
+        return ann.seen
+
+    def test_route_opens_each_phase_once_nested_in_order(self, recorded):
+        router, pool = _router_and_pool()
+        prompt = list(range(1, 1 + 5 * 4))
+        _store(pool, "pod-1", prompt[:12])
+        del recorded[:]
+        opened_under = []
+        real = tracing._Phase.__enter__
+
+        def enter(self):
+            opened_under.append([r[0] for r in recorded if r[2]])
+            return real(self)
+
+        tracing._Phase.__enter__ = enter
+        try:
+            assert router.route(prompt, "m") == "pod-1"
+        finally:
+            tracing._Phase.__enter__ = real
+        assert tuple(r[0] for r in recorded) == self.ROUTE
+        assert not any(r[2] for r in recorded)
+        # The five parts open inside the decision, one after another.
+        assert opened_under == [[]] + 5 * [[tracing.PHASE_ROUTE_DECIDE]]
+        decide = recorded[0][1]
+        assert decide == {"keys": 5, "pods": 2, "pod": "pod-1", "best": 3.0,
+                          "speculative": 5, "expired": 0}
+        # Neither pod nor step of an engine on the parts.
+        assert all(r[1] == {} for r in recorded[1:])
+
+    def test_round_robin_reads_best_0_and_expiry_is_counted(self, recorded):
+        router, _ = _router_and_pool()
+        router.config.speculative_ttl_s = 0.0
+        assert router.route(list(range(1, 9)), "m") == "pod-0"
+        first = recorded[0][1]
+        assert (first["best"], first["keys"], first["speculative"]) == (0.0, 2, 2)
+        del recorded[:]
+        # No full block: nothing to look up, and the two entries expired.
+        assert router.route([1, 2], "m") == "pod-1"
+        assert tuple(r[0] for r in recorded) == tuple(
+            n for n in self.ROUTE if n != tracing.PHASE_ROUTE_LOOKUP)
+        assert recorded[0][1] == {"keys": 0, "pods": 2, "pod": "pod-1",
+                                  "best": 0.0, "speculative": 0, "expired": 2}
+
+    def test_ingest_carries_pod_events_and_keys(self, recorded):
+        from llmd_kv_cache_tpu.events.model import (
+            AllBlocksClearedEvent, BlockRemovedEvent, BlockStoredEvent,
+            EventBatch)
+
+        _, pool = _router_and_pool()
+        pool.process_event_batch(EventBatch(timestamp=time.time(), events=[
+            BlockStoredEvent(block_hashes=[1, 2, 3],
+                             tokens=list(range(12)), block_size=4),
+            BlockRemovedEvent(block_hashes=[1, 2]),
+            AllBlocksClearedEvent()]), "pod-7", "m")
+        assert recorded == [[tracing.PHASE_INGEST,
+                             {"pod": "pod-7", "events": 3, "keys": 5}, False]]
+
+    def test_off_they_are_the_shared_noop_and_build_nothing(
+            self, monkeypatch):
+        """No engine of the process has phases: every site of the router
+        and the pool is the engine's off path."""
+        import sys
+
+        monkeypatch.setattr(tracing, "_process_phases", None)
+        assert tracing.process_phases() is None
+        opened = []
+        monkeypatch.setattr(tracing, "_Phase",
+                            lambda *a: opened.append(a) or tracing._NOOP_CM)
+        router, pool = _router_and_pool()
+        prompt = list(range(1, 1 + 5 * 4))
+        _store(pool, "pod-1", prompt[:12])
+        assert router.route(prompt, "m") == "pod-1"
+        assert opened == []
+        names = (*self.ROUTE, tracing.PHASE_INGEST)
+        for name in names:
+            assert tracing.phase(tracing.process_phases(),
+                                 name) is tracing._NOOP_CM
+        before = sys.getallocatedblocks()
+        for _ in range(1000):
+            for name in names:
+                with tracing.phase(router._phases or tracing.process_phases(),
+                                   name):
+                    pass
+        assert sys.getallocatedblocks() - before <= 2
+
+    def test_on_with_the_first_engine_that_has_phases(self, monkeypatch):
+        """As the benchmark's fleet builds them: the pool before the
+        engines, the router after, neither told anything."""
+        monkeypatch.setattr(tracing, "_process_phases", None)
+        router, pool = _router_and_pool()
+        _phase_engine(telemetry=False)
+        assert tracing.process_phases() is None
+        eng, _ = _phase_engine(telemetry=True)
+        owner = tracing.process_phases()
+        assert isinstance(owner, tracing.Phases) and owner is not eng._phases
+        _phase_engine(telemetry=True)
+        assert tracing.process_phases() is owner        # one a process
+        owner._annotation = ann = _Annotations()
+        _store(pool, "pod-0", list(range(1, 9)))
+        router.route(list(range(1, 9)), "m")
+        assert [r[0] for r in ann.seen] == [tracing.PHASE_INGEST, *self.ROUTE]
+
+    def test_a_router_takes_an_owner_of_its_own(self, monkeypatch):
+        """A process without engines (the scorer's): the caller says so."""
+        from llmd_kv_cache_tpu.scoring.router import KVAwareRouter
+
+        monkeypatch.setattr(tracing, "_process_phases", None)
+        router, _ = _router_and_pool()
+        mine = tracing.Phases()
+        mine._annotation = ann = _Annotations()
+        router = KVAwareRouter(router.indexer, router.pods, phases=mine)
+        router.route(list(range(1, 9)), "m")
+        assert [r[0] for r in ann.seen][0] == tracing.PHASE_ROUTE_DECIDE
+        assert tracing.process_phases() is None
