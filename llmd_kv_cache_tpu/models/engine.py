@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import heapq
+import itertools
 import time
 import dataclasses
 from dataclasses import dataclass, field
@@ -114,6 +115,30 @@ def _with_state(program):
         return functools.partial(_WITH_STATE[program.func],
                                  **program.keywords)
     return _WITH_STATE[program]
+
+
+# Device (as an engine was given it; None: JAX's default) → the count that
+# numbers every step program this process sends to it, whichever engine
+# sends it, traced or not. ``next()`` on it is one C call: no lock.
+_launch_counts: dict = {}
+
+
+@dataclass
+class _Unread:
+    """A step program whose tokens the host has not read yet."""
+
+    picked: Any    # int32 [padded (+ the model's counters)], on the device
+    rows: list     # the requests it ran, in the order of its rows
+    padded: int    # its rows with the padding: the counters lie behind them
+    tokens: int    # the real tokens it ran (what the counters are counts of)
+    launch: int    # its ordinal on the device (``_launch_counts``)
+    program: str   # "decode" or "prefill"
+    host: Optional[np.ndarray] = None  # ``picked`` on the host, once read
+
+    def row_of(self, req) -> int:
+        """``req``'s row, or -1 (by identity: a ``Request`` compares by
+        value, field for field)."""
+        return next((i for i, row in enumerate(self.rows) if row is req), -1)
 
 
 def _resolve_kv_dtype(name: str):
@@ -1157,6 +1182,33 @@ class MiniEngine:
             else:
                 self._ragged = True
 
+        # The device's launch count (``_launch_input``), the ordinal of the
+        # last program this engine sent, and how many padded decode
+        # programs it has sent since another engine's went in between.
+        self._launches = _launch_counts.setdefault(
+            self._device, itertools.count(1))
+        self._launch = self._lone_decodes = 0
+        # The padded decode program whose tokens are still unread
+        # (``step()``), and the tokens its form returned last, on the
+        # device: the operand ``prev`` of every such program, so that one
+        # is compiled whether anything is in flight or not.
+        self._unread: Optional[_Unread] = None
+        # The last prefill chunk of a step that read nothing
+        # (``_bound_chunks``).
+        self._chunk_ahead: Optional[_Unread] = None
+        # Not the fused bursts (the scan samples for itself: ROADMAP D2),
+        # not a sharded engine (its tokens come back laid out over a mesh
+        # and ``prev`` would be a second form of every program), not a
+        # hybrid one (its window pages are ensured and reclaimed from
+        # ``computed_len``, a token at a time): those read each program
+        # before the next is built, and take no ``prev``.
+        self._defers = (mesh is None and not self.hybrid
+                        and self._burst == 1)
+        # (Every padded decode form counts what the model counts.)
+        self._prev = jax.device_put(
+            np.zeros((self.cfg.max_batch + len(mcfg.step_counters),),
+                     np.int32), self._device) if self._defers else None
+
         # What actually serves each phase, resolved above for the engine's
         # lifetime; read through ``attention_backends``.
         def phase(pallas: bool) -> dict:
@@ -1307,7 +1359,7 @@ class MiniEngine:
         # above, else None — every phase site is then the shared no-op.
         self._phases: Optional[EnginePhases] = None
         if self.telemetry is not None:
-            self._phases = EnginePhases(self.cfg.pod_identifier, self._device)
+            self._phases = EnginePhases(self.cfg.pod_identifier)
             for _, manager in self._telemetry_pools:
                 manager.phases = self._phases
 
@@ -1414,7 +1466,7 @@ class MiniEngine:
         and run the prefill step for the uncached suffix (synchronously —
         the request returns ready to decode)."""
         req = self._admit(request_id, prompt, max_new_tokens)
-        self._finish_prefill(req, self._prefill(req))
+        self._finish_prefill(req, int(self._fetch(self._prefill(req))[0]))
         return req
 
     def _dispatch_phase(self, req: Optional[Request], rows: int,
@@ -1441,25 +1493,55 @@ class MiniEngine:
                      request_id=req.request_id, prefill_pos=req.prefill_pos,
                      process=self.cfg.pod_identifier, **named)
 
-    def _launch_input(self, packed, sp):
-        """A step program's packed inputs on the device and, on its
-        dispatch phase ``sp``, the ``launch`` it is numbered with. An
-        argument of the jitted call, so the number is taken as the last
-        thing before the call: the device runs what one process sends it
-        in that order, whichever replica sent it."""
+    def _launch_input(self, packed, sp, decode: bool = False):
+        """A step program's packed inputs on the device, the program
+        numbered (``self._launch``, and ``launch`` on its dispatch phase
+        ``sp``). An argument of the jitted call, so the number is taken as
+        the last thing before the call: the device runs what one process
+        sends it in that order, whichever replica sent it.
+
+        The numbers are also how this engine sees whether it has the chip
+        to itself: ``_lone_decodes`` counts the padded decode programs
+        (``decode``) it launched since a number last went to another
+        engine."""
         x = self._to_dev(packed)
-        ph = self._phases
-        if ph is not None:
-            sp.set_attribute("launch", ph.next_launch())
+        n = next(self._launches)
+        if n != self._launch + 1:
+            self._lone_decodes = 0
+        self._launch = n
+        if decode:
+            self._lone_decodes += 1
+        if sp is not NOOP_SPAN:
+            sp.set_attribute("launch", n)
         return x
 
-    def _fetch_phase(self):
-        """The ``step.fetch`` phase of the program this engine launched
-        last: the blocking read of its tokens, named by its ``launch``."""
+    def _fetch_phase(self, launch: int):
+        """The ``step.fetch`` phase of program ``launch`` of this device:
+        the blocking read of its tokens."""
         ph = self._phases
         if ph is None:
             return phase(None, PHASE_STEP_FETCH)
-        return phase(ph, PHASE_STEP_FETCH, launch=ph.launch)
+        return phase(ph, PHASE_STEP_FETCH, launch=launch)
+
+    def _fetch(self, rec: _Unread) -> np.ndarray:
+        """``rec``'s tokens on the host: read once, inside its
+        ``step.fetch``, which also takes what the program counted."""
+        if rec.host is None:
+            with self._fetch_phase(rec.launch) as sp:
+                rec.host = np.asarray(rec.picked)
+                self._device_counts(sp, rec.host[rec.padded:], rec.tokens,
+                                    rec.program)
+        return rec.host
+
+    def _drain(self, cause: str) -> None:
+        """Wait for the decode program in flight, if one is, before what
+        ends or moves a request under it (``cause``). Its tokens stay with
+        the record; the next ``step()`` returns them as it would have."""
+        rec = self._unread
+        if rec is not None and rec.host is None:
+            self._fetch(rec)
+            if self.telemetry is not None:
+                self.telemetry.on_lookahead_drained(cause)
 
     def _device_counts(self, sp, counts: np.ndarray, tokens: int,
                        program: str) -> None:
@@ -1865,6 +1947,7 @@ class MiniEngine:
         """Hand the current (possibly donated-and-replaced) cache arrays to
         the offload copiers; forward() replaces the cache arrays every
         step, so the copiers must never hold stale references."""
+        self._drain("offload")
         self.offload_handlers.copier.k_cache = self.k_cache
         self.offload_handlers.copier.v_cache = self.v_cache
         if self.hybrid:
@@ -2301,24 +2384,28 @@ class MiniEngine:
             self.swa_manager.release(committed, [])
         req.swa_acquired_from = limit
 
-    def _prefill(self, req: Request) -> int:
+    def _prefill(self, req: Request) -> _Unread:
         """Run the model over the whole uncached prompt suffix, chunked;
-        returns the first generated token.
+        returns the last chunk's program, the first generated token unread.
 
         Chunks of at most ``max_prefill_tokens`` bound activation memory on
         long prompts (vLLM-style chunked prefill); each chunk's KV lands in
         the paged cache so the next chunk attends over it.
         """
         while req.prefill_pos is not None:
-            first_token = self._prefill_chunk(req)
-        return first_token
+            first = self._prefill_chunk(req)
+        return first
 
-    def _prefill_chunk(self, req: Request) -> Optional[int]:
+    def _prefill_chunk(self, req: Request) -> _Unread:
         """One prefill chunk at ``req.prefill_pos``; advances it (None once
-        the prompt is fully prefilled). The chunk's program samples its
-        last position; only the last chunk's token is read, and returned:
-        a host read waits for the device. That chunk also leaves the
-        position's logits row in ``req.last_logits``, on the device."""
+        the prompt is fully prefilled) and returns the chunk's program,
+        unread. The program samples its last position; only the last
+        chunk's token is a token of the request, read by whoever is handed
+        the program (``_fetch``): a host read waits for the device, so
+        ``step()`` launches its decode program first. That chunk also
+        leaves the position's logits row in ``req.last_logits``, on the
+        device. An earlier chunk's is read only to wait for it
+        (``step()``: an engine with nothing to decode)."""
         page_size = self.cfg.model.page_size
         ph = self._phases
         with phase(ph, PHASE_STEP_INPUTS):
@@ -2406,15 +2493,10 @@ class MiniEngine:
             # Padding-waste accounting: len(chunk) real tokens rode a
             # seq-token padded dispatch (the power-of-two page bucket).
             self.telemetry.on_dispatch_tokens(len(chunk), seq)
-        if not last:
-            req.prefill_pos = pos + len(chunk)
-            return None
-        req.last_logits = row
-        req.prefill_pos = None
-        with self._fetch_phase() as sp:
-            picked = np.asarray(token)
-            self._device_counts(sp, picked[1:], len(chunk), "prefill")
-            return int(picked[0])
+        if last:
+            req.last_logits = row
+        req.prefill_pos = None if last else pos + len(chunk)
+        return _Unread(token, [req], 1, len(chunk), self._launch, "prefill")
 
     def _commit_full_blocks(self, req: Request,
                             upto: Optional[int] = None) -> None:
@@ -2581,46 +2663,96 @@ class MiniEngine:
         # Continuous batching: one prefill chunk for the oldest admitted-
         # but-not-yet-decoding request (FIFO — finish one prefill before
         # starting the next so TTFTs don't all pay for each other).
-        # Snapshot: _prefill_chunk → _finish_prefill → _finish mutates
-        # self._running for 1-token requests.
-        just_prefilled: Optional[str] = None
         with phase(ph, PHASE_STEP_SCHEDULE):
             prefill_req = self._pick_prefill()
+            # (Before the chunk below takes its last request out of it.)
+            prefilling = any(self.requests[rid].prefill_pos is not None
+                             for rid in self._running)
         if self._ragged:
             # Ragged scheduling: the prefill chunk and every active decode
             # row pack into one flat-axis dispatch (the prefill bootstrap
             # token still lands next step, exactly as on the padded path).
             emitted.update(self._ragged_step(prefill_req))
         else:
+            chunk = first = None  # this step's chunk; it, if it finishes
             if prefill_req is not None:
                 req = prefill_req
-                first_token = self._prefill_chunk(req)
+                chunk = self._prefill_chunk(req)
+                if req.prefill_pos is None:
+                    first = chunk
                 if (req.prefill_pos is not None and self.handoff is not None
                         and self.cfg.role == "prefill"):
                     # Prefill pod: commit this chunk's full blocks NOW so
                     # the transfer streams chunk-granular (the final
                     # chunk commits in _finish_prefill as usual).
                     self._commit_prefill_chunk(req)
-                if req.prefill_pos is None:
-                    self._finish_prefill(req, first_token)
-                    if req.output:
-                        emitted[req.request_id] = req.output[-1]
-                        # Its decode starts next step: including it in this
-                        # step's decode batch would overwrite the prefill
-                        # bootstrap token just emitted (a streaming caller
-                        # would lose one token).
-                        just_prefilled = req.request_id
-            active = [self.requests[rid] for rid in self._running
-                      if not self.requests[rid].done
-                      and self.requests[rid].prefill_pos is None
-                      and rid != just_prefilled]
-            for chunk_start in range(0, len(active), self.cfg.max_batch):
-                chunk = active[chunk_start:chunk_start + self.cfg.max_batch]
-                burst = self._burst
-                if burst > 1:
-                    emitted.update(self._decode_chunk_burst(chunk, burst))
-                else:
-                    emitted.update(self._decode_chunk(chunk))
+            # A request whose prefill finishes here starts to decode next
+            # step: in this step's decode batch it would overwrite the
+            # bootstrap token emitted below (a streaming caller would lose
+            # one token).
+            active = [req for req in map(self.requests.get, self._running)
+                      if not req.done and req.prefill_pos is None
+                      and (first is None or req is not first.rows[0])]
+            # (The jitted call stays one frame under ``step()``, as it
+            # was: see ``_prefill_chunk``.)
+            cur, self._unread = self._unread, None
+            one = len(active) <= self.cfg.max_batch
+            if cur is None and not (self._defers and one):
+                # Fused bursts (the scan samples for itself), sharded or
+                # hybrid engines (``_defers``), several chunks: each
+                # program is read before the next is built.
+                emitted.update(self._read_first(first))
+                for at in range(0, len(active), self.cfg.max_batch):
+                    chunk = active[at:at + self.cfg.max_batch]
+                    if self._burst > 1:
+                        emitted.update(
+                            self._decode_chunk_burst(chunk, self._burst))
+                    else:
+                        emitted.update(
+                            self._read_decode(self._launch_decode(chunk)))
+            else:
+                # ``cur``: the decode program this step returns the tokens
+                # of, launched a step ago or now. While this engine only
+                # decodes and has the chip to itself, the next one is
+                # launched before ``cur`` is read and takes its tokens on
+                # the device: the way back, this step's bookkeeping, the
+                # caller's and the next step's inputs then run beside a
+                # busy chip. Nothing else of step N+1 depends on N: a
+                # request stops by count, its pages were allocated at
+                # admission, its context grows by one. Both conditions are
+                # read from what the engine sees:
+                # - no request of its own in prefill (``prefilling``): a
+                #   program launched ahead never stands beside a chunk of
+                #   this engine, so the engine is at most one decode
+                #   program ahead of the device and never a chunk (a
+                #   chunk is twenty decode programs long, and whatever
+                #   arrives at the other replica waits behind it). A
+                #   request admitted while a program is in flight finds
+                #   that program read here as ``cur``: no drain;
+                # - no other engine's program between this engine's last
+                #   two decode programs or behind them (the launch
+                #   numbers: ``_launch_input``). Two busy replicas each
+                #   keep one program in the queue.
+                if cur is None and active:
+                    cur = self._launch_decode(active)
+                if (cur is not None and one and not prefilling
+                        and self._lone_decodes >= 2):
+                    # A row whose unread token is its last is left out.
+                    ahead = [req for req in active if not (
+                        len(req.output) + 1 >= req.max_new_tokens
+                        and cur.row_of(req) >= 0)]
+                    if ahead:
+                        self._unread = self._launch_decode(ahead, cur)
+                # A finishing chunk's token is read, and its blocks are
+                # committed, beside the decode program just launched.
+                emitted.update(self._read_first(first))
+                if cur is not None:
+                    emitted.update(self._read_decode(cur))
+            # (In a method of its own: with these lines here, every
+            # 28-layer program took 0.15 s longer to lower on the chip's
+            # host, 6 s of set-up: PERF.md section 6, PR 43; as with the
+            # helper frame of PR 31, why is not known.)
+            self._bound_chunks(chunk, bool(emitted))
         with phase(ph, PHASE_STEP_FINISH) as sp:
             for rid in list(self._running):
                 req = self.requests[rid]
@@ -2633,6 +2765,22 @@ class MiniEngine:
                 sp.set_attribute("programs", ph.programs)
                 sp.set_attribute("transfers", ph.transfers)
         return emitted
+
+    def _bound_chunks(self, chunk: Optional[_Unread], read: bool) -> None:
+        """The end of a padded ``step()`` that launched ``chunk`` (or none)
+        and ``read`` something or nothing. A step that read nothing behind
+        its chunk (nothing to decode, the prompt not finished) has waited
+        for nothing: left so, an engine sends a whole document's chunks
+        ahead of the device, as many as the runtime keeps in flight (32 of
+        113-180 ms on the chip: PERF.md section 6, PR 43), and whatever the
+        other replica launches next stands seconds behind them. It waits
+        for the chunk before this one: one chunk runs, one is queued behind
+        it, and the engine is no further ahead."""
+        behind, self._chunk_ahead = self._chunk_ahead, None
+        if chunk is not None and not read:
+            self._chunk_ahead = chunk
+            if behind is not None:
+                self._fetch(behind)
 
     def _drain_offload(self, target_job: Optional[int] = None):
         results = self._drain_offload_multi(
@@ -2717,6 +2865,13 @@ class MiniEngine:
             time.sleep(0.005)
 
     def _finish(self, req: Request, outcome: str = "finished") -> None:
+        rec = self._unread
+        if rec is not None and rec.row_of(req) >= 0:
+            # Ended with its token unread (an abort, a reset): the token is
+            # dropped, and the program has run before the pages go back.
+            self._drain(outcome)
+            if all(row.done for row in rec.rows):
+                self._unread = None
         if self.telemetry is not None:
             self.telemetry.on_finish(req.request_id, outcome)
         if req.handoff_deadline is not None:
@@ -2853,7 +3008,7 @@ class MiniEngine:
 
         out: dict[str, int] = {}
         if decode_rows or finishing:
-            with self._fetch_phase():
+            with self._fetch_phase(self._launch):
                 next_tokens = np.asarray(picked)
         if decode_rows:
             now = time.monotonic() if tel is not None else 0.0
@@ -2971,7 +3126,7 @@ class MiniEngine:
                 self._pools(), shapes=shapes, steps=steps)
             self._take_pools(pools)
             toks.copy_to_host_async()
-        with self._fetch_phase():
+        with self._fetch_phase(self._launch):
             toks_host = np.asarray(toks)
         out = {}
         now = time.monotonic() if self.telemetry is not None else 0.0
@@ -2987,6 +3142,15 @@ class MiniEngine:
         return out
 
     def _decode_chunk(self, chunk: list[Request]) -> dict[str, int]:
+        """One decode step of ``chunk``, read at once."""
+        return self._read_decode(self._launch_decode(chunk))
+
+    def _launch_decode(self, chunk: list[Request],
+                       unread: Optional[_Unread] = None) -> _Unread:
+        """Launch one decode step of ``chunk`` and leave its tokens on the
+        device. ``unread``: the decode program before it, whose tokens the
+        host has not taken into ``req.output`` yet: a row of it takes its
+        token from there, on the device, and stands one token further."""
         # Pad to max_batch so decode compiles exactly once regardless of the
         # active-request count; padded rows have new_lens=0 (all writes go
         # to the garbage page, logits ignored).
@@ -3020,6 +3184,18 @@ class MiniEngine:
                 slots = np.zeros((b,), np.int32)  # rows without: the spare
                 slots[:len(chunk)] = [req.state_slot for req in chunk]
                 state_args = (slots, [0, 0, 0])
+            # Launched ahead: the program before it is still unread.
+            ahead = unread is not None and unread.host is None
+            operand = {}
+            if self._defers:
+                # Every program of this form takes the last one's tokens,
+                # whether any row reads them or not: one program compiled.
+                src = np.full((b,), -1, np.int32)
+                if unread is not None:
+                    src[:len(chunk)] = [unread.row_of(req) for req in chunk]
+                    ctx[src >= 0] += 1
+                state_args += (src,)
+                operand["prev"] = self._prev
             packed, shapes = pack_inputs(
                 (last[:, None], tables, *swa_tables, ctx, new_lens,
                  *state_args))
@@ -3028,10 +3204,14 @@ class MiniEngine:
         with self._dispatch_phase(None, len(chunk), len(chunk), b,
                                   self._decode_forward) as sp:
             picked, _, pools = self._decode_forward(
-                self.params, self.cfg.model, self._launch_input(packed, sp),
-                self._pools(), shapes=shapes)
+                self.params, self.cfg.model,
+                self._launch_input(packed, sp, decode=True),
+                self._pools(), shapes=shapes, **operand)
             self._take_pools(pools)
             picked.copy_to_host_async()
+            if self._defers:
+                self._prev = picked
+                sp.set_attribute("ahead", int(ahead))
             if self.state_pool is not None:
                 sp.set_attribute("state_rows", len(chunk))
             topk = self.cfg.model.index_topk
@@ -3044,10 +3224,6 @@ class MiniEngine:
                     "index_keys", int(keys[keys > topk].sum()))
                 sp.set_attribute(
                     "selected_keys", int(np.minimum(keys, topk).sum()))
-        out = {}
-        with self._fetch_phase() as sp:
-            next_tokens = np.asarray(picked)
-            self._device_counts(sp, next_tokens[b:], len(chunk), "decode")
         tel = self.telemetry
         if tel is not None:
             # Padding-waste accounting for the padded path: len(chunk)
@@ -3055,8 +3231,19 @@ class MiniEngine:
             # from the ragged path, so the waste ratio directly compares
             # the two schedulers.
             tel.on_dispatch_tokens(len(chunk), b)
-        now = time.monotonic() if tel is not None else 0.0
-        for i, req in enumerate(chunk):
+            if ahead:
+                tel.on_launched_ahead()
+        return _Unread(picked, chunk, b, len(chunk), self._launch, "decode")
+
+    def _read_decode(self, rec: _Unread) -> dict[str, int]:
+        """Take a decode program's tokens into its rows: what ``step()``
+        returns of them. A row aborted since the launch drops its token."""
+        next_tokens = self._fetch(rec)
+        out = {}
+        now = time.monotonic() if self.telemetry is not None else 0.0
+        for i, req in enumerate(rec.rows):
+            if req.done:
+                continue
             req.computed_len += 1
             tok = int(next_tokens[i])
             req.output.append(tok)
@@ -3065,6 +3252,15 @@ class MiniEngine:
             if self.hybrid:
                 self._swa_reclaim(req)
         return out
+
+    def _read_first(self, first: Optional[_Unread]) -> dict[str, int]:
+        """A finishing prefill chunk's token read and its request
+        bootstrapped (``_finish_prefill``): what ``step()`` returns of it."""
+        if first is None:
+            return {}
+        req = first.rows[0]
+        self._finish_prefill(req, int(self._fetch(first)[0]))
+        return {req.request_id: req.output[-1]}
 
     def _release(self, req: Request) -> None:
         page_size = self.cfg.model.page_size

@@ -2466,12 +2466,22 @@ def step_program(body, static=(), tokens_out: bool = False, kept_row=None):
     device (``cfg.step_counters``), the counts follow the tokens in the
     same array, ``int32 [rows + len(cfg.step_counters)]``, so that they
     reach the host in the tokens' own transfer.
+    ``prev``: what the last program of this form returned as its tokens,
+    still on the device and not donated, for a step launched before the
+    host has read them (``MiniEngine._launch_decode``). ``packed`` then ends
+    in one vector more, ``src`` (``int32 [rows]``): a row takes its token
+    from row ``src`` of ``prev`` (an index: a row's place may differ from
+    one step to the next), or from ``packed`` as ever where ``src`` is -1.
     """
     counted = "counters" in inspect.signature(body).parameters
 
-    def program(params, cfg, packed, pools, shapes, keep_row=False,
-                token_sharding=None, **kw):
+    def program(params, cfg, packed, pools, shapes, prev=None,
+                keep_row=False, token_sharding=None, **kw):
         tokens, *rest = unpack_inputs(packed, shapes)
+        if prev is not None:
+            *rest, src = rest
+            tokens = jnp.where(src[:, None] >= 0,
+                               prev[jnp.maximum(src, 0)][:, None], tokens)
         if token_sharding is not None:
             tokens = jax.lax.with_sharding_constraint(tokens, token_sharding)
         counters = {} if counted and cfg.step_counters else None

@@ -257,6 +257,11 @@ class EngineTelemetry:
         self._dispatch_padded = 0
         self._dispatches = 0
         self._last_waste_ratio = 0.0
+        # Decode programs launched before the one before them was read
+        # (on_launched_ahead), and programs in flight that had to be
+        # waited for early, by what asked (on_lookahead_drained).
+        self._launched_ahead = 0
+        self._drained: Dict[str, int] = {}
 
     # -- lifecycle hooks (called by MiniEngine) ---------------------------
 
@@ -388,6 +393,16 @@ class EngineTelemetry:
         self._dispatches += 1
         self._last_waste_ratio = 1.0 - real / dispatched
 
+    def on_launched_ahead(self) -> None:
+        """A decode program launched while the tokens of the one before
+        it were still unread (``MiniEngine.step``)."""
+        self._launched_ahead += 1
+
+    def on_lookahead_drained(self, cause: str) -> None:
+        """A decode program in flight waited for before its step: an
+        abort, a reset, a copier that takes the pools (``cause``)."""
+        self._drained[cause] = self._drained.get(cause, 0) + 1
+
     # -- read side --------------------------------------------------------
 
     def _phase_stats(self, hist) -> dict:
@@ -421,6 +436,10 @@ class EngineTelemetry:
                 "padded_tokens_total": self._dispatch_padded,
                 "last_waste_ratio": self._last_waste_ratio,
                 "dispatches": self._dispatches,
+            },
+            "lookahead": {
+                "launched_ahead": self._launched_ahead,
+                "drained": dict(self._drained),
             },
             "last_profile": self.profiler.last,
         }

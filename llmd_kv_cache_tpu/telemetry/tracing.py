@@ -42,7 +42,6 @@ has its phases on.
 from __future__ import annotations
 
 import contextlib
-import itertools
 import os
 import random
 import re
@@ -711,9 +710,15 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
 # what it launched: ``program``, the jitted function's name (a device
 # trace calls the execution ``jit_<program>``), and ``launch``, the
 # program's ordinal among all that this process sent to the same device
-# (``EnginePhases.next_launch``); the ``step.fetch`` that waits for a
-# program's tokens carries that ``launch``, and a prefill chunk whose token
-# nobody reads has no fetch.
+# (``MiniEngine._launch_input``: numbered traced or not, because the engine
+# reads from the numbers whether it has the chip to itself); the
+# ``step.fetch`` that waits for a program's tokens carries that ``launch``,
+# and a prefill chunk behind which the step reads a decode program has no
+# fetch (where the step reads nothing, the chunk before it is fetched, to
+# wait for it: ``MiniEngine.step``). A padded decode
+# step's dispatch carries ``ahead``: 1 where it was launched before the
+# tokens of the decode program before it were read (it takes them on the
+# device), else 0; that program's fetch then follows a later dispatch.
 # ``route.*`` run inside ``KVAwareRouter.route`` and ``ingest`` inside
 # ``Pool.process_event_batch``, under an owner that is no engine
 # (``process_phases``), so they carry neither ``pod`` nor ``step`` of their
@@ -798,46 +803,30 @@ def process_phases() -> Optional[Phases]:
     return _process_phases
 
 
-# Device (as an engine was given it; None: JAX's default) → the count its
-# launches are numbered from. ``next()`` on it is one C call: no lock.
-_launch_counts: dict = {}
-
-
 class EnginePhases(Phases):
     """What one engine's phases share: whose they are, the ordinal of the
     ``step()`` they run in (0 before the first), and what that step has
     dispatched and moved to the device so far (``programs``: jitted calls,
     counted by the phases given ``programs=``; ``transfers``/``bytes``:
     counted by the engine's ``_to_dev``). Every phase carries ``pod`` and
-    ``step``; ``step.finish`` carries the step's totals. ``launch`` is the
-    ordinal of the last program this engine sent to ``device``, counted
-    over every engine of the process on that device. Touched by the one
+    ``step``; ``step.finish`` carries the step's totals. Touched by the one
     thread that owns the engine.
     """
 
-    __slots__ = ("pod", "step", "programs", "transfers", "bytes", "launch",
-                 "_launches")
+    __slots__ = ("pod", "step", "programs", "transfers", "bytes")
 
-    def __init__(self, pod: str, device=None):
+    def __init__(self, pod: str):
         global _process_phases
         super().__init__()
         self.pod = pod
         self.step = 0
-        self.programs = self.transfers = self.bytes = self.launch = 0
-        self._launches = _launch_counts.setdefault(device, itertools.count(1))
+        self.programs = self.transfers = self.bytes = 0
         if _process_phases is None:
             _process_phases = Phases()
 
     def begin_step(self) -> None:
         self.step += 1
         self.programs = self.transfers = self.bytes = 0
-
-    def next_launch(self) -> int:
-        """Number the program about to be launched: called as the last
-        thing before the jitted call, so that programs reach the device
-        in the order of their ordinals."""
-        self.launch = next(self._launches)
-        return self.launch
 
 
 class _Phase:

@@ -1,5 +1,8 @@
 """Mini serving engine tests: prefix caching, events, e2e indexer loop."""
 
+import dataclasses
+import types
+
 import numpy as np
 import pytest
 
@@ -619,3 +622,370 @@ class TestUnpipelinedDecodePadding:
                                   orig(real, padded)))
         eng.step()
         assert dispatches == [(1, 4)], dispatches
+
+
+# -- a lone engine launches its next decode step ahead ------------------------
+
+
+def _served_model(kind):
+    """``(cfg, params, engine options)`` of a toy model of each kind that
+    steps through the padded path: dense (XLA and the Pallas kernels), a
+    routed one whose programs count on the device behind their tokens, and
+    one that keeps a sequence state in a pool beside its pages."""
+    if kind in ("dense", "dense-pallas"):
+        pallas = True if kind == "dense-pallas" else None
+        return LlamaConfig.tiny(), None, dict(
+            use_pallas_decode=pallas, use_pallas_prefill=pallas)
+    from kvbench.harness import fleet, names
+
+    conf = names.config_for_run(names.benchmark(), {
+        "counters": "deepseek-v3.2-exp-ep16-l5",
+        "state": "gigachat3.5-ep16-l5"}[kind], rehearse=True)
+    cfg, params = fleet.build_model(conf, 11)
+    return cfg, params, {}
+
+
+def _decodes(seen):
+    """The attributes of the decode ``step.dispatch`` phases recorded."""
+    return [attrs for name, attrs, _ in seen
+            if name == "step.dispatch" and "prefill_pos" not in attrs]
+
+
+def _queue_depths(seen):
+    """Walk a recorded launch log (one engine's phases, in order) and hold
+    it to the queue-depth invariant: of either kind of program the engine
+    is never more than one ahead of the device. A fetch of launch L says
+    every program the engine launched up to L has run (a device runs them
+    in order), so what is in flight is what was launched since the last
+    fetch's L. At the moment any chunk is launched at most one decode
+    program is in flight (the one launched ahead before the request was
+    admitted) and at most one chunk (the one before it, where nothing was
+    read behind that); at a decode program's launch at most one other
+    decode program; and a ``step()`` that launches a chunk launches no
+    decode program ahead. Returns ``(chunks, the most decode programs and
+    the most chunks in flight at a chunk's launch)``."""
+    flying, chunk_steps, ahead_steps = [], set(), set()
+    chunks = decodes_deep = chunks_deep = 0
+    for name, attrs, _ in seen:
+        if name == "step.fetch":
+            flying = [(n, kind) for n, kind in flying if n > attrs["launch"]]
+        if name != "step.dispatch":
+            continue
+        kind = "chunk" if "prefill_pos" in attrs else "decode"
+        same = sum(1 for _, k in flying if k == kind)
+        assert same <= 1, (attrs, flying)
+        if kind == "chunk":
+            chunks += 1
+            chunk_steps.add(attrs["step"])
+            chunks_deep = max(chunks_deep, same)
+            decodes_deep = max(decodes_deep, len(flying) - same)
+            assert len(flying) - same <= 1, (attrs, flying)
+        elif attrs.get("ahead"):
+            ahead_steps.add(attrs["step"])
+        flying.append((attrs["launch"], kind))
+    assert not chunk_steps & ahead_steps
+    return chunks, decodes_deep, chunks_deep
+
+
+class TestLookAhead:
+    """``MiniEngine.step`` launches decode program N+1 before it reads N's
+    tokens (N+1 takes them on the device) while the engine only decodes and
+    has the chip to itself. Both are read from what the engine sees: no
+    request of its own in prefill, and the device's launch numbers, so a
+    second engine that launches in between holds it off: the synchronous
+    order."""
+
+    # (step, request, prompt tokens, new tokens): rows join and finish at
+    # different steps, beside rows that decode and in the stretches between;
+    # one finishes with its prefill, one a token later.
+    PLAN = ((0, "a", 37, 40), (0, "b", 7, 25), (14, "c", 70, 12),
+            (30, "d", 5, 1), (32, "e", 9, 2), (36, "f", 21, 6))
+
+    def _pair(self, kind, monkeypatch, **over):
+        from llmd_kv_cache_tpu.models import engine as engine_module
+        from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
+            EngineTelemetryConfig)
+
+        monkeypatch.setattr(engine_module, "_launch_counts", {})
+        cfg, params, options = _served_model(kind)
+        conf = dict(model=cfg, num_pages=128, max_pages_per_seq=32,
+                    max_batch=4, max_prefill_tokens=2 * cfg.page_size,
+                    telemetry=EngineTelemetryConfig(), **options)
+        conf.update(over)
+        eng = MiniEngine(EngineConfig(pod_identifier="pod-0", **conf),
+                         params=params)
+        other = MiniEngine(EngineConfig(pod_identifier="pod-1", **conf),
+                           params=eng.params)
+        from test_telemetry import _recorded
+
+        return eng, other, _recorded(eng._phases), cfg
+
+    def _serve(self, eng, cfg, other=None, after=None, plan=PLAN):
+        """Step ``eng`` through ``plan``; ``other`` launches a program
+        before each of its steps. Every call returns at most one token a
+        request, and exactly the tokens that reached ``req.output``."""
+        rng = np.random.default_rng(5)
+        prompts = {rid: rng.integers(1, cfg.vocab_size, n).tolist()
+                   for _, rid, n, _ in plan}
+        reqs, streams, step = {}, {}, 0
+        while step < 200 and (eng.requests or step <= plan[-1][0]):
+            for at, rid, _, new in plan:
+                if at == step:
+                    reqs[rid] = eng.enqueue(rid, prompts[rid],
+                                            max_new_tokens=new)
+            if other is not None:
+                other.enqueue(f"o{step}", prompts[plan[0][1]][:3],
+                              max_new_tokens=2)
+                while other.requests:
+                    other.step()
+            grew = {rid: len(req.output) for rid, req in reqs.items()}
+            emitted = eng.step()
+            for rid, req in reqs.items():
+                assert len(req.output) - grew[rid] == (rid in emitted)
+            for rid, token in emitted.items():
+                streams.setdefault(rid, []).append(token)
+            if after is not None:
+                after(step, reqs)
+            step += 1
+        assert not eng.requests
+        assert streams == {rid: req.output for rid, req in reqs.items()
+                           if req.output}
+        return {rid: list(req.output) for rid, req in reqs.items()}
+
+    @pytest.mark.parametrize(
+        "kind", ["dense", "dense-pallas", "counters", "state"])
+    def test_ahead_gives_the_synchronous_order_token_for_token(
+            self, kind, monkeypatch):
+        eng, other, seen, cfg = self._pair(kind, monkeypatch)
+        ahead = self._serve(eng, cfg)
+        decodes = _decodes(seen)
+        assert sum(d["ahead"] for d in decodes) > len(decodes) // 3
+        assert [len(ahead[rid]) for _, rid, _, _ in self.PLAN] == [
+            new for _, _, _, new in self.PLAN]
+        tel = eng.telemetry.debug_vars()["lookahead"]
+        assert tel == {"launched_ahead": sum(d["ahead"] for d in decodes),
+                       "drained": {}}
+        chunks, decodes_deep, chunks_deep = _queue_depths(seen)
+        assert chunks >= 9 and decodes_deep == chunks_deep == 1
+        assert eng._unread is None
+
+        held, other, seen, _ = self._pair(kind, monkeypatch)
+        assert held._launches is other._launches is not eng._launches
+        assert self._serve(held, cfg, other) == ahead
+        assert _decodes(seen) and not any(d["ahead"] for d in _decodes(seen))
+        assert held.telemetry.debug_vars()["lookahead"] == {
+            "launched_ahead": 0, "drained": {}}
+        assert (held.block_manager.pool_stats()
+                == eng.block_manager.pool_stats())
+
+    @pytest.mark.parametrize("kind", ["dense", "counters", "state"])
+    def test_a_rows_last_token_is_not_launched_past(self, kind, monkeypatch):
+        """A row stops by count: a program launched ahead leaves out the
+        row whose unread token is its last, so no row runs for nothing and
+        the last ``step()`` of a run launches nothing."""
+        plan = ((0, "a", 9, 12), (0, "b", 5, 7), (0, "c", 6, 3))
+        eng, _, seen, cfg = self._pair(kind, monkeypatch)
+        out = self._serve(eng, cfg, plan=plan)
+        assert [len(out[rid]) for rid in "abc"] == [12, 7, 3]
+        decodes = _decodes(seen)
+        # (Three admissions: three steps with a chunk.)
+        assert [d["ahead"] for d in decodes] == 3 * [0] + 8 * [1]
+        assert sum(d["rows"] for d in decodes) == sum(
+            new - 1 for _, _, _, new in plan)
+        last = max(attrs["step"] for _, attrs, _ in seen)
+        assert [name for name, attrs, _ in seen if attrs["step"] == last
+                and name.startswith("step.") and name not in (
+                    "step.offload_poll", "step.schedule")] == [
+                        "step.fetch", "step.finish"]
+        assert eng._unread is None
+
+    def test_an_engine_in_prefill_never_launches_ahead(self, monkeypatch):
+        """The case PR 42 let through: a prompt of many chunks admitted
+        beside a decoding row, the other engine idle. No step that launches
+        a chunk launches a decode program ahead; when the first chunk goes
+        out, the one program launched ahead before the admission is the
+        only one unread, and it is read in that step as any other (no
+        drain); from then on every chunk finds the queue empty."""
+        plan = ((0, "a", 6, 60), (8, "long", 90, 4))
+        eng, _, seen, cfg = self._pair("dense", monkeypatch)
+        unread_at_chunk = []
+        chunk = eng._prefill_chunk
+
+        def watched(req):
+            rec = eng._unread  # (taken by ``step()`` only after the chunk)
+            unread_at_chunk.append(
+                0 if rec is None or rec.host is not None else 1)
+            return chunk(req)
+
+        monkeypatch.setattr(eng, "_prefill_chunk", watched)
+        out = self._serve(eng, cfg, plan=plan)
+        assert [len(out["a"]), len(out["long"])] == [60, 4]
+        chunks, decodes_deep, chunks_deep = _queue_depths(seen)
+        assert chunks == len(unread_at_chunk) == 13
+        # (The chunk of the admission's own step, behind the program in
+        # flight, is the one chunk the next step's is launched behind.)
+        assert decodes_deep == chunks_deep == 1
+        assert unread_at_chunk == [0, 1] + 11 * [0]        # a's; long's 12
+        by_step = {}
+        for name, attrs, _ in seen:
+            if name == "step.dispatch":
+                by_step.setdefault(attrs["step"], []).append(
+                    "chunk" if "prefill_pos" in attrs else attrs["ahead"])
+        # Step 9 (the plan's 8) reads the program step 8 launched ahead and
+        # launches the chunk alone; steps 10-20 are synchronous: a chunk,
+        # then the decode program, read before the step returns.
+        assert by_step[8] == [1] and by_step[9] == ["chunk"]
+        assert all(by_step[step] == ["chunk", 0] for step in range(10, 21))
+        # The first step without a prefill is synchronous and launches
+        # ahead again at once: the engine was alone all along.
+        assert by_step[21] == [0, 1] and by_step[22] == [1]
+        assert eng.telemetry.debug_vars()["lookahead"]["drained"] == {}
+
+        held, other, _, _ = self._pair("dense", monkeypatch)
+        assert self._serve(held, cfg, other, plan=plan) == out
+
+    @pytest.mark.parametrize("kind", ["dense", "state"])
+    def test_an_engine_with_nothing_to_decode_is_one_chunk_ahead(
+            self, kind, monkeypatch):
+        """A prompt of many chunks and no row to decode: a step reads
+        nothing of its own, so it waits for the chunk before the one it
+        launched. One chunk runs, one is queued, and the engine is no
+        further ahead of the device (left alone it sent a whole document's
+        chunks ahead, and the other replica's next program stood behind
+        them all)."""
+        eng, _, seen, cfg = self._pair(kind, monkeypatch)
+        chunk = 2 * cfg.page_size
+        out = self._serve(eng, cfg, plan=((0, "long", 12 * chunk - 3, 3),))
+        assert len(out["long"]) == 3
+        assert _queue_depths(seen) == (12, 0, 1)
+        log = [("chunk" if "prefill_pos" in a else "decode", a["launch"])
+               if name == "step.dispatch" else ("fetch", a["launch"])
+               for name, a, _ in seen
+               if name in ("step.dispatch", "step.fetch")]
+        # Chunk k+1 goes out, then chunk k is waited for; the last one's
+        # token is the request's first, read in its own step.
+        assert log[:5] == [("chunk", 1), ("chunk", 2), ("fetch", 1),
+                           ("chunk", 3), ("fetch", 2)]
+        assert log[21:] == [("chunk", 12), ("fetch", 12), ("decode", 13),
+                            ("fetch", 13), ("decode", 14), ("fetch", 14)]
+        assert eng._chunk_ahead is None
+
+    def test_two_engines_in_turn_never_launch_ahead(self, monkeypatch):
+        a, b, seen, cfg = self._pair("dense", monkeypatch)
+        b._phases._annotation = a._phases._annotation
+        rng = np.random.default_rng(9)
+        for eng in (a, b):
+            eng.enqueue("r", rng.integers(1, 256, 11).tolist(),
+                        max_new_tokens=8)
+        while a.requests or b.requests:
+            for eng in (a, b):
+                if eng.requests:
+                    eng.step()
+                    assert eng._unread is None
+        assert {d["pod"] for d in _decodes(seen)} == {"pod-0", "pod-1"}
+        assert [d["ahead"] for d in _decodes(seen)] == 14 * [0]
+        # Left alone, the one that still decodes goes ahead.
+        a.enqueue("s", rng.integers(1, 256, 11).tolist(), max_new_tokens=8)
+        while a.requests:
+            a.step()
+        assert [d["ahead"] for d in _decodes(seen)[14:]] == [
+            0, 0, 1, 1, 1, 1, 1]
+
+    @pytest.mark.parametrize("how", ["abort", "reset", "offload"])
+    def test_ending_a_request_under_a_program_in_flight(self, how,
+                                                        monkeypatch):
+        """The program is waited for first (``lookahead.drained`` names
+        what asked), an ended row's unread token is dropped, and the pools
+        read as a synchronous engine's."""
+        plan = ((0, "a", 7, 9), (0, "b", 5, 9))
+
+        class _Copiers:  # what ``_sync_caches_to_copier`` hands the pools
+            copier = types.SimpleNamespace(k_cache=None, v_cache=None)
+
+        def end_one(eng, in_flight):
+            def after(step, reqs):
+                if step != 4:
+                    return
+                assert (eng._unread is not None) == in_flight
+                assert len(reqs["a"].output) == 5
+                if how == "abort":
+                    assert eng.abort_request("a")
+                    assert (eng._unread is not None) == in_flight
+                elif how == "reset":
+                    eng.reset_cache()
+                    assert eng._unread is None and eng.step() == {}
+                else:
+                    monkeypatch.setattr(eng, "offload_handlers", _Copiers)
+                    eng._sync_caches_to_copier()
+                    assert _Copiers.copier.k_cache is eng.k_cache
+                    # Waited for, and still this engine's to return.
+                    assert (eng._unread is not None) == in_flight
+                    assert not in_flight or eng._unread.host is not None
+                    monkeypatch.setattr(eng, "offload_handlers", None)
+                assert len(reqs["a"].output) == 5       # not taken early
+            return after
+
+        eng, _, _, cfg = self._pair("dense", monkeypatch)
+        ahead = self._serve(eng, cfg, after=end_one(eng, True), plan=plan)
+        held, other, _, _ = self._pair("dense", monkeypatch)
+        assert self._serve(held, cfg, other, after=end_one(held, False),
+                           plan=plan) == ahead
+        assert len(ahead["a"]) == (9 if how == "offload" else 5)
+        assert len(ahead["b"]) == (4 if how == "reset" else 9)
+        assert (eng.block_manager.pool_stats()
+                == held.block_manager.pool_stats())
+        assert eng.block_manager.num_free() == held.block_manager.num_free()
+        assert eng.telemetry.debug_vars()["lookahead"]["drained"] == {
+            "offload" if how == "offload" else "aborted": 1}
+        assert held.telemetry.debug_vars()["lookahead"]["drained"] == {}
+
+    @pytest.mark.parametrize("kind", ["burst", "sharded", "hybrid"])
+    def test_an_engine_that_cannot_defer_takes_no_prev(self, kind,
+                                                       monkeypatch):
+        """Fused bursts, a mesh and window pages keep the synchronous
+        order and the programs they had: no ``prev`` operand, no ``src``
+        in the packed inputs, no ``ahead`` on a dispatch, nothing unread
+        between steps."""
+        import jax
+        from jax.sharding import Mesh
+
+        from llmd_kv_cache_tpu.models import engine as engine_module
+        from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
+            EngineTelemetryConfig)
+
+        monkeypatch.setattr(engine_module, "_launch_counts", {})
+        tiny = LlamaConfig.tiny()
+        mesh = None
+        over = {}
+        if kind == "burst":
+            over = dict(decode_burst=4)
+        elif kind == "sharded":
+            mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("tp",))
+        else:
+            tiny = dataclasses.replace(tiny, sliding_window=8,
+                                       swa_layers=(1,))
+            over = dict(num_swa_pages=64)
+        eng = MiniEngine(EngineConfig(
+            model=tiny, num_pages=64, max_pages_per_seq=16, max_batch=2,
+            pod_identifier="p", telemetry=EngineTelemetryConfig(), **over),
+            seed=0, mesh=mesh)
+        assert eng._defers is False and eng._prev is None
+        from test_telemetry import _recorded
+
+        seen = _recorded(eng._phases)
+        calls = []
+        for name in ("_decode_forward", "_decode_multi"):
+            program = getattr(eng, name, None)
+            if program is not None:
+                monkeypatch.setattr(eng, name, lambda *a, _p=program, **kw: (
+                    calls.append(kw), _p(*a, **kw))[1])
+        eng.enqueue("a", list(range(1, 8)), max_new_tokens=9)
+        eng.enqueue("b", list(range(9, 14)), max_new_tokens=6)
+        while eng.requests:
+            eng.step()
+            assert eng._unread is None
+        assert calls and not any("prev" in kw for kw in calls)
+        assert _decodes(seen) and not any(
+            "ahead" in d for d in _decodes(seen))
+        assert eng.telemetry.debug_vars()["lookahead"] == {
+            "launched_ahead": 0, "drained": {}}

@@ -76,6 +76,62 @@ def test_a_slice_cut_at_both_ends(numbered):
         ("pod-1", DECODE, False)]              # its fetch was cut
 
 
+def lone_ahead(cycles=8):
+    """One replica decoding alone and a step ahead (PR 43): its programs
+    run back to back, each dispatched while the one before it runs, and a
+    program's fetch opens after the NEXT program's dispatch closed."""
+    from kvbench.tests.test_launches import Slice
+
+    s = Slice()
+    for i in range(cycles):
+        t = 6.0 * i
+        first = i == 0
+        s.launch("pod-0", DECODE, (0.0, 0.5) if first else (t - 5.4, t - 4.9),
+                 (t + (0.6 if first else 0.0), t + 6.0),
+                 fetch=(t + 1.1, t + 6.3), step=i + 1)
+        s.host[-2].stats["ahead"] = int(not first)
+    # As the host plane holds them: a fetch behind the later dispatch.
+    s.host.sort(key=lambda e: e.start)
+    return s
+
+
+def test_a_fetch_that_follows_a_later_dispatch_pairs_by_launch():
+    """The pairing reads no more ``unpaired`` and no more ``clock_fault``
+    from a lone replica a step ahead than from the synchronous order."""
+    names = [e.name for e in lone_ahead().host]
+    assert names[:4] == ["step.dispatch", "step.dispatch", "step.fetch",
+                         "step.dispatch"]
+    found = _launches.of(lone_ahead().run())
+    sync = _launches.of(lone().run())
+    assert (found.programs, found.unpaired, found.clock_faults,
+            found.offset) == (sync.programs, sync.unpaired,
+                              sync.clock_faults, sync.offset) == (8, 0, 0, 0)
+    assert owners(found) == 8 * [("pod-0", DECODE, True)]
+    assert [p.fetch.stats["launch"] for p in found.pairs] == [
+        p.dispatch.stats["launch"] for p in found.pairs]
+    # All but the first found the chip busy: no gap is a lone round trip.
+    assert [p.waited_from == p.dispatch.start for p in found.pairs] == [
+        True] + 7 * [False]
+    assert found.lone_gaps_ms() == []
+
+
+def test_launched_ahead_share():
+    from kvbench.harness import names
+    from kvbench.harness.loop import Run
+
+    reader = names.metric("launched_ahead_share")
+    assert reader.compute(Run(seconds=1.0)) is None            # untraced
+    assert reader.compute(lone_ahead().run()) == pytest.approx(87.5)
+    # A program older than ``ahead`` (the parent): counts are 0, not None.
+    assert reader.compute(lone().run()) == 0.0
+    assert reader.compute(lone(numbered=False).run()) == 0.0
+    # A prefill chunk is no decode program.
+    assert reader.compute(chunks_ahead().run()) == 0.0
+    s = chunks_ahead()
+    s.host[-2].stats["ahead"] = 1
+    assert reader.compute(s.run()) == 100.0
+
+
 def test_a_missing_dispatch_leaves_a_numbered_hole():
     """A dispatch the host plane lost: by ``launch`` the programs after it
     keep their owners; by order alone they would each take the next one's."""

@@ -530,7 +530,8 @@ class TestEndToEndTrace:
 from llmd_kv_cache_tpu.telemetry import tracing  # noqa: E402
 
 
-def _phase_engine(telemetry=True, ragged=False, sink=None, **over):
+def _phase_engine(telemetry=True, ragged=False, sink=None, device=None,
+                  **over):
     from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
     from llmd_kv_cache_tpu.models.llama import LlamaConfig
     from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
@@ -543,7 +544,8 @@ def _phase_engine(telemetry=True, ragged=False, sink=None, **over):
                pod_identifier="pod-x", ragged_attention=ragged,
                telemetry=EngineTelemetryConfig() if telemetry else None)
     cfg.update(over)
-    return MiniEngine(EngineConfig(**cfg), event_sink=sink), tiny
+    return MiniEngine(EngineConfig(**cfg), event_sink=sink,
+                      device=device), tiny
 
 
 def _drain(eng, limit=200):
@@ -721,7 +723,8 @@ class TestEnginePhases:
             tracing.PHASE_ENQUEUE_LOOKUP]
         assert sorted(by_step) == list(range(1, steps + 1))
         names_seen = set()
-        for step, phases in by_step.items():
+        launched = fetched = 0
+        for step, phases in sorted(by_step.items()):
             names = [n for n, _ in phases]
             names_seen.update(names)
             count = names.count
@@ -733,10 +736,15 @@ class TestEnginePhases:
             # Once per program dispatched; a program whose tokens the
             # host reads is fetched once. Every program samples inside
             # itself: nothing is dispatched besides the step programs.
+            # A lone engine launches a decode program a step ahead, so a
+            # step may read a program an earlier step launched: its last
+            # one launches nothing.
             programs = count(tracing.PHASE_STEP_DISPATCH)
-            assert programs >= 1
+            fetched += count(tracing.PHASE_STEP_FETCH)
+            launched += programs
+            assert programs + count(tracing.PHASE_STEP_FETCH) >= 1
             assert count(tracing.PHASE_STEP_INPUTS) == programs
-            assert count(tracing.PHASE_STEP_FETCH) <= programs
+            assert fetched <= launched
             assert set(names) <= set(tracing.PHASE_NAMES)
             finish = phases[-1][1]
             assert finish["programs"] == programs == sum(
@@ -808,8 +816,12 @@ class TestEnginePhases:
         """A decode-only ``step()`` dispatches one program fed by one
         transfer; a step in which a prefill finishes, two and two on the
         padded path (the chunk's program, then the decode program), one and
-        one on the ragged path. After a prefill ``last_logits`` is the
-        ``[vocab]`` float32 row the benchmark's probe reads."""
+        one on the ragged path, and never a decode program ahead; and
+        where a padded engine that only decodes and has the device to
+        itself enters its look-ahead, the next step's decode program too,
+        after which a step is one and one again. After a prefill
+        ``last_logits`` is the ``[vocab]`` float32 row the benchmark's
+        probe reads."""
         over = dict(xla={}, ragged=dict(ragged=True), pallas=dict(
             use_pallas_decode=True, use_pallas_prefill=True))[backend]
         eng, tiny = _phase_engine(**over)
@@ -845,12 +857,29 @@ class TestEnginePhases:
         both = 1 if backend == "ragged" else 2
         assert (programs, transfers, moved) == (both, both, [1] * both)
         assert set(emitted) == {"a", "b"}
+        if backend != "ragged":
+            # A request in prefill: nothing is launched ahead.
+            assert [a.get("ahead") for n, a, _ in seen
+                    if n == tracing.PHASE_STEP_DISPATCH] == [None, 0]
+            assert eng._unread is None
         row_b = np.asarray(b.last_logits, np.float32)
         assert row_b.shape == (tiny.vocab_size,)
         assert b.output == [int(row_b.argmax())]
         # a's row is still the one its prefill left.
         np.testing.assert_array_equal(
             np.asarray(a.last_logits, np.float32), row)
+        # Step 4: both decode; the padded engine launches the next step's
+        # program too (without b, whose unread token is its last) ...
+        emitted, programs, transfers, moved = finish_of_step()
+        assert (programs, transfers, moved) == (both, both, [1] * both)
+        assert set(emitted) == {"a", "b"} and len(a.output) == 4
+        if backend != "ragged":
+            assert [(a["ahead"], a["rows"]) for n, a, _ in seen
+                    if n == tracing.PHASE_STEP_DISPATCH] == [(0, 2), (1, 1)]
+        # ... and from then on a step is one program launched, one read.
+        emitted, programs, transfers, moved = finish_of_step()
+        assert (programs, transfers, moved) == (1, 1, [1])
+        assert set(emitted) == {"a"} and len(a.output) == 5
         _drain(eng)
         assert len(a.output) == 6 and len(b.output) == 2
 
@@ -901,14 +930,14 @@ class TestLaunches:
         the next ``launch`` of the device, whichever engine it is; a fetch
         names the launch it waits for; a chunk nobody reads has none;
         ``program`` is the name the jit gives the module."""
-        from llmd_kv_cache_tpu.models import llama
+        from llmd_kv_cache_tpu.models import engine, llama
 
-        monkeypatch.setattr(tracing, "_launch_counts", {})
+        monkeypatch.setattr(engine, "_launch_counts", {})
         over = dict(xla={}, ragged=dict(ragged=True), pallas=dict(
             use_pallas_decode=True, use_pallas_prefill=True))[backend]
         a, tiny = _phase_engine(pod_identifier="pod-a", **over)
         b, _ = _phase_engine(pod_identifier="pod-b", **over)
-        assert a._phases._launches is b._phases._launches
+        assert a._launches is b._launches
         a._phases._annotation = b._phases._annotation = ann = _Annotations()
         page = tiny.page_size
         # Three pages at two a chunk: the first chunk's token is not read.
@@ -947,21 +976,33 @@ class TestLaunches:
                 for d in dispatches)
 
     def test_a_device_has_its_own_count(self, monkeypatch):
-        monkeypatch.setattr(tracing, "_launch_counts", {})
-        one, other = tracing.EnginePhases("p", "chip-0"), tracing.EnginePhases(
-            "q", "chip-1")
-        same = tracing.EnginePhases("r", "chip-0")
-        assert [one.next_launch(), other.next_launch(), same.next_launch(),
-                one.next_launch()] == [1, 1, 2, 3]
-        assert (one.launch, other.launch, same.launch) == (3, 1, 2)
+        from llmd_kv_cache_tpu.models import engine
 
-    def test_off_counts_nothing(self, monkeypatch):
-        monkeypatch.setattr(tracing, "_launch_counts", {})
+        import jax
+
+        monkeypatch.setattr(engine, "_launch_counts", {})
+        cpu = jax.devices()[0]
+        one, _ = _phase_engine(device=cpu)
+        other, _ = _phase_engine()         # JAX's default: a key of its own
+        same, _ = _phase_engine(device=cpu)
+        assert one._launches is same._launches is not other._launches
+        packed = np.zeros(1, np.int32)
+        assert [eng._launch_input(packed, tracing.NOOP_SPAN) is not None
+                and eng._launch for eng in (one, other, same, one)] == [
+                    1, 1, 2, 3]
+        assert (one._launch, other._launch, same._launch) == (3, 1, 2)
+
+    def test_off_numbers_and_opens_nothing(self, monkeypatch):
+        """An untraced engine numbers its programs too (it reads from the
+        numbers whether it has the chip to itself) and opens no phase."""
+        from llmd_kv_cache_tpu.models import engine
+
+        monkeypatch.setattr(engine, "_launch_counts", {})
         eng, tiny = _phase_engine(telemetry=False)
         eng.enqueue("a", list(range(1, 2 * tiny.page_size)), max_new_tokens=2)
         _drain(eng)
-        assert tracing._launch_counts == {}
-        assert eng._fetch_phase() is tracing._NOOP_CM
+        assert eng._launch == 2 and eng._phases is None
+        assert eng._fetch_phase(eng._launch) is tracing._NOOP_CM
 
 
 # -- the router's and the pool's phases ---------------------------------------
