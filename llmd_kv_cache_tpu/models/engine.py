@@ -44,6 +44,7 @@ from ..events.model import (
     GenericEvent,
 )
 from ..ops import sparse_index
+from ..ops.pallas_latent_prefill import per_head_expanded_keys
 from ..ops.pallas_paged_attention import (
     head_dim_supported as _pallas_head_dim_supported,
 )
@@ -83,6 +84,7 @@ from .llama import (
     init_params,
     init_state_pool,
     pack_inputs,
+    prefill_per_head,
     step_decode_pallas,
     step_decode_pallas_state,
     step_decode_steps,
@@ -1488,10 +1490,30 @@ class MiniEngine:
         if req is None:
             return phase(ph, PHASE_STEP_DISPATCH, programs=1, rows=rows,
                          tokens=tokens, padded=padded, **named)
+        if named and self.cfg.model.is_mla:
+            named["expanded_keys"] = self._expanded_keys(
+                program, req.prefill_pos, tokens, padded)
         return phase(ph, PHASE_STEP_DISPATCH, traceparent, programs=1,
                      rows=rows, tokens=tokens, padded=padded,
                      request_id=req.request_id, prefill_pos=req.prefill_pos,
                      process=self.cfg.pod_identifier, **named)
+
+    def _expanded_keys(self, program, pos: int, tokens: int,
+                       padded: int) -> int:
+        """``expanded_keys`` of a latent model's prefill dispatch: the key
+        positions a head expands a latent layer where ``program``, handed
+        ``tokens`` tokens at ``pos`` padded to ``padded``, attends per head
+        (``llama.prefill_per_head``: the chunk program's rule, from its
+        shapes); 0 where it holds the absorbed kernel."""
+        if (program is not self._prefill_forward or self._pp > 1
+                or self._attention_backends["prefill"]["backend"] != "pallas"
+                or not prefill_per_head(
+                    self.cfg.model, padded,
+                    self.mesh if self._tp > 1 else None)):
+            return 0
+        return per_head_expanded_keys(
+            pos + tokens, self.cfg.model.page_size,
+            self.cfg.max_pages_per_seq)
 
     def _launch_input(self, packed, sp, decode: bool = False):
         """A step program's packed inputs on the device, the program

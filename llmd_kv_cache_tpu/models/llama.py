@@ -1761,12 +1761,12 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
             with jax.named_scope(SCOPE_ATTENTION):
                 if tails is not None:
                     extra = tail_kwargs(tail_ks[g][lj], tail_ks[g][lj])
-                ctx = attention_fn(
-                    q_eff, k_caches[g], v_stack, lj, table, positions,
-                    total_lens, None, **extra,
-                )
-                attn = jnp.einsum("bshr,hrv->bshv", ctx[..., :r],
-                                  layer["w_uv"])
+                # Absorbed, or per head where ``attention_fn`` brings a
+                # ``per_head_fn`` (a prefill chunk of enough queries).
+                queries = (q_eff, q_nope, q_rope)
+                attn = _latent_attention(
+                    attention_fn, queries, layer, k_caches[g], v_stack, lj,
+                    table, positions, total_lens, **extra)
         else:
             with jax.named_scope(SCOPE_QKV):
                 attn_in = _rms_norm(x, layer["attn_norm"], cfg.norm_eps,
@@ -2355,6 +2355,7 @@ def forward_prefill_pallas(
             bias=bias, interpret=interpret,
         )
 
+    _offer_per_head(attention_fn, cfg, seq, mesh, ctx_lens, interpret)
     return _forward_impl(
         params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
         attention_fn, last_only=last_only, kernel={"interpret": interpret},
@@ -2571,3 +2572,82 @@ step_decode_steps = step_program(
     forward_decode_steps.__wrapped__, _BURST_STATIC, tokens_out=True)
 step_decode_steps_hybrid = step_program(
     forward_decode_steps_hybrid.__wrapped__, _BURST_STATIC, tokens_out=True)
+
+
+# -- a latent layer's attention: absorbed, or per head ----------------------
+# Everything of the per-head form lives down here, in functions of their
+# own, and reaches the shared body as an attribute of ``attention_fn``: no
+# new parameter, local or longer expression in ``_forward_impl_grouped``,
+# ``_forward_impl`` or ``forward_prefill_pallas``, and the latter's
+# ``attention_fn`` left as it was (the selection's lines stand twice). Those
+# frames are under every op of every program of every model while it is
+# traced, and their sizes decide which call sites of tracing and lowering
+# fall on the edge of one of the interpreter's 16 KiB frame chunks: two slots
+# more in each cost the dense cells, which run none of this, 2-4 s of set-up
+# (PERF.md §6, PR 47; ``hack/frame_sizes.py`` compares two trees).
+
+
+def _latent_attention(attention_fn, queries, layer, *pages_and_lens, **extra):
+    """The heads' values ``[b, seq, heads, v]`` of a latent-attention
+    layer from ``queries = (q_eff, q_nope, q_rope)``. Absorbed:
+    ``attention_fn`` on the absorbed query ``q_eff`` over the latent, the
+    context un-absorbed through ``w_uv``. Per head, where ``attention_fn``
+    brings a ``per_head_fn`` (``_offer_per_head``): that, on the heads' own
+    queries."""
+    q_eff, q_nope, q_rope = queries
+    per_head_fn = getattr(attention_fn, "per_head_fn", None)
+    if per_head_fn is not None:
+        return per_head_fn(q_nope, q_rope, layer, *pages_and_lens, **extra)
+    ctx = attention_fn(q_eff, *pages_and_lens, None, **extra)
+    return jnp.einsum("bshr,hrv->bshv", ctx[..., :layer["w_uv"].shape[1]],
+                      layer["w_uv"])
+
+
+def prefill_per_head(cfg: LlamaConfig, seq: int, mesh=None) -> bool:
+    """Whether ``forward_prefill_pallas`` attends a chunk padded to ``seq``
+    queries per head, on keys and values expanded from the latents inside
+    the kernel (``ops.pallas_latent_prefill``), and not in the absorbed
+    form: a latent model, no mesh, and queries enough that expanding a key
+    once a head costs less than multiplying the page's width for every
+    query. From shapes alone; a program holds one of the two kernels."""
+    from ..ops.pallas_latent_prefill import per_head_min_queries
+
+    return (cfg.is_mla and mesh is None
+            and seq >= per_head_min_queries(
+                cfg.kv_cache_head_dim, cfg.kv_lora_rank, cfg.head_dim,
+                cfg.head_dim))
+
+
+def _offer_per_head(attention_fn, cfg, seq, mesh, ctx_lens, interpret):
+    """Hang the chunk's per-head form on ``forward_prefill_pallas``'s
+    ``attention_fn`` where the rule (``prefill_per_head``) takes it."""
+    if prefill_per_head(cfg, seq, mesh):
+        attention_fn.per_head_fn = partial(
+            _per_head_prefill_attention, cfg=cfg, ctx_lens=ctx_lens,
+            interpret=interpret)
+
+
+def _per_head_prefill_attention(q_nope, q_rope, layer, k_stack, v_stack,
+                                layer_idx, table, positions, total_lens,
+                                index=None, *, cfg, ctx_lens, interpret):
+    from ..ops.pallas_latent_prefill import pallas_per_head_prefill_attention
+
+    bias = None
+    if index is not None and table.shape[1] * cfg.page_size > cfg.index_topk:
+        # The chunk's selection, as the absorbed form's ``attention_fn``
+        # makes it: v_stack is the index stream.
+        with jax.named_scope(SCOPE_INDEX):
+            scores = sparse_index.dsa_index_scores(
+                *index, sparse_index.gather_index_keys(
+                    v_stack, layer_idx, table),
+                total_lens, interpret=interpret)
+        with jax.named_scope(SCOPE_SELECT):
+            bias = sparse_index.dsa_keep_bias(
+                scores, positions, total_lens, topk=cfg.index_topk,
+                interpret=interpret)
+    return pallas_per_head_prefill_attention(
+        q_nope, q_rope, layer["w_uk"], layer["w_uv"], k_stack, table,
+        ctx_lens, total_lens,
+        scale=((cfg.head_dim + cfg.qk_rope_head_dim) ** -0.5
+               * cfg.softmax_scale_mult),
+        layer_idx=layer_idx, bias=bias, interpret=interpret)

@@ -706,7 +706,14 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
 # and, where the model has them, what the program reads a layer: a decode
 # step that selects ``index_keys`` and ``selected_keys``, a prefill chunk
 # that selects ``threshold_keys`` (``ops.sparse_index``); with linear layers
-# a decode step ``state_rows``, a prefill chunk ``scan_tokens``. It names
+# a decode step ``state_rows``, a prefill chunk ``scan_tokens``. A latent
+# model's prefill chunk carries ``expanded_keys``
+# (``MiniEngine._dispatch_phase``, from the chunk program's own rule,
+# ``llama.prefill_per_head``): the key positions a head expands a latent
+# layer (whole superblocks up to the chunk's end) where the chunk is padded
+# to enough queries to attend per head (``ops.pallas_latent_prefill``), 0
+# where the program holds the absorbed kernel (a short chunk, a mesh, the
+# ragged scheduler's chunk, the XLA prefill). It names
 # what it launched: ``program``, the jitted function's name (a device
 # trace calls the execution ``jit_<program>``), and ``launch``, the
 # program's ordinal among all that this process sent to the same device

@@ -159,9 +159,9 @@ def model():
 
 
 def engine(model, **kw) -> MiniEngine:
-    return MiniEngine(EngineConfig(
+    return MiniEngine(EngineConfig(**{**dict(
         model=model.cfg, num_pages=64, max_pages_per_seq=16, max_batch=4,
-        max_prefill_tokens=64, **kw), params=model.params)
+        max_prefill_tokens=64), **kw}), params=model.params)
 
 
 def serve(eng, rid, prompt, new=1):
@@ -205,6 +205,32 @@ def test_prefill_in_unequal_chunks_and_decode_through_the_pool(model,
     for token, answers in zip(out, alts):
         assert min(float((a.max() - a[token]) / np.abs(a).max())
                    for a in answers) < TOLERANCE
+
+
+def test_a_full_chunk_of_the_gated_layer_attends_per_head(model):
+    """400 tokens as one chunk padded to 512: at the toy widths (latent 64
+    + rope 32 in pages of 96 lanes, heads of 64) the full layer's chunk
+    attends per head from 333 queries on, with no selection and its gate
+    on the heads' values as ever. The logits agree with the reference's
+    and with the absorbed program's (chunks of 64)."""
+    from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
+        EngineTelemetryConfig)
+    from tests.test_telemetry import _recorded
+
+    assert llama.prefill_per_head(model.cfg, 512)
+    assert not llama.prefill_per_head(model.cfg, 256)
+    prompt = prompt_of(400, 12)
+    pallas = dict(use_pallas_decode=True, use_pallas_prefill=True,
+                  max_pages_per_seq=32)
+    eng = engine(model, max_prefill_tokens=512,
+                 telemetry=EngineTelemetryConfig(), **pallas)
+    seen = _recorded(eng._phases)
+    _, per_head = serve(eng, "per-head", prompt)
+    assert [a["expanded_keys"] for n, a, _ in seen
+            if n == "step.dispatch" and "prefill_pos" in a] == [512]
+    assert nearest(model, prompt, 399, per_head) < TOLERANCE
+    _, absorbed = serve(engine(model, **pallas), "absorbed", prompt)
+    assert np.abs(per_head - absorbed).max() / np.abs(absorbed).max() < SAME
 
 
 def test_a_hit_through_each_rule_that_leaves_a_snapshot(model):

@@ -132,6 +132,35 @@ def test_launched_ahead_share():
     assert reader.compute(s.run()) == 100.0
 
 
+def test_prefill_per_head_share():
+    """Of the slice's chunks (dispatches that name a ``prefill_pos``), those
+    whose ``expanded_keys`` is above 0; and which cells report it."""
+    from kvbench.harness import names
+    from kvbench.harness.loop import Run
+
+    reader = names.metric("prefill_per_head_share")
+    assert reader.compute(Run(seconds=1.0)) is None            # untraced
+    assert reader.compute(lone().run()) is None                # no chunk
+    # A program older than ``expanded_keys`` (the parent): 0, not None.
+    assert reader.compute(chunks_ahead().run()) == 0.0
+    s = chunks_ahead()
+    chunks = [e for e in s.host if e.name == "step.dispatch"
+              and "prefill_pos" in e.stats]
+    assert len(chunks) >= 2
+    for e in chunks:
+        e.stats["expanded_keys"] = 0        # the absorbed kernel ran
+    assert reader.compute(s.run()) == 0.0
+    chunks[0].stats["expanded_keys"] = 25_600
+    assert reader.compute(s.run()) == pytest.approx(100.0 / len(chunks))
+    bench = names.benchmark()
+    reporting = {w["name"] for w in bench["workloads"]
+                 if "prefill_per_head_share" in {
+                     m["name"] for m in names.cell_metrics(
+                         bench, w["name"], True)}}
+    assert reporting == {"deepseek-v3.2-exp-ep16-l5.doc-reask-32k",
+                         "gigachat3.5-ep16-l5.sessions-32k"}
+
+
 def test_a_missing_dispatch_leaves_a_numbered_hole():
     """A dispatch the host plane lost: by ``launch`` the programs after it
     keep their owners; by order alone they would each take the next one's."""

@@ -70,6 +70,15 @@ def test_a_traced_cell_pairs_programs_launched_ahead(cell):
     assert line["correct"] is True and line["failed"] == 0
     share = line["metrics"]["launched_ahead_share"]
     assert share["unit"] == "%" and 0.0 <= share["value"] <= 100.0
+    # The cells whose models have latent-attention layers say how many of
+    # the slice's chunks attended per head (none at a rehearsal's chunks
+    # of 64: the form changes from 167 queries on at its widths); the
+    # dense cells do not report it.
+    latent = cell.startswith(("deepseek-v3.2-exp", "gigachat3.5"))
+    assert ("prefill_per_head_share" in line["metrics"]) == latent
+    if latent:
+        per_head = line["metrics"]["prefill_per_head_share"]
+        assert per_head["unit"] == "%" and 0.0 <= per_head["value"] <= 100.0
     (found,) = re.findall(
         r"launches: (\d+) step programs, (\d+) placed \(by launch, offset "
         r"(\d+)\), unpaired (\d+), clock_fault (\d+)", out.stdout)

@@ -166,6 +166,11 @@ class TestTraceNames:
         assert ppa.pallas_paged_decode_attention.__name__ == ppa.KERNEL_DECODE
         assert ppa.pallas_paged_prefill_attention.__name__ == ppa.KERNEL_PREFILL
         assert ppa.pallas_paged_ragged_attention.__name__ == ppa.KERNEL_RAGGED
+        from llmd_kv_cache_tpu.ops import pallas_latent_prefill as plp
+
+        assert (plp.pallas_per_head_prefill_attention.__name__
+                == plp.KERNEL_PER_HEAD_PREFILL
+                == "pallas_per_head_prefill_attention")
         # Today's strings: the readers that exist read what they read.
         assert (llama.PROGRAM_PREFILL, llama.PROGRAM_DECODE) == (
             "forward_prefill_pallas", "forward_decode_pallas")
@@ -220,6 +225,127 @@ class TestTraceNames:
         # on them and the compile-cache key (which leaves metadata out)
         # cannot move.
         assert with_scopes == without
+
+
+class TestLatentPrefillForms:
+    """A latent model's chunk program holds one of two attention kernels,
+    chosen from its shapes (``llama.prefill_per_head``): per head on keys
+    and values expanded from the latents inside the kernel from enough
+    queries on, else absorbed. Same function either way."""
+
+    @pytest.fixture(scope="class")
+    def latent(self):
+        # Rank 16 + rope 8 in pages of 24 lanes, heads of 16: the forms'
+        # FLOPs break even at 64 queries, the rule's margin makes it 84.
+        import dataclasses
+
+        cfg = dataclasses.replace(LlamaConfig.deepseek_tiny(),
+                                  dtype=jnp.float32)
+        return SimpleNamespace(
+            cfg=cfg, params=init_params(jax.random.PRNGKey(5), cfg),
+            # The same prefill under another jit key (decode-only field).
+            twin=dataclasses.replace(cfg, mla_decode_stream="reuse"))
+
+    @staticmethod
+    def chunk(cfg, seq, ctx, new, seed=0):
+        k_cache, v_cache = init_kv_cache(cfg, num_pages=80)
+        tokens = np.zeros((1, seq), np.int32)
+        tokens[0, :new] = np.random.default_rng(seed).integers(1, 250, new)
+        return (jnp.asarray(tokens), k_cache, v_cache,
+                jnp.arange(1, 65, dtype=jnp.int32)[None, :],
+                jnp.asarray([ctx], jnp.int32), jnp.asarray([new], jnp.int32))
+
+    def test_the_rule_is_the_shapes(self, latent, cfg):
+        from llmd_kv_cache_tpu.models import llama
+
+        assert llama.prefill_per_head(latent.cfg, 128)
+        assert llama.prefill_per_head(latent.cfg, 84)
+        assert not llama.prefill_per_head(latent.cfg, 83)
+        assert not llama.prefill_per_head(latent.cfg, 128, mesh=object())
+        assert not llama.prefill_per_head(cfg, 4096)      # no latent
+
+    @pytest.mark.parametrize("seq,per_head", [(128, True), (64, False)])
+    def test_a_program_holds_one_of_the_two_kernels(self, latent, seq,
+                                                    per_head):
+        from llmd_kv_cache_tpu.models import llama
+        from llmd_kv_cache_tpu.ops.pallas_latent_prefill import (
+            KERNEL_PER_HEAD_PREFILL)
+        from llmd_kv_cache_tpu.ops.pallas_paged_attention import (
+            KERNEL_PREFILL)
+
+        text = str(jax.make_jaxpr(
+            lambda params, *chunk: llama.forward_prefill_pallas(
+                params, latent.cfg, *chunk, interpret=True,
+                last_only=True))(latent.params,
+                                 *self.chunk(latent.cfg, seq, 0, seq - 3)))
+        assert (KERNEL_PER_HEAD_PREFILL in text) == per_head
+        assert (KERNEL_PREFILL in text) == (not per_head)
+
+    def test_both_forms_give_the_chunks_logits(self, latent, monkeypatch):
+        """A chunk of 128 behind 40 cached tokens: per head, absorbed (the
+        rule switched off under the twin's jit key) and the XLA forward."""
+        from llmd_kv_cache_tpu.models import llama
+
+        first = self.chunk(latent.cfg, 64, 0, 40, seed=1)
+        _, k_cache, v_cache = forward(latent.params, latent.cfg, *first)
+        tokens, _, _, table, _, new = self.chunk(latent.cfg, 128, 40, 117)
+
+        def args():                    # every forward donates its pools
+            return (tokens, jnp.copy(k_cache), jnp.copy(v_cache), table,
+                    jnp.asarray([40], jnp.int32), new)
+
+        want, _, _ = forward(latent.params, latent.cfg, *args())
+        per_head, _, _ = llama.forward_prefill_pallas(
+            latent.params, latent.cfg, *args(), interpret=True)
+        monkeypatch.setattr(llama, "prefill_per_head",
+                            lambda *_a, **_k: False)
+        absorbed, _, _ = llama.forward_prefill_pallas(
+            latent.params, latent.twin, *args(), interpret=True)
+        for got in (per_head, absorbed):
+            np.testing.assert_allclose(np.asarray(got[0, :117]),
+                                       np.asarray(want[0, :117]),
+                                       rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(per_head[0, :117]),
+                                   np.asarray(absorbed[0, :117]),
+                                   rtol=2e-4, atol=2e-4)
+
+
+class TestFramesUnderEveryProgram:
+    """A tripwire, not a law. The functions below are on the stack of every
+    op of every model's step programs while they are traced and lowered,
+    and the sizes of their frames (locals + stack slots) decide which call
+    sites of that work fall on the edge of one of CPython's 16 KiB frame
+    chunks, where every call maps and unmaps memory: a slot more or less
+    has moved every dense cell's set-up by seconds, either way (PERF.md §6,
+    PR 47; ``hack/stack_chunk_cliff.py``). A PR that changes one of them on
+    purpose measures ``setup_s`` in ``qwen3-1.7b.short-control`` on the
+    chip, parent against change, and then writes the new size here."""
+
+    SIZES = {
+        "llmd_kv_cache_tpu/models/llama.py": {
+            "_forward_impl_grouped": 94, "_forward_impl": 32,
+            "forward_prefill_pallas": 39,
+            "forward_prefill_pallas.attention_fn": 34,
+            "forward_decode_pallas": 37, "step_program.program": 35},
+        "llmd_kv_cache_tpu/models/engine.py": {
+            "MiniEngine.step": 26, "MiniEngine._prefill_chunk": 41,
+            "MiniEngine._launch_decode": 36},
+    }
+
+    def test_their_frames_keep_their_size(self):
+        import sys
+
+        if sys.version_info[:2] != (3, 12):
+            pytest.skip("slot counts are the 3.12 compiler's")
+        root = Path(__file__).resolve().parents[1]
+        sys.path.insert(0, str(root / "hack"))
+        try:
+            import frame_sizes
+        finally:
+            sys.path.pop(0)
+        for rel, want in self.SIZES.items():
+            got = frame_sizes.frames(root / rel)
+            assert {n: got.get(n) for n in want} == want, rel
 
 
 class TestNoLayerOfAPoolMoves:

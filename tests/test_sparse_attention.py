@@ -135,6 +135,42 @@ class TestThroughThePagedCache:
         assert rel(logits, served.want[0]) < F32_TOL
         assert list(req.output) == served.out
 
+    def test_a_full_chunk_attends_per_head(self, served):
+        """The prompt as one chunk padded to 128 queries: at these widths
+        (latent 64 + rope 32 in pages of 128 lanes, heads of 32) the chunk
+        program attends per head from 42 queries on, on keys and values
+        expanded from the latents inside the kernel, under the same
+        selection. Same logits and tokens as the reference and as the
+        absorbed program (chunks of 32, above); its dispatch says how many
+        keys a head expanded (one superblock: the row's 256), an absorbed
+        chunk's says 0."""
+        from tests.test_telemetry import _recorded
+
+        assert llama.prefill_per_head(served.cfg, 128)
+        assert not llama.prefill_per_head(served.cfg, 32)
+        eng = engine(served.cfg, served.params, pallas=True, chunk=128,
+                     telemetry=EngineTelemetryConfig())
+        seen = _recorded(eng._phases)
+        req, logits = serve(eng, "per-head", PROMPT, 7)
+        assert rel(logits, served.want[0]) < F32_TOL
+        assert list(req.output) == served.out
+        assert [a["expanded_keys"] for n, a, _ in seen
+                if n == "step.dispatch" and "prefill_pos" in a] == [256]
+        short = engine(served.cfg, served.params, pallas=True,
+                       telemetry=EngineTelemetryConfig())
+        seen = _recorded(short._phases)
+        _, absorbed = serve(short, "absorbed", PROMPT, 1)
+        assert rel(logits, absorbed) < F32_TOL
+        assert [a["expanded_keys"] for n, a, _ in seen
+                if n == "step.dispatch" and "prefill_pos" in a] == [0] * 4
+        # The XLA prefill has neither kernel.
+        xla = engine(served.cfg, served.params, chunk=128,
+                     telemetry=EngineTelemetryConfig())
+        seen = _recorded(xla._phases)
+        serve(xla, "xla", PROMPT, 1)
+        assert [a["expanded_keys"] for n, a, _ in seen
+                if n == "step.dispatch" and "prefill_pos" in a] == [0]
+
     def test_chunked_prefill_is_one_chunk(self, served):
         whole = engine(served.cfg, served.params, chunk=128)
         _, logits = serve(whole, "one-chunk", PROMPT, 1)

@@ -25,8 +25,9 @@ from llmd_kv_cache_tpu.parallel.serve import (
 
 def _engine(cfg, params, mesh=None, **kw):
     return MiniEngine(
-        EngineConfig(model=cfg, num_pages=64, max_pages_per_seq=16,
-                     model_name="tp-test", pod_identifier="p", **kw),
+        EngineConfig(**{**dict(model=cfg, num_pages=64, max_pages_per_seq=16,
+                               model_name="tp-test", pod_identifier="p"),
+                        **kw}),
         params=params, mesh=mesh,
     )
 
@@ -180,6 +181,34 @@ def test_tp_mla_pallas_decode(mla_setup):
     out = _engine(cfg, params, mesh=mesh, use_pallas_decode=True,
                   decode_burst=4).generate("r", prompt, max_new_tokens=8)
     assert out == ref
+
+
+def test_tp_mla_prefill_keeps_the_absorbed_kernel(mla_setup):
+    """A chunk of 128 queries (these widths go per head from 84 on a lone
+    device): the sharded engine's chunk program keeps the absorbed kernel,
+    per shard over its heads, and says so (``expanded_keys`` 0); same
+    tokens as the lone engine, whose chunk attends per head."""
+    from llmd_kv_cache_tpu.models.llama import prefill_per_head
+    from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
+        EngineTelemetryConfig)
+    from tests.test_telemetry import _recorded
+
+    cfg, params = mla_setup
+    prompt = np.random.default_rng(12).integers(1, 250, 100).tolist()
+    mesh = make_mesh({"tp": 2}, jax.devices()[:2])
+    assert prefill_per_head(cfg, 128) and not prefill_per_head(cfg, 128, mesh)
+    expanded = {}
+    out = {}
+    for name, m in (("lone", None), ("tp", mesh)):
+        eng = _engine(cfg, params, mesh=m, use_pallas_decode=True,
+                      use_pallas_prefill=True, max_prefill_tokens=128,
+                      max_pages_per_seq=32, telemetry=EngineTelemetryConfig())
+        seen = _recorded(eng._phases)
+        out[name] = eng.generate("r", prompt, max_new_tokens=4)
+        expanded[name] = [a["expanded_keys"] for n, a, _ in seen
+                          if n == "step.dispatch" and "prefill_pos" in a]
+    assert out["tp"] == out["lone"]
+    assert expanded == {"lone": [128], "tp": [0]}
 
 
 def test_tp_mla_latent_cache_replicates(mla_setup):

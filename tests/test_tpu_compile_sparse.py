@@ -20,6 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from llmd_kv_cache_tpu.models import llama
 from llmd_kv_cache_tpu.ops import sparse_index
+from llmd_kv_cache_tpu.ops.pallas_latent_prefill import (
+    pallas_per_head_prefill_attention)
 from llmd_kv_cache_tpu.ops.pallas_paged_attention import (
     pallas_paged_decode_attention, pallas_paged_prefill_attention)
 
@@ -89,6 +91,31 @@ def test_masked_latent_prefill(shaped):
              shaped((1, ROW_PAGES), jnp.int32), shaped((1,), jnp.int32),
              shaped((1,), jnp.int32),
              shaped((1, CHUNK, KEYS), jnp.float32))
+
+
+@pytest.mark.parametrize("heads,q_seq,selects", [
+    (128, CHUNK, True), (128, 256, True), (64, CHUNK, False),
+    (64, 256, False)], ids=["selecting-512", "selecting-256", "gated-512",
+                            "gated-256"])
+def test_per_head_latent_prefill(shaped, heads, q_seq, selects):
+    """A chunk's queries whole and 16 heads a program: their blocks, a
+    superblock of 1024 latents twice, its slice of the selection twice and
+    the heads' softmax state in fast memory, at both latent
+    configurations' head counts and the engine's two buckets that go per
+    head."""
+    def prefill(q_nope, q_rope, w_uk, w_uv, pages, table, ctx, total,
+                *bias):
+        return pallas_per_head_prefill_attention(
+            q_nope, q_rope, w_uk, w_uv, pages, table, ctx, total,
+            scale=192 ** -0.5, layer_idx=2, bias=bias[0] if bias else None)
+
+    compiles(prefill, shaped((1, q_seq, heads, 128)),
+             shaped((1, q_seq, heads, 64)), shaped((heads, 512, 128)),
+             shaped((heads, 512, 128)),
+             shaped((LAYERS, PAGES, 1, PAGE, 640)),
+             shaped((1, ROW_PAGES), jnp.int32), shaped((1,), jnp.int32),
+             shaped((1,), jnp.int32),
+             *([shaped((1, q_seq, KEYS), jnp.float32)] if selects else []))
 
 
 @pytest.mark.parametrize("gathered", [False, True],
