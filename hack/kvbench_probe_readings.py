@@ -20,6 +20,9 @@ chip (``chiprun -- python3 hack/kvbench_probe_readings.py ...``) or, with
                        fault, e.g. ``swiglu_limit=0``
   --fault NAME         a fault planted in the serving program
                        (``FAULTS``); it has to come out not ok
+                       (``xla-recurrence`` is no fault: the recurrence's
+                       XLA form in the kernels' place, which has to read
+                       as the kernels do)
 """
 
 from __future__ import annotations
@@ -80,10 +83,28 @@ def _gate_left_out() -> None:
     llama._sublayer_out = ungated
 
 
+def _xla_recurrence() -> None:
+    """Not a fault: the recurrence's XLA form in the kernels' place (the
+    same blocked algorithm, the compiler's float32 matmuls), to tell a
+    kernel's arithmetic from the algorithm's."""
+    import functools
+
+    from llmd_kv_cache_tpu.ops import gated_deltanet as gd
+
+    for name in ("gdn_scan", "gdn_step", "kda_scan", "kda_step"):
+        served = getattr(gd, name)
+
+        def xla(*args, _served=served, **kw):
+            return _served(*args, **{**kw, "kernel": False,
+                                     "interpret": False})
+
+        setattr(gd, name, functools.wraps(served)(xla))
+
+
 # Faults planted in the program, by name. Each replaces something the step
 # programs look up when they are first traced.
 FAULTS = {"stale-state": _stale_state, "conv-tail": _conv_tail_dropped,
-          "no-gate": _gate_left_out}
+          "no-gate": _gate_left_out, "xla-recurrence": _xla_recurrence}
 
 
 def main() -> None:

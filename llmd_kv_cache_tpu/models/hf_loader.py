@@ -104,7 +104,7 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     # silently-wrong logits.
     supported = ("llama", "mistral", "mixtral", "qwen2", "qwen3",
                  "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32",
-                 "gigachat3_5")
+                 "gigachat3_5", "solar_open2")
     if hf_cfg.model_type not in supported:
         raise NotImplementedError(
             f"model_type {hf_cfg.model_type!r} is not supported "
@@ -118,6 +118,8 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
             f"hidden_act {act!r} != silu: the SwiGLU MLP here would be "
             f"silently wrong")
     rope_scaling = _convert_rope_scaling(hf_cfg)
+    if hf_cfg.model_type == "solar_open2":
+        return _config_from_solar(hf_cfg, page_size, dtype)
     if hf_cfg.model_type.startswith("deepseek"):
         return _config_from_deepseek(hf_cfg, page_size, dtype,
                                      rope_scaling)
@@ -201,6 +203,51 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     )
 
 
+def _v3_routed(hf_cfg: Any) -> dict:
+    """``LlamaConfig``'s keys for DeepSeek-V3's routed layers (sigmoid
+    scores, ``noaux_tc``, groups, a shared expert) behind
+    ``first_k_dense_replace`` dense ones, served by the exact grouped
+    dispatch; nothing for a model without them. A top-level ``layer_share``
+    is one chip's share of each layer (``_config_from_deepseek``)."""
+    n_layers = hf_cfg.num_hidden_layers
+    first_dense = getattr(hf_cfg, "first_k_dense_replace", 0)
+    if not (getattr(hf_cfg, "n_routed_experts", None)
+            and n_layers > first_dense):
+        return {}
+    if hf_cfg.model_type not in _V3_ROUTED:
+        raise NotImplementedError(
+            "MoE conversion is implemented for deepseek_v3 and "
+            "deepseek_v32 (V2's softmax/greedy router differs)")
+    if getattr(hf_cfg, "topk_method", "noaux_tc") not in (
+            "noaux_tc", None):
+        raise NotImplementedError(
+            f"topk_method {hf_cfg.topk_method!r} unsupported")
+    moe_kw = {}
+    share = getattr(hf_cfg, "layer_share", None)
+    if share:
+        held = int(hf_cfg.n_routed_experts)
+        if held * int(share["chips"]) != int(share["n_routed_experts"]):
+            raise ValueError(
+                f"layer_share: {held} experts held x {share['chips']} "
+                f"chips is not the router's "
+                f"{share['n_routed_experts']}")
+        moe_kw["experts_held"] = (int(share["rank"]) * held, held)
+    return dict(
+        moe_kw,
+        num_experts=int(share["n_routed_experts"]) if share
+        else hf_cfg.n_routed_experts,
+        num_experts_per_token=hf_cfg.num_experts_per_tok,
+        moe_layers=tuple(range(first_dense, n_layers)),
+        n_shared_experts=hf_cfg.n_shared_experts,
+        moe_intermediate_size=hf_cfg.moe_intermediate_size,
+        moe_router=("deepseek_v3", getattr(hf_cfg, "n_group", 1),
+                    getattr(hf_cfg, "topk_group", 1),
+                    int(bool(hf_cfg.norm_topk_prob)),
+                    float(hf_cfg.routed_scaling_factor)),
+        moe_dispatch="grouped",
+    )
+
+
 def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
                           rope_scaling: tuple = ()) -> LlamaConfig:
     """DeepSeek-V2/V3/V3.2 → absorbed-MLA config.
@@ -237,40 +284,7 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
             f"v_head_dim {hf_cfg.v_head_dim} != qk_nope_head_dim "
             f"{hf_cfg.qk_nope_head_dim}: this model shares one head_dim")
     n_layers = hf_cfg.num_hidden_layers
-    moe_kw = {}
-    first_dense = getattr(hf_cfg, "first_k_dense_replace", 0)
-    if getattr(hf_cfg, "n_routed_experts", None) and n_layers > first_dense:
-        if hf_cfg.model_type not in _V3_ROUTED:
-            raise NotImplementedError(
-                "MoE conversion is implemented for deepseek_v3 and "
-                "deepseek_v32 (V2's softmax/greedy router differs)")
-        if getattr(hf_cfg, "topk_method", "noaux_tc") not in (
-                "noaux_tc", None):
-            raise NotImplementedError(
-                f"topk_method {hf_cfg.topk_method!r} unsupported")
-        share = getattr(hf_cfg, "layer_share", None)
-        if share:
-            held = int(hf_cfg.n_routed_experts)
-            if held * int(share["chips"]) != int(share["n_routed_experts"]):
-                raise ValueError(
-                    f"layer_share: {held} experts held x {share['chips']} "
-                    f"chips is not the router's "
-                    f"{share['n_routed_experts']}")
-            moe_kw["experts_held"] = (int(share["rank"]) * held, held)
-        moe_kw = dict(
-            moe_kw,
-            num_experts=int(share["n_routed_experts"]) if share
-            else hf_cfg.n_routed_experts,
-            num_experts_per_token=hf_cfg.num_experts_per_tok,
-            moe_layers=tuple(range(first_dense, n_layers)),
-            n_shared_experts=hf_cfg.n_shared_experts,
-            moe_intermediate_size=hf_cfg.moe_intermediate_size,
-            moe_router=("deepseek_v3", hf_cfg.n_group,
-                        hf_cfg.topk_group,
-                        int(bool(hf_cfg.norm_topk_prob)),
-                        float(hf_cfg.routed_scaling_factor)),
-            moe_dispatch="grouped",
-        )
+    moe_kw = _v3_routed(hf_cfg)
     # DeepSeek yarn: the generic cos/sin attention factor applies via
     # rope_scaling; for deepseek_v3 ONLY, mscale_all_dim ADDITIONALLY
     # multiplies the softmax scale by mscale^2 (in-tree
@@ -320,7 +334,7 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
 
 # The model types whose routed layers are DeepSeek-V3's (sigmoid scores, a
 # correction bias, a shared expert) and whose yarn raises the softmax scale.
-_V3_ROUTED = ("deepseek_v3", "deepseek_v32", "gigachat3_5")
+_V3_ROUTED = ("deepseek_v3", "deepseek_v32", "gigachat3_5", "solar_open2")
 
 # The one reading of each of GigaChat3.5's keys that name a form and do not
 # define it: what ``llama`` implements. Another value is another model.
@@ -379,6 +393,70 @@ def _config_from_gigachat(hf_cfg: Any, page_size: int, dtype: Any,
         post_norms=True,
         attn_output_gate=bool(getattr(hf_cfg, "gated_attention", False)),
         swiglu_limit=float(getattr(hf_cfg, "swiglu_limit", 0) or 0),
+    )
+
+
+def _config_from_solar(hf_cfg: Any, page_size: int,
+                       dtype: Any) -> LlamaConfig:
+    """Solar-Open2 (``model_type: solar_open2``): the layers ``gqa_layers``
+    lists attend (GQA without positional encoding, ``use_rope: false``;
+    the heads' outputs gated, ``use_gqa_gate``), every other layer's mixer
+    is Kimi-style delta attention (``linear_attn_config``: as many key as
+    value heads, a conv, a decay for every key channel through a low-rank
+    projection since ``kda_use_full_proj`` is false, ``beta`` in (0, 2)
+    with ``kda_allow_neg_eigval``); every feed-forward from
+    ``first_k_dense_replace`` on is DeepSeek-V3's routed one in one group
+    (``_v3_routed``, ``layer_share`` included). The low-rank projections
+    are as wide as a head (``linear_attn_config.head_dim``: the family's
+    published form; the config gives no width). What the published config
+    does not give and a top-level key may (no checkpoint has them):
+    ``state_slots``, ``state_checkpoint_tokens``, ``embed_init_scale``,
+    ``router_bias_init_scale`` as for GigaChat3.5."""
+    if getattr(hf_cfg, "use_rope", False):
+        raise NotImplementedError(
+            "use_rope true: partial rotary beside linear layers is not "
+            "the model built (its attention carries no position)")
+    if getattr(hf_cfg, "kda_use_full_proj", False):
+        raise NotImplementedError(
+            "kda_use_full_proj: the decay's projection built is low-rank")
+    la = hf_cfg.linear_attn_config
+    heads = int(la["num_heads"])
+    if la.get("num_kv_heads") not in (None, heads):
+        raise NotImplementedError(
+            "linear_attn_config.num_kv_heads: a channel-wise decay is built "
+            "with as many key heads as value heads")
+    attends = set(hf_cfg.gqa_layers)
+    n_layers = hf_cfg.num_hidden_layers
+    return LlamaConfig(
+        vocab_size=hf_cfg.vocab_size,
+        hidden_size=hf_cfg.hidden_size,
+        num_layers=n_layers,
+        num_heads=hf_cfg.num_attention_heads,
+        num_kv_heads=hf_cfg.num_key_value_heads,
+        head_dim=int(hf_cfg.head_dim),
+        intermediate_size=hf_cfg.intermediate_size,
+        rope_theta=0.0,
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        page_size=page_size,
+        dtype=dtype,
+        attn_output_gate=bool(getattr(hf_cfg, "use_gqa_gate", False)),
+        linear_layers=tuple(i for i in range(n_layers) if i not in attends),
+        linear=LinearAttention(
+            key_heads=heads, value_heads=heads,
+            key_dim=int(la["head_dim"]), value_dim=int(la["head_dim"]),
+            conv_kernel=int(la["short_conv_kernel_size"]),
+            gate_scale=1.0, norm_eps=float(hf_cfg.rms_norm_eps),
+            decay="channel",
+            beta_scale=2.0 if getattr(hf_cfg, "kda_allow_neg_eigval",
+                                      False) else 1.0,
+            gate_rank=int(la["head_dim"])),
+        state_slots=int(getattr(hf_cfg, "state_slots", 64)),
+        state_checkpoint_tokens=int(getattr(
+            hf_cfg, "state_checkpoint_tokens", 4096)),
+        embed_init_scale=float(getattr(hf_cfg, "embed_init_scale", 0.02)),
+        router_bias_init_scale=float(getattr(
+            hf_cfg, "router_bias_init_scale", 0.02)),
+        **_v3_routed(hf_cfg),
     )
 
 

@@ -53,7 +53,10 @@ SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_MLP,
 # combination after. No metric reads them yet (``kvbench/trace/reduce.py``
 # keeps an op's name and drops its scope: ROADMAP S0, device time by scope);
 # they are for whoever opens a profiler capture of a step, where every op's
-# name carries its scopes.
+# name carries its scopes. A model with linear layers has the recurrence's
+# two kernels under their own names there too (``ops.gated_deltanet``:
+# ``gdn_scan`` / ``gdn_step``, or ``kda_scan`` / ``kda_step`` where the decay
+# is channel-wise), which the ``*_roofline`` readers find by the ops' names.
 SCOPE_INDEX = "dsa_index"
 SCOPE_SELECT = "dsa_select"
 SCOPE_SPARSE_ATTENTION = "dsa_attend"
@@ -76,7 +79,14 @@ class LinearAttention:
     gate ``gate_scale * sigmoid(z)`` and its eps. A sequence's cache in
     such a layer is one float32 state ``[value_heads, key_dim, value_dim]``
     and the conv's last ``conv_kernel - 1`` inputs (``ops.gated_deltanet``).
-    """
+
+    ``decay`` is the form of the recurrence's decay: ``"head"``, one scalar
+    a value head and token (Gated DeltaNet: ``w_qkvz``, ``w_ba``), or
+    ``"channel"``, one value a head and key channel (Kimi-style delta
+    attention: q, k, v from ``w_conv_in``; the decay and the output gate
+    through low-rank projections ``gate_rank`` wide; as many key heads as
+    value heads). ``beta = beta_scale * sigmoid(.)``: 2 admits negative
+    eigenvalues of a token's transition."""
 
     key_heads: int
     value_heads: int
@@ -85,6 +95,9 @@ class LinearAttention:
     conv_kernel: int = 4
     gate_scale: float = 2.0
     norm_eps: float = 1e-6
+    decay: str = "head"
+    beta_scale: float = 1.0
+    gate_rank: int = 0
 
     @property
     def conv_channels(self) -> int:
@@ -104,6 +117,8 @@ class LlamaConfig:
     num_kv_heads: int = 4
     head_dim: int = 64
     intermediate_size: int = 1408
+    # 0: no positional encoding at all (NoPE): ``_rope`` hands back what
+    # it was given.
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     page_size: int = 16
@@ -265,18 +280,31 @@ class LlamaConfig:
 
     def __post_init__(self):
         if self.linear_layers:
-            if self.linear is None or not self.is_mla:
+            if self.linear is None:
+                raise ValueError("linear_layers need their sizes (linear)")
+            # The layers that attend keep pages of a latent (absorbed MLA)
+            # or of GQA keys and values; the linear layers' decay is a
+            # scalar a head or a value a key channel: the hybrids of
+            # states and pages that are built.
+            if self.linear.decay not in ("head", "channel"):
                 raise ValueError(
-                    "linear_layers need their sizes (linear) and latent "
-                    "attention in the layers that attend: the one hybrid "
-                    "of states and pages that is built")
+                    f"linear.decay {self.linear.decay!r}: a scalar a head "
+                    f"(\"head\") or a value a key channel (\"channel\")")
+            if self.linear.decay == "channel" and (
+                    self.linear.key_heads != self.linear.value_heads
+                    or self.linear.gate_rank <= 0):
+                raise ValueError(
+                    "a channel-wise decay needs as many key heads as value "
+                    "heads and the width of its low-rank projections "
+                    "(gate_rank)")
             if not all(0 <= i < self.num_layers for i in self.linear_layers):
                 raise ValueError("linear_layers indices out of range")
             if len(set(self.linear_layers)) == self.num_layers:
                 raise ValueError(
                     "a model of linear layers alone has no pages for a "
                     "snapshot to stand on")
-            if self.is_dsa or self.sliding_window is not None:
+            if (self.is_dsa or self.sliding_window is not None
+                    or self.swa_layers):
                 raise ValueError(
                     "linear layers beside an indexer or a window are not "
                     "built")
@@ -615,12 +643,29 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
         # [1, 16), a step dt log-uniform in [1e-3, 1e-1] through its
         # inverse softplus. With the token's own term (x W_a, about +-1.7)
         # the heads' memories then run from a few tokens to a thousand.
+        # (A channel-wise decay draws its step a key channel, A a head.)
+        channel = la.decay == "channel"
         step = jnp.exp(jax.random.uniform(
-            ck[4], (la.value_heads,), jnp.float32, math.log(1e-3),
+            ck[4], (la.key_heads * la.key_dim if channel
+                    else la.value_heads,), jnp.float32, math.log(1e-3),
             math.log(1e-1)))
+        if channel:
+            rk = jax.random.split(ck[1], 5)
+            layer.update({
+                "w_conv_in": dense(ck[0], (h, la.conv_channels)),
+                "w_beta": dense(rk[0], (h, la.value_heads)),
+                "w_f_down": dense(rk[1], (h, la.gate_rank)),
+                "w_f_up": dense(rk[2], (la.gate_rank,
+                                        la.key_heads * la.key_dim)),
+                "w_g_down": dense(rk[3], (h, la.gate_rank)),
+                "w_g_up": dense(rk[4], (la.gate_rank, la.inner)),
+            })
+        else:
+            layer.update({
+                "w_qkvz": dense(ck[0], (h, la.conv_channels + la.inner)),
+                "w_ba": dense(ck[1], (h, 2 * la.value_heads)),
+            })
         layer.update({
-            "w_qkvz": dense(ck[0], (h, la.conv_channels + la.inner)),
-            "w_ba": dense(ck[1], (h, 2 * la.value_heads)),
             "conv_w": _dense_init(ck[2], (la.conv_kernel, la.conv_channels),
                                   jnp.float32, 0.5),
             "A_log": jnp.log(jax.random.uniform(
@@ -1311,8 +1356,11 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
     NTK scheme (long wavelengths divided by ``factor``, short kept,
     smooth ramp between) — or ``("yarn", factor, beta_fast, beta_slow,
     original_max, attention_factor)``; both match transformers'
-    ``modeling_rope_utils`` formulas.
+    ``modeling_rope_utils`` formulas. ``theta`` 0 is a model without
+    positional encoding: ``x`` as it came.
     """
+    if not theta:
+        return x
     hd = x.shape[-1]
     half = hd // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
@@ -1389,6 +1437,38 @@ def _sublayer_out(out, gate_in, layer, cfg, which: str) -> jax.Array:
     return out
 
 
+def _linear_inputs(x, layer, la, live):
+    """What a linear mixer projects from its normed input ``x [b, s, h]``:
+    ``(the conv's input [b, s, conv channels], the output gate's
+    pre-activation [b, s, inner], beta [b, s, value heads], the log-decay
+    g)``, ``beta`` and ``g`` float32 and 0 where ``live [b, s, 1]`` is
+    not. ``g`` is ``[b, s, value heads]`` (``decay`` "head": ``[q, k, v,
+    z] = x W_qkvz``, ``[b, a] = x W_ba``, ``g = -exp(A_log) softplus(a +
+    dt_bias)``) or ``[b, s, heads, key_dim]`` ("channel": ``[q, k, v] = x
+    W_conv_in``, ``z = (x W_g_down) W_g_up``, ``b = x W_beta``, ``g =
+    -exp(A_log) softplus((x W_f_down) W_f_up + dt_bias)`` with ``A_log`` a
+    head and ``dt_bias`` a channel)."""
+    f32 = jnp.float32
+    if la.decay == "channel":
+        a = ((x @ layer["w_f_down"]) @ layer["w_f_up"]).astype(f32)
+        g = jax.nn.softplus(a + layer["dt_bias"]).reshape(
+            *a.shape[:2], la.key_heads, la.key_dim)
+        g = -jnp.exp(layer["A_log"])[:, None] * g
+        beta = la.beta_scale * jax.nn.sigmoid(
+            (x @ layer["w_beta"]).astype(f32))
+        return (x @ layer["w_conv_in"],
+                (x @ layer["w_g_down"]) @ layer["w_g_up"],
+                jnp.where(live, beta, 0.0),
+                jnp.where(live[..., None], g, 0.0))
+    qkvz = x @ layer["w_qkvz"]
+    ba = (x @ layer["w_ba"]).astype(f32)
+    chans = la.conv_channels
+    beta = jnp.where(live, jax.nn.sigmoid(ba[..., :la.value_heads]), 0.0)
+    g = jnp.where(live, -jnp.exp(layer["A_log"]) * jax.nn.softplus(
+        ba[..., la.value_heads:] + layer["dt_bias"]), 0.0)
+    return qkvz[..., :chans], qkvz[..., chans:], beta, g
+
+
 def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
                     kernel):
     """A Gated DeltaNet mixer over ``x [b, s, h]`` (the layer's normed
@@ -1397,29 +1477,33 @@ def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
     updated)``. ``lj`` is the layer's index in the state pool; ``state``,
     ``kernel`` as ``_forward_impl_grouped`` takes them.
 
-    ``[q, k, v, z] = x W_qkvz`` and ``[b, a] = x W_ba``; a depthwise causal
-    conv over q, k, v (its first taps read the row's conv state: the last
-    inputs of what came before) and SiLU; q, k of unit length per head, q
-    times ``key_dim^-1/2``; ``beta = sigmoid(b)``, ``log alpha =
-    -exp(A_log) softplus(a + dt_bias)``; the recurrence (``ops.
-    gated_deltanet``: a chunk is scanned in blocks of a page, a decode
-    step updates every row's state in place); the heads' outputs normed
-    per head and gated by ``gate_scale * sigmoid(z)``."""
-    from ..ops.gated_deltanet import (
-        KERNEL_SCAN, KERNEL_STEP, gdn_scan, gdn_step)
+    The projections (``_linear_inputs``: q, k, v, the gate's z, beta and
+    the log-decay, in the form ``cfg.linear.decay`` names); a depthwise
+    causal conv over q, k, v (its first taps read the row's conv state:
+    the last inputs of what came before) and SiLU; q, k of unit length per
+    head, q times ``key_dim^-1/2``; the recurrence (``ops.gated_deltanet``:
+    a chunk is scanned in blocks of a page, a decode step updates every
+    row's state in place; ``kda_*`` where the decay is channel-wise); the
+    heads' outputs normed per head and gated by ``gate_scale *
+    sigmoid(z)``."""
+    from ..ops import gated_deltanet as gd
 
     la = cfg.linear
+    if la.decay == "channel":
+        scan, step = gd.kda_scan, gd.kda_step
+        scopes = gd.KERNEL_KDA_SCAN, gd.KERNEL_KDA_STEP
+    else:
+        scan, step = gd.gdn_scan, gd.gdn_step
+        scopes = gd.KERNEL_SCAN, gd.KERNEL_STEP
     f32 = jnp.float32
     b, s, _ = x.shape
     recurrent, conv, slots, snap = state
-    taps, chans = la.conv_kernel, la.conv_channels
+    taps = la.conv_kernel
     nk = la.key_heads * la.key_dim
     use = dict(kernel=kernel is not None,
                interpret=bool(kernel and kernel["interpret"]))
 
-    qkvz = x @ layer["w_qkvz"]
-    ba = (x @ layer["w_ba"]).astype(f32)
-    mixed, z = qkvz[..., :chans], qkvz[..., chans:]
+    mixed, z, beta, g = _linear_inputs(x, layer, la, valid[..., None])
     fresh = ctx_lens == 0                                          # [b]
     tail = jnp.where(fresh[:, None, None], 0, conv[lj, slots])
     window = jnp.concatenate([tail.astype(mixed.dtype), mixed], axis=1)
@@ -1442,24 +1526,20 @@ def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
     q = unit(mixed[..., :nk], la.key_heads) * la.key_dim ** -0.5
     k = unit(mixed[..., nk:2 * nk], la.key_heads)
     v = mixed[..., 2 * nk:].reshape(b, s, la.value_heads, la.value_dim)
-    live = valid[..., None]
-    beta = jnp.where(live, jax.nn.sigmoid(ba[..., :la.value_heads]), 0.0)
-    g = jnp.where(live, -jnp.exp(layer["A_log"]) * jax.nn.softplus(
-        ba[..., la.value_heads:] + layer["dt_bias"]), 0.0)
 
     if s == 1:
-        with jax.named_scope(KERNEL_STEP):
-            o, recurrent = gdn_step(recurrent, lj, slots, q[:, 0], k[:, 0],
+        with jax.named_scope(scopes[1]):
+            o, recurrent = step(recurrent, lj, slots, q[:, 0], k[:, 0],
                                     v[:, 0], g[:, 0], beta[:, 0], **use)
         o = o[:, None]
     else:
         if snap is not None and b != 1:
             raise ValueError("a chunk that leaves snapshots is one row")
         outs = []
-        with jax.named_scope(KERNEL_SCAN):
+        with jax.named_scope(scopes[0]):
             for i in range(b):
                 first = jnp.where(fresh[i], 0.0, recurrent[lj, slots[i]])
-                o_i, end, inner = gdn_scan(
+                o_i, end, inner = scan(
                     q[i], k[i], v[i], g[i], beta[i], first,
                     -1 if snap is None else snap[0], block=cfg.page_size,
                     **use)

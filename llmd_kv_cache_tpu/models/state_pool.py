@@ -55,6 +55,9 @@ class StatePool:
         self._heap: list[tuple[float, int, int]] = []
         self._entry_seq = 0
         self.evictions = 0                      # lifetime, as BlockManager's
+        # Of those, the snapshots that left because a page under them did
+        # (``drop_dependents``): the pages ran out before the slots.
+        self.orphaned = 0
         self._removed: list[int] = []
         self._stored: list[GenericEvent] = []
 
@@ -62,7 +65,8 @@ class StatePool:
         return {"state_slots": self.slots,
                 "state_working": len(self.working),
                 "state_snapshots": len(self.snapshots),
-                "state_evictions": self.evictions}
+                "state_evictions": self.evictions,
+                "state_orphaned": self.orphaned}
 
     # -- slots --
 
@@ -181,6 +185,7 @@ class StatePool:
                   if not gone.isdisjoint(snap.chain)]:
             self._remove(h)
             self.evictions += 1
+            self.orphaned += 1
 
     def drain(self) -> list[GenericEvent]:
         """The events gathered since the last call, removals first."""

@@ -25,6 +25,23 @@ kernels are tested against) and as a Pallas kernel (what a TPU serves):
 
 The jitted wrappers' names are what a device trace calls the kernels
 (``gdn_scan.<n>``, ``gdn_step.<n>``); readers of traces match them.
+
+**The channel-wise form** (Kimi-style delta attention, ``kda_scan`` /
+``kda_step``): the decay is a vector, one value a key channel, ``alpha_t``
+in (0, 1)^dk::
+
+    St_t = (I - beta_t k_t k_t^T) Diag(alpha_t) St_{t-1} + beta_t k_t v_t^T
+    o_t = St_t^T q_t
+
+(the scalar form above with ``alpha_t`` the same in every channel). The
+state, its pool and the snapshot at a requested block are the scalar
+form's. ``beta`` reaches 2 here, so the block's triangular system is
+inverted over nested groups (``_nested_unit_lower_inverse``). And a
+block's pairwise matrices differ:
+``sum_c x_ic k_jc exp(G_ic - G_jc)`` (``G`` the log-decay summed from the
+block's start) is no product of two factors that both stay finite over a
+page (a channel may lose e^-50 and more), so they are built over three
+levels of blocking (``_kda_pairs``).
 """
 
 from __future__ import annotations
@@ -38,6 +55,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 KERNEL_SCAN = "gdn_scan"
 KERNEL_STEP = "gdn_step"
+KERNEL_KDA_SCAN = "kda_scan"
+KERNEL_KDA_STEP = "kda_step"
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -262,7 +281,10 @@ def _step_kernel(slot_ref, layer_ref, q_ref, k_ref, v_ref, a_ref, b_ref,
                                   axis=0, keepdims=True)
 
 
-def _step_pallas(pool, layer, slots, q, k, v, alpha, beta, interpret):
+def _step_pallas(pool, layer, slots, q, k, v, alpha, beta, interpret,
+                 step=_step_kernel):
+    """``alpha [rows, Hv]`` (one decay a head, handed to the kernel over
+    the value lanes) or ``[rows, Hv, dk]`` (one a key channel)."""
     rows, hv, dk = q.shape
     dv = v.shape[-1]
     heads = 8 if hv % 8 == 0 else hv
@@ -276,12 +298,12 @@ def _step_pallas(pool, layer, slots, q, k, v, alpha, beta, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(rows, hv // heads),
-        in_specs=[vec(dk), vec(dk), vec(dv), vec(dv), vec(dv),
+        in_specs=[vec(dk), vec(dk), vec(dv), vec(alpha.shape[-1]), vec(dv),
                   pl.BlockSpec((1, 1, heads, dk, dv), state)],
         out_specs=[vec(dv), pl.BlockSpec((1, 1, heads, dk, dv), state)],
     )
     o, pool = pl.pallas_call(
-        functools.partial(_step_kernel, heads=heads),
+        functools.partial(step, heads=heads),
         out_shape=[jax.ShapeDtypeStruct((rows, hv, dv), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         grid_spec=grid_spec,
@@ -289,13 +311,14 @@ def _step_pallas(pool, layer, slots, q, k, v, alpha, beta, interpret):
         input_output_aliases={7: 1},
         interpret=interpret,
     )(slots.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-      q, k, v, jnp.broadcast_to(alpha[..., None], v.shape),
+      q, k, v, alpha,
       jnp.broadcast_to(beta[..., None], v.shape), pool)
     return o, pool
 
 
 def _step_xla(pool, layer, slots, q, k, v, alpha, beta):
-    st = pool[layer, slots] * alpha[..., None, None]    # [rows, Hv, dk, dv]
+    # alpha [rows, Hv, 1 | dk]: one decay a head, or one a key channel.
+    st = pool[layer, slots] * alpha[..., None]          # [rows, Hv, dk, dv]
     sk = jnp.einsum("rhk,rhkv->rhv", k, st, precision=_HIGHEST)
     st = st + k[..., :, None] * (beta[..., None] * (v - sk))[..., None, :]
     o = jnp.einsum("rhk,rhkv->rhv", q, st, precision=_HIGHEST)
@@ -314,8 +337,262 @@ def gdn_step(pool, layer, slots, q, k, v, g, beta, kernel: bool = False,
     f32 = jnp.float32
     rep = v.shape[1] // q.shape[1]
     q, k = (jnp.repeat(x.astype(f32), rep, axis=1) for x in (q, k))
-    args = (pool, layer, slots, q, k, v.astype(f32),
+    v, alpha, beta = v.astype(f32), jnp.exp(g.astype(f32)), beta.astype(f32)
+    if kernel:
+        return _step_pallas(pool, layer, slots, q, k, v,
+                            jnp.broadcast_to(alpha[..., None], v.shape),
+                            beta, interpret)
+    return _step_xla(pool, layer, slots, q, k, v, alpha[..., None], beta)
+
+
+# -- the channel-wise form --
+
+# Group sizes of the pairwise matrices' levels, outermost first (the block
+# itself stands before them).
+_KDA_GROUPS = (16, 4, 1)
+
+
+def _kda_pairs(x, k, gc, row, col):
+    """``P[i, j] = sum_c x[i, c] k[j, c] exp(gc[i, c] - gc[j, c])`` for ``j
+    < i`` (0 elsewhere): ``x [n, dk]`` (a block's queries and keys stacked:
+    ``n`` a multiple of the block's ``c`` tokens, row ``i`` is token ``i %
+    c``), ``k, gc [c, dk]``, ``row, col [c, c]`` iotas.
+
+    ``exp(gc_i - gc_j)`` is at most 1 and ``exp(-gc_j)`` alone may be e^50:
+    a pair's decay is split at a token between the two, ``exp(gc_i - gc_m)
+    exp(gc_m - gc_j)`` with ``j < m <= i``, both factors at most 1 whatever
+    the decays (a product that underflows was under e^-87 itself). Which
+    ``m`` serves a pair: tokens are grouped by 16, by 4 inside a 16 and
+    singly inside a 4; a pair whose tokens part at a level takes the first
+    token of the row's group at that level. A level's pairs are then, for
+    each of the up to three earlier groups ``p`` a row's group may follow
+    inside the enclosing one, one matrix product of rows scaled by
+    ``exp(gc_i - gc_m(i))`` and keys scaled by ``exp(gc_m - gc_j)``, kept
+    where row and key stand so. The reference rows of ``gc`` are gathered
+    by products with 0/1 matrices (exact at this precision, and the same
+    row on both sides of a pair). Whole-matrix operations under masks, as
+    ``_unit_lower_inverse``: the XLA form and the kernel share them."""
+    c = k.shape[0]
+    n = x.shape[0]
+    token = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    # The stacked rows' own token and the keys' under them.
+    xrow = jax.lax.broadcasted_iota(jnp.int32, (n, c), 0) % c
+    xcol = jax.lax.broadcasted_iota(jnp.int32, (n, c), 1)
+    tall = gc if n == c else jnp.concatenate([gc] * (n // c), axis=0)
+    sizes = [c] + [s for s in _KDA_GROUPS if s < c and c % s == 0]
+    out = jnp.zeros((n, c), jnp.float32)
+    for outer, inner in zip(sizes, sizes[1:]):
+        first = (xrow // inner) * inner         # the row's group's start
+        rows = x if inner == 1 else x * jnp.exp(
+            tall - _dot((xcol == first).astype(jnp.float32), gc))
+        same = (xrow // outer) == (xcol // outer)
+        for p in range(1, outer // inner):
+            # For key j: the start of group p of its enclosing group, the
+            # reference of the rows that stand in that group; it serves
+            # the keys before it.
+            ref = (row // outer) * outer + p * inner
+            shift = _dot((col == ref).astype(jnp.float32), gc) - gc
+            keys = k * jnp.exp(jnp.where(
+                token < (token // outer) * outer + p * inner, shift,
+                -jnp.inf))
+            keep = same & ((xrow % outer) // inner == p)
+            out = out + jnp.where(keep, _dot_nt(rows, keys), 0.0)
+    return out
+
+
+def _nested_unit_lower_inverse(n, eye, row, col):
+    """``(I - n)^-1`` for a strictly lower-triangular ``n [c, c]`` whose
+    entries may reach 2 (``beta`` in (0, 2) on keys that lie close
+    together). ``_unit_lower_inverse`` takes powers of a 16-token block up
+    to the 8th: with such entries they reach 1e5 and the inverse, of
+    order one, is what is left of their differences (an error of 0.1 in
+    float32, measured). Here no power beyond the third is ever formed:
+    groups of 4 tokens are inverted by ``(I + n)(I + n^2)``, and each
+    further level (16, then the block) folds what lies between its four
+    groups in as ``(I - D n_o)^-1 D``, ``D n_o`` nilpotent in four steps
+    again. As many matrix products as the other."""
+    c = n.shape[0]
+    sizes = [s for s in (4, 16) if s < c and c % s == 0] + [c]
+    inside = (row // sizes[0]) == (col // sizes[0])
+    d = _neumann(jnp.where(inside, n, 0.0), eye,
+                 (sizes[0] - 1).bit_length())
+    for below, size in zip(sizes, sizes[1:]):
+        level = (row // size) == (col // size)
+        p = _dot(d, jnp.where(level & ~inside, n, 0.0))
+        d = _dot(_neumann(p, eye, (size // below - 1).bit_length()), d)
+        inside = level
+    return d
+
+
+def _kda_block_update(q, k, v, gc, beta, st):
+    """One block of one head, channel-wise decay. ``q, k [c, dk]``, ``v [c,
+    dv]`` float32; ``gc [c, dk]`` each channel's log-decay summed from the
+    block's first token to each token (inclusive), ``beta [1, c]``; ``st
+    [dk, dv]`` the state before the block. Returns ``(o [c, dv], st')``.
+    ``_block_update`` with the decay inside the sums over channels."""
+    c, dk = q.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = (row == col).astype(jnp.float32)
+    bcol = _column(eye, beta)
+    decay = jnp.exp(gc)                           # from the block's start
+    pairs = _kda_pairs(jnp.concatenate([q, k], axis=0), k, gc, row, col)
+    n = -(bcol * pairs[c:])                       # strictly lower
+    t = _nested_unit_lower_inverse(n, eye, row, col)
+    u = _dot(t, v * bcol)                                         # [c, dv]
+    w = _dot(t, k * decay * bcol)                                 # [c, dk]
+    v_new = u - _dot(w, st)
+    inside = pairs[:c] + eye * jnp.sum(q * k, axis=1, keepdims=True)
+    o = _dot(q * decay, st) + _dot(inside, v_new)
+    g_last = gc[c - 1:c]                                          # [1, dk]
+    krow = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
+    whole = _column((krow == kcol).astype(jnp.float32), jnp.exp(g_last))
+    st = st * whole + _dot_tn(k * jnp.exp(g_last - gc), v_new)
+    return o, st
+
+
+def _kda_blocks(q, k, v, g, beta, block):
+    """Head-major operands: ``q, k, v [H, T, d]``, the in-block running
+    log-decay ``gc [H, T, dk]`` and ``beta [H, T / block, 1, block]``."""
+    t, h, dk = g.shape
+    nb = t // block
+    gc = jnp.cumsum(g.astype(jnp.float32).reshape(nb, block, h, dk), axis=1)
+    return (q.transpose(1, 0, 2), k.transpose(1, 0, 2), v.transpose(1, 0, 2),
+            gc.reshape(t, h, dk).transpose(1, 0, 2),
+            beta.astype(jnp.float32).reshape(nb, 1, block, h).transpose(
+                3, 0, 1, 2))
+
+
+def _kda_scan_xla(qh, kh, vh, gc, bb, st0, snap_block):
+    h, nb = bb.shape[:2]
+    block = bb.shape[-1]
+
+    def split(x):  # [H, T, d] -> [nb, H, block, d]
+        return x.reshape(h, nb, block, -1).transpose(1, 0, 2, 3)
+
+    per_head = jax.vmap(_kda_block_update)
+
+    def body(carry, xs):
+        st, snap = carry
+        i, qb, kb, vb, gb, betab = xs
+        f32 = jnp.float32
+        o, st = per_head(qb.astype(f32), kb.astype(f32), vb.astype(f32),
+                         gb, betab, st)
+        return (st, jnp.where(i == snap_block, st, snap)), o
+
+    (st, snap), o = jax.lax.scan(
+        body, (st0, st0),
+        (jnp.arange(nb), split(qh), split(kh), split(vh), split(gc),
+         bb.transpose(1, 0, 2, 3)))
+    return o.transpose(1, 0, 2, 3).reshape(h, nb * block, -1), st, snap
+
+
+def _kda_scan_kernel(snap_ref, q_ref, k_ref, v_ref, gc_ref, b_ref, st0_ref,
+                     o_ref, end_ref, snap_out_ref, st_scr, *, nb):
+    b = pl.program_id(1)
+
+    @pl.when(b == 0)
+    def _():
+        st_scr[...] = st0_ref[0]
+        snap_out_ref[0] = st0_ref[0]
+
+    f32 = jnp.float32
+    o, st = _kda_block_update(q_ref[0].astype(f32), k_ref[0].astype(f32),
+                              v_ref[0].astype(f32), gc_ref[0],
+                              b_ref[0, 0], st_scr[...])
+    o_ref[0] = o.astype(o_ref.dtype)
+    st_scr[...] = st
+
+    @pl.when(b == snap_ref[0])
+    def _():
+        snap_out_ref[0] = st
+
+    @pl.when(b == nb - 1)
+    def _():
+        end_ref[0] = st
+
+
+def _kda_scan_pallas(qh, kh, vh, gc, bb, st0, snap_block, interpret):
+    h, t, dk = qh.shape
+    nb, block = bb.shape[1], bb.shape[-1]
+    dv = vh.shape[-1]
+
+    def tokens(width):
+        return pl.BlockSpec((1, block, width), lambda i, b, *_: (i, b, 0))
+
+    def state():
+        return pl.BlockSpec((1, dk, dv), lambda i, b, *_: (i, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(h, nb),
+        in_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk),
+                  pl.BlockSpec((1, 1, 1, block),
+                               lambda i, b, *_: (i, b, 0, 0)),
+                  state()],
+        out_specs=[tokens(dv), state(), state()],
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kda_scan_kernel, nb=nb),
+        out_shape=[jax.ShapeDtypeStruct((h, t, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((h, dk, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((h, dk, dv), jnp.float32)],
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(snap_block, (1,)).astype(jnp.int32), qh, kh, vh, gc, bb,
+      st0)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "kernel", "interpret"))
+def kda_scan(q, k, v, g, beta, state, snap_block, block: int,
+             kernel: bool = False, interpret: bool = False):
+    """``gdn_scan`` with a decay for every key channel: ``q, k [T, H, dk]``,
+    ``v [T, H, dv]``, ``g [T, H, dk]`` (a token's log-decay a channel, at
+    most 0), ``beta [T, H]`` (``g`` and ``beta`` 0 at a padded token),
+    ``state [H, dk, dv]`` float32. Returns what ``gdn_scan`` returns."""
+    if q.shape[0] % block:
+        raise ValueError(f"a chunk of {q.shape[0]} tokens is not a whole "
+                         f"number of blocks of {block}")
+    operands = _kda_blocks(q, k, v, g, beta, block)
+    if kernel:
+        o, st, snap = _kda_scan_pallas(*operands, state, snap_block,
+                                       interpret)
+    else:
+        o, st, snap = _kda_scan_xla(*operands, state, snap_block)
+    return o.transpose(1, 0, 2), st, snap
+
+
+def _kda_step_kernel(slot_ref, layer_ref, q_ref, k_ref, v_ref, a_ref, b_ref,
+                     pool_ref, o_ref, out_ref, *, heads):
+    del slot_ref, layer_ref  # the index maps read them
+    dk = q_ref.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
+    eye = (row == col).astype(jnp.float32)
+    for h in range(heads):
+        at = slice(h, h + 1)
+        st = pool_ref[0, 0, h] * _column(eye, a_ref[0, at, :])   # decay
+        kcol = _column(eye, k_ref[0, at, :])
+        sk = jnp.sum(kcol * st, axis=0, keepdims=True)           # [1, dv]
+        st = st + kcol * (b_ref[0, at, :] * (v_ref[0, at, :] - sk))
+        out_ref[0, 0, h] = st
+        o_ref[0, at, :] = jnp.sum(_column(eye, q_ref[0, at, :]) * st,
+                                  axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "interpret"),
+                   donate_argnames=("pool",))
+def kda_step(pool, layer, slots, q, k, v, g, beta, kernel: bool = False,
+             interpret: bool = False):
+    """``gdn_step`` with a decay for every key channel: ``q, k, g [rows, H,
+    dk]``, ``v [rows, H, dv]``, ``beta [rows, H]``."""
+    f32 = jnp.float32
+    args = (pool, layer, slots, q.astype(f32), k.astype(f32), v.astype(f32),
             jnp.exp(g.astype(f32)), beta.astype(f32))
     if kernel:
-        return _step_pallas(*args, interpret)
+        return _step_pallas(*args, interpret, step=_kda_step_kernel)
     return _step_xla(*args)

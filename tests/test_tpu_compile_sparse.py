@@ -176,3 +176,39 @@ def test_recurrence_step(shaped):
              shaped((ROWS, GDN_VALUE_HEADS, GDN_DIM)),
              shaped((ROWS, GDN_VALUE_HEADS), f32),
              shaped((ROWS, GDN_VALUE_HEADS), f32))
+
+
+KDA_LAYERS, KDA_SLOTS, KDA_HEADS = 6, 41, 64
+
+
+def test_channelwise_delta_rule_scan(shaped):
+    """``solar-open2-ep16-l8``: a chunk of 512 tokens in blocks of 64, 64
+    heads with a decay for every key channel: the pairwise decays over
+    three levels of blocking, as matrix products under masks."""
+    from llmd_kv_cache_tpu.ops.gated_deltanet import kda_scan
+
+    f32 = jnp.float32
+    compiles(functools.partial(kda_scan, block=PAGE, kernel=True),
+             shaped((CHUNK, KDA_HEADS, GDN_DIM), f32),
+             shaped((CHUNK, KDA_HEADS, GDN_DIM), f32),
+             shaped((CHUNK, KDA_HEADS, GDN_DIM)),
+             shaped((CHUNK, KDA_HEADS, GDN_DIM), f32),
+             shaped((CHUNK, KDA_HEADS), f32),
+             shaped((KDA_HEADS, GDN_DIM, GDN_DIM), f32),
+             shaped((), jnp.int32))
+
+
+def test_channelwise_recurrence_step(shaped):
+    """8 rows' states decayed a key channel and updated in place."""
+    from llmd_kv_cache_tpu.ops.gated_deltanet import kda_step
+
+    f32 = jnp.float32
+    compiles(functools.partial(kda_step, kernel=True),
+             shaped((KDA_LAYERS, KDA_SLOTS, KDA_HEADS, GDN_DIM, GDN_DIM),
+                    f32),
+             shaped((), jnp.int32), shaped((ROWS,), jnp.int32),
+             shaped((ROWS, KDA_HEADS, GDN_DIM), f32),
+             shaped((ROWS, KDA_HEADS, GDN_DIM), f32),
+             shaped((ROWS, KDA_HEADS, GDN_DIM)),
+             shaped((ROWS, KDA_HEADS, GDN_DIM), f32),
+             shaped((ROWS, KDA_HEADS), f32))
