@@ -60,6 +60,58 @@ def token_at_a_time(q, k, v, g, beta, state):
     return jax.lax.scan(token, state, (q, k, v, g, beta))
 
 
+def products(fn, *shapes):
+    """``(count, MFLOP at one pass)`` of the ``dot_general``s in ``fn``'s
+    jaxpr at float32 operands of ``shapes``."""
+    import jax
+    import jax.numpy as jnp
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+            if e.primitive.name == "dot_general":
+                (contract, _), _ = e.params["dimension_numbers"]
+                lhs = e.invars[0].aval.shape
+                yield 2e-6 * np.prod(e.outvars[0].aval.shape) * np.prod(
+                    [lhs[i] for i in contract])
+
+    flops = list(walk(jax.make_jaxpr(fn)(*(
+        jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes)).jaxpr))
+    return len(flops), sum(flops)
+
+
+def print_products(c, d) -> None:
+    """A block's matrix products, counted from the jaxprs: what the
+    kernel's time follows (every one of them runs in so many bfloat16
+    passes: 6 at the highest precision)."""
+    import jax
+    import jax.numpy as jnp
+
+    from llmd_kv_cache_tpu.ops import gated_deltanet as gd
+
+    tok, ch, sq = (c, d), (1, c), (c, c)
+    kda = products(gd._kda_block_update, tok, tok, tok, tok, ch, (d, d))
+    pairs = products(gd._kda_pairs, (2 * c, d), tok, tok)
+
+    def invert(n):
+        row = jax.lax.broadcasted_iota(jnp.int32, sq, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, sq, 1)
+        return gd._nested_unit_lower_inverse(
+            n, (row == col).astype(jnp.float32), row, col)
+
+    inverse = products(invert, sq)
+    scalar = products(gd._block_update, tok, tok, tok, ch, ch, (d, d))
+    print(f"products of one block of one head ({c} tokens, dk = dv = {d}; "
+          "count, MFLOP at one pass):")
+    for name, (count, mflop) in (
+            ("_kda_pairs", pairs), ("_nested_unit_lower_inverse", inverse),
+            ("u, w and the state's", tuple(
+                a - b - i for a, b, i in zip(kda, pairs, inverse))),
+            ("the channel-wise block", kda), ("the scalar form's", scalar)):
+        print(f"  {name}: {count}, {mflop:.1f}", flush=True)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--rehearse", action="store_true")
@@ -84,6 +136,7 @@ def main() -> None:
           f"over a page: {float(g[:page].sum(0).max()):.3g} .. "
           f"{float(g[:page].sum(0).min()):.3g}", flush=True)
 
+    print_products(page, d)
     want_end, want_o = jax.jit(token_at_a_time)(q, k, v, g, beta, state)
     want_snap, _ = jax.jit(token_at_a_time)(
         q[:2 * page], k[:2 * page], v[:2 * page], g[:2 * page],

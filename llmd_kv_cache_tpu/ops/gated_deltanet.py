@@ -41,7 +41,13 @@ block's pairwise matrices differ:
 ``sum_c x_ic k_jc exp(G_ic - G_jc)`` (``G`` the log-decay summed from the
 block's start) is no product of two factors that both stay finite over a
 page (a channel may lose e^-50 and more), so they are built over three
-levels of blocking (``_kda_pairs``).
+levels of blocking (``_kda_pairs``): a pair's decay is split at a token
+between the two, whose row of ``G`` is taken by static slices and sublane
+rolls (``_group_rows``; no matrix product copies rows), and a level is one
+product of scaled rows and scaled keys, at the outermost level on the rows
+that are kept alone. Every product of the block, the pairwise ones, the
+inverse's and the state's, is float32 at ``Precision.HIGHEST`` (six
+bfloat16 passes), and the state stays float32.
 """
 
 from __future__ import annotations
@@ -352,11 +358,32 @@ def gdn_step(pool, layer, slots, q, k, v, g, beta, kernel: bool = False,
 _KDA_GROUPS = (16, 4, 1)
 
 
-def _kda_pairs(x, k, gc, row, col):
+def _group_rows(a, size, offset=0):
+    """``a[(i // size) * size + offset]`` in row ``i``: every group of
+    ``size`` rows gets its own row ``offset``, bit for bit and by no
+    product. Static row slices, each broadcast over its group, where the
+    groups are whole tiles of 8 rows; in smaller groups, ``a`` rolled by
+    up to ``size - 1`` rows under a select on ``i % size``."""
+    c, d = a.shape
+    if size >= c:
+        return a[offset:offset + 1]
+    if size % 8 == 0:
+        return jnp.concatenate(
+            [jnp.broadcast_to(a[s + offset:s + offset + 1], (size, d))
+             for s in range(0, c, size)], axis=0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) % size
+    out = a
+    for r in range(size):
+        if r != offset:
+            out = jnp.where(at == r, jnp.roll(a, r - offset, axis=0), out)
+    return out
+
+
+def _kda_pairs(x, k, gc):
     """``P[i, j] = sum_c x[i, c] k[j, c] exp(gc[i, c] - gc[j, c])`` for ``j
     < i`` (0 elsewhere): ``x [n, dk]`` (a block's queries and keys stacked:
     ``n`` a multiple of the block's ``c`` tokens, row ``i`` is token ``i %
-    c``), ``k, gc [c, dk]``, ``row, col [c, c]`` iotas.
+    c``), ``k, gc [c, dk]``.
 
     ``exp(gc_i - gc_j)`` is at most 1 and ``exp(-gc_j)`` alone may be e^50:
     a pair's decay is split at a token between the two, ``exp(gc_i - gc_m)
@@ -366,37 +393,59 @@ def _kda_pairs(x, k, gc, row, col):
     singly inside a 4; a pair whose tokens part at a level takes the first
     token of the row's group at that level. A level's pairs are then, for
     each of the up to three earlier groups ``p`` a row's group may follow
-    inside the enclosing one, one matrix product of rows scaled by
-    ``exp(gc_i - gc_m(i))`` and keys scaled by ``exp(gc_m - gc_j)``, kept
-    where row and key stand so. The reference rows of ``gc`` are gathered
-    by products with 0/1 matrices (exact at this precision, and the same
-    row on both sides of a pair). Whole-matrix operations under masks, as
-    ``_unit_lower_inverse``: the XLA form and the kernel share them."""
+    inside the enclosing one, rows scaled by ``exp(gc_i - gc_m(i))`` times
+    keys scaled by ``exp(gc_m - gc_j)``, kept where row and key stand so.
+
+    The reference rows ``gc_m`` are rows of ``gc`` at static places, the
+    same float32 row on both sides of a pair, taken by slices and rolls
+    (``_group_rows``) and never by a product. At the outermost level group
+    ``p`` keeps whole rows ``p * inner ..``, so only those are multiplied
+    (aligned slices, one product a group); at the levels inside it the
+    kept rows are interleaved: one product of all rows with the level's
+    key operands stacked, under masks. Five products for a block of 64,
+    all at the highest precision: at three bfloat16 passes for six the
+    close keys' state read 1.4e-4 of the recurrence for 2e-5 (2e-4 is
+    allowed) and the scan took 1.97 ms a layer's chunk for 2.08 on the
+    chip (``PERF.md``, PR 49). The XLA form and the kernel share all of
+    it."""
     c = k.shape[0]
-    n = x.shape[0]
+    parts = x.shape[0] // c
+
+    def tall(a):                        # over the stacked queries and keys
+        return a if parts == 1 else jnp.concatenate([a] * parts, axis=0)
+
     token = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-    # The stacked rows' own token and the keys' under them.
-    xrow = jax.lax.broadcasted_iota(jnp.int32, (n, c), 0) % c
-    xcol = jax.lax.broadcasted_iota(jnp.int32, (n, c), 1)
-    tall = gc if n == c else jnp.concatenate([gc] * (n // c), axis=0)
+    row = tall(jax.lax.broadcasted_iota(jnp.int32, (c, c), 0))
+    col = tall(jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
     sizes = [c] + [s for s in _KDA_GROUPS if s < c and c % s == 0]
-    out = jnp.zeros((n, c), jnp.float32)
+    out = jnp.zeros((parts * c, c), jnp.float32)
     for outer, inner in zip(sizes, sizes[1:]):
-        first = (xrow // inner) * inner         # the row's group's start
-        rows = x if inner == 1 else x * jnp.exp(
-            tall - _dot((xcol == first).astype(jnp.float32), gc))
-        same = (xrow // outer) == (xcol // outer)
-        for p in range(1, outer // inner):
-            # For key j: the start of group p of its enclosing group, the
-            # reference of the rows that stand in that group; it serves
-            # the keys before it.
-            ref = (row // outer) * outer + p * inner
-            shift = _dot((col == ref).astype(jnp.float32), gc) - gc
-            keys = k * jnp.exp(jnp.where(
-                token < (token // outer) * outer + p * inner, shift,
-                -jnp.inf))
-            keep = same & ((xrow % outer) // inner == p)
-            out = out + jnp.where(keep, _dot_nt(rows, keys), 0.0)
+        rows = x if inner == 1 else x * tall(
+            jnp.exp(gc - _group_rows(gc, inner)))
+        groups = range(1, outer // inner)
+        # For key j: the start of group p of its enclosing group, the
+        # reference of the rows that stand in that group; it serves the
+        # keys before it.
+        keys = [k * jnp.exp(jnp.where(
+            token % outer < p * inner,
+            _group_rows(gc, outer, p * inner) - gc, -jnp.inf))
+            for p in groups]
+        if outer == c:
+            got = [jnp.zeros((parts * inner, c), jnp.float32)] + [
+                _dot_nt(jnp.concatenate(
+                    [rows[m * c + p * inner:m * c + (p + 1) * inner]
+                     for m in range(parts)], axis=0), keys_p)
+                for p, keys_p in zip(groups, keys)]
+            out = jnp.concatenate([g[m * inner:(m + 1) * inner]
+                                   for m in range(parts) for g in got],
+                                  axis=0)
+            continue
+        got = _dot_nt(rows, jnp.concatenate(keys, axis=0))
+        same = (row // outer) == (col // outer)
+        group = (row % outer) // inner           # the row's, inside outer
+        for p in groups:
+            out = out + jnp.where(same & (group == p),
+                                  got[:, (p - 1) * c:p * c], 0.0)
     return out
 
 
@@ -436,7 +485,7 @@ def _kda_block_update(q, k, v, gc, beta, st):
     eye = (row == col).astype(jnp.float32)
     bcol = _column(eye, beta)
     decay = jnp.exp(gc)                           # from the block's start
-    pairs = _kda_pairs(jnp.concatenate([q, k], axis=0), k, gc, row, col)
+    pairs = _kda_pairs(jnp.concatenate([q, k], axis=0), k, gc)
     n = -(bcol * pairs[c:])                       # strictly lower
     t = _nested_unit_lower_inverse(n, eye, row, col)
     u = _dot(t, v * bcol)                                         # [c, dv]

@@ -76,6 +76,19 @@ def inputs(tokens, valid, page, seed=0, heads=2, dk=32, dv=16):
                  for a in (q, k, v, g, beta, state))
 
 
+def neighbouring(tokens):
+    """``inputs`` with neighbouring keys nearly parallel, slow decays and
+    ``beta`` in (1.7, 2)."""
+    rng = np.random.default_rng(4)
+    q, k, v, g, beta, state = inputs(tokens, tokens, 64, seed=4)
+    common = np.abs(rng.normal(size=(1, 2, 32)))
+    k = common + 0.05 * rng.normal(size=(tokens, 2, 32))
+    k = (k / np.linalg.norm(k, axis=-1, keepdims=True)).astype(np.float32)
+    g = (0.02 * g).astype(np.float32)
+    beta = (1.7 + 0.3 * rng.uniform(size=beta.shape)).astype(np.float32)
+    return q, k, v, g, beta, state
+
+
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas"])
 @pytest.mark.parametrize("block", [16, 32, 64])
 def test_channelwise_scan_is_the_recurrence(kernel, block):
@@ -106,15 +119,8 @@ def test_neighbouring_keys_nearly_equal_and_beta_near_two(kernel):
     16-token block reach 1e5, and a product of powers returned an inverse
     wrong by 0.1 (the first chip run's probe read 0.05-0.12 with it; random
     one-orthant keys had passed). The nested inverse holds."""
-    tokens = 128
-    rng = np.random.default_rng(4)
-    q, k, v, g, beta, state = inputs(tokens, tokens, 64, seed=4)
-    common = np.abs(rng.normal(size=(1, 2, 32)))
-    k = common + 0.05 * rng.normal(size=(tokens, 2, 32))
-    k = (k / np.linalg.norm(k, axis=-1, keepdims=True)).astype(np.float32)
+    q, k, v, g, beta, state = neighbouring(128)
     assert (k[1:, 0] * k[:-1, 0]).sum(-1).min() > 0.97
-    g = (0.02 * g).astype(np.float32)
-    beta = (1.7 + 0.3 * rng.uniform(size=beta.shape)).astype(np.float32)
     want, states = recurrence(q, k, v, g, beta, state)
     o, end, inner = kda_scan(q, k, v, g, beta, state, 0, block=64,
                              kernel=kernel, interpret=True)
@@ -122,6 +128,84 @@ def test_neighbouring_keys_nearly_equal_and_beta_near_two(kernel):
     np.testing.assert_allclose(o, want, atol=2e-4 * scale)
     np.testing.assert_allclose(end, states[-1], atol=2e-4)
     np.testing.assert_allclose(inner, states[63], atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("block", [16, 32, 64])
+@pytest.mark.parametrize("keys", ["decays", "neighbours"])
+def test_a_blocks_pairwise_decays_are_the_direct_sum(keys, block, kernel):
+    """``_kda_pairs`` alone, a block's queries and keys stacked, against
+    ``sum_c x_ic k_jc exp(G_ic - G_jc)`` (``j < i``) summed in float64:
+    channels that lose e^-50 and more over the block, and the neighbouring
+    keys. The strictly lower part to 1e-5 of its largest entry, zeros on
+    and above the diagonal, finite everywhere."""
+    from jax.experimental import pallas as pl
+
+    from llmd_kv_cache_tpu.ops.gated_deltanet import _kda_pairs
+
+    q, k, _, g, _, _ = (inputs(block, block, block) if keys == "decays"
+                        else neighbouring(block))
+    x = np.concatenate([q[:, 0], k[:, 0]])
+    gc = np.cumsum(g[:, 0], axis=0, dtype=np.float32)
+    if keys == "decays":
+        assert gc[-1].min() < -50 < -1 < gc[-1].max()
+    if kernel:
+        def body(x_ref, k_ref, gc_ref, o_ref):
+            o_ref[...] = _kda_pairs(x_ref[...], k_ref[...], gc_ref[...])
+
+        build = pl.pallas_call(body, interpret=True, out_shape=(
+            jax.ShapeDtypeStruct((2 * block, block), jnp.float32)))
+    else:
+        build = jax.jit(_kda_pairs)
+    got = np.asarray(build(x, k[:, 0], gc))
+    G = gc.astype(np.float64)
+    lower = np.tril(np.ones((block, block), bool), -1)
+    with np.errstate(over="ignore"):     # above the diagonal: masked below
+        decay = np.exp(G[:, None, :] - G[None, :, :])
+    decay = np.where(lower[..., None], decay, 0)
+    want = np.concatenate([
+        np.einsum("ic,jc,ijc->ij", part.astype(np.float64),
+                  k[:, 0].astype(np.float64), decay)
+        for part in (q[:, 0], k[:, 0])])
+    assert np.isfinite(got).all()
+    assert (got[~np.tile(lower, (2, 1))] == 0).all()
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 * np.abs(want).max())
+
+
+def test_no_product_of_a_block_copies_rows_of_the_running_decay():
+    """The reference rows of ``gc`` are taken by slices and rolls: at the
+    published block no ``dot_general`` of ``_kda_block_update`` has ``gc``
+    itself or a 0/1 matrix (a comparison turned into numbers) for an
+    operand, as the eleven gathers of the first form had."""
+    from llmd_kv_cache_tpu.ops.gated_deltanet import _kda_block_update
+
+    c, d = 64, 128
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, f32) for s in (
+        (c, d), (c, d), (c, d), (c, d), (1, c), (d, d))]
+    closed = jax.make_jaxpr(_kda_block_update)(*shapes)
+    gc = closed.jaxpr.invars[3]
+
+    def products(jaxpr):
+        """What made each operand of every ``dot_general``, sub-jaxprs
+        (a ``jnp.roll`` is one) included."""
+        made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+        for e in jaxpr.eqns:
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from products(sub)
+            if e.primitive.name == "dot_general":
+                yield [(v, made_by.get(v)) for v in e.invars]
+
+    def selects(operand, maker):
+        return operand is gc or (
+            maker is not None
+            and maker.primitive.name == "convert_element_type"
+            and maker.invars[0].aval.dtype == jnp.bool_)
+
+    found = list(products(closed.jaxpr))
+    assert len(found) >= 16
+    assert not [p for p in found if any(selects(*o) for o in p)]
 
 
 def test_channelwise_chunks_of_unequal_size_chain_to_the_whole():
