@@ -363,11 +363,14 @@ class Request:
     # A model with linear layers: the row's working slot in the state pool
     # (0: none), the blocks whose pages matched at admission (the hit is
     # cut back to the deepest snapshot among them; passing their end, the
-    # prefill leaves a snapshot there for whoever shares as much), and the
-    # block hashes its prefill has left snapshots on, not yet announced.
+    # prefill leaves a snapshot there for whoever shares as much), the
+    # block hashes its prefill has left snapshots on, not yet announced,
+    # and the one of them its periodic checkpoint stands on: the next
+    # periodic checkpoint takes that one's slot (``_plan_snapshots``).
     state_slot: int = 0
     page_hit_blocks: int = 0
     snapshots: list[int] = field(default_factory=list)
+    checkpoint: Optional[int] = None
 
     @property
     def total_len(self) -> int:
@@ -1858,29 +1861,47 @@ class MiniEngine:
         are the boundaries in ``(pos, pos + n]`` that are (a) the last one
         a repeat of this prompt could resume from, (c) the end of the pages
         that matched at admission beyond the snapshot it was admitted on,
-        (b) a multiple of ``state_checkpoint_tokens``. The chunk's end is
-        free (the state is there); of the boundaries inside it the scan
-        gives one, the first of a, c, b."""
+        (b) the newest multiple of ``state_checkpoint_tokens``. The chunk's
+        end is free (the state is there); of the boundaries inside it the
+        scan gives one, the first of a, c, b.
+
+        A boundary that is only (b) is a periodic checkpoint, and a prefill
+        holds one: the next is written over the last (``StatePool.reserve``:
+        unannounced until the prefill ends, so nobody was admitted on pages
+        up to it; where a prefill-role engine has committed them already, a
+        reader's copy was dispatched at its admission, ahead of the program
+        that overwrites the slot). A prompt of any length thus costs the
+        pool three slots at most, and the others' resume points stay. The
+        one that trails the prefill's end is announced with (a) and (c): a
+        prompt that parts from this one resumes within a spacing of where
+        it parts if that is inside the last spacing, else from its deepest
+        snapshot, and leaves (c) for the next."""
         page = self.cfg.model.page_size
         pool = self.state_pool
         every = self.cfg.model.state_checkpoint_tokens
         last, end = (len(req.prompt) - 1) // page * page, pos + n
         shared = req.page_hit_blocks * page
-        wanted = [b for b in (
-            last, shared if shared > req.cached_len else 0,
-            *(range(-(-(pos + 1) // every) * every, end + 1, every)
-              if every else ()))
-            if pos < b <= end and b % page == 0]
+        kept = (last, shared if shared > req.cached_len else 0)
+        wanted = [b for b in (*kept, end // every * every if every else 0)
+                  if pos < b <= end and b % page == 0]
         snap, taken = [-1, 0, 0], []
         with phase(self._phases, PHASE_STEP_SNAPSHOT) as sp:
-            evicted = pool.evictions
+            evicted, replaced = pool.evictions, pool.replaced
             for b in dict.fromkeys(wanted):
                 inner = b != end
                 if inner and snap[1]:
                     continue  # the scan gives one state inside a chunk
-                slot = pool.reserve(req.block_hashes[b // page - 1])
+                h = req.block_hashes[b // page - 1]
+                periodic = b not in kept
+                slot = pool.reserve(h, req.checkpoint if periodic else None)
                 if slot is None:
                     continue
+                if periodic:
+                    if req.checkpoint not in pool.snapshots:
+                        # Replaced just now, or evicted since.
+                        req.snapshots = [s for s in req.snapshots
+                                         if s != req.checkpoint]
+                    req.checkpoint = h
                 taken.append((b, slot))
                 if inner:
                     snap[0], snap[1] = (b - pos) // page - 1, slot
@@ -1888,6 +1909,7 @@ class MiniEngine:
                     snap[2] = slot
             sp.set_attribute("snapshots", len(taken))
             sp.set_attribute("state_evicted", pool.evictions - evicted)
+            sp.set_attribute("replaced", pool.replaced - replaced)
         return snap, taken
 
     def _finish_prefill(self, req: Request, first_token: int) -> None:

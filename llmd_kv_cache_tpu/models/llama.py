@@ -248,8 +248,8 @@ class LlamaConfig:
     # pages: the page pools hold ``page_layers`` only, and a sequence's
     # states live in a pool of ``state_slots`` slots beside them
     # (``init_state_pool``), snapshotted at block boundaries so that a
-    # prefix hit finds the state its pages end on: at least every
-    # ``state_checkpoint_tokens`` tokens of a prefill (``engine.StatePool``).
+    # prefix hit finds the state its pages end on: a prefill's checkpoint
+    # trails it by under ``state_checkpoint_tokens`` (``_plan_snapshots``).
     linear_layers: tuple = ()
     linear: Any = None  # Optional[LinearAttention]
     state_slots: int = 0

@@ -11,11 +11,14 @@ of cache: the pages up to a boundary and a snapshot at it
 Snapshots leave least-recently-used first, by a heap kept as
 ``BlockManager``'s is; a page eviction takes the snapshots that stand on
 the evicted block or after it (``drop_dependents``: without their pages
-they serve nobody). What the index is told: a snapshot is a
-``BlockStored`` of cache group ``group_idx`` and kind ``mamba`` on the
-block it stands on, once that block's pages are committed (``announce``),
-and a ``BlockRemoved`` of that group when it leaves. Events gather here
-and leave in the batch of whoever calls ``drain``.
+they serve nobody). A prefill keeps one periodic checkpoint behind it:
+passing the next one, it writes it over its last (``reserve``'s
+``give_up``), so a long prompt costs the pool one slot for them and not
+one every ``state_checkpoint_tokens``. What the index is told: a snapshot
+is a ``BlockStored`` of cache group ``group_idx`` and kind ``mamba`` on
+the block it stands on, once that block's pages are committed
+(``announce``), and a ``BlockRemoved`` of that group when it leaves.
+Events gather here and leave in the batch of whoever calls ``drain``.
 
 Slot 0 is spare (``llama.init_state_pool``) and never handed out.
 """
@@ -58,6 +61,9 @@ class StatePool:
         # Of those, the snapshots that left because a page under them did
         # (``drop_dependents``): the pages ran out before the slots.
         self.orphaned = 0
+        # Not among them: periodic checkpoints a prefill overwrote with
+        # its next one (``reserve``'s ``give_up``).
+        self.replaced = 0
         self._removed: list[int] = []
         self._stored: list[GenericEvent] = []
 
@@ -66,7 +72,8 @@ class StatePool:
                 "state_working": len(self.working),
                 "state_snapshots": len(self.snapshots),
                 "state_evictions": self.evictions,
-                "state_orphaned": self.orphaned}
+                "state_orphaned": self.orphaned,
+                "state_replaced": self.replaced}
 
     # -- slots --
 
@@ -137,15 +144,24 @@ class StatePool:
                 return depth, snap.slot
         return 0, None
 
-    def reserve(self, h: int) -> Optional[int]:
+    def reserve(self, h: int, give_up: Optional[int] = None) -> Optional[int]:
         """A slot for a snapshot about to be written on block ``h``; None
         where one stands there already (it counts as used now) or every
-        slot is a working one."""
+        slot is a working one. The slot is that of the snapshot on
+        ``give_up`` where that one was never announced (the caller's own
+        periodic checkpoint, which its next one replaces: nobody has
+        matched pages up to it, so nobody resumes from it); it leaves
+        without an event and is counted as ``replaced``, not evicted."""
         snap = self.snapshots.get(h)
         if snap is not None:
             self._touch(h, snap)
             return None
-        return self._take()
+        old = self.snapshots.get(give_up)
+        if old is None or old.announced:
+            return self._take()
+        del self.snapshots[give_up]
+        self.replaced += 1
+        return old.slot
 
     def store(self, h: int, slot: int, chain: Sequence[int],
               parent_hash: int, tokens: Sequence[int]) -> None:

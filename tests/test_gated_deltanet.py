@@ -234,23 +234,35 @@ def test_a_full_chunk_of_the_gated_layer_attends_per_head(model):
 
 
 def test_a_hit_through_each_rule_that_leaves_a_snapshot(model):
-    """(a) a prompt's last block boundary, (b) every multiple of
-    ``state_checkpoint_tokens`` (64 here) a prefill passes, (c) the end of
-    the pages a prefill matched beyond the snapshot it was admitted on.
-    Each hit is admitted at that depth with the state copied from the
-    snapshot, and its logits agree with the reference's."""
+    """(a) a prompt's last block boundary, (b) the newest multiple of
+    ``state_checkpoint_tokens`` (64 here) a prefill has passed: its one
+    periodic checkpoint, (c) the end of the pages a prefill matched beyond
+    the snapshot it was admitted on. Each hit is admitted at that depth
+    with the state copied from the snapshot, and its logits agree with the
+    reference's. A prompt that forks off more than a spacing before the
+    first one's end finds pages and no state: it is admitted at 0 and
+    leaves (c) for the next."""
     eng = engine(model)
     first = prompt_of(150, 2)
     _, cold = serve(eng, "first", first)
+    depths = sorted(len(s.chain) * 16
+                    for s in eng.state_pool.snapshots.values())
+    assert depths == [128, 144]              # 64 gave its slot to 128
     # (a): the same prompt again resumes at (150 - 1) // 16 * 16.
     again, hit = serve(eng, "again", first)
     assert again.cached_len == 144
     assert np.abs(hit - cold).max() / np.abs(cold).max() < SAME
-    # (b): one that shares 100 tokens has 6 blocks of pages (96 tokens)
-    # to match and a snapshot at 64, none at 96.
+    # (b): one that shares 140 tokens has 8 blocks of pages (128 tokens)
+    # to match and the first's trailing checkpoint at their end.
+    near = first[:140] + prompt_of(40, 7)
+    req, logits = serve(eng, "near", near)
+    assert (req.page_hit_blocks, req.cached_len) == (8, 128)
+    assert nearest(model, near, 179, logits) < TOLERANCE
+    # The fork at 100: 6 blocks of pages (96 tokens) and no snapshot under
+    # them since the checkpoint at 64 was written over.
     second = first[:100] + prompt_of(40, 3)
     req, logits = serve(eng, "second", second)
-    assert (req.page_hit_blocks, req.cached_len) == (6, 64)
+    assert (req.page_hit_blocks, req.cached_len) == (6, 0)
     assert nearest(model, second, 139, logits) < TOLERANCE
     # (c): passing 96, the second left a snapshot there for the third.
     third = first[:100] + prompt_of(25, 4)

@@ -338,20 +338,34 @@ def test_prefill_in_unequal_chunks_and_decode_through_the_pool(model,
 
 def test_a_hit_resumes_from_a_snapshot_beside_gqa_pages(model):
     """The same prompt again resumes at its last block boundary, from the
-    snapshot there and the pages under it; one that shares 100 tokens
-    resumes at 64 (a multiple of ``state_checkpoint_tokens``), where its 3
-    matched pages (96 tokens) have a snapshot."""
+    snapshot there and the pages under it; one that shares 200 tokens
+    resumes at 192, the first prefill's periodic checkpoint (the newest
+    multiple of ``state_checkpoint_tokens`` it passed: 64 and 128 gave
+    their slot to it); one that shares 100 finds 3 pages (96 tokens) and
+    no state under them, is admitted at 0 and leaves a snapshot at the end
+    of those pages, from which a third is served."""
     eng = engine(model)
-    first = prompt_of(150, 2)
+    first = prompt_of(230, 2)
     _, cold = serve(eng, "first", first)
+    depths = sorted(len(s.chain) * 32
+                    for s in eng.state_pool.snapshots.values())
+    assert depths == [192, 224]
     again, hit = serve(eng, "again", first)
-    assert again.cached_len == 128
+    assert again.cached_len == 224
     assert np.abs(hit - cold).max() / np.abs(cold).max() < SAME
-    assert nearest(model, first, 149, hit) < TOLERANCE
+    assert nearest(model, first, 229, hit) < TOLERANCE
+    near = first[:200] + prompt_of(40, 7)
+    req, logits = serve(eng, "near", near)
+    assert (req.page_hit_blocks, req.cached_len) == (6, 192)
+    assert nearest(model, near, 239, logits) < TOLERANCE
     second = first[:100] + prompt_of(40, 3)
     req, logits = serve(eng, "second", second)
-    assert (req.page_hit_blocks, req.cached_len) == (3, 64)
+    assert (req.page_hit_blocks, req.cached_len) == (3, 0)
     assert nearest(model, second, 139, logits) < TOLERANCE
+    third = first[:100] + prompt_of(25, 4)
+    req, logits = serve(eng, "third", third)
+    assert (req.page_hit_blocks, req.cached_len) == (3, 96)
+    assert nearest(model, third, 124, logits) < TOLERANCE
 
 
 def test_a_snapshot_whose_page_is_evicted_is_orphaned(model):
