@@ -316,7 +316,7 @@ def bench_engine_telemetry() -> dict:
             engine_mod.EngineConfig(
                 model=cfg, num_pages=128, max_pages_per_seq=16,
                 model_name="bench-telemetry", pod_identifier="p",
-                decode_burst=8, telemetry=telemetry,
+                telemetry=telemetry,
             ),
             params=params, seed=0,
         )
@@ -372,7 +372,7 @@ def bench_engine_telemetry() -> dict:
 
     return {
         "metric": "engine-telemetry overhead on the decode-step path "
-                  "(batch 4, burst 8, pool scrape every 16 steps)",
+                  "(batch 4, pool scrape every 16 steps)",
         "value": round(overhead_pct, 4),
         "unit": "% of decode-step p50",
         "vs_baseline": 1.0,

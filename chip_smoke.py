@@ -291,8 +291,7 @@ def attention_case(rng, *, batch, ctx_max, kv_heads=8, head_dim=128,
 
 def decode_agreement(rng, interpret, *, batch=8, ctx_max=4096,
                      q_heads=16, kv_heads=8, head_dim=128, kv_dtype=None,
-                     window=None, sinks=None, shared_kv=False,
-                     tail_steps=0) -> float:
+                     window=None, sinks=None, shared_kv=False) -> float:
     """Flash-decode kernel vs ``ops.paged_attention`` on one layer."""
     import jax.numpy as jnp
     import numpy as np
@@ -311,32 +310,13 @@ def decode_agreement(rng, interpret, *, batch=8, ctx_max=4096,
                     PAGE + 1, ctx_max // 2, ctx_max // 3][:batch], np.int32)
     q = jnp.asarray(rng.normal(size=(batch, q_heads, head_dim)),
                     jnp.bfloat16)
-    tail = {}
-    if tail_steps:
-        tshape = (batch, tail_steps, kv_heads, head_dim)
-        tail = dict(
-            tail_k=jnp.asarray(rng.normal(size=tshape), jnp.bfloat16),
-            tail_v=jnp.asarray(rng.normal(size=tshape), jnp.bfloat16),
-            tail_lens=jnp.asarray(
-                rng.integers(1, tail_steps + 1, batch), jnp.int32))
-        if shared_kv:
-            tail["tail_v"] = None
     ctx = jnp.asarray(ctx)
     out = pallas_paged_decode_attention(
         q, k, v, table, ctx, sliding_window=window, sinks=sinks,
-        shared_kv=shared_kv, layer_idx=1, interpret=interpret, **tail)
-    if tail_steps:
-        # Tail contract: paged keys cover [0, ctx); the query sits at the
-        # tail's end.
-        q_pos = (ctx + tail["tail_lens"] - 1)[:, None]
-        ref_tail = dict(tail, tail_v=tail["tail_k"] if shared_kv
-                        else tail["tail_v"])
-    else:
-        q_pos = (ctx - 1)[:, None]
-        ref_tail = {}
-    ref = paged_attention(q[:, None], k[1], v[1], table, q_pos, ctx,
-                          sliding_window=window, attention_sinks=sinks,
-                          **ref_tail)[:, 0]
+        shared_kv=shared_kv, layer_idx=1, interpret=interpret)
+    ref = paged_attention(q[:, None], k[1], v[1], table, (ctx - 1)[:, None],
+                          ctx, sliding_window=window,
+                          attention_sinks=sinks)[:, 0]
     return _rel_err(out, ref)
 
 
@@ -896,10 +876,6 @@ def arm_lines(interpret: bool):
          lambda: decode_agreement(rng, interpret, **swa)),
         (f"window {win} + 4 sinks prefill, 32/8x128, ctx {wctx}",
          lambda: prefill_agreement(rng, interpret, chunk=chunk, **swa)),
-        ("burst tail rows (T=8) decode, 16/8x128",
-         lambda: decode_agreement(rng, interpret, tail_steps=8, **base)),
-        ("burst tail rows (T=8) MLA decode, 16 heads x640",
-         lambda: decode_agreement(rng, interpret, tail_steps=8, **mla)),
     ]
 
 
@@ -949,8 +925,6 @@ def engine_arm_lines(S, interpret: bool):
     return [
         ("engine ragged_attention=True",
          lambda: against_default(ragged_attention=True)),
-        ("engine decode_burst=8 (burst tails in the scan)",
-         lambda: against_default(decode_burst=8)),
         ("engine kv_cache_dtype=f8_e4m3",
          lambda: against_default(kv_cache_dtype="f8_e4m3")),
     ]

@@ -42,7 +42,7 @@ def main() -> None:
         engines[dtype] = MiniEngine(
             EngineConfig(model=cfg, num_pages=128, max_pages_per_seq=16,
                          model_name="fp8-demo", pod_identifier=f"pod-{dtype}",
-                         kv_cache_dtype=dtype, decode_burst=8),
+                         kv_cache_dtype=dtype),
             params=params, seed=0)
 
     outs = {}

@@ -200,18 +200,16 @@ OFFLOAD_SHED_BLOCKS = Counter(
     ["medium"],
 )
 
-# Admission-to-first-schedule delay: a request enqueued while a fused
-# decode burst is in flight waits for the burst to drain before the
-# scheduler first picks it up — up to decode_burst tokens of added TTFT
-# under load. This histogram makes that cost observable so operators can
-# trade decode_burst against admission latency with data. Observed at the
+# Admission-to-first-schedule delay: a request waits in the queue behind
+# the step in flight and older prefills before the scheduler first picks
+# it up; the CoDel shedder (resilience.shedding) acts on it. Observed at the
 # request's first scheduling visit, BEFORE any deferred storage restore:
 # restore time is a storage-tier cost tracked by the kv_offload_* families,
 # not a scheduling wait.
 ENGINE_ADMISSION_DELAY = Histogram(
     "kvcache_engine_admission_delay_seconds",
-    "enqueue() to first scheduler pick (burst-admission latency; excludes "
-    "any deferred storage-restore wait that follows)",
+    "enqueue() to first scheduler pick (excludes any deferred "
+    "storage-restore wait that follows)",
     buckets=(1e-4, 1e-3, 5e-3, 1e-2, 5e-2, 0.1, 0.5, 1.0, 5.0),
 )
 

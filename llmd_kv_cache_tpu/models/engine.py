@@ -87,8 +87,6 @@ from .llama import (
     prefill_per_head,
     step_decode_pallas,
     step_decode_pallas_state,
-    step_decode_steps,
-    step_decode_steps_hybrid,
     step_forward,
     step_forward_hybrid,
     step_forward_state,
@@ -162,11 +160,8 @@ class EngineConfig:
     # Hybrid models: size of the SWA group's separate page pool (None →
     # num_pages). SWA pages are allocated just-in-time and reclaimed as
     # slots fall out of the window, so per-request peak demand is
-    # window + max(prefill-chunk, decode_burst) pages (+ the decode page),
-    # not prompt length — the memory win of hybrid attention. Fused bursts
-    # freeze the window tables for up to decode_burst tokens and reclaim
-    # at the burst boundary; an undersized pool degrades that step to
-    # single-token decoding rather than failing.
+    # window + prefill-chunk pages (+ the decode page), not prompt length
+    # — the memory win of hybrid attention.
     num_swa_pages: Optional[int] = None
     max_pages_per_seq: int = 64
     max_batch: int = 8
@@ -229,13 +224,6 @@ class EngineConfig:
     # most this many tokens (vLLM-style), bounding per-step activation
     # memory for long prompts. Must be a multiple of the page size.
     max_prefill_tokens: int = 512
-    # Fused decode bursts: up to this many greedy tokens per device
-    # dispatch (lax.scan inside one jit). 1 = one token per step() —
-    # finest-grained continuous batching; larger values amortize dispatch
-    # overhead at the cost of admitting new requests only between bursts.
-    # Bursts are bucketed to powers of two so the jit cache stays
-    # O(log burst). Every cell runs 1; a burst has no line on the ledger.
-    decode_burst: int = 1
     # Ragged single-kernel attention: pack the step's admitted prefill
     # chunk and every active decode row into ONE flat-token-axis dispatch
     # (ops.pallas_paged_attention.pallas_paged_ragged_attention) instead
@@ -243,8 +231,8 @@ class EngineConfig:
     # A decode row is a 1-token ragged row, a prefill chunk a longer one;
     # per-sequence padding disappears (the flat axis pads only to a
     # power-of-two token bucket) and mixed traffic stops paying two
-    # kernel pipelines' fill/drain per step. Single-shard, non-hybrid,
-    # decode_burst=1 only — other configurations warn once and keep the
+    # kernel pipelines' fill/drain per step. Single-shard, non-hybrid
+    # only — other configurations warn once and keep the
     # padded two-kernel path; the same fallback serves shapes the kernel
     # cannot take (unaligned head_dim on real TPU, fp8 pages whose
     # kv_heads*page_size is not a 32 multiple). Runs interpreted on CPU.
@@ -272,7 +260,7 @@ class EngineConfig:
     # costs only that request's TTFT, never the running batch).
     handoff_wait_s: float = 10.0
     # CoDel-style overload shedding at admission (resilience.shedding):
-    # when burst-admission delay (enqueue → first scheduler pick) stays
+    # when admission delay (enqueue → first scheduler pick) stays
     # above the target for a full interval, ``enqueue`` sheds
     # lowest-priority work first instead of letting the queue grow
     # without bound. 0 (default) disables the shedder entirely — no
@@ -322,7 +310,7 @@ class Request:
     # _admit blocked them for up to the 30 s deadline).
     restore_pending: bool = False
     # enqueue() timestamp, cleared at first prefill schedule — feeds the
-    # burst-admission-delay histogram.
+    # admission-delay histogram.
     enqueued_at: Optional[float] = None
     # W3C traceparent carried from the scorer (ScoreResponse.traceparent →
     # enqueue()): when set, the engine parents admission/prefill/decode
@@ -846,9 +834,6 @@ class MiniEngine:
                      "a restored prefix would come back without its state)"),
                     (mesh is not None,
                      "a mesh (the state pool is not sharded)"),
-                    (self.cfg.decode_burst > 1,
-                     "decode_burst > 1 (a fused burst does not carry the "
-                     "states through its scan)"),
                     (self.cfg.ragged_attention,
                      "ragged_attention (the recurrence is served by the "
                      "padded step programs)"),
@@ -1017,26 +1002,11 @@ class MiniEngine:
                     "using XLA attention",
                     mcfg.kv_cache_heads, self._tp, mcfg.page_size)
                 use_pallas = False
-        # Hybrid: fused bursts run the grouped two-pool scan
-        # (forward_decode_steps_hybrid) with freeze-and-reclaim SWA paging,
-        # and the flash-decode kernel applies there per layer (each layer
-        # sees only its own group's table/window). The SINGLE-token hybrid
-        # step stays on the XLA grouped forward — at one token per dispatch
-        # the kernel win is noise next to dispatch cost, and keeping one
-        # code path for it bounds the jit-cache footprint.
-        hybrid_burst_pallas = use_pallas and self.hybrid
         if self.hybrid:
-            if use_pallas and self.cfg.use_pallas_decode:
-                if self.cfg.decode_burst > 1:
-                    logger.info(
-                        "hybrid model: Pallas decode applies to fused "
-                        "bursts; single-token steps use XLA attention")
-                else:
-                    logger.warning(
-                        "hybrid model with decode_burst=1: Pallas decode "
-                        "only runs inside fused bursts, so every decode "
-                        "uses XLA attention (set decode_burst>1 to engage "
-                        "the kernel)")
+            # A two-pool (window + global) model steps through the XLA
+            # grouped forward over both pools: the kernels take one page
+            # table a call, and no step program hands each layer its own
+            # group's (ROADMAP queue 2, M5).
             use_pallas = False
         rows = max(1, self.cfg.decode_batch_rows)
         if mcfg.kv_cache_heads == 1:
@@ -1096,21 +1066,6 @@ class MiniEngine:
         if self.state_pool is not None:
             self._decode_forward = _with_state(self._decode_forward)
             self._prefill_forward = _with_state(self._prefill_forward)
-        self._decode_multi = functools.partial(
-            step_decode_steps, use_pallas=use_pallas,
-            interpret=use_pallas and interpret, mesh=pallas_mesh,
-            batch_rows=rows if use_pallas else 1,
-        )
-        if self.hybrid:
-            hybrid_mesh = (mesh if hybrid_burst_pallas and self._tp > 1
-                           else None)
-            self._decode_multi = functools.partial(
-                step_decode_steps_hybrid, use_pallas=hybrid_burst_pallas,
-                interpret=hybrid_burst_pallas and interpret,
-                mesh=hybrid_mesh,
-                batch_rows=(rows if hybrid_burst_pallas
-                            and hybrid_mesh is None else 1),
-            )
         if self._pp > 1:
             from ..parallel.pp_serve import make_pp_serve_forward
 
@@ -1125,32 +1080,6 @@ class MiniEngine:
                 else step_program(make_pp_serve_forward(
                     mesh, mcfg, self.params,
                     microbatches=self._pp_decode_mb)))
-            if self.cfg.decode_burst > 1:
-                logger.warning("pp serving v1 decodes single-token; "
-                               "decode_burst=%d clamped to 1",
-                               self.cfg.decode_burst)
-
-        # Burst size: the power-of-two floor of cfg.decode_burst, fixed for
-        # the engine's lifetime — ONE fused-decode program. Per-row budgets
-        # freeze finished rows on-device, so ticks past every row's budget
-        # cost ~a token's compute; shrinking the burst near a request's
-        # tail instead (an earlier design) compiled a fresh program per
-        # smaller bucket mid-serving, cratering steady-state decode on
-        # short generations.
-        self._burst = 1
-        if mcfg.is_dsa and self.cfg.decode_burst > 1:
-            logger.warning(
-                "learned sparse attention decodes single-token (a burst's "
-                "tail has no index keys); decode_burst=%d clamped to 1",
-                self.cfg.decode_burst)
-        while (self._burst * 2 <= self.cfg.decode_burst and self._pp == 1
-               and not mcfg.is_dsa):
-            self._burst *= 2
-        # Latched when the SWA pool proves too small for burst transients:
-        # the engine then decodes single-token for its lifetime (warned
-        # once) — deterministic behavior instead of a doomed per-step
-        # allocation retry.
-        self._burst_degraded = False
 
         # Ragged single-kernel scheduling (EngineConfig.ragged_attention):
         # resolve eligibility ONCE — the blockers are all engine-lifetime
@@ -1167,10 +1096,6 @@ class MiniEngine:
                                 "in the padded step programs)")
             if mesh is not None:
                 blockers.append("mesh-sharded serving (tp/sp/pp)")
-            if self._burst != 1:
-                blockers.append(
-                    f"decode_burst={self.cfg.decode_burst} (fused bursts "
-                    "scan the padded decode program)")
             if on_tpu and not _pallas_head_dim_supported(kernel_width):
                 blockers.append(
                     f"cache payload width {kernel_width} is not "
@@ -1201,14 +1126,12 @@ class MiniEngine:
         # The last prefill chunk of a step that read nothing
         # (``_bound_chunks``).
         self._chunk_ahead: Optional[_Unread] = None
-        # Not the fused bursts (the scan samples for itself: ROADMAP D2),
-        # not a sharded engine (its tokens come back laid out over a mesh
+        # Not a sharded engine (its tokens come back laid out over a mesh
         # and ``prev`` would be a second form of every program), not a
         # hybrid one (its window pages are ensured and reclaimed from
         # ``computed_len``, a token at a time): those read each program
         # before the next is built, and take no ``prev``.
-        self._defers = (mesh is None and not self.hybrid
-                        and self._burst == 1)
+        self._defers = mesh is None and not self.hybrid
         # (Every padded decode form counts what the model counts.)
         self._prev = jax.device_put(
             np.zeros((self.cfg.max_batch + len(mcfg.step_counters),),
@@ -1220,19 +1143,11 @@ class MiniEngine:
             return {"backend": "pallas" if pallas else "xla",
                     "interpret": bool(pallas and interpret)}
 
-        if self.hybrid:
-            # Single-token hybrid steps and hybrid prefill run the XLA
-            # grouped forward; only fused bursts reach the kernel.
-            decode_pallas = hybrid_burst_pallas and self._burst > 1
-            prefill_pallas = False
-        else:
-            decode_pallas = use_pallas
-            prefill_pallas = bool(prefill_pallas and use_pallas)
         self._attention_backends = {
             "platform": dev0.platform,
             "device_kind": dev0.device_kind,
-            "decode": phase(decode_pallas),
-            "prefill": phase(prefill_pallas),
+            "decode": phase(use_pallas),
+            "prefill": phase(bool(prefill_pallas and use_pallas)),
             "ragged": phase(True) if self._ragged else None,
         }
         logger.info("engine %s attention backends: %s",
@@ -1325,7 +1240,7 @@ class MiniEngine:
         # restore typically costs — recompute is the faster path then.
         self._restore_latency_ema = 0.0
 
-        # Admission overload shedding (CoDel over burst-admission delay).
+        # Admission overload shedding (CoDel over admission delay).
         # None unless configured — the disabled path costs one attribute
         # load per enqueue/step.
         self.shedder: Optional[CoDelShedder] = None
@@ -1678,8 +1593,7 @@ class MiniEngine:
             # under queue pressure the offload round trip is the first
             # cost to drop (recompute keeps the scheduler moving).
             req.restore_pending = False
-        # Burst-admission latency: with decode_burst > 1 the first prefill
-        # chunk can only run once the in-flight burst drains — observed at
+        # Admission delay (enqueue → first scheduler pick) is observed at
         # first schedule (kvcache_engine_admission_delay_seconds).
         req.enqueued_at = time.monotonic()
         if handoff:
@@ -2360,22 +2274,6 @@ class MiniEngine:
         table[: len(req.pages)] = req.pages
         return table
 
-    def _release_burst_transients(self, chunk: list[Request]) -> None:
-        """Hand back SWA pages pre-extended for a burst that cannot run.
-
-        Slots beyond each request's current decode block exist only
-        because of this burst attempt (after a completed burst,
-        ``computed_len`` has advanced past every written slot), so they
-        are private, uncommitted, and safe to free directly.
-        """
-        page_size = self.cfg.model.page_size
-        for req in chunk:
-            keep = req.computed_len // page_size + 1
-            while len(req.swa_pages) > keep:
-                page = req.swa_pages.pop()
-                if page:
-                    self.swa_manager.free_pages.append(page)
-
     def _swa_table_for(self, req: Request) -> np.ndarray:
         table = np.zeros((self.cfg.max_pages_per_seq,), np.int32)
         table[: len(req.swa_pages)] = req.swa_pages
@@ -2651,8 +2549,8 @@ class MiniEngine:
             req = self.requests[rid]
             if req.prefill_pos is not None:
                 if req.enqueued_at is not None:
-                    # First scheduler pick: the wait is the burst-admission
-                    # latency (plus queueing behind older prefills). A
+                    # First scheduler pick: the wait is the queueing
+                    # behind older prefills and the step in flight. A
                     # deferred storage restore may still follow — that wait
                     # is a storage cost (kv_offload_*), deliberately not
                     # part of this scheduling metric.
@@ -2689,12 +2587,9 @@ class MiniEngine:
         decode step for every decoding request.
 
         Returns {request_id: newest_token}. Decode is batched into a single
-        jit call with padding up to max_batch; when ``decode_burst > 1``
-        each call may emit a power-of-two burst of tokens per request (all
-        of a request's burst tokens land in ``req.output``; the returned
-        dict carries the newest). ``enqueue``d requests prefill here,
-        chunk-at-a-time — a long prompt delays running decodes by one
-        chunk per step, never its whole prefill.
+        jit call with padding up to max_batch. ``enqueue``d requests
+        prefill here, chunk-at-a-time — a long prompt delays running
+        decodes by one chunk per step, never its whole prefill.
         """
         tel = self.telemetry
         step_t0 = time.monotonic() if tel is not None else 0.0
@@ -2742,18 +2637,13 @@ class MiniEngine:
             cur, self._unread = self._unread, None
             one = len(active) <= self.cfg.max_batch
             if cur is None and not (self._defers and one):
-                # Fused bursts (the scan samples for itself), sharded or
-                # hybrid engines (``_defers``), several chunks: each
-                # program is read before the next is built.
+                # Sharded or hybrid engines (``_defers``), several
+                # chunks: each program is read before the next is built.
                 emitted.update(self._read_first(first))
                 for at in range(0, len(active), self.cfg.max_batch):
                     chunk = active[at:at + self.cfg.max_batch]
-                    if self._burst > 1:
-                        emitted.update(
-                            self._decode_chunk_burst(chunk, self._burst))
-                    else:
-                        emitted.update(
-                            self._read_decode(self._launch_decode(chunk)))
+                    emitted.update(
+                        self._read_decode(self._launch_decode(chunk)))
             else:
                 # ``cur``: the decode program this step returns the tokens
                 # of, launched a step ago or now. While this engine only
@@ -3061,7 +2951,7 @@ class MiniEngine:
                 tok = int(next_tokens[i])
                 req.output.append(tok)
                 out[req.request_id] = tok
-                self._row_emitted(req, 1, now)
+                self._row_emitted(req, now)
 
         if prefill_req is not None:
             req = prefill_req
@@ -3078,25 +2968,24 @@ class MiniEngine:
                     self._commit_prefill_chunk(req)
         return out
 
-    def _row_emitted(self, req: Request, taken: int, now: float) -> None:
-        """Bookkeeping of one row's ``taken`` newly decoded tokens."""
+    def _row_emitted(self, req: Request, now: float) -> None:
+        """Bookkeeping of one row's newly decoded token."""
         if self.telemetry is not None:
-            self.telemetry.on_decode_tokens(req.request_id, taken, now)
+            self.telemetry.on_decode_tokens(req.request_id, 1, now)
         if req.traceparent is not None:
             # Event-style span: marks the emission point in the trace.
             span_event(SPAN_ENGINE_DECODE_STEP, req.traceparent,
-                       request_id=req.request_id, tokens=taken,
+                       request_id=req.request_id, tokens=1,
                        computed_len=req.computed_len,
                        process=self.cfg.pod_identifier)
         if len(req.output) >= req.max_new_tokens:
             req.done = True
 
     def _decode_batch_arrays(self, chunk: list[Request], rows: int = 0):
-        """Padded per-row decode inputs shared by the single-step and burst
-        paths: (last tokens, computed context, page tables). The last
-        token may have come from sampling with its KV not yet computed —
-        that is why positions derive from ``computed_len``, and both paths
-        must keep doing so. ``rows`` overrides the ``max_batch`` padding
+        """Padded per-row decode inputs: (last tokens, computed context,
+        page tables). The last token may have come from sampling with its
+        KV not yet computed — that is why positions derive from
+        ``computed_len``. ``rows`` overrides the ``max_batch`` padding
         target (the unpipelined-pp decode bucket)."""
         b = rows or self.cfg.max_batch
         last = np.zeros((b,), np.int32)
@@ -3107,83 +2996,6 @@ class MiniEngine:
             ctx[i] = req.computed_len
             tables[i] = self._page_table_for(req)
         return last, ctx, tables
-
-
-    def _decode_chunk_burst(self, chunk: list[Request], steps: int) -> dict[str, int]:
-        """Fused multi-token decode: one dispatch emits up to ``steps``
-        greedy tokens per row; each row decodes until its own remaining
-        budget and freezes after.
-
-        Hybrid models run the two-pool scan with freeze-and-reclaim SWA
-        paging: the SWA table is pre-extended through every
-        page the burst will touch, frozen for the scan, and slots that
-        slid out of the window are reclaimed once per burst on the host —
-        so SWA families keep the burst's dispatch-amortization win at the
-        cost of up to ``steps`` tokens of extra transient window pages."""
-        page_size = self.cfg.model.page_size
-        if self.hybrid and self._burst_degraded:
-            return self._decode_chunk(chunk)
-        ph = self._phases
-        degraded = False
-        with phase(ph, PHASE_STEP_INPUTS):
-            last, ctx, tables = self._decode_batch_arrays(chunk)
-            budgets = np.zeros((self.cfg.max_batch,), np.int32)
-            swa_tables = [np.zeros_like(tables)] if self.hybrid else []
-            for i, req in enumerate(chunk):
-                budgets[i] = req.max_new_tokens - len(req.output)
-                if self.hybrid:
-                    taken = min(steps, int(budgets[i]))
-                    # The burst writes KV at positions computed_len ..
-                    # computed_len+taken-1; every SWA slot it touches needs
-                    # a live page before the tables freeze. If the pool
-                    # cannot cover the whole batch's burst transient (pool
-                    # sized to the single-step bound), latch single-token
-                    # decoding for this engine instead of dying mid-decode:
-                    # the transients already taken for the chunk are handed
-                    # back first, so the single-step path's own page needs
-                    # are met.
-                    try:
-                        self._swa_ensure(
-                            req, (req.computed_len + max(taken, 1) - 1)
-                            // page_size)
-                    except RuntimeError:
-                        self._release_burst_transients(chunk)
-                        self._burst_degraded = degraded = True
-                        logger.warning(
-                            "SWA pool cannot cover a %d-token burst "
-                            "transient; decoding single-token from now on "
-                            "(size num_swa_pages for window + decode_burst "
-                            "to keep bursts)", steps)
-                        break
-                    swa_tables[0][i] = self._swa_table_for(req)
-            packed, shapes = pack_inputs(
-                (last, tables, *swa_tables, ctx, budgets))
-        if degraded:
-            return self._decode_chunk(chunk)
-
-        # Dispatched as every program of a step is: see _prefill_chunk.
-        with self._dispatch_phase(None, len(chunk), len(chunk) * steps,
-                                  self.cfg.max_batch * steps,
-                                  self._decode_multi) as sp:
-            toks, _, pools = self._decode_multi(
-                self.params, self.cfg.model, self._launch_input(packed, sp),
-                self._pools(), shapes=shapes, steps=steps)
-            self._take_pools(pools)
-            toks.copy_to_host_async()
-        with self._fetch_phase(self._launch):
-            toks_host = np.asarray(toks)
-        out = {}
-        now = time.monotonic() if self.telemetry is not None else 0.0
-        for i, req in enumerate(chunk):
-            taken = min(steps, int(budgets[i]))
-            burst = [int(t) for t in toks_host[i, :taken]]
-            req.output.extend(burst)
-            req.computed_len += taken
-            out[req.request_id] = burst[-1]
-            self._row_emitted(req, taken, now)
-            if self.hybrid:
-                self._swa_reclaim(req)
-        return out
 
     def _decode_chunk(self, chunk: list[Request]) -> dict[str, int]:
         """One decode step of ``chunk``, read at once."""
@@ -3292,7 +3104,7 @@ class MiniEngine:
             tok = int(next_tokens[i])
             req.output.append(tok)
             out[req.request_id] = tok
-            self._row_emitted(req, 1, now)
+            self._row_emitted(req, now)
             if self.hybrid:
                 self._swa_reclaim(req)
         return out

@@ -1561,8 +1561,8 @@ def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
 
 def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                           ctx_lens, new_lens, attention_fn, last_only=False,
-                          tails=None, ragged=None, kernel=None,
-                          counters=None, state=None):
+                          ragged=None, kernel=None, counters=None,
+                          state=None):
     """Shared transformer body over grouped KV pools.
 
     ``k_caches[g]`` holds group g's layers stacked in ``cfg.group_layers(g)``
@@ -1579,16 +1579,6 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     token (``new_lens - 1``) — the prefill-chunk case, where the full
     [seq, vocab] lm_head matmul and its fp32 materialization are pure waste
     (chunk × vocab of matmul and of HBM write on logits nobody reads).
-
-    ``tails=(tail_ks, tail_vs, ctx_base)`` is the fused-decode-burst mode
-    (seq == 1): the paged caches are READ-ONLY (XLA copies large scan
-    carries every iteration, so the burst scan must not carry them) and
-    the current token's K/V is written into the burst-local tail buffers
-    ``tail_ks[g]`` [layers_g, batch, steps, kvh, width] at slot
-    ``ctx_lens - ctx_base`` instead; attention folds the tail after the
-    paged keys (ops-level ``tail_k/tail_v/tail_lens``). Returns
-    ``(logits, tail_ks, tail_vs)`` in place of the caches; the caller
-    scatters the accumulated tail into the caches once, outside the scan.
 
     ``ragged=row_starts`` ([rows+1] flat-token prefix sums) is the ragged
     mixed-batch mode: ``tokens`` is one flat axis [1, total_q] where row r
@@ -1617,19 +1607,15 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
     ``kernel`` also picks the recurrence's Pallas kernels.
     """
     batch, seq = tokens.shape
-    if cfg.linear_layers and (state is None or tails is not None
-                              or ragged is not None):
+    if cfg.linear_layers and (state is None or ragged is not None):
         raise NotImplementedError(
             "linear layers are served by the padded step programs, handed "
-            "their state pool: no fused decode bursts, no ragged batches")
-    if cfg.is_dsa and (tails is not None or ragged is not None):
+            "their state pool: no ragged batches")
+    if cfg.is_dsa and ragged is not None:
         raise NotImplementedError(
             "learned sparse attention is served by the padded step "
-            "programs: no fused decode bursts, no ragged batches")
+            "programs: no ragged batches")
     if ragged is not None:
-        if tails is not None:
-            raise ValueError("ragged mode is scatter-then-attend; "
-                             "burst tails are not supported")
         if batch != 1:
             raise ValueError(
                 f"ragged mode takes one flat token axis [1, total_q], "
@@ -1646,36 +1632,6 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
         valid = jnp.arange(seq)[None, :] < new_lens[:, None]
         new_tokens = (positions, valid)
     total_lens = ctx_lens + new_lens
-    if tails is not None:
-        # The burst path is single-token-per-tick: tmask broadcasts
-        # valid [b, 1] over [b, T] and tail_lens counts exactly one new
-        # token per live row. A seq>1 caller would mis-mask silently.
-        if seq != 1:
-            raise ValueError(
-                f"tails mode requires seq == 1 (decode bursts), got {seq}")
-        tail_ks, tail_vs, ctx_base = tails
-        tail_ks, tail_vs = list(tail_ks), list(tail_vs)
-        t_steps = tail_ks[0].shape[2]
-        slot = ctx_lens - ctx_base  # [b] tail tokens already written
-        # One-hot write mask over tail slots (t_steps ≤ burst size, so a
-        # where over [b, T, ...] beats any scatter): live rows write the
-        # current token at slot; frozen rows write nothing.
-        tmask = ((jnp.arange(t_steps)[None, :] == slot[:, None])
-                 & valid)  # [b, T]
-        tail_lens = slot + new_lens  # attendable tail keys incl. current
-
-        def write_tail(buf, new_kv):
-            # buf [b, T, kvh, w]; new_kv [b, 1, kvh, w] broadcasts over T.
-            # Explicit cast: a quantized (fp8) cache makes the tail buffer
-            # fp8 too, and 8-bit floats refuse implicit promotion — the
-            # cast is also the semantics (tail tokens quantize exactly
-            # like their eventual scatter into the cache).
-            return jnp.where(tmask[:, :, None, None],
-                             new_kv.astype(buf.dtype), buf)
-
-        def tail_kwargs(tk_l, tv_l):
-            return dict(tail_k=tk_l, tail_v=tv_l, tail_lens=tail_lens,
-                        ctx_base=ctx_base)
 
     # Static layer→(group, local index) map, resolved at trace time. One
     # pool holds the layers that keep pages, in order.
@@ -1685,24 +1641,17 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
             for j, li in enumerate(cfg.group_layers(g)):
                 local_idx[li] = (g, j)
 
-    if tails is None:
-        # Where the step's tokens land in each group's pool: the same for
-        # every layer of the group and for K and V.
-        with jax.named_scope(SCOPE_KV_WRITE):
-            writes = [page_writes(cfg.page_size, table, *new_tokens)
-                      for table in tables]
+    # Where the step's tokens land in each group's pool: the same for
+    # every layer of the group and for K and V.
+    with jax.named_scope(SCOPE_KV_WRITE):
+        writes = [page_writes(cfg.page_size, table, *new_tokens)
+                  for table in tables]
 
     def write_layer(cache, g, lj, new_kv):
         """Group ``g``'s stack ``cache`` with ``new_kv`` written into its
         layer ``lj``, in place on the donated buffer."""
         with jax.named_scope(SCOPE_KV_WRITE):
             return write_kv_pages(cache, writes[g], new_kv, layer_idx=lj)
-
-    def write_tail_layer(buf, lj, new_kv):
-        # The tails are burst-sized, not pools: a where over the layer's
-        # [b, T, ...] buffer beats a scatter (see ``write_tail``).
-        with jax.named_scope(SCOPE_KV_WRITE):
-            return buf.at[lj].set(write_tail(buf[lj], new_kv))
 
     with jax.named_scope(SCOPE_EMBED):
         x = params["embed"][tokens]  # [b, s, h]
@@ -1833,14 +1782,9 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                         hi ** -0.5 * di ** -0.5)
                 v_caches[g] = write_layer(v_caches[g], g, lj, k_idx)
                 extra = {"index": (q_idx, w_idx)}
-            if tails is not None:
-                tail_ks[g] = write_tail_layer(tail_ks[g], lj, latent)
-            else:
-                k_caches[g] = write_layer(k_caches[g], g, lj, latent)
+            k_caches[g] = write_layer(k_caches[g], g, lj, latent)
             v_stack = v_caches[g] if cfg.is_dsa else k_caches[g]
             with jax.named_scope(SCOPE_ATTENTION):
-                if tails is not None:
-                    extra = tail_kwargs(tail_ks[g][lj], tail_ks[g][lj])
                 # Absorbed, or per head where ``attention_fn`` brings a
                 # ``per_head_fn`` (a prefill chunk of enough queries).
                 queries = (q_eff, q_nope, q_rope)
@@ -1879,20 +1823,12 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                 q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
                 k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
-            extra = {}
-            if tails is not None:
-                tail_ks[g] = write_tail_layer(tail_ks[g], lj, k)
-                tail_vs[g] = write_tail_layer(tail_vs[g], lj, v)
-            else:
-                k_caches[g] = write_layer(k_caches[g], g, lj, k)
-                v_caches[g] = write_layer(v_caches[g], g, lj, v)
+            k_caches[g] = write_layer(k_caches[g], g, lj, k)
+            v_caches[g] = write_layer(v_caches[g], g, lj, v)
             with jax.named_scope(SCOPE_ATTENTION):
-                if tails is not None:
-                    extra = tail_kwargs(tail_ks[g][lj], tail_vs[g][lj])
                 attn = attention_fn(
                     q, k_caches[g], v_caches[g], lj, table, positions,
-                    total_lens, cfg.layer_window(li), **extra,
-                )
+                    total_lens, cfg.layer_window(li))
         with jax.named_scope(SCOPE_ATTENTION):
             x = x + _sublayer_out(attn.reshape(batch, seq, -1), attn_in,
                                   layer, cfg, "attn")
@@ -1917,8 +1853,6 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                 idx = jnp.maximum(new_lens - 1, 0)  # [b]
                 x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
         logits = (x @ params["lm_head"]).astype(jnp.float32)
-    if tails is not None:
-        return logits, tuple(tail_ks), tuple(tail_vs)
     if state is not None:
         return logits, tuple(k_caches), tuple(v_caches), state[:2]
     return logits, tuple(k_caches), tuple(v_caches)
@@ -2130,225 +2064,6 @@ def forward_decode_pallas(
     )
 
 
-def _decode_step_attention(use_pallas: bool, interpret: bool, mesh,
-                           sinks: int | None = None,
-                           shared_kv: bool = False,
-                           shared_stream: str = "copy",
-                           batch_rows: int = 1):
-    """Attention closure for fused decode bodies — one implementation for
-    the single-pool and hybrid two-pool scans (the grouped forward hands
-    each layer its own group's table and window, so the closure is
-    pool-agnostic). ``sinks`` (StreamingLLM) applies in-kernel on the
-    Pallas path and in-mask on the XLA path — same semantics, parity
-    tested in tests/test_pallas_attention.py."""
-    from ..ops.pallas_paged_attention import (
-        pallas_paged_decode_attention, sharded_paged_decode_attention)
-
-    def attention(q, k_stack, v_stack, layer_idx, table, positions,
-                  total_lens, window,
-                  tail_k=None, tail_v=None, tail_lens=None, ctx_base=None):
-        # Burst-tail mode: the paged cache covers only ctx_base keys; the
-        # tail holds the burst's tokens (see _forward_impl_grouped). Every
-        # backend reads the stack at layer_idx: the kernels in-kernel, the
-        # XLA path in its page gather.
-        base_lens = total_lens if ctx_base is None else ctx_base
-        if use_pallas and mesh is not None:
-            out = sharded_paged_decode_attention(
-                mesh, q[:, 0], k_stack, v_stack, table, base_lens,
-                sliding_window=window, sinks=sinks, shared_kv=shared_kv,
-                shared_stream=shared_stream,
-                tail_k=tail_k, tail_v=tail_v, tail_lens=tail_lens,
-                layer_idx=layer_idx, interpret=interpret,
-            )
-            return out[:, None]
-        if use_pallas:
-            out = pallas_paged_decode_attention(
-                q[:, 0], k_stack, v_stack, table, base_lens,
-                sliding_window=window, sinks=sinks, shared_kv=shared_kv,
-                shared_stream=shared_stream,
-                tail_k=tail_k, tail_v=tail_v, tail_lens=tail_lens,
-                layer_idx=layer_idx, batch_rows=batch_rows,
-                interpret=interpret,
-            )
-            return out[:, None]
-        return paged_attention(
-            q, k_stack, v_stack, table, positions, base_lens,
-            sliding_window=window, attention_sinks=sinks, tail_k=tail_k,
-            tail_v=tail_v, tail_lens=tail_lens, layer_idx=layer_idx,
-        )
-
-    return attention
-
-
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "steps", "use_pallas", "interpret", "mesh",
-                     "batch_rows"),
-    donate_argnames=("k_cache", "v_cache"),
-)
-def forward_decode_steps(
-    params: Params,
-    cfg: LlamaConfig,
-    last_tokens: jax.Array,  # [batch] int32 — the most recent token per row
-    k_cache: jax.Array,
-    v_cache: jax.Array,
-    page_table: jax.Array,  # [batch, pages_per_seq] int32
-    ctx_lens: jax.Array,  # [batch] computed context before this call
-    active: jax.Array,  # [batch] 1 for live rows, 0 for padding
-    steps: int,
-    use_pallas: bool = False,
-    interpret: bool = False,
-    mesh=None,
-    batch_rows: int = 1,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Greedy decode of ``steps`` tokens fused into ONE XLA program.
-
-    A ``lax.scan`` over the single-token decode body: each tick scatters
-    the previous token's KV, attends, and argmaxes the next token —
-    device-resident the whole way, so a burst costs one dispatch and one
-    logits-free [batch, steps] token download instead of ``steps``
-    round-trips: it removes per-token launch overhead and logits
-    transfers.
-
-    ``active`` is each row's remaining token budget, not a binary mask: a
-    row decodes while the tick index is below its budget and freezes after
-    (writes land in the garbage page, context stops advancing, the token
-    output repeats its final value) — so one burst serves a mixed batch
-    where requests finish at different ticks, and rows with ``active == 0``
-    are inert padding throughout. Page tables must already cover
-    ``ctx + min(active, steps)`` tokens (the engine preallocates through
-    ``max_new_tokens`` at admission).
-    Returns ``(tokens [batch, steps], k_cache, v_cache)``; row i's valid
-    entries are the first ``min(active[i], steps)``.
-
-    The scan does NOT carry the caches (XLA copies large while-loop
-    carries every iteration — see ``_decode_steps_scan``); burst tokens
-    accumulate in a small KV tail folded into attention per step and are
-    scattered into the caches once, after the scan. The XLA backend's
-    burst is bit-identical to single-stepping (same softmax structure);
-    the Pallas backend's fp32 tail round sums in a different order than
-    the in-page rounds, so greedy argmax can legitimately flip on
-    logit ties within ~1 bf16 ulp (random-weight test models tie often;
-    trained models rarely).
-    """
-    toks, ks, vs = _decode_steps_scan(
-        params, cfg, last_tokens, (k_cache,), (v_cache,), (page_table,),
-        ctx_lens, active, steps,
-        _decode_step_attention(use_pallas, interpret, mesh,
-                               sinks=cfg.attention_sinks or None,
-                               shared_kv=cfg.is_mla,
-                               shared_stream=cfg.mla_decode_stream,
-                               batch_rows=batch_rows),
-    )
-    return toks, ks[0], vs[0]
-
-
-def _decode_steps_scan(params, cfg, last_tokens, k_caches, v_caches, tables,
-                       ctx_lens, active, steps, attention):
-    """The fused-decode scan body over grouped KV pools — one
-    implementation for the single-pool (1-tuple degenerate form, mirroring
-    ``_forward_impl``) and hybrid two-pool variants, so the live/freeze and
-    ctx-advance semantics cannot diverge between them.
-
-    The paged caches are scan CONSTANTS, not carries: XLA copies large
-    while-loop carries every iteration (measured ~300 GB/s r+w on a v5e —
-    a 4.6 GB cache pair cost ~30 ms/step of pure copy at production pool
-    sizes), so each tick attends over the frozen base cache plus a
-    burst-local KV tail (≤steps tokens, the only carried KV state) and
-    the accumulated tail is scattered into the caches ONCE after the
-    scan, where jit-boundary donation keeps it in place.
-    """
-    batch = last_tokens.shape[0]
-    tail_ks = tuple(
-        jnp.zeros((kc.shape[0], batch, steps) + kc.shape[2:3] + kc.shape[4:],
-                  kc.dtype)
-        for kc in k_caches)
-    tail_vs = tuple(
-        jnp.zeros((vc.shape[0], batch, steps) + vc.shape[2:3] + vc.shape[4:],
-                  vc.dtype)
-        for vc in v_caches)
-
-    def body(carry, tick):
-        toks, tks, tvs, ctx = carry
-        live = (tick < active).astype(jnp.int32)  # [batch]
-        logits, tks, tvs = _forward_impl_grouped(
-            params, cfg, toks[:, None], k_caches, v_caches, tables, ctx,
-            live, attention, tails=(tks, tvs, ctx_lens),
-        )
-        nxt = jnp.where(live > 0, greedy_tokens(logits[:, 0]), toks)
-        return (nxt, tks, tvs, ctx + live), nxt
-
-    (_t, tail_ks, tail_vs, _c), toks = jax.lax.scan(
-        body, (last_tokens, tail_ks, tail_vs, ctx_lens),
-        jnp.arange(steps, dtype=jnp.int32),
-    )
-
-    # Fold the burst's tokens into the paged caches — one batched scatter
-    # per (group, layer, K/V) at the program tail, in place on the
-    # donated buffers.
-    tpos = ctx_lens[:, None] + jnp.arange(steps)[None, :]  # [b, T]
-    tvalid = jnp.arange(steps)[None, :] < jnp.minimum(active, steps)[:, None]
-    k_caches = list(k_caches)
-    v_caches = list(v_caches)
-    with jax.named_scope(SCOPE_KV_WRITE):
-        for g in range(len(k_caches)):
-            writes = page_writes(cfg.page_size, tables[g], tpos, tvalid)
-            for lj in range(k_caches[g].shape[0]):
-                k_caches[g] = write_kv_pages(
-                    k_caches[g], writes, tail_ks[g][lj], layer_idx=lj)
-                if v_caches[g].shape[-1]:  # MLA's width-0 V pool: no data
-                    v_caches[g] = write_kv_pages(
-                        v_caches[g], writes, tail_vs[g][lj], layer_idx=lj)
-    return toks.T, tuple(k_caches), tuple(v_caches)  # toks [batch, steps]
-
-
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "steps", "use_pallas", "interpret", "mesh",
-                     "batch_rows"),
-    donate_argnames=("k0", "v0", "k1", "v1"),
-)
-def forward_decode_steps_hybrid(
-    params: Params,
-    cfg: LlamaConfig,
-    last_tokens: jax.Array,  # [batch] int32
-    k0: jax.Array, v0: jax.Array,   # full-attention group pool
-    k1: jax.Array, v1: jax.Array,   # sliding-window group pool
-    table0: jax.Array,  # [batch, pages_per_seq] into group 0's pool
-    table1: jax.Array,  # [batch, pages_per_seq] into group 1's pool
-    ctx_lens: jax.Array,
-    active: jax.Array,  # [batch] per-row remaining token budget
-    steps: int,
-    use_pallas: bool = False,
-    interpret: bool = False,
-    mesh=None,
-    batch_rows: int = 1,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Fused multi-token decode over the hybrid two-pool layout.
-
-    The freeze-and-reclaim half of the SWA burst design:
-    the engine pre-extends each request's SWA table through the pages the
-    whole burst will touch, the scan runs ``steps`` device-resident ticks
-    against the frozen tables (same per-row budget semantics as
-    ``forward_decode_steps``), and the host reclaims slots that slid out
-    of the window once per burst instead of once per token. SWA layers get
-    their sliding-window mask and group-1 table from the grouped forward;
-    the flash-decode kernel applies per layer, so ``use_pallas`` covers
-    both pools (the kernel is single-pool per *layer*, which is all it
-    ever sees). Returns ``(tokens [batch, steps], k0, v0, k1, v1)``.
-    """
-    toks, ks, vs = _decode_steps_scan(
-        params, cfg, last_tokens, (k0, k1), (v0, v1), (table0, table1),
-        ctx_lens, active, steps,
-        _decode_step_attention(use_pallas, interpret, mesh,
-                               sinks=cfg.attention_sinks or None,
-                               shared_kv=cfg.is_mla,
-                               shared_stream=cfg.mla_decode_stream,
-                               batch_rows=batch_rows),
-    )
-    return toks, ks[0], vs[0], ks[1], vs[1]
-
-
 @partial(
     jax.jit,
     static_argnames=("cfg", "interpret", "mesh", "last_only"),
@@ -2523,7 +2238,7 @@ def unpack_inputs(packed, shapes: tuple) -> list:
     return arrays
 
 
-def step_program(body, static=(), tokens_out: bool = False, kept_row=None):
+def step_program(body, static=(), kept_row=None):
     """The step form of a forward: the same body with its per-step inputs
     in one array and the sampling as its tail, jitted under the same name.
 
@@ -2540,8 +2255,7 @@ def step_program(body, static=(), tokens_out: bool = False, kept_row=None):
     ``keep_row=True``, one row ``[vocab]`` (``kept_row(*rest)``'s, else
     row 0: what the last chunk of a prefill leaves in
     ``Request.last_logits``); otherwise ``row`` is None.
-    ``tokens_out``: the body samples for itself (a burst) and its tokens
-    pass through. ``token_sharding`` constrains the unpacked tokens
+    ``token_sharding`` constrains the unpacked tokens
     (sequence-parallel prefill: the compute follows them).
     Where the body takes ``counters`` and the model counts anything on the
     device (``cfg.step_counters``), the counts follow the tokens in the
@@ -2569,8 +2283,6 @@ def step_program(body, static=(), tokens_out: bool = False, kept_row=None):
         if counters is not None:
             kw["counters"] = counters
         out, *pools = body(params, cfg, tokens, *pools, *rest, **kw)
-        if tokens_out:
-            return out, None, tuple(pools)
         if out.ndim == 3:
             if out.shape[1] != 1:
                 raise ValueError(
@@ -2647,11 +2359,6 @@ step_prefill_pallas = step_program(
     forward_prefill_pallas.__wrapped__, ("interpret", "mesh", "last_only"))
 step_ragged = step_program(
     forward_ragged.__wrapped__, ("interpret",), kept_row=_last_ragged_row)
-_BURST_STATIC = ("steps", "use_pallas", "interpret", "mesh", "batch_rows")
-step_decode_steps = step_program(
-    forward_decode_steps.__wrapped__, _BURST_STATIC, tokens_out=True)
-step_decode_steps_hybrid = step_program(
-    forward_decode_steps_hybrid.__wrapped__, _BURST_STATIC, tokens_out=True)
 
 
 # -- a latent layer's attention: absorbed, or per head ----------------------

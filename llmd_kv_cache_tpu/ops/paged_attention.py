@@ -31,9 +31,6 @@ def paged_attention(
     scale: float | None = None,
     sliding_window: int | None = None,
     attention_sinks: int | None = None,
-    tail_k: jax.Array | None = None,  # [batch, T, kv_heads, head_dim]
-    tail_v: jax.Array | None = None,
-    tail_lens: jax.Array | None = None,  # [batch] valid tail tokens
     layer_idx: int | None = None,
     keep: jax.Array | None = None,  # [batch, q_seq, kv_len] bool
 ) -> jax.Array:
@@ -47,21 +44,12 @@ def paged_attention(
     ``events.go:40``). Returns ``[batch, q_seq, q_heads, head_dim]`` in
     the query dtype.
 
-    ``tail_k/tail_v/tail_lens`` append a dense burst-local KV tail after
-    the paged keys: tail slot ``j`` sits at logical position
-    ``total_lens + j`` and is attendable while ``j < tail_lens``. This is
-    the fused-decode-burst path — the paged cache stays a read-only scan
-    constant (XLA copies large scan carries every iteration, see
-    ``forward_decode_steps``) and only the ≤steps-token tail is carried.
-    With a tail, ``total_lens`` is the FROZEN base length and queries sit
-    at ``q_positions ≥ total_lens``.
-
     ``layer_idx`` makes ``k_cache``/``v_cache`` the ``[layers, num_pages,
     ...]`` stacks: the page gather takes the layer as one more index, so
     no layer of a pool is ever sliced out.
 
     ``keep`` (learned sparse attention) further restricts each query to
-    the keys it selected, by position in the row's pages; not with a tail.
+    the keys it selected, by position in the row's pages.
     """
     batch, q_seq, q_heads, head_dim = q.shape
     kv_heads = k_cache.shape[-3]
@@ -83,14 +71,6 @@ def paged_attention(
 
     k_pos = jnp.broadcast_to(jnp.arange(kv_len)[None], (batch, kv_len))
     k_valid = k_pos < total_lens[:, None]
-    if tail_k is not None:
-        t = tail_k.shape[1]
-        k = jnp.concatenate([k, tail_k.astype(k.dtype)], axis=1)
-        v = jnp.concatenate([v, tail_v.astype(v.dtype)], axis=1)
-        tail_pos = total_lens[:, None] + jnp.arange(t)[None]
-        k_pos = jnp.concatenate([k_pos, tail_pos], axis=1)
-        k_valid = jnp.concatenate(
-            [k_valid, jnp.arange(t)[None] < tail_lens[:, None]], axis=1)
 
     # MXU-friendly numerics: feed the matmuls bf16 operands with fp32
     # accumulation (bf16·bf16 products are exact in fp32) instead of
@@ -99,7 +79,7 @@ def paged_attention(
     # grouped einsum over [b, q, kvh, group, hd] so KV heads are never
     # materialized ``group``× (the repeat would burn HBM bandwidth).
     qg = q.reshape(batch, q_seq, kv_heads, group, head_dim)
-    # [b, kvh, group, q_seq, kv_len(+T)], fp32
+    # [b, kvh, group, q_seq, kv_len], fp32
     logits = jnp.einsum(
         "bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32
     ) * scale

@@ -207,9 +207,8 @@ def _decode_stream_bounds(ctx_len, q_end, page_size, sliding_window, sinks):
     kernels so the window/sink page arithmetic cannot drift between
     them (same rationale as ``_superblock_streamer``). SWA skips pages
     wholly before q_end - window (``q_end`` is the exclusive query
-    position bound — ctx_len without a burst tail, ctx_len + tail_len
-    with one); sinks keep the first ceil(S/page_size) pages streamed via
-    the loop-counter remap."""
+    position bound: ctx_len for a decode row); sinks keep the first
+    ceil(S/page_size) pages streamed via the loop-counter remap."""
     num_pages = (ctx_len + page_size - 1) // page_size
     if sliding_window is not None:
         first_window = jnp.minimum(
@@ -226,66 +225,18 @@ def _decode_stream_bounds(ctx_len, q_end, page_size, sliding_window, sinks):
     return first_window, sink_pages, num_iters
 
 
-def _decode_mask(positions, ctx_len, q_end, sliding_window, sinks):
+def _decode_mask(positions, ctx_len, sliding_window, sinks):
     """Attendability of decode key ``positions``: in-bounds (< ctx_len),
-    and inside the sliding window of the query at position ``q_end - 1``
+    and inside the sliding window of the query at position ``ctx_len - 1``
     unless a sink position. Shared between the per-head and merged
     decode kernels."""
     in_bounds = positions < ctx_len
     if sliding_window is not None:
-        in_window = positions >= q_end - sliding_window
+        in_window = positions >= ctx_len - sliding_window
         if sinks:
             in_window = in_window | (positions < sinks)
         in_bounds = in_bounds & in_window
     return in_bounds
-
-
-def _tail_fold(q_h, k_t, v_t, tail_len, ctx_len, m, l, acc, *,
-               scale, sliding_window, sinks):
-    """Fold the dense burst-local KV tail (one extra online-softmax
-    round) into one head's state. ``k_t``/``v_t`` are that head's tail
-    keys/values [T, head_dim]. Tail slot ``j`` holds the key at logical
-    position ctx_len + j, attendable while ``j < tail_len``; the query
-    sits at ctx_len + tail_len - 1, so the window condition is
-    ``tail_len - 1 - j < W`` — except sink positions (absolute position
-    ctx_len + j < S), which stay attendable past the window like any
-    other sink key (reachable only when ctx_len < S and the burst
-    outruns the window, but the XLA reference keeps them and the mask
-    must not drift). Shared by the merged and per-head decode kernels.
-
-    The fold computes in explicit fp32: Mosaic miscompiles
-    mixed-precision dots with tiny contraction/result dims (T ≤ burst —
-    loud 'vector.broadcast' verifier failure at T=1, silently wrong
-    values at T=8 with 384-wide MLA operands on a real v5e). bf16→fp32
-    upcast is exact, so the scores match the bf16-operand/fp32-accum
-    MXU path up to summation order, and the tail is tiny so fp32 VPU
-    compute costs nothing."""
-    t = k_t.shape[0]
-    scores = jax.lax.dot_general(
-        q_h.astype(jnp.float32), k_t.astype(jnp.float32),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [group, T]
-    jt = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
-    ok = jt < tail_len
-    if sliding_window is not None:
-        in_window = tail_len - 1 - jt < sliding_window
-        if sinks:
-            in_window = in_window | (ctx_len + jt < sinks)
-        ok = ok & in_window
-    scores = jnp.where(ok, scores, _NEG_INF)
-
-    m_cur = jnp.max(scores, axis=1, keepdims=True)
-    m_new = jnp.maximum(m, m_cur)
-    p = jnp.exp(scores - m_new)
-    alpha = jnp.exp(m - m_new)
-    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_new = acc * alpha + jax.lax.dot_general(
-        p, v_t.astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return m_new, l_new, acc_new
 
 
 # Keys of a decode granule: the width of one MXU pass on a v5e, and eight
@@ -312,7 +263,7 @@ class _LiveGranules(NamedTuple):
 
 
 def _live_granules(page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch,
-                   sem, *, ctx_len, q_end, page_size, kpb, sliding_window,
+                   sem, *, ctx_len, page_size, kpb, sliding_window,
                    sinks, shared_kv, shared_copy, layer_idx, row=None):
     """One decode row's stream over keys [0, ctx_len), cut to the keys it
     has. The superblock stays the DMA batch (``kpb`` pages a round, double
@@ -348,7 +299,7 @@ def _live_granules(page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch,
     pages = _granule_pages(kpb, page_size)
     per_round = kpb // pages
     first_window, sink_pages, num_iters = _decode_stream_bounds(
-        ctx_len, q_end, page_size, sliding_window, sinks)
+        ctx_len, ctx_len, page_size, sliding_window, sinks)
     positions, sb_dma = _superblock_streamer(
         page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch, sem,
         kpb=kpb, num_iters=num_iters, first_window=first_window,
@@ -392,7 +343,7 @@ def _live_granules(page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch,
         first, n = (0, kpb) if g is None else (g * pages, pages)
         return _decode_mask(
             positions(sb, ctx_len, page_size, first=first, pages=n),
-            ctx_len, q_end, sliding_window, sinks)
+            ctx_len, sliding_window, sinks)
 
     def fold_round(slot, sb, state, fold):
         n = live(sb)
@@ -461,14 +412,11 @@ def _decode_kernel(
     # scalar prefetch
     page_table_ref,  # [batch, pages_per_seq] int32 (SMEM)
     ctx_lens_ref,  # [batch] int32 (SMEM)
-    tail_lens_ref,  # [batch] int32 (SMEM; zeros when has_tail=False)
     layer_ref,  # [1] int32 (SMEM): layer of a stacked cache, else unused
     # inputs
     q_ref,  # [1, 1, group, head_dim] VMEM block for (b, h)
     k_hbm,  # [num_pages, kv_heads, page_size, head_dim] (ANY/HBM)
     v_hbm,  # same
-    tail_k_ref,  # [1, 1, T, head_dim] VMEM block for (b, h); dummy if no tail
-    tail_v_ref,  # same (placeholder when shared_kv)
     # output
     o_ref,  # [1, 1, group, head_dim] VMEM block
     # scratch
@@ -483,7 +431,6 @@ def _decode_kernel(
     pages_per_block: int,
     shared_kv: bool,
     shared_copy: bool,
-    has_tail: bool,
     stacked: bool,
 ):
     b = pl.program_id(0)
@@ -491,7 +438,6 @@ def _decode_kernel(
     group, head_dim = q_ref.shape[2], q_ref.shape[3]
 
     ctx_len = ctx_lens_ref[b]
-    tail_len = tail_lens_ref[b] if has_tail else jnp.int32(0)
     # SWA: pages entirely outside the window are skipped, so long contexts
     # stream only ~window/page_size pages. Attention sinks (StreamingLLM,
     # reference events.go:40 sink_full_attention) additionally stream the
@@ -507,7 +453,7 @@ def _decode_kernel(
     # is cut to its live 128-key granules (``_live_granules``).
     st = _live_granules(
         page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch, sem,
-        ctx_len=ctx_len, q_end=ctx_len + tail_len, page_size=page_size,
+        ctx_len=ctx_len, page_size=page_size,
         kpb=pages_per_block, sliding_window=sliding_window, sinks=sinks,
         shared_kv=shared_kv, shared_copy=shared_copy,
         layer_idx=layer_ref[0] if stacked else None)
@@ -533,13 +479,6 @@ def _decode_kernel(
 
     m_fin, l_fin, acc = jax.lax.fori_loop(
         0, st.num_sb, body, _fold_state(group, head_dim))
-    if has_tail:
-        k_t = tail_k_ref[0, 0]  # [T, head_dim]; head picked by the block
-        v_t = k_t if shared_kv else tail_v_ref[0, 0]
-        m_fin, l_fin, acc = _tail_fold(
-            q, k_t, v_t, tail_len, ctx_len, m_fin, l_fin, acc,
-            scale=scale, sliding_window=sliding_window, sinks=sinks)
-
     out = acc / jnp.maximum(l_fin, 1e-30)
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
@@ -548,14 +487,11 @@ def _decode_kernel_merged(
     # scalar prefetch
     page_table_ref,  # [batch, pages_per_seq] int32 (SMEM)
     ctx_lens_ref,  # [batch] int32 (SMEM)
-    tail_lens_ref,  # [batch] int32 (SMEM; zeros when has_tail=False)
     layer_ref,  # [1] int32 (SMEM): layer of a stacked cache, else unused
     # inputs
     q_ref,  # [1, kv_heads, group, head_dim] VMEM block for (b,)
     k_hbm,  # [num_pages, kv_heads, page_size, head_dim] (ANY/HBM)
     v_hbm,  # same
-    tail_k_ref,  # [1, T, kv_heads, head_dim] VMEM block; dummy if no tail
-    tail_v_ref,  # same (placeholder when shared_kv)
     # output
     o_ref,  # [1, kv_heads, group, head_dim] VMEM block
     # scratch
@@ -570,7 +506,6 @@ def _decode_kernel_merged(
     pages_per_block: int,
     shared_kv: bool,
     shared_copy: bool,
-    has_tail: bool,
     stacked: bool,
     quant: bool = False,
 ):
@@ -607,14 +542,12 @@ def _decode_kernel_merged(
     rows, kv_heads, group = q_ref.shape[0], q_ref.shape[1], q_ref.shape[2]
     head_dim = q_ref.shape[3]
 
-    ctx_len, tail_len, streams = [], [], []
+    streams = []
     for r in range(rows):
         b = b0 * rows + r
-        ctx_len.append(ctx_lens_ref[b])
-        tail_len.append(tail_lens_ref[b] if has_tail else jnp.int32(0))
         streams.append(_live_granules(
             page_table_ref, b, None, k_hbm, v_hbm, k_scratch, v_scratch,
-            sem, ctx_len=ctx_len[r], q_end=ctx_len[r] + tail_len[r],
+            sem, ctx_len=ctx_lens_ref[b],
             page_size=page_size, kpb=pages_per_block,
             sliding_window=sliding_window, sinks=sinks, shared_kv=shared_kv,
             shared_copy=shared_copy,
@@ -675,13 +608,6 @@ def _decode_kernel_merged(
     for r in range(rows):
         for h in range(kv_heads):
             m_fin, l_fin, acc = state[r][h]
-            if has_tail:
-                m_fin, l_fin, acc = _tail_fold(
-                    qs[r][h], tail_k_ref[r, :, h],
-                    tail_k_ref[r, :, h] if shared_kv
-                    else tail_v_ref[r, :, h],
-                    tail_len[r], ctx_len[r], m_fin, l_fin, acc, scale=scale,
-                    sliding_window=sliding_window, sinks=sinks)
             out = acc / jnp.maximum(l_fin, 1e-30)
             o_ref[r, h] = out.astype(o_ref.dtype)
 
@@ -847,14 +773,11 @@ def _ragged_kernel(
     ctx_lens_ref,  # [rows] int32 (tokens cached BEFORE each row's new ones)
     block_first_ref,  # [num_q_blocks] int32: first row touching each block
     block_rows_ref,  # [num_q_blocks] int32: rows touching each block
-    tail_lens_ref,  # [rows] int32 (zeros when has_tail=False)
     layer_ref,  # [1] int32: layer of a stacked cache, else unused
     # inputs
     q_ref,  # [1, q_tile, kv_heads, group, head_dim] VMEM block for (g,)
     k_hbm,  # [num_pages, kv_heads, page_size, head_dim] (ANY/HBM)
     v_hbm,  # same
-    tail_k_ref,  # [rows, T, kv_heads, head_dim] whole-array VMEM; dummy if no tail
-    tail_v_ref,  # same (placeholder when shared_kv)
     # output
     o_ref,  # [1, q_tile, kv_heads, group, head_dim] VMEM block
     # scratch
@@ -870,7 +793,6 @@ def _ragged_kernel(
     pages_per_block: int,
     shared_kv: bool,
     shared_copy: bool,
-    has_tail: bool,
     stacked: bool,
     quant: bool = False,
 ):
@@ -897,10 +819,6 @@ def _ragged_kernel(
 
     ``quant``: fp8 (1-byte) pages in the flat whole-page layout with a
     per-round upcast, exactly the merged decode kernel's operand mode.
-    ``has_tail``: burst-local dense KV tails folded per row via
-    ``_tail_fold`` — its mask puts every query at ``ctx + tail_len - 1``,
-    so tails are only valid for single-token (decode) rows; multi-token
-    rows must carry ``tail_lens == 0``.
     """
     g = pl.program_id(0)
     kv_heads, group = q_ref.shape[2], q_ref.shape[3]
@@ -933,19 +851,8 @@ def _ragged_kernel(
         kv_limit = (ctx_len - row_start
                     + jnp.minimum(row_end, blk_start + q_tile))
         q_first = ctx_len + jnp.maximum(row_start, blk_start) - row_start
-        q_end = q_first + 1
-        tail_len = tail_lens_ref[r] if has_tail else jnp.int32(0)
-        if has_tail:
-            # A tail row (tail_len > 0 — a 1-token row by contract) keeps
-            # its new KV in the dense tail, not the pages: the paged scan
-            # covers [0, ctx_len) and the query sits at
-            # ctx_len + tail_len - 1 (the decode kernels' tail contract).
-            is_tail_row = tail_len > 0
-            kv_limit = jnp.where(is_tail_row, ctx_len, kv_limit)
-            q_end = jnp.where(is_tail_row, ctx_len + tail_len, q_end)
-            q_pos = jnp.where(is_tail_row, q_end - 1, q_pos)
         fw, sp, ni = _decode_stream_bounds(
-            kv_limit, q_end, page_size, sliding_window, sinks)
+            kv_limit, q_first + 1, page_size, sliding_window, sinks)
         num_sb = (ni + kpb - 1) // kpb
         sb_positions, sb_dma = _superblock_streamer(
             page_table_ref, r, None, k_hbm, v_hbm, k_scratch, v_scratch,
@@ -1040,29 +947,6 @@ def _ragged_kernel(
         a0 = tuple(jnp.zeros((group, q_tile, head_dim), jnp.float32)
                    for _ in range(kv_heads))
         m_r, l_r, acc_r = jax.lax.fori_loop(0, num_sb, body, (m0, l0, a0))
-        m_r, l_r, acc_r = list(m_r), list(l_r), list(acc_r)
-
-        if has_tail:
-            # _tail_fold's mask assumes every query sits at the tail end
-            # (ctx + tail_len - 1) — true for this row's single query when
-            # tail_lens[r] > 0 only on 1-token rows (the documented
-            # contract); the garbage it computes for foreign q rows is
-            # discarded by the liveness select below. The fold is
-            # row-wise over its leading axis, so the [group, q_tile, …]
-            # state folds as [group·q_tile, …].
-            for h in range(kv_heads):
-                k_t = tail_k_ref[r, :, h]  # [T, head_dim]
-                v_t = k_t if shared_kv else tail_v_ref[r, :, h]
-                mf, lf, af = _tail_fold(
-                    qs[h].reshape(group * q_tile, head_dim), k_t, v_t,
-                    tail_len, ctx_len,
-                    m_r[h].reshape(group * q_tile, 1),
-                    l_r[h].reshape(group * q_tile, 1),
-                    acc_r[h].reshape(group * q_tile, head_dim),
-                    scale=scale, sliding_window=sliding_window, sinks=sinks)
-                m_r[h] = mf.reshape(group, q_tile, 1)
-                l_r[h] = lf.reshape(group, q_tile, 1)
-                acc_r[h] = af.reshape(group, q_tile, head_dim)
 
         # Commit row r's q rows into the block state; foreign rows keep
         # theirs (the merged decode kernel's live guard, per q row).
@@ -1106,9 +990,6 @@ def pallas_paged_ragged_attention(
     pages_per_block: int | None = None,
     shared_kv: bool = False,
     shared_stream: str = "copy",
-    tail_k: jax.Array | None = None,  # [rows, T, kv_heads, head_dim]
-    tail_v: jax.Array | None = None,
-    tail_lens: jax.Array | None = None,  # [rows] valid tail tokens
     layer_idx: jax.Array | int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -1128,10 +1009,7 @@ def pallas_paged_ragged_attention(
     A 1-byte (fp8 e4m3) cache takes the merged decode kernel's quantized
     operand mode: whole flat pages DMA'd at 1 byte/element and upcast
     once per round (needs ``kv_heads * page_size % 32 == 0`` on real
-    TPU, merged layout only — same rules as decode). ``tail_*`` fold a
-    dense burst-local tail per row via ``_tail_fold``; its mask pins
-    every query to the tail end, so only 1-token rows may carry
-    ``tail_lens > 0``.
+    TPU, merged layout only — same rules as decode).
     """
     total_q, q_heads, head_dim = q.shape
     # layer_idx: stacked caches, in-kernel layer indexing (see the other
@@ -1181,29 +1059,9 @@ def pallas_paged_ragged_attention(
         pages_per_block = max(1, min(keys // page_size,
                                      page_table.shape[1]))
 
-    has_tail = tail_k is not None
-    if has_tail:
-        if tail_lens is None:
-            raise ValueError(
-                "tail_k requires tail_lens [rows] int32 (valid tail "
-                "tokens per row)")
-        if tail_v is None and not shared_kv:
-            raise ValueError(
-                "tail_k requires tail_v [rows, T, kv_heads, head_dim] "
-                "unless shared_kv=True (single-stream MLA)")
-    else:
-        # Structural placeholders (see the decode wrapper): the kernel
-        # always takes tail refs; has_tail=False makes the fold dead code.
-        tail_k = jnp.zeros((rows, 1, kv_heads, head_dim), q.dtype)
-        tail_lens = jnp.zeros((rows,), jnp.int32)
-    if shared_kv or not has_tail:
-        tail_v = jnp.zeros((rows, 1, kv_heads, head_dim), q.dtype)
-    t_len = tail_k.shape[1]
-
     # Quantized (fp8 e4m3) cache arm — the merged decode kernel's operand
     # mode carried over verbatim: flat whole-page view, 1-byte DMAs,
-    # per-round upcast; tails ride in the query dtype (their values were
-    # quantized through the cache when written, so the upcast is exact).
+    # per-round upcast.
     quant = k_cache.dtype.itemsize == 1
     if quant:
         if shared_kv:
@@ -1225,7 +1083,7 @@ def pallas_paged_ragged_attention(
         q_tile=q_tile, sliding_window=sliding_window, sinks=int(sinks or 0),
         pages_per_block=pages_per_block, shared_kv=shared_kv,
         shared_copy=shared_kv and shared_stream == "copy",
-        has_tail=has_tail, stacked=layer_idx is not None, quant=quant,
+        stacked=layer_idx is not None, quant=quant,
     )
 
     if quant:
@@ -1236,7 +1094,7 @@ def pallas_paged_ragged_attention(
              if shared_kv and shared_stream != "copy" else k_scr)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=6,
         grid=(num_blocks,),
         in_specs=[
             pl.BlockSpec(
@@ -1245,17 +1103,6 @@ def pallas_paged_ragged_attention(
             ),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            # Tails ride as whole-array blocks: a q block can span several
-            # rows, so no per-row BlockSpec fits — the kernel indexes rows
-            # dynamically. Tail buffers are burst-sized (rows × steps).
-            pl.BlockSpec(
-                (rows, t_len, kv_heads, head_dim),
-                lambda g, *_prefetch: (0, 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (rows, tail_v.shape[1], kv_heads, head_dim),
-                lambda g, *_prefetch: (0, 0, 0, 0),
-            ),
         ],
         out_specs=pl.BlockSpec(
             (1, q_tile, kv_heads, group, head_dim),
@@ -1276,9 +1123,8 @@ def pallas_paged_ragged_attention(
         grid_spec=grid_spec,
         interpret=interpret,
     )(page_table.astype(jnp.int32), row_starts, ctx_lens,
-      block_first, block_rows, tail_lens.astype(jnp.int32),
-      _layer_operand(layer_idx), q_blocked, k_cache, v_cache,
-      tail_k.astype(q.dtype), tail_v.astype(q.dtype))
+      block_first, block_rows, _layer_operand(layer_idx), q_blocked,
+      k_cache, v_cache)
 
     return out.reshape(total_q, q_heads, head_dim)
 
@@ -1446,9 +1292,6 @@ def pallas_paged_decode_attention(
     shared_kv: bool = False,
     shared_stream: str = "copy",
     merge_heads: bool | None = None,
-    tail_k: jax.Array | None = None,  # [batch, T, kv_heads, head_dim]
-    tail_v: jax.Array | None = None,
-    tail_lens: jax.Array | None = None,  # [batch] valid tail tokens
     layer_idx: jax.Array | int | None = None,
     batch_rows: int = 1,
     interpret: bool = False,
@@ -1532,29 +1375,6 @@ def pallas_paged_decode_attention(
 
     q_blocked = q.reshape(batch, kv_heads, group, head_dim)
 
-    has_tail = tail_k is not None
-    if has_tail:
-        # The tail arguments travel as a set: a tail without its valid
-        # lengths (or, for separate K/V caches, without its values) would
-        # surface much later as an opaque shape/attribute error.
-        if tail_lens is None:
-            raise ValueError(
-                "tail_k requires tail_lens [batch] int32 (valid tail "
-                "tokens per sequence)")
-        if tail_v is None and not shared_kv:
-            raise ValueError(
-                "tail_k requires tail_v [batch, T, kv_heads, head_dim] "
-                "unless shared_kv=True (single-stream MLA)")
-    if not has_tail:
-        # Structural placeholders: the kernels always take tail refs so
-        # the two arities share one code path; has_tail=False makes the
-        # fold dead code and the 2 KB dummy blocks are never read.
-        tail_k = jnp.zeros((batch, 1, kv_heads, head_dim), k_cache.dtype)
-        tail_lens = jnp.zeros((batch,), jnp.int32)
-    if shared_kv or not has_tail:
-        tail_v = jnp.zeros((batch, 1, kv_heads, head_dim), k_cache.dtype)
-    t_len = tail_k.shape[1]
-
     # Multi-row programs: zero-pad the batch to a row multiple. Padded
     # rows have ctx_len 0 → no rounds, no DMAs; their outputs are 0 and
     # sliced off below.
@@ -1563,11 +1383,8 @@ def pallas_paged_decode_attention(
         pad = batch_rows - batch % batch_rows
         bpad = [(0, pad)] + [(0, 0)] * 3
         q_blocked = jnp.pad(q_blocked, bpad)
-        tail_k = jnp.pad(tail_k, bpad)
-        tail_v = jnp.pad(tail_v, bpad)
         page_table = jnp.pad(page_table, [(0, pad), (0, 0)])
         ctx_lens = jnp.pad(ctx_lens, (0, pad))
-        tail_lens = jnp.pad(tail_lens, (0, pad))
         batch += pad
 
     # Quantized (fp8 e4m3) cache arm: DMA the 1-byte pages — the whole
@@ -1578,9 +1395,7 @@ def pallas_paged_decode_attention(
     # contiguous whole pages [.., kv_heads*page_size, head_dim] (a free
     # reshape) and each DMA moves one full page for every head, which is
     # aligned whenever kv_heads*page_size % 32 == 0. Merged-heads only
-    # (the per-head grid would need the misaligned sub-slice), and the
-    # burst tail rides as bf16 — its values were already quantized
-    # through the cache dtype when written, so the upcast is exact.
+    # (the per-head grid would need the misaligned sub-slice).
     quant = k_cache.dtype.itemsize == 1
     if quant:
         if shared_kv:
@@ -1598,8 +1413,6 @@ def pallas_paged_decode_attention(
         flat = (kv_heads * page_size, head_dim)
         k_cache = k_cache.reshape(k_cache.shape[:-3] + flat)
         v_cache = v_cache.reshape(v_cache.shape[:-3] + flat)
-        tail_k = tail_k.astype(q.dtype)
-        tail_v = tail_v.astype(q.dtype)
 
     if merge_heads:
         rr = batch_rows
@@ -1609,7 +1422,7 @@ def pallas_paged_decode_attention(
             sinks=int(sinks or 0), pages_per_block=pages_per_block,
             shared_kv=shared_kv,
             shared_copy=shared_kv and shared_stream == "copy",
-            has_tail=has_tail, stacked=layer_idx is not None, quant=quant,
+            stacked=layer_idx is not None, quant=quant,
         )
         if quant:
             k_scr = ((2, pages_per_block, kv_heads * page_size, head_dim)
@@ -1626,7 +1439,7 @@ def pallas_paged_decode_attention(
         sem_shape = ((2, pages_per_block, 2) if rr == 1
                      else (2, rr, pages_per_block, 2))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=3,
             grid=(batch // rr,),
             in_specs=[
                 pl.BlockSpec(
@@ -1635,14 +1448,6 @@ def pallas_paged_decode_attention(
                 ),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(
-                    (rr, t_len, kv_heads, head_dim),
-                    lambda b, *_prefetch: (b, 0, 0, 0),
-                ),
-                pl.BlockSpec(
-                    (rr, tail_v.shape[1], kv_heads, head_dim),
-                    lambda b, *_prefetch: (b, 0, 0, 0),
-                ),
             ],
             out_specs=pl.BlockSpec(
                 (rr, kv_heads, group, head_dim),
@@ -1655,22 +1460,15 @@ def pallas_paged_decode_attention(
             ],
         )
     else:
-        # Tail transposed to [batch, kvh, T, hd] for this path: Mosaic
-        # requires the last two block dims to divide (8, 128) or equal
-        # the array dims — a size-1 block on a kvh>1 second-to-last axis
-        # is rejected, so the head axis moves out of the blocked pair
-        # and is picked by the index map.
-        tail_k = tail_k.transpose(0, 2, 1, 3)
-        tail_v = tail_v.transpose(0, 2, 1, 3)
         kernel = functools.partial(
             _decode_kernel, page_size=page_size, scale=head_dim ** -0.5,
             sliding_window=sliding_window, sinks=int(sinks or 0),
             pages_per_block=pages_per_block, shared_kv=shared_kv,
             shared_copy=shared_kv and shared_stream == "copy",
-            has_tail=has_tail, stacked=layer_idx is not None,
+            stacked=layer_idx is not None,
         )
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=3,
             grid=(batch, kv_heads),
             in_specs=[
                 pl.BlockSpec(
@@ -1680,14 +1478,6 @@ def pallas_paged_decode_attention(
                 ),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(
-                    (1, 1, t_len, head_dim),
-                    lambda b, h, *_prefetch: (b, h, 0, 0),
-                ),
-                pl.BlockSpec(
-                    (1, 1, tail_v.shape[2], head_dim),
-                    lambda b, h, *_prefetch: (b, h, 0, 0),
-                ),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, group, head_dim),
@@ -1716,9 +1506,7 @@ def pallas_paged_decode_attention(
         grid_spec=grid_spec,
         interpret=interpret,
     )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      tail_lens.astype(jnp.int32), _layer_operand(layer_idx),
-      q_blocked, k_cache, v_cache, tail_k.astype(k_cache.dtype),
-      tail_v.astype(k_cache.dtype))
+      _layer_operand(layer_idx), q_blocked, k_cache, v_cache)
 
     return out.reshape(batch, q_heads, head_dim)[:out_batch]
 
@@ -1744,8 +1532,8 @@ def _kv_pool_spec(k_cache, stacked=False):
 def sharded_paged_decode_attention(
     mesh, q, k_cache, v_cache, page_table, ctx_lens, *,
     sliding_window=None, sinks=None, pages_per_block=None, shared_kv=False,
-    shared_stream="copy", merge_heads=None, tail_k=None, tail_v=None,
-    tail_lens=None, layer_idx=None, interpret=False,
+    shared_stream="copy", merge_heads=None, layer_idx=None,
+    interpret=False,
 ):
     """Flash-decode over a tp-sharded paged KV cache.
 
@@ -1767,42 +1555,22 @@ def sharded_paged_decode_attention(
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    has_tail = tail_k is not None
-
-    def local(q_, k_, v_, t_, l_, tk_, tv_, tl_):
+    def local(q_, k_, v_, t_, l_):
         return pallas_paged_decode_attention(
             q_, k_, v_, t_, l_, sliding_window=sliding_window, sinks=sinks,
             pages_per_block=pages_per_block, shared_kv=shared_kv,
             shared_stream=shared_stream, merge_heads=merge_heads,
-            tail_k=tk_ if has_tail else None,
-            tail_v=tv_ if has_tail else None,
-            tail_lens=tl_ if has_tail else None,
             layer_idx=layer_idx, interpret=interpret,
         )
 
     kv_spec = _kv_pool_spec(k_cache, stacked=layer_idx is not None)
-    # Tail buffers shard on their kv-heads axis alongside the pool (a
-    # replicated single-head MLA pool replicates its tail too).
-    kvh_axis = 2 if layer_idx is not None else 1
-    tail_spec = (P() if k_cache.shape[kvh_axis] == 1
-                 else P(None, None, "tp", None))
-    if not has_tail:
-        # Zero-size placeholders keep the shard_map arity fixed.
-        batch = q.shape[0]
-        tail_k = jnp.zeros(
-            (batch, 1, k_cache.shape[kvh_axis], k_cache.shape[-1]),
-            k_cache.dtype)
-        tail_v = tail_k
-        tail_lens = jnp.zeros((batch,), jnp.int32)
-    elif tail_v is None:  # shared_kv callers pass only the latent tail
-        tail_v = tail_k
     return shard_map(
         local, mesh=mesh,
         in_specs=(P(None, "tp", None), kv_spec, kv_spec,
-                  P(None, None), P(None), tail_spec, tail_spec, P(None)),
+                  P(None, None), P(None)),
         out_specs=P(None, "tp", None),
         check_vma=False,
-    )(q, k_cache, v_cache, page_table, ctx_lens, tail_k, tail_v, tail_lens)
+    )(q, k_cache, v_cache, page_table, ctx_lens)
 
 
 def sharded_paged_prefill_attention(

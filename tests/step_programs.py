@@ -7,7 +7,7 @@ step-form tests in ``test_model.py`` (a forward's step form, which the
 engine dispatches, gives the tokens its logits give).
 
 A pool layer here is ``[11, 2, 4, 16]`` (the hybrid SWA group's
-``[7, 2, 4, 16]``): no activation, weight or tail buffer of these
+``[7, 2, 4, 16]``): no activation or weight of these
 configurations has that shape, so an op of that shape is an op on a layer.
 """
 
@@ -57,13 +57,6 @@ def _ragged(params, cfg, pools, step):
             row_starts, ctx)
 
 
-def _burst(params, cfg, pools, step):
-    """Bursts of two ticks: budgets 2 and 1, then 1 and none."""
-    ctx, active = ((_i32(3, 5), _i32(2, 1)), (_i32(5, 6), _i32(1, 0)))[step]
-    tables = (TABLE, SWA_TABLE)[:len(pools) // 2]
-    return (params, cfg, TOKENS[step][:, 0], *pools, *tables, ctx, active)
-
-
 class Program(NamedTuple):
     fn: Callable  # the jitted program; returns (out, *pools)
     cfg: llama.LlamaConfig
@@ -76,8 +69,6 @@ class Program(NamedTuple):
     # What the engine adds to ``static`` when it dispatches the step form:
     # a chunk's logits are its last position's.
     chunk: bool = False
-
-
 
 
 _GQA = llama.LlamaConfig.tiny()
@@ -104,16 +95,6 @@ PROGRAMS = {
         llama.step_prefill_pallas, True),
     "forward_ragged": Program(llama.forward_ragged, _GQA, _ragged, {}, True,
                               llama.step_ragged),
-    "forward_decode_steps": Program(
-        llama.forward_decode_steps, _GQA, _burst,
-        dict(steps=2, use_pallas=True), True, llama.step_decode_steps),
-    "forward_decode_steps_xla_mla": Program(
-        llama.forward_decode_steps, _MLA, _burst, dict(steps=2), False,
-        llama.step_decode_steps),
-    "forward_decode_steps_hybrid": Program(
-        llama.forward_decode_steps_hybrid, _HYBRID, _burst,
-        dict(steps=2, use_pallas=True), True,
-        llama.step_decode_steps_hybrid),
 }
 
 

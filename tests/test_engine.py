@@ -296,79 +296,6 @@ class TestEngineIndexerLoop:
         assert "pod-b" not in scores
 
 
-class TestDecodeBurst:
-    """Fused multi-token decode (forward_decode_steps): burst size must be
-    a pure dispatch-count optimization — greedy outputs identical to
-    single-token stepping."""
-
-    def _generate(self, burst, use_pallas=False, max_new=7):
-        from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
-        from llmd_kv_cache_tpu.models.llama import LlamaConfig
-
-        eng = MiniEngine(
-            EngineConfig(model=LlamaConfig.tiny(), num_pages=64,
-                         max_pages_per_seq=16, model_name="tiny",
-                         pod_identifier="p", decode_burst=burst,
-                         use_pallas_decode=use_pallas or None),
-            seed=0,
-        )
-        return eng.generate("r", list(range(30, 42)), max_new_tokens=max_new)
-
-    def test_burst_matches_single_step(self):
-        assert self._generate(burst=4) == self._generate(burst=1)
-
-    def test_burst_matches_single_step_pallas(self):
-        assert (self._generate(burst=4, use_pallas=True)
-                == self._generate(burst=1, use_pallas=True))
-
-    def test_burst_exceeding_remaining_is_clamped(self):
-        # max_new 3: bursts must go 2, then 1 — never overshoot
-        out = self._generate(burst=8, max_new=3)
-        assert len(out) == 3
-        assert out == self._generate(burst=1, max_new=3)
-
-    def test_burst_mixed_batch(self):
-        """Two requests decoding together with different remaining budgets:
-        the chunk takes the min-bounded burst and both finish correctly."""
-        from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
-        from llmd_kv_cache_tpu.models.llama import LlamaConfig
-
-        def run(burst):
-            eng = MiniEngine(
-                EngineConfig(model=LlamaConfig.tiny(), num_pages=64,
-                             max_pages_per_seq=16, model_name="tiny",
-                             pod_identifier="p", decode_burst=burst),
-                seed=0,
-            )
-            a = eng.add_request("a", list(range(10, 22)), max_new_tokens=5)
-            b = eng.add_request("b", list(range(50, 66)), max_new_tokens=3)
-            while not (a.done and b.done):
-                eng.step()
-            return a.output, b.output
-
-        assert run(4) == run(1)
-
-    def test_burst_not_clamped_by_near_done_request(self):
-        """Per-row budget freezing: a request about to finish must not drag
-        the whole chunk's burst down to its remainder."""
-        from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
-        from llmd_kv_cache_tpu.models.llama import LlamaConfig
-
-        eng = MiniEngine(
-            EngineConfig(model=LlamaConfig.tiny(), num_pages=64,
-                         max_pages_per_seq=16, model_name="tiny",
-                         pod_identifier="p", decode_burst=8),
-            seed=0,
-        )
-        a = eng.add_request("a", list(range(10, 22)), max_new_tokens=9)
-        b = eng.add_request("b", list(range(50, 66)), max_new_tokens=2)
-        # admission already emitted each request's first token (TTFT)
-        assert len(a.output) == 1 and len(b.output) == 1
-        eng.step()
-        assert b.done  # took its single remaining token, then froze
-        assert len(a.output) == 9  # full 8-token burst despite b's budget
-
-
 class TestContinuousBatching:
     """enqueue(): admission now, prefill chunk-at-a-time inside step()
     interleaved with decode (vLLM chunked-prefill scheduling)."""
@@ -396,9 +323,9 @@ class TestContinuousBatching:
         assert req.output == ref
 
     def test_admission_delay_metric_observed(self):
-        """enqueue()-to-first-schedule wait feeds the burst-admission
-        histogram (VERDICT r2 weak #8: the cost of decode_burst admission
-        granularity must be observable)."""
+        """enqueue()-to-first-schedule wait feeds the admission-delay
+        histogram, once a request: what the CoDel shedder and an
+        operator read the queue's wait from."""
         from llmd_kv_cache_tpu.metrics.collector import ENGINE_ADMISSION_DELAY
         from llmd_kv_cache_tpu.models.engine import MiniEngine
 
@@ -408,7 +335,7 @@ class TestContinuousBatching:
                 if s.name.endswith("_count"))
 
         before = hist_count()
-        eng = MiniEngine(self._cfg(decode_burst=8), seed=0)
+        eng = MiniEngine(self._cfg(), seed=0)
         req = eng.enqueue("r", list(range(1, 9)), max_new_tokens=4)
         assert hist_count() == before  # not yet scheduled
         eng.step()  # first schedule observes the delay
@@ -939,10 +866,10 @@ class TestLookAhead:
             "offload" if how == "offload" else "aborted": 1}
         assert held.telemetry.debug_vars()["lookahead"]["drained"] == {}
 
-    @pytest.mark.parametrize("kind", ["burst", "sharded", "hybrid"])
+    @pytest.mark.parametrize("kind", ["sharded", "hybrid"])
     def test_an_engine_that_cannot_defer_takes_no_prev(self, kind,
                                                        monkeypatch):
-        """Fused bursts, a mesh and window pages keep the synchronous
+        """A mesh and window pages keep the synchronous
         order and the programs they had: no ``prev`` operand, no ``src``
         in the packed inputs, no ``ahead`` on a dispatch, nothing unread
         between steps."""
@@ -956,29 +883,23 @@ class TestLookAhead:
         monkeypatch.setattr(engine_module, "_launch_counts", {})
         tiny = LlamaConfig.tiny()
         mesh = None
-        over = {}
-        if kind == "burst":
-            over = dict(decode_burst=4)
-        elif kind == "sharded":
+        if kind == "sharded":
             mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("tp",))
         else:
             tiny = dataclasses.replace(tiny, sliding_window=8,
                                        swa_layers=(1,))
-            over = dict(num_swa_pages=64)
         eng = MiniEngine(EngineConfig(
-            model=tiny, num_pages=64, max_pages_per_seq=16, max_batch=2,
-            pod_identifier="p", telemetry=EngineTelemetryConfig(), **over),
-            seed=0, mesh=mesh)
+            model=tiny, num_pages=64, num_swa_pages=64,
+            max_pages_per_seq=16, max_batch=2, pod_identifier="p",
+            telemetry=EngineTelemetryConfig()), seed=0, mesh=mesh)
         assert eng._defers is False and eng._prev is None
         from test_telemetry import _recorded
 
         seen = _recorded(eng._phases)
         calls = []
-        for name in ("_decode_forward", "_decode_multi"):
-            program = getattr(eng, name, None)
-            if program is not None:
-                monkeypatch.setattr(eng, name, lambda *a, _p=program, **kw: (
-                    calls.append(kw), _p(*a, **kw))[1])
+        program = eng._decode_forward
+        monkeypatch.setattr(eng, "_decode_forward", lambda *a, **kw: (
+            calls.append(kw), program(*a, **kw))[1])
         eng.enqueue("a", list(range(1, 8)), max_new_tokens=9)
         eng.enqueue("b", list(range(9, 14)), max_new_tokens=6)
         while eng.requests:

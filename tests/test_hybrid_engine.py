@@ -139,89 +139,6 @@ class TestHybridEquivalence:
         assert req.swa_pages[:2] == [0, 0] and all(req.swa_pages[2:4])
 
 
-class TestHybridBurstDecode:
-    """Fused decode bursts on the two-pool layout (freeze-and-reclaim SWA
-    paging): burst ≥ 8 must be token-identical to single-token stepping."""
-
-    def _serve(self, burst, prompt, n_tokens=12, num_swa_pages=None):
-        eng = MiniEngine(
-            EngineConfig(
-                model=hybrid_cfg(), num_pages=64,
-                num_swa_pages=num_swa_pages, max_pages_per_seq=16,
-                model_name="tiny-hybrid", pod_identifier="pod-h",
-                decode_burst=burst,
-            ))
-        return eng.generate("r", prompt, max_new_tokens=n_tokens), eng
-
-    def test_burst8_token_identical_to_single_step(self):
-        prompt = list(range(10, 29))  # crosses page and window boundaries
-        single, _ = self._serve(1, prompt)
-        burst, _ = self._serve(8, prompt)
-        assert burst == single
-
-    def test_burst16_long_generation_slides_window(self):
-        # Generation far beyond the window: burst boundaries land mid-page
-        # and mid-window; reclaim happens between bursts only.
-        prompt = list(range(30, 37))
-        single, _ = self._serve(1, prompt, n_tokens=33)
-        burst, _ = self._serve(16, prompt, n_tokens=33)
-        assert burst == single
-
-    def test_burst_reclaims_out_of_window_pages(self):
-        # After a long burst generation the SWA pool must have recovered
-        # the slid-out pages: next request still gets served.
-        prompt = list(range(40, 48))
-        _, eng = self._serve(8, prompt, n_tokens=24, num_swa_pages=8)
-        out2 = eng.generate("r2", list(range(60, 68)), max_new_tokens=24)
-        assert len(out2) == 24
-
-    def test_undersized_swa_pool_degrades_to_single_step(self):
-        """A pool sized to the single-step bound must not die under
-        decode_burst: the step falls back to single-token decoding and
-        output stays identical."""
-        prompt = list(range(40, 48))
-        single, _ = self._serve(1, prompt, n_tokens=16, num_swa_pages=4)
-        burst, _ = self._serve(16, prompt, n_tokens=16, num_swa_pages=4)
-        assert burst == single
-
-    def test_pallas_burst_matches_xla_burst(self):
-        """The flash-decode kernel applies inside hybrid bursts (per layer,
-        each layer sees its own group's table/window): interpret-mode
-        Pallas bursts are token-identical to the XLA burst path."""
-        prompt = list(range(10, 29))
-        outs = {}
-        for use_pallas in (False, True):
-            eng = MiniEngine(
-                EngineConfig(
-                    model=hybrid_cfg(), num_pages=64, max_pages_per_seq=16,
-                    model_name="tiny-hybrid", pod_identifier="pod-h",
-                    decode_burst=8, use_pallas_decode=use_pallas,
-                ))
-            outs[use_pallas] = eng.generate("r", prompt, max_new_tokens=12)
-        assert outs[False] == outs[True]
-
-    def test_mixed_batch_budgets(self):
-        # Continuous batching: two requests with different budgets decode
-        # in one fused burst; each stops at its own max_new_tokens.
-        eng = MiniEngine(
-            EngineConfig(
-                model=hybrid_cfg(), num_pages=64, max_pages_per_seq=16,
-                model_name="tiny-hybrid", pod_identifier="pod-h",
-                decode_burst=8,
-            ))
-        a = eng.add_request("a", list(range(10, 18)), max_new_tokens=13)
-        b = eng.add_request("b", list(range(20, 28)), max_new_tokens=5)
-        for _ in range(40):
-            if a.done and b.done:
-                break
-            eng.step()
-        assert len(a.output) == 13 and len(b.output) == 5
-        # Token equality vs single-step serving of the same prompts.
-        sa, _ = self._serve(1, list(range(10, 18)), n_tokens=13)
-        sb, _ = self._serve(1, list(range(20, 28)), n_tokens=5)
-        assert a.output == sa and b.output == sb
-
-
 class TestGroupEvents:
     def test_stored_events_carry_group_specs(self):
         events = []

@@ -13,8 +13,8 @@ the bytes changes the directory).
 
 Quantization error is bounded (2^-3 relative per element), so these tests
 pin closeness and internal consistency, not bit-parity with bf16: the
-fp8 engine must agree with ITSELF across serve paths (burst vs single
-step, restore vs recompute) bit-exactly, while the bf16 comparison is a
+fp8 engine must agree with ITSELF across serve paths (kernel vs XLA
+attention, restore vs recompute) bit-exactly, while the bf16 comparison is a
 bounded-error check.
 """
 
@@ -80,18 +80,6 @@ class TestForwardQuality:
 
 
 class TestServeConsistency:
-    def test_burst_matches_single_step(self):
-        """The fused burst carries its tail in the cache dtype, so burst
-        and single-step serving quantize identically — token output must
-        be bit-equal between them (the same invariant the bf16 engine
-        pins)."""
-        prompt = np.random.default_rng(3).integers(1, 250, 48).tolist()
-        outs = []
-        for burst in (1, 8):
-            eng = fp8_engine(decode_burst=burst)
-            outs.append(eng.generate("r0", prompt, max_new_tokens=12))
-        assert outs[0] == outs[1], outs
-
     def test_prefix_cache_hit_reuses_fp8_pages(self):
         eng = fp8_engine()
         prompt = list(range(30, 62))  # 2 pages worth
@@ -104,20 +92,21 @@ class TestServeConsistency:
 
     def test_qwen_bias_family_fp8_serves(self):
         """QKV-bias + qk-norm family (Qwen lineage) over an fp8 pool:
-        burst==single-step stays bit-equal — the family's extra
+        the kernel's quantized arm and XLA attention read the same
+        bytes, so their tokens stay bit-equal — the family's extra
         projection terms change nothing about where quantization
-        happens (scatter/tail writes)."""
+        happens (the scatter's cast on write)."""
         cfg = LlamaConfig.qwen3_tiny()
         prompt = np.random.default_rng(11).integers(
             1, cfg.vocab_size - 1, 48).tolist()
         outs = []
-        for burst in (1, 8):
+        for pallas in (False, True):
             eng = MiniEngine(EngineConfig(
                 model=cfg, num_pages=64, max_pages_per_seq=16,
                 kv_cache_dtype="f8_e4m3", model_name="qwen-fp8",
-                pod_identifier="p", decode_burst=burst), seed=0)
+                pod_identifier="p", use_pallas_decode=pallas), seed=0)
             outs.append(eng.generate("r0", prompt, max_new_tokens=10))
-        assert outs[0] == outs[1], outs
+        assert len(outs[0]) == 10 and outs[0] == outs[1], outs
 
     def test_hybrid_fp8_serves(self):
         cfg = LlamaConfig.sink_tiny()
@@ -197,19 +186,23 @@ class TestQuantKernelArm:
             pallas_paged_decode_attention(q, lat, lat, table, lens,
                                           shared_kv=True, interpret=True)
 
-    def test_hybrid_engine_pallas_fp8_matches_xla_fp8(self):
-        """Hybrid fused bursts route each cache group through the quant
-        kernel arm (per-layer group pools); pallas and XLA backends over
-        the same fp8 groups must emit identical tokens."""
-        cfg = LlamaConfig.sink_tiny()
-        prompt = np.random.default_rng(4).integers(1, 250, 64).tolist()
+    def test_windowed_engine_pallas_fp8_matches_xla_fp8(self):
+        """A window + sinks model over fp8 pages: the kernel's quant arm
+        (window page skipping, sink pages, in-VMEM upcast) and the XLA
+        backend over the same bytes must emit identical tokens. Pages of
+        16 tokens: ``kv_heads * page_size`` has to be a multiple of 32
+        for 1-byte pages to ride the kernel at all."""
+        cfg = dataclasses.replace(LlamaConfig.sink_tiny(), page_size=16)
+        prompt = np.random.default_rng(5).integers(1, 250, 64).tolist()
         outs = {}
         for pallas in (False, True):
             eng = MiniEngine(EngineConfig(
                 model=cfg, num_pages=64, num_swa_pages=64,
                 max_pages_per_seq=24, kv_cache_dtype="f8_e4m3",
-                model_name="hyb-fp8", pod_identifier="p", decode_burst=8,
+                model_name="hyb-fp8", pod_identifier="p",
                 use_pallas_decode=pallas), seed=0)
+            assert eng.attention_backends["decode"]["backend"] == (
+                "pallas" if pallas else "xla")
             outs[pallas] = eng.generate("r0", prompt, max_new_tokens=8)
         assert outs[False] == outs[True], outs
 
@@ -223,8 +216,7 @@ class TestQuantKernelArm:
             eng = MiniEngine(EngineConfig(
                 num_pages=64, max_pages_per_seq=16,
                 kv_cache_dtype="f8_e4m3", model_name="t",
-                pod_identifier="p", decode_burst=8,
-                use_pallas_decode=pallas), seed=0)
+                pod_identifier="p", use_pallas_decode=pallas), seed=0)
             outs[pallas] = eng.generate("r0", prompt, max_new_tokens=8)
         assert outs[False] == outs[True], outs
 
@@ -306,10 +298,8 @@ class TestMeshComposition:
         assert tp_eng.k_cache.sharding.shard_shape(
             tp_eng.k_cache.shape)[2] == kvh // 2
 
-    def test_tp_burst_and_dp_axis(self):
+    def test_tp_with_dp_axis(self):
         ref = self._ref()
-        _, burst = self._gen(mesh=self._mesh({"tp": 2}), decode_burst=4)
-        assert burst == ref
         _, dptp = self._gen(mesh=self._mesh({"dp": 4, "tp": 2}))
         assert dptp == ref
 

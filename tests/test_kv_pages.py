@@ -194,7 +194,7 @@ def _oracle_write_kv_pages(cache, writes, new_kv, layer_idx=None):
 
 @pytest.mark.parametrize("name", list(sp.PROGRAMS))
 def test_program_equals_take_scatter_put_back(name):
-    """Two steps of each program: logits (tokens, for a burst) and every
+    """Two steps of each program: logits and every
     pool equal to the same program writing by the old form. MLA's width-0
     V stack comes back as it went in."""
     prog = sp.PROGRAMS[name]

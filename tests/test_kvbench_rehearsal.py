@@ -1,5 +1,6 @@
 """Tier-1's walk of the benchmark's own path: every cell of
-``BENCHMARK.json``, rehearsed on the CPU through ``kvbench/run.py``.
+``BENCHMARK.json``, rehearsed on the CPU through ``kvbench/run.py`` (the
+traced walk is ``test_kvbench_rehearsal_traced.py``).
 
 The harness reaches into the program by name (``EngineConfig``'s fields,
 ``MiniEngine.attention_backends``, ``PHASE_NAMES``, ``llama.PROGRAM_*``); a
@@ -42,48 +43,3 @@ def test_cell_rehearses(cell):
     judged = {m["name"] for m in BENCHMARK["end_to_end"]
               if cell in m.get("workloads", [cell])}
     assert judged <= set(line["metrics"])
-
-
-@pytest.mark.parametrize("cell", [w["name"] for w in BENCHMARK["workloads"]])
-def test_a_traced_cell_pairs_programs_launched_ahead(cell):
-    """A traced rehearsal of every cell: ``launched_ahead_share`` is
-    reported (a replica that only decodes and has the device to itself
-    launches its next decode program before it reads the last one's
-    tokens), and the pairing of device programs with ``step.dispatch`` /
-    ``step.fetch`` (a fetch may follow a later dispatch: it is found by its
-    ``launch``) reads no clock fault and leaves unpaired only what the
-    slice's head cut: held to that in the cell whose replicas take turns.
-    Where two are busy at once the rehearsal's CPU runs their programs side
-    by side and a program launched ahead waits for the one it reads, so
-    the programs do not start in the order of their launches as a chip's
-    do, and the pairing has nothing to stand on."""
-    import re
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run(
-        [sys.executable, "kvbench/run.py", "--workload", cell, "--seed",
-         "2900000555", "--seconds", "6", "--trace", "1", "--rehearse"],
-        capture_output=True, text=True, timeout=300, cwd=ROOT, env=env)
-    assert out.returncode == 0, out.stderr[-4000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["failed"] == 0
-    share = line["metrics"]["launched_ahead_share"]
-    assert share["unit"] == "%" and 0.0 <= share["value"] <= 100.0
-    # The cells whose models have latent-attention layers say how many of
-    # the slice's chunks attended per head (none at a rehearsal's chunks
-    # of 64: the form changes from 167 queries on at its widths); the
-    # dense cells do not report it.
-    latent = cell.startswith(("deepseek-v3.2-exp", "gigachat3.5"))
-    assert ("prefill_per_head_share" in line["metrics"]) == latent
-    if latent:
-        per_head = line["metrics"]["prefill_per_head_share"]
-        assert per_head["unit"] == "%" and 0.0 <= per_head["value"] <= 100.0
-    (found,) = re.findall(
-        r"launches: (\d+) step programs, (\d+) placed \(by launch, offset "
-        r"(\d+)\), unpaired (\d+), clock_fault (\d+)", out.stdout)
-    programs, placed, offset, unpaired, faults = map(int, found)
-    assert programs > 0 and placed == programs - unpaired
-    if cell == "qwen3-1.7b.sessions":
-        # A replica has at most two programs out when the slice begins.
-        assert faults == 0 and unpaired == offset <= 4

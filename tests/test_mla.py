@@ -4,7 +4,7 @@ The engine serves MLA in absorbed form — multi-query paged attention over
 the latent itself (models/llama.py MLA branch). These tests pin that to
 the textbook non-absorbed formulation (materialize per-head K/V from the
 latent, plain causal attention), and cover the family end-to-end:
-latent-paged engine serving, prefix reuse, fused bursts, mla_attention
+latent-paged engine serving, prefix reuse, mla_attention
 event tagging (reference ``events.go:34``), and single-stream offload
 round-trips.
 """
@@ -150,14 +150,6 @@ class TestMLAEngine:
         assert req.cached_len > 0  # latent blocks served from cache
         eng2 = self._engine()
         assert eng2.generate("r", prompt, max_new_tokens=8) == toks
-
-    def test_burst_token_identical(self):
-        prompt = list(range(30, 49))
-        single = self._engine(decode_burst=1).generate(
-            "r", prompt, max_new_tokens=12)
-        burst = self._engine(decode_burst=8).generate(
-            "r", prompt, max_new_tokens=12)
-        assert burst == single
 
     def test_events_tagged_mla(self):
         events = []

@@ -323,10 +323,10 @@ class TestFramesUnderEveryProgram:
 
     SIZES = {
         "llmd_kv_cache_tpu/models/llama.py": {
-            "_forward_impl_grouped": 94, "_forward_impl": 32,
+            "_forward_impl_grouped": 83, "_forward_impl": 32,
             "forward_prefill_pallas": 39,
             "forward_prefill_pallas.attention_fn": 34,
-            "forward_decode_pallas": 37, "step_program.program": 35},
+            "forward_decode_pallas": 37, "step_program.program": 34},
         "llmd_kv_cache_tpu/models/engine.py": {
             "MiniEngine.step": 26, "MiniEngine._prefill_chunk": 41,
             "MiniEngine._launch_decode": 36},
@@ -500,7 +500,6 @@ class TestStepForms:
         from llmd_kv_cache_tpu.models import llama
 
         prog, params, static = self._case(name)
-        burst = "steps" in static
         want_pools = sp.init_pools(prog.cfg)
         got_pools = sp.init_pools(prog.cfg)
         for step in range(2):
@@ -511,12 +510,9 @@ class TestStepForms:
             tokens, row, got_pools = prog.step(
                 params, prog.cfg, packed, got_pools, shapes=shapes,
                 keep_row=prog.chunk, **static)
-            if burst:
-                want = np.asarray(out)           # it samples for itself
-            else:
-                logits = np.asarray(out, np.float32)
-                logits = logits.reshape(-1, logits.shape[-1])
-                want = logits.argmax(-1)
+            logits = np.asarray(out, np.float32)
+            logits = logits.reshape(-1, logits.shape[-1])
+            want = logits.argmax(-1)
             assert tokens.dtype == jnp.int32 and tokens.shape == want.shape
             np.testing.assert_array_equal(np.asarray(tokens), want)
             if prog.chunk:
@@ -573,10 +569,8 @@ class TestStepForms:
                 prog.step, params, prog.cfg, packed, pools, shapes=shapes,
                 keep_row=keep, **static)
             with_vocab = [s for s in shapes_out if vocab in s]
-            burst = "steps" in static
-            assert with_vocab == ([(vocab,)] if keep and not burst else [])
+            assert with_vocab == ([(vocab,)] if keep else [])
             assert shapes_out[len(with_vocab) + 1:] == [
                 tuple(p.shape) for p in pools]
         # The form tests and references call does hand its logits back.
-        if "steps" not in static:
-            assert any(vocab in s for s in outputs(prog.fn, *args, **static))
+        assert any(vocab in s for s in outputs(prog.fn, *args, **static))

@@ -275,113 +275,6 @@ def test_merged_vs_per_head_parity(window, sinks):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("window,sinks", [(None, None), (12, None), (12, 4)])
-def test_burst_tail_matches_scattered_reference(window, sinks):
-    """The dense burst-local KV tail (fused-decode path: base cache
-    frozen, burst tokens in a small carried tail) must equal scattering
-    the valid tail tokens into the cache and attending normally — for
-    the XLA path and both kernel grids, across window/sink configs."""
-    # Table capacity is 16 tokens (4 pages x 4): ctx + T must fit so the
-    # scattered reference is faithful.
-    T = 6
-    q, k_cache, v_cache, table, _ = build_case(q_heads=8, kv_heads=2, ctx=10)
-    rng = np.random.default_rng(3)
-    B = q.shape[0]
-    ctx_lens = jnp.asarray([10, 7], jnp.int32)
-    tail_lens = jnp.asarray([5, 1], jnp.int32)
-    tail_k = jnp.asarray(rng.normal(size=(B, T, 2, 8)), jnp.float32)
-    tail_v = jnp.asarray(rng.normal(size=(B, T, 2, 8)), jnp.float32)
-
-    tpos = ctx_lens[:, None] + jnp.arange(T)[None, :]
-    tvalid = jnp.arange(T)[None, :] < tail_lens[:, None]
-    k_full = scatter_kv_pages(k_cache, tail_k, table, tpos, tvalid)
-    v_full = scatter_kv_pages(v_cache, tail_v, table, tpos, tvalid)
-    total = ctx_lens + tail_lens
-    ref = paged_attention(q[:, None], k_full, v_full, table,
-                          (total - 1)[:, None], total,
-                          sliding_window=window, attention_sinks=sinks)[:, 0]
-
-    got_xla = paged_attention(q[:, None], k_cache, v_cache, table,
-                              (total - 1)[:, None], ctx_lens,
-                              sliding_window=window, attention_sinks=sinks,
-                              tail_k=tail_k, tail_v=tail_v,
-                              tail_lens=tail_lens)[:, 0]
-    np.testing.assert_allclose(np.asarray(got_xla), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    for mh in (False, True):
-        got = pallas_paged_decode_attention(
-            q, k_cache, v_cache, table, ctx_lens, sliding_window=window,
-            sinks=sinks, merge_heads=mh, tail_k=tail_k, tail_v=tail_v,
-            tail_lens=tail_lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_burst_tail_sink_positions():
-    """Torture case: a request enters the burst with ctx_base < sinks, so
-    some TAIL slots sit at sink positions — they must stay attendable
-    once the burst outruns the window (the XLA reference keeps them via
-    the concatenated-position mask; the kernels' tail fold must agree)."""
-    T = 8
-    q, k_cache, v_cache, table, _ = build_case(q_heads=8, kv_heads=2, ctx=2)
-    rng = np.random.default_rng(6)
-    B = q.shape[0]
-    ctx_lens = jnp.asarray([2, 1], jnp.int32)
-    tail_lens = jnp.asarray([8, 6], jnp.int32)  # burst outran window=3
-    tail_k = jnp.asarray(rng.normal(size=(B, T, 2, 8)), jnp.float32)
-    tail_v = jnp.asarray(rng.normal(size=(B, T, 2, 8)), jnp.float32)
-    window, sinks = 3, 4
-
-    tpos = ctx_lens[:, None] + jnp.arange(T)[None, :]
-    tvalid = jnp.arange(T)[None, :] < tail_lens[:, None]
-    k_full = scatter_kv_pages(k_cache, tail_k, table, tpos, tvalid)
-    v_full = scatter_kv_pages(v_cache, tail_v, table, tpos, tvalid)
-    total = ctx_lens + tail_lens
-    ref = paged_attention(q[:, None], k_full, v_full, table,
-                          (total - 1)[:, None], total,
-                          sliding_window=window, attention_sinks=sinks)[:, 0]
-    got_xla = paged_attention(q[:, None], k_cache, v_cache, table,
-                              (total - 1)[:, None], ctx_lens,
-                              sliding_window=window, attention_sinks=sinks,
-                              tail_k=tail_k, tail_v=tail_v,
-                              tail_lens=tail_lens)[:, 0]
-    np.testing.assert_allclose(np.asarray(got_xla), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    for mh in (False, True):
-        got = pallas_paged_decode_attention(
-            q, k_cache, v_cache, table, ctx_lens, sliding_window=window,
-            sinks=sinks, merge_heads=mh, tail_k=tail_k, tail_v=tail_v,
-            tail_lens=tail_lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_burst_tail_shared_kv():
-    """Absorbed-MLA form: the latent tail is both K and V (single-stream),
-    with the value read being the same latent the key matched."""
-    T = 4
-    q, k_cache, _v, table, _ = build_case(q_heads=8, kv_heads=1, ctx=12)
-    rng = np.random.default_rng(4)
-    B = q.shape[0]
-    ctx_lens = jnp.asarray([12, 9], jnp.int32)
-    tail_lens = jnp.asarray([3, 1], jnp.int32)
-    tail_k = jnp.asarray(rng.normal(size=(B, T, 1, 8)), jnp.float32)
-
-    tpos = ctx_lens[:, None] + jnp.arange(T)[None, :]
-    tvalid = jnp.arange(T)[None, :] < tail_lens[:, None]
-    k_full = scatter_kv_pages(k_cache, tail_k, table, tpos, tvalid)
-    total = ctx_lens + tail_lens
-    ref = paged_attention(q[:, None], k_full, k_full, table,
-                          (total - 1)[:, None], total)[:, 0]
-    for mh in (False, True):
-        got = pallas_paged_decode_attention(
-            q, k_cache, k_cache, table, ctx_lens, shared_kv=True,
-            merge_heads=mh, tail_k=tail_k, tail_lens=tail_lens,
-            interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-
 def test_stacked_cache_layer_idx():
     """layer_idx mode: the kernel DMAs from the full [layers, pages, …]
     stack (slicing outside the pallas_call would materialize a per-layer
@@ -460,27 +353,20 @@ def test_batch_rows_parity(rows):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("window,sinks", [(None, None), (12, 4)])
-def test_batch_rows_with_tail_and_windows(window, sinks):
-    """batch_rows composed with the burst tail, sliding windows, and
-    sinks — the full fused-decode feature set in one multi-row program."""
-    T = 6
-    q, k_cache, v_cache, table, _ = build_case(q_heads=8, kv_heads=2, ctx=10)
-    rng = np.random.default_rng(3)
-    B = q.shape[0]
-    ctx_lens = jnp.asarray([10, 7], jnp.int32)
-    tail_lens = jnp.asarray([5, 1], jnp.int32)
-    tail_k = jnp.asarray(rng.normal(size=(B, T, 2, 8)), jnp.float32)
-    tail_v = jnp.asarray(rng.normal(size=(B, T, 2, 8)), jnp.float32)
+@pytest.mark.parametrize("window,sinks", [(None, None), (6, 4)])
+def test_batch_rows_with_windows(window, sinks):
+    """batch_rows composed with sliding windows and sinks in one
+    multi-row program (rows of 16 and 11 keys: the longer skips a page
+    between its sinks and its window)."""
+    q, k_cache, v_cache, table, ctx_lens = build_case(
+        q_heads=8, kv_heads=2, ctx=16)
 
     base = pallas_paged_decode_attention(
         q, k_cache, v_cache, table, ctx_lens, sliding_window=window,
-        sinks=sinks, tail_k=tail_k, tail_v=tail_v, tail_lens=tail_lens,
-        interpret=True)
+        sinks=sinks, interpret=True)
     multi = pallas_paged_decode_attention(
         q, k_cache, v_cache, table, ctx_lens, sliding_window=window,
-        sinks=sinks, tail_k=tail_k, tail_v=tail_v, tail_lens=tail_lens,
-        batch_rows=2, interpret=True)
+        sinks=sinks, batch_rows=2, interpret=True)
     np.testing.assert_allclose(np.asarray(multi), np.asarray(base),
                                rtol=2e-5, atol=2e-5)
 
@@ -505,108 +391,3 @@ def test_batch_rows_requires_merged():
         pallas_paged_decode_attention(
             q, k_cache, v_cache, table, ctx_lens, merge_heads=False,
             batch_rows=2, interpret=True)
-
-
-# ---- live granules: a row's copies and folds follow its own keys ----------
-#
-# At pages of 16 a granule is 8 pages (128 keys) and the default superblock
-# 64 pages, so these lengths sit on every edge of the guard: one page, a
-# page boundary, a granule boundary, a superblock boundary, and a row of
-# three rounds whose last is cut short.
-GRANULE_CTX = (1, 15, 16, 127, 128, 129, 1023, 1024, 1025, 2500)
-GRANULE_PS = 16
-
-
-def granule_case(ctx_lens, kv_heads=2, q_heads=4, head_dim=16, seed=11,
-                 width=160):
-    """Rows of ``ctx_lens`` keys over distinct, shuffled pages of 16."""
-    rng = np.random.default_rng(seed)
-    batch = len(ctx_lens)
-    pages = [-(-c // GRANULE_PS) for c in ctx_lens]
-    num_pages = sum(pages) + 1
-    order = 1 + rng.permutation(num_pages - 1)
-    table = np.zeros((batch, width), np.int32)
-    at = 0
-    for b, n in enumerate(pages):
-        table[b, :n] = order[at:at + n]
-        at += n
-    shape = (num_pages, kv_heads, GRANULE_PS, head_dim)
-    k_cache = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    v_cache = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(batch, q_heads, head_dim)), jnp.float32)
-    return (q, k_cache, v_cache, jnp.asarray(table),
-            jnp.asarray(ctx_lens, jnp.int32))
-
-
-# name -> (kv_heads, keyword arguments of the kernel)
-GRANULE_ARMS = {
-    "merged": (2, {}),
-    "rows4": (2, dict(batch_rows=4)),
-    "per_head": (2, dict(merge_heads=False)),
-    "shared_copy": (1, dict(shared_kv=True, shared_stream="copy")),
-    "shared_reuse": (1, dict(shared_kv=True, shared_stream="reuse")),
-    "shared_copy_merged": (2, dict(shared_kv=True, shared_stream="copy")),
-    "tail": (2, {}),
-    "tail_rows4": (2, dict(batch_rows=4)),
-    "kpb4": (2, dict(pages_per_block=4)),  # the granule is the superblock
-    "kpb12": (2, dict(pages_per_block=12)),  # no whole number of granules
-    "kpb16": (2, dict(pages_per_block=16)),  # two granules a round
-    "kpb16_per_head": (2, dict(pages_per_block=16, merge_heads=False)),
-}
-
-
-@pytest.mark.parametrize("window,sinks",
-                         [(None, None), (300, None), (1500, 20)])
-@pytest.mark.parametrize("arm", list(GRANULE_ARMS))
-def test_live_granules_match_reference(arm, window, sinks):
-    """Every edge of the live-granule guard in one batch, against the XLA
-    reference: whatever the superblock, the grid, the stream or the
-    window, a row attends its own keys and nothing else."""
-    kv_heads, kw = GRANULE_ARMS[arm]
-    q, k_cache, v_cache, table, ctx_lens = granule_case(
-        GRANULE_CTX, kv_heads=kv_heads)
-    if kw.get("shared_kv"):
-        v_cache = k_cache
-    ref_kw, tail = {}, {}
-    q_pos = ctx_lens - 1
-    if arm.startswith("tail"):
-        rng = np.random.default_rng(5)
-        T = 6
-        shape = (len(GRANULE_CTX), T, kv_heads, q.shape[-1])
-        tail = dict(tail_k=jnp.asarray(rng.normal(size=shape), jnp.float32),
-                    tail_v=jnp.asarray(rng.normal(size=shape), jnp.float32),
-                    tail_lens=jnp.asarray(
-                        rng.integers(1, T + 1, len(GRANULE_CTX)), jnp.int32))
-        q_pos = ctx_lens + tail["tail_lens"] - 1
-    out = pallas_paged_decode_attention(
-        q, k_cache, v_cache, table, ctx_lens, sliding_window=window,
-        sinks=sinks, interpret=True, **kw, **tail)
-    ref = paged_attention(
-        q[:, None], k_cache, v_cache, table, q_pos[:, None], ctx_lens,
-        sliding_window=window, attention_sinks=sinks, **tail)[:, 0]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("arm", ["merged", "per_head", "rows2", "kpb16"])
-def test_dead_granules_are_not_folded(arm):
-    """A short row runs after long rows whose V pages hold ``inf``: the
-    staging scratch past the short row's one live granule still holds
-    them, and a fold of it through a mask would give 0 x inf. The short
-    rows come out finite and equal to the reference."""
-    kw = {"merged": {}, "per_head": dict(merge_heads=False),
-          "rows2": dict(batch_rows=2),
-          "kpb16": dict(pages_per_block=16)}[arm]
-    ctx = (1025, 1030, 20, 129)
-    q, k_cache, v_cache, table, ctx_lens = granule_case(ctx, width=72)
-    long_pages = np.asarray(table)[:2].ravel()
-    v_cache = v_cache.at[long_pages[long_pages > 0]].set(jnp.inf)
-    out = pallas_paged_decode_attention(
-        q, k_cache, v_cache, table, ctx_lens, interpret=True, **kw)
-    ref = paged_attention(
-        q[:, None], k_cache, v_cache, table, (ctx_lens - 1)[:, None],
-        ctx_lens)[:, 0]
-    assert not np.isfinite(np.asarray(out[:2])).any()  # the test's premise
-    assert np.isfinite(np.asarray(out[2:])).all()
-    np.testing.assert_allclose(np.asarray(out[2:]), np.asarray(ref[2:]),
-                               rtol=2e-5, atol=2e-5)

@@ -89,22 +89,6 @@ def test_pallas_decode_matches_xla_with_attention_sinks():
     assert outs[False] == outs[True]
 
 
-def test_pallas_decode_matches_xla_with_sink_bursts():
-    """Fused decode bursts through the kernel for sink models."""
-    prompt = list(range(60, 80))
-    outs = {}
-    for use_pallas in (False, True):
-        engine = MiniEngine(
-            EngineConfig(model=LlamaConfig.sink_tiny(), num_pages=64,
-                         max_pages_per_seq=16, model_name="sink",
-                         pod_identifier="p", use_pallas_decode=use_pallas,
-                         decode_burst=4),
-            seed=0,
-        )
-        outs[use_pallas] = engine.generate("r", prompt, max_new_tokens=8)
-    assert outs[False] == outs[True]
-
-
 def test_pallas_decode_matches_xla_for_mla():
     """Absorbed MLA decodes through the flash kernel as the kv_heads=1
     multi-query case (latent pool passed as both K and V) — the engine no
@@ -164,7 +148,6 @@ def test_pallas_decode_batch_rows_matches_single_row():
                 model=LlamaConfig.tiny(), num_pages=128,
                 max_pages_per_seq=16, model_name="tiny", pod_identifier="p",
                 use_pallas_decode=True, decode_batch_rows=rows,
-                decode_burst=4,
             ),
             seed=0,
         )

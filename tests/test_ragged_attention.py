@@ -5,8 +5,8 @@ metadata (per-row ``q_start/q_len/ctx_len`` prefix-summed into a flat token
 axis). Every case here runs in interpreter mode on the CPU backend and
 checks the ragged kernel row-by-row against the XLA ``paged_attention``
 reference, across the fallback-matrix axes: sliding window, attention
-sinks, fp8 (e4m3) pages, MLA shared-latent streaming, dense decode tails,
-and flat-axis padding. The final test drives the engine end-to-end:
+sinks, fp8 (e4m3) pages, MLA shared-latent streaming, and flat-axis
+padding. The final test drives the engine end-to-end:
 ``ragged_attention=True`` must emit token streams identical to the padded
 two-kernel fallback on a mixed continuous-batching workload.
 """
@@ -28,15 +28,11 @@ from llmd_kv_cache_tpu.ops.pallas_paged_attention import (
 def run_case(q_lens, ctx_lens, q_heads=4, kv_heads=2, head_dim=8,
              page_size=4, num_pages=64, q_tile=8, sliding_window=None,
              sinks=None, dtype=jnp.float32, cache_dtype=None,
-             shared_kv=False, shared_stream="copy", tail_lens=None,
-             seed=0):
+             shared_kv=False, shared_stream="copy", seed=0):
     """Build a ragged batch, run the kernel, assert per-row vs reference.
 
-    Rows without a tail use scatter-then-attend semantics: all
-    ``ctx + q_len`` keys are already in the pages and queries sit at
-    ``ctx .. ctx+q_len-1``. A row with ``tail_lens[r] = T > 0`` is a
-    decode row whose burst KV lives in a dense tail: paged keys span
-    ``[0, ctx)`` and its single query sits at ``ctx + T - 1``.
+    Scatter-then-attend semantics: all ``ctx + q_len`` keys are already
+    in the pages and queries sit at ``ctx .. ctx+q_len-1``.
     """
     rows = len(q_lens)
     pages_per_seq = 8
@@ -45,9 +41,7 @@ def run_case(q_lens, ctx_lens, q_heads=4, kv_heads=2, head_dim=8,
     table = jnp.asarray(table, jnp.int32)
     cache_dtype = cache_dtype or dtype
 
-    tails = tail_lens or [0] * rows
-    total_lens = [c + (0 if t else q) for c, q, t
-                  in zip(ctx_lens, q_lens, tails)]
+    total_lens = [c + q for c, q in zip(ctx_lens, q_lens)]
     max_total = max(total_lens)
 
     k_cache = jnp.zeros((num_pages, kv_heads, page_size, head_dim), dtype)
@@ -64,57 +58,35 @@ def run_case(q_lens, ctx_lens, q_heads=4, kv_heads=2, head_dim=8,
     k_cache = k_cache.astype(cache_dtype)
     v_cache = k_cache if shared_kv else v_cache.astype(cache_dtype)
 
-    max_tail = max(max(tails), 1)
-    tail_k = jnp.asarray(rng.randn(rows, max_tail, kv_heads, head_dim),
-                         dtype)
-    tail_v = (tail_k if shared_kv else jnp.asarray(
-        rng.randn(rows, max_tail, kv_heads, head_dim), dtype))
-
     total_q = sum(q_lens)
     pad = (-total_q) % q_tile
     q_flat = jnp.asarray(rng.randn(total_q + pad, q_heads, head_dim), dtype)
     row_starts = jnp.asarray(
         np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
 
-    tail_kw = {}
-    if tail_lens is not None:
-        tail_kw = dict(tail_k=tail_k, tail_lens=jnp.asarray(tails, jnp.int32))
-        if not shared_kv:
-            tail_kw["tail_v"] = tail_v
     out = pallas_paged_ragged_attention(
         q_flat, k_cache, v_cache, table, row_starts,
         jnp.asarray(ctx_lens, jnp.int32),
         q_tile=q_tile, sliding_window=sliding_window, sinks=sinks,
         shared_kv=shared_kv, shared_stream=shared_stream,
-        interpret=True, **tail_kw)
+        interpret=True)
 
     for r in range(rows):
         qs, qe = int(row_starts[r]), int(row_starts[r + 1])
         q_r = q_flat[qs:qe][None]  # [1, q_len, qh, hd]
-        if tails[r]:
-            # Decode-tail row: frozen paged base + dense burst-local tail.
-            q_pos = jnp.asarray([[ctx_lens[r] + tails[r] - 1]], jnp.int32)
-            ref = paged_attention(
-                q_r, k_cache, v_cache, table[r:r + 1], q_pos,
-                jnp.asarray([ctx_lens[r]], jnp.int32),
-                sliding_window=sliding_window,
-                attention_sinks=sinks or 0,
-                tail_k=tail_k[r:r + 1], tail_v=tail_v[r:r + 1],
-                tail_lens=jnp.asarray([tails[r]], jnp.int32))[0]
-        else:
-            q_pos = jnp.arange(ctx_lens[r], total_lens[r])[None]
-            ref = paged_attention(
-                q_r, k_cache, v_cache, table[r:r + 1], q_pos,
-                jnp.asarray([total_lens[r]], jnp.int32),
-                sliding_window=sliding_window,
-                attention_sinks=sinks or 0)[0]
+        q_pos = jnp.arange(ctx_lens[r], total_lens[r])[None]
+        ref = paged_attention(
+            q_r, k_cache, v_cache, table[r:r + 1], q_pos,
+            jnp.asarray([total_lens[r]], jnp.int32),
+            sliding_window=sliding_window,
+            attention_sinks=sinks or 0)[0]
         tol = 2e-5 if (dtype == jnp.float32
                        and cache_dtype == jnp.float32) else 5e-2
         np.testing.assert_allclose(
             np.asarray(out[qs:qe], np.float32), np.asarray(ref, np.float32),
             rtol=tol, atol=tol,
             err_msg=f"row {r} q_lens={q_lens} ctx={ctx_lens} "
-                    f"w={sliding_window} s={sinks} tails={tails}")
+                    f"w={sliding_window} s={sinks}")
 
 
 def test_mixed_batch_straddles_q_tiles():
@@ -164,13 +136,6 @@ def test_mla_shared_latent(stream):
     head_dim) feeds both matmuls via the shared-KV stream."""
     run_case([1, 5, 1], [13, 0, 27], q_heads=4, kv_heads=1, head_dim=32,
              shared_kv=True, shared_stream=stream)
-
-
-@pytest.mark.parametrize("window,sinks", [(None, None), (8, None), (8, 2)])
-def test_decode_tail_rows(window, sinks):
-    """Burst-decode rows carry their in-flight KV as a dense tail."""
-    run_case([1, 1, 5], [13, 21, 0], tail_lens=[2, 3, 0],
-             sliding_window=window, sinks=sinks)
 
 
 def test_rejects_bad_metadata():

@@ -115,14 +115,6 @@ class TestSinkEngine:
             seed=0).generate("r", prompt, max_new_tokens=16)
         assert sunk != plain
 
-    def test_burst_token_identical(self):
-        prompt = list(range(10, 30))
-        single = self._engine(decode_burst=1).generate(
-            "r", prompt, max_new_tokens=16)
-        burst = self._engine(decode_burst=8).generate(
-            "r", prompt, max_new_tokens=16)
-        assert burst == single
-
     def test_offload_spec_must_declare_sinks(self, tmp_path):
         from llmd_kv_cache_tpu.offload.spec import SharedStorageOffloadSpec
 

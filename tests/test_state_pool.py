@@ -215,7 +215,6 @@ def stateful_config(**kw) -> LlamaConfig:
 
 
 @pytest.mark.parametrize("change,reason", [
-    (dict(decode_burst=4), "decode_burst"),
     (dict(ragged_attention=True), "ragged_attention"),
     (dict(max_batch=6), "state_slots 6 for max_batch 6"),
 ])

@@ -66,18 +66,6 @@ def test_tp_with_dp_axis(setup):
     assert out == ref
 
 
-def test_tp_decode_burst(setup):
-    """Fused multi-token decode bursts work through the sharded path."""
-    cfg, params = setup
-    prompt = np.random.default_rng(2).integers(1, 250, 12).tolist()
-    ref = _engine(cfg, params, decode_burst=4).generate(
-        "r", prompt, max_new_tokens=8)
-    mesh = make_mesh({"tp": 2}, jax.devices()[:2])
-    out = _engine(cfg, params, mesh=mesh, decode_burst=4).generate(
-        "r", prompt, max_new_tokens=8)
-    assert out == ref
-
-
 def test_tp_hybrid_engine(setup):
     """Hybrid (full+SWA) models shard both page pools."""
     cfg = LlamaConfig(
@@ -105,17 +93,6 @@ def test_tp_pallas_attention(setup):
     out = _engine(cfg, params, mesh=mesh,
                   use_pallas_decode=True).generate("r", prompt,
                                                    max_new_tokens=8)
-    assert out == ref
-
-
-def test_tp_pallas_decode_burst(setup):
-    """Fused decode bursts through the sharded Pallas kernel."""
-    cfg, params = setup
-    prompt = np.random.default_rng(7).integers(1, 250, 12).tolist()
-    ref = _engine(cfg, params).generate("r", prompt, max_new_tokens=8)
-    mesh = make_mesh({"tp": 2}, jax.devices()[:2])
-    out = _engine(cfg, params, mesh=mesh, use_pallas_decode=True,
-                  decode_burst=4).generate("r", prompt, max_new_tokens=8)
     assert out == ref
 
 
@@ -158,18 +135,6 @@ def test_tp_mla_matches_single_device(mla_setup):
     assert out == ref
 
 
-def test_tp_mla_decode_burst(mla_setup):
-    """Fused decode bursts through the sharded absorbed-MLA path."""
-    cfg, params = mla_setup
-    prompt = np.random.default_rng(9).integers(1, 250, 12).tolist()
-    ref = _engine(cfg, params, decode_burst=4).generate(
-        "r", prompt, max_new_tokens=8)
-    mesh = make_mesh({"tp": 2}, jax.devices()[:2])
-    out = _engine(cfg, params, mesh=mesh, decode_burst=4).generate(
-        "r", prompt, max_new_tokens=8)
-    assert out == ref
-
-
 def test_tp_mla_pallas_decode(mla_setup):
     """Absorbed MLA through the flash-decode kernel under tp: each shard
     runs its local query heads as one multi-query group against the
@@ -178,8 +143,9 @@ def test_tp_mla_pallas_decode(mla_setup):
     prompt = np.random.default_rng(10).integers(1, 250, 24).tolist()
     ref = _engine(cfg, params).generate("r", prompt, max_new_tokens=8)
     mesh = make_mesh({"tp": 2}, jax.devices()[:2])
-    out = _engine(cfg, params, mesh=mesh, use_pallas_decode=True,
-                  decode_burst=4).generate("r", prompt, max_new_tokens=8)
+    out = _engine(cfg, params, mesh=mesh,
+                  use_pallas_decode=True).generate("r", prompt,
+                                                   max_new_tokens=8)
     assert out == ref
 
 
@@ -274,34 +240,3 @@ def test_ep_serve_moe_matches_single_device():
     assert got_ep == ref
     got_ep_tp = run(make_mesh({"ep": 2, "tp": 2}, jax.devices()[:4]))
     assert got_ep_tp == ref
-
-
-def test_decode_burst_under_sp_and_ep_meshes(setup):
-    """Fused decode bursts under sp (prefill-sharding only; decode is
-    seq=1) and ep (MoE-less model: axis present but unused) meshes —
-    the architecture doc's composition matrix cites this test."""
-    cfg, params = setup
-    prompt = np.random.default_rng(5).integers(1, 250, 16).tolist()
-    ref = _engine(cfg, params, decode_burst=4).generate(
-        "r", prompt, max_new_tokens=8)
-    for axis in ("sp", "ep"):
-        mesh = make_mesh({axis: 2}, jax.devices()[:2])
-        out = _engine(cfg, params, mesh=mesh, decode_burst=4).generate(
-            "r", prompt, max_new_tokens=8)
-        assert out == ref, axis
-
-
-def test_decode_burst_under_ep_moe(setup):
-    """Bursts through a REAL expert-parallel MoE engine (experts
-    sharded over ep) match single-device."""
-    from llmd_kv_cache_tpu.models.llama import init_params as _init
-
-    cfg = LlamaConfig.mixtral_tiny()
-    params = _init(jax.random.PRNGKey(7), cfg)
-    prompt = np.random.default_rng(6).integers(1, 250, 16).tolist()
-    ref = _engine(cfg, params, decode_burst=4).generate(
-        "r", prompt, max_new_tokens=8)
-    mesh = make_mesh({"ep": 2}, jax.devices()[:2])
-    out = _engine(cfg, params, mesh=mesh, decode_burst=4).generate(
-        "r", prompt, max_new_tokens=8)
-    assert out == ref
