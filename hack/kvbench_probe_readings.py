@@ -20,7 +20,9 @@ chip (``chiprun -- python3 hack/kvbench_probe_readings.py ...``) or, with
                        fault, e.g. ``swiglu_limit=0``
   --fault NAME         a fault planted in the serving program
                        (``FAULTS``); it has to come out not ok
-                       (``xla-recurrence`` is no fault: the recurrence's
+                       (``verify-mask``: a drafting model's first verified
+                       position sees the draft's key too;
+                       ``xla-recurrence`` is no fault: the recurrence's
                        XLA form in the kernels' place, which has to read
                        as the kernels do)
 """
@@ -101,10 +103,26 @@ def _xla_recurrence() -> None:
         setattr(gd, name, functools.wraps(served)(xla))
 
 
+def _verify_mask_off_by_one() -> None:
+    """A speculative step's first position sees the draft's key too (every
+    query row of the decode kernel takes the last position's bound)."""
+    from llmd_kv_cache_tpu.ops import pallas_paged_attention as ppa
+
+    served = ppa._decode_mask
+
+    def off_by_one(positions, ctx_len, sliding_window, sinks, back=None,
+                   first_key=0):
+        return served(positions, ctx_len, sliding_window, sinks, None,
+                      first_key)
+
+    ppa._decode_mask = off_by_one
+
+
 # Faults planted in the program, by name. Each replaces something the step
 # programs look up when they are first traced.
 FAULTS = {"stale-state": _stale_state, "conv-tail": _conv_tail_dropped,
-          "no-gate": _gate_left_out, "xla-recurrence": _xla_recurrence}
+          "no-gate": _gate_left_out, "xla-recurrence": _xla_recurrence,
+          "verify-mask": _verify_mask_off_by_one}
 
 
 def main() -> None:

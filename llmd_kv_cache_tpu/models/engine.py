@@ -77,6 +77,7 @@ from ..telemetry.tracing import (
 )
 from ..utils.logging import get_logger
 from .llama import (
+    DRAFTING_PROGRAMS,
     LlamaConfig,
     copy_state_slot,
     init_kv_cache,
@@ -359,6 +360,9 @@ class Request:
     page_hit_blocks: int = 0
     snapshots: list[int] = field(default_factory=list)
     checkpoint: Optional[int] = None
+    # A model with a prediction module: the module's draft of the token
+    # after ``output[-1]``, which the next decode step verifies.
+    draft: int = 0
 
     @property
     def total_len(self) -> int:
@@ -1136,6 +1140,12 @@ class MiniEngine:
         self._prev = jax.device_put(
             np.zeros((self.cfg.max_batch + len(mcfg.step_counters),),
                      np.int32), self._device) if self._defers else None
+        # The most tokens a decode step gives a row: 2 where the model
+        # brings a prediction module and every step verifies its draft.
+        self._step_tokens = 1
+        if mcfg.num_nextn_predict_layers:
+            self._serve_drafting(use_pallas, bool(prefill_pallas),
+                                 interpret, offload_spec)
 
         # What actually serves each phase, resolved above for the engine's
         # lifetime; read through ``attention_backends``.
@@ -1471,6 +1481,8 @@ class MiniEngine:
                 rec.host = np.asarray(rec.picked)
                 self._device_counts(sp, rec.host[rec.padded:], rec.tokens,
                                     rec.program)
+                if self._step_tokens > 1:
+                    self._read_drafts(sp, rec)
         return rec.host
 
     def _drain(self, cause: str) -> None:
@@ -1755,6 +1767,12 @@ class MiniEngine:
         # prompt token for logits, hence the min with len-1); add_request
         # drains it synchronously, enqueue leaves it for step().
         req.prefill_pos = min(req.cached_len, len(req.prompt) - 1)
+        if self.cfg.model.num_nextn_predict_layers and req.cached_len:
+            # A model that drafts: the module's row at the hit's first new
+            # slot is made from the hidden state of the position before it,
+            # which no page holds. The hit's last position is computed
+            # again (its latents written as they stand): one token a hit.
+            req.prefill_pos = min(req.prefill_pos, req.cached_len - 1)
         self.requests[request_id] = req
         self._running.append(request_id)
         if self.telemetry is not None:
@@ -2671,9 +2689,10 @@ class MiniEngine:
                     cur = self._launch_decode(active)
                 if (cur is not None and one and not prefilling
                         and self._lone_decodes >= 2):
-                    # A row whose unread token is its last is left out.
+                    # A row whose unread token(s) may be its last is left out.
                     ahead = [req for req in active if not (
-                        len(req.output) + 1 >= req.max_new_tokens
+                        len(req.output) + self._step_tokens
+                        >= req.max_new_tokens
                         and cur.row_of(req) >= 0)]
                     if ahead:
                         self._unread = self._launch_decode(ahead, cur)
@@ -2968,14 +2987,14 @@ class MiniEngine:
                     self._commit_prefill_chunk(req)
         return out
 
-    def _row_emitted(self, req: Request, now: float) -> None:
-        """Bookkeeping of one row's newly decoded token."""
+    def _row_emitted(self, req: Request, now: float, n: int = 1) -> None:
+        """Bookkeeping of one row's ``n`` newly decoded tokens."""
         if self.telemetry is not None:
-            self.telemetry.on_decode_tokens(req.request_id, 1, now)
+            self.telemetry.on_decode_tokens(req.request_id, n, now)
         if req.traceparent is not None:
             # Event-style span: marks the emission point in the trace.
             span_event(SPAN_ENGINE_DECODE_STEP, req.traceparent,
-                       request_id=req.request_id, tokens=1,
+                       request_id=req.request_id, tokens=n,
                        computed_len=req.computed_len,
                        process=self.cfg.pod_identifier)
         if len(req.output) >= req.max_new_tokens:
@@ -3107,6 +3126,158 @@ class MiniEngine:
             self._row_emitted(req, now)
             if self.hybrid:
                 self._swa_reclaim(req)
+        return out
+
+    # -- a model that drafts (``LlamaConfig.num_nextn_predict_layers``) --
+    # Its step programs are ``llama.DRAFTING_PROGRAMS`` and the three
+    # methods that build, launch and read a program have forms of their own
+    # below, bound over the others at construction: ``step()`` and the frames
+    # under every other model's programs stay what they are.
+
+    def _serve_drafting(self, use_pallas: bool, prefill_pallas: bool,
+                        interpret: bool, offload_spec) -> None:
+        """Construction's last part for a model with a prediction module:
+        what is not built beside it refuses by name, and the speculative
+        programs and step methods take the others' places."""
+        for unfit, why in (
+                (self.mesh is not None,
+                 "a mesh (the verify step is one program on one device)"),
+                (self.cfg.ragged_attention,
+                 "ragged_attention (the draft is verified by the padded "
+                 "decode program)"),
+                (offload_spec is not None,
+                 "an offload spec (a restored prefix moves the hit, and the "
+                 "module's row at its first new slot needs the hidden state "
+                 "of the position before it)")):
+            if unfit:
+                raise ValueError(
+                    "a model with a prediction module verifies its draft "
+                    "in every decode step and is not served with " + why)
+        self._step_tokens = 2
+        self._decode_forward = functools.partial(
+            DRAFTING_PROGRAMS[(use_pallas, False)], interpret=interpret)
+        self._prefill_forward = functools.partial(
+            DRAFTING_PROGRAMS[(prefill_pallas and use_pallas, True)],
+            interpret=interpret)
+        self._prefill_chunk = self._prefill_chunk_drafting
+        self._launch_decode = self._launch_decode_drafting
+        self._read_decode = self._read_decode_drafting
+        # A row of ``prev``: two tokens, how many count, the next draft.
+        self._prev = jax.device_put(
+            np.zeros((4 * self.cfg.max_batch
+                      + len(self.cfg.model.step_counters),), np.int32),
+            self._device)
+
+    def _prefill_chunk_drafting(self, req: Request) -> _Unread:
+        """``_prefill_chunk`` of a model that drafts: the chunk's program
+        also runs the module over it, handed the token after the chunk (-1
+        at the prompt's end: the one it samples), and returns the first
+        draft behind the sampled token."""
+        page_size = self.cfg.model.page_size
+        with phase(self._phases, PHASE_STEP_INPUTS):
+            chunk_cap = max(page_size, self.cfg.max_prefill_tokens
+                            // page_size * page_size)
+            pos = req.prefill_pos
+            chunk = req.prompt[pos:pos + chunk_cap]
+            end = pos + len(chunk)
+            seq = page_size  # a power of two of pages: see _prefill_chunk
+            while seq < len(chunk):
+                seq *= 2
+            tokens = np.zeros((1, seq), np.int32)
+            tokens[0, :len(chunk)] = chunk
+            last = end >= len(req.prompt)
+            packed, shapes = pack_inputs(
+                (tokens, self._page_table_for(req)[None, :], [pos],
+                 [len(chunk)], [-1 if last else req.prompt[end]]))
+        with self._dispatch_phase(req, 1, len(chunk), seq,
+                                  self._prefill_forward) as sp:
+            picked, row, pools = self._prefill_forward(
+                self.params, self.cfg.model, self._launch_input(packed, sp),
+                self._pools(), shapes=shapes)
+            self._take_pools(pools)
+            if last:
+                picked.copy_to_host_async()
+        req.computed_len = end
+        if self.telemetry is not None:
+            self.telemetry.on_dispatch_tokens(len(chunk), seq)
+        if last:
+            req.last_logits = row
+        req.prefill_pos = None if last else end
+        return _Unread(picked, [req], 2, len(chunk), self._launch, "prefill")
+
+    def _launch_decode_drafting(self, chunk: list[Request],
+                                unread: Optional[_Unread] = None) -> _Unread:
+        """``_launch_decode`` of a model that drafts: every row runs its
+        last token and its draft (two positions). A row of ``unread`` takes
+        its token, its draft AND how far its context has grown from that
+        program's result on the device: the host knows none of them yet."""
+        b = self.cfg.max_batch
+        with phase(self._phases, PHASE_STEP_INPUTS):
+            last, ctx, tables = self._decode_batch_arrays(chunk, rows=b)
+            new_lens = np.zeros((b,), np.int32)
+            new_lens[:len(chunk)] = 1
+            drafts = np.zeros((b,), np.int32)
+            drafts[:len(chunk)] = [req.draft for req in chunk]
+            ahead = unread is not None and unread.host is None
+            src = np.full((b,), -1, np.int32)
+            if unread is not None:
+                src[:len(chunk)] = [unread.row_of(req) for req in chunk]
+            packed, shapes = pack_inputs(
+                (last[:, None], tables, ctx, new_lens, drafts, src))
+        with self._dispatch_phase(None, len(chunk), 2 * len(chunk), b,
+                                  self._decode_forward) as sp:
+            picked, _, pools = self._decode_forward(
+                self.params, self.cfg.model,
+                self._launch_input(packed, sp, decode=True),
+                self._pools(), shapes=shapes, prev=self._prev)
+            self._take_pools(pools)
+            picked.copy_to_host_async()
+            self._prev = picked
+            sp.set_attribute("ahead", int(ahead))
+            sp.set_attribute("spec_drafted", len(chunk))
+        tel = self.telemetry
+        if tel is not None:
+            tel.on_dispatch_tokens(2 * len(chunk), 2 * b)
+            if ahead:
+                tel.on_launched_ahead()
+        return _Unread(picked, chunk, 4 * b, 2 * len(chunk), self._launch,
+                       "decode")
+
+    def _read_drafts(self, sp, rec: _Unread) -> None:
+        """What a drafting model's program says of drafts, as it is read.
+        A prefill chunk: the first draft, behind the token (the last
+        chunk's is the request's). A decode program, onto its
+        ``step.fetch``: the drafts it verified (one a row) and those it
+        accepted (rows that count two tokens), where that is first known."""
+        if rec.program == "prefill":
+            rec.rows[0].draft = int(rec.host[1])
+            return
+        b = rec.padded // 4
+        accepted = int((rec.host[2 * b:2 * b + len(rec.rows)] == 2).sum())
+        if sp is not NOOP_SPAN:
+            sp.set_attribute("spec_drafted", len(rec.rows))
+            sp.set_attribute("spec_accepted", accepted)
+        if self.telemetry is not None:
+            self.telemetry.on_drafts_verified(len(rec.rows), accepted)
+
+    def _read_decode_drafting(self, rec: _Unread) -> dict[str, int]:
+        """``_read_decode`` of a model that drafts: a row takes the one or
+        two tokens its program counted, never past ``max_new_tokens`` (a
+        second token beyond it is dropped), and the next draft."""
+        host = self._fetch(rec)
+        b = rec.padded // 4
+        out = {}
+        now = time.monotonic() if self.telemetry is not None else 0.0
+        for i, req in enumerate(rec.rows):
+            if req.done:
+                continue
+            took = [int(host[i]), int(host[b + i])][:min(
+                int(host[2 * b + i]), req.max_new_tokens - len(req.output))]
+            req.computed_len += len(took)
+            req.output.extend(took)
+            req.draft = int(host[3 * b + i])
+            out[req.request_id] = took[-1]
+            self._row_emitted(req, now, len(took))
         return out
 
     def _read_first(self, first: Optional[_Unread]) -> dict[str, int]:

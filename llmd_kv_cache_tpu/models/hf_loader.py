@@ -104,14 +104,13 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     # silently-wrong logits.
     supported = ("llama", "mistral", "mixtral", "qwen2", "qwen3",
                  "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32",
-                 "gigachat3_5", "solar_open2")
+                 "gigachat3_5", "solar_open2", "pangu_ultra_moe")
     if hf_cfg.model_type not in supported:
         raise NotImplementedError(
             f"model_type {hf_cfg.model_type!r} is not supported "
             f"(supported: {supported}); of the DeepSeek line what is still "
-            f"refused is a step of more than one token (the multi-token-"
-            f"prediction module is never built) and fp8 latent or index "
-            f"streams")
+            f"refused is fp8 latent or index streams, and the multi-token-"
+            f"prediction module is built for pangu_ultra_moe alone")
     act = getattr(hf_cfg, "hidden_act", "silu")
     if act not in ("silu", "swish"):
         raise NotImplementedError(
@@ -125,6 +124,8 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
                                      rope_scaling)
     if hf_cfg.model_type == "gigachat3_5":
         return _config_from_gigachat(hf_cfg, page_size, dtype, rope_scaling)
+    if hf_cfg.model_type == "pangu_ultra_moe":
+        return _config_from_pangu(hf_cfg, page_size, dtype, rope_scaling)
     if getattr(hf_cfg, "mlp_bias", False):
         raise NotImplementedError(
             "MLP biases are not implemented; a bias-free conversion "
@@ -274,9 +275,10 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
     ``embed_init_scale`` (no checkpoint has one) is what ``init_params``
     draws the embedding at: ``LlamaConfig.embed_init_scale``.
 
-    Not built: ``num_nextn_predict_layers`` (multi-token prediction is a
-    step that yields more than one token a sequence; the model's own
-    inference code serves without it), and ``params_from_hf`` maps no
+    ``num_nextn_predict_layers`` is not read for these model types: their
+    modules are left out, as the models' own inference code serves without
+    them (a step that verifies a module's draft is served for
+    ``pangu_ultra_moe``: ``_config_from_pangu``). ``params_from_hf`` maps no
     indexer tensor yet (random weights serve; a checkpoint refuses there).
     """
     if hf_cfg.v_head_dim != hf_cfg.qk_nope_head_dim:
@@ -334,7 +336,32 @@ def _config_from_deepseek(hf_cfg: Any, page_size: int, dtype: Any,
 
 # The model types whose routed layers are DeepSeek-V3's (sigmoid scores, a
 # correction bias, a shared expert) and whose yarn raises the softmax scale.
-_V3_ROUTED = ("deepseek_v3", "deepseek_v32", "gigachat3_5", "solar_open2")
+_V3_ROUTED = ("deepseek_v3", "deepseek_v32", "gigachat3_5", "solar_open2",
+              "pangu_ultra_moe")
+
+
+def _config_from_pangu(hf_cfg: Any, page_size: int, dtype: Any,
+                       rope_scaling: tuple = ()) -> LlamaConfig:
+    """openPangu-Ultra-MoE (``model_type: pangu_ultra_moe``): DeepSeek-V3's
+    latent attention and expert layers (``_config_from_deepseek`` reads
+    those keys, ``layer_share`` and the init scales included; the router is
+    one group, sigmoid scores, the chosen experts' weights normalised) in a
+    block with a norm before AND after each sub-layer (``sandwich_norm``),
+    plain RoPE, and the model's multi-token-prediction module SERVED:
+    ``num_nextn_predict_layers: 1`` makes ``init_params`` draw the module
+    and the engine verify its draft in every decode step
+    (``LlamaConfig.num_nextn_predict_layers``); more than one is refused
+    there. ``params_from_hf`` maps no tensor of the module yet (random
+    weights serve; a checkpoint refuses there)."""
+    if not getattr(hf_cfg, "sandwich_norm", False):
+        raise NotImplementedError(
+            "pangu_ultra_moe without sandwich_norm: the block built norms "
+            "every sub-layer's input and output")
+    return dataclasses.replace(
+        _config_from_deepseek(hf_cfg, page_size, dtype, rope_scaling),
+        post_norms=True,
+        num_nextn_predict_layers=int(getattr(
+            hf_cfg, "num_nextn_predict_layers", 0) or 0))
 
 # The one reading of each of GigaChat3.5's keys that name a form and do not
 # define it: what ``llama`` implements. Another value is another model.
@@ -496,6 +523,11 @@ def params_from_hf(state_dict: Mapping[str, Any], cfg: LlamaConfig,
     this repo's half-split rotary reproduces HF's interleaved one (see
     ``_deinterleave``).
     """
+    if cfg.num_nextn_predict_layers:
+        raise NotImplementedError(
+            "no checkpoint's tensors are mapped for a prediction module "
+            "(nor for the post-norms of its model): random weights serve "
+            "(llama.init_params)")
     if cfg.linear_layers:
         raise NotImplementedError(
             "no checkpoint's tensors are mapped for linear layers (nor for "

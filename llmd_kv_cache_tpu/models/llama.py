@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import inspect
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any
 
@@ -277,8 +277,29 @@ class LlamaConfig:
     # score gaps it competes with (at 0.02: +-25% around ``held /
     # experts``; at 0.002: the sampling noise of a chunk).
     router_bias_init_scale: float = 0.02
+    # Multi-token prediction (DeepSeek-V3's form): 1 = the model brings one
+    # prediction module, a block of its own kind behind the last layer that
+    # drafts the token after next from the main model's hidden state and
+    # the next token (``draft_logits`` below), and a decode step verifies
+    # the draft: two positions a row, one or two tokens out
+    # (``drafting_step_program``). The module's latents are one more layer
+    # of every page: layer 0 of the pool, the main layers behind it
+    # (``page_layers``). 0 = none, and nothing of this is in any program.
+    num_nextn_predict_layers: int = 0
 
     def __post_init__(self):
+        if self.num_nextn_predict_layers:
+            if self.num_nextn_predict_layers != 1:
+                raise NotImplementedError(
+                    "more than one prediction module (a step that drafts "
+                    "more than one position) is not built")
+            if not self.is_mla or self.linear_layers or self.is_dsa:
+                raise NotImplementedError(
+                    "a prediction module is served over latent attention "
+                    "alone (the decode kernel verifies two positions over "
+                    "one shared latent head): beside key/value pages, a "
+                    "state pool (states rolled back by row) or an indexer "
+                    "it is not built")
         if self.linear_layers:
             if self.linear is None:
                 raise ValueError("linear_layers need their sizes (linear)")
@@ -460,9 +481,11 @@ class LlamaConfig:
 
     @property
     def page_layers(self) -> tuple:
-        """The layers that keep pages, in order: a page pool's layer axis."""
-        return tuple(i for i in range(self.num_layers)
-                     if i not in self.linear_layers)
+        """The layers that keep pages, in order: a page pool's layer axis.
+        A prediction module's layer is ``-1`` and comes first: seen as a
+        model of one layer (``_module_view``) its latents are layer 0."""
+        return (-1,) * self.num_nextn_predict_layers + tuple(
+            i for i in range(self.num_layers) if i not in self.linear_layers)
 
     @property
     def is_dsa(self) -> bool:
@@ -582,7 +605,27 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
             cfg.layer_kind(i) == "linear")
         for i in range(cfg.num_layers)
     ]
-    return {"layers": layers, **_init_top_jit(keys[0], keys[1], cfg)}
+    return {"layers": layers, **_init_top_jit(keys[0], keys[1], cfg),
+            **_init_module(key, cfg)}
+
+
+def _init_module(key: jax.Array, cfg: "LlamaConfig") -> Params:
+    """``{"mtp": ...}``, the prediction module's own parameters, of a model
+    that brings one (else nothing): the projection ``w_eh`` of ``[embedding
+    ; hidden state]`` (each behind a norm of its own), one block of the
+    model's kind (an expert layer where the model has experts) and the norm
+    ahead of the head. The embedding and the head are the model's."""
+    if not cfg.num_nextn_predict_layers:
+        return {}
+    h = cfg.hidden_size
+    ks = jax.random.split(jax.random.fold_in(key, 1), 2)
+    return {"mtp": {
+        "enorm": _norm_init(ks[0], h, cfg),
+        "hnorm": _norm_init(ks[1], h, cfg),
+        "w_eh": _dense_init(ks[0], (2 * h, h), cfg.dtype),
+        "layer": _init_layer_jit(ks[1], cfg, cfg.num_experts > 0),
+        "final_norm": _norm_init(jax.random.fold_in(ks[1], 1), h, cfg),
+    }}
 
 
 def _dense_init(k, shape, dt, scale=0.02):
@@ -880,6 +923,10 @@ def fuse_params(params: Params, cfg: LlamaConfig) -> Params:
             fused_any = True
         fused_layers.append(lyr)
     out["layers"] = fused_layers
+    if "mtp" in params:
+        # The module's block, as a model of that one layer.
+        out["mtp"] = {**params["mtp"], "layer": fuse_params(
+            {"layers": [params["mtp"]["layer"]]}, cfg)["layers"][0]}
     if fused_any:
         # Record the interleave the tree was ACTUALLY fused with, so
         # unfuse_params can refuse a mismatched config instead of silently
@@ -930,6 +977,12 @@ def unfuse_params(params: Params, cfg: LlamaConfig) -> Params:
     corrupt with no error anywhere downstream)."""
     out = dict(params)
     marker = out.pop("fused_interleave", None)
+    if "mtp" in out:
+        # The module's block, as a model of that one layer under the same
+        # marker (a canonical block comes back as it is).
+        out["mtp"] = {**out["mtp"], "layer": unfuse_params(
+            {"layers": [out["mtp"]["layer"]], "fused_interleave": marker},
+            cfg)["layers"][0]}
     fused_keys = ("w_qkv", "b_qkv", "w_mla_in", "w_gate_up", "w_gate_up_sh")
     if not any(k in lyr for lyr in params["layers"] for k in fused_keys):
         return out  # already canonical
@@ -2416,10 +2469,10 @@ def _offer_per_head(attention_fn, cfg, seq, mesh, ctx_lens, interpret):
 
 def _per_head_prefill_attention(q_nope, q_rope, layer, k_stack, v_stack,
                                 layer_idx, table, positions, total_lens,
-                                index=None, *, cfg, ctx_lens, interpret):
+                                index=None, *, cfg, ctx_lens, interpret,
+                                bias=None):
     from ..ops.pallas_latent_prefill import pallas_per_head_prefill_attention
 
-    bias = None
     if index is not None and table.shape[1] * cfg.page_size > cfg.index_topk:
         # The chunk's selection, as the absorbed form's ``attention_fn``
         # makes it: v_stack is the index stream.
@@ -2438,3 +2491,315 @@ def _per_head_prefill_attention(q_nope, q_rope, layer, k_stack, v_stack,
         scale=((cfg.head_dim + cfg.qk_rope_head_dim) ** -0.5
                * cfg.softmax_scale_mult),
         layer_idx=layer_idx, bias=bias, interpret=interpret)
+
+
+# -- a model that drafts: its prediction module, and the steps that run it ---
+# A model with ``cfg.num_nextn_predict_layers`` is stepped by programs of
+# their own (``drafting_step_program``), under the names of the forwards they
+# stand for. Everything of them lives down here, in functions of their own:
+# the shared body above is called as it is, on views of the parameters, and
+# no frame that lies under another model's programs changes (see the section
+# above).
+#
+# Main position ``i`` (token ``t_i``, hidden state ``h_i`` after the final
+# norm) gives the module the row ``u_i = [norm_e(Emb(t_{i+1})) ; norm_h(h_i)]
+# W_eh``; the module's block attends ``u_0..u_i`` and its head (the model's
+# own, behind the module's norm) gives the logits of ``t_{i+2}``. The block's
+# latent of ``u_i`` is written at slot ``i + 1`` of the row's pages, the slot
+# of the last token it was computed from, and rotated as that position
+# (RoPE's scores depend on distances alone): every slot of a page, in all its
+# layers, is then a function of the tokens up to that slot, which is what a
+# block's hash covers, and a prefix hit reads the module's cache as it reads
+# the model's. Slot 0 holds nothing of the module and its layer never
+# attends it (``first_key``, a bias over a chunk's keys).
+
+SCOPE_DRAFT = "mtp_draft"
+
+
+class _HeadTap:
+    """Stands where the shared body looks for ``lm_head``: keeps what the
+    head is handed, the hidden states after the final norm ``[b, seq, h]``,
+    for the prediction module, and answers with the logits of position ``at
+    [b]`` of every row alone (None: of every position)."""
+
+    def __init__(self, head, at=None):
+        self.head, self.at, self.hidden = head, at, None
+
+    def __rmatmul__(self, x):
+        self.hidden = x
+        if self.at is not None:
+            x = jnp.take_along_axis(x, self.at[:, None, None], axis=1)
+        return x @ self.head
+
+
+def _module_view(params: Params, cfg: LlamaConfig, rows: jax.Array):
+    """The prediction module as the shared body runs it: a model of one
+    layer whose "embedding" is the module's input ``rows [n, h]`` (looked up
+    by row number), with the module's norm ahead of the model's own head.
+    Its one layer is layer 0 of the pool (``LlamaConfig.page_layers``)."""
+    module = params["mtp"]
+    view = {"layers": [module["layer"]], "embed": rows,
+            "final_norm": module["final_norm"], "lm_head": params["lm_head"]}
+    return view, replace(cfg, num_layers=1, num_nextn_predict_layers=0,
+                         moe_layers=())
+
+
+def _past_first(q, table, page_size):
+    """bool ``[b, seq, keys]``: every key of a row's pages but slot 0."""
+    keys = table.shape[1] * page_size
+    return jnp.broadcast_to(jnp.arange(keys) >= 1, (*q.shape[:2], keys))
+
+
+def _module_attention(cfg, backend, ctx_lens, new_lens, seq, interpret):
+    """The module's layer's ``attention_fn`` on ``backend`` (``"xla"``,
+    ``"decode"``: the Pallas decode kernel over the step's positions,
+    ``"prefill"``: the chunk's kernels), over slots 1 and up of the row's
+    pages. ``ctx_lens``: the slot the first of the ``seq`` rows is written
+    at."""
+    if backend == "decode":
+        return _positions_attention(cfg, ctx_lens, new_lens, seq, interpret,
+                                    first_key=1)
+    if backend == "xla":
+        def attention_fn(q, k_stack, v_stack, layer_idx, table, positions,
+                         total_lens, window):
+            return paged_attention(
+                q, k_stack, v_stack, table, positions, total_lens,
+                layer_idx=layer_idx,
+                keep=_past_first(q, table, cfg.page_size))
+        return attention_fn
+
+    from ..ops.pallas_paged_attention import pallas_paged_prefill_attention
+
+    def bias(q, table):
+        return jnp.where(_past_first(q, table, cfg.page_size), 0.0, -1e30)
+
+    def attention_fn(q, k_stack, v_stack, layer_idx, table, positions,
+                     total_lens, window):
+        return pallas_paged_prefill_attention(
+            q, k_stack, v_stack, table, ctx_lens, total_lens,
+            q_tile=_prefill_q_tile(cfg, seq), shared_kv=True,
+            layer_idx=layer_idx, bias=bias(q, table), interpret=interpret)
+
+    def per_head(q_nope, q_rope, layer, k_stack, v_stack, layer_idx, table,
+                 positions, total_lens):
+        return _per_head_prefill_attention(
+            q_nope, q_rope, layer, k_stack, v_stack, layer_idx, table,
+            positions, total_lens, cfg=cfg, ctx_lens=ctx_lens,
+            interpret=interpret, bias=bias(q_nope, table))
+
+    if prefill_per_head(cfg, seq):
+        attention_fn.per_head_fn = per_head
+    return attention_fn
+
+
+def _prefill_q_tile(cfg: LlamaConfig, seq: int) -> int:
+    """``forward_prefill_pallas``'s rule for a chunk's query tile (its own
+    lines stand there: that frame is under every model's programs)."""
+    group = cfg.num_heads // max(1, cfg.kv_cache_heads)
+    q_tile = math.gcd(seq, max(128, 1024 // max(1, group)))
+    if group * q_tile > 4096:
+        q_tile = math.gcd(seq, max(16, 2048 // group))
+    return q_tile
+
+
+def _positions_attention(cfg, ctx_lens, new_lens, seq, interpret,
+                         first_key=0):
+    """An ``attention_fn`` over the Pallas decode kernel for rows of ``seq``
+    positions each: position ``j`` of a live row attends the keys below
+    ``ctx_lens + j + 1`` (from ``first_key`` on), the row's latents streamed
+    once for all of them. A row with nothing new streams nothing."""
+    from ..ops.pallas_paged_attention import pallas_paged_decode_attention
+
+    lens = jnp.where(new_lens > 0, ctx_lens + seq, 0)
+
+    def attention_fn(q, k_stack, v_stack, layer_idx, table, positions,
+                     total_lens, window):
+        return pallas_paged_decode_attention(
+            q, k_stack, k_stack, table, lens, shared_kv=True,
+            shared_stream=cfg.mla_decode_stream, layer_idx=layer_idx,
+            first_key=first_key, interpret=interpret)
+    return attention_fn
+
+
+def draft_logits(params, cfg, hidden, next_tokens, k_cache, v_cache,
+                 page_table, ctx_lens, new_lens, backend, interpret=False,
+                 counters=None, last_only=True):
+    """The prediction module over ``seq`` main positions a row from
+    ``ctx_lens`` on (the first ``new_lens`` of them real): ``hidden [b, seq,
+    h]`` the main model's hidden states there (after its final norm),
+    ``next_tokens [b, seq]`` the token after each. Returns ``(float32 logits
+    of the token after next [b, 1, vocab] at each row's last real position
+    (``last_only``; else of every position), k_cache, v_cache)`` with the
+    module's latents written at slots ``ctx_lens + 1`` and up of layer 0."""
+    module = params["mtp"]
+    b, seq, h = hidden.shape
+    with jax.named_scope(SCOPE_DRAFT):
+        rows = jnp.concatenate(
+            [_rms_norm(params["embed"][next_tokens], module["enorm"],
+                       cfg.norm_eps, cfg.norm_offset),
+             _rms_norm(hidden, module["hnorm"], cfg.norm_eps,
+                       cfg.norm_offset)], axis=-1) @ module["w_eh"]
+        view, view_cfg = _module_view(params, cfg, rows.reshape(b * seq, h))
+        return _forward_impl(
+            view, view_cfg, jnp.arange(b * seq).reshape(b, seq), k_cache,
+            v_cache, page_table, ctx_lens + 1, new_lens,
+            _module_attention(cfg, backend, ctx_lens + 1, new_lens, seq,
+                              interpret),
+            last_only=last_only,
+            kernel=None if backend == "xla" else {"interpret": interpret},
+            counters=counters)
+
+
+def _main_forward(params, cfg, tokens, k_cache, v_cache, page_table,
+                  ctx_lens, new_lens, backend, interpret, counters, at=None):
+    """The main model over ``tokens [b, seq]`` on ``backend``: ``(float32
+    logits (of position ``at [b]`` alone, or of every position), hidden
+    states after the final norm [b, seq, h], k_cache, v_cache)``."""
+    tap = _HeadTap(params["lm_head"], at)
+    view = {**params, "lm_head": tap}
+    if backend == "xla":
+        out = forward.__wrapped__(
+            view, cfg, tokens, k_cache, v_cache, page_table, ctx_lens,
+            new_lens, counters=counters)
+    elif backend == "prefill":
+        out = forward_prefill_pallas.__wrapped__(
+            view, cfg, tokens, k_cache, v_cache, page_table, ctx_lens,
+            new_lens, interpret=interpret, counters=counters)
+    else:
+        out = _forward_impl(
+            view, cfg, tokens, k_cache, v_cache, page_table, ctx_lens,
+            new_lens, _positions_attention(
+                cfg, ctx_lens, new_lens, tokens.shape[1], interpret),
+            kernel={"interpret": interpret}, counters=counters)
+    logits, k_cache, v_cache = out
+    return logits, tap.hidden, k_cache, v_cache
+
+
+def _verify_and_draft(params, cfg, tokens, k_cache, v_cache, page_table,
+                      ctx_lens, new_lens, drafts, backend, interpret,
+                      counters):
+    """One speculative decode step. A live row (``new_lens`` 1) holds its
+    last accepted token ``tokens [b, 1]`` at position ``ctx_lens`` and the
+    module's draft of the next. The main model runs both positions against
+    the row's pages; its own choices ``g1, g2`` there are what the row
+    emits: both where the draft was ``g1`` (the second position then saw the
+    right token), else ``g1`` alone, and the draft's latents at position
+    ``ctx_lens + 1`` stay behind to be written over by the next step. The
+    module then runs over the position(s) emitted and leaves the next draft.
+    Returns ``(int32 [4, b]: g1, g2, how many of them count, the next draft;
+    k_cache, v_cache)``."""
+    logits, hidden, k_cache, v_cache = _main_forward(
+        params, cfg, jnp.concatenate([tokens, drafts[:, None]], axis=1),
+        k_cache, v_cache, page_table, ctx_lens, 2 * new_lens, backend,
+        interpret, counters)
+    chosen = greedy_tokens(logits).T                              # [2, b]
+    if backend == "xla":
+        count = (new_lens > 0) * (1 + (chosen[0] == drafts))
+    else:
+        # The kernels' programs: acceptance is the named op a trace tells
+        # the module's part of the step by (``ops.draft_accept``).
+        from ..ops.draft_accept import mtp_accept
+
+        chosen, count = mtp_accept(chosen, drafts, new_lens > 0,
+                                   interpret=interpret)
+    draft, k_cache, v_cache = draft_logits(
+        params, cfg, hidden, chosen.T, k_cache, v_cache, page_table,
+        ctx_lens, count, backend, interpret, counters)
+    return (jnp.concatenate([chosen, count[None],
+                             greedy_tokens(draft[:, 0])[None]]),
+            k_cache, v_cache)
+
+
+def _chunk_and_draft(params, cfg, tokens, k_cache, v_cache, page_table,
+                     ctx_lens, new_lens, following, backend, interpret,
+                     counters):
+    """One prefill chunk of a drafting model: the main model over the
+    chunk, sampling its last position, and the module over the same
+    positions, each with the token after it: the chunk's next, then
+    ``following [b]``, the prompt's token after the chunk, or where that is
+    -1 (the prompt ends here) the token just sampled, so that the module's
+    cache stands at the prompt's end and the first draft leaves with the
+    first token. Returns ``(int32 [2, b]: the token sampled, the draft of
+    the one after it; the sampled position's logits [b, vocab]; k_cache,
+    v_cache)``."""
+    last = jnp.maximum(new_lens - 1, 0)
+    logits, hidden, k_cache, v_cache = _main_forward(
+        params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens, new_lens,
+        backend, interpret, counters, at=last)
+    picked = greedy_tokens(logits[:, 0])
+    after = jnp.where(
+        jnp.arange(tokens.shape[1])[None, :] == last[:, None],
+        jnp.where(following >= 0, following, picked)[:, None],
+        jnp.roll(tokens, -1, axis=1))
+    draft, k_cache, v_cache = draft_logits(
+        params, cfg, hidden, after, k_cache, v_cache, page_table, ctx_lens,
+        new_lens, backend, interpret, counters)
+    return (jnp.stack([picked, greedy_tokens(draft[:, 0])]), logits[:, 0],
+            k_cache, v_cache)
+
+
+def drafting_step_program(name: str, backend: str, chunk: bool):
+    """The step form of a drafting model's forward: what ``step_program`` is
+    to the others, jitted under ``name`` (the forward it stands for: a trace
+    tells programs by it). Called ``(params, cfg, packed, pools, shapes=,
+    interpret=)`` with ``pools = (k_cache, v_cache)``, donated.
+
+    ``chunk``: a prefill chunk. ``packed`` holds ``(tokens [1, seq], the page
+    table, ctx_lens, new_lens, following)`` and the result is ``(int32 [2 +
+    counters]: the sampled token, the first draft, what the model counts;
+    the sampled position's logits [vocab]; pools)``
+    (``_chunk_and_draft``).
+
+    Else a decode step (``_verify_and_draft``). ``packed`` holds ``(tokens
+    [rows, 1], the page table, ctx_lens, new_lens, drafts, src)`` and the
+    result is ``(int32 [4 * rows + counters]: every row's first token, then
+    every row's second, how many count, the next drafts; None; pools)``.
+    ``prev`` is what the last program of this form returned, still on the
+    device: where ``src`` is not -1 a row takes from row ``src`` of it what
+    the host has not read yet: its last token (the second where two count),
+    its draft, and that many positions more of context."""
+    def program(params, cfg, packed, pools, shapes, prev=None,
+                interpret=False):
+        tokens, table, ctx_lens, new_lens, *rest = unpack_inputs(
+            packed, shapes)
+        counters = {} if cfg.step_counters else None
+        row = None
+        if chunk:
+            picked, row, *pools = _chunk_and_draft(
+                params, cfg, tokens, *pools, table, ctx_lens, new_lens,
+                rest[0], backend, interpret, counters)
+            row = row[0]
+        else:
+            drafts, src = rest
+            rows = src.shape[0]
+            at = jnp.maximum(src, 0)
+            two = prev[2 * rows + at] == 2
+            taken = src >= 0
+            tokens = jnp.where(
+                taken, jnp.where(two, prev[rows + at], prev[at]),
+                tokens[:, 0])[:, None]
+            drafts = jnp.where(taken, prev[3 * rows + at], drafts)
+            ctx_lens = ctx_lens + jnp.where(taken, prev[2 * rows + at], 0)
+            picked, *pools = _verify_and_draft(
+                params, cfg, tokens, *pools, table, ctx_lens, new_lens,
+                drafts, backend, interpret, counters)
+        picked = picked.reshape(-1)
+        if counters is not None:
+            picked = jnp.concatenate([picked, jnp.stack(
+                [jnp.asarray(counters.get(n, 0), jnp.int32)
+                 for n in cfg.step_counters])])
+        return picked, row, tuple(pools)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, static_argnames=("cfg", "shapes", "interpret"),
+                   donate_argnames=("pools",))
+
+
+# ``(pallas, chunk)`` -> the program: the XLA forward serves both phases of
+# an engine without the kernels, as ``step_forward`` does.
+DRAFTING_PROGRAMS = {
+    (False, True): drafting_step_program("forward", "xla", True),
+    (False, False): drafting_step_program("forward", "xla", False),
+    (True, True): drafting_step_program(PROGRAM_PREFILL, "prefill", True),
+    (True, False): drafting_step_program(PROGRAM_DECODE, "decode", False),
+}

@@ -225,12 +225,23 @@ def _decode_stream_bounds(ctx_len, q_end, page_size, sliding_window, sinks):
     return first_window, sink_pages, num_iters
 
 
-def _decode_mask(positions, ctx_len, sliding_window, sinks):
+def _decode_mask(positions, ctx_len, sliding_window, sinks, back=None,
+                 first_key=0):
     """Attendability of decode key ``positions``: in-bounds (< ctx_len),
     and inside the sliding window of the query at position ``ctx_len - 1``
     unless a sink position. Shared between the per-head and merged
-    decode kernels."""
+    decode kernels.
+
+    ``back`` ([query rows, 1], rows of more than one position): how many
+    positions before the row's last a query row stands, and so how many
+    keys fewer it sees; the mask is then ``[query rows, keys]``.
+    ``first_key``: the keys below it are nobody's (a prediction module's
+    layer holds nothing at slot 0)."""
+    if back is not None:
+        ctx_len = ctx_len - back
     in_bounds = positions < ctx_len
+    if first_key:
+        in_bounds = in_bounds & (positions >= first_key)
     if sliding_window is not None:
         in_window = positions >= ctx_len - sliding_window
         if sinks:
@@ -264,7 +275,8 @@ class _LiveGranules(NamedTuple):
 
 def _live_granules(page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch,
                    sem, *, ctx_len, page_size, kpb, sliding_window,
-                   sinks, shared_kv, shared_copy, layer_idx, row=None):
+                   sinks, shared_kv, shared_copy, layer_idx, row=None,
+                   back=None, first_key=0):
     """One decode row's stream over keys [0, ctx_len), cut to the keys it
     has. The superblock stays the DMA batch (``kpb`` pages a round, double
     buffered); inside it a *granule* of ``_granule_pages`` pages is the
@@ -343,7 +355,7 @@ def _live_granules(page_table_ref, b, h, k_hbm, v_hbm, k_scratch, v_scratch,
         first, n = (0, kpb) if g is None else (g * pages, pages)
         return _decode_mask(
             positions(sb, ctx_len, page_size, first=first, pages=n),
-            ctx_len, sliding_window, sinks)
+            ctx_len, sliding_window, sinks, back, first_key)
 
     def fold_round(slot, sb, state, fold):
         n = live(sb)
@@ -432,12 +444,21 @@ def _decode_kernel(
     shared_kv: bool,
     shared_copy: bool,
     stacked: bool,
+    q_positions: int = 1,
+    first_key: int = 0,
 ):
     b = pl.program_id(0)
     h = pl.program_id(1)
     group, head_dim = q_ref.shape[2], q_ref.shape[3]
 
     ctx_len = ctx_lens_ref[b]
+    back = None
+    if q_positions > 1:
+        # The block's rows are the row's positions, each with its heads
+        # (``group`` is both): position j sees q_positions - 1 - j keys
+        # fewer than the last.
+        back = (q_positions - 1) - jax.lax.broadcasted_iota(
+            jnp.int32, (group, 1), 0) // (group // q_positions)
     # SWA: pages entirely outside the window are skipped, so long contexts
     # stream only ~window/page_size pages. Attention sinks (StreamingLLM,
     # reference events.go:40 sink_full_attention) additionally stream the
@@ -456,7 +477,8 @@ def _decode_kernel(
         ctx_len=ctx_len, page_size=page_size,
         kpb=pages_per_block, sliding_window=sliding_window, sinks=sinks,
         shared_kv=shared_kv, shared_copy=shared_copy,
-        layer_idx=layer_ref[0] if stacked else None)
+        layer_idx=layer_ref[0] if stacked else None, back=back,
+        first_key=first_key)
 
     st.start(0, 0)
     q = q_ref[0, 0]  # [group, head_dim], cache dtype (see ``_fold``)
@@ -1278,7 +1300,7 @@ def pallas_paged_prefill_attention(
                    static_argnames=("interpret", "sliding_window", "sinks",
                                     "pages_per_block", "shared_kv",
                                     "shared_stream", "merge_heads",
-                                    "batch_rows"))
+                                    "batch_rows", "first_key"))
 def pallas_paged_decode_attention(
     q: jax.Array,  # [batch, q_heads, head_dim]
     k_cache: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
@@ -1294,9 +1316,20 @@ def pallas_paged_decode_attention(
     merge_heads: bool | None = None,
     layer_idx: jax.Array | int | None = None,
     batch_rows: int = 1,
+    first_key: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
     """Flash-decode over paged KV. Returns ``[batch, q_heads, head_dim]``.
+
+    ``q`` may be ``[batch, positions, q_heads, head_dim]`` (a step that
+    verifies a draft: the row's last ``positions`` tokens at once; the
+    result has that shape too). ``ctx_lens`` then counts the keys of the
+    row's LAST position and position ``j`` attends ``positions - 1 - j``
+    fewer: the positions fold in beside the heads (``positions * group``
+    query rows a kv head) under ``_decode_mask``'s ``back``, so the row's
+    pages are streamed once for all of them. The per-head grid only (one
+    kv head: a latent pool, where the fold is a plain reshape).
+    ``first_key``: keys below it are attended by nobody.
 
     The page size is the cache's native page dimension — the DMA tiles and
     mask arithmetic are derived from it, so no override is offered.
@@ -1327,6 +1360,10 @@ def pallas_paged_decode_attention(
     shrinks accordingly; the batch is zero-padded to a multiple (padded
     rows stream nothing and their outputs are sliced off).
     """
+    q_positions = 1
+    if q.ndim == 4:
+        q_positions = q.shape[1]
+        q = q.reshape(q.shape[0], -1, q.shape[-1])
     batch, q_heads, head_dim = q.shape
     # layer_idx: see the prefill wrapper — stacked caches, in-kernel
     # layer indexing, no per-layer slice copy at the custom-call boundary.
@@ -1338,6 +1375,10 @@ def pallas_paged_decode_attention(
     _check_head_dim_alignment(head_dim, interpret)
     if merge_heads is None:
         merge_heads = kv_heads > 1
+    if (q_positions > 1 or first_key) and (merge_heads or kv_heads > 1):
+        raise NotImplementedError(
+            "rows of more than one position (and first_key) are built for "
+            "the per-head grid over one kv head: a latent pool")
     if shared_stream not in ("copy", "reuse"):
         raise ValueError(
             f"shared_stream must be 'copy' or 'reuse', got {shared_stream!r}")
@@ -1465,7 +1506,8 @@ def pallas_paged_decode_attention(
             sliding_window=sliding_window, sinks=int(sinks or 0),
             pages_per_block=pages_per_block, shared_kv=shared_kv,
             shared_copy=shared_kv and shared_stream == "copy",
-            stacked=layer_idx is not None,
+            stacked=layer_idx is not None, q_positions=q_positions,
+            first_key=first_key,
         )
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -1508,6 +1550,8 @@ def pallas_paged_decode_attention(
     )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       _layer_operand(layer_idx), q_blocked, k_cache, v_cache)
 
+    if q_positions > 1:
+        return out.reshape(batch, q_positions, -1, head_dim)[:out_batch]
     return out.reshape(batch, q_heads, head_dim)[:out_batch]
 
 

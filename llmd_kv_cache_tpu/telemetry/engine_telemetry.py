@@ -262,6 +262,10 @@ class EngineTelemetry:
         # waited for early, by what asked (on_lookahead_drained).
         self._launched_ahead = 0
         self._drained: Dict[str, int] = {}
+        # Drafts a model's prediction module had verified, and those the
+        # main model agreed with (on_drafts_verified).
+        self.spec_drafted = 0
+        self.spec_accepted = 0
 
     # -- lifecycle hooks (called by MiniEngine) ---------------------------
 
@@ -398,6 +402,12 @@ class EngineTelemetry:
         it were still unread (``MiniEngine.step``)."""
         self._launched_ahead += 1
 
+    def on_drafts_verified(self, drafted: int, accepted: int) -> None:
+        """A speculative decode program read: ``drafted`` rows each had
+        one draft verified, ``accepted`` of them emitted two tokens."""
+        self.spec_drafted += drafted
+        self.spec_accepted += accepted
+
     def on_lookahead_drained(self, cause: str) -> None:
         """A decode program in flight waited for before its step: an
         abort, a reset, a copier that takes the pools (``cause``)."""
@@ -440,6 +450,10 @@ class EngineTelemetry:
             "lookahead": {
                 "launched_ahead": self._launched_ahead,
                 "drained": dict(self._drained),
+            },
+            "speculation": {
+                "spec_drafted": self.spec_drafted,
+                "spec_accepted": self.spec_accepted,
             },
             "last_profile": self.profiler.last,
         }

@@ -90,12 +90,42 @@ PROGRAMS = {
     "forward_decode_pallas": Program(
         llama.forward_decode_pallas, _GQA, _padded(_decode), {}, True,
         llama.step_decode_pallas),
+    "forward_decode_pallas_mla": Program(
+        llama.forward_decode_pallas, _MLA, _padded(_decode), {}, True,
+        llama.step_decode_pallas),
     "forward_prefill_pallas": Program(
         llama.forward_prefill_pallas, _GQA, _padded(_chunk), {}, True,
         llama.step_prefill_pallas, True),
     "forward_ragged": Program(llama.forward_ragged, _GQA, _ragged, {}, True,
                               llama.step_ragged),
 }
+
+
+# A model that drafts (``LlamaConfig.num_nextn_predict_layers``) is stepped
+# by programs of its own, ``llama.DRAFTING_PROGRAMS``: (kernels, chunk) ->
+# the speculative form of a forward. Here with the module's latents as one
+# more layer of the pool ([11, 1, 4, 24 + 8 lanes of pad]).
+DRAFT_CFG = llama.LlamaConfig(
+    vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+    num_kv_heads=4, head_dim=16, intermediate_size=128, page_size=4,
+    kv_lora_rank=16, qk_rope_head_dim=8, latent_pad=8, q_lora_rank=24,
+    post_norms=True, num_nextn_predict_layers=1)
+DRAFTING = {("speculative_" + llama.DRAFTING_PROGRAMS[key].__name__
+             + ("_chunk" if key[1] else "_decode")): key
+            for key in llama.DRAFTING_PROGRAMS}
+
+
+def drafting_inputs(chunk: bool, step: int = 0):
+    """``(packed, shapes)`` of one speculative program's per-step arrays: a
+    prefill chunk of row 0 (the token after it: 9, then the prompt's end),
+    or two decode rows, the second of padding at ``step`` 1."""
+    if chunk:
+        tokens, ctx, new = _chunk(step)
+        return llama.pack_inputs((tokens[:1], TABLE[:1], ctx[:1], new[:1],
+                                  _i32(9 if step == 0 else -1)))
+    tokens, ctx, new = _decode(step)
+    return llama.pack_inputs((tokens, TABLE, ctx, new, _i32(5, 3),
+                              _i32(-1, -1)))
 
 
 def step_inputs(args, pools):
@@ -115,3 +145,32 @@ def init_pools(cfg, dtype=None):
     keys = jax.random.split(jax.random.PRNGKey(7), len(pools))
     return tuple(jax.random.normal(k, p.shape, jnp.float32).astype(p.dtype)
                  for k, p in zip(keys, pools))
+
+
+def step_form_jaxpr(name: str, prev: bool = False) -> str:
+    """The step form of ``PROGRAMS[name]`` as the engine dispatches it, as
+    its jaxpr's text (kernels included, as the equations of their bodies),
+    without what differs between two checkouts of the same program: source
+    positions and objects' addresses. ``prev``: a padded decode form as
+    ``MiniEngine._launch_decode`` calls it, taking the last program's
+    tokens on the device. What a model's programs ARE: a PR that means to
+    leave them alone leaves this text alone
+    (``test_model.py::TestStepFormsStayWhatTheyWere``)."""
+    import re
+
+    prog = PROGRAMS[name]
+    params = llama.init_params(jax.random.PRNGKey(0), prog.cfg)
+    pools = init_pools(prog.cfg)
+    arrays = list(step_inputs(prog.args(params, prog.cfg, pools, 0), pools))
+    static = dict(prog.static)
+    if prog.chunk:
+        static.update(last_only=True, keep_row=True)
+    if prev:
+        rows = arrays[0].shape[0]
+        arrays.append(np.full((rows,), -1, np.int32))
+        static["prev"] = jnp.zeros((rows,), jnp.int32)
+    packed, shapes = llama.pack_inputs(arrays)
+    text = str(prog.step.trace(
+        params, prog.cfg, packed, pools, shapes=shapes, **static).jaxpr)
+    text = re.sub(r" at (0x[0-9a-f]+|[^\s:]+:\d+)", "", text)
+    return re.sub(r"\S*/[\w/.-]+\.py(:\d+)*", "", text)

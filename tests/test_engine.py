@@ -557,8 +557,9 @@ class TestUnpipelinedDecodePadding:
 def _served_model(kind):
     """``(cfg, params, engine options)`` of a toy model of each kind that
     steps through the padded path: dense (XLA and the Pallas kernels), a
-    routed one whose programs count on the device behind their tokens, and
-    one that keeps a sequence state in a pool beside its pages."""
+    routed one whose programs count on the device behind their tokens, one
+    that keeps a sequence state in a pool beside its pages, and one whose
+    prediction module drafts a token that every decode step verifies."""
     if kind in ("dense", "dense-pallas"):
         pallas = True if kind == "dense-pallas" else None
         return LlamaConfig.tiny(), None, dict(
@@ -567,7 +568,8 @@ def _served_model(kind):
 
     conf = names.config_for_run(names.benchmark(), {
         "counters": "deepseek-v3.2-exp-ep16-l5",
-        "state": "gigachat3.5-ep16-l5"}[kind], rehearse=True)
+        "state": "gigachat3.5-ep16-l5",
+        "drafting": "openpangu-ultra-ep32-l5"}[kind], rehearse=True)
     cfg, params = fleet.build_model(conf, 11)
     return cfg, params, {}
 
@@ -649,8 +651,9 @@ class TestLookAhead:
 
     def _serve(self, eng, cfg, other=None, after=None, plan=PLAN):
         """Step ``eng`` through ``plan``; ``other`` launches a program
-        before each of its steps. Every call returns at most one token a
-        request, and exactly the tokens that reached ``req.output``."""
+        before each of its steps. Every call returns a request's newest
+        token, and ``req.output`` grew by one token (or, where the model
+        drafts and the draft was right, two) exactly where it did."""
         rng = np.random.default_rng(5)
         prompts = {rid: rng.integers(1, cfg.vocab_size, n).tolist()
                    for _, rid, n, _ in plan}
@@ -668,9 +671,13 @@ class TestLookAhead:
             grew = {rid: len(req.output) for rid, req in reqs.items()}
             emitted = eng.step()
             for rid, req in reqs.items():
-                assert len(req.output) - grew[rid] == (rid in emitted)
+                grown = len(req.output) - grew[rid]
+                assert (grown > 0) == (rid in emitted)
+                assert grown <= eng._step_tokens
             for rid, token in emitted.items():
-                streams.setdefault(rid, []).append(token)
+                assert token == reqs[rid].output[-1]
+                streams.setdefault(rid, []).extend(
+                    reqs[rid].output[grew[rid]:])
             if after is not None:
                 after(step, reqs)
             step += 1
@@ -680,7 +687,7 @@ class TestLookAhead:
         return {rid: list(req.output) for rid, req in reqs.items()}
 
     @pytest.mark.parametrize(
-        "kind", ["dense", "dense-pallas", "counters", "state"])
+        "kind", ["dense", "dense-pallas", "counters", "state", "drafting"])
     def test_ahead_gives_the_synchronous_order_token_for_token(
             self, kind, monkeypatch):
         eng, other, seen, cfg = self._pair(kind, monkeypatch)

@@ -405,6 +405,76 @@ class TestNoLayerOfAPoolMoves:
         assert ("stablehlo.custom_call" in seen) == prog.pallas
 
 
+    @pytest.mark.parametrize("name", list(sp.DRAFTING))
+    def test_speculative_program_touches_rows_not_layers(self, name):
+        """The programs of a model that drafts: the main model's two
+        positions and the module's rows go into the stack in place too, the
+        module's into layer 0."""
+        from llmd_kv_cache_tpu.models import llama
+
+        pallas, chunk = sp.DRAFTING[name]
+        cfg = sp.DRAFT_CFG
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        pools = sp.init_pools(cfg)
+        packed, shapes = sp.drafting_inputs(chunk)
+        extra = {} if chunk else {"prev": jnp.zeros((8,), jnp.int32)}
+        module = llama.DRAFTING_PROGRAMS[pallas, chunk].trace(
+            params, cfg, packed, pools, shapes=shapes, **extra,
+        ).lower(lowering_platforms=("tpu",)).compiler_ir()
+        stack = tuple(pools[0].shape)
+        layers = {stack[1:], (1,) + stack[1:]}
+        seen = set()
+        for op in self._ops(module):
+            seen.add(op.name)
+            if op.name in ("stablehlo.slice", "stablehlo.dynamic_slice",
+                           "stablehlo.gather"):
+                moved = tuple(op.results[0].type.shape)
+            elif op.name == "stablehlo.dynamic_update_slice":
+                moved = tuple(op.operands[1].type.shape)
+            elif op.name == "stablehlo.scatter":
+                moved = tuple(op.operands[0].type.shape)
+            else:
+                continue
+            assert moved not in layers, (op.name, moved)
+        assert "stablehlo.scatter" in seen
+        assert ("stablehlo.custom_call" in seen) == pallas
+
+
+class TestStepFormsStayWhatTheyWere:
+    """A model without a prediction module is stepped by the programs it
+    was stepped by before there were modules (PR 53): each step form's
+    jaxpr, kernels' bodies included, hashes to what it hashed to at that
+    PR's parent commit (``step_programs.step_form_jaxpr``: source positions
+    and addresses taken out). A PR that changes a program on purpose
+    writes the new hash here and says so; `python3 -c "import
+    step_programs as sp, hashlib; ..."` over ``step_form_jaxpr``
+    regenerates them."""
+
+    PARENT = {
+        ("forward", False): "41566c4569e93a4f",
+        ("forward_mla", False): "ab42d57e6b5a77cd",
+        ("forward_decode", False): "d8621efbf1d3aee8",
+        ("forward_decode", True): "047d47611c6b4807",
+        ("forward_hybrid", False): "6df7bf4b47aca6e5",
+        ("forward_hybrid_decode", False): "859fbe2470c85f19",
+        ("forward_hybrid_decode", True): "72c563787de2291b",
+        ("forward_decode_pallas", False): "4ff07f204f260696",
+        ("forward_decode_pallas", True): "940756cc4bc86688",
+        ("forward_decode_pallas_mla", False): "37704127a2f4d79e",
+        ("forward_decode_pallas_mla", True): "0b799f6505384c83",
+        ("forward_prefill_pallas", False): "8f0e29ebbef8a369",
+        ("forward_ragged", False): "0b3bb8d2206a457e",
+    }
+
+    @pytest.mark.parametrize("name, prev", list(PARENT))
+    def test_a_program_is_the_parents(self, name, prev):
+        import hashlib
+
+        text = sp.step_form_jaxpr(name, prev)
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+            self.PARENT[name, prev])
+
+
 def _benchmark_configs():
     """name -> file of every configuration ``BENCHMARK.json`` runs."""
     root = Path(__file__).resolve().parents[1]

@@ -132,6 +132,28 @@ def test_latent_decode(shaped, gathered):
              shaped((ROWS, pages), jnp.int32), shaped((ROWS,), jnp.int32))
 
 
+@pytest.mark.parametrize("first_key", [0, 1], ids=["main", "module"])
+def test_latent_verify_decode(shaped, first_key):
+    """The decode kernel verifying a draft (``openpangu-ultra-ep32-l5``): a
+    row's two positions fold in beside the 128 heads, 256 query rows over
+    the one latent head, in a pool of six layers; the prediction module's
+    layer attends from slot 1 on."""
+    pool = (LAYERS + 1, 3000, 1, PAGE, 640)
+    compiles(functools.partial(pallas_paged_decode_attention, shared_kv=True,
+                               layer_idx=2, first_key=first_key),
+             shaped((ROWS, 2, 128, 640)), shaped(pool), shaped(pool),
+             shaped((ROWS, ROW_PAGES), jnp.int32), shaped((ROWS,), jnp.int32))
+
+
+def test_draft_acceptance(shaped):
+    """The acceptance kernel between the main model's part of a
+    speculative step and the module's: a few scalars in SMEM."""
+    from llmd_kv_cache_tpu.ops.draft_accept import mtp_accept
+
+    compiles(mtp_accept, shaped((2, ROWS), jnp.int32),
+             shaped((ROWS,), jnp.int32), shaped((ROWS,), jnp.int32))
+
+
 @pytest.mark.parametrize("m,k,n", [(4096, 7168, 2048), (128, 2048, 7168)],
                          ids=["up-of-a-chunk", "down-of-a-step"])
 def test_grouped_matmul(shaped, m, k, n):
