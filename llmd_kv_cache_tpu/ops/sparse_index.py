@@ -25,9 +25,16 @@ XLA and the Pallas step programs:
   the Pallas prefill program: a tile of queries reads its live scores once
   and bisects on the copy in VMEM, where ``kth_largest`` passes 32 times
   over the whole padded width in HBM.
-- ``select_topk``: the positions themselves, exact (``jax.lax.top_k``;
-  ``approx_max_k`` would be another model), for a decode row, which then
-  gathers the selected latents and attends them alone.
+- ``select_topk``: the positions themselves, for a decode row, which then
+  gathers the selected latents and attends them alone. Exact, and by
+  counting as well (``approx_max_k`` would be another model, and a sort of
+  every row's 33,792 slots was a seventh of a decode step): one kernel,
+  ``topk_by_count``, bisects a row's threshold on a copy of its live scores
+  in VMEM, breaks ties at the threshold by position as ``jax.lax.top_k``
+  does, and finds every slot's key from prefix sums of what is kept, all
+  of it 0/1 products on the MXU. Positions come out ascending. A
+  row of at most ``topk`` keys, every padded row among them, costs
+  nothing.
 """
 
 from __future__ import annotations
@@ -43,10 +50,12 @@ from jax.experimental.pallas import tpu as pltpu
 # ``pallas_paged_prefill_attention(bias=)`` reads).
 DROPPED = -1e30
 
-# The scoring kernel's name as a device trace has it (its jitted wrapper's
-# ``__name__``, as ``ops.pallas_paged_attention`` names its kernels).
+# The kernels' names as a device trace has them (their jitted wrappers'
+# ``__name__``, as ``ops.pallas_paged_attention`` names its kernels); the
+# benchmark's ``dsa_select_share`` counts the ops whose names start ``topk``.
 KERNEL_INDEX = "dsa_index_scores"
 KERNEL_KEEP = "dsa_keep_bias"
+KERNEL_SELECT = "topk_by_count"
 
 
 def gather_index_keys(idx_stack: jax.Array, layer_idx, page_table: jax.Array
@@ -187,6 +196,11 @@ def keep_mask(scores: jax.Array, q_positions: jax.Array,
 _MASKED = (0xFF800000 ^ 0x7FFFFFFF) - 2 ** 32
 
 
+def _ordered_int32(x: jax.Array) -> jax.Array:
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
 def _keep_tiles(q_seq: int, n_keys: int) -> tuple[int, int]:
     """``dsa_keep_bias``'s tile: (queries a program, keys a block)."""
     return _tile(q_seq, 16), _tile(n_keys, 1024)
@@ -233,9 +247,8 @@ def _keep_kernel(qpos_ref, lens_ref, s_hbm, o_ref, landed, buf, sem, *, tq,
     @pl.loop(0, counted)
     def _(j):
         copy(j).wait()
-        bits = jax.lax.bitcast_convert_type(landed[j], jnp.int32)
-        ordered = bits ^ ((bits >> 31) & 0x7FFFFFFF)
-        buf[j] = jnp.where(candidates(j), ordered, _MASKED)
+        buf[j] = jnp.where(candidates(j), _ordered_int32(landed[j]),
+                           _MASKED)
 
     def step(i, thr):
         # ``kth_largest``'s step on signed keys: or-ing a bit into the
@@ -327,19 +340,235 @@ def threshold_keys(ctx_len: int, new_len: int, q_seq: int, n_keys: int,
     return counted
 
 
+# A block of a decode row's scores: one float32 vreg, 8 chunks of 128 keys.
+_LANES = 128
+_BLOCK = 8 * _LANES
+
+
+def _select_kernel(lens_ref, s_hbm, o_ref, landed, buf, before, upto,
+                   in_chunk, sem, *, topk):
+    # lens_ref [rows] (SMEM); s_hbm the scores [rows, chunks, 128], left in
+    # HBM: key p of a row lies at [p // 128, p % 128]; o_ref [1, tiles,
+    # 128]: slot s of the row's selection at [s // 128, s % 128]; landed
+    # and buf [chunks rounded up to 128, 128]: the row's live blocks as
+    # they arrive and as ordered int32; before, upto [chunks, 128] and
+    # in_chunk [chunks // 128, 128, 128]: the prefix sums of what is kept.
+    b = pl.program_id(0)
+    total = lens_ref[b]
+    tiles = o_ref.shape[1]
+    chunks = landed.shape[0]
+    f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
+
+    def iota(shape, dim):
+        return jax.lax.broadcasted_iota(i32, shape, dim)
+
+    @pl.when(total <= topk)
+    def _():
+        # The row keeps every key it has: nothing is copied or counted.
+        o_ref[0] = iota((tiles, _LANES), 0) * _LANES + iota(
+            (tiles, _LANES), 1)
+
+    @pl.when(total > topk)
+    def _():
+        live = (total + _BLOCK - 1) // _BLOCK
+
+        def block(j):
+            return pl.ds(pl.multiple_of(j * 8, 8), 8)
+
+        def copy(j):
+            return pltpu.make_async_copy(
+                s_hbm.at[b, block(j), :], landed.at[block(j), :], sem.at[0])
+
+        def positions(j):
+            return j * _BLOCK + iota((8, _LANES), 0) * _LANES + iota(
+                (8, _LANES), 1)
+
+        @pl.loop(0, live)
+        def _(j):
+            copy(j).start()
+
+        @pl.loop(0, live)
+        def _(j):
+            copy(j).wait()
+            # Past the row's length: below -inf's, which a score may be.
+            buf[block(j), :] = jnp.where(
+                positions(j) < total, _ordered_int32(landed[block(j), :]),
+                jnp.iinfo(i32).min)
+
+        def count(holds):
+            """How many keys of the live blocks ``holds(block, its
+            positions)`` is true of, as ``[1, 1]``."""
+            def one(j, acc):
+                return acc + holds(buf[block(j), :], positions(j)
+                                   ).astype(i32)
+
+            acc = jax.lax.fori_loop(0, live, one,
+                                    jnp.zeros((8, _LANES), i32))
+            return jnp.sum(jnp.sum(acc, axis=0, keepdims=True), axis=1,
+                           keepdims=True)
+
+        def score_bit(i, thr):
+            # ``kth_largest``'s step on signed keys (``_keep_kernel``).
+            trial = thr ^ (jnp.int32(1) << (31 - i))
+            return jnp.where(count(lambda x, _p: x >= trial) >= topk,
+                             trial, thr)
+
+        thr = jax.lax.fori_loop(
+            0, 32, score_bit, jnp.full((1, 1), jnp.iinfo(i32).min, i32))
+        # Of the keys that score exactly the threshold the lowest
+        # positions are kept, as many as the scores above it leave room
+        # for: those before ``cut``, the largest position that has no more
+        # than that many of them before it (every one, bits set, where
+        # there are no more).
+        room = topk - count(lambda x, _p: x > thr)
+        ties = count(lambda x, _p: x == thr)
+
+        def position_bit(i, cut):
+            trial = cut | (jnp.int32(1) << (bits_of_a_position - 1 - i))
+            fit = count(lambda x, p: (x == thr) & (p < trial)) <= room
+            return jnp.where(fit, trial, cut)
+
+        bits_of_a_position = (chunks * _LANES).bit_length()
+        cut = jax.lax.fori_loop(
+            0, jnp.where(jnp.sum(ties - room) > 0, bits_of_a_position, 0),
+            position_bit, jnp.zeros((1, 1), i32))
+        cut = jnp.where(ties > room, cut, jnp.iinfo(i32).max)
+
+        # What is kept before each chunk of 128 keys and up to its end, and
+        # up to each lane within it, as 0/1 triangles on the MXU (a chunk
+        # keeps 128 at most and a row ``topk``: bfloat16 and the float32
+        # sums hold them exactly).
+        at = iota((chunks, _LANES), 0) * _LANES + iota((chunks, _LANES), 1)
+        scores = buf[...]
+        kept = ((at < total) & ((scores > thr) | ((scores == thr)
+                                                 & (at < cut)))).astype(bf16)
+        a_chunk = jnp.dot(kept, jnp.ones((_LANES, _LANES), bf16),
+                          preferred_element_type=f32)  # every lane: its sum
+        earlier = iota((chunks, chunks), 1) < iota((chunks, chunks), 0)
+        before[...] = jnp.dot(earlier.astype(bf16), a_chunk.astype(bf16),
+                              preferred_element_type=f32)
+        upto[...] = before[...] + a_chunk
+        to_lane = iota((_LANES, _LANES), 1) <= iota((_LANES, _LANES), 0)
+        for g in range(chunks // _LANES):  # [lane, chunk of the group]
+            in_chunk[g] = jax.lax.dot_general(
+                to_lane.astype(bf16), kept[g * _LANES:(g + 1) * _LANES],
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=f32).astype(bf16)
+
+        # A tile of 128 slots is filled from the chunks whose slots reach
+        # into it, [first, last): lane t of both rows below is tile t's.
+        edge = (iota((1, _LANES), 1) * _LANES).astype(f32)
+        first = jnp.sum((upto[...] <= edge).astype(i32), axis=0,
+                        keepdims=True)
+        last = jnp.sum((before[...] < edge + _LANES).astype(i32), axis=0,
+                       keepdims=True)
+        lane = iota((1, _LANES), 1)
+
+        @pl.loop(0, tiles)
+        def _(t):
+            # Slot s is the key that has s kept before it: in the chunk
+            # that owns s, at the lane where the count up to it passes s.
+            # A group of 128 chunks at a time: which chunk owns each slot
+            # of the tile is a 0/1 matrix, and its product with the
+            # group's counts brings every slot its chunk's.
+            slot = (t * _LANES + lane).astype(f32)
+
+            def group(g, found):
+                chunk, kept_before, counts = found
+                rows = pl.ds(pl.multiple_of(g * _LANES, _LANES), _LANES)
+                ahead = before[rows, :]
+                owns = (ahead <= slot) & (slot < upto[rows, :])
+                index = (g * _LANES + iota((_LANES, _LANES), 0)).astype(f32)
+                return (
+                    chunk + jnp.sum(jnp.where(owns, index, 0.0), axis=0,
+                                    keepdims=True),
+                    kept_before + jnp.sum(jnp.where(owns, ahead, 0.0),
+                                          axis=0, keepdims=True),
+                    counts + jnp.dot(in_chunk[g], owns.astype(bf16),
+                                     preferred_element_type=f32))
+
+            chunk, kept_before, counts = jax.lax.fori_loop(
+                jnp.sum(jnp.where(lane == t, first, 0)) // _LANES,
+                (jnp.sum(jnp.where(lane == t, last, 0)) + _LANES - 1)
+                // _LANES, group,
+                (jnp.zeros((1, _LANES), f32), jnp.zeros((1, _LANES), f32),
+                 jnp.zeros((_LANES, _LANES), f32)))
+            in_lane = jnp.sum((counts + kept_before <= slot).astype(f32),
+                              axis=0, keepdims=True)
+            o_ref[0, pl.ds(t, 1), :] = (chunk * _LANES + in_lane).astype(i32)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "interpret"))
+def topk_by_count(scores: jax.Array, total_lens: jax.Array, *, topk: int,
+                  interpret: bool = False) -> jax.Array:
+    """The positions of each row's ``topk`` best of its first
+    ``total_lens`` float32 ``scores [rows, keys]``, ascending, as int32
+    ``[rows, topk]``: the set ``jax.lax.top_k`` picks (scores compared as
+    their bits in the floats' order, so ``+0.0`` lies above ``-0.0``; of
+    equal scores the lower position first), found by counting. A row of at
+    most ``topk`` keys gets ``arange(topk)``.
+
+    Grid (row). A program copies its row's live blocks of 1024 scores from
+    HBM once, orders them as ``_ordered_bits`` does and runs
+    ``kth_largest``'s 32 steps on the copy in VMEM, then as many steps
+    again over the positions of the scores tied with the threshold if
+    there are more of those than fit. Prefix sums of what is kept (0/1
+    triangles on the MXU) say how many keys are kept before each chunk of
+    128 keys and up to each lane inside it; slot s then is the key with s
+    kept before it, found a tile of 128 slots at a time: which chunk owns
+    each slot is a 0/1 matrix over a group of 128 chunks, one product with
+    the group's counts brings every slot its chunk's, and a compare and a
+    count give the lane. A tile looks at the groups its slots' chunks lie
+    in, one or two as a rule: ``topk`` / 128 + chunks / 128 products a row
+    at most, where one product a chunk took twice the time at 33 k keys
+    (``hack/bench_dsa_select.py``). A row of at most ``topk`` keys copies
+    and counts nothing."""
+    rows, keys = scores.shape
+    tiles = -(-topk // _LANES)
+    if tiles > _LANES:  # the kernel holds a tile's two edges in one lane
+        raise ValueError(f"topk {topk} is more than {_LANES * _LANES}")
+    padded = -(-keys // _BLOCK) * _BLOCK
+    if padded != keys:
+        scores = jnp.pad(scores, [(0, 0), (0, padded - keys)])
+    chunks = -(-padded // _LANES // _LANES) * _LANES
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(rows,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, tiles, _LANES), lambda b, *_p: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((chunks, _LANES), jnp.float32),
+                        pltpu.VMEM((chunks, _LANES), jnp.int32),
+                        pltpu.VMEM((chunks, _LANES), jnp.float32),
+                        pltpu.VMEM((chunks, _LANES), jnp.float32),
+                        pltpu.VMEM((chunks // _LANES, _LANES, _LANES),
+                                   jnp.bfloat16),
+                        pltpu.SemaphoreType.DMA((1,))],
+    )
+    picked = pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk),
+        out_shape=jax.ShapeDtypeStruct((rows, tiles, _LANES), jnp.int32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret,
+    )(total_lens.astype(jnp.int32),
+      scores.astype(jnp.float32).reshape(rows, padded // _LANES, _LANES))
+    return picked.reshape(rows, tiles * _LANES)[:, :topk]
+
+
 def select_topk(scores: jax.Array, total_lens: jax.Array, topk: int
                 ) -> tuple[jax.Array, jax.Array]:
     """A decode row's selection: ``(positions [batch, topk], count
     [batch])``. The first ``count = min(total_lens, topk)`` positions are
-    the row's ``topk`` best-scoring keys among its ``total_lens`` (in score
-    order; attention does not care); a row of at most ``topk`` keys gets
-    all of them whatever ``scores`` holds for it."""
-    pos = jnp.arange(scores.shape[-1])[None, :]
-    short = (total_lens <= topk)[:, None]
-    scores = jnp.where(short, -pos.astype(jnp.float32), scores)
-    scores = jnp.where(pos < total_lens[:, None], scores, -jnp.inf)
-    _, picked = jax.lax.top_k(scores, topk)
-    return picked.astype(jnp.int32), jnp.minimum(total_lens, topk)
+    the row's ``topk`` best-scoring keys among its ``total_lens``
+    (ascending; attention does not care); a row of at most ``topk`` keys
+    gets all of them whatever ``scores`` holds for it. ``topk_by_count``,
+    through the interpreter where the program is not lowered for a TPU."""
+    picked = jax.lax.platform_dependent(
+        scores, total_lens,
+        tpu=functools.partial(topk_by_count, topk=topk),
+        default=functools.partial(topk_by_count, topk=topk, interpret=True))
+    return picked, jnp.minimum(total_lens, topk)
 
 
 def gather_selected(k_stack: jax.Array, layer_idx, page_table: jax.Array,

@@ -8,6 +8,7 @@ selection is live. Logits are compared, not tokens.
 
 import dataclasses
 import json
+import re
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -314,6 +315,12 @@ class TestSelection:
                 == sparse_index.KERNEL_INDEX == "dsa_index_scores")
         assert (sparse_index.dsa_keep_bias.__name__
                 == sparse_index.KERNEL_KEEP == "dsa_keep_bias")
+        assert (sparse_index.topk_by_count.__name__
+                == sparse_index.KERNEL_SELECT == "topk_by_count")
+        # The benchmark's reader of the selection's share tells ops by
+        # name, and has to keep finding what replaced the sort.
+        from kvbench.metrics import dsa_select_share
+        assert re.match(dsa_select_share.OPS, sparse_index.KERNEL_SELECT)
 
     # (scores' shape, ctx_lens, new_lens, topk, what the scores hold). 32
     # queries a chunk in tiles of 16; keys in blocks of 256, 128 or 64.
@@ -373,17 +380,75 @@ class TestSelection:
         assert (candidates & (np.asarray(want) != 0.0)).any() == (
             int((ctx + new).max()) > topk and holds != "zeros")
 
-    def test_select_topk_is_exact_and_takes_short_rows_whole(self):
-        rng = np.random.default_rng(1)
-        scores = jnp.asarray(rng.standard_normal((3, 96)), jnp.float32)
-        lens = jnp.asarray([96, 20, 33], jnp.int32)
-        picked, count = sparse_index.select_topk(scores, lens, 32)
-        assert count.tolist() == [32, 20, 32]
-        best = np.argsort(-np.asarray(scores[0]))[:32]
-        assert set(picked[0].tolist()) == set(best.tolist())
-        assert sorted(picked[1, :20].tolist()) == list(range(20))
-        best = np.argsort(-np.asarray(scores[2, :33]))[:32]
-        assert set(picked[2].tolist()) == set(best.tolist())
+    # (rows x keys, total_lens, topk, what the scores hold). The kernel
+    # counts blocks of 1024 keys in chunks of 128 and fills slots in tiles
+    # of 128.
+    SELECT_CASES = {
+        "random-rows": ((3, 96), [96, 20, 33], 32, "normal"),
+        "one-key-more-than-topk": ((2, 256), [65, 201], 64, "normal"),
+        "lens-off-the-block": ((3, 2048 + 1024), [2500, 1025, 3071], 64,
+                               "normal"),
+        "rows-of-0-1-and-topk-keys": ((4, 384), [0, 1, 64, 300], 64,
+                                      "normal"),
+        "ties-at-the-threshold": ((3, 640), [601, 333, 640], 200, "ties"),
+        "an-all-equal-row": ((2, 512), [512, 300], 70, "equal"),
+        "zeros-of-both-signs": ((2, 1280), [1280, 700], 130, "zeros"),
+        "minus-infinity-past-the-length": ((2, 512), [200, 129], 128,
+                                           "-inf"),
+        "slots-in-several-tiles": ((2, 4096), [4000, 2100], 300, "ties"),
+        "the-cells-shape-one-live-row": (
+            (8, 33792), [0, 0, 0, 29000, 0, 0, 0, 0], 2048, "ties"),
+        "the-cells-shape-two-live-rows": (
+            (8, 33792), [33792, 0, 1500, 0, 0, 8200, 0, 2048], 2048,
+            "normal"),
+    }
+
+    @pytest.mark.parametrize("case", SELECT_CASES)
+    def test_select_topk_picks_what_top_k_picks(self, case):
+        """``select_topk`` (the counting kernel, interpreted) against
+        ``jax.lax.top_k`` on the same scores masked past each row's length:
+        ``count`` positions a row, the same set, ascending; a row of at
+        most ``topk`` keys whole and in order."""
+        shape, lens, topk, holds = self.SELECT_CASES[case]
+        rng = np.random.default_rng(len(case))
+        x = rng.standard_normal(shape).astype(np.float32)
+        if holds == "ties":
+            x = np.round(x * 4) / 4  # some twenty values in all
+        elif holds == "equal":
+            x[:] = 0.25
+        elif holds == "zeros":
+            # A weighted sum of ReLUs: few above zero, most zero, of
+            # either sign.
+            x = np.where(x > 1.5, x, np.where(x > 0, 0.0, -0.0)
+                         ).astype(np.float32)
+        elif holds == "-inf":
+            x[:, 100:] = -np.inf  # finite scores fall short of topk too
+        lens = np.asarray(lens)
+        pos = np.arange(shape[1])[None, :]
+        masked = jnp.where(pos < lens[:, None], jnp.asarray(x), -jnp.inf)
+        want = np.asarray(jax.lax.top_k(masked, topk)[1])
+        picked, count = sparse_index.select_topk(
+            jnp.asarray(x), jnp.asarray(lens, jnp.int32), topk)
+        picked = np.asarray(picked)
+        assert picked.shape == (shape[0], topk) and picked.dtype == np.int32
+        assert count.tolist() == np.minimum(lens, topk).tolist()
+        for row, n in enumerate(lens):
+            if n <= topk:
+                assert picked[row].tolist() == list(range(topk))
+            else:
+                assert picked[row].tolist() == sorted(want[row].tolist())
+                assert picked[row].max() < n
+        # The case is what its name says: a key tied with the threshold is
+        # left out where ties are planted, and -0.0 loses to +0.0.
+        if holds in ("ties", "equal", "zeros"):
+            row = int(np.argmax(lens))
+            live = x[row, :lens[row]]
+            thr = np.sort(live)[-topk]
+            assert (live == thr).sum() > (live[picked[row]] == thr).sum() > 0
+        if holds == "zeros":
+            chosen = x[0, picked[0]]
+            assert (chosen == 0).any() and not np.signbit(
+                chosen[chosen == 0]).any()
 
     @pytest.mark.parametrize("q_seq", [1, 32])
     def test_the_scoring_kernel_is_the_function(self, q_seq):

@@ -77,6 +77,14 @@ def test_keep_bias(shaped):
              shaped((1, CHUNK), jnp.int32), shaped((1,), jnp.int32))
 
 
+def test_select_by_count(shaped):
+    """A decode step's selection: a row's 33 blocks of 1024 scores in
+    fast memory as they land and ordered, the prefix sums of what is kept
+    beside them, the triangle over its 384 chunks, 16 tiles of slots."""
+    compiles(functools.partial(sparse_index.topk_by_count, topk=2048),
+             shaped((ROWS, KEYS), jnp.float32), shaped((ROWS,), jnp.int32))
+
+
 def test_masked_latent_prefill(shaped):
     """128 query heads on one 640-lane latent head, 16 query rows a
     program, a selection bias over every key of the row."""
