@@ -2069,7 +2069,7 @@ def forward_decode_pallas(
         with jax.named_scope(SCOPE_SELECT):
             picked, count = sparse_index.select_topk(scores, lens, topk)
             chosen = sparse_index.gather_selected(
-                k_stack, layer_idx, table, picked)
+                k_stack, layer_idx, table, picked, count)
         with jax.named_scope(SCOPE_SPARSE_ATTENTION):
             pages = chosen.shape[0] // lens.shape[0]
             own = jnp.arange(chosen.shape[0], dtype=jnp.int32).reshape(

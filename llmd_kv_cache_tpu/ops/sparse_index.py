@@ -8,8 +8,9 @@ own row with a few light heads,
     I[t, s] = sum_j w[t, j] * relu(q_idx[t, j] . k_idx[s]),
 
 keeps the ``topk`` best positions ``s <= t`` (all of them while there are
-no more than ``topk``) and attends those only. Three pieces, shared by the
-XLA and the Pallas step programs:
+no more than ``topk``) and attends those only. Four pieces; the first three
+are shared by the XLA and the Pallas step programs, the last is the Pallas
+decode program's:
 
 - ``index_scores``: the row's pages of the index stream gathered into
   logical order (a page is the pool's own unit, so the gather moves whole
@@ -35,6 +36,18 @@ XLA and the Pallas step programs:
   of it 0/1 products on the MXU. Positions come out ascending. A
   row of at most ``topk`` keys, every padded row among them, costs
   nothing.
+- ``gather_selected``: the chosen latents as a pool of their own, which the
+  decode kernel then streams as it streams any pool. On a TPU one kernel,
+  ``gather_by_product``, that reads ``count`` of each row: a padded row
+  fetches nothing, a row that keeps its first ``count`` keys has those
+  pages copied whole, and a row that chose among more streams its own
+  pages through VMEM, each looked up in its line of the page table on the
+  way, and compacts them with 0/1 products (the positions ascend, so a
+  round of pages fills a run of slots). A copy of one 1,280 B cache row the
+  chip's compiler refuses (the pool's tiling is 8 rows), and XLA's gather
+  of all 8 x 2048 slots, a row at a time behind a 16,384-element look-up of
+  their pages, was 0.38 ms a layer whatever the rows held
+  (``hack/bench_dsa_gather.py``); it stays as what runs off the chip.
 """
 
 from __future__ import annotations
@@ -52,10 +65,12 @@ DROPPED = -1e30
 
 # The kernels' names as a device trace has them (their jitted wrappers'
 # ``__name__``, as ``ops.pallas_paged_attention`` names its kernels); the
-# benchmark's ``dsa_select_share`` counts the ops whose names start ``topk``.
+# benchmark's ``dsa_select_share`` counts the ops whose names start ``topk``
+# or ``gather``.
 KERNEL_INDEX = "dsa_index_scores"
 KERNEL_KEEP = "dsa_keep_bias"
 KERNEL_SELECT = "topk_by_count"
+KERNEL_GATHER = "gather_by_product"
 
 
 def gather_index_keys(idx_stack: jax.Array, layer_idx, page_table: jax.Array
@@ -571,12 +586,183 @@ def select_topk(scores: jax.Array, total_lens: jax.Array, topk: int
     return picked, jnp.minimum(total_lens, topk)
 
 
-def gather_selected(k_stack: jax.Array, layer_idx, page_table: jax.Array,
-                    positions: jax.Array) -> jax.Array:
-    """The cache rows at ``positions [batch, n]`` of each row's own pages,
-    as a pool of their own: ``[batch * pages, 1, page_size, width]`` with
-    ``pages = ceil(n / page_size)`` pages a row, row ``b``'s at ``b *
-    pages``. The decode kernel then streams them as it streams any pool."""
+# Slots of the chosen pool a product fills at a time, and keys of a round:
+# one MXU pass wide, and the decode kernel's superblock.
+_SLOT_TILE = 128
+_ROUND_KEYS = 1024
+
+
+def _gather_kernel(table_ref, count_ref, layer_ref, pos_ref, k_hbm, o_hbm,
+                   landed, chosen, slot_pos, sem, *, page_size, kpb):
+    # table_ref [rows, pages a row], count_ref [rows], layer_ref [1] (SMEM);
+    # pos_ref [1, tiles, 128]: slot s of the row's selection at [s // 128,
+    # s % 128]; k_hbm the pool [layers, pages, 1, page_size, width] and
+    # o_hbm the chosen one [rows * pages, 1, page_size, width], both left
+    # in HBM; landed [2, kpb, page_size, width]: a round of the row's own
+    # pages, twice; chosen [pages rounded up to tiles, page_size, width]:
+    # the row's slots as they fill; slot_pos [tiles * 128, 128]: slot s's
+    # position all along line s.
+    b = pl.program_id(0)
+    count = count_ref[b]
+    layer = layer_ref[0]
+    tiles = pos_ref.shape[1]
+    pages = o_hbm.shape[0] // table_ref.shape[0]
+    per_tile = _SLOT_TILE // page_size
+    width = chosen.shape[-1]
+    keys = kpb * page_size
+    i32 = jnp.int32
+
+    def iota(shape, dim):
+        return jax.lax.broadcasted_iota(i32, shape, dim)
+
+    slot = iota((tiles, _SLOT_TILE), 0) * _SLOT_TILE + iota(
+        (tiles, _SLOT_TILE), 1)
+    pos = jnp.where(slot < count, pos_ref[0], -1)
+    reach = jnp.max(pos)  # the row's last chosen position; -1: a padded row
+    # Ascending and distinct, so they are the row's first ``count`` keys
+    # where the last of them is key ``count - 1``: whole pages then.
+    leading = reach == count - 1
+
+    def page_copy(j):
+        return pltpu.make_async_copy(
+            k_hbm.at[layer, table_ref[b, j], 0],
+            o_hbm.at[b * pages + j, 0], sem.at[0, 0])
+
+    @pl.when((count > 0) & leading)
+    def _():
+        whole = (count + page_size - 1) // page_size
+
+        @pl.loop(0, whole)
+        def _(j):
+            page_copy(j).start()
+
+        @pl.loop(0, whole)
+        def _(j):
+            page_copy(j).wait()
+
+    @pl.when((count > 0) & ~leading)
+    def _():
+        last_page = reach // page_size
+
+        def copies(buf, r):
+            # Past the row's last page that page again (finite, and no
+            # slot's): a product may not meet what VMEM held before.
+            return [pltpu.make_async_copy(
+                k_hbm.at[layer, table_ref[b, jnp.minimum(r * kpb + t,
+                                                         last_page)], 0],
+                landed.at[buf, t], sem.at[buf, t]) for t in range(kpb)]
+
+        for c in copies(0, 0):
+            c.start()
+        chosen[...] = jnp.zeros_like(chosen)
+        for t in range(tiles):
+            slot_pos[t * _SLOT_TILE:(t + 1) * _SLOT_TILE, :] = jnp.transpose(
+                jnp.broadcast_to(pos[t:t + 1], (_SLOT_TILE, _SLOT_TILE)))
+
+        def a_round(r, lo):
+            buf = r % 2
+
+            @pl.when(r < last_page // kpb)
+            def _():
+                for c in copies(1 - buf, r + 1):
+                    c.start()
+
+            for c in copies(buf, r):
+                c.wait()
+            hi = jnp.sum(((pos >= 0) & (pos < (r + 1) * keys)).astype(i32))
+            latents = landed[buf].reshape(keys, width)
+            key = r * keys + iota((_SLOT_TILE, keys), 1)
+
+            # The round's keys are slots [lo, hi), ``hi`` of the row's
+            # chosen positions lying below its end: a tile of 128 slots at
+            # a time, which of the round's keys each slot is as a 0/1
+            # matrix, times the round (one 1 a line, float32 sums: exact).
+            @pl.loop(lo // _SLOT_TILE, (hi + _SLOT_TILE - 1) // _SLOT_TILE)
+            def _(t):
+                lines = pl.ds(pl.multiple_of(t * _SLOT_TILE, _SLOT_TILE),
+                              _SLOT_TILE)
+                is_key = key == slot_pos[lines, :][:, :1]
+                got = jnp.dot(is_key.astype(latents.dtype), latents,
+                              preferred_element_type=jnp.float32)
+                at = pl.ds(t * per_tile, per_tile)
+                chosen[at] = chosen[at] + got.astype(chosen.dtype).reshape(
+                    per_tile, page_size, width)
+
+            return hi
+
+        jax.lax.fori_loop(0, last_page // kpb + 1, a_round, jnp.int32(0))
+        out = pltpu.make_async_copy(
+            chosen.at[pl.ds(0, pages)], o_hbm.at[pl.ds(b * pages, pages), 0],
+            sem.at[0, 0])
+        out.start()
+        out.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gather_by_product(k_stack: jax.Array, layer_idx, page_table: jax.Array,
+                      positions: jax.Array, count: jax.Array, *,
+                      interpret: bool = False) -> jax.Array:
+    """``gather_selected`` as one kernel, grid (row), that reads what a
+    row holds and no more.
+
+    A row of ``count`` 0 (every padded row) does nothing: its pages of the
+    result are never written, and the decode kernel loads none of them for
+    a row of no keys. A row whose chosen positions are its first ``count``
+    keys (its last chosen position is ``count - 1``: what ``select_topk``
+    gives a row of at most ``topk`` keys) has its first ``ceil(count /
+    page_size)`` pages copied whole, pool to result. Any other row streams
+    its own pages up to its last chosen position through VMEM, 1024 keys a
+    round, twice buffered, looking each page up in its line of the page
+    table on the way, and compacts them on the MXU: a round's keys are a
+    run of the row's slots (the positions ascend), and for each tile of 128
+    slots the run touches, which of the round's keys each slot is, as a 0/1
+    matrix, times the round. One 1 a line and float32 sums, so a slot gets
+    its cache row's values exactly (a ``-0.0`` comes out ``+0.0``, and what
+    the MXU flushes, flushed); a product also multiplies the round's other
+    keys by 0, so the pool's pages have to be finite, as every kernel that
+    masks by score already needs them. The row's ``pages`` pages are then
+    written once, zero past ``count``: all a decode kernel may load for it.
+    Positions have to ascend within a row's first ``count``."""
+    batch, n = positions.shape
+    page_size, width = k_stack.shape[-2:]
+    if _SLOT_TILE % page_size:
+        raise ValueError(f"a page of {page_size} keys does not divide "
+                         f"{_SLOT_TILE}")
+    pages = -(-n // page_size)
+    tiles = -(-n // _SLOT_TILE)
+    if tiles * _SLOT_TILE != n:
+        positions = jnp.pad(positions, [(0, 0), (0, tiles * _SLOT_TILE - n)])
+    kpb = min(_ROUND_KEYS // page_size, page_table.shape[1])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(batch,),
+        in_specs=[pl.BlockSpec((1, tiles, _SLOT_TILE),
+                               lambda b, *_p: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, kpb, page_size, width), k_stack.dtype),
+            pltpu.VMEM((tiles * (_SLOT_TILE // page_size), page_size, width),
+                       k_stack.dtype),
+            pltpu.VMEM((tiles * _SLOT_TILE, _SLOT_TILE), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, kpb))],
+    )
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, page_size=page_size, kpb=kpb),
+        out_shape=jax.ShapeDtypeStruct((batch * pages, 1, page_size, width),
+                                       k_stack.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), count.astype(jnp.int32),
+      jnp.asarray(layer_idx, jnp.int32).reshape(1),
+      positions.astype(jnp.int32).reshape(batch, tiles, _SLOT_TILE), k_stack)
+
+
+def _gather_by_index(k_stack, layer_idx, page_table, positions, count):
+    """``gather_selected`` in ``jax.numpy``: every slot of every row."""
+    del count
     batch, n = positions.shape
     page_size, width = k_stack.shape[-2:]
     pages = -(-n // page_size)
@@ -587,3 +773,19 @@ def gather_selected(k_stack: jax.Array, layer_idx, page_table: jax.Array,
                                 page_table.shape[1] - 1), axis=1)
     rows = k_stack[layer_idx, page, 0, positions % page_size]  # [b, n, w]
     return rows.reshape(batch * pages, 1, page_size, width)
+
+
+def gather_selected(k_stack: jax.Array, layer_idx, page_table: jax.Array,
+                    positions: jax.Array, count: jax.Array) -> jax.Array:
+    """The cache rows at the first ``count [batch]`` of ``positions
+    [batch, n]`` of each row's own pages, as a pool of their own: ``[batch
+    * pages, 1, page_size, width]`` with ``pages = ceil(n / page_size)``
+    pages a row, row ``b``'s at ``b * pages``. The decode kernel then
+    streams them as it streams any pool, ``count`` keys of each row; what
+    lies past a row's ``count`` is whatever the form that ran left there
+    (``gather_by_product`` on a TPU: finite where a decode kernel may load
+    it; off the chip the ``jax.numpy`` gather of every slot, which traces
+    faster than the kernel interprets)."""
+    return jax.lax.platform_dependent(
+        k_stack, jnp.asarray(layer_idx, jnp.int32), page_table, positions,
+        count, tpu=gather_by_product, default=_gather_by_index)

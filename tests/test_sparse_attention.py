@@ -317,10 +317,14 @@ class TestSelection:
                 == sparse_index.KERNEL_KEEP == "dsa_keep_bias")
         assert (sparse_index.topk_by_count.__name__
                 == sparse_index.KERNEL_SELECT == "topk_by_count")
+        assert (sparse_index.gather_by_product.__name__
+                == sparse_index.KERNEL_GATHER == "gather_by_product")
         # The benchmark's reader of the selection's share tells ops by
-        # name, and has to keep finding what replaced the sort.
+        # name, and has to keep finding what replaced the sort, and the
+        # gather of the chosen latents now that it is a kernel.
         from kvbench.metrics import dsa_select_share
         assert re.match(dsa_select_share.OPS, sparse_index.KERNEL_SELECT)
+        assert re.match(dsa_select_share.OPS, sparse_index.KERNEL_GATHER)
 
     # (scores' shape, ctx_lens, new_lens, topk, what the scores hold). 32
     # queries a chunk in tiles of 16; keys in blocks of 256, 128 or 64.
@@ -449,6 +453,140 @@ class TestSelection:
             chosen = x[0, picked[0]]
             assert (chosen == 0).any() and not np.signbit(
                 chosen[chosen == 0]).any()
+
+    # (page size, width, layers, layer_idx, pages a row, topk, each row's
+    # keys, the order of a row's pages in the pool). The kernel streams a
+    # row's pages 1024 keys a round and fills slots in tiles of 128.
+    GATHER_CASES = {
+        "a-padded-row-beside-a-live-one": (16, 128, 1, 0, 24, 64, [0, 300],
+                                           "shuffled"),
+        "a-row-under-topk": (16, 128, 1, 0, 8, 64, [40], "shuffled"),
+        "a-row-of-exactly-topk": (16, 128, 1, 0, 8, 64, [64], "shuffled"),
+        "a-row-over-topk-ending-mid-page": (16, 128, 1, 0, 24, 64, [301],
+                                            "shuffled"),
+        "two-unlike-rows-and-two-padded": (16, 128, 2, 1, 40, 64,
+                                           [0, 70, 0, 600], "shuffled"),
+        "another-layer": (16, 128, 3, 2, 24, 64, [333, 64, 65], "shuffled"),
+        "pages-out-of-order": (16, 128, 2, 0, 24, 64, [380, 50],
+                               "descending"),
+        "pages-in-order": (16, 128, 2, 1, 24, 64, [380, 50], "ascending"),
+        "topk-off-the-page-and-the-tile": (16, 128, 1, 0, 24, 40,
+                                           [39, 40, 41, 380], "shuffled"),
+        "several-rounds-and-tiles": (64, 256, 2, 1, 50, 300,
+                                     [3200, 0, 301, 1100], "shuffled"),
+        "every-row-padded": (16, 128, 1, 0, 8, 64, [0, 0], "shuffled"),
+    }
+
+    @staticmethod
+    def gather_inputs(case, page_size, width, layers, row_pages, topk, lens,
+                      order):
+        """A pool of pages that no two rows share, a page table in
+        ``order`` and ascending positions as ``select_topk`` leaves them
+        (a row of at most ``topk`` keys its first); page 0 is nobody's."""
+        rng = np.random.default_rng(len(case))
+        used = sum(-(-n // page_size) for n in lens)
+        pool = rng.standard_normal(
+            (layers, used + 5, 1, page_size, width)).astype(np.float32)
+        ids = np.arange(1, used + 5)
+        ids = {"shuffled": rng.permutation(ids), "ascending": ids,
+               "descending": ids[::-1]}[order]
+        table = np.zeros((len(lens), row_pages), np.int32)
+        positions = np.tile(np.arange(topk, dtype=np.int32), (len(lens), 1))
+        at = 0
+        for row, n in enumerate(lens):
+            need = -(-n // page_size)
+            table[row, :need] = ids[at:at + need]
+            at += need
+            if n > topk:
+                positions[row] = np.sort(rng.choice(n, topk, replace=False))
+        return pool, table, positions, np.minimum(lens, topk).astype(np.int32)
+
+    @staticmethod
+    def gather_reference(pool, layer_idx, table, positions):
+        """The ``jax.numpy`` gather that ``gather_selected`` was until
+        PR 55: every slot of every row, a page looked up for each."""
+        page_size = pool.shape[-2]
+        page = jnp.take_along_axis(
+            jnp.asarray(table), jnp.asarray(positions) // page_size, axis=1)
+        return np.asarray(jnp.asarray(pool)[
+            layer_idx, page, 0, jnp.asarray(positions) % page_size])
+
+    @pytest.mark.parametrize("case", GATHER_CASES)
+    def test_the_gather_kernel_fetches_what_the_gather_fetched(self, case):
+        """``gather_by_product`` (interpreted) against the ``jax.numpy``
+        gather: the first ``count`` slots of every live row bit for bit,
+        zeros behind them in a row that was compacted, and nothing written
+        for a padded row (the interpreter hands a kernel NaNs to write
+        into)."""
+        (page_size, width, layers, layer_idx, row_pages, topk, lens,
+         order) = self.GATHER_CASES[case]
+        pool, table, positions, count = self.gather_inputs(
+            case, page_size, width, layers, row_pages, topk, lens, order)
+        want = self.gather_reference(pool, layer_idx, table, positions)
+        pages = -(-topk // page_size)
+        args = (jnp.asarray(pool, jnp.bfloat16), layer_idx,
+                jnp.asarray(table), jnp.asarray(positions),
+                jnp.asarray(count))
+        got = sparse_index.gather_by_product(*args, interpret=True)
+        assert got.shape == (len(lens) * pages, 1, page_size, width)
+        assert got.dtype == jnp.bfloat16
+        got = np.asarray(got).reshape(len(lens), pages * page_size, width)
+        served = np.asarray(sparse_index.gather_selected(*args)).reshape(
+            got.shape)  # off the chip: the gather itself
+        want = np.asarray(jnp.asarray(want, jnp.bfloat16))
+        for row, n in enumerate(count):
+            assert got[row, :n].tobytes() == want[row, :n].tobytes()
+            assert served[row, :n].tobytes() == want[row, :n].tobytes()
+            tail = got[row, n:].astype(np.float32)
+            if n == 0:
+                assert np.isnan(tail).all()
+            elif lens[row] > topk:  # compacted: zeros to the pages' end
+                assert (tail == 0).all()
+            else:  # whole pages: the last one's own tail, and no further
+                end = -(-n // page_size) * page_size
+                assert np.isfinite(tail[:end - n]).all()
+                assert np.isnan(tail[end - n:]).all()
+
+    def test_attention_over_the_gathered_pool_meets_nothing_unwritten(self):
+        """The decode kernel over ``gather_by_product``'s pool, with the
+        pool's unused pages and everything the kernel did not write NaN:
+        every live row finite and what it is over the gather's pool. (A
+        masked key still enters ``p @ v`` as ``0 x`` what its slot holds.)
+        A padded row attends nothing and comes out 0."""
+        from llmd_kv_cache_tpu.ops.pallas_paged_attention import (
+            pallas_paged_decode_attention)
+        page_size, width, topk, lens = 16, 128, 72, [0, 500, 30, 72, 0, 75]
+        pool, table, positions, count = self.gather_inputs(
+            "poisoned", page_size, width, 2, 40, topk, lens, "shuffled")
+        unused = np.setdiff1d(np.arange(pool.shape[1]), table[table > 0])
+        pool[:, unused] = np.nan
+        pages = -(-topk // page_size)
+        got = sparse_index.gather_by_product(
+            jnp.asarray(pool, jnp.bfloat16), 1, jnp.asarray(table),
+            jnp.asarray(positions), jnp.asarray(count), interpret=True)
+        assert np.isnan(np.asarray(got, np.float32)).any()  # the padded rows
+        want = self.gather_reference(np.nan_to_num(pool), 1, table, positions)
+        want = jnp.asarray(np.pad(want, [
+            (0, 0), (0, pages * page_size - topk), (0, 0)]),
+            jnp.bfloat16).reshape(got.shape)
+        q = jnp.asarray(np.random.default_rng(7).standard_normal(
+            (len(lens), 4, width)), jnp.bfloat16)
+        own = jnp.arange(len(lens) * pages, dtype=jnp.int32).reshape(
+            len(lens), pages)
+
+        def attend(chosen):
+            return np.asarray(pallas_paged_decode_attention(
+                q, chosen, chosen, own, jnp.asarray(count), shared_kv=True,
+                interpret=True), np.float32)
+
+        out, ref = attend(got), attend(want)
+        assert np.isfinite(out).all()
+        for row, n in enumerate(count):
+            if n:
+                np.testing.assert_array_equal(out[row], ref[row])
+                assert np.abs(out[row]).max() > 0
+            else:
+                assert (out[row] == 0).all()
 
     @pytest.mark.parametrize("q_seq", [1, 32])
     def test_the_scoring_kernel_is_the_function(self, q_seq):

@@ -85,6 +85,16 @@ def test_select_by_count(shaped):
              shaped((ROWS, KEYS), jnp.float32), shaped((ROWS,), jnp.int32))
 
 
+def test_gather_by_product(shaped):
+    """A decode step's gather of the chosen latents: a round of 16 pages
+    of a row twice in fast memory, the row's 2048 slots as they fill, every
+    slot's position along a line, 528 page ids a row as scalars."""
+    compiles(sparse_index.gather_by_product,
+             shaped((LAYERS, PAGES, 1, PAGE, 640)), shaped((), jnp.int32),
+             shaped((ROWS, ROW_PAGES), jnp.int32),
+             shaped((ROWS, 2048), jnp.int32), shaped((ROWS,), jnp.int32))
+
+
 def test_masked_latent_prefill(shaped):
     """128 query heads on one 640-lane latent head, 16 query rows a
     program, a selection bias over every key of the row."""
