@@ -22,6 +22,8 @@ chip (``chiprun -- python3 hack/kvbench_probe_readings.py ...``) or, with
                        (``FAULTS``); it has to come out not ok
                        (``verify-mask``: a drafting model's first verified
                        position sees the draft's key too;
+                       ``no-residual-scale``: a model with a
+                       ``residual_multiplier`` served without it;
                        ``xla-recurrence`` is no fault: the recurrence's
                        XLA form in the kernels' place, which has to read
                        as the kernels do)
@@ -85,22 +87,41 @@ def _gate_left_out() -> None:
     llama._sublayer_out = ungated
 
 
+def _residual_unscaled() -> None:
+    """What a sub-layer adds joins the residual whole, as in a model
+    without ``residual_multiplier``."""
+    import dataclasses
+
+    from llmd_kv_cache_tpu.models import llama
+
+    served = llama._sublayer_out
+
+    def unscaled(out, gate_in, layer, cfg, which):
+        return served(out, gate_in, layer,
+                      dataclasses.replace(cfg, residual_multiplier=1.0),
+                      which)
+
+    llama._sublayer_out = unscaled
+
+
 def _xla_recurrence() -> None:
     """Not a fault: the recurrence's XLA form in the kernels' place (the
     same blocked algorithm, the compiler's float32 matmuls), to tell a
     kernel's arithmetic from the algorithm's."""
     import functools
 
-    from llmd_kv_cache_tpu.ops import gated_deltanet as gd
+    from llmd_kv_cache_tpu.ops import gated_deltanet as gd, mamba2 as m2
 
-    for name in ("gdn_scan", "gdn_step", "kda_scan", "kda_step"):
-        served = getattr(gd, name)
+    for module, name in ((gd, "gdn_scan"), (gd, "gdn_step"),
+                         (gd, "kda_scan"), (gd, "kda_step"),
+                         (m2, "mamba2_scan"), (m2, "mamba2_step")):
+        served = getattr(module, name)
 
         def xla(*args, _served=served, **kw):
             return _served(*args, **{**kw, "kernel": False,
                                      "interpret": False})
 
-        setattr(gd, name, functools.wraps(served)(xla))
+        setattr(module, name, functools.wraps(served)(xla))
 
 
 def _verify_mask_off_by_one() -> None:
@@ -121,7 +142,9 @@ def _verify_mask_off_by_one() -> None:
 # Faults planted in the program, by name. Each replaces something the step
 # programs look up when they are first traced.
 FAULTS = {"stale-state": _stale_state, "conv-tail": _conv_tail_dropped,
-          "no-gate": _gate_left_out, "xla-recurrence": _xla_recurrence,
+          "no-gate": _gate_left_out,
+          "no-residual-scale": _residual_unscaled,
+          "xla-recurrence": _xla_recurrence,
           "verify-mask": _verify_mask_off_by_one}
 
 

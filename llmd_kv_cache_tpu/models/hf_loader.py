@@ -104,13 +104,19 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     # silently-wrong logits.
     supported = ("llama", "mistral", "mixtral", "qwen2", "qwen3",
                  "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32",
-                 "gigachat3_5", "solar_open2", "pangu_ultra_moe")
+                 "gigachat3_5", "solar_open2", "pangu_ultra_moe",
+                 "granitemoehybrid")
     if hf_cfg.model_type not in supported:
         raise NotImplementedError(
             f"model_type {hf_cfg.model_type!r} is not supported "
             f"(supported: {supported}); of the DeepSeek line what is still "
             f"refused is fp8 latent or index streams, and the multi-token-"
-            f"prediction module is built for pangu_ultra_moe alone")
+            f"prediction module is built for pangu_ultra_moe alone; of the "
+            f"Granite line the hybrid is built (granitemoehybrid: Mamba-2 "
+            f"beside NoPE attention, routed feed-forwards) and what is still "
+            f"refused is rope in its attending layers, a bias on its "
+            f"projections, more than one group of B and C, and dense "
+            f"feed-forwards")
     act = getattr(hf_cfg, "hidden_act", "silu")
     if act not in ("silu", "swish"):
         raise NotImplementedError(
@@ -119,6 +125,8 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     rope_scaling = _convert_rope_scaling(hf_cfg)
     if hf_cfg.model_type == "solar_open2":
         return _config_from_solar(hf_cfg, page_size, dtype)
+    if hf_cfg.model_type == "granitemoehybrid":
+        return _config_from_granite(hf_cfg, page_size, dtype)
     if hf_cfg.model_type.startswith("deepseek"):
         return _config_from_deepseek(hf_cfg, page_size, dtype,
                                      rope_scaling)
@@ -204,6 +212,24 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     )
 
 
+def _layer_share(hf_cfg: Any, experts: int) -> dict:
+    """``LlamaConfig``'s ``num_experts`` and ``experts_held`` from the
+    experts a config counts and its top-level ``layer_share`` (one chip's
+    share of each layer: ``{"chips", "rank", "n_routed_experts"}``; the
+    count is then what the chip holds, contiguous from ``rank * count``,
+    and ``n_routed_experts`` the router's width). Without the key: all of
+    them, held whole."""
+    share = getattr(hf_cfg, "layer_share", None)
+    if not share:
+        return {"num_experts": experts}
+    if experts * int(share["chips"]) != int(share["n_routed_experts"]):
+        raise ValueError(
+            f"layer_share: {experts} experts held x {share['chips']} "
+            f"chips is not the router's {share['n_routed_experts']}")
+    return {"num_experts": int(share["n_routed_experts"]),
+            "experts_held": (int(share["rank"]) * experts, experts)}
+
+
 def _v3_routed(hf_cfg: Any) -> dict:
     """``LlamaConfig``'s keys for DeepSeek-V3's routed layers (sigmoid
     scores, ``noaux_tc``, groups, a shared expert) behind
@@ -223,20 +249,8 @@ def _v3_routed(hf_cfg: Any) -> dict:
             "noaux_tc", None):
         raise NotImplementedError(
             f"topk_method {hf_cfg.topk_method!r} unsupported")
-    moe_kw = {}
-    share = getattr(hf_cfg, "layer_share", None)
-    if share:
-        held = int(hf_cfg.n_routed_experts)
-        if held * int(share["chips"]) != int(share["n_routed_experts"]):
-            raise ValueError(
-                f"layer_share: {held} experts held x {share['chips']} "
-                f"chips is not the router's "
-                f"{share['n_routed_experts']}")
-        moe_kw["experts_held"] = (int(share["rank"]) * held, held)
     return dict(
-        moe_kw,
-        num_experts=int(share["n_routed_experts"]) if share
-        else hf_cfg.n_routed_experts,
+        _layer_share(hf_cfg, int(hf_cfg.n_routed_experts)),
         num_experts_per_token=hf_cfg.num_experts_per_tok,
         moe_layers=tuple(range(first_dense, n_layers)),
         n_shared_experts=hf_cfg.n_shared_experts,
@@ -484,6 +498,97 @@ def _config_from_solar(hf_cfg: Any, page_size: int,
         router_bias_init_scale=float(getattr(
             hf_cfg, "router_bias_init_scale", 0.02)),
         **_v3_routed(hf_cfg),
+    )
+
+
+def _config_from_granite(hf_cfg: Any, page_size: int,
+                         dtype: Any) -> LlamaConfig:
+    """Granite 4.0-H (``model_type: granitemoehybrid``): ``layer_types``
+    names each layer's mixer, "mamba" (Mamba-2 in the ``mamba_*`` sizes:
+    ``mamba_n_heads`` heads of ``mamba_d_head`` over a state of
+    ``mamba_d_state``, a conv of ``mamba_d_conv`` taps with a bias, the
+    gated norm over all inner channels) or "attention" (GQA of
+    ``hidden_size / num_attention_heads`` a head, no positional encoding,
+    scores times ``attention_multiplier``); every feed-forward routes
+    ``num_experts_per_tok`` of ``num_local_experts`` experts of width
+    ``intermediate_size`` by the softmax over the chosen logits, beside an
+    always-on MLP of ``shared_intermediate_size``; the embedding times
+    ``embedding_multiplier``, each sub-layer's output times
+    ``residual_multiplier``, the logits over ``logits_scaling``. A
+    top-level ``layer_share`` is one chip's share of each layer as
+    ``_v3_routed`` reads it: ``num_local_experts`` is then what the chip
+    holds and ``layer_share.n_routed_experts`` the router's width. The head
+    is kept apart from the embedding whatever ``tie_word_embeddings`` says.
+    What the published config does not give and a top-level key may (no
+    checkpoint has them): ``state_slots``, ``state_checkpoint_tokens``,
+    ``embed_init_scale`` as for GigaChat3.5. Each form that is not built is
+    refused by its key."""
+    for key, built in (("position_embedding_type", "nope"),
+                       ("mamba_proj_bias", False), ("attention_bias", False),
+                       ("mamba_conv_bias", True), ("mamba_n_groups", 1),
+                       ("normalization_function", "rmsnorm")):
+        if getattr(hf_cfg, key, built) != built:
+            raise NotImplementedError(
+                f"{key} {getattr(hf_cfg, key)!r}: what is built is "
+                f"{built!r}")
+    if getattr(hf_cfg, "time_step_limit", None) not in (
+            None, (0.0, float("inf")), [0.0, float("inf")]):
+        raise NotImplementedError("time_step_limit: the step is not clipped")
+    kinds = list(hf_cfg.layer_types)
+    n_layers = hf_cfg.num_hidden_layers
+    if len(kinds) != n_layers or set(kinds) - {"mamba", "attention"}:
+        raise NotImplementedError(
+            f"layer_types {kinds!r}: one of 'mamba', 'attention' for each "
+            f"of the {n_layers} layers")
+    heads, head = int(hf_cfg.mamba_n_heads), int(hf_cfg.mamba_d_head)
+    if heads * head != int(hf_cfg.mamba_expand) * hf_cfg.hidden_size:
+        raise ValueError(
+            f"mamba_n_heads x mamba_d_head = {heads * head} is not "
+            f"mamba_expand x hidden_size")
+    experts = int(getattr(hf_cfg, "num_local_experts", 0) or 0)
+    if not experts:
+        raise NotImplementedError(
+            "num_local_experts 0: the dense feed-forward of the smaller "
+            "hybrids is not built")
+    inter, shared = int(hf_cfg.intermediate_size), int(
+        getattr(hf_cfg, "shared_intermediate_size", 0) or 0)
+    if shared % inter:
+        raise NotImplementedError(
+            f"shared_intermediate_size {shared} is not a multiple of the "
+            f"experts' intermediate_size {inter}")
+    return LlamaConfig(
+        vocab_size=hf_cfg.vocab_size,
+        hidden_size=hf_cfg.hidden_size,
+        num_layers=n_layers,
+        num_heads=hf_cfg.num_attention_heads,
+        num_kv_heads=hf_cfg.num_key_value_heads,
+        head_dim=hf_cfg.hidden_size // hf_cfg.num_attention_heads,
+        intermediate_size=inter,
+        rope_theta=0.0,
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        page_size=page_size,
+        dtype=dtype,
+        linear_layers=tuple(i for i, kind in enumerate(kinds)
+                            if kind == "mamba"),
+        linear=LinearAttention(
+            key_heads=1, value_heads=heads, key_dim=int(hf_cfg.mamba_d_state),
+            value_dim=head, conv_kernel=int(hf_cfg.mamba_d_conv),
+            gate_scale=1.0, norm_eps=float(hf_cfg.rms_norm_eps),
+            decay="mamba2"),
+        state_slots=int(getattr(hf_cfg, "state_slots", 64)),
+        state_checkpoint_tokens=int(getattr(
+            hf_cfg, "state_checkpoint_tokens", 4096)),
+        embed_init_scale=float(getattr(hf_cfg, "embed_init_scale", 0.02)),
+        num_experts_per_token=int(hf_cfg.num_experts_per_tok),
+        n_shared_experts=shared // inter,
+        moe_intermediate_size=inter,
+        moe_router=("softmax_topk", 1),
+        moe_dispatch="grouped",
+        embedding_multiplier=float(hf_cfg.embedding_multiplier),
+        residual_multiplier=float(hf_cfg.residual_multiplier),
+        attention_multiplier=float(hf_cfg.attention_multiplier),
+        logits_scaling=float(hf_cfg.logits_scaling),
+        **_layer_share(hf_cfg, experts),
     )
 
 

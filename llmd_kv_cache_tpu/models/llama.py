@@ -56,7 +56,9 @@ SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_MLP,
 # name carries its scopes. A model with linear layers has the recurrence's
 # two kernels under their own names there too (``ops.gated_deltanet``:
 # ``gdn_scan`` / ``gdn_step``, or ``kda_scan`` / ``kda_step`` where the decay
-# is channel-wise), which the ``*_roofline`` readers find by the ops' names.
+# is channel-wise; ``ops.mamba2``: ``mamba2_scan`` / ``mamba2_step`` where the
+# layer is a state-space one), which the ``*_roofline`` readers find by the
+# ops' names.
 SCOPE_INDEX = "dsa_index"
 SCOPE_SELECT = "dsa_select"
 SCOPE_SPARSE_ATTENTION = "dsa_attend"
@@ -86,7 +88,16 @@ class LinearAttention:
     attention: q, k, v from ``w_conv_in``; the decay and the output gate
     through low-rank projections ``gate_rank`` wide; as many key heads as
     value heads). ``beta = beta_scale * sigmoid(.)``: 2 admits negative
-    eigenvalues of a token's transition."""
+    eigenvalues of a token's transition.
+
+    ``decay == "mamba2"`` is no delta rule: a Mamba-2 state-space layer
+    (``_mamba2``, ``ops.mamba2``) in the same sizes: ``value_heads`` heads of
+    ``value_dim`` channels, a state ``key_dim`` wide, ``key_heads`` groups
+    of ``B`` and ``C`` (one is built), a conv with a bias over ``[x | B |
+    C]`` (``conv_channels`` as above), one decay a head. Its output is
+    normed over all ``inner`` channels after the gate ``silu(z)``; the
+    delta rules' ``gate_scale``, ``beta_scale`` and ``gate_rank`` mean
+    nothing there and are refused."""
 
     key_heads: int
     value_heads: int
@@ -106,6 +117,18 @@ class LinearAttention:
     @property
     def inner(self) -> int:
         return self.value_heads * self.value_dim
+
+    @property
+    def state_shape(self) -> tuple:
+        """A sequence's recurrent state in one layer, as the pool holds it
+        (float32): ``[value heads, key_dim, value_dim]``, or a Mamba-2
+        layer's tiles of heads side by side (``ops.mamba2.state_shape``:
+        as many values, no lane left empty)."""
+        if self.decay == "mamba2":
+            from ..ops.mamba2 import state_shape
+
+            return state_shape(self.value_heads, self.value_dim, self.key_dim)
+        return (self.value_heads, self.key_dim, self.value_dim)
 
 
 @dataclass(frozen=True)
@@ -286,6 +309,19 @@ class LlamaConfig:
     # of every page: layer 0 of the pool, the main layers behind it
     # (``page_layers``). 0 = none, and nothing of this is in any program.
     num_nextn_predict_layers: int = 0
+    # Granite's four scalars (1 / 0 for every other model, whose programs
+    # hold nothing of them): the embedding's rows times
+    # ``embedding_multiplier``; what each sub-layer adds to the residual
+    # times ``residual_multiplier``; attention's scores times
+    # ``attention_multiplier`` in place of ``head_dim ** -0.5`` (0: that);
+    # the logits over ``logits_scaling``. The residual's is applied where a
+    # sub-layer's output joins it (``_sublayer_out``); the other three reach
+    # the shared body as a view of the parameters (``multiplied``), which
+    # ``with_state`` hands it.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.num_nextn_predict_layers:
@@ -307,10 +343,29 @@ class LlamaConfig:
             # or of GQA keys and values; the linear layers' decay is a
             # scalar a head or a value a key channel: the hybrids of
             # states and pages that are built.
-            if self.linear.decay not in ("head", "channel"):
+            if self.linear.decay not in ("head", "channel", "mamba2"):
                 raise ValueError(
                     f"linear.decay {self.linear.decay!r}: a scalar a head "
-                    f"(\"head\") or a value a key channel (\"channel\")")
+                    f"(\"head\") or a value a key channel (\"channel\"), "
+                    f"both delta rules, or a state-space layer "
+                    f"(\"mamba2\")")
+            if self.linear.decay == "mamba2":
+                for name, plain in (("gate_scale", 1.0), ("beta_scale", 1.0),
+                                    ("gate_rank", 0)):
+                    if getattr(self.linear, name) != plain:
+                        raise ValueError(
+                            f"linear.{name} {getattr(self.linear, name)!r} "
+                            f"is a delta rule's: a mamba2 layer has no such "
+                            f"term (leave it at {plain!r})")
+                if self.linear.key_heads != 1:
+                    raise NotImplementedError(
+                        f"linear.key_heads {self.linear.key_heads} "
+                        f"(mamba_n_groups): one group of B and C for all "
+                        f"heads is what is built")
+                if self.is_mla or self.norm_offset or self.post_norms:
+                    raise NotImplementedError(
+                        "a mamba2 layer is built beside GQA pages under "
+                        "plain pre-norm")
             if self.linear.decay == "channel" and (
                     self.linear.key_heads != self.linear.value_heads
                     or self.linear.gate_rank <= 0):
@@ -377,15 +432,16 @@ class LlamaConfig:
         if self.moe_layers and not all(
                 0 <= i < self.num_layers for i in self.moe_layers):
             raise ValueError("moe_layers indices out of range")
-        if self.moe_dispatch == "grouped" and not (
-                self.moe_router and self.moe_router[0] == "deepseek_v3"):
+        if self.moe_dispatch == "grouped" and not self.routes_by_share:
             raise ValueError(
-                "moe_dispatch='grouped' serves the deepseek_v3 router only")
+                "moe_dispatch='grouped' serves the deepseek_v3 router and "
+                "('softmax_topk', 1), the softmax over the chosen logits")
         if self.experts_held:
-            if not (self.moe_router and self.moe_router[0] == "deepseek_v3"):
+            if not self.routes_by_share:
                 raise ValueError(
                     "experts_held (a chip's share of an expert layer) is "
-                    "implemented for the deepseek_v3 router")
+                    "implemented for the deepseek_v3 router and for "
+                    "('softmax_topk', 1) under moe_dispatch='grouped'")
             first, count = self.experts_held
             if count < 1 or first < 0 or first + count > self.num_experts:
                 raise ValueError(
@@ -436,6 +492,17 @@ class LlamaConfig:
             # makes that shardable; MLA serves unfused under tp.
             raise ValueError(
                 "fused_interleave > 1 is not supported for MLA configs")
+        if self.has_multipliers:
+            if not self.linear_layers:
+                raise NotImplementedError(
+                    "embedding_multiplier, attention_multiplier and "
+                    "logits_scaling reach the programs through with_state: "
+                    "a model without linear layers is not served with them")
+            if self.is_mla or self.qk_norm or self.fused_interleave != 1:
+                raise NotImplementedError(
+                    "attention_multiplier scales plain GQA queries: with "
+                    "latent attention, a norm on q or an interleaved fused "
+                    "layout it is not built")
         if self.attention_sinks:
             if self.sliding_window is None:
                 raise ValueError("attention_sinks requires sliding_window")
@@ -498,14 +565,31 @@ class LlamaConfig:
         return self.experts_held[1] if self.experts_held else self.num_experts
 
     @property
+    def routes_by_share(self) -> bool:
+        """A router whose layer a chip may hold a share of
+        (``experts_held``) and serve by the exact grouped dispatch:
+        DeepSeek-V3's, always; the softmax over the chosen logits
+        (``("softmax_topk", 1)``) where ``moe_dispatch`` is "grouped"."""
+        if not self.moe_router:
+            return False
+        return self.moe_router[0] == "deepseek_v3" or (
+            tuple(self.moe_router) == ("softmax_topk", 1)
+            and self.moe_dispatch == "grouped")
+
+    @property
+    def has_multipliers(self) -> bool:
+        """Whether ``multiplied`` changes anything of the parameters."""
+        return (self.embedding_multiplier != 1.0 or self.logits_scaling != 1.0
+                or bool(self.attention_multiplier))
+
+    @property
     def step_counters(self) -> tuple:
         """What a step program of this model counts on the device and
         hands back behind its sampled tokens (``step_program``): of a
         routed model, the assignments (token x chosen expert, real tokens
         only) that fell to the experts held, and the experts they touched,
         both summed over the routed layers."""
-        routed = (self.num_experts > 0 and self.moe_router
-                  and self.moe_router[0] == "deepseek_v3")
+        routed = self.num_experts > 0 and self.routes_by_share
         return ("assignments_held", "experts_touched") if routed else ()
 
     @property
@@ -679,7 +763,9 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
     if cfg.attn_output_gate and not linear:
         layer["w_og"] = dense(jax.random.fold_in(lk[3], 1),
                               (h, cfg.num_heads * hd))
-    if linear:
+    if linear and cfg.linear.decay == "mamba2":
+        layer.update(_init_mamba2(lk[0], cfg))
+    elif linear:
         la = cfg.linear
         ck = jax.random.split(lk[0], 6)
         # The decay's parameters as the family initialises them: A in
@@ -767,6 +853,16 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
             "w_up": ffn(lk[5], (held, h, inter)),
             "w_down": dense(lk[6], (held, inter, h)),
         })
+        if (cfg.moe_router and cfg.moe_router[0] == "softmax_topk"
+                and cfg.n_shared_experts):
+            # An always-on MLP beside the experts, no bias on the choice.
+            sh = inter * cfg.n_shared_experts
+            skeys = jax.random.split(lk[7], 4)
+            layer.update({
+                "w_gate_sh": ffn(skeys[1], (h, sh)),
+                "w_up_sh": ffn(skeys[2], (h, sh)),
+                "w_down_sh": dense(skeys[3], (sh, h)),
+            })
         if cfg.moe_router and cfg.moe_router[0] == "deepseek_v3":
             # deepseek_v3: bias + shared expert
             sh = inter * max(cfg.n_shared_experts, 1)
@@ -792,6 +888,34 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
             "w_down": dense(lk[6], (cfg.intermediate_size, h)),
         })
     return layer
+
+
+def _init_mamba2(key: jax.Array, cfg: LlamaConfig) -> Params:
+    """A Mamba-2 mixer's own parameters (``_mamba2``): ``w_in`` gives ``[z |
+    x B C | dt]``; the conv's taps at 0.5 and its bias; the decay's
+    parameters as the family initialises them (``A`` in [1, 16), a step
+    log-uniform in [1e-3, 1e-1] through its inverse softplus: a head's
+    memory runs from a few tokens to thousands); the skip ``D`` at one;
+    the gated norm's weight over all inner channels."""
+    la = cfg.linear
+    ck = jax.random.split(key, 5)
+    step = jnp.exp(jax.random.uniform(
+        ck[3], (la.value_heads,), jnp.float32, math.log(1e-3),
+        math.log(1e-1)))
+    return {
+        "w_in": _dense_init(
+            ck[0], (cfg.hidden_size,
+                    la.inner + la.conv_channels + la.value_heads), cfg.dtype),
+        "conv_w": _dense_init(ck[1], (la.conv_kernel, la.conv_channels),
+                              jnp.float32, 0.5),
+        "conv_b": _dense_init(jax.random.fold_in(ck[1], 1),
+                              (la.conv_channels,), jnp.float32),
+        "A_log": jnp.log(jax.random.uniform(
+            ck[2], (la.value_heads,), jnp.float32, 1.0, 16.0)),
+        "dt_bias": jnp.log(jnp.expm1(step)),
+        "D": jnp.ones((la.value_heads,), jnp.float32),
+        "o_norm": _norm_init(ck[4], la.inner, cfg),
+    }
 
 
 def _interleave_concat(parts: list, t: int, axis: int = 1) -> jax.Array:
@@ -1066,14 +1190,16 @@ def init_kv_cache(cfg: LlamaConfig, num_pages: int,
 def init_state_pool(cfg: LlamaConfig) -> tuple[jax.Array, jax.Array]:
     """The pool of sequence states of a model with linear layers, beside
     its page pools: ``(recurrent [linear layers, slots + 1, value heads,
-    key_dim, value_dim] float32, conv [linear layers, slots + 1, conv_kernel
-    - 1, conv channels]`` in the model's type``)``. A slot holds one
+    key_dim, value_dim] float32`` (a Mamba-2 layer's tiles in the last
+    three places: ``LinearAttention.state_shape``)``, conv [linear layers,
+    slots + 1, conv_kernel - 1, conv channels]`` in the model's
+    type``)``. A slot holds one
     sequence's state in every linear layer: a running row's, or a snapshot
     at a block boundary. Slot 0 is the spare one (as page 0 is): rows that
     decode nothing and snapshots nobody asked for are written there."""
     la, n = cfg.linear, len(cfg.linear_layers)
-    return (jnp.zeros((n, cfg.state_slots + 1, la.value_heads, la.key_dim,
-                       la.value_dim), jnp.float32),
+    return (jnp.zeros((n, cfg.state_slots + 1, *la.state_shape),
+                      jnp.float32),
             jnp.zeros((n, cfg.state_slots + 1, la.conv_kernel - 1,
                        la.conv_channels), cfg.dtype))
 
@@ -1362,6 +1488,41 @@ def _moe_deepseek(mlp_in, layer, cfg, valid=None, kernel=None,
     return (out + shared).reshape(b, s, h)
 
 
+def _moe_softmax_share(mlp_in, layer, cfg, valid=None, kernel=None,
+                       counters=None):
+    """A chip's share of a layer routed by the softmax over the chosen
+    logits (Granite's gate, Mixtral's: ``("softmax_topk", 1)``): the
+    router's logits over all ``cfg.num_experts`` in float32, the ``k``
+    largest, their softmax; the terms of the experts this layer holds
+    (``cfg.experts_held``; all without it) by the exact grouped dispatch,
+    weighted over all ``k`` chosen, held or not; and the always-on MLP
+    where the layer has one. An expert chosen and not held adds nothing
+    here: its chip adds it. (``_moe_deepseek``'s lines for the dispatch and
+    the shared MLP stand here again: its frame lies under four cells'
+    programs and keeps its size.)"""
+    b, s, h = mlp_in.shape
+    x = mlp_in.reshape(b * s, h)
+    logits = x.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
+    top, idx = jax.lax.top_k(logits, cfg.num_experts_per_token)
+    first = cfg.experts_held[0] if cfg.experts_held else 0
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        out = _experts_grouped(x, layer, idx, jax.nn.softmax(top, axis=-1),
+                               first, valid, kernel, counters,
+                               cfg.swiglu_limit).astype(mlp_in.dtype)
+    if "w_gate_up_sh" in layer:  # fused serving layout (fuse_params)
+        sh_gu = (x @ layer["w_gate_up_sh"]).astype(jnp.float32)
+        sh_gate, sh_up = split_fused_out(
+            sh_gu, (sh_gu.shape[-1] // 2,) * 2, cfg.fused_interleave)
+    elif "w_gate_sh" in layer:
+        sh_gate = (x @ layer["w_gate_sh"]).astype(jnp.float32)
+        sh_up = (x @ layer["w_up_sh"]).astype(jnp.float32)
+    else:
+        return out.reshape(b, s, h)
+    shared = _swiglu(sh_gate, sh_up, cfg.swiglu_limit).astype(
+        x.dtype) @ layer["w_down_sh"]
+    return (out + shared).reshape(b, s, h)
+
+
 def _mlp(mlp_in: jax.Array, layer: dict, cfg: "LlamaConfig",
          aux_out: Any = None, valid: Any = None, kernel: Any = None,
          counters: Any = None) -> jax.Array:
@@ -1381,6 +1542,9 @@ def _mlp(mlp_in: jax.Array, layer: dict, cfg: "LlamaConfig",
         if cfg.moe_router and cfg.moe_router[0] == "deepseek_v3":
             return _moe_deepseek(mlp_in, layer, cfg, valid=valid,
                                  kernel=kernel, counters=counters)
+        if cfg.moe_dispatch == "grouped":
+            return _moe_softmax_share(mlp_in, layer, cfg, valid=valid,
+                                      kernel=kernel, counters=counters)
         if cfg.moe_dispatch == "capacity":
             return _moe_capacity(mlp_in, layer, cfg, aux_out, valid=valid)
         if cfg.moe_dispatch == "dense":
@@ -1477,7 +1641,8 @@ def _sublayer_out(out, gate_in, layer, cfg, which: str) -> jax.Array:
     residual. A mixer's output ``out`` goes through its output projection,
     gated first by ``sigmoid(gate_in W_g)`` where the layer has that gate
     (attention's: the heads' outputs, from the layer's normed input); with
-    ``cfg.post_norms`` the result is normed."""
+    ``cfg.post_norms`` the result is normed; what joins the residual is
+    that times ``cfg.residual_multiplier``."""
     if which == "attn":
         if "w_og" in layer:
             gate = jax.nn.sigmoid((gate_in @ layer["w_og"]).astype(
@@ -1487,6 +1652,9 @@ def _sublayer_out(out, gate_in, layer, cfg, which: str) -> jax.Array:
     if cfg.post_norms:
         out = _rms_norm(out, layer[which + "_post_norm"], cfg.norm_eps,
                         cfg.norm_offset)
+    if cfg.residual_multiplier != 1.0:
+        out = (out.astype(jnp.float32) * cfg.residual_multiplier).astype(
+            out.dtype)
     return out
 
 
@@ -1539,6 +1707,9 @@ def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
     row's state in place; ``kda_*`` where the decay is channel-wise); the
     heads' outputs normed per head and gated by ``gate_scale *
     sigmoid(z)``."""
+    if cfg.linear.decay == "mamba2":  # no delta rule: a section of its own
+        return _mamba2(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
+                       kernel)
     from ..ops import gated_deltanet as gd
 
     la = cfg.linear
@@ -1609,6 +1780,107 @@ def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
     o = o * (cfg.norm_offset + layer["o_norm"]) * (
         la.gate_scale * jax.nn.sigmoid(z.reshape(o.shape).astype(f32)))
     return (o.astype(x.dtype).reshape(b, s, la.inner),
+            (recurrent, conv, slots, snap))
+
+
+# -- a state-space layer (Mamba-2) -------------------------------------------
+# Reached from ``_gated_deltanet`` (what the shared body calls for a layer
+# that keeps a state), which keeps its own lines for the conv's tail and the
+# snapshots: its frame lies under two cells' programs.
+
+
+def _conv_through_tail(mixed, layer, conv, lj, slots, fresh, new_lens, snap,
+                       page_size):
+    """A depthwise causal conv (with its bias) and SiLU over ``mixed [b, s,
+    channels]``, its first taps reading each row's conv state (the last
+    ``taps - 1`` inputs of what came before; zeros for a ``fresh`` row):
+    ``(the conv's output in mixed's type, conv)`` with the rows' new tails
+    written under their slots and, for a chunk that leaves snapshots
+    (``snap``, one row), the tail after the requested block and the one at
+    the chunk's end under theirs."""
+    s = mixed.shape[1]
+    taps = layer["conv_w"].shape[0]
+    tail = jnp.where(fresh[:, None, None], 0, conv[lj, slots])
+    window = jnp.concatenate([tail.astype(mixed.dtype), mixed], axis=1)
+
+    def tail_at(n):
+        """The conv state after a row's first ``n [b]`` tokens."""
+        at = n[:, None] + jnp.arange(taps - 1)[None, :]
+        return jnp.take_along_axis(window, at[:, :, None], axis=1)
+
+    out = jax.nn.silu(sum(
+        window[:, j:j + s].astype(jnp.float32) * layer["conv_w"][j]
+        for j in range(taps)) + layer["conv_b"]).astype(mixed.dtype)
+    new_tail = tail_at(new_lens).astype(conv.dtype)
+    conv = conv.at[lj, slots].set(new_tail)
+    if snap is not None:
+        conv = conv.at[lj, snap[1]].set(tail_at(
+            (snap[0:1] + 1) * page_size)[0].astype(conv.dtype))
+        conv = conv.at[lj, snap[2]].set(new_tail[0])
+    return out, conv
+
+
+def _mamba2(x, layer, cfg, lj, state, valid, ctx_lens, new_lens, kernel):
+    """A Mamba-2 mixer over ``x [b, s, h]`` (the layer's normed input), as
+    ``_gated_deltanet`` returns: ``(the heads' outputs [b, s, inner] before
+    the output projection, the state with this layer's part updated)``.
+
+    ``[z | x B C | dt] = x W_in``; a depthwise causal conv with a bias over
+    ``x B C`` and SiLU; the step ``d = softplus(dt + dt_bias)`` a head (0
+    at a padded token: the state stays), ``A = -exp(A_log)``; the
+    recurrence ``S <- exp(d A) S + d x (x) B``, ``y = S C + D x``
+    (``ops.mamba2``: a chunk is scanned in blocks of a page, a decode step
+    updates every row's state in place); ``y silu(z)`` normed over all
+    inner channels."""
+    from ..ops import mamba2 as m2
+
+    la = cfg.linear
+    f32 = jnp.float32
+    b, s, _ = x.shape
+    recurrent, conv, slots, snap = state
+    inner, n = la.inner, la.key_dim
+    use = dict(kernel=kernel is not None,
+               interpret=bool(kernel and kernel["interpret"]))
+    if snap is not None and b != 1:
+        raise ValueError("a chunk that leaves snapshots is one row")
+
+    zxd = x @ layer["w_in"]
+    z = zxd[..., :inner].astype(f32)
+    dt = jnp.where(valid[..., None], jax.nn.softplus(
+        zxd[..., inner + la.conv_channels:].astype(f32) + layer["dt_bias"]),
+        0.0)
+    fresh = ctx_lens == 0                                          # [b]
+    mixed, conv = _conv_through_tail(
+        zxd[..., inner:inner + la.conv_channels], layer, conv, lj, slots,
+        fresh, new_lens, snap, cfg.page_size)
+    xs = mixed[..., :inner].reshape(b, s, la.value_heads, la.value_dim)
+    bs, cs = mixed[..., inner:inner + n], mixed[..., inner + n:]
+    a = -jnp.exp(layer["A_log"])
+
+    if s == 1:
+        with jax.named_scope(m2.KERNEL_STEP):
+            y, recurrent = m2.mamba2_step(
+                recurrent, lj, slots, xs[:, 0], bs[:, 0], cs[:, 0], dt[:, 0],
+                a, layer["D"], **use)
+        y = y[:, None]
+    else:
+        outs = []
+        with jax.named_scope(m2.KERNEL_SCAN):
+            for i in range(b):
+                first = jnp.where(fresh[i], 0.0, recurrent[lj, slots[i]])
+                y_i, end, at_block = m2.mamba2_scan(
+                    xs[i], bs[i], cs[i], dt[i], a, layer["D"], first,
+                    -1 if snap is None else snap[0], block=cfg.page_size,
+                    **use)
+                recurrent = recurrent.at[lj, slots[i]].set(end)
+                outs.append(y_i)
+            if snap is not None:
+                recurrent = recurrent.at[lj, snap[1]].set(at_block)
+                recurrent = recurrent.at[lj, snap[2]].set(end)
+        y = jnp.stack(outs)
+    y = y.reshape(b, s, inner) * jax.nn.silu(z)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + la.norm_eps)
+    return ((y * layer["o_norm"]).astype(x.dtype),
             (recurrent, conv, slots, snap))
 
 
@@ -2360,6 +2632,59 @@ def step_program(body, static=(), kept_row=None):
         donate_argnames=("pools",))
 
 
+class _Scaled:
+    """Stands where the shared body looks for a matrix: the matrix with a
+    scale that is applied in float32 before the result is rounded to the
+    model's type. As an embedding (``[rows]``) its rows times ``scale``; as
+    a projection (``x @ .``) the product's first ``upto`` columns (all of
+    them: None) times ``scale``."""
+
+    def __init__(self, w, scale, upto=None):
+        self.w, self.scale, self.upto = w, scale, upto
+
+    def __getitem__(self, rows):
+        return (self.w[rows].astype(jnp.float32) * self.scale).astype(
+            self.w.dtype)
+
+    def __rmatmul__(self, x):
+        y = jnp.matmul(x, self.w, preferred_element_type=jnp.float32)
+        if self.upto is None:
+            y = y * self.scale
+        else:
+            y = jnp.where(jnp.arange(y.shape[-1]) < self.upto,
+                          y * self.scale, y)
+        return y.astype(x.dtype)
+
+
+def multiplied(params: Params, cfg: LlamaConfig) -> Params:
+    """``params`` as the shared body reads them under Granite's scalars
+    (``LlamaConfig.embedding_multiplier`` ...): the embedding's rows times
+    ``embedding_multiplier``, the logits over ``logits_scaling``, and in
+    the layers that attend the queries times ``attention_multiplier *
+    head_dim ** 0.5``, since the attention kernels scale scores by
+    ``head_dim ** -0.5`` themselves (``wq``, or the query columns of a fused
+    ``w_qkv``). Each in float32 on the product, before it is rounded.
+    ``params`` itself for every other model: its programs hold nothing of
+    this."""
+    if not cfg.has_multipliers:
+        return params
+    view = {**params,
+            "embed": _Scaled(params["embed"], cfg.embedding_multiplier),
+            "lm_head": _Scaled(params["lm_head"], 1.0 / cfg.logits_scaling)}
+    if cfg.attention_multiplier:
+        q_scale = cfg.attention_multiplier * cfg.head_dim ** 0.5
+        layers = []
+        for layer in params["layers"]:
+            if "wq" in layer:
+                layer = {**layer, "wq": _Scaled(layer["wq"], q_scale)}
+            elif "w_qkv" in layer:
+                layer = {**layer, "w_qkv": _Scaled(
+                    layer["w_qkv"], q_scale, cfg.num_heads * cfg.head_dim)}
+            layers.append(layer)
+        view["layers"] = layers
+    return view
+
+
 def _last_ragged_row(_table, row_starts, _ctx_lens):
     """The last row that holds tokens: the prefill chunk, when a ragged
     step carries one."""
@@ -2370,13 +2695,15 @@ def with_state(body):
     """``body`` (a forward above) as a model with linear layers is stepped:
     its state pool behind the page pools (donated with them), each row's
     slot and the chunk's snapshot request (``[block, slot, end_slot]``;
-    zeros on a decode step) behind its per-step arrays. Under the body's
-    own name: a trace tells programs by it."""
+    zeros on a decode step) behind its per-step arrays; the parameters as
+    ``multiplied`` views them (themselves, but for a model with Granite's
+    scalars). Under the body's own name: a trace tells programs by it."""
     def stateful(params, cfg, tokens, k_cache, v_cache, recurrent, conv,
                  page_table, ctx_lens, new_lens, slots, snap, counters=None,
                  **kw):
         logits, k_cache, v_cache, (recurrent, conv) = body(
-            params, cfg, tokens, k_cache, v_cache, page_table, ctx_lens,
+            multiplied(params, cfg), cfg, tokens, k_cache, v_cache,
+            page_table, ctx_lens,
             new_lens, counters=counters,
             state=(recurrent, conv, slots,
                    snap if tokens.shape[1] > 1 else None), **kw)

@@ -1,0 +1,326 @@
+"""Mamba-2: the recurrence of a state-space layer whose cache is one state a
+sequence, not a page a block of tokens.
+
+Per head ``h`` (of ``H``, each ``P`` channels wide), with the step ``d_t =
+softplus(dt_t + dt_bias) > 0``, ``A_h < 0`` and one ``B_t, C_t [N]`` shared
+by all heads (one group)::
+
+    S_t = exp(d_t A) S_{t-1} + d_t x_t (x) B_t          S [P, N] a head
+    y_t = S_t C_t + D x_t
+
+No delta term and no inverse (``ops.gated_deltanet`` has those): a token's
+write is an outer product and nothing of the state is read back into it.
+
+**The state's layout.** ``P`` is 64 where a register's lanes are 128, so a
+state is kept as tiles ``[N, W]`` of ``pack = W / P`` heads side by side
+(``heads_per_tile``): tile ``g``, lane ``j * P + p`` is channel ``p`` of
+head ``g * pack + j``, which is where ``x`` flattened to ``[H * P]`` has it.
+A state of ``[H, P, N]`` is ``[G, N, W]`` in the pool (``state_shape``,
+``pack_state`` / ``unpack_state``), every lane of every tile used; as ``[H,
+N, P]`` the pool's last axis would be padded to the lanes and weigh twice
+as much.
+
+Two forms, each in XLA (what the CPU serves and the kernels are tested
+against) and as a Pallas kernel (what a TPU serves):
+
+- ``mamba2_scan``: a chunk of tokens of one sequence, in blocks of
+  ``block`` tokens (the page): the blocked (SSD) form of the recurrence.
+  Inside a block, for a tile's heads: ``C B^T`` (computed once for all
+  heads) under each head's decay mask times ``d x``; between blocks the
+  carried state, read by ``C`` and written by ``B^T (d x)``. It returns the
+  state at the chunk's end and at the end of one requested block: a
+  snapshot at a block boundary costs no second pass and splits no chunk.
+  A decay over a block reaches ``e^-50`` and beyond, so every decay is the
+  ``exp`` of a difference of the block's cumulative log-decays that is at
+  most 0 (``gc_i - gc_j`` for ``j <= i``, ``gc_last - gc_j``, ``gc_i``),
+  never a quotient of two ``exp`` that each may overflow.
+- ``mamba2_step``: one token of every row of a decode batch, the rows'
+  states updated in place in the (donated) pool under their slot ids.
+
+Every product is float32 at ``Precision.HIGHEST`` and the state stays
+float32. The jitted wrappers' names are what a device trace calls the
+kernels (``mamba2_scan.<n>``, ``mamba2_step.<n>``); readers of traces match
+them.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .gated_deltanet import _HIGHEST, _column, _dot, _dot_nt, _dot_tn
+
+KERNEL_SCAN = "mamba2_scan"
+KERNEL_STEP = "mamba2_step"
+
+_LANES = 128
+
+
+def heads_per_tile(heads: int, head_dim: int) -> int:
+    """Heads that share a state tile's lanes."""
+    return math.gcd(heads, max(1, _LANES // head_dim))
+
+
+def state_shape(heads: int, head_dim: int, state_dim: int) -> tuple:
+    """``(G, N, W)``: a sequence's state in one layer as the pool holds
+    it."""
+    pack = heads_per_tile(heads, head_dim)
+    return (heads // pack, state_dim, pack * head_dim)
+
+
+def pack_state(s: jax.Array) -> jax.Array:
+    """``S [H, P, N]`` as the pool's tiles ``[G, N, W]``."""
+    h, p, n = s.shape
+    pack = heads_per_tile(h, p)
+    return s.reshape(h // pack, pack, p, n).transpose(0, 3, 1, 2).reshape(
+        h // pack, n, pack * p)
+
+
+def unpack_state(tiles: jax.Array, head_dim: int) -> jax.Array:
+    """``pack_state`` undone: ``[G, N, W] -> [H, P, N]``."""
+    g, n, w = tiles.shape
+    pack = w // head_dim
+    return tiles.reshape(g, n, pack, head_dim).transpose(0, 2, 3, 1).reshape(
+        g * pack, head_dim, n)
+
+
+def _block_update(x, b, c, cb, gd, skip, st, *, pack):
+    """One block of one tile's heads. ``x [c, W]`` the tokens' inputs, ``b,
+    c [c, N]``, ``cb = c b^T [c, c]``, ``skip [1, W]`` (``D`` over the
+    lanes), ``st [N, W]`` the state before the block, all float32; ``gd [2
+    pack, c]``: for head ``j`` of the tile, row ``2 j`` the log-decay
+    summed from the block's first token to each token (inclusive), row ``2
+    j + 1`` the step ``d``. Returns ``(y [c, W], st')``. A padded token has
+    ``d = 0``: it leaves the state as it was."""
+    n_tok, w = x.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_tok, n_tok), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_tok, n_tok), 1)
+    eye = (row == col).astype(jnp.float32)
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // (w // pack)
+    # The heads' log-decays and steps over their own lanes, and each
+    # head's pairs: exp(gc_i - gc_j) for j <= i, else 0 (never exp of a
+    # positive number).
+    gl = jnp.zeros((n_tok, w), jnp.float32)
+    dl = jnp.zeros((n_tok, w), jnp.float32)
+    pairs = []
+    for j in range(pack):
+        gc = gd[2 * j:2 * j + 1]
+        gcol = _column(eye, gc)
+        gl = jnp.where(lane_head == j, gcol, gl)
+        dl = jnp.where(lane_head == j, _column(eye, gd[2 * j + 1:2 * j + 2]),
+                       dl)
+        pairs.append(cb * jnp.exp(jnp.where(row >= col, gcol - gc,
+                                            -jnp.inf)))
+    xd = x * dl
+    y = _dot(c, st) * jnp.exp(gl) + x * skip
+    for j in range(pack):
+        y = y + _dot(pairs[j], jnp.where(lane_head == j, xd, 0.0))
+    g_last = gl[n_tok - 1:n_tok]
+    st = st * jnp.exp(g_last) + _dot_tn(b, xd * jnp.exp(g_last - gl))
+    return y, st
+
+
+def _scan_xla(xw, b, c, cb, gd, skip, st0, snap_block, pack):
+    t, hp = xw.shape
+    g, nb = gd.shape[:2]
+    block = t // nb
+    per_tile = jax.vmap(functools.partial(_block_update, pack=pack),
+                        in_axes=(0, None, None, None, 0, 0, 0))
+
+    def body(carry, xs):
+        st, snap = carry
+        i, x_i, b_i, c_i, cb_i, gd_i = xs
+        y, st = per_tile(x_i.astype(jnp.float32), b_i, c_i, cb_i, gd_i, skip,
+                         st)
+        return (st, jnp.where(i == snap_block, st, snap)), y
+
+    (st, snap), y = jax.lax.scan(
+        body, (st0, st0),
+        (jnp.arange(nb),
+         xw.reshape(nb, block, g, hp // g).transpose(0, 2, 1, 3),
+         b.reshape(nb, block, -1), c.reshape(nb, block, -1), cb,
+         gd.transpose(1, 0, 2, 3)))
+    return y.transpose(0, 2, 1, 3).reshape(t, hp), st, snap
+
+
+def _scan_kernel(snap_ref, x_ref, b_ref, c_ref, cb_ref, gd_ref, skip_ref,
+                 st0_ref, y_ref, end_ref, snap_out_ref, st_scr, *, nb, pack):
+    blk = pl.program_id(1)
+
+    @pl.when(blk == 0)
+    def _():
+        st_scr[...] = st0_ref[0]
+        snap_out_ref[0] = st0_ref[0]
+
+    y, st = _block_update(x_ref[...].astype(jnp.float32), b_ref[...],
+                          c_ref[...], cb_ref[0], gd_ref[0, 0], skip_ref[0],
+                          st_scr[...], pack=pack)
+    y_ref[...] = y
+    st_scr[...] = st
+
+    @pl.when(blk == snap_ref[0])
+    def _():
+        snap_out_ref[0] = st
+
+    @pl.when(blk == nb - 1)
+    def _():
+        end_ref[0] = st
+
+
+def _scan_pallas(xw, b, c, cb, gd, skip, st0, snap_block, pack, interpret):
+    t, hp = xw.shape
+    g, nb = gd.shape[:2]
+    block = t // nb
+    n, w = st0.shape[1:]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(g, nb),
+        in_specs=[
+            pl.BlockSpec((block, w), lambda i, k, *_: (k, i)),
+            pl.BlockSpec((block, n), lambda i, k, *_: (k, 0)),
+            pl.BlockSpec((block, n), lambda i, k, *_: (k, 0)),
+            pl.BlockSpec((1, block, block), lambda i, k, *_: (k, 0, 0)),
+            pl.BlockSpec((1, 1, 2 * pack, block),
+                         lambda i, k, *_: (i, k, 0, 0)),
+            pl.BlockSpec((1, 1, w), lambda i, k, *_: (i, 0, 0)),
+            pl.BlockSpec((1, n, w), lambda i, k, *_: (i, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((block, w), lambda i, k, *_: (k, i)),
+            pl.BlockSpec((1, n, w), lambda i, k, *_: (i, 0, 0)),
+            pl.BlockSpec((1, n, w), lambda i, k, *_: (i, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((n, w), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_scan_kernel, nb=nb, pack=pack),
+        out_shape=[jax.ShapeDtypeStruct((t, hp), jnp.float32),
+                   jax.ShapeDtypeStruct(st0.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(st0.shape, jnp.float32)],
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(snap_block, (1,)).astype(jnp.int32), xw, b, c, cb, gd,
+      skip, st0)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "kernel", "interpret"))
+def mamba2_scan(x, B, C, dt, A, D, state, snap_block, block: int,
+                kernel: bool = False, interpret: bool = False):
+    """A chunk of one sequence. ``x [T, H, P]`` (after the conv and its
+    SiLU), ``B, C [T, N]``, ``dt [T, H]`` the steps (after the softplus; 0
+    at a padded token), ``A, D [H]`` (``A`` negative), ``state [G, N, W]``
+    float32 before the chunk (``state_shape``); ``T`` a whole number of
+    blocks. Returns ``(y [T, H, P] float32, the state after the chunk, the
+    state after block snap_block)``; the last is the state before the chunk
+    where ``snap_block`` names no block."""
+    t, h, p = x.shape
+    if t % block:
+        raise ValueError(f"a chunk of {t} tokens is not a whole number of "
+                         f"blocks of {block}")
+    f32 = jnp.float32
+    nb = t // block
+    pack = heads_per_tile(h, p)
+    g = h // pack
+    dt = dt.astype(f32)
+    gc = jnp.cumsum((dt * A.astype(f32)).reshape(nb, block, h), axis=1)
+    # [G, nb, 2 pack, block]: a tile's heads' rows, each its running
+    # log-decay and then its step.
+    gd = jnp.stack([gc, dt.reshape(nb, block, h)], axis=-1).reshape(
+        nb, block, g, 2 * pack).transpose(2, 0, 3, 1)
+    b, c = B.astype(f32), C.astype(f32)
+    cb = jax.vmap(_dot_nt)(c.reshape(nb, block, -1), b.reshape(nb, block, -1))
+    skip = jnp.repeat(D.astype(f32), p).reshape(g, 1, pack * p)
+    xw = x.reshape(t, h * p)
+    if kernel:
+        y, st, snap = _scan_pallas(xw, b, c, cb, gd, skip, state, snap_block,
+                                   pack, interpret)
+    else:
+        y, st, snap = _scan_xla(xw, b, c, cb, gd, skip, state, snap_block,
+                                pack)
+    return y.reshape(t, h, p), st, snap
+
+
+def _step_kernel(slot_ref, layer_ref, a_ref, dx_ref, b_ref, c_ref, pool_ref,
+                 y_ref, out_ref, *, tiles):
+    del slot_ref, layer_ref  # the index maps read them
+    n = b_ref.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    eye = (row == col).astype(jnp.float32)
+    bcol, ccol = _column(eye, b_ref[0]), _column(eye, c_ref[0])
+    for j in range(tiles):
+        at = slice(j, j + 1)
+        st = pool_ref[0, 0, j] * a_ref[0, at, :] + bcol * dx_ref[0, at, :]
+        out_ref[0, 0, j] = st
+        y_ref[0, at, :] = jnp.sum(ccol * st, axis=0, keepdims=True)
+
+
+def _step_pallas(pool, layer, slots, a, dx, b, c, interpret):
+    rows, g, w = a.shape
+    n = b.shape[-1]
+    tiles = 8 if g % 8 == 0 else g
+
+    def lanes(r, j, *_):
+        return (r, j, 0)
+
+    def once(r, j, *_):
+        return (r, 0, 0)
+
+    def state(r, j, slot_ref, layer_ref):
+        return (layer_ref[0], slot_ref[r], j, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(rows, g // tiles),
+        in_specs=[pl.BlockSpec((1, tiles, w), lanes),
+                  pl.BlockSpec((1, tiles, w), lanes),
+                  pl.BlockSpec((1, 1, n), once),
+                  pl.BlockSpec((1, 1, n), once),
+                  pl.BlockSpec((1, 1, tiles, n, w), state)],
+        out_specs=[pl.BlockSpec((1, tiles, w), lanes),
+                   pl.BlockSpec((1, 1, tiles, n, w), state)],
+    )
+    return pl.pallas_call(
+        functools.partial(_step_kernel, tiles=tiles),
+        out_shape=[jax.ShapeDtypeStruct((rows, g, w), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        grid_spec=grid_spec,
+        # Operand 6 (behind the two scalars) is the pool: updated in place.
+        input_output_aliases={6: 1},
+        interpret=interpret,
+    )(slots.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      a, dx, b[:, None, :], c[:, None, :], pool)
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "interpret"),
+                   donate_argnames=("pool",))
+def mamba2_step(pool, layer, slots, x, B, C, dt, A, D, kernel: bool = False,
+                interpret: bool = False):
+    """One token of every row. ``pool [layers, slots, G, N, W]`` float32
+    (donated; row ``r``'s state is ``pool[layer, slots[r]]``, and rows that
+    decode nothing share the spare slot 0 and hand in ``dt = 0``, which
+    leaves a state as it was), ``x [rows, H, P]``, ``B, C [rows, N]``, ``dt
+    [rows, H]`` (after the softplus), ``A, D [H]``. Returns ``(y [rows, H,
+    P] float32, pool)``."""
+    f32 = jnp.float32
+    rows, h, p = x.shape
+    g, _, w = pool.shape[2:]
+    x, dt = x.astype(f32), dt.astype(f32)
+    a = jnp.repeat(jnp.exp(dt * A.astype(f32)), p, axis=1).reshape(rows, g, w)
+    dx = (dt[..., None] * x).reshape(rows, g, w)
+    b, c = B.astype(f32), C.astype(f32)
+    if kernel:
+        y, pool = _step_pallas(pool, layer, slots, a, dx, b, c, interpret)
+    else:
+        st = (pool[layer, slots] * a[:, :, None, :]
+              + b[:, None, :, None] * dx[:, :, None, :])
+        y = jnp.einsum("rn,rgnw->rgw", c, st, precision=_HIGHEST)
+        pool = pool.at[layer, slots].set(st)
+    return y.reshape(rows, h, p) + D.astype(f32)[:, None] * x, pool

@@ -252,3 +252,39 @@ def test_channelwise_recurrence_step(shaped):
              shaped((ROWS, KDA_HEADS, GDN_DIM)),
              shaped((ROWS, KDA_HEADS, GDN_DIM), f32),
              shaped((ROWS, KDA_HEADS), f32))
+
+
+# ``granite-4.0-h-small-ep2-l10``: 9 Mamba-2 layers, 41 slots, 128 heads of
+# 64 channels over a state of 128 (two heads a tile of 128 lanes), 16 rows.
+M2_LAYERS, M2_SLOTS, M2_HEADS, M2_HEAD, M2_STATE, M2_ROWS = (
+    9, 41, 128, 64, 128, 16)
+
+
+def test_mamba2_scan(shaped):
+    """A chunk of 512 tokens in blocks of 64: a tile's two heads' pairs
+    under their decay masks and the carried state, as matrix products."""
+    from llmd_kv_cache_tpu.ops.mamba2 import mamba2_scan, state_shape
+
+    f32 = jnp.float32
+    compiles(functools.partial(mamba2_scan, block=PAGE, kernel=True),
+             shaped((CHUNK, M2_HEADS, M2_HEAD)),
+             shaped((CHUNK, M2_STATE)), shaped((CHUNK, M2_STATE)),
+             shaped((CHUNK, M2_HEADS), f32), shaped((M2_HEADS,), f32),
+             shaped((M2_HEADS,), f32),
+             shaped(state_shape(M2_HEADS, M2_HEAD, M2_STATE), f32),
+             shaped((), jnp.int32))
+
+
+def test_mamba2_step(shaped):
+    """16 rows' states decayed a head and written to in place."""
+    from llmd_kv_cache_tpu.ops.mamba2 import mamba2_step, state_shape
+
+    f32 = jnp.float32
+    compiles(functools.partial(mamba2_step, kernel=True),
+             shaped((M2_LAYERS, M2_SLOTS,
+                     *state_shape(M2_HEADS, M2_HEAD, M2_STATE)), f32),
+             shaped((), jnp.int32), shaped((M2_ROWS,), jnp.int32),
+             shaped((M2_ROWS, M2_HEADS, M2_HEAD)),
+             shaped((M2_ROWS, M2_STATE)), shaped((M2_ROWS, M2_STATE)),
+             shaped((M2_ROWS, M2_HEADS), f32), shaped((M2_HEADS,), f32),
+             shaped((M2_HEADS,), f32))
