@@ -1390,6 +1390,46 @@ def _experts_dense(x, layer, idx, w, first, limit=0.0):
 # Rows a grouped matmul's tile holds: the assignments are padded to a
 # whole number of them.
 _GMM_ROWS = 128
+# What one grid step of ``gmm`` may hold in VMEM, of Mosaic's default 16 MiB
+# of scoped VMEM (megablox passes no ``vmem_limit_bytes``), and the largest
+# piece of an expert's matrix it moves.
+_GMM_VMEM_BYTES = 12 << 20
+_GMM_PIECE_BYTES = 3 << 20
+
+
+def gmm_vmem_bytes(tm, tk, tn, itemsize):
+    """What ``gmm`` keeps in VMEM under a tiling: two buffers each of the
+    ``[tk, tn]`` weight piece, the ``[tm, tk]`` rows and the ``[tm, tn]``
+    float32 output, and the float32 accumulator."""
+    return 2 * tk * tn * itemsize + 2 * tm * tk * itemsize + 3 * tm * tn * 4
+
+
+def gmm_tiling(m, k, n, itemsize):
+    """``(tm, tk, tn)`` for ``[m, k]`` rows times experts of ``[k, n]``, from
+    the shapes alone. ``gmm`` moves one ``[tk, tn]`` piece of one expert's
+    matrix a grid step; the pieces tile the matrix in multiples of 128 lanes
+    (an axis that is no multiple goes whole), weigh at most
+    ``_GMM_PIECE_BYTES`` and fit ``_GMM_VMEM_BYTES`` with the rows and the
+    output beside them. ``k`` goes whole where a piece of twice the rows'
+    tile in lanes then fits: no accumulator pass, and a group that straddles
+    two row tiles keeps its piece, for the rows read again once a tile of
+    ``n``. Else, and among those, the largest piece, the wider in ``n`` of
+    equals (a wider output meets the accumulator less often). What decided:
+    ``hack/bench_gmm.py --sweep`` (PERF.md §6, PR 58)."""
+    del m  # under 128 rows the MXU's time is the weights' own: no gain
+    tm = _GMM_ROWS
+
+    def tiles(axis):
+        return [d for d in range(128, axis + 1, 128) if axis % d == 0] or [
+            axis]
+
+    fit = [(tk, tn) for tk in tiles(k) for tn in tiles(n)
+           if tk * tn * itemsize <= _GMM_PIECE_BYTES
+           and gmm_vmem_bytes(tm, tk, tn, itemsize) <= _GMM_VMEM_BYTES]
+    tk, tn = max(fit or [(tiles(k)[0], tiles(n)[0])],
+                 key=lambda p: (p[0] == k and p[1] >= 2 * tm,
+                                p[0] * p[1], p[1]))
+    return tm, tk, tn
 
 
 def _grouped_matmul(lhs, rhs, group_sizes, kernel):
@@ -1399,18 +1439,21 @@ def _grouped_matmul(lhs, rhs, group_sizes, kernel):
     ``ragged_dot`` (the XLA programs), else ``{"interpret": bool}`` → the
     Pallas grouped matmul (megablox ``gmm``), which visits the tiles that
     hold rows of a group and reads the weights of the groups they touch:
-    work and bytes follow the assignments. On one v5e at DeepSeek-V3.2's
-    widths with 16 experts held the three matmuls of a layer take 0.53 ms
-    with ``gmm`` and 0.63 with ``ragged_dot`` for a decode step's 8 tokens,
-    2.8 and 5.2 ms for a chunk of 512 (PERF.md, PR 34); off the chip
-    ``gmm`` would run interpreted."""
+    work and bytes follow the assignments, in pieces that ``gmm_tiling``
+    sizes to the expert. On one v5e a layer's three matmuls take 0.48 ms
+    at granite-4.0-h-small's widths with 36 experts held for a decode
+    step of 5 tokens (90% of the touched experts' bytes; 0.76 ms in the
+    256-512 KB pieces of the rule before) and 2.09 ms at DeepSeek-V3.2's
+    with 16 held for a chunk of 512 (83%; 2.26): ``hack/bench_gmm.py``'s
+    table, PERF.md §6, PR 58. Off the chip ``gmm`` would run
+    interpreted."""
     if kernel is None:
         return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                                   preferred_element_type=jnp.float32)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     k, n = rhs.shape[1:]
-    tiling = (_GMM_ROWS, math.gcd(k, 512), math.gcd(n, 1024))
+    tiling = gmm_tiling(lhs.shape[0], k, n, rhs.dtype.itemsize)
     return gmm(lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
                tiling=tiling, interpret=kernel["interpret"])
 

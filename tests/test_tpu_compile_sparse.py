@@ -172,12 +172,23 @@ def test_draft_acceptance(shaped):
              shaped((ROWS,), jnp.int32), shaped((ROWS,), jnp.int32))
 
 
-@pytest.mark.parametrize("m,k,n", [(4096, 7168, 2048), (128, 2048, 7168)],
-                         ids=["up-of-a-chunk", "down-of-a-step"])
-def test_grouped_matmul(shaped, m, k, n):
+@pytest.mark.parametrize("m,k,n,held", [
+    pytest.param(4096, 7168, 2048, 16, id="up-of-a-chunk"),
+    pytest.param(128, 2048, 7168, 16, id="down-of-a-step"),
+    pytest.param(256, 4096, 768, 36, id="granite-up-of-a-step"),
+    pytest.param(5120, 4096, 768, 36, id="granite-up-of-a-chunk"),
+    pytest.param(256, 768, 4096, 36, id="granite-down-of-a-step"),
+    pytest.param(4096, 4096, 1280, 20, id="solar-up-of-a-chunk"),
+    pytest.param(128, 1280, 4096, 20, id="solar-down-of-a-step"),
+    pytest.param(128, 2048, 7680, 8, id="openpangu-down-of-a-step"),
+])
+def test_grouped_matmul(shaped, m, k, n, held):
+    """The tiles ``llama.gmm_tiling`` gives each configuration's experts:
+    one that Mosaic refuses (lanes, scoped VMEM) fails here, not on the
+    chip."""
     compiles(lambda x, w, sizes: llama._grouped_matmul(
         x, w, sizes, {"interpret": False}),
-        shaped((m, k)), shaped((16, k, n)), shaped((16,), jnp.int32))
+        shaped((m, k)), shaped((held, k, n)), shaped((held,), jnp.int32))
 
 
 # gigachat3.5-ep16-l5: 32 key heads serving 64 value heads of 128 x 128, 4
