@@ -13,7 +13,14 @@ pool's ``state_replaced``, in set-up and in the window. Nothing of the
 measurement changes: the dump is written after the window, where ``run.py`` decides ``correct``, to stderr (``run.py``'s own
 log goes to stdout, ahead of its result line); ``matched`` is noted as
 ``enqueue`` returns, since the harness lets go of the engine's request
-when it finishes.
+when it finishes. With ``--trace 1`` every ``request.first_token`` marker
+of the traced slice follows, one line a request as its engine told it
+(``kvbench/metrics/_first_token.py``): queued, of which behind another's
+chunks, its prefill and its own chunks' device time inside it, and what
+its time to the first token holds outside the engine; then one line for
+the slice (``first_token_summary``): the split of its median first token,
+and three checks of the markers against the trace around them. In a cell
+that lists none of the five readers too.
 
   chiprun --timeout 1200 -- python3 hack/kvbench_requests.py \\
       --workload solar-open2-ep16-l8.sessions-64k --seed 7 --seconds 50
@@ -81,6 +88,104 @@ def dump(run, matched: dict) -> None:
             for k, v in after.items() if k in COUNTERS), file=sys.stderr)
 
 
+def first_token_summary(markers: list, run) -> str:
+    """One traced slice's split of the median first token (medians do not
+    add; the means at the end do), and three checks of every marker:
+    ``behind_ns`` within ``queued_ns``; ``queued_ns + prefill_ns`` beside
+    the trace's own interval from the end of the request's ``enqueue.admit``
+    to the marker; ``chunks`` beside the slice's ``step.dispatch``es that
+    name the request, where it holds the first."""
+    from kvbench.harness.stats import percentile
+    from kvbench.metrics import _first_token, _launches, _read
+
+    ms = _first_token.MS
+
+    def p50(values):
+        got = percentile([v for v in values if v is not None], 50)
+        return None if got is None else round(got, 3)
+
+    joined = [m for m in markers if m.outside_ms is not None]
+    whole = [m for m in markers if m.own_device_ns is not None]
+    admits = {str(e.stats.get("request_id")): e
+              for e in _read.phase_events(run, "enqueue.admit")}
+    drift = [round(abs(m.engine_ns - (m.event.start
+                                      - admits[m.request_id].end)) * ms, 3)
+             for m in markers if m.request_id in admits]
+    mine = {m.request_id: m for m in markers}
+    dispatched = dict.fromkeys(mine, 0)
+    launches = []
+    for d in _read.phase_events(run, _launches.DISPATCH):
+        if "launch" in d.stats:
+            launches.append(int(d.stats["launch"]))
+        m = _first_token.owner(mine, d)
+        if m is not None:
+            dispatched[m.request_id] += 1
+    held = [m for m in markers if m.first_launch >= min(launches, default=0)]
+    total = {k: sum(getattr(m, k) for m in markers) * ms
+             for k in ("queued_ns", "behind_ns", "prefill_ns")}
+    n = max(1, len(joined))
+    inside = sum(m.engine_ns for m in joined) * ms / n
+    outside = sum(m.outside_ms for m in joined) / n
+    return (
+        f"{len(markers)} markers, {len(joined)} joined, "
+        f"{sum(1 for m in markers if m.cached_tokens > 0)} with "
+        f"cached_tokens > 0 (p50 {p50(m.cached_tokens for m in markers)} "
+        f"of prompt_tokens {p50(m.prompt_tokens for m in markers)}); ms "
+        f"p50 of: due -> first token "
+        f"{p50(m.outside_ms + m.engine_ns * ms for m in joined)}, its route {p50(m.record.route_s * 1e3 for m in joined)}, outside "
+        f"the engine {p50(m.outside_ms for m in joined)}, queued "
+        f"{p50(m.queued_ns * ms for m in markers)}, of which behind "
+        f"another's chunks {p50(m.behind_ns * ms for m in markers)} "
+        f"(behind_chunks p50 {p50(m.behind_chunks for m in markers)}, max "
+        f"{max((m.behind_chunks for m in markers), default=None)}), "
+        f"prefill_ns {p50(m.prefill_ns * ms for m in markers)}, of which "
+        f"own chunks on the device "
+        f"{p50(m.own_device_ns * ms for m in whole)} over the {len(whole)} "
+        f"whose chunks are all placed (chunks p50 "
+        f"{p50(m.chunks for m in markers)}, decodes_between p50 "
+        f"{p50(m.decodes_between for m in markers)}); sums ms: queued "
+        f"{total['queued_ns']:.3f} behind {total['behind_ns']:.3f} prefill "
+        f"{total['prefill_ns']:.3f}; means ms over the joined: due -> first "
+        f"token {inside + outside:.3f} = engine {inside:.3f} + outside "
+        f"{outside:.3f}; checks: behind_ns > queued_ns in "
+        f"{sum(1 for m in markers if m.behind_ns > m.queued_ns)}; "
+        f"queued_ns + prefill_ns off the trace's (enqueue.admit's end -> "
+        f"marker) by at most {max(drift, default=None)} ms over "
+        f"{len(drift)} whose admission the slice holds; chunks differ from "
+        f"the slice's dispatches in "
+        f"{sum(1 for m in held if dispatched[m.request_id] != m.chunks)} of "
+        f"{len(held)} whose first chunk it holds")
+
+
+def dump_first_tokens(run) -> None:
+    from kvbench.metrics import _first_token
+
+    def ms(ns):
+        return "-" if ns is None else f"{ns * 1e-6:.1f}"
+
+    markers = _first_token.of(run)
+    if markers is None:
+        return
+    for m in markers:
+        rec = m.record
+        print(f"[first_token] {m.request_id} {m.event.stats.get('pod')} "
+              f"prompt {m.prompt_tokens} cached {m.cached_tokens} chunks "
+              f"{m.chunks} launches {m.first_launch}-{m.last_launch} "
+              f"decodes_between {m.decodes_between} queued_ms "
+              f"{ms(m.queued_ns)} behind_ms {ms(m.behind_ns)} "
+              f"behind_chunks {m.behind_chunks} prefill_ms "
+              f"{ms(m.prefill_ns)} own_device_ms {ms(m.own_device_ns)}"
+              + ("" if rec is None else
+                 f" due at {rec.start - run.t_start:.2f}s route_ms "
+                 f"{rec.route_s * 1e3:.1f} ttft_ms "
+                 + ("- outside_ms -" if m.outside_ms is None else
+                    f"{(rec.token_times[0] - rec.start) * 1e3:.1f} "
+                    f"outside_ms {m.outside_ms:.1f}")),
+              file=sys.stderr)
+    print(f"[first_tokens] {first_token_summary(markers, run)}",
+          file=sys.stderr)
+
+
 def main(argv=None) -> int:
     decide = bench_run.correctness
     matched: dict = {}
@@ -88,6 +193,7 @@ def main(argv=None) -> int:
 
     def correctness(ctx, run):
         dump(run, matched)
+        dump_first_tokens(run)
         return decide(ctx, run)
 
     bench_run.correctness = correctness

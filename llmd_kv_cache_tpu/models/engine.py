@@ -43,7 +43,6 @@ from ..events.model import (
     BlockStoredEvent,
     GenericEvent,
 )
-from ..ops import sparse_index
 from ..ops.pallas_latent_prefill import per_head_expanded_keys
 from ..ops.pallas_paged_attention import (
     head_dim_supported as _pallas_head_dim_supported,
@@ -60,6 +59,7 @@ from ..telemetry.tracing import (
     PHASE_ENQUEUE_ADMIT,
     PHASE_ENQUEUE_HASH,
     PHASE_ENQUEUE_LOOKUP,
+    PHASE_REQUEST_FIRST_TOKEN,
     PHASE_STEP_COMMIT,
     PHASE_STEP_DISPATCH,
     PHASE_STEP_EMIT,
@@ -1413,6 +1413,11 @@ class MiniEngine:
         traceparent = None if req is None else req.traceparent
         if ph is None and traceparent is None:
             return phase(None, PHASE_STEP_DISPATCH)
+        if ph is not None:
+            # (Phases come with the telemetry.) Whose chunk the launch
+            # about to be numbered is, or that it is a decode program.
+            self.telemetry.on_dispatch(
+                None if req is None else req.request_id)
         named = {} if ph is None or program is None else {
             "program": getattr(program, "func", program).__name__}
         if req is None:
@@ -1463,6 +1468,8 @@ class MiniEngine:
             self._lone_decodes += 1
         if sp is not NOOP_SPAN:
             sp.set_attribute("launch", n)
+        if self.telemetry is not None:
+            self.telemetry.on_launch(n)
         return x
 
     def _fetch_phase(self, launch: int):
@@ -1862,7 +1869,7 @@ class MiniEngine:
             sp.set_attribute("blocks", req.committed_blocks - before)
             req.output.append(first_token)
             if self.telemetry is not None:
-                self.telemetry.on_first_token(req.request_id)
+                self._first_token_phase(req)
             if self.audit is not None:
                 self._emit_audit_outcome(req)
             if self.cfg.role == "prefill" and self.handoff is not None:
@@ -1879,6 +1886,19 @@ class MiniEngine:
             elif req.max_new_tokens <= 1:
                 req.done = True
                 self._finish(req)
+
+    def _first_token_phase(self, req: Request) -> None:
+        """``req``'s first token stands: the telemetry's clock of it, and
+        ``request.first_token`` (phases come with the telemetry), opened
+        and closed at once: a marker at this point of the capture that
+        carries the request's way here as the telemetry kept it
+        (``_ReqState.first_token_split``)."""
+        st = self.telemetry.on_first_token(
+            req.request_id, len(req.prompt), req.cached_len)
+        if st is not None:
+            with phase(self._phases, PHASE_REQUEST_FIRST_TOKEN,
+                       **st.first_token_split()):
+                pass
 
     def _emit_audit_outcome(self, req: Request) -> None:
         """Best-effort ground-truth emission at prefill finish: the
@@ -2373,24 +2393,29 @@ class MiniEngine:
                             // page_size * page_size)
             pos = req.prefill_pos
             chunk = req.prompt[pos:pos + chunk_cap]
+            # (Two locals where ``threshold_keys`` had two until PR 59, so
+            # the frame keeps its 41 slots and ``hack/frame_sizes.py`` reads
+            # no difference here. Whether 39 would move set-up was not
+            # measured: ROADMAP S7 only has it that a frame which grew did.)
+            new, end = len(chunk), pos + len(chunk)
             # Bucket the padded length to powers of two (in pages) so the
             # jit cache holds O(log max_prefill) shapes instead of one per
             # suffix length — compiles are 20-40 s each on TPU.
-            pages_needed = max(1, (len(chunk) + page_size - 1) // page_size)
+            pages_needed = max(1, (new + page_size - 1) // page_size)
             bucket = 1
             while bucket < pages_needed:
                 bucket *= 2
             seq = bucket * page_size
             tokens = np.zeros((1, seq), np.int32)
-            tokens[0, : len(chunk)] = chunk
+            tokens[0, :new] = chunk
             tables = [self._page_table_for(req)[None, :]]
             if self.hybrid:
                 # SWA pages arrive just-in-time for this chunk's blocks and
                 # out-of-window slots return to the pool after it, so a
                 # long prompt's peak SWA demand is window + chunk.
-                self._swa_ensure(req, (pos + len(chunk) - 1) // page_size)
+                self._swa_ensure(req, (end - 1) // page_size)
                 tables.append(self._swa_table_for(req)[None, :])
-            last = pos + len(chunk) >= len(req.prompt)
+            last = end >= len(req.prompt)
             token_sharding = None
             if self._sp > 1 and seq % self._sp == 0:
                 # Sequence-parallel prefill: the chunk's tokens are held
@@ -2401,10 +2426,10 @@ class MiniEngine:
                 token_sharding = NamedSharding(self.mesh, P(None, "sp"))
             state_args, taken = (), ()
             if self.state_pool is not None:
-                snap, taken = self._plan_snapshots(req, pos, len(chunk))
+                snap, taken = self._plan_snapshots(req, pos, new)
                 state_args = ([req.state_slot], snap)
             packed, shapes = pack_inputs(
-                (tokens, *tables, [pos], [len(chunk)], *state_args))
+                (tokens, *tables, [pos], [new], *state_args))
 
         # Every program of a step is dispatched the same way, inside its
         # dispatch phase: its inputs go in one transfer (an argument of the
@@ -2417,7 +2442,7 @@ class MiniEngine:
         # not in a helper: through one (a frame more, the pools splatted)
         # every 28-layer program took 1.5-2 s longer to trace and lower on
         # the chip's host (PERF.md §6, PR 31) — why is not known either.
-        with self._dispatch_phase(req, 1, len(chunk), seq,
+        with self._dispatch_phase(req, 1, new, seq,
                                   self._prefill_forward) as sp:
             token, row, pools = self._prefill_forward(
                 self.params, self.cfg.model, self._launch_input(packed, sp),
@@ -2427,16 +2452,7 @@ class MiniEngine:
             if last:
                 token.copy_to_host_async()
             if self.state_pool is not None:
-                sp.set_attribute("scan_tokens", len(chunk))
-            topk = self.cfg.model.index_topk
-            n_keys = self.cfg.max_pages_per_seq * page_size
-            if (topk and n_keys > topk and sp is not NOOP_SPAN
-                    and self._attention_backends["prefill"]["backend"]
-                    == "pallas"):
-                # What the chunk's selection counts a layer to find its
-                # queries' thresholds (``kth_largest``: seq * n_keys).
-                sp.set_attribute("threshold_keys", sparse_index.threshold_keys(
-                    pos, len(chunk), seq, n_keys, topk))
+                sp.set_attribute("scan_tokens", new)
         for boundary, slot in taken:
             blocks = boundary // page_size
             self.state_pool.store(
@@ -2446,17 +2462,17 @@ class MiniEngine:
                 else EMPTY_BLOCK_HASH,
                 req.prompt[boundary - page_size:boundary])
             req.snapshots.append(req.block_hashes[blocks - 1])
-        req.computed_len = pos + len(chunk)
+        req.computed_len = end
         if self.hybrid:
             self._swa_reclaim(req)  # reads computed_len
         if self.telemetry is not None:
-            # Padding-waste accounting: len(chunk) real tokens rode a
+            # Padding-waste accounting: ``new`` real tokens rode a
             # seq-token padded dispatch (the power-of-two page bucket).
-            self.telemetry.on_dispatch_tokens(len(chunk), seq)
+            self.telemetry.on_dispatch_tokens(new, seq)
         if last:
             req.last_logits = row
-        req.prefill_pos = None if last else pos + len(chunk)
-        return _Unread(token, [req], 1, len(chunk), self._launch, "prefill")
+        req.prefill_pos = None if last else end
+        return _Unread(token, [req], 1, new, self._launch, "prefill")
 
     def _commit_full_blocks(self, req: Request,
                             upto: Optional[int] = None) -> None:
@@ -2580,9 +2596,9 @@ class MiniEngine:
                         # CoDel signal: sustained admission delay above
                         # the target trips brownout/shed at enqueue.
                         self.shedder.observe_delay(admission_delay)
-                    req.enqueued_at = None
                     if tel is not None:
-                        tel.on_first_schedule(rid)
+                        tel.on_first_schedule(rid, req.enqueued_at)
+                    req.enqueued_at = None
                 # Deferred storage restore (enqueue path): started above on
                 # the request's first step, polled here across steps —
                 # decodes keep running below while the load is in flight.
@@ -2610,7 +2626,7 @@ class MiniEngine:
         decodes by one chunk per step, never its whole prefill.
         """
         tel = self.telemetry
-        step_t0 = time.monotonic() if tel is not None else 0.0
+        step_t0 = tel.begin_step() if tel is not None else 0.0
         ph = self._phases
         if ph is not None:
             ph.begin_step()
@@ -2713,7 +2729,7 @@ class MiniEngine:
                     self._finish(req)
             if tel is not None:
                 tel.on_step(time.monotonic() - step_t0, bool(emitted),
-                            self._telemetry_pools)
+                            self._telemetry_pools, prefill_req is not None)
                 # The step's counters ride its last phase.
                 sp.set_attribute("programs", ph.programs)
                 sp.set_attribute("transfers", ph.transfers)

@@ -340,21 +340,6 @@ def dsa_keep_bias(scores: jax.Array, q_positions: jax.Array,
       total_lens.astype(jnp.int32), scores.astype(jnp.float32))
 
 
-def threshold_keys(ctx_len: int, new_len: int, q_seq: int, n_keys: int,
-                   topk: int) -> int:
-    """The scores ``dsa_keep_bias`` counts a layer for one row's chunk of
-    ``q_seq`` queries (``new_len`` of them real) behind ``ctx_len`` cached
-    tokens: over its query tiles, queries x the keys of the blocks copied
-    in. ``kth_largest`` counts ``q_seq * n_keys``."""
-    tq, tk = _keep_tiles(q_seq, n_keys)
-    counted = 0
-    for start in range(0, q_seq, tq):
-        reach = min(ctx_len + start + tq - 1, ctx_len + new_len - 1) + 1
-        if reach > topk:
-            counted += tq * -(-reach // tk) * tk
-    return counted
-
-
 # A block of a decode row's scores: one float32 vreg, 8 chunks of 128 keys.
 _LANES = 128
 _BLOCK = 8 * _LANES

@@ -122,24 +122,78 @@ class EngineTelemetryConfig:
         )
 
 
+# What ``request.first_token`` and a finished request's summary both show
+# of a ``_ReqState`` as it stands: counts, no clock.
+_FIRST_TOKEN_COUNTS = (
+    "prompt_tokens", "cached_tokens", "chunks", "first_launch",
+    "last_launch", "decodes_between", "behind_chunks",
+)
+
+
 class _ReqState:
-    """Per-request lifecycle clock. Preallocated; mutated in place."""
+    """Per-request lifecycle clock. Preallocated; mutated in place.
+
+    Every time is ``time.monotonic()``. Beside the four points of a
+    request's life it holds what its first token waited for, as the engine
+    saw it (``first_token_split``: what ``request.first_token`` carries and
+    ``debug_vars()["requests"]["recent"]`` shows untraced).
+    """
 
     __slots__ = (
         "request_id", "traceparent", "enqueue_ts", "admit_ts",
         "first_token_ts", "last_token_ts", "tokens", "prefix_hit_blocks",
+        "sched_ts", "queued_s", "behind_s", "behind_chunks", "chunks",
+        "first_launch", "last_launch", "decodes_at_sched", "decodes_between",
+        "prompt_tokens", "cached_tokens",
     )
 
     def __init__(self, request_id: str, now: float, prefix_hit_blocks: int,
                  traceparent: Optional[str]):
         self.request_id = request_id
         self.traceparent = traceparent
-        self.enqueue_ts = now
+        self.enqueue_ts = now   # inside admission (``on_admitted``)
+        # The first pick: the start of the ``step()`` whose scheduler first
+        # found this request at the head of its queue (``on_first_schedule``),
+        # whether a restore or handoff gate then held it or not.
         self.admit_ts: Optional[float] = None
         self.first_token_ts: Optional[float] = None
         self.last_token_ts: Optional[float] = None
         self.tokens = 0
         self.prefix_hit_blocks = prefix_hit_blocks
+        # The start of the ``step()`` that first ran a chunk of it (the
+        # first pick's where no gate held it: ``on_dispatch``), and the wait
+        # from the end of ``enqueue()`` to there.
+        self.sched_ts: Optional[float] = None
+        self.queued_s = 0.0
+        # Of that wait: the engine's steps whose prefill chunk was another
+        # request's (their wall time, their count). The rest is its own
+        # restore or handoff gate and the caller's time between steps.
+        self.behind_s = 0.0
+        self.behind_chunks = 0
+        # Its own prefill chunks dispatched, and the device's launch
+        # ordinals (``MiniEngine._launch_input``) of the first and the last.
+        self.chunks = 0
+        self.first_launch = -1
+        self.last_launch = -1
+        # The engine's decode programs launched between ``sched_ts`` (the
+        # engine's count there) and its first token; 0 until that stands.
+        self.decodes_at_sched = 0
+        self.decodes_between = 0
+        self.prompt_tokens = 0
+        self.cached_tokens = 0  # at the first token: after a restore
+
+    def first_token_split(self) -> dict:
+        """What the ``request.first_token`` phase carries: counts, and
+        three durations in ns, each a difference of two readings of the
+        one clock. ``queued_ns + prefill_ns`` is what the TTFT histogram
+        observed less the rest of ``enqueue()`` behind ``on_admitted``."""
+        return {
+            "request_id": self.request_id,
+            **{k: getattr(self, k) for k in _FIRST_TOKEN_COUNTS},
+            "queued_ns": int(self.queued_s * 1e9),
+            "behind_ns": int(self.behind_s * 1e9),
+            "prefill_ns": int((self.first_token_ts - self.sched_ts) * 1e9),
+        }
 
     def summary(self, finish_ts: float, outcome: str) -> dict:
         return {
@@ -153,6 +207,10 @@ class _ReqState:
             "prefix_hit_blocks": self.prefix_hit_blocks,
             "traced": self.traceparent is not None,
             "outcome": outcome,
+            "sched_ts": self.sched_ts,
+            "queued_s": self.queued_s,
+            "behind_s": self.behind_s,
+            **{k: getattr(self, k) for k in _FIRST_TOKEN_COUNTS},
         }
 
 
@@ -266,6 +324,13 @@ class EngineTelemetry:
         # main model agreed with (on_drafts_verified).
         self.spec_drafted = 0
         self.spec_accepted = 0
+        # A request's way to its first token (``_ReqState``): the start of
+        # the step under way (``begin_step``), the decode programs launched
+        # so far, and the request whose chunk is being dispatched
+        # (``on_dispatch``) until its launch is numbered (``on_launch``).
+        self._step_t0 = 0.0
+        self._decode_launches = 0
+        self._launching: Optional[_ReqState] = None
 
     # -- lifecycle hooks (called by MiniEngine) ---------------------------
 
@@ -286,25 +351,72 @@ class EngineTelemetry:
         if st is not None:
             st.traceparent = traceparent
 
-    def on_first_schedule(self, request_id: str) -> None:
+    def begin_step(self) -> float:
+        """The start of an engine ``step()``, which it returns."""
+        self._step_t0 = time.monotonic()
+        return self._step_t0
+
+    def on_first_schedule(self, request_id: str,
+                          enqueued_at: Optional[float] = None) -> None:
+        """The scheduler's first pick of a request, in the ``step()`` that
+        ``begin_step`` opened; ``enqueued_at``: when ``enqueue()`` ended."""
         st = self._requests.get(request_id)
         if st is not None and st.admit_ts is None:
-            st.admit_ts = time.monotonic()
+            st.admit_ts = self._step_t0
+            if enqueued_at is not None:
+                st.queued_s = max(0.0, self._step_t0 - enqueued_at)
 
-    def on_first_token(self, request_id: str) -> None:
+    def on_dispatch(self, request_id: Optional[str]) -> None:
+        """A step program about to be launched: a prefill chunk of
+        ``request_id`` (the ragged program that carries one too), or with
+        None a decode program."""
+        if request_id is None:
+            self._decode_launches += 1
+            self._launching = None
+            return
+        st = self._launching = self._requests.get(request_id)
+        if st is not None and st.sched_ts is None and st.admit_ts is not None:
+            # The first chunk of a request the scheduler picked (the
+            # synchronous ``add_request`` runs its chunks in no step): its
+            # gates are behind it, and what they held it is part of its wait.
+            st.sched_ts = self._step_t0
+            st.queued_s += st.sched_ts - st.admit_ts
+            st.decodes_at_sched = self._decode_launches
+
+    def on_launch(self, launch: int) -> None:
+        """The device's ordinal of the program ``on_dispatch`` announced."""
+        st = self._launching
+        if st is not None:
+            self._launching = None
+            st.chunks += 1
+            if st.first_launch < 0:
+                st.first_launch = launch
+            st.last_launch = launch
+
+    def on_first_token(self, request_id: str, prompt_tokens: int = 0,
+                       cached_tokens: int = 0) -> Optional[_ReqState]:
+        """The request's record as its first token stands (what
+        ``request.first_token`` is made from), or None for an unknown one."""
         st = self._requests.get(request_id)
         if st is None:
-            return
+            return None
         now = time.monotonic()
         st.first_token_ts = now
         st.last_token_ts = now
         st.tokens = 1
+        st.prompt_tokens = prompt_tokens
+        st.cached_tokens = cached_tokens
         if st.admit_ts is None:  # synchronous add_request path
             st.admit_ts = st.enqueue_ts
+        if st.sched_ts is None:  # no chunk of its own ran in a step()
+            st.sched_ts = st.admit_ts
+        else:
+            st.decodes_between = self._decode_launches - st.decodes_at_sched
         # The trace-id exemplar links a slow TTFT bucket straight to the
         # retained trace in the fleet collector (OpenMetrics exposition).
         self.ttft.observe(now - st.enqueue_ts,
                           trace_id=trace_id_of(st.traceparent))
+        return st
 
     def on_decode_tokens(self, request_id: str, n: int, now: float) -> None:
         st = self._requests.get(request_id)
@@ -341,13 +453,22 @@ class EngineTelemetry:
                 "outcome": outcome, "tokens": st.tokens})
 
     def on_step(self, duration_s: float, decoded: bool,
-                pools: Sequence[Tuple[str, Any]] = ()) -> None:
+                pools: Sequence[Tuple[str, Any]] = (),
+                prefilled: bool = False) -> None:
         """Once per engine ``step()``: step timing + decimated pool scrape.
 
         ``pools`` is ``[(group_name, block_manager), ...]``; each block
         manager answers :meth:`~models.engine.BlockManager.pool_stats`.
+        ``prefilled``: the step ran a prefill chunk. Every request the
+        scheduler has not picked yet stood behind it for the step's length
+        (the one it picked has its ``admit_ts`` by now).
         """
         self.step_seconds.observe(duration_s)
+        if prefilled:
+            for st in self._requests.values():
+                if st.admit_ts is None:
+                    st.behind_s += duration_s
+                    st.behind_chunks += 1
         if decoded:
             collector.ENGINE_DECODE_STEPS.inc()
         self._step_counter += 1

@@ -704,8 +704,7 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
 # evicted). Every step program samples as its own tail.
 # ``step.dispatch`` carries its program's ``rows``, ``tokens`` and ``padded``
 # and, where the model has them, what the program reads a layer: a decode
-# step that selects ``index_keys`` and ``selected_keys``, a prefill chunk
-# that selects ``threshold_keys`` (``ops.sparse_index``); with linear layers
+# step that selects ``index_keys`` and ``selected_keys``; with linear layers
 # a decode step ``state_rows``, a prefill chunk ``scan_tokens``. A latent
 # model's prefill chunk carries ``expanded_keys``
 # (``MiniEngine._dispatch_phase``, from the chunk program's own rule,
@@ -732,6 +731,23 @@ def init_tracing(service_name: Optional[str] = None) -> bool:
 # own: ``route.decide`` (all of a routing decision; nests the five below)
 # carries ``keys``, ``pods``, ``pod``, ``best``, ``speculative`` and
 # ``expired``; ``ingest`` carries ``pod``, ``events`` and ``keys``.
+# ``request.first_token`` is a marker, opened and closed at once where a
+# request's first token stands (``MiniEngine._finish_prefill``, inside
+# ``step.commit``): its place in the capture is the first token's, beside
+# the device's ops, and it carries the request's way there as the engine's
+# telemetry kept it (``EngineTelemetry``'s ``_ReqState.first_token_split``):
+# ``request_id``, ``prompt_tokens``, ``cached_tokens`` (after a restore),
+# ``chunks`` (its prefill chunks dispatched) with ``first_launch`` and
+# ``last_launch`` (their dispatches' ``launch``), ``decodes_between`` (this
+# engine's decode programs dispatched from its first chunk to here) and three
+# durations in ns, each the difference of two readings of one monotonic
+# clock: ``queued_ns`` (the end of ``enqueue()`` → the start of the
+# ``step()`` that first ran a chunk of it: the one that first picked it,
+# unless a restore or handoff gate then held it), of which ``behind_ns``
+# (with ``behind_chunks``: this engine's steps in between whose chunk was
+# another request's; the rest is its own gate and the caller's time between
+# steps), and ``prefill_ns`` (the start of that ``step()`` → the marker).
+# The synchronous ``add_request`` emits it with ``queued_ns`` 0.
 PHASE_ENQUEUE_ADMIT = "enqueue.admit"      # all of admission (nests the two below)
 PHASE_ENQUEUE_HASH = "enqueue.hash"        # tokens → block hashes
 PHASE_ENQUEUE_LOOKUP = "enqueue.lookup"    # prefix probe, page allocation, eviction
@@ -751,6 +767,7 @@ PHASE_ROUTE_LOOKUP = "route.lookup"        # the chain looked up in the index
 PHASE_ROUTE_SCORE = "route.score"          # the scorer over what was found, and the pick
 PHASE_ROUTE_SPECULATE = "route.speculate"  # speculative entries for the chosen pod
 PHASE_INGEST = "ingest"                    # Pool.process_event_batch: one batch applied to the index
+PHASE_REQUEST_FIRST_TOKEN = "request.first_token"  # a marker: a request's first token stands, and how it got there
 
 PHASE_NAMES = (
     PHASE_ENQUEUE_ADMIT, PHASE_ENQUEUE_HASH, PHASE_ENQUEUE_LOOKUP,
@@ -760,7 +777,7 @@ PHASE_NAMES = (
     PHASE_STEP_SNAPSHOT,
     PHASE_ROUTE_EXPIRE, PHASE_ROUTE_HASH, PHASE_ROUTE_LOOKUP,
     PHASE_ROUTE_SCORE, PHASE_ROUTE_SPECULATE, PHASE_ROUTE_DECIDE,
-    PHASE_INGEST,
+    PHASE_INGEST, PHASE_REQUEST_FIRST_TOKEN,
 )
 
 SPAN_ENGINE_ADMISSION = "llm_d.kv_cache.engine.admission"

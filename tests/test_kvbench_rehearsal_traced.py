@@ -20,6 +20,10 @@ from test_kvbench_rehearsal import (  # noqa: F401 (the fixture applies here)
     require_native,
 )
 
+FIRST_TOKEN = ("engine_queue_ms_p50", "behind_prefill_share",
+               "engine_ttft_ms_p50", "prefill_own_device_share",
+               "ttft_outside_engine_ms_p50")
+
 
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCHMARK["workloads"]])
 def test_a_traced_cell_pairs_programs_launched_ahead(cell):
@@ -54,6 +58,21 @@ def test_a_traced_cell_pairs_programs_launched_ahead(cell):
     if latent:
         per_head = line["metrics"]["prefill_per_head_share"]
         assert per_head["unit"] == "%" and 0.0 <= per_head["value"] <= 100.0
+    # A request's way to its first token, told by the engine (PR 59): the
+    # cells that list the five readers print a number for each, 0.0 where
+    # the slice held no marker, and the engine's split holds together.
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if m["name"] in FIRST_TOKEN and cell in m["workloads"]}
+    assert listed in (set(), set(FIRST_TOKEN))
+    if listed:
+        got = {name: line["metrics"][name]["value"] for name in FIRST_TOKEN}
+        assert min(got.values()) >= 0.0
+        # (No ceiling on ``prefill_own_device_share``: a chip runs one
+        # program at a time, the rehearsal's CPU starts a program's first
+        # ops beside the one before it, and the spans of a request's chunks
+        # can add up to more than the time they ran in.)
+        assert got["behind_prefill_share"] <= 100.0
+        assert got["engine_queue_ms_p50"] <= got["engine_ttft_ms_p50"]
     (found,) = re.findall(
         r"launches: (\d+) step programs, (\d+) placed \(by launch, offset "
         r"(\d+)\), unpaired (\d+), clock_fault (\d+)", out.stdout)

@@ -754,28 +754,6 @@ class TestCounters:
         # The XLA prefill finds its thresholds with ``kth_largest``.
         assert not any("threshold_keys" in a for _, a, _ in seen)
 
-    def test_a_prefill_chunk_carries_what_its_threshold_counts(self, served):
-        """Through the Pallas prefill: 24 pages a row = 384 keys in blocks
-        of 128, chunks of 32 queries in tiles of 16, ``topk`` 32. A tile
-        counts 16 x the blocks up to its last candidate; one that reaches
-        32 keys at most counts none. 150 tokens: the chunk at 0 reaches 16
-        and 32 keys; at 32, 64 and 96 one block a tile; at 128 (22 tokens,
-        padded to 32) its tiles reach 144 and 150 keys, two blocks each."""
-        from tests.test_telemetry import _recorded
-
-        eng = MiniEngine(EngineConfig(
-            model=served.cfg, num_pages=64, max_pages_per_seq=24,
-            max_batch=2, max_prefill_tokens=32, use_pallas_decode=True,
-            use_pallas_prefill=True, telemetry=EngineTelemetryConfig()),
-            params=served.params)
-        seen = _recorded(eng._phases)
-        prompt = (PROMPT + PROMPT)[:150]
-        serve(eng, "counted", prompt, 1)
-        counted = [a["threshold_keys"] for n, a, _ in seen
-                   if n == "step.dispatch" and "threshold_keys" in a]
-        assert counted == [0, 2 * 16 * 128, 2 * 16 * 128, 2 * 16 * 128,
-                           2 * 16 * 256]
-
 
 class TestCounts:
     @pytest.fixture(scope="class")

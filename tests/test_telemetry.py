@@ -769,7 +769,8 @@ class TestEnginePhases:
         # state beside its pages only (tests/test_gated_deltanet.py).
         assert names_seen == {n for n in tracing.PHASE_NAMES
                               if n.startswith("step.")
-                              } - {tracing.PHASE_STEP_SNAPSHOT}
+                              } - {tracing.PHASE_STEP_SNAPSHOT} | {
+                                  tracing.PHASE_REQUEST_FIRST_TOKEN}
         assert events                  # the sink did receive the batches
         commits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_COMMIT]
         assert sorted(c["blocks"] for c in commits) == [1, 3]
@@ -1003,6 +1004,384 @@ class TestLaunches:
         _drain(eng)
         assert eng._launch == 2 and eng._phases is None
         assert eng._fetch_phase(eng._launch) is tracing._NOOP_CM
+
+
+# -- a request's way to its first token (``request.first_token``) -------------
+
+
+def _markers(seen):
+    return [a for n, a, _ in seen if n == tracing.PHASE_REQUEST_FIRST_TOKEN]
+
+
+def _chunks_of(seen, rid):
+    return [a for n, a, _ in seen if n == tracing.PHASE_STEP_DISPATCH
+            and a.get("request_id") == rid]
+
+
+MARKER_KEYS = {"pod", "step", "request_id", "prompt_tokens", "cached_tokens",
+               "chunks", "first_launch", "last_launch", "decodes_between",
+               "behind_chunks", "queued_ns", "behind_ns", "prefill_ns"}
+
+
+class TestFirstTokenMarker:
+    @pytest.fixture(scope="class")
+    def three(self):
+        """Three requests enqueued at once into one engine with phases:
+        ``a`` of three chunks, ``b`` of two, ``c`` of one, each long enough
+        to decode while the next prefills."""
+        from llmd_kv_cache_tpu.models import engine
+
+        counts, engine._launch_counts = engine._launch_counts, {}
+        try:
+            eng, tiny = _phase_engine()
+            seen = _recorded(eng._phases)
+            page = tiny.page_size
+            prompts = {"a": list(range(1, 1 + 5 * page)),
+                       "b": list(range(500, 500 + 3 * page + 3)),
+                       "c": list(range(900, 900 + page + 3))}
+            for rid, prompt in prompts.items():
+                eng.enqueue(rid, prompt, max_new_tokens=8)
+            _drain(eng)
+        finally:
+            engine._launch_counts = counts
+        return eng, seen, prompts
+
+    def test_one_marker_a_request_inside_its_commit(self, three):
+        _, seen, prompts = three
+        marks = _markers(seen)
+        assert [m["request_id"] for m in marks] == ["a", "b", "c"]
+        for m in marks:
+            assert set(m) == MARKER_KEYS and m["pod"] == "pod-x"
+            assert m["prompt_tokens"] == len(prompts[m["request_id"]])
+            assert m["cached_tokens"] == 0
+        names = [n for n, _, _ in seen]
+        for at, n in enumerate(names):
+            if n == tracing.PHASE_REQUEST_FIRST_TOKEN:
+                # Opened while the request's ``step.commit`` was open, in
+                # the same step, and closed at once.
+                commit = max(i for i in range(at)
+                             if names[i] == tracing.PHASE_STEP_COMMIT)
+                assert seen[commit][1]["request_id"] == seen[at][1][
+                    "request_id"]
+                assert seen[commit][1]["step"] == seen[at][1]["step"]
+                assert seen[at][2] is False
+
+    def test_chunks_and_launches_are_its_dispatches(self, three):
+        _, seen, _ = three
+        for m in _markers(seen):
+            own = _chunks_of(seen, m["request_id"])
+            assert m["chunks"] == len(own) >= 1
+            assert m["first_launch"] == own[0]["launch"]
+            assert m["last_launch"] == own[-1]["launch"]
+        assert [m["chunks"] for m in _markers(seen)] == [3, 2, 1]
+
+    def test_a_request_stands_behind_the_chunks_ahead_of_it(self, three):
+        _, seen, _ = three
+        a, b, c = _markers(seen)
+        assert (a["behind_chunks"], a["behind_ns"]) == (0, 0)
+        assert b["behind_chunks"] == a["chunks"]
+        assert c["behind_chunks"] == a["chunks"] + b["chunks"]
+        for m in (a, b, c):
+            assert 0 <= m["behind_ns"] <= m["queued_ns"]
+            assert m["prefill_ns"] > 0
+        # The steps ``b`` stood behind are ``a``'s whole prefill.
+        assert 0 < b["behind_ns"] < c["behind_ns"]
+        assert b["queued_ns"] < c["queued_ns"]
+        assert a["queued_ns"] < b["behind_ns"]
+
+    def test_decodes_between_are_the_decode_programs_beside_its_chunks(
+            self, three):
+        _, seen, _ = three
+        a, b, c = _markers(seen)
+        assert a["decodes_between"] == 0          # nothing decoded yet
+        for m in (b, c):
+            first = next(i for i, (n, at, _) in enumerate(seen)
+                         if n == tracing.PHASE_STEP_DISPATCH
+                         and at.get("request_id") == m["request_id"])
+            last = next(i for i, (n, at, _) in enumerate(seen)
+                        if n == tracing.PHASE_REQUEST_FIRST_TOKEN
+                        and at["request_id"] == m["request_id"])
+            decodes = [at for n, at, _ in seen[first:last]
+                       if n == tracing.PHASE_STEP_DISPATCH
+                       and "request_id" not in at]
+            assert m["decodes_between"] == len(decodes)
+        # ``a`` decodes one step beside each of ``b``'s chunks.
+        assert b["decodes_between"] == b["chunks"]
+        assert c["decodes_between"] == c["chunks"]
+
+    def test_debug_vars_show_the_same_split_untraced(self, three):
+        eng, seen, _ = three
+        recent = {r["request_id"]: r for r in
+                  eng.telemetry.debug_vars()["requests"]["recent"]}
+        for m in _markers(seen):
+            r = recent[m["request_id"]]
+            for key in ("chunks", "first_launch", "last_launch",
+                        "decodes_between", "behind_chunks", "prompt_tokens",
+                        "cached_tokens"):
+                assert r[key] == m[key], key
+            assert int(r["queued_s"] * 1e9) == m["queued_ns"]
+            assert int(r["behind_s"] * 1e9) == m["behind_ns"]
+            assert int((r["first_token_ts"] - r["sched_ts"]) * 1e9) == m[
+                "prefill_ns"]
+            # No gate held any of them: the step that first picked a
+            # request (``admit_ts`` is its start) ran its first chunk.
+            assert r["enqueue_ts"] <= r["admit_ts"] == r["sched_ts"] <= r[
+                "first_token_ts"]
+
+    def test_a_prefix_hit_carries_what_was_cached(self):
+        eng, tiny = _phase_engine()
+        seen = _recorded(eng._phases)
+        page = tiny.page_size
+        prompt = list(range(1, 1 + 4 * page))
+        eng.enqueue("first", prompt, max_new_tokens=2)
+        _drain(eng)
+        req = eng.enqueue("again", prompt + [7, 8, 9], max_new_tokens=2)
+        assert req.cached_len == 4 * page
+        _drain(eng)
+        first, again = _markers(seen)
+        assert (first["cached_tokens"], first["chunks"]) == (0, 2)
+        assert again["cached_tokens"] == 4 * page
+        assert again["prompt_tokens"] == 4 * page + 3
+        assert again["chunks"] == 1 == len(_chunks_of(seen, "again"))
+
+    def test_a_gate_that_holds_the_head_is_queued_not_prefill(self):
+        """A restore gate (stood in for here) holds ``late`` at the head of
+        the queue for three steps after its first pick while ``early``
+        decodes: those steps are ``late``'s wait, not its prefill, and their
+        decode programs do not stand between its chunks."""
+        import time
+
+        eng, tiny = _phase_engine()
+        seen = _recorded(eng._phases)
+        page = tiny.page_size
+        eng.enqueue("early", list(range(1, 1 + page)), max_new_tokens=12)
+        eng.step()
+        late = eng.enqueue("late", list(range(500, 500 + 3 * page)),
+                           max_new_tokens=2)
+        held = []
+
+        def gate(req):
+            held.append(time.monotonic())
+            if len(held) <= 3:
+                time.sleep(0.01)
+                return False
+            req.restore_job = None
+            return True
+
+        late.restore_job, eng._poll_deferred_restore = object(), gate
+        _drain(eng)
+        early, m = _markers(seen)
+        assert (early["request_id"], m["request_id"]) == ("early", "late")
+        assert len(held) == 4
+        # Three steps of 10 ms and more before its first chunk's step.
+        assert m["queued_ns"] >= 30e6 and m["behind_ns"] == 0
+        first = next(i for i, (n, at, _) in enumerate(seen)
+                     if n == tracing.PHASE_STEP_DISPATCH
+                     and at.get("request_id") == "late")
+        decodes = [i for i, (n, at, _) in enumerate(seen)
+                   if n == tracing.PHASE_STEP_DISPATCH
+                   and "request_id" not in at]
+        gated = [i for i in decodes if i < first]
+        assert len(gated) >= 3
+        assert m["chunks"] == 2 and m["decodes_between"] == 2
+        st = next(r for r in eng.telemetry.debug_vars()["requests"]["recent"]
+                  if r["request_id"] == "late")
+        assert st["admit_ts"] <= held[0] < held[3] and (
+            st["admit_ts"] < st["sched_ts"] <= held[3])
+
+    def test_add_request_emits_it_with_nothing_queued(self):
+        eng, tiny = _phase_engine()
+        seen = _recorded(eng._phases)
+        prompt = list(range(1, 1 + 3 * tiny.page_size))
+        eng.add_request("sync", prompt, max_new_tokens=2)
+        (m,) = _markers(seen)
+        assert set(m) == MARKER_KEYS
+        assert (m["queued_ns"], m["behind_ns"], m["behind_chunks"],
+                m["decodes_between"]) == (0, 0, 0, 0)
+        own = _chunks_of(seen, "sync")
+        assert m["chunks"] == len(own) == 2
+        assert (m["first_launch"], m["last_launch"]) == (
+            own[0]["launch"], own[-1]["launch"])
+        assert m["prefill_ns"] > 0 and m["step"] == 0
+
+    def test_a_ragged_chunk_carries_the_decode_rows(self):
+        """The ragged scheduler's program that holds a chunk is a chunk:
+        the rows that decode ride it, and no decode program stands between
+        a pick and its first token."""
+        eng, tiny = _phase_engine(ragged=True)
+        seen = _recorded(eng._phases)
+        page = tiny.page_size
+        eng.enqueue("a", list(range(1, 1 + 3 * page)), max_new_tokens=6)
+        eng.enqueue("b", list(range(500, 500 + 3 * page)), max_new_tokens=3)
+        _drain(eng)
+        a, b = _markers(seen)
+        assert (a["chunks"], b["chunks"]) == (2, 2)
+        assert b["behind_chunks"] == a["chunks"]
+        assert b["decodes_between"] == 0 and b["behind_ns"] <= b["queued_ns"]
+        assert len(_chunks_of(seen, "b")) == 2
+
+    def test_without_telemetry_no_record_is_built_and_nothing_opens(
+            self, monkeypatch):
+        from llmd_kv_cache_tpu.telemetry import engine_telemetry
+
+        built, opened = [], []
+        real = engine_telemetry._ReqState.__init__
+        monkeypatch.setattr(
+            engine_telemetry._ReqState, "__init__",
+            lambda self, *a, **kw: built.append(a) or real(self, *a, **kw))
+        monkeypatch.setattr(tracing, "_Phase",
+                            lambda *a: opened.append(a) or tracing._NOOP_CM)
+        eng, tiny = _phase_engine(telemetry=False)
+        assert eng.telemetry is None and eng._phases is None
+        eng.enqueue("a", list(range(1, 3 * tiny.page_size)), max_new_tokens=3)
+        eng.add_request("b", list(range(50, 60)), max_new_tokens=2)
+        _drain(eng)
+        assert built == [] and opened == []
+
+
+class TestFirstTokenClock:
+    """``EngineTelemetry``'s record of a request's way to its first token,
+    driven by hand: the hooks the engine calls, in its order."""
+
+    @staticmethod
+    def _tel():
+        from llmd_kv_cache_tpu.telemetry.engine_telemetry import (
+            EngineTelemetry,
+            EngineTelemetryConfig,
+        )
+
+        return EngineTelemetry(EngineTelemetryConfig(flight_records=False))
+
+    def test_behind_counts_only_steps_that_ran_another_requests_chunk(self):
+        tel = self._tel()
+        tel.on_admitted("old", 0)
+        tel.on_admitted("new", 0)
+        t0 = tel.begin_step()
+        tel.on_first_schedule("old", t0 - 0.002)
+        tel.on_step(0.010, False, (), True)      # old's chunk: new waits
+        tel.begin_step()
+        tel.on_step(0.004, True, (), False)      # a gate held old: no chunk
+        tel.begin_step()
+        tel.on_step(0.020, True, (), True)
+        new, old = tel._requests["new"], tel._requests["old"]
+        assert (new.behind_chunks, old.behind_chunks) == (2, 0)
+        assert new.behind_s == pytest.approx(0.030) and old.behind_s == 0.0
+        assert old.queued_s == pytest.approx(0.002)
+        t1 = tel.begin_step()
+        tel.on_first_schedule("new", t1 - 0.5)
+        tel.on_dispatch("new")
+        tel.on_step(0.010, True, (), True)       # its own chunk
+        assert new.behind_chunks == 2 and new.queued_s == pytest.approx(0.5)
+        # A second pick of a request, and its second chunk, change nothing
+        # of its first.
+        tel.begin_step()
+        tel.on_first_schedule("new", 0.0)
+        tel.on_dispatch("new")
+        assert new.admit_ts == new.sched_ts == t1
+        assert new.queued_s == pytest.approx(0.5)
+
+    def test_a_gate_after_the_pick_is_part_of_the_wait(self):
+        """A restore or handoff gate holds the queue's head after its first
+        pick: no chunk runs, the engine decodes on, and the request's wait
+        ends with the step that runs its first chunk; the decode programs
+        of the steps it was held are not between its chunks."""
+        tel = self._tel()
+        tel.on_admitted("r", 0)
+        tel.on_admitted("next", 0)
+        t0 = tel.begin_step()
+        tel.on_first_schedule("r", t0 - 0.003)
+        tel.on_dispatch(None)
+        tel.on_step(0.004, True, (), False)      # held: a decode step alone
+        st = tel._requests["r"]
+        assert (st.admit_ts, st.sched_ts) == (t0, None)
+        tel.begin_step()
+        tel.on_first_schedule("r", None)
+        tel.on_dispatch(None)
+        tel.on_step(0.004, True, (), False)      # held again
+        t2 = tel.begin_step()
+        tel.on_dispatch("r")                     # the gate let it through
+        tel.on_launch(11)
+        tel.on_dispatch(None)
+        tel.on_step(0.012, True, (), True)
+        assert (st.admit_ts, st.sched_ts) == (t0, t2)
+        assert st.queued_s == pytest.approx(0.003 + (t2 - t0))
+        tel.begin_step()
+        tel.on_dispatch("r")
+        tel.on_launch(13)
+        split = tel.on_first_token("r", 64, 32).first_token_split()
+        assert split["decodes_between"] == 1 and split["chunks"] == 2
+        assert split["queued_ns"] == int(st.queued_s * 1e9)
+        assert split["prefill_ns"] == int((st.first_token_ts - t2) * 1e9)
+        # Nobody's chunk ran while the gate held the head: the one behind
+        # it stood behind the one step that ended with a chunk of ``r``.
+        assert tel._requests["next"].behind_chunks == 1
+        assert split["behind_ns"] == 0
+
+    def test_launches_go_to_the_request_whose_chunk_was_announced(self):
+        tel = self._tel()
+        tel.on_admitted("r", 0)
+        tel.begin_step()
+        tel.on_first_schedule("r", None)
+        tel.on_launch(3)                 # nothing announced: nobody's
+        tel.on_dispatch("r")
+        tel.on_launch(4)
+        tel.on_dispatch(None)            # a decode program
+        tel.on_launch(5)
+        tel.on_dispatch(None)
+        tel.on_launch(6)
+        tel.on_dispatch("r")
+        tel.on_launch(9)
+        tel.on_dispatch("gone")          # an unknown request: nobody's
+        tel.on_launch(10)
+        st = tel.on_first_token("r", prompt_tokens=40, cached_tokens=16)
+        assert st is tel._requests["r"]
+        split = st.first_token_split()
+        assert split == {
+            "request_id": "r", "prompt_tokens": 40, "cached_tokens": 16,
+            "chunks": 2, "first_launch": 4, "last_launch": 9,
+            "decodes_between": 2, "behind_chunks": 0, "queued_ns": 0,
+            "behind_ns": 0, "prefill_ns": split["prefill_ns"]}
+        assert split["prefill_ns"] >= 0
+        assert tel.on_first_token("unknown") is None
+
+    def test_decodes_before_the_first_chunk_are_not_between(self):
+        tel = self._tel()
+        tel.on_admitted("r", 0)
+        for _ in range(5):
+            tel.on_dispatch(None)
+        tel.begin_step()
+        tel.on_first_schedule("r", None)
+        tel.on_dispatch("r")
+        tel.on_dispatch(None)
+        assert tel.on_first_token("r").decodes_between == 1
+
+    @pytest.mark.parametrize("picked", [False, True])
+    def test_a_request_that_ends_before_its_first_token(self, picked):
+        """Nothing stands between a pick and a first token that never came."""
+        tel = self._tel()
+        tel.on_admitted("r", 0)
+        tel.on_dispatch(None)
+        if picked:
+            tel.begin_step()
+            tel.on_first_schedule("r", None)
+            tel.on_dispatch("r")
+            tel.on_launch(7)
+            tel.on_dispatch(None)
+        tel.on_finish("r", "aborted")
+        (summary,) = tel.finished
+        assert summary["first_token_ts"] is None
+        assert summary["decodes_between"] == 0
+        assert summary["chunks"] == int(picked)
+        assert (summary["sched_ts"] is not None) is picked
+
+    def test_the_synchronous_path_is_its_own_first_pick(self):
+        tel = self._tel()
+        tel.on_admitted("r", 2)
+        st = tel.on_first_token("r", 24, 16)
+        assert st.admit_ts == st.sched_ts == st.enqueue_ts
+        assert (st.queued_s, st.decodes_between) == (0.0, 0)
+        assert st.first_token_split()["prefill_ns"] == int(
+            (st.first_token_ts - st.enqueue_ts) * 1e9)
 
 
 # -- the router's and the pool's phases ---------------------------------------
