@@ -5,9 +5,13 @@ of 512 tokens and ``kda_step`` over 8 rows, each Pallas kernel against the
 recurrence run a token at a time in float32, on keys that lie in one
 orthant with neighbours nearly parallel (what a conv and a SiLU leave),
 ``beta`` in (1, 2) and decays that take a channel from e^0 to e^-50 and
-below over a page; then each kernel's time, and the scalar form's on the
-same shapes (the step's times include a copy of the pool, which the
-engine's donated pool does not pay: read them against each other).
+below over a page; both steps against their XLA forms on the device itself
+with rows of the spare slot behind, between and before the live ones
+(``bench_mamba2.against_xla_form``); then each kernel's time and the scalar
+form's on the same shapes: a scan's single calls, and the steps over the
+cell's 6 linear layers chained in one program with the donated pool carried
+in place, at 1, 2 and 8 live rows of the decode shape's 8, in GB/s of the
+live rows' states (``bench_mamba2.time_steps``).
 
   chiprun -- python3 hack/bench_kda.py       # one v5e, ~1 min
   python3 hack/bench_kda.py --rehearse       # the CPU, toy sizes, no times
@@ -174,45 +178,48 @@ def main() -> None:
           f"{max(rel(o1[r], want[r][1][0]) for r in range(rows)):.2e} state "
           f"{max(rel(pool[1, at[r]], want[r][0]) for r in range(rows)):.2e}",
           flush=True)
-    if toy:
-        return
-    for name, fn, n in (("kda_scan", scan, 20),):
-        t0 = time.perf_counter()
-        for _ in range(n):
-            out = fn()
-        jax.block_until_ready(out)
-        print(f"{name}: {(time.perf_counter() - t0) / n * 1e3:.3f} ms a "
-              f"layer's chunk of {tokens}", flush=True)
-    t0 = time.perf_counter()
-    for _ in range(50):
-        o1, pool = step(pool)
-    jax.block_until_ready(pool)
-    print(f"kda_step: {(time.perf_counter() - t0) / 50 * 1e3:.3f} ms a "
-          f"layer's step of {rows} rows "
-          f"({rows * heads * d * d * 8 / 819e9 * 1e3:.3f} ms at 819 GB/s)",
-          flush=True)
-    # The scalar form on the same shapes (one decay a head), for scale.
+    from bench_mamba2 import against_xla_form, time_steps
     from llmd_kv_cache_tpu.ops.gated_deltanet import gdn_scan, gdn_step
 
     g1 = g[..., 0]
-    scalar = lambda: gdn_scan(q, k, v, g1, beta / 2, state, jnp.int32(1),
-                              block=page, kernel=True)
-    jax.block_until_ready(scalar())
+
+    def channelwise(pool, layer, at, salt, kernel=True):
+        live = (at != 0)[:, None]
+        return kda_step(pool, layer, at, q[:rows] + salt, k[:rows], v[:rows],
+                        g[:rows] * live[..., None], beta[:rows] * live,
+                        kernel=kernel, interpret=toy and kernel)
+
+    def scalar(pool, layer, at, salt, kernel=True):
+        live = (at != 0)[:, None]
+        return gdn_step(pool, layer, at, q[:rows] + salt, k[:rows], v[:rows],
+                        g1[:rows] * live, beta[:rows] / 2 * live,
+                        kernel=kernel, interpret=toy and kernel)
+
+    against_xla_form("kda_step", channelwise, pool, at)
+    against_xla_form("gdn_step", scalar, pool, at)
+    if toy:
+        return
     t0 = time.perf_counter()
     for _ in range(20):
-        out = scalar()
+        out = scan()
+    jax.block_until_ready(out)
+    print(f"kda_scan: {(time.perf_counter() - t0) / 20 * 1e3:.3f} ms a "
+          f"layer's chunk of {tokens}", flush=True)
+    # The scalar form on the same shapes (one decay a head), for scale.
+    scalar_scan = lambda: gdn_scan(q, k, v, g1, beta / 2, state, jnp.int32(1),
+                                   block=page, kernel=True)
+    jax.block_until_ready(scalar_scan())
+    t0 = time.perf_counter()
+    for _ in range(20):
+        out = scalar_scan()
     jax.block_until_ready(out)
     print(f"gdn_scan: {(time.perf_counter() - t0) / 20 * 1e3:.3f} ms",
           flush=True)
-    old = lambda p: gdn_step(p, 1, at, q[:rows], k[:rows], v[:rows],
-                             g1[:rows], beta[:rows] / 2, kernel=True)
-    o1, pool = jax.block_until_ready(old(pool))
-    t0 = time.perf_counter()
-    for _ in range(50):
-        o1, pool = old(pool)
-    jax.block_until_ready(pool)
-    print(f"gdn_step: {(time.perf_counter() - t0) / 50 * 1e3:.3f} ms",
-          flush=True)
+    # The steps over the linear layers of a decode program, the pool
+    # carried in place: 1, 2 and 8 live rows of the decode shape's 8.
+    pool = time_steps("kda_step", channelwise, pool, at, layers,
+                      (1, 2, rows))
+    time_steps("gdn_step", scalar, pool, at, layers, (1, 2, rows))
 
 
 if __name__ == "__main__":

@@ -21,7 +21,12 @@ kernels are tested against) and as a Pallas kernel (what a TPU serves):
   at the end of one requested block: a snapshot at a block boundary costs
   no second pass and splits no chunk.
 - ``gdn_step``: one token of every row of a decode batch, the rows' states
-  updated in place in the (donated) pool under their slot ids.
+  updated in place in the (donated) pool under their slot ids. Rows that
+  decode nothing name the spare slot 0 and hand in ``g = beta = 0``, which
+  leaves a state as it was: the XLA form reads and writes the spare slot
+  so, once a padded row; the kernel neither reads nor writes it, and walks
+  the live rows' states alone (``_live_walk``, shared with ``kda_step`` and
+  ``ops.mamba2``'s ``mamba2_step``).
 
 The jitted wrappers' names are what a device trace calls the kernels
 (``gdn_scan.<n>``, ``gdn_step.<n>``); readers of traces match them.
@@ -53,6 +58,7 @@ bfloat16 passes), and the state stays float32.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -269,55 +275,165 @@ def gdn_scan(q, k, v, g, beta, state, snap_block, block: int,
     return o.transpose(1, 0, 2), st, snap
 
 
-def _step_kernel(slot_ref, layer_ref, q_ref, k_ref, v_ref, a_ref, b_ref,
-                 pool_ref, o_ref, out_ref, *, heads):
-    del slot_ref, layer_ref  # the index maps read them
+# A grid step of a decode step moves at most this much of a row's state in,
+# and as much out: half of a state of the three served models (64 tiles of
+# [128, 128] float32). A full batch moves 600 GB/s at any block from 512 KiB
+# up; a row that decodes nothing costs its grid steps, about 0.35 us each,
+# so few large blocks serve a batch of few live rows best, and a block of
+# the whole state gains no more (``hack/bench_mamba2.py``, ``bench_kda.py``;
+# ``PERF.md`` section 6, PR 60).
+_STEP_BLOCK_BYTES = 2 * 2 ** 20
+
+
+def _tiles_a_step(tiles: int, tile_bytes: int) -> int:
+    """How many of a state's ``tiles`` (heads, or lane tiles of heads) one
+    grid step of a decode step takes: the most that ``_STEP_BLOCK_BYTES``
+    holds, in whole sublane tiles of 8 (the rows' vectors are blocked with
+    them), else all of them."""
+    if tiles % 8:
+        return tiles
+    most = max(8, _STEP_BLOCK_BYTES // tile_bytes)
+    return max(t for t in range(8, tiles + 1, 8)
+               if tiles % t == 0 and t <= most)
+
+
+def _step_params(block_bytes: int):
+    """Room for a state block in and out, each twice buffered, and the
+    body's own values beside them."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=4 * block_bytes + 12 * 2 ** 20)
+
+
+def _live_walk(slots, layer, groups: int):
+    """What a decode step's grid ``(rows, groups)`` walks, from the rows'
+    slots alone: a row is live where its slot is not the spare slot 0, and
+    only a live row's state is read, updated and written.
+
+    Returns ``(scalars, vec, out, state)``: the kernel's prefetched scalars
+    ``(slot_at [rows], stand [rows], layer [1])`` and the index maps of a
+    row's vectors (blocks ``[1, tiles, width]``: ``vec`` what the kernel
+    reads, ``out`` what it writes, every block of every row) and of the
+    pool (blocks ``[1, 1, tiles, ., .]``), read and written under the same
+    map. ``stand[r]`` is -1 for a live row, which walks its own slot's
+    groups ``j`` in order. A row that decodes nothing stands still instead:
+    at the last group of the live row before it, the block that row has
+    just left behind, or, with no live row before it, at group 0 of the
+    live row after it, the block that row starts with. Pallas copies a
+    block in when its index changes and out before it changes, so a step
+    whose block stands still moves nothing, and what a live row wrote goes
+    out once, when the walk moves on (``_step_row`` guards the body). With
+    no live row at all the walk stands at the spare slot's group 0, which
+    ``_step_row`` hands through as it was."""
+    slots = slots.astype(jnp.int32)
+    rows = slots.shape[0]
+    at = jnp.arange(rows, dtype=jnp.int32)
+    live = slots != 0
+    before = jax.lax.cummax(jnp.where(live, at, -1))
+    after = jax.lax.cummin(jnp.where(live, at, rows), reverse=True)
+    slot_at = slots[jnp.where(before >= 0, before,
+                              jnp.minimum(after, rows - 1))]
+    stand = jnp.where(live, -1, jnp.where(before >= 0, groups - 1, 0))
+    scalars = (slot_at, stand.astype(jnp.int32),
+               jnp.reshape(layer, (1,)).astype(jnp.int32))
+
+    def group(r, j, stand_ref):
+        return jnp.where(stand_ref[r] < 0, j, stand_ref[r])
+
+    def vec(r, j, slot_ref, stand_ref, layer_ref):
+        return (r, group(r, j, stand_ref), 0)
+
+    def out(r, j, *_):
+        return (r, j, 0)
+
+    def state(r, j, slot_ref, stand_ref, layer_ref):
+        return (layer_ref[0], slot_ref[r], group(r, j, stand_ref), 0, 0)
+
+    return scalars, vec, out, state
+
+
+def _step_row(slot_ref, stand_ref, pool_ref, out_ref, o_ref, update):
+    """One grid step of ``_live_walk``: ``update()`` for a live row; for a
+    row that decodes nothing, zeros for its output and no touch of the
+    state block, which keeps what the live row it stands at left there."""
+    r, j = pl.program_id(0), pl.program_id(1)
+    pl.when(stand_ref[r] < 0)(update)
+
+    @pl.when(stand_ref[r] >= 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when((r == 0) & (j == 0) & (slot_ref[0] == 0))
+    def _():  # nothing is live: the block that goes out is the spare slot's
+        out_ref[...] = pool_ref[...]
+
+
+def _step_kernel(slot_ref, stand_ref, layer_ref, q_ref, k_ref, v_ref, a_ref,
+                 b_ref, pool_ref, o_ref, out_ref, *, heads, channelwise):
+    del layer_ref  # the index maps read it
     dk = q_ref.shape[-1]
     row = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
     eye = (row == col).astype(jnp.float32)
-    for h in range(heads):
-        at = slice(h, h + 1)
-        st = pool_ref[0, 0, h] * a_ref[0, at, :]                 # decay
-        kcol = _column(eye, k_ref[0, at, :])
-        sk = jnp.sum(kcol * st, axis=0, keepdims=True)           # [1, dv]
-        st = st + kcol * (b_ref[0, at, :] * (v_ref[0, at, :] - sk))
-        out_ref[0, 0, h] = st
-        o_ref[0, at, :] = jnp.sum(_column(eye, q_ref[0, at, :]) * st,
-                                  axis=0, keepdims=True)
+
+    sub = math.gcd(heads, 8)
+
+    def group(i, _):
+        # A sublane tile of heads: their vectors loaded once, aligned, and
+        # taken apart by static slices.
+        base = pl.multiple_of(i * sub, sub)
+        q, k, v, a, b = (ref[0, pl.ds(base, sub), :]
+                         for ref in (q_ref, k_ref, v_ref, a_ref, b_ref))
+        for u in range(sub):
+            at = slice(u, u + 1)
+            st = pool_ref[0, 0, base + u] * (
+                _column(eye, a[at]) if channelwise else a[at])   # decay
+            kcol = _column(eye, k[at])
+            sk = jnp.sum(kcol * st, axis=0, keepdims=True)       # [1, dv]
+            st = st + kcol * (b[at] * (v[at] - sk))
+            out_ref[0, 0, base + u] = st
+            o_ref[0, pl.ds(base + u, 1), :] = jnp.sum(
+                _column(eye, q[at]) * st, axis=0, keepdims=True)
+
+    def update():
+        # A loop over the block's tiles of heads and not its unrolling: a
+        # program lowers the kernel once a layer and decode shape.
+        jax.lax.fori_loop(0, heads // sub, group, None)
+
+    _step_row(slot_ref, stand_ref, pool_ref, out_ref, o_ref, update)
 
 
 def _step_pallas(pool, layer, slots, q, k, v, alpha, beta, interpret,
-                 step=_step_kernel):
-    """``alpha [rows, Hv]`` (one decay a head, handed to the kernel over
-    the value lanes) or ``[rows, Hv, dk]`` (one a key channel)."""
+                 channelwise=False):
+    """``alpha [rows, Hv, dv]`` (one decay a head, over the value lanes) or,
+    ``channelwise``, ``[rows, Hv, dk]`` (one a key channel)."""
     rows, hv, dk = q.shape
     dv = v.shape[-1]
-    heads = 8 if hv % 8 == 0 else hv
+    heads = _tiles_a_step(hv, dk * dv * 4)
+    scalars, vec, out, state = _live_walk(slots, layer, hv // heads)
 
-    def vec(width):
-        return pl.BlockSpec((1, heads, width), lambda r, j, *_: (r, j, 0))
-
-    def state(r, j, slot_ref, layer_ref):
-        return (layer_ref[0], slot_ref[r], j, 0, 0)
+    def vecs(width):
+        return pl.BlockSpec((1, heads, width), vec)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(rows, hv // heads),
-        in_specs=[vec(dk), vec(dk), vec(dv), vec(alpha.shape[-1]), vec(dv),
-                  pl.BlockSpec((1, 1, heads, dk, dv), state)],
-        out_specs=[vec(dv), pl.BlockSpec((1, 1, heads, dk, dv), state)],
+        in_specs=[vecs(dk), vecs(dk), vecs(dv), vecs(alpha.shape[-1]),
+                  vecs(dv), pl.BlockSpec((1, 1, heads, dk, dv), state)],
+        out_specs=[pl.BlockSpec((1, heads, dv), out),
+                   pl.BlockSpec((1, 1, heads, dk, dv), state)],
     )
     o, pool = pl.pallas_call(
-        functools.partial(step, heads=heads),
+        functools.partial(_step_kernel, heads=heads,
+                          channelwise=channelwise),
         out_shape=[jax.ShapeDtypeStruct((rows, hv, dv), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         grid_spec=grid_spec,
-        # Operand 7 (behind the two scalars) is the pool: updated in place.
-        input_output_aliases={7: 1},
+        # Operand 8 (behind the three scalars) is the pool: updated in
+        # place.
+        input_output_aliases={8: 1},
+        compiler_params=_step_params(heads * dk * dv * 4),
         interpret=interpret,
-    )(slots.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-      q, k, v, alpha,
+    )(*scalars, q, k, v, alpha,
       jnp.broadcast_to(beta[..., None], v.shape), pool)
     return o, pool
 
@@ -337,9 +453,13 @@ def gdn_step(pool, layer, slots, q, k, v, g, beta, kernel: bool = False,
              interpret: bool = False):
     """One token of every row. ``pool [layers, slots, Hv, dk, dv]``
     float32 (donated; row ``r``'s state is ``pool[layer, slots[r]]``, and
-    rows that decode nothing share the spare slot 0), ``q, k [rows, Hk,
-    dk]``, ``v [rows, Hv, dv]``, ``g, beta [rows, Hv]``. Returns ``(o
-    [rows, Hv, dv] float32, pool)``."""
+    rows that decode nothing share the spare slot 0 and hand in ``g = beta
+    = 0``), ``q, k [rows, Hk, dk]``, ``v [rows, Hv, dv]``, ``g, beta [rows,
+    Hv]``. Returns ``(o [rows, Hv, dv] float32, pool)``. The XLA form
+    updates the spare slot as any other (by 1 and 0: it stays what it
+    was); the kernel reads ``slots`` and neither reads nor writes it, nor
+    any slot no row names, and a row of the spare slot gets zeros for its
+    output."""
     f32 = jnp.float32
     rep = v.shape[1] // q.shape[1]
     q, k = (jnp.repeat(x.astype(f32), rep, axis=1) for x in (q, k))
@@ -615,33 +735,17 @@ def kda_scan(q, k, v, g, beta, state, snap_block, block: int,
     return o.transpose(1, 0, 2), st, snap
 
 
-def _kda_step_kernel(slot_ref, layer_ref, q_ref, k_ref, v_ref, a_ref, b_ref,
-                     pool_ref, o_ref, out_ref, *, heads):
-    del slot_ref, layer_ref  # the index maps read them
-    dk = q_ref.shape[-1]
-    row = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
-    eye = (row == col).astype(jnp.float32)
-    for h in range(heads):
-        at = slice(h, h + 1)
-        st = pool_ref[0, 0, h] * _column(eye, a_ref[0, at, :])   # decay
-        kcol = _column(eye, k_ref[0, at, :])
-        sk = jnp.sum(kcol * st, axis=0, keepdims=True)           # [1, dv]
-        st = st + kcol * (b_ref[0, at, :] * (v_ref[0, at, :] - sk))
-        out_ref[0, 0, h] = st
-        o_ref[0, at, :] = jnp.sum(_column(eye, q_ref[0, at, :]) * st,
-                                  axis=0, keepdims=True)
-
-
 @functools.partial(jax.jit, static_argnames=("kernel", "interpret"),
                    donate_argnames=("pool",))
 def kda_step(pool, layer, slots, q, k, v, g, beta, kernel: bool = False,
              interpret: bool = False):
     """``gdn_step`` with a decay for every key channel: ``q, k, g [rows, H,
-    dk]``, ``v [rows, H, dv]``, ``beta [rows, H]``."""
+    dk]``, ``v [rows, H, dv]``, ``beta [rows, H]``; as there, the kernel
+    moves the states of the rows whose slot is not the spare slot 0 and no
+    other."""
     f32 = jnp.float32
     args = (pool, layer, slots, q.astype(f32), k.astype(f32), v.astype(f32),
             jnp.exp(g.astype(f32)), beta.astype(f32))
     if kernel:
-        return _step_pallas(*args, interpret, step=_kda_step_kernel)
+        return _step_pallas(*args, interpret, channelwise=True)
     return _step_xla(*args)

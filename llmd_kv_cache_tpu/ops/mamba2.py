@@ -35,7 +35,12 @@ against) and as a Pallas kernel (what a TPU serves):
   most 0 (``gc_i - gc_j`` for ``j <= i``, ``gc_last - gc_j``, ``gc_i``),
   never a quotient of two ``exp`` that each may overflow.
 - ``mamba2_step``: one token of every row of a decode batch, the rows'
-  states updated in place in the (donated) pool under their slot ids.
+  states updated in place in the (donated) pool under their slot ids. Rows
+  that decode nothing name the spare slot 0 and hand in ``dt = 0``, which
+  leaves a state as it was: the XLA form reads and writes the spare slot
+  so, once a padded row; the kernel neither reads nor writes it, and walks
+  the live rows' states alone (``gated_deltanet._live_walk``, which the
+  delta rule's two step kernels share).
 
 Every product is float32 at ``Precision.HIGHEST`` and the state stays
 float32. The jitted wrappers' names are what a device trace calls the
@@ -53,7 +58,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gated_deltanet import _HIGHEST, _column, _dot, _dot_nt, _dot_tn
+from .gated_deltanet import (_HIGHEST, _column, _dot, _dot_nt, _dot_tn,
+                              _live_walk, _step_params, _step_row,
+                              _tiles_a_step)
 
 KERNEL_SCAN = "mamba2_scan"
 KERNEL_STEP = "mamba2_step"
@@ -247,44 +254,48 @@ def mamba2_scan(x, B, C, dt, A, D, state, snap_block, block: int,
     return y.reshape(t, h, p), st, snap
 
 
-def _step_kernel(slot_ref, layer_ref, a_ref, dx_ref, b_ref, c_ref, pool_ref,
-                 y_ref, out_ref, *, tiles):
-    del slot_ref, layer_ref  # the index maps read them
+def _step_kernel(slot_ref, stand_ref, layer_ref, a_ref, dx_ref, b_ref, c_ref,
+                 pool_ref, y_ref, out_ref, *, tiles):
+    del layer_ref  # the index maps read it
     n = b_ref.shape[-1]
     row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
     eye = (row == col).astype(jnp.float32)
-    bcol, ccol = _column(eye, b_ref[0]), _column(eye, c_ref[0])
-    for j in range(tiles):
-        at = slice(j, j + 1)
-        st = pool_ref[0, 0, j] * a_ref[0, at, :] + bcol * dx_ref[0, at, :]
-        out_ref[0, 0, j] = st
-        y_ref[0, at, :] = jnp.sum(ccol * st, axis=0, keepdims=True)
+
+    def update():
+        bcol, ccol = _column(eye, b_ref[0]), _column(eye, c_ref[0])
+
+        def tile(j, _):
+            at = pl.ds(j, 1)
+            st = pool_ref[0, 0, j] * a_ref[0, at, :] + bcol * dx_ref[0, at, :]
+            out_ref[0, 0, j] = st
+            y_ref[0, at, :] = jnp.sum(ccol * st, axis=0, keepdims=True)
+
+        # A loop and not its unrolling: a program lowers the kernel once a
+        # layer and decode shape, and the block's tiles are many.
+        jax.lax.fori_loop(0, tiles, tile, None)
+
+    _step_row(slot_ref, stand_ref, pool_ref, out_ref, y_ref, update)
 
 
 def _step_pallas(pool, layer, slots, a, dx, b, c, interpret):
     rows, g, w = a.shape
     n = b.shape[-1]
-    tiles = 8 if g % 8 == 0 else g
-
-    def lanes(r, j, *_):
-        return (r, j, 0)
+    tiles = _tiles_a_step(g, n * w * 4)
+    scalars, vec, out, state = _live_walk(slots, layer, g // tiles)
 
     def once(r, j, *_):
         return (r, 0, 0)
 
-    def state(r, j, slot_ref, layer_ref):
-        return (layer_ref[0], slot_ref[r], j, 0, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(rows, g // tiles),
-        in_specs=[pl.BlockSpec((1, tiles, w), lanes),
-                  pl.BlockSpec((1, tiles, w), lanes),
+        in_specs=[pl.BlockSpec((1, tiles, w), vec),
+                  pl.BlockSpec((1, tiles, w), vec),
                   pl.BlockSpec((1, 1, n), once),
                   pl.BlockSpec((1, 1, n), once),
                   pl.BlockSpec((1, 1, tiles, n, w), state)],
-        out_specs=[pl.BlockSpec((1, tiles, w), lanes),
+        out_specs=[pl.BlockSpec((1, tiles, w), out),
                    pl.BlockSpec((1, 1, tiles, n, w), state)],
     )
     return pl.pallas_call(
@@ -292,11 +303,12 @@ def _step_pallas(pool, layer, slots, a, dx, b, c, interpret):
         out_shape=[jax.ShapeDtypeStruct((rows, g, w), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         grid_spec=grid_spec,
-        # Operand 6 (behind the two scalars) is the pool: updated in place.
-        input_output_aliases={6: 1},
+        # Operand 7 (behind the three scalars) is the pool: updated in
+        # place.
+        input_output_aliases={7: 1},
+        compiler_params=_step_params(tiles * n * w * 4),
         interpret=interpret,
-    )(slots.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-      a, dx, b[:, None, :], c[:, None, :], pool)
+    )(*scalars, a, dx, b[:, None, :], c[:, None, :], pool)
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "interpret"),
@@ -305,10 +317,13 @@ def mamba2_step(pool, layer, slots, x, B, C, dt, A, D, kernel: bool = False,
                 interpret: bool = False):
     """One token of every row. ``pool [layers, slots, G, N, W]`` float32
     (donated; row ``r``'s state is ``pool[layer, slots[r]]``, and rows that
-    decode nothing share the spare slot 0 and hand in ``dt = 0``, which
-    leaves a state as it was), ``x [rows, H, P]``, ``B, C [rows, N]``, ``dt
-    [rows, H]`` (after the softplus), ``A, D [H]``. Returns ``(y [rows, H,
-    P] float32, pool)``."""
+    decode nothing share the spare slot 0 and hand in ``dt = 0``), ``x
+    [rows, H, P]``, ``B, C [rows, N]``, ``dt [rows, H]`` (after the
+    softplus), ``A, D [H]``. Returns ``(y [rows, H, P] float32, pool)``.
+    The XLA form updates the spare slot as any other (``dt = 0`` decays it
+    by 1 and adds 0: it stays what it was); the kernel reads ``slots`` and
+    neither reads nor writes it, nor any slot no row names, and a row of
+    the spare slot gets ``D x`` alone for its output."""
     f32 = jnp.float32
     rows, h, p = x.shape
     g, _, w = pool.shape[2:]

@@ -12,6 +12,7 @@ from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
+import live_rows
 import numpy as np
 import pytest
 
@@ -22,6 +23,7 @@ from kvbench.harness import fleet as F, names  # noqa: E402
 from llmd_kv_cache_tpu.models import llama  # noqa: E402
 from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine  # noqa: E402
 from llmd_kv_cache_tpu.models.hf_loader import config_from_hf  # noqa: E402
+from llmd_kv_cache_tpu.ops import gated_deltanet as gd  # noqa: E402
 from llmd_kv_cache_tpu.ops.gated_deltanet import gdn_scan, gdn_step  # noqa: E402
 
 CONFIG = "gigachat3.5-ep16-l5"
@@ -145,6 +147,62 @@ def test_decode_step_updates_the_rows_slots_in_place(kernel):
         np.testing.assert_allclose(o[r], out[0], atol=2e-5)
         want[1, slot] = states[0]
     np.testing.assert_allclose(new, want, atol=2e-5)  # nothing else moved
+
+
+@live_rows.CASES
+def test_decode_step_moves_the_live_rows_states_and_no_other(slots,
+                                                             monkeypatch):
+    """A row of the spare slot 0 costs the kernel no state: 16 heads a
+    state, 8 a grid step, rows that hand in ``g = beta = 0`` as the
+    engine's do."""
+    hv, dk, dv = 16, 16, 8
+    live_rows.two_groups_a_row(monkeypatch, hv * dk * dv * 4)
+    q, k, v, g, beta, _ = inputs(live_rows.ROWS, live_rows.ROWS, seed=7,
+                                 hv=hv, dk=dk, dv=dv)
+    live = (np.asarray(slots) != 0)[:, None]
+    g, beta = g * live, beta * live
+    pool = np.random.default_rng(7).normal(
+        size=(2, live_rows.SLOTS, hv, dk, dv)).astype(np.float32)
+
+    def step(pool, slots, kernel):
+        return gdn_step.__wrapped__(pool, 1, slots, q, k, v, g, beta,
+                                    kernel=kernel, interpret=kernel)
+
+    live_rows.check(step, pool, slots)
+
+
+@pytest.mark.parametrize("slots, slot_at, stand", [
+    ((3, 5, 0, 0), (3, 5, 5, 5), (-1, -1, 1, 1)),
+    ((3, 0, 5, 0), (3, 3, 5, 5), (-1, 1, -1, 1)),
+    ((0, 0, 6, 2), (6, 6, 6, 2), (0, 0, -1, -1)),
+    ((4, 2, 5, 6), (4, 2, 5, 6), (-1, -1, -1, -1)),
+    ((0, 0, 0, 0), (0, 0, 0, 0), (0, 0, 0, 0))],
+    ids=["live_first", "spare_between", "spare_first", "all_live",
+         "none_live"])
+def test_a_row_that_decodes_nothing_stands_at_a_live_rows_block(
+        slots, slot_at, stand):
+    """The walk of the three step kernels (``_live_walk``, two groups a
+    row): a live row walks its own slot's groups (``stand`` -1); a row of
+    the spare slot stands at the last group of the live row before it, or
+    at the first group of the live row after it where none is before, so
+    its grid steps name no block that is not already in fast memory, and
+    the spare slot's is named only where nothing is live."""
+    (got_slot, got_stand, layer), vec, out, state = gd._live_walk(
+        jnp.asarray(slots, jnp.int32), 1, 2)
+    assert tuple(np.asarray(got_slot)) == slot_at
+    assert tuple(np.asarray(got_stand)) == stand
+    blocks = [tuple(int(i) for i in state(r, j, got_slot, got_stand, layer))
+              for r in range(4) for j in range(2)]
+    assert [b[1] for b in blocks] == [s for s in slot_at for _ in range(2)]
+    # A block index changes only where a live row moves on: as many
+    # copies in as live rows x groups, and one at least.
+    changes = 1 + sum(a != b for a, b in zip(blocks, blocks[1:]))
+    assert changes == max(1, 2 * sum(s != 0 for s in slots))
+    assert [tuple(int(i) for i in out(r, j)) for r in range(4)
+            for j in range(2)] == [(r, j, 0) for r in range(4)
+                                   for j in range(2)]
+    assert all(int(vec(r, j, got_slot, got_stand, layer)[0]) == r
+               for r in range(4) for j in range(2))
 
 
 # -- the engine against the reference ----------------------------------------

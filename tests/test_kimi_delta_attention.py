@@ -13,6 +13,7 @@ from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
+import live_rows
 import numpy as np
 import pytest
 
@@ -249,6 +250,28 @@ def test_channelwise_step_updates_the_rows_slots_in_place(kernel):
         np.testing.assert_allclose(o[r], out[0], atol=2e-5)
         want[1, slot] = states[0]
     np.testing.assert_allclose(new, want, atol=2e-5)  # nothing else moved
+
+
+@live_rows.CASES
+def test_channelwise_step_moves_the_live_rows_states_and_no_other(
+        slots, monkeypatch):
+    """A row of the spare slot 0 costs the kernel no state: 16 heads a
+    state, 8 a grid step, rows that hand in ``g = beta = 0`` as the
+    engine's do."""
+    heads, dk, dv = 16, 32, 16
+    live_rows.two_groups_a_row(monkeypatch, heads * dk * dv * 4)
+    q, k, v, g, beta, _ = inputs(live_rows.ROWS, live_rows.ROWS, 1, seed=8,
+                                 heads=heads, dk=dk, dv=dv)
+    live = (np.asarray(slots) != 0)[:, None]
+    g, beta = g * live[..., None], beta * live
+    pool = np.random.default_rng(8).normal(
+        size=(2, live_rows.SLOTS, heads, dk, dv)).astype(np.float32)
+
+    def step(pool, slots, kernel):
+        return kda_step.__wrapped__(pool, 1, slots, q, k, v, g, beta,
+                                    kernel=kernel, interpret=kernel)
+
+    live_rows.check(step, pool, slots)
 
 
 def test_the_kernels_names_are_what_a_trace_calls_them():

@@ -7,6 +7,7 @@ which has to fail where the float32 one passes."""
 import re
 
 import jax.numpy as jnp
+import live_rows
 import numpy as np
 import pytest
 
@@ -194,6 +195,27 @@ def test_the_step_is_one_token_of_the_scan_and_of_the_recurrence(kernel):
     np.testing.assert_allclose(new, want, atol=2e-5)   # nothing else moved
     np.testing.assert_array_equal(new[1, 0], pool[1, 0])
     np.testing.assert_array_equal(new[0], pool[0])
+
+
+@live_rows.CASES
+def test_the_step_moves_the_live_rows_states_and_no_other(slots, monkeypatch):
+    """A row of the spare slot 0 costs the kernel no state: 16 tiles a
+    state, 8 a grid step, rows that hand in ``dt = 0`` as the engine's do."""
+    heads, p, n = 32, 64, 16
+    shape = m2.state_shape(heads, p, n)
+    assert shape == (16, 16, 128)
+    live_rows.two_groups_a_row(monkeypatch, int(np.prod(shape)) * 4)
+    x, b, c, dt, a, skip, _ = inputs(live_rows.ROWS, live_rows.ROWS, seed=6,
+                                     heads=heads, p=p, n=n)
+    dt = dt * (np.asarray(slots) != 0)[:, None]
+    pool = np.random.default_rng(6).normal(
+        size=(2, live_rows.SLOTS, *shape)).astype(np.float32)
+
+    def step(pool, slots, kernel):
+        return mamba2_step.__wrapped__(pool, 1, slots, x, b, c, dt, a, skip,
+                                       kernel=kernel, interpret=kernel)
+
+    live_rows.check(step, pool, slots)
 
 
 def test_the_kernels_names_are_what_a_trace_calls_them():
