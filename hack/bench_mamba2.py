@@ -20,6 +20,11 @@ that no live row names compared bit for bit with what it held.
 
   chiprun -- python3 hack/bench_mamba2.py       # one v5e, ~1 min
   python3 hack/bench_mamba2.py --rehearse       # the CPU, toy sizes, no times
+
+Another shape by ``--heads``, ``--head-dim``, ``--state``, ``--groups`` (of B
+and C; head ``h`` reads group ``h // (heads / groups)``) and ``--rows``:
+``falcon-h1-34b-l9``'s is ``--heads 32 --head-dim 128 --state 256 --groups 2
+--rows 12`` (one head a tile of ``[256, 128]``).
 """
 
 from __future__ import annotations
@@ -34,10 +39,10 @@ import numpy as np
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
-def inputs(rng, tokens, heads, p, n):
-    """x, B, C as a conv over 4 tokens and a SiLU leave them; the step a
-    head log-uniform in [1e-3, 1e-1] times a token's own factor; A in
-    [1, 16)."""
+def inputs(rng, tokens, heads, p, n, groups=1):
+    """x, B, C (``[tokens, groups, n]``) as a conv over 4 tokens and a SiLU
+    leave them; the step a head log-uniform in [1e-3, 1e-1] times a token's
+    own factor; A in [1, 16)."""
     def behind_silu(width):
         raw = rng.normal(size=(tokens + 3, width))
         mixed = sum(0.5 * raw[j:j + tokens] for j in range(4))
@@ -47,19 +52,25 @@ def inputs(rng, tokens, heads, p, n):
     dt = (np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), size=(1, heads)))
           * rng.uniform(0.5, 4.0, size=(tokens, heads)))
     a = -rng.uniform(1.0, 16.0, size=(heads,))
-    return x, behind_silu(n), behind_silu(n), dt, a, np.ones((heads,))
+    return (x, behind_silu(groups * n).reshape(tokens, groups, n),
+            behind_silu(groups * n).reshape(tokens, groups, n), dt, a,
+            np.ones((heads,)))
 
 
 def token_at_a_time(x, b, c, dt, a, skip, state):
-    """``state [H, P, N]``: returns (the end state, y [T, H, P])."""
+    """``state [H, P, N]``, ``b, c [T, groups, N]``: returns (the end state,
+    y [T, H, P])."""
     import jax
     import jax.numpy as jnp
 
+    per = x.shape[1] // b.shape[1]                     # heads a group
+
     def token(s, at):
         x_t, b_t, c_t, d_t = at
+        b_t, c_t = jnp.repeat(b_t, per, 0), jnp.repeat(c_t, per, 0)  # [H, N]
         s = (jnp.exp(d_t * a)[:, None, None] * s
-             + (d_t[:, None] * x_t)[:, :, None] * b_t[None, None, :])
-        y = jnp.einsum("hpn,n->hp", s, c_t, precision="highest")
+             + (d_t[:, None] * x_t)[:, :, None] * b_t[:, None, :])
+        y = jnp.einsum("hpn,hn->hp", s, c_t, precision="highest")
         return s, y + skip[:, None] * x_t
 
     return jax.lax.scan(token, state, (x, b, c, dt))
@@ -151,6 +162,11 @@ def time_steps(name, step, pool, at, layers, lives, reps=10):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--heads", type=int, default=0)
+    ap.add_argument("--head-dim", type=int, default=0)
+    ap.add_argument("--state", type=int, default=0)
+    ap.add_argument("--groups", type=int, default=1)
+    ap.add_argument("--rows", type=int, default=0)
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -164,10 +180,13 @@ def main() -> None:
     tokens, heads, p, n, page, rows, layers, slots = (
         (64, 4, 16, 16, 32, 3, 2, 5) if toy
         else (512, 128, 64, 128, 64, 16, 9, 41))
+    heads, p, n, rows = (args.heads or heads, args.head_dim or p,
+                         args.state or n, args.rows or rows)
     rng = np.random.default_rng(57)
     f32 = jnp.float32
     x, b, c, dt, a, skip = (jnp.asarray(v, f32)
-                            for v in inputs(rng, tokens, heads, p, n))
+                            for v in inputs(rng, tokens, heads, p, n,
+                                            args.groups))
     state = jnp.asarray(rng.normal(size=(heads, p, n)), f32)
     over_page = (dt[:page].sum(0) * a)
     print(f"device {jax.devices()[0].device_kind}; a head's log-decay over "
@@ -186,7 +205,8 @@ def main() -> None:
         y, end, snap = jax.block_until_ready(m2.mamba2_scan(
             x, b, c, dt, a, skip, tiles, jnp.int32(1), block=page,
             kernel=kernel, interpret=toy))
-        print(f"mamba2_scan {tokens} x {heads} heads, "
+        print(f"mamba2_scan {tokens} x {heads} heads of {p} over a state "
+              f"of {n}, {args.groups} group(s), "
               f"{'kernel' if kernel else 'XLA form'}: finite "
               f"{bool(jnp.isfinite(y).all() and jnp.isfinite(end).all())} "
               f"y {rel(y, want_y):.2e} end "

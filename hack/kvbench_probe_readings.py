@@ -9,6 +9,10 @@ chip (``chiprun -- python3 hack/kvbench_probe_readings.py ...``) or, with
   --seeds 1,2,3        the probe against the served program
   --margins            also the reference's ``margin_readings`` (how far
                        bfloat16 moves a router's deciding gaps)
+  --branches           also the reference's ``branch_sizes`` where it has
+                       them (a layer's residual and what each branch adds
+                       to it, over 512 tokens): what a configuration's
+                       initialisation scales are set from
   --control TYPE       the probe against the reference's own ``Control``
                        (its forward rounded to TYPE, e.g. float8_e4m3fn;
                        ``state:bfloat16`` where the reference keeps a
@@ -26,12 +30,17 @@ chip (``chiprun -- python3 hack/kvbench_probe_readings.py ...``) or, with
                        ``residual_multiplier`` served without it;
                        ``xla-recurrence`` is no fault: the recurrence's
                        XLA form in the kernels' place, which has to read
-                       as the kernels do)
+                       as the kernels do; a model of two mixers a layer:
+                       ``norm-all-channels``, ``wrong-group``,
+                       ``no-key-multiplier``, ``out-multipliers-swapped``,
+                       ``no-rope``). Several, comma-separated, are planted
+                       one after another in one process
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
@@ -139,13 +148,84 @@ def _verify_mask_off_by_one() -> None:
     ppa._decode_mask = off_by_one
 
 
+def _norm_over_all_channels() -> None:
+    """A Mamba-2 mixer's gated norm takes one mean square over all inner
+    channels where the model norms each group of B and C apart."""
+    from llmd_kv_cache_tpu.models import llama
+
+    served = llama._group_rms
+    llama._group_rms = lambda y, groups, eps: served(y, 1, eps)
+
+
+def _wrong_group() -> None:
+    """A head reads the B and C of another group (the groups in reverse)."""
+    from llmd_kv_cache_tpu.ops import mamba2 as m2
+
+    served = m2._groups
+    m2._groups = lambda a, tiles: served(a, tiles)[:, ::-1]
+
+
+def _served_as(**changes):
+    """A fault in a model's scalars: the step programs read the parameters
+    (``llama.multiplied``) under a configuration with ``changes(cfg)``."""
+    def plant() -> None:
+        import dataclasses
+
+        from llmd_kv_cache_tpu.models import llama
+
+        served = llama.multiplied
+        llama.multiplied = lambda params, cfg: served(
+            params, dataclasses.replace(
+                cfg, **{k: v(cfg) for k, v in changes.items()}))
+    return plant
+
+
+def _rope_left_out() -> None:
+    """Queries and keys reach attention unrotated."""
+    from llmd_kv_cache_tpu.models import llama
+
+    llama._rope = lambda x, *_args, **_kw: x
+
+
 # Faults planted in the program, by name. Each replaces something the step
 # programs look up when they are first traced.
 FAULTS = {"stale-state": _stale_state, "conv-tail": _conv_tail_dropped,
           "no-gate": _gate_left_out,
           "no-residual-scale": _residual_unscaled,
           "xla-recurrence": _xla_recurrence,
-          "verify-mask": _verify_mask_off_by_one}
+          "verify-mask": _verify_mask_off_by_one,
+          "norm-all-channels": _norm_over_all_channels,
+          "wrong-group": _wrong_group,
+          "no-key-multiplier": _served_as(
+              attention_multiplier=lambda cfg: cfg.head_dim ** -0.5),
+          "out-multipliers-swapped": _served_as(
+              ssm_out_multiplier=lambda cfg: cfg.attention_out_multiplier,
+              attention_out_multiplier=lambda cfg: cfg.ssm_out_multiplier),
+          "no-rope": _rope_left_out}
+
+
+@contextlib.contextmanager
+def planted(fault: str):
+    """``FAULTS[fault]`` planted for the block's length (nothing for ""):
+    every module a fault touches is put back as it was, and nothing traced
+    before, inside or after the block is shared across its edges."""
+    import jax
+
+    from llmd_kv_cache_tpu.models import engine, llama
+    from llmd_kv_cache_tpu.ops import (gated_deltanet, mamba2,
+                                       pallas_paged_attention)
+
+    modules = (engine, llama, gated_deltanet, mamba2, pallas_paged_attention)
+    saved = [dict(vars(m)) for m in modules]
+    jax.clear_caches()
+    if fault:
+        FAULTS[fault]()
+    try:
+        yield
+    finally:
+        for module, was in zip(modules, saved):
+            vars(module).update(was)
+        jax.clear_caches()
 
 
 def main() -> None:
@@ -153,15 +233,22 @@ def main() -> None:
     ap.add_argument("--config", default="deepseek-v3.2-exp-ep16-l5")
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--margins", action="store_true")
+    ap.add_argument("--branches", action="store_true")
     ap.add_argument("--control", default="")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--set", action="append", default=[])
     ap.add_argument("--serve", action="append", default=[])
-    ap.add_argument("--fault", default="", choices=["", *FAULTS])
+    ap.add_argument("--fault", default="")
     args = ap.parse_args()
-    if args.fault:
-        FAULTS[args.fault]()
+    faults = args.fault.split(",")
+    if set(faults) - {"", *FAULTS}:
+        ap.error(f"--fault: one or more of {sorted(FAULTS)}")
+    for fault in faults:
+        with planted(fault):
+            readings(args, fault)
 
+
+def readings(args, fault: str) -> None:
     import jax
 
     from llmd_kv_cache_tpu.models.engine import EngineConfig, MiniEngine
@@ -197,7 +284,7 @@ def main() -> None:
                 params=params)
         rep = correct.probe(fl, params, ref, seed, n, new)
         stats = jax.devices()[0].memory_stats() or {}
-        what = " ".join(filter(None, [args.control or "served", args.fault,
+        what = " ".join(filter(None, [args.control or "served", fault,
                                       *args.serve]))
         print(f"READING seed {seed} {what}: ok "
               f"{rep['ok']} prefill_err {rep['prefill_rel_err']:.4f} "
@@ -206,6 +293,13 @@ def main() -> None:
               f"{rep['alternatives']} faults {rep['faults']} | "
               f"{time.time() - t0:.0f}s peak "
               f"{stats.get('peak_bytes_in_use', 0) / 1e9:.2f}GB", flush=True)
+        if args.branches:
+            rng = np.random.default_rng(seed + 1)
+            for at, sizes in enumerate(ref.branch_sizes(
+                    params, cfg, rng.integers(1, cfg.vocab_size, 512))):
+                print(f"BRANCHES seed {seed} layer {at}: residual "
+                      f"{sizes[0]:.4f} adds", " ".join(
+                          f"{v:.4f}" for v in sizes[1:]), flush=True)
         if args.margins:
             rng = np.random.default_rng(seed + 1)
             tokens = rng.integers(1, cfg.vocab_size, n + new).tolist()

@@ -87,11 +87,14 @@ from .llama import (
     pack_inputs,
     prefill_per_head,
     step_decode_pallas,
+    step_decode_pallas_paged_state,
     step_decode_pallas_state,
     step_forward,
     step_forward_hybrid,
+    step_forward_paged_state,
     step_forward_state,
     step_prefill_pallas,
+    step_prefill_pallas_paged_state,
     step_prefill_pallas_state,
     step_program,
     step_ragged,
@@ -111,11 +114,20 @@ _WITH_STATE = {
 }
 
 
-def _with_state(program):
+# The same for a model whose state layers keep pages too (two mixers a
+# layer): the page pools ride in the state (``llama.with_pages_in_state``).
+_WITH_PAGES_IN_STATE = {
+    step_forward: step_forward_paged_state,
+    step_decode_pallas: step_decode_pallas_paged_state,
+    step_prefill_pallas: step_prefill_pallas_paged_state,
+}
+
+
+def _with_state(program, paged=False):
+    forms = _WITH_PAGES_IN_STATE if paged else _WITH_STATE
     if isinstance(program, functools.partial):
-        return functools.partial(_WITH_STATE[program.func],
-                                 **program.keywords)
-    return _WITH_STATE[program]
+        return functools.partial(forms[program.func], **program.keywords)
+    return forms[program]
 
 
 # Device (as an engine was given it; None: JAX's default) → the count that
@@ -1068,8 +1080,10 @@ class MiniEngine:
             # grouped forward over both pools.
             self._decode_forward = self._prefill_forward = step_forward_hybrid
         if self.state_pool is not None:
-            self._decode_forward = _with_state(self._decode_forward)
-            self._prefill_forward = _with_state(self._prefill_forward)
+            self._decode_forward = _with_state(
+                self._decode_forward, bool(mcfg.parallel_layers))
+            self._prefill_forward = _with_state(
+                self._prefill_forward, bool(mcfg.parallel_layers))
         if self._pp > 1:
             from ..parallel.pp_serve import make_pp_serve_forward
 

@@ -105,7 +105,7 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     supported = ("llama", "mistral", "mixtral", "qwen2", "qwen3",
                  "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32",
                  "gigachat3_5", "solar_open2", "pangu_ultra_moe",
-                 "granitemoehybrid")
+                 "granitemoehybrid", "falcon_h1")
     if hf_cfg.model_type not in supported:
         raise NotImplementedError(
             f"model_type {hf_cfg.model_type!r} is not supported "
@@ -115,8 +115,14 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
             f"Granite line the hybrid is built (granitemoehybrid: Mamba-2 "
             f"beside NoPE attention, routed feed-forwards) and what is still "
             f"refused is rope in its attending layers, a bias on its "
-            f"projections, more than one group of B and C, and dense "
-            f"feed-forwards")
+            f"projections and dense feed-forwards; of the Falcon-H1 line "
+            f"the block of two mixers is built (falcon_h1: Mamba-2 and "
+            f"rotary GQA from one normed input, groups of B and C, the "
+            f"family's multipliers) and what is still refused is "
+            f"mamba_rms_norm false, the norm before the gate, a bias on any "
+            f"projection, attn_layer_indices, rope_scaling, an "
+            f"attention_in_multiplier other than 1 and a checkpoint's "
+            f"tensors")
     act = getattr(hf_cfg, "hidden_act", "silu")
     if act not in ("silu", "swish"):
         raise NotImplementedError(
@@ -127,6 +133,8 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
         return _config_from_solar(hf_cfg, page_size, dtype)
     if hf_cfg.model_type == "granitemoehybrid":
         return _config_from_granite(hf_cfg, page_size, dtype)
+    if hf_cfg.model_type == "falcon_h1":
+        return _config_from_falcon_h1(hf_cfg, page_size, dtype)
     if hf_cfg.model_type.startswith("deepseek"):
         return _config_from_deepseek(hf_cfg, page_size, dtype,
                                      rope_scaling)
@@ -501,13 +509,29 @@ def _config_from_solar(hf_cfg: Any, page_size: int,
     )
 
 
+def _refuse_unbuilt(hf_cfg: Any, built: tuple) -> None:
+    """A state-space family's forms that are not built, refused by the key
+    that asks for them: ``built`` pairs a key with the one value served (a
+    missing key is that value); the step ``softplus(dt + dt_bias)`` is
+    never clipped."""
+    for key, value in built:
+        if getattr(hf_cfg, key, value) != value:
+            raise NotImplementedError(
+                f"{key} {getattr(hf_cfg, key)!r}: what is built is "
+                f"{value!r}")
+    if getattr(hf_cfg, "time_step_limit", None) not in (
+            None, (0.0, float("inf")), [0.0, float("inf")]):
+        raise NotImplementedError("time_step_limit: the step is not clipped")
+
+
 def _config_from_granite(hf_cfg: Any, page_size: int,
                          dtype: Any) -> LlamaConfig:
     """Granite 4.0-H (``model_type: granitemoehybrid``): ``layer_types``
     names each layer's mixer, "mamba" (Mamba-2 in the ``mamba_*`` sizes:
     ``mamba_n_heads`` heads of ``mamba_d_head`` over a state of
-    ``mamba_d_state``, a conv of ``mamba_d_conv`` taps with a bias, the
-    gated norm over all inner channels) or "attention" (GQA of
+    ``mamba_d_state`` and ``mamba_n_groups`` groups of B and C, a conv of
+    ``mamba_d_conv`` taps with a bias, the gated norm a group of channels
+    at a time) or "attention" (GQA of
     ``hidden_size / num_attention_heads`` a head, no positional encoding,
     scores times ``attention_multiplier``); every feed-forward routes
     ``num_experts_per_tok`` of ``num_local_experts`` experts of width
@@ -523,17 +547,10 @@ def _config_from_granite(hf_cfg: Any, page_size: int,
     checkpoint has them): ``state_slots``, ``state_checkpoint_tokens``,
     ``embed_init_scale`` as for GigaChat3.5. Each form that is not built is
     refused by its key."""
-    for key, built in (("position_embedding_type", "nope"),
-                       ("mamba_proj_bias", False), ("attention_bias", False),
-                       ("mamba_conv_bias", True), ("mamba_n_groups", 1),
-                       ("normalization_function", "rmsnorm")):
-        if getattr(hf_cfg, key, built) != built:
-            raise NotImplementedError(
-                f"{key} {getattr(hf_cfg, key)!r}: what is built is "
-                f"{built!r}")
-    if getattr(hf_cfg, "time_step_limit", None) not in (
-            None, (0.0, float("inf")), [0.0, float("inf")]):
-        raise NotImplementedError("time_step_limit: the step is not clipped")
+    _refuse_unbuilt(hf_cfg, (
+        ("position_embedding_type", "nope"), ("mamba_proj_bias", False),
+        ("attention_bias", False), ("mamba_conv_bias", True),
+        ("normalization_function", "rmsnorm")))
     kinds = list(hf_cfg.layer_types)
     n_layers = hf_cfg.num_hidden_layers
     if len(kinds) != n_layers or set(kinds) - {"mamba", "attention"}:
@@ -571,7 +588,8 @@ def _config_from_granite(hf_cfg: Any, page_size: int,
         linear_layers=tuple(i for i, kind in enumerate(kinds)
                             if kind == "mamba"),
         linear=LinearAttention(
-            key_heads=1, value_heads=heads, key_dim=int(hf_cfg.mamba_d_state),
+            key_heads=int(getattr(hf_cfg, "mamba_n_groups", 1)),
+            value_heads=heads, key_dim=int(hf_cfg.mamba_d_state),
             value_dim=head, conv_kernel=int(hf_cfg.mamba_d_conv),
             gate_scale=1.0, norm_eps=float(hf_cfg.rms_norm_eps),
             decay="mamba2"),
@@ -589,6 +607,77 @@ def _config_from_granite(hf_cfg: Any, page_size: int,
         attention_multiplier=float(hf_cfg.attention_multiplier),
         logits_scaling=float(hf_cfg.logits_scaling),
         **_layer_share(hf_cfg, experts),
+    )
+
+
+def _config_from_falcon_h1(hf_cfg: Any, page_size: int,
+                           dtype: Any) -> LlamaConfig:
+    """Falcon-H1 (``model_type: falcon_h1``): every layer runs a Mamba-2
+    mixer and rotary GQA side by side from one normed input, then a dense
+    SwiGLU (``LlamaConfig.parallel_layers``). The mixer's inner width is
+    ``mamba_d_ssm`` = ``mamba_n_heads x mamba_d_head`` (``mamba_expand`` is
+    not read), over a state of ``mamba_d_state`` and ``mamba_n_groups``
+    groups of B and C, each group's channels normed apart after the gate;
+    attention has ``head_dim`` from the config. The family's multipliers:
+    ``embedding_multiplier``; ``lm_head_multiplier`` (served as
+    ``logits_scaling`` = its inverse); ``key_multiplier`` on the keys
+    (served on the scores: ``attention_multiplier = key_multiplier x
+    head_dim ** -0.5``, so the pages hold unscaled keys);
+    ``ssm_in_multiplier`` and the five ``ssm_multipliers``; each mixer's
+    output times ``ssm_out_multiplier`` / ``attention_out_multiplier``;
+    ``mlp_multipliers``. The head is kept apart from the embedding. What
+    the published config does not give and a top-level key may (no
+    checkpoint has them): ``state_slots``, ``state_checkpoint_tokens`` and
+    the scales random weights are drawn at, ``embed_init_scale``,
+    ``mixer_init_scale``, ``mlp_init_scale``. Each form that is not built is
+    refused by its key."""
+    _refuse_unbuilt(hf_cfg, (
+        ("mamba_rms_norm", True), ("mamba_norm_before_gate", False),
+        ("mamba_proj_bias", False), ("attention_bias", False),
+        ("mlp_bias", False), ("projectors_bias", False),
+        ("mamba_conv_bias", True), ("mamba_use_mlp", True),
+        ("attn_layer_indices", None), ("rope_scaling", None),
+        ("attention_in_multiplier", 1)))
+    heads, head = int(hf_cfg.mamba_n_heads), int(hf_cfg.mamba_d_head)
+    if heads * head != int(hf_cfg.mamba_d_ssm):
+        raise ValueError(
+            f"mamba_n_heads x mamba_d_head = {heads * head} is not "
+            f"mamba_d_ssm {hf_cfg.mamba_d_ssm}")
+    n_layers = hf_cfg.num_hidden_layers
+    return LlamaConfig(
+        vocab_size=hf_cfg.vocab_size,
+        hidden_size=hf_cfg.hidden_size,
+        num_layers=n_layers,
+        num_heads=hf_cfg.num_attention_heads,
+        num_kv_heads=hf_cfg.num_key_value_heads,
+        head_dim=int(hf_cfg.head_dim),
+        intermediate_size=int(hf_cfg.intermediate_size),
+        rope_theta=float(hf_cfg.rope_theta),
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        page_size=page_size,
+        dtype=dtype,
+        linear_layers=tuple(range(n_layers)),
+        parallel_layers=tuple(range(n_layers)),
+        linear=LinearAttention(
+            key_heads=int(hf_cfg.mamba_n_groups), value_heads=heads,
+            key_dim=int(hf_cfg.mamba_d_state), value_dim=head,
+            conv_kernel=int(hf_cfg.mamba_d_conv), gate_scale=1.0,
+            norm_eps=float(hf_cfg.rms_norm_eps), decay="mamba2"),
+        state_slots=int(getattr(hf_cfg, "state_slots", 64)),
+        state_checkpoint_tokens=int(getattr(
+            hf_cfg, "state_checkpoint_tokens", 4096)),
+        embed_init_scale=float(getattr(hf_cfg, "embed_init_scale", 0.02)),
+        mixer_init_scale=float(getattr(hf_cfg, "mixer_init_scale", 0.02)),
+        mlp_init_scale=float(getattr(hf_cfg, "mlp_init_scale", 0.02)),
+        embedding_multiplier=float(hf_cfg.embedding_multiplier),
+        logits_scaling=1.0 / float(hf_cfg.lm_head_multiplier),
+        attention_multiplier=(float(hf_cfg.key_multiplier)
+                              * int(hf_cfg.head_dim) ** -0.5),
+        ssm_in_multiplier=float(hf_cfg.ssm_in_multiplier),
+        ssm_multipliers=tuple(float(m) for m in hf_cfg.ssm_multipliers),
+        ssm_out_multiplier=float(hf_cfg.ssm_out_multiplier),
+        attention_out_multiplier=float(hf_cfg.attention_out_multiplier),
+        mlp_multipliers=tuple(float(m) for m in hf_cfg.mlp_multipliers),
     )
 
 

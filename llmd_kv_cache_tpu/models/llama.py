@@ -93,11 +93,12 @@ class LinearAttention:
     ``decay == "mamba2"`` is no delta rule: a Mamba-2 state-space layer
     (``_mamba2``, ``ops.mamba2``) in the same sizes: ``value_heads`` heads of
     ``value_dim`` channels, a state ``key_dim`` wide, ``key_heads`` groups
-    of ``B`` and ``C`` (one is built), a conv with a bias over ``[x | B |
-    C]`` (``conv_channels`` as above), one decay a head. Its output is
-    normed over all ``inner`` channels after the gate ``silu(z)``; the
-    delta rules' ``gate_scale``, ``beta_scale`` and ``gate_rank`` mean
-    nothing there and are refused."""
+    of ``B`` and ``C`` (head ``h`` reads group ``h // (value_heads /
+    key_heads)``), a conv with a bias over ``[x | B | C]``
+    (``conv_channels`` as above), one decay a head. Its output is normed
+    after the gate ``silu(z)``, each group's ``inner / key_heads`` channels
+    by their own mean square; the delta rules' ``gate_scale``,
+    ``beta_scale`` and ``gate_rank`` mean nothing there and are refused."""
 
     key_heads: int
     value_heads: int
@@ -322,6 +323,31 @@ class LlamaConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
+    # Layers of two mixers (Falcon-H1's block): a layer listed here keeps a
+    # state AND pages. From one normed input ``u`` a Mamba-2 mixer and GQA
+    # run side by side and ``ssm_out_multiplier Mamba2(u) +
+    # attention_out_multiplier Attn(u)`` joins the residual; then the MLP.
+    # Such a layer is one of ``linear_layers`` (the shared body runs it as
+    # one, handed the pages with the state: ``_parallel_block``,
+    # ``with_pages_in_state``) and one of ``page_layers``. () for every
+    # other model, whose programs hold nothing of this.
+    parallel_layers: tuple = ()
+    # Falcon-H1's further scalars (1 / () for every other model), each
+    # applied in float32 to the product that ``multiplied`` names:
+    # ``ssm_multipliers`` = ``(z, x, B, C, dt)`` over the columns of a
+    # Mamba-2 mixer's input projection, on top of ``ssm_in_multiplier``
+    # on its input; each mixer's output projection times its own scalar;
+    # ``mlp_multipliers`` = (the gate's pre-activation, the MLP's output).
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: tuple = ()
+    ssm_out_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = ()
+    # Standard deviation ``init_params`` draws the input projections of a
+    # layer of two mixers at (``w_in``, ``wq``, ``wk``, ``wv``; random
+    # weights only): under multipliers that shrink what they project, the
+    # size at which a state is read back and attention chooses among keys.
+    mixer_init_scale: float = 0.02
 
     def __post_init__(self):
         if self.num_nextn_predict_layers:
@@ -357,11 +383,12 @@ class LlamaConfig:
                             f"linear.{name} {getattr(self.linear, name)!r} "
                             f"is a delta rule's: a mamba2 layer has no such "
                             f"term (leave it at {plain!r})")
-                if self.linear.key_heads != 1:
+                if self.linear.state_shape[0] % self.linear.key_heads:
                     raise NotImplementedError(
                         f"linear.key_heads {self.linear.key_heads} "
-                        f"(mamba_n_groups): one group of B and C for all "
-                        f"heads is what is built")
+                        f"(mamba_n_groups) over {self.linear.state_shape[0]} "
+                        f"state tiles of heads side by side: a tile would "
+                        f"hold heads of two groups of B and C")
                 if self.is_mla or self.norm_offset or self.post_norms:
                     raise NotImplementedError(
                         "a mamba2 layer is built beside GQA pages under "
@@ -375,7 +402,8 @@ class LlamaConfig:
                     "(gate_rank)")
             if not all(0 <= i < self.num_layers for i in self.linear_layers):
                 raise ValueError("linear_layers indices out of range")
-            if len(set(self.linear_layers)) == self.num_layers:
+            if (len(set(self.linear_layers)) == self.num_layers
+                    and not self.parallel_layers):
                 raise ValueError(
                     "a model of linear layers alone has no pages for a "
                     "snapshot to stand on")
@@ -390,6 +418,29 @@ class LlamaConfig:
                 raise ValueError(
                     "a model with linear layers needs state_slots >= 2 (a "
                     "working slot and a snapshot)")
+        if self.parallel_layers:
+            if (self.linear is None or self.linear.decay != "mamba2"
+                    or self.is_mla or self.num_experts):
+                raise NotImplementedError(
+                    "a layer of two mixers is Mamba-2 beside GQA under a "
+                    "dense MLP: with a delta rule, latent pages or experts "
+                    "it is not built")
+            if (tuple(self.parallel_layers) != tuple(range(self.num_layers))
+                    or tuple(self.linear_layers) != self.parallel_layers):
+                raise NotImplementedError(
+                    "parallel_layers must name every layer, in order, and "
+                    "linear_layers the same: a layer of two mixers beside a "
+                    "layer of one (whose pages the shared body would write "
+                    "apart from theirs) is not built")
+        elif (self.ssm_out_multiplier != 1.0
+                or self.attention_out_multiplier != 1.0):
+            raise ValueError(
+                "ssm_out_multiplier and attention_out_multiplier weigh the "
+                "two mixers of a parallel layer: the model has none")
+        if self.ssm_multipliers and len(self.ssm_multipliers) != 5:
+            raise ValueError("ssm_multipliers are five: (z, x, B, C, dt)")
+        if self.mlp_multipliers and len(self.mlp_multipliers) != 2:
+            raise ValueError("mlp_multipliers are two: (gate, output)")
         if self.num_experts > 0 and self.num_experts_per_token > self.num_experts:
             raise ValueError(
                 f"num_experts_per_token ({self.num_experts_per_token}) exceeds "
@@ -543,7 +594,9 @@ class LlamaConfig:
         return self.kv_lora_rank > 0
 
     def layer_kind(self, layer_idx: int) -> str:
-        """``"linear"`` (a state a sequence) or ``"attention"`` (pages)."""
+        """``"linear"`` (a state a sequence) or ``"attention"`` (pages). A
+        layer of two mixers (``parallel_layers``) keeps both and is run as
+        a linear one."""
         return "linear" if layer_idx in self.linear_layers else "attention"
 
     @property
@@ -552,7 +605,8 @@ class LlamaConfig:
         A prediction module's layer is ``-1`` and comes first: seen as a
         model of one layer (``_module_view``) its latents are layer 0."""
         return (-1,) * self.num_nextn_predict_layers + tuple(
-            i for i in range(self.num_layers) if i not in self.linear_layers)
+            i for i in range(self.num_layers)
+            if i not in self.linear_layers or i in self.parallel_layers)
 
     @property
     def is_dsa(self) -> bool:
@@ -580,7 +634,10 @@ class LlamaConfig:
     def has_multipliers(self) -> bool:
         """Whether ``multiplied`` changes anything of the parameters."""
         return (self.embedding_multiplier != 1.0 or self.logits_scaling != 1.0
-                or bool(self.attention_multiplier))
+                or bool(self.attention_multiplier)
+                or bool(self.parallel_layers) or bool(self.ssm_multipliers)
+                or self.ssm_in_multiplier != 1.0
+                or bool(self.mlp_multipliers))
 
     @property
     def step_counters(self) -> tuple:
@@ -765,6 +822,19 @@ def _init_layer_jit(key: jax.Array, cfg: LlamaConfig,
                               (h, cfg.num_heads * hd))
     if linear and cfg.linear.decay == "mamba2":
         layer.update(_init_mamba2(lk[0], cfg))
+        if cfg.parallel_layers:
+            # A layer of two mixers: GQA's matrices beside the Mamba-2
+            # mixer's, each mixer its own output projection.
+            def mixer_in(k, shape):
+                return _dense_init(k, shape, dt, cfg.mixer_init_scale)
+
+            layer.update({
+                "w_ssm_out": layer["wo"],
+                "wq": mixer_in(lk[1], (h, cfg.num_heads * hd)),
+                "wk": mixer_in(lk[2], (h, cfg.num_kv_heads * hd)),
+                "wv": mixer_in(lk[8], (h, cfg.num_kv_heads * hd)),
+                "wo": dense(lk[9], (cfg.num_heads * hd, h)),
+            })
     elif linear:
         la = cfg.linear
         ck = jax.random.split(lk[0], 6)
@@ -896,7 +966,8 @@ def _init_mamba2(key: jax.Array, cfg: LlamaConfig) -> Params:
     parameters as the family initialises them (``A`` in [1, 16), a step
     log-uniform in [1e-3, 1e-1] through its inverse softplus: a head's
     memory runs from a few tokens to thousands); the skip ``D`` at one;
-    the gated norm's weight over all inner channels."""
+    the gated norm's weight over all inner channels. In a layer of two
+    mixers ``w_in`` is drawn at ``cfg.mixer_init_scale``."""
     la = cfg.linear
     ck = jax.random.split(key, 5)
     step = jnp.exp(jax.random.uniform(
@@ -905,7 +976,8 @@ def _init_mamba2(key: jax.Array, cfg: LlamaConfig) -> Params:
     return {
         "w_in": _dense_init(
             ck[0], (cfg.hidden_size,
-                    la.inner + la.conv_channels + la.value_heads), cfg.dtype),
+                    la.inner + la.conv_channels + la.value_heads), cfg.dtype,
+            cfg.mixer_init_scale if cfg.parallel_layers else 0.02),
         "conv_w": _dense_init(ck[1], (la.conv_kernel, la.conv_channels),
                               jnp.float32, 0.5),
         "conv_b": _dense_init(jax.random.fold_in(ck[1], 1),
@@ -1751,8 +1823,8 @@ def _gated_deltanet(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
     heads' outputs normed per head and gated by ``gate_scale *
     sigmoid(z)``."""
     if cfg.linear.decay == "mamba2":  # no delta rule: a section of its own
-        return _mamba2(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
-                       kernel)
+        return (_parallel_block if cfg.parallel_layers else _mamba2)(
+            x, layer, cfg, lj, state, valid, ctx_lens, new_lens, kernel)
     from ..ops import gated_deltanet as gd
 
     la = cfg.linear
@@ -1873,8 +1945,9 @@ def _mamba2(x, layer, cfg, lj, state, valid, ctx_lens, new_lens, kernel):
     at a padded token: the state stays), ``A = -exp(A_log)``; the
     recurrence ``S <- exp(d A) S + d x (x) B``, ``y = S C + D x``
     (``ops.mamba2``: a chunk is scanned in blocks of a page, a decode step
-    updates every row's state in place); ``y silu(z)`` normed over all
-    inner channels."""
+    updates every row's state in place), a head reading its group's ``B``
+    and ``C``; ``y silu(z)`` normed a group of ``inner / key_heads``
+    channels at a time (one group: over all inner channels)."""
     from ..ops import mamba2 as m2
 
     la = cfg.linear
@@ -1897,7 +1970,9 @@ def _mamba2(x, layer, cfg, lj, state, valid, ctx_lens, new_lens, kernel):
         zxd[..., inner:inner + la.conv_channels], layer, conv, lj, slots,
         fresh, new_lens, snap, cfg.page_size)
     xs = mixed[..., :inner].reshape(b, s, la.value_heads, la.value_dim)
-    bs, cs = mixed[..., inner:inner + n], mixed[..., inner + n:]
+    bs = mixed[..., inner:inner + la.key_heads * n].reshape(
+        b, s, la.key_heads, n)
+    cs = mixed[..., inner + la.key_heads * n:].reshape(b, s, la.key_heads, n)
     a = -jnp.exp(layer["A_log"])
 
     if s == 1:
@@ -1921,10 +1996,89 @@ def _mamba2(x, layer, cfg, lj, state, valid, ctx_lens, new_lens, kernel):
                 recurrent = recurrent.at[lj, snap[1]].set(at_block)
                 recurrent = recurrent.at[lj, snap[2]].set(end)
         y = jnp.stack(outs)
-    y = y.reshape(b, s, inner) * jax.nn.silu(z)
-    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + la.norm_eps)
+    y = _group_rms(y.reshape(b, s, inner) * jax.nn.silu(z), la.key_heads,
+                   la.norm_eps)
     return ((y * layer["o_norm"]).astype(x.dtype),
             (recurrent, conv, slots, snap))
+
+
+def _group_rms(y, groups: int, eps: float):
+    """``y [..., channels]`` over its root mean square, each of ``groups``
+    equal runs of channels by its own."""
+    g = y.reshape(*y.shape[:-1], groups, -1)
+    return (g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True)
+                              + eps)).reshape(y.shape)
+
+
+# -- a layer of two mixers (Falcon-H1) ---------------------------------------
+# Reached as ``_mamba2`` is: the shared body runs such a layer as a linear
+# one (its norm, this, ``_sublayer_out``, the MLP) and touches no page of it.
+# The page pools and the rows' page table therefore ride where the recurrent
+# pool does in ``state`` (``with_pages_in_state``), from layer to layer
+# through here and back to the step program.
+
+SCOPE_MIXER_SSM = "mixer.ssm"
+SCOPE_MIXER_ATTENTION = "mixer.attention"
+
+
+def _parallel_block(x, layer, cfg, lj, state, valid, ctx_lens, new_lens,
+                    kernel):
+    """Both mixers of a parallel layer over ``x [b, s, h]``, its ONE normed
+    input, as ``_mamba2`` returns: ``([the Mamba-2 mixer's gated, normed
+    output | the attention heads' outputs] [b, s, inner + heads x head_dim],
+    the state with this layer's part of every pool updated)``. The layer's
+    ``wo`` as ``multiplied`` views it takes the two halves through their
+    own output projections under their own multipliers (``_Summed``).
+
+    The attention is the attending branch's: q, k, v (fused or not; the
+    queries carry ``attention_multiplier``), RoPE, the new keys and values
+    written into this layer of the donated page pools, then the kernel the
+    step's form asks for: XLA (``kernel`` None), the Pallas decode kernel
+    for one position a row, the Pallas prefill kernel for a chunk (a row a
+    program: ``EngineConfig.decode_batch_rows`` does not reach here)."""
+    (recurrent, k_cache, v_cache, table), conv, slots, snap = state
+    with jax.named_scope(SCOPE_MIXER_SSM):
+        ssm, (recurrent, conv, _, _) = _mamba2(
+            x, layer, cfg, lj, (recurrent, conv, slots, snap), valid,
+            ctx_lens, new_lens, kernel)
+    with jax.named_scope(SCOPE_MIXER_ATTENTION):
+        b, s, _ = x.shape
+        at = cfg.page_layers.index(cfg.linear_layers[lj])
+        positions = ctx_lens[:, None] + jnp.arange(s)[None, :]
+        total_lens = ctx_lens + new_lens
+        nq = cfg.num_heads * cfg.head_dim
+        nk = cfg.num_kv_heads * cfg.head_dim
+        if "w_qkv" in layer:  # fused serving layout (fuse_params)
+            q, k, v = split_fused_out(x @ layer["w_qkv"], (nq, nk, nk),
+                                      cfg.fused_interleave)
+        else:
+            q, k, v = x @ layer["wq"], x @ layer["wk"], x @ layer["wv"]
+        q = _rope(q.reshape(b, s, cfg.num_heads, cfg.head_dim), positions,
+                  cfg.rope_theta, cfg.rope_scaling)
+        k = _rope(k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim), positions,
+                  cfg.rope_theta, cfg.rope_scaling)
+        v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        with jax.named_scope(SCOPE_KV_WRITE):
+            writes = page_writes(cfg.page_size, table, positions, valid)
+            k_cache = write_kv_pages(k_cache, writes, k, layer_idx=at)
+            v_cache = write_kv_pages(v_cache, writes, v, layer_idx=at)
+        if kernel is None:
+            attn = paged_attention(q, k_cache, v_cache, table, positions,
+                                   total_lens, layer_idx=at)
+        else:
+            from ..ops import pallas_paged_attention as ppa
+
+            if s == 1:
+                attn = ppa.pallas_paged_decode_attention(
+                    q[:, 0], k_cache, v_cache, table, total_lens,
+                    layer_idx=at, interpret=kernel["interpret"])[:, None]
+            else:
+                attn = ppa.pallas_paged_prefill_attention(
+                    q, k_cache, v_cache, table, ctx_lens, total_lens,
+                    q_tile=_prefill_q_tile(cfg, s), layer_idx=at,
+                    interpret=kernel["interpret"])
+    return (jnp.concatenate([ssm, attn.reshape(b, s, nq)], axis=-1),
+            ((recurrent, k_cache, v_cache, table), conv, slots, snap))
 
 
 def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
@@ -2699,6 +2853,24 @@ class _Scaled:
         return y.astype(x.dtype)
 
 
+class _Summed:
+    """Stands where ``_sublayer_out`` looks for the output projection of a
+    layer of two mixers: handed ``[m | a]`` (``_parallel_block``) it answers
+    ``scale_m (m @ w_m) + scale_a (a @ w_a)``, each product scaled in
+    float32 and the sum rounded once to the model's type."""
+
+    def __init__(self, *parts):
+        self.parts = parts  # (matrix [rows, h], scale) in the halves' order
+
+    def __rmatmul__(self, x):
+        at, y = 0, 0.0
+        for w, scale in self.parts:
+            y = y + scale * jnp.matmul(x[..., at:at + w.shape[0]], w,
+                                       preferred_element_type=jnp.float32)
+            at += w.shape[0]
+        return y.astype(x.dtype)
+
+
 def multiplied(params: Params, cfg: LlamaConfig) -> Params:
     """``params`` as the shared body reads them under Granite's scalars
     (``LlamaConfig.embedding_multiplier`` ...): the embedding's rows times
@@ -2706,25 +2878,50 @@ def multiplied(params: Params, cfg: LlamaConfig) -> Params:
     the layers that attend the queries times ``attention_multiplier *
     head_dim ** 0.5``, since the attention kernels scale scores by
     ``head_dim ** -0.5`` themselves (``wq``, or the query columns of a fused
-    ``w_qkv``). Each in float32 on the product, before it is rounded.
-    ``params`` itself for every other model: its programs hold nothing of
-    this."""
+    ``w_qkv``). Under Falcon-H1's as well: a Mamba-2 mixer's ``w_in`` times
+    ``ssm_in_multiplier`` and, a column, its part's ``ssm_multipliers``; a
+    dense MLP's gate and output times ``mlp_multipliers``; and a parallel
+    layer's ``wo`` as the two mixers' output projections under their own
+    multipliers (``_Summed``). Each in float32 on the product, before it is
+    rounded. ``params`` itself for every other model: its programs hold
+    nothing of this."""
     if not cfg.has_multipliers:
         return params
     view = {**params,
             "embed": _Scaled(params["embed"], cfg.embedding_multiplier),
             "lm_head": _Scaled(params["lm_head"], 1.0 / cfg.logits_scaling)}
-    if cfg.attention_multiplier:
-        q_scale = cfg.attention_multiplier * cfg.head_dim ** 0.5
-        layers = []
-        for layer in params["layers"]:
-            if "wq" in layer:
-                layer = {**layer, "wq": _Scaled(layer["wq"], q_scale)}
-            elif "w_qkv" in layer:
-                layer = {**layer, "w_qkv": _Scaled(
-                    layer["w_qkv"], q_scale, cfg.num_heads * cfg.head_dim)}
-            layers.append(layer)
-        view["layers"] = layers
+    q_scale = cfg.attention_multiplier * cfg.head_dim ** 0.5
+    ssm_in = None
+    if cfg.ssm_multipliers or cfg.ssm_in_multiplier != 1.0:
+        la = cfg.linear
+        groups = la.key_heads * la.key_dim
+        ssm_in = cfg.ssm_in_multiplier * np.repeat(
+            np.asarray(cfg.ssm_multipliers or (1.0,) * 5, np.float32),
+            (la.inner, la.inner, groups, groups, la.value_heads))
+    layers = []
+    for layer in params["layers"]:
+        seen = {}  # what this layer shows in another matrix's place
+        if q_scale and "wq" in layer:
+            seen["wq"] = _Scaled(layer["wq"], q_scale)
+        elif q_scale and "w_qkv" in layer:
+            seen["w_qkv"] = _Scaled(layer["w_qkv"], q_scale,
+                                    cfg.num_heads * cfg.head_dim)
+        if ssm_in is not None and "w_in" in layer:
+            seen["w_in"] = _Scaled(layer["w_in"], ssm_in)
+        if cfg.mlp_multipliers and "router" not in layer:
+            gate, out = cfg.mlp_multipliers
+            if "w_gate_up" in layer:
+                seen["w_gate_up"] = _Scaled(
+                    layer["w_gate_up"], gate, layer["w_gate_up"].shape[1] // 2)
+            else:
+                seen["w_gate"] = _Scaled(layer["w_gate"], gate)
+            seen["w_down"] = _Scaled(layer["w_down"], out)
+        if "w_ssm_out" in layer:
+            seen["wo"] = _Summed(
+                (layer["w_ssm_out"], cfg.ssm_out_multiplier),
+                (layer["wo"], cfg.attention_out_multiplier))
+        layers.append({**layer, **seen} if seen else layer)
+    view["layers"] = layers
     return view
 
 
@@ -2756,6 +2953,27 @@ def with_state(body):
     return stateful
 
 
+def with_pages_in_state(body):
+    """``with_state(body)`` for a model whose layers keep a state AND pages
+    (``cfg.parallel_layers``). The shared body runs such a layer as a
+    linear one and touches no page, so the page pools and the rows' page
+    table ride where the recurrent pool does in the state it is handed,
+    from layer to layer through ``_parallel_block`` and back; the pools the
+    body itself hands back are the ones it was given and are dropped."""
+    stateful = with_state(body)
+
+    def paged(params, cfg, tokens, k_cache, v_cache, recurrent, conv,
+              page_table, *rest, counters=None, **kw):
+        logits, _, _, (recurrent, k_cache, v_cache, _), conv = stateful(
+            params, cfg, tokens, k_cache, v_cache,
+            (recurrent, k_cache, v_cache, page_table), conv, page_table,
+            *rest, counters=counters, **kw)
+        return logits, k_cache, v_cache, recurrent, conv
+
+    paged.__name__ = paged.__qualname__ = body.__name__
+    return paged
+
+
 @partial(jax.jit, donate_argnames=("state",))
 def copy_state_slot(state: tuple, src_dst: jax.Array) -> tuple:
     """Slot ``src_dst[0]`` of every pool of ``state`` (``init_state_pool``)
@@ -2773,6 +2991,14 @@ step_decode_pallas_state = step_program(
     ("interpret", "mesh", "batch_rows"))
 step_prefill_pallas_state = step_program(
     with_state(forward_prefill_pallas.__wrapped__),
+    ("interpret", "mesh", "last_only"))
+step_forward_paged_state = step_program(
+    with_pages_in_state(forward.__wrapped__), ("last_only",))
+step_decode_pallas_paged_state = step_program(
+    with_pages_in_state(forward_decode_pallas.__wrapped__),
+    ("interpret", "mesh", "batch_rows"))
+step_prefill_pallas_paged_state = step_program(
+    with_pages_in_state(forward_prefill_pallas.__wrapped__),
     ("interpret", "mesh", "last_only"))
 step_forward_hybrid = step_program(
     forward_hybrid.__wrapped__, ("last_only",))
@@ -2964,9 +3190,13 @@ def _module_attention(cfg, backend, ctx_lens, new_lens, seq, interpret):
 
 def _prefill_q_tile(cfg: LlamaConfig, seq: int) -> int:
     """``forward_prefill_pallas``'s rule for a chunk's query tile (its own
-    lines stand there: that frame is under every model's programs)."""
+    lines stand there: that frame is under every model's programs), with
+    the rows a group may take rounded down to a power of two: the same
+    tile for 1, 2, 4, ... query heads a key/value head, and for 5 (20 over
+    4) a tile of 128 where the gcd of 512 and 1024 // 5 would leave 4."""
     group = cfg.num_heads // max(1, cfg.kv_cache_heads)
-    q_tile = math.gcd(seq, max(128, 1024 // max(1, group)))
+    q_tile = math.gcd(seq, max(128, 1 << (
+        (1024 // max(1, group)).bit_length() - 1)))
     if group * q_tile > 4096:
         q_tile = math.gcd(seq, max(16, 2048 // group))
     return q_tile

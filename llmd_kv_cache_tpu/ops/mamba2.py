@@ -2,8 +2,9 @@
 sequence, not a page a block of tokens.
 
 Per head ``h`` (of ``H``, each ``P`` channels wide), with the step ``d_t =
-softplus(dt_t + dt_bias) > 0``, ``A_h < 0`` and one ``B_t, C_t [N]`` shared
-by all heads (one group)::
+softplus(dt_t + dt_bias) > 0``, ``A_h < 0`` and ``B_t, C_t [N]`` shared by
+the heads of a group (``Gr`` groups of ``H / Gr`` heads in order: head ``h``
+reads group ``h // (H / Gr)``; ``B, C [T, N]`` is one group)::
 
     S_t = exp(d_t A) S_{t-1} + d_t x_t (x) B_t          S [P, N] a head
     y_t = S_t C_t + D x_t
@@ -18,15 +19,16 @@ head ``g * pack + j``, which is where ``x`` flattened to ``[H * P]`` has it.
 A state of ``[H, P, N]`` is ``[G, N, W]`` in the pool (``state_shape``,
 ``pack_state`` / ``unpack_state``), every lane of every tile used; as ``[H,
 N, P]`` the pool's last axis would be padded to the lanes and weigh twice
-as much.
+as much. A tile's heads are of one group (``_groups`` refuses a shape
+where they would not be), so a tile reads one ``B`` and one ``C``.
 
 Two forms, each in XLA (what the CPU serves and the kernels are tested
 against) and as a Pallas kernel (what a TPU serves):
 
 - ``mamba2_scan``: a chunk of tokens of one sequence, in blocks of
   ``block`` tokens (the page): the blocked (SSD) form of the recurrence.
-  Inside a block, for a tile's heads: ``C B^T`` (computed once for all
-  heads) under each head's decay mask times ``d x``; between blocks the
+  Inside a block, for a tile's heads: ``C B^T`` (computed once a group)
+  under each head's decay mask times ``d x``; between blocks the
   carried state, read by ``C`` and written by ``B^T (d x)``. It returns the
   state at the chunk's end and at the end of one requested block: a
   snapshot at a block boundary costs no second pass and splits no chunk.
@@ -135,23 +137,31 @@ def _block_update(x, b, c, cb, gd, skip, st, *, pack):
 def _scan_xla(xw, b, c, cb, gd, skip, st0, snap_block, pack):
     t, hp = xw.shape
     g, nb = gd.shape[:2]
-    block = t // nb
-    per_tile = jax.vmap(functools.partial(_block_update, pack=pack),
-                        in_axes=(0, None, None, None, 0, 0, 0))
+    gr, block = b.shape[0], t // nb
+    # A group's tiles share its b, c and c b^T; the groups each their own.
+    per_tile = jax.vmap(jax.vmap(
+        functools.partial(_block_update, pack=pack),
+        in_axes=(0, None, None, None, 0, 0, 0)))
+
+    def grouped(a):  # the tiles' axis (the first) as [groups, tiles of one]
+        return a.reshape(gr, g // gr, *a.shape[1:])
 
     def body(carry, xs):
         st, snap = carry
         i, x_i, b_i, c_i, cb_i, gd_i = xs
-        y, st = per_tile(x_i.astype(jnp.float32), b_i, c_i, cb_i, gd_i, skip,
-                         st)
-        return (st, jnp.where(i == snap_block, st, snap)), y
+        y, st = per_tile(grouped(x_i.astype(jnp.float32)), b_i, c_i, cb_i,
+                         grouped(gd_i), grouped(skip), grouped(st))
+        st = st.reshape(st0.shape)
+        return (st, jnp.where(i == snap_block, st, snap)), y.reshape(
+            g, block, hp // g)
 
     (st, snap), y = jax.lax.scan(
         body, (st0, st0),
         (jnp.arange(nb),
          xw.reshape(nb, block, g, hp // g).transpose(0, 2, 1, 3),
-         b.reshape(nb, block, -1), c.reshape(nb, block, -1), cb,
-         gd.transpose(1, 0, 2, 3)))
+         b.reshape(gr, nb, block, -1).transpose(1, 0, 2, 3),
+         c.reshape(gr, nb, block, -1).transpose(1, 0, 2, 3),
+         cb.transpose(1, 0, 2, 3), gd.transpose(1, 0, 2, 3)))
     return y.transpose(0, 2, 1, 3).reshape(t, hp), st, snap
 
 
@@ -164,8 +174,8 @@ def _scan_kernel(snap_ref, x_ref, b_ref, c_ref, cb_ref, gd_ref, skip_ref,
         st_scr[...] = st0_ref[0]
         snap_out_ref[0] = st0_ref[0]
 
-    y, st = _block_update(x_ref[...].astype(jnp.float32), b_ref[...],
-                          c_ref[...], cb_ref[0], gd_ref[0, 0], skip_ref[0],
+    y, st = _block_update(x_ref[...].astype(jnp.float32), b_ref[0],
+                          c_ref[0], cb_ref[0, 0], gd_ref[0, 0], skip_ref[0],
                           st_scr[...], pack=pack)
     y_ref[...] = y
     st_scr[...] = st
@@ -184,14 +194,20 @@ def _scan_pallas(xw, b, c, cb, gd, skip, st0, snap_block, pack, interpret):
     g, nb = gd.shape[:2]
     block = t // nb
     n, w = st0.shape[1:]
+    per_group = g // b.shape[0]    # tile i reads group i // per_group
+
+    def group_block(i, k, *_):
+        return (i // per_group, k, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(g, nb),
         in_specs=[
             pl.BlockSpec((block, w), lambda i, k, *_: (k, i)),
-            pl.BlockSpec((block, n), lambda i, k, *_: (k, 0)),
-            pl.BlockSpec((block, n), lambda i, k, *_: (k, 0)),
-            pl.BlockSpec((1, block, block), lambda i, k, *_: (k, 0, 0)),
+            pl.BlockSpec((1, block, n), group_block),
+            pl.BlockSpec((1, block, n), group_block),
+            pl.BlockSpec((1, 1, block, block),
+                         lambda i, k, *_: (i // per_group, k, 0, 0)),
             pl.BlockSpec((1, 1, 2 * pack, block),
                          lambda i, k, *_: (i, k, 0, 0)),
             pl.BlockSpec((1, 1, w), lambda i, k, *_: (i, 0, 0)),
@@ -217,12 +233,26 @@ def _scan_pallas(xw, b, c, cb, gd, skip, st0, snap_block, pack, interpret):
       skip, st0)
 
 
+def _groups(a: jax.Array, tiles: int) -> jax.Array:
+    """``B`` or ``C`` as float32 ``[rows, Gr, N]``, from that or, one
+    group, ``[rows, N]``. The state's ``tiles`` must split evenly over the
+    groups: a tile never holds heads of two."""
+    a = a.astype(jnp.float32)
+    a = a[:, None] if a.ndim == 2 else a
+    if tiles % a.shape[1]:
+        raise ValueError(
+            f"{a.shape[1]} groups of B and C over {tiles} tiles of heads: a "
+            f"tile would hold heads of two groups")
+    return a
+
+
 @functools.partial(jax.jit, static_argnames=("block", "kernel", "interpret"))
 def mamba2_scan(x, B, C, dt, A, D, state, snap_block, block: int,
                 kernel: bool = False, interpret: bool = False):
     """A chunk of one sequence. ``x [T, H, P]`` (after the conv and its
-    SiLU), ``B, C [T, N]``, ``dt [T, H]`` the steps (after the softplus; 0
-    at a padded token), ``A, D [H]`` (``A`` negative), ``state [G, N, W]``
+    SiLU), ``B, C [T, Gr, N]`` (or ``[T, N]``: one group), ``dt [T, H]`` the
+    steps (after the softplus; 0 at a padded token), ``A, D [H]`` (``A``
+    negative), ``state [G, N, W]``
     float32 before the chunk (``state_shape``); ``T`` a whole number of
     blocks. Returns ``(y [T, H, P] float32, the state after the chunk, the
     state after block snap_block)``; the last is the state before the chunk
@@ -241,8 +271,9 @@ def mamba2_scan(x, B, C, dt, A, D, state, snap_block, block: int,
     # log-decay and then its step.
     gd = jnp.stack([gc, dt.reshape(nb, block, h)], axis=-1).reshape(
         nb, block, g, 2 * pack).transpose(2, 0, 3, 1)
-    b, c = B.astype(f32), C.astype(f32)
-    cb = jax.vmap(_dot_nt)(c.reshape(nb, block, -1), b.reshape(nb, block, -1))
+    b, c = (_groups(v, g).transpose(1, 0, 2) for v in (B, C))  # [Gr, T, N]
+    cb = jax.vmap(jax.vmap(_dot_nt))(c.reshape(-1, nb, block, c.shape[-1]),
+                                     b.reshape(-1, nb, block, b.shape[-1]))
     skip = jnp.repeat(D.astype(f32), p).reshape(g, 1, pack * p)
     xw = x.reshape(t, h * p)
     if kernel:
@@ -263,13 +294,14 @@ def _step_kernel(slot_ref, stand_ref, layer_ref, a_ref, dx_ref, b_ref, c_ref,
     eye = (row == col).astype(jnp.float32)
 
     def update():
-        bcol, ccol = _column(eye, b_ref[0]), _column(eye, c_ref[0])
+        bcol, ccol = _column(eye, b_ref[0, 0]), _column(eye, c_ref[0, 0])
 
         def tile(j, _):
             at = pl.ds(j, 1)
-            st = pool_ref[0, 0, j] * a_ref[0, at, :] + bcol * dx_ref[0, at, :]
+            st = (pool_ref[0, 0, j] * a_ref[0, 0, at, :]
+                  + bcol * dx_ref[0, 0, at, :])
             out_ref[0, 0, j] = st
-            y_ref[0, at, :] = jnp.sum(ccol * st, axis=0, keepdims=True)
+            y_ref[0, 0, at, :] = jnp.sum(ccol * st, axis=0, keepdims=True)
 
         # A loop and not its unrolling: a program lowers the kernel once a
         # layer and decode shape, and the block's tiles are many.
@@ -280,27 +312,37 @@ def _step_kernel(slot_ref, stand_ref, layer_ref, a_ref, dx_ref, b_ref, c_ref,
 
 def _step_pallas(pool, layer, slots, a, dx, b, c, interpret):
     rows, g, w = a.shape
-    n = b.shape[-1]
-    tiles = _tiles_a_step(g, n * w * 4)
+    _, gr, n = b.shape
+    # A grid step's tiles are of one group (so many of a group's tiles),
+    # and a row's vectors are blocked as [rows, Gr, a group's tiles, W]: a
+    # block of fewer than a sublane tile of 8 is then a group's whole.
+    tiles = _tiles_a_step(g // gr, n * w * 4)
+    steps = g // gr // tiles                      # grid steps a group
     scalars, vec, out, state = _live_walk(slots, layer, g // tiles)
 
+    def in_group(index_map):
+        def at(r, j, *refs):
+            r, j, _ = index_map(r, j, *refs)
+            return (r, j // steps, j % steps, 0)
+        return at
+
     def once(r, j, *_):
-        return (r, 0, 0)
+        return (r, j // steps, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(rows, g // tiles),
-        in_specs=[pl.BlockSpec((1, tiles, w), vec),
-                  pl.BlockSpec((1, tiles, w), vec),
-                  pl.BlockSpec((1, 1, n), once),
-                  pl.BlockSpec((1, 1, n), once),
+        in_specs=[pl.BlockSpec((1, 1, tiles, w), in_group(vec)),
+                  pl.BlockSpec((1, 1, tiles, w), in_group(vec)),
+                  pl.BlockSpec((1, 1, 1, n), once),
+                  pl.BlockSpec((1, 1, 1, n), once),
                   pl.BlockSpec((1, 1, tiles, n, w), state)],
-        out_specs=[pl.BlockSpec((1, tiles, w), out),
+        out_specs=[pl.BlockSpec((1, 1, tiles, w), in_group(out)),
                    pl.BlockSpec((1, 1, tiles, n, w), state)],
     )
-    return pl.pallas_call(
+    y, pool = pl.pallas_call(
         functools.partial(_step_kernel, tiles=tiles),
-        out_shape=[jax.ShapeDtypeStruct((rows, g, w), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((rows, gr, g // gr, w), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         grid_spec=grid_spec,
         # Operand 7 (behind the three scalars) is the pool: updated in
@@ -308,7 +350,9 @@ def _step_pallas(pool, layer, slots, a, dx, b, c, interpret):
         input_output_aliases={7: 1},
         compiler_params=_step_params(tiles * n * w * 4),
         interpret=interpret,
-    )(*scalars, a, dx, b[:, None, :], c[:, None, :], pool)
+    )(*scalars, a.reshape(rows, gr, -1, w), dx.reshape(rows, gr, -1, w),
+      b[:, :, None], c[:, :, None], pool)
+    return y.reshape(rows, g, w), pool
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "interpret"),
@@ -318,8 +362,9 @@ def mamba2_step(pool, layer, slots, x, B, C, dt, A, D, kernel: bool = False,
     """One token of every row. ``pool [layers, slots, G, N, W]`` float32
     (donated; row ``r``'s state is ``pool[layer, slots[r]]``, and rows that
     decode nothing share the spare slot 0 and hand in ``dt = 0``), ``x
-    [rows, H, P]``, ``B, C [rows, N]``, ``dt [rows, H]`` (after the
-    softplus), ``A, D [H]``. Returns ``(y [rows, H, P] float32, pool)``.
+    [rows, H, P]``, ``B, C [rows, Gr, N]`` (or ``[rows, N]``: one group),
+    ``dt [rows, H]`` (after the softplus), ``A, D [H]``. Returns ``(y [rows,
+    H, P] float32, pool)``.
     The XLA form updates the spare slot as any other (``dt = 0`` decays it
     by 1 and adds 0: it stays what it was); the kernel reads ``slots`` and
     neither reads nor writes it, nor any slot no row names, and a row of
@@ -330,12 +375,15 @@ def mamba2_step(pool, layer, slots, x, B, C, dt, A, D, kernel: bool = False,
     x, dt = x.astype(f32), dt.astype(f32)
     a = jnp.repeat(jnp.exp(dt * A.astype(f32)), p, axis=1).reshape(rows, g, w)
     dx = (dt[..., None] * x).reshape(rows, g, w)
-    b, c = B.astype(f32), C.astype(f32)
+    b, c = _groups(B, g), _groups(C, g)                   # [rows, Gr, N]
     if kernel:
         y, pool = _step_pallas(pool, layer, slots, a, dx, b, c, interpret)
     else:
-        st = (pool[layer, slots] * a[:, :, None, :]
-              + b[:, None, :, None] * dx[:, :, None, :])
-        y = jnp.einsum("rn,rgnw->rgw", c, st, precision=_HIGHEST)
-        pool = pool.at[layer, slots].set(st)
+        def grouped(t):  # [rows, G, ...] as [rows, Gr, a group's tiles, ...]
+            return t.reshape(rows, b.shape[1], -1, *t.shape[2:])
+
+        st = (grouped(pool[layer, slots]) * grouped(a)[..., None, :]
+              + b[:, :, None, :, None] * grouped(dx)[..., None, :])
+        y = jnp.einsum("rkn,rktnw->rktw", c, st, precision=_HIGHEST)
+        pool = pool.at[layer, slots].set(st.reshape(rows, g, *st.shape[3:]))
     return y.reshape(rows, h, p) + D.astype(f32)[:, None] * x, pool

@@ -6,7 +6,6 @@ never tokens) through prefill, decode through the pool and a hit from a
 snapshot; three planted faults, each far outside the tolerance; an expert
 layer's shares against the uncut layer; the loader."""
 
-import contextlib
 import dataclasses
 import sys
 from pathlib import Path
@@ -22,7 +21,7 @@ sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "hack"))
 
 from kvbench.harness import fleet as F, names  # noqa: E402
-from llmd_kv_cache_tpu.models import engine as engine_mod, llama  # noqa: E402
+from llmd_kv_cache_tpu.models import llama  # noqa: E402
 from llmd_kv_cache_tpu.models.engine import (  # noqa: E402
     EngineConfig, MiniEngine)
 from llmd_kv_cache_tpu.models.hf_loader import config_from_hf  # noqa: E402
@@ -151,23 +150,14 @@ def test_a_burst_decodes_in_one_batch_each_row_on_its_own_state(model):
                        for a in answers) < TOLERANCE
 
 
-@contextlib.contextmanager
 def planted(fault):
     """A fault of ``hack/kvbench_probe_readings.py`` planted in the program
-    for the block's length: what the step programs look up when they are
-    traced, so nothing traced before or after may be shared."""
+    for the block's length (``planted`` there: what the step programs look
+    up when they are traced is put back, and nothing traced before or after
+    is shared)."""
     import kvbench_probe_readings as tool
 
-    saved = (engine_mod.copy_state_slot, llama._gated_deltanet,
-             llama._sublayer_out)
-    jax.clear_caches()
-    tool.FAULTS[fault]()
-    try:
-        yield
-    finally:
-        (engine_mod.copy_state_slot, llama._gated_deltanet,
-         llama._sublayer_out) = saved
-        jax.clear_caches()
+    return tool.planted(fault)
 
 
 @pytest.mark.parametrize("fault", ["conv-tail", "stale-state",
@@ -251,9 +241,13 @@ def test_the_scalars_and_the_third_form_are_refused_where_not_built():
         with pytest.raises(ValueError, match=field):
             llama.LlamaConfig(**{**base, "linear": dataclasses.replace(
                 la, **{field: value})})
+    # Two heads of 64 a state tile: two groups of B and C are a tile each,
+    # four would put heads of two groups into one tile.
+    llama.LlamaConfig(**{**base, "linear": dataclasses.replace(
+        la, key_heads=2)})
     with pytest.raises(NotImplementedError, match="key_heads"):
         llama.LlamaConfig(**{**base, "linear": dataclasses.replace(
-            la, key_heads=2)})
+            la, key_heads=4)})
     with pytest.raises(NotImplementedError, match="with_state"):
         llama.LlamaConfig(embedding_multiplier=12.0)
     with pytest.raises(NotImplementedError, match="attention_multiplier"):

@@ -237,3 +237,163 @@ def test_the_kernels_names_are_what_a_trace_calls_them():
         pattern = N.metric(reader).KERNEL
         assert re.search(pattern, f"{own}.12")
         assert not re.search(pattern, f"{other}.12")
+
+
+# -- more than one group of B and C ------------------------------------------
+# Heads of 128 channels (one head a tile) in two groups, each with its own
+# B and C: head ``h`` reads group ``h // (heads / groups)``.
+
+
+def grouped(tokens, valid, seed, heads=4, p=128, n=32, groups=2, **kw):
+    """``inputs`` with ``B, C [T, groups, N]``, each group its own draw."""
+    x, b, c, dt, a, skip, state = inputs(tokens, valid, seed=seed,
+                                         heads=heads, p=p, n=n, **kw)
+    more = [inputs(tokens, valid, seed=seed + 100 * g, heads=heads, p=p,
+                   n=n)[1:3] for g in range(1, groups)]
+    b = np.stack([b] + [m[0] for m in more], axis=1)
+    c = np.stack([c] + [m[1] for m in more], axis=1)
+    return x, b, c, dt, a, skip, state
+
+
+def grouped_recurrence(x, b, c, dt, a, skip, state):
+    """``recurrence`` a group of heads at a time: ``b, c [T, Gr, N]``."""
+    heads, groups = x.shape[1], b.shape[1]
+    per = heads // groups
+    outs, states = [], []
+    for g in range(groups):
+        of = slice(g * per, (g + 1) * per)
+        o, s = recurrence(x[:, of], b[:, g], c[:, g], dt[:, of], a[of],
+                          skip[of], state[of])
+        outs.append(o)
+        states.append(s)
+    return (np.concatenate(outs, axis=1),
+            [np.concatenate([s[t] for s in states])
+             for t in range(x.shape[0])])
+
+
+@FORMS
+def test_the_grouped_scan_is_the_recurrence(kernel):
+    """Two groups, one head a tile: the outputs, the end state and the
+    requested block's state are the recurrence's, to 1e-4 in float32."""
+    x, b, c, dt, a, skip, state = grouped(48, 40, seed=11)
+    assert m2.heads_per_tile(4, 128) == 1
+    want, states = grouped_recurrence(x, b, c, dt, a, skip, state)
+    y, end, snap = mamba2_scan(x, b, c, dt, a, skip, m2.pack_state(state), 1,
+                               block=16, kernel=kernel, interpret=True)
+    np.testing.assert_allclose(y[:40], want[:40],
+                               atol=1e-4 * np.abs(want).max())
+    np.testing.assert_allclose(m2.unpack_state(end, 128), states[39],
+                               atol=1e-4)
+    np.testing.assert_allclose(m2.unpack_state(snap, 128), states[31],
+                               atol=1e-4)
+
+
+def test_the_grouped_kernels_are_their_xla_forms():
+    """Pallas (interpreted) against XLA at one head a tile and two groups:
+    the scan over three blocks and the step over four rows."""
+    x, b, c, dt, a, skip, state = grouped(48, 48, seed=12)
+    tiles = m2.pack_state(state)
+    for got, want in zip(
+            mamba2_scan(x, b, c, dt, a, skip, tiles, 0, block=16,
+                        kernel=True, interpret=True),
+            mamba2_scan(x, b, c, dt, a, skip, tiles, 0, block=16)):
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    pool = np.random.default_rng(12).normal(
+        size=(2, 6, *tiles.shape)).astype(np.float32)
+    slots = np.array([3, 0, 5, 1], np.int32)
+    step_dt = dt[:4] * (slots != 0)[:, None]
+    for got, want in zip(
+            mamba2_step(jnp.asarray(pool), 1, slots, x[:4], b[:4], c[:4],
+                        step_dt, a, skip, kernel=True, interpret=True),
+            mamba2_step(jnp.asarray(pool), 1, slots, x[:4], b[:4], c[:4],
+                        step_dt, a, skip)):
+        got, want = np.asarray(got), np.asarray(want)
+        if got.ndim == 3:           # a padded row's output is the kernel's 0
+            got, want = got[slots != 0], want[slots != 0]
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_a_grouped_state_kept_in_bfloat16_fails_where_float32_passes():
+    x, b, c, dt, a, skip, state = grouped(96, 96, seed=13, fastest=2.0)
+    _, states = grouped_recurrence(x, b, c, dt, a, skip, state)
+
+    def chained(keep):
+        tiles = m2.pack_state(state)
+        for at in range(0, 96, 16):
+            _, tiles, _ = mamba2_scan(
+                *(v[at:at + 16] for v in (x, b, c, dt)), a, skip, tiles, -1,
+                block=16)
+            tiles = keep(tiles)
+        return float(np.abs(m2.unpack_state(tiles, 128) - states[-1]).max())
+
+    assert chained(lambda t: t) < 1e-4
+    assert chained(lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)) > (
+        1e-3)
+
+
+@FORMS
+def test_the_grouped_step_is_one_token_of_the_scan(kernel):
+    """Each live row's output and state are one token of the recurrence
+    and of the grouped scan; the spare slot and the other layer stay."""
+    rows, heads, p, n = 4, 4, 128, 32
+    x, b, c, dt, a, skip, _ = grouped(rows, 3, seed=14)
+    pool = np.random.default_rng(14).normal(
+        size=(2, 6, *m2.state_shape(heads, p, n))).astype(np.float32)
+    slots = np.array([4, 2, 5, 0], np.int32)
+    y, new = mamba2_step(jnp.asarray(pool), 1, slots, x, b, c, dt, a, skip,
+                         kernel=kernel, interpret=True)
+    for r in range(3):
+        before = m2.unpack_state(jnp.asarray(pool[1, slots[r]]), p)
+        out, states = grouped_recurrence(x[r:r + 1], b[r:r + 1], c[r:r + 1],
+                                         dt[r:r + 1], a, skip, before)
+        np.testing.assert_allclose(y[r], out[0], atol=2e-5)
+        np.testing.assert_allclose(
+            new[1, slots[r]],
+            m2.pack_state(jnp.asarray(states[0], np.float32)), atol=2e-5)
+
+        def padded(v):
+            return np.concatenate([v[r:r + 1], np.zeros_like(v[:1])
+                                   .repeat(15, 0)])
+        y_scan, end, _ = mamba2_scan(
+            padded(x), padded(b), padded(c), padded(dt), a, skip,
+            pool[1, slots[r]], -1, block=16)
+        np.testing.assert_allclose(y[r], y_scan[0], atol=2e-5)
+        np.testing.assert_allclose(new[1, slots[r]], end, atol=2e-5)
+    np.testing.assert_array_equal(new[1, 0], pool[1, 0])
+    np.testing.assert_array_equal(new[0], pool[0])
+
+
+@FORMS
+def test_one_group_is_the_call_without_a_group_axis(kernel):
+    """``B, C [T, N]``, ``[T, 1, N]`` and two groups that carry the same B
+    and C give the same numbers exactly, scan and step."""
+    x, b, c, dt, a, skip, state = inputs(32, 32, seed=15, heads=4, p=128,
+                                         n=32)
+    tiles = m2.pack_state(state)
+    pool = jnp.asarray(np.random.default_rng(15).normal(
+        size=(1, 5, *tiles.shape)).astype(np.float32))
+    slots = np.array([1, 4, 0, 2], np.int32)
+    use = dict(kernel=kernel, interpret=True)
+
+    def both(b, c):
+        return (*mamba2_scan(x, b, c, dt, a, skip, tiles, 0, block=16,
+                             **use),
+                *mamba2_step(pool + 0, 0, slots, x[:4], b[:4], c[:4],
+                             dt[:4] * (slots != 0)[:, None], a, skip, **use))
+
+    flat = both(b, c)
+    for b_g, c_g in ((b[:, None], c[:, None]),
+                     (np.stack([b, b], 1), np.stack([c, c], 1))):
+        for got, want in zip(both(b_g, c_g), flat):
+            np.testing.assert_array_equal(got, want)
+
+
+def test_a_tile_of_two_groups_is_refused():
+    """Two heads of 64 share a tile: they cannot read two groups."""
+    x, b, c, dt, a, skip, state = grouped(16, 16, seed=16, heads=2, p=64)
+    with pytest.raises(ValueError, match="two groups"):
+        mamba2_scan(x, b, c, dt, a, skip, m2.pack_state(state), -1, block=16)
+    pool = jnp.zeros((1, 2, *m2.state_shape(2, 64, 32)), jnp.float32)
+    with pytest.raises(ValueError, match="two groups"):
+        mamba2_step(pool, 0, np.array([1], np.int32), x[:1], b[:1], c[:1],
+                    dt[:1], a, skip)

@@ -515,6 +515,23 @@ class TestDecodeKernelLowersForTheChip:
         k_cache, v_cache = init_kv_cache(cfg, engine["num_pages"])
         rows, width = engine["max_batch"], engine["max_pages_per_seq"]
         state = {}
+        if cfg.parallel_layers:
+            # Its layers keep pages AND a state: the pools ride in the state
+            # and the multipliers reach the body as a view, both of which
+            # the step form sets up (``llama.with_pages_in_state``).
+            from llmd_kv_cache_tpu.models import llama
+
+            packed, shapes = llama.pack_inputs((
+                np.zeros((rows, 1)), np.zeros((rows, width)),
+                np.ones((rows,)), np.ones((rows,)), np.zeros((rows,)),
+                np.zeros((3,))))
+            text = llama.step_decode_pallas_paged_state.trace(
+                params, cfg, packed,
+                (k_cache, v_cache, *llama.init_state_pool(cfg)),
+                shapes=shapes, interpret=False, mesh=None, batch_rows=1,
+            ).lower(lowering_platforms=("tpu",)).as_text()
+            assert text.count("tpu_custom_call") >= 2  # both mixers' kernels
+            return
         if cfg.linear_layers:  # its state pool and the rows' slots in it
             from llmd_kv_cache_tpu.models.llama import init_state_pool
 

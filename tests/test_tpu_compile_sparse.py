@@ -299,3 +299,42 @@ def test_mamba2_step(shaped):
              shaped((M2_ROWS, M2_STATE)), shaped((M2_ROWS, M2_STATE)),
              shaped((M2_ROWS, M2_HEADS), f32), shaped((M2_HEADS,), f32),
              shaped((M2_HEADS,), f32))
+
+
+# The recurrence of ``falcon-h1-34b-l9``'s mixers: 32 heads of 128 channels
+# (one head a tile) over a state of 256, two groups of B and C, 12 rows.
+FH_LAYERS, FH_SLOTS, FH_HEADS, FH_HEAD, FH_STATE, FH_GROUPS, FH_ROWS = (
+    9, 38, 32, 128, 256, 2, 12)
+
+
+def test_mamba2_scan_of_two_groups_at_one_head_a_tile(shaped):
+    """A chunk of 512 tokens in blocks of 64 over tiles ``[256, 128]``, a
+    tile reading its group's B, C and ``C B^T``."""
+    from llmd_kv_cache_tpu.ops.mamba2 import mamba2_scan, state_shape
+
+    f32 = jnp.float32
+    assert state_shape(FH_HEADS, FH_HEAD, FH_STATE) == (32, 256, 128)
+    compiles(functools.partial(mamba2_scan, block=PAGE, kernel=True),
+             shaped((CHUNK, FH_HEADS, FH_HEAD)),
+             shaped((CHUNK, FH_GROUPS, FH_STATE)),
+             shaped((CHUNK, FH_GROUPS, FH_STATE)),
+             shaped((CHUNK, FH_HEADS), f32), shaped((FH_HEADS,), f32),
+             shaped((FH_HEADS,), f32),
+             shaped(state_shape(FH_HEADS, FH_HEAD, FH_STATE), f32),
+             shaped((), jnp.int32))
+
+
+def test_mamba2_step_of_two_groups_at_one_head_a_tile(shaped):
+    """12 rows' states, a group's 16 tiles a grid step (2 MiB in, 2 out)."""
+    from llmd_kv_cache_tpu.ops.mamba2 import mamba2_step, state_shape
+
+    f32 = jnp.float32
+    compiles(functools.partial(mamba2_step, kernel=True),
+             shaped((FH_LAYERS, FH_SLOTS,
+                     *state_shape(FH_HEADS, FH_HEAD, FH_STATE)), f32),
+             shaped((), jnp.int32), shaped((FH_ROWS,), jnp.int32),
+             shaped((FH_ROWS, FH_HEADS, FH_HEAD)),
+             shaped((FH_ROWS, FH_GROUPS, FH_STATE)),
+             shaped((FH_ROWS, FH_GROUPS, FH_STATE)),
+             shaped((FH_ROWS, FH_HEADS), f32), shaped((FH_HEADS,), f32),
+             shaped((FH_HEADS,), f32))
