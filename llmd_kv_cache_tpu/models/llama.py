@@ -2531,9 +2531,8 @@ def forward_decode_pallas(
         latents."""
         topk = cfg.index_topk
         with jax.named_scope(SCOPE_INDEX):
-            scores = sparse_index.dsa_index_scores(
-                *index, sparse_index.gather_index_keys(
-                    idx_stack, layer_idx, table),
+            scores = sparse_index.dsa_index_scores_paged(
+                *index, idx_stack, layer_idx, table,
                 jnp.where(lens > topk, lens, 0), interpret=interpret)[:, 0]
         with jax.named_scope(SCOPE_SELECT):
             picked, count = sparse_index.select_topk(scores, lens, topk)

@@ -16,7 +16,12 @@ decode program's:
   logical order (a page is the pool's own unit, so the gather moves whole
   16 KiB pages) and scored by one kernel, ``dsa_index_scores``, which reads
   ``lens`` keys of each row and no more; ``index_scores_xla`` is the same
-  function in ``jax.numpy`` for the XLA programs.
+  function in ``jax.numpy`` for the XLA programs. A chunk's one row is
+  scored so. A decode step, which has the pool, the rows' page table and
+  one live row in eight, scores the keys where they lie
+  (``dsa_index_scores_paged``: the same products over a live row's own
+  pages, streamed from the pool through its line of the page table): the
+  gather wrote 69 MB a layer for eight padded rows whatever they held.
 - ``kth_largest`` / ``keep_mask``: exact selection as a threshold, by
   bisection on the scores' bits: what a query keeps is ``score >= its
   topk-th largest``. Keys tied with the topk-th are all kept (with real
@@ -163,6 +168,131 @@ def dsa_index_scores(q_idx: jax.Array, w_idx: jax.Array, keys: jax.Array,
             vmem_limit_bytes=64 * 2 ** 20),
         interpret=interpret,
     )(lens.astype(jnp.int32), q2, w2, keys.astype(q_idx.dtype))
+
+
+# Keys of a round of a row's own pages through VMEM: the decode kernel's
+# superblock, and one block of ``dsa_index_scores``.
+_ROUND_KEYS = 1024
+
+
+def _round_copies(k_hbm, layer, table_ref, b, last_page, landed, sem):
+    """``copies(buf, r)``: round ``r`` of row ``b``'s own pages of layer
+    ``layer`` of the pool ``k_hbm [layers, pages, 1, page_size, width]``
+    into ``landed[buf] [pages a round, page_size, width]``, each page looked
+    up in the row's line of the page table by a scalar read and signalled
+    on ``sem[buf, t]``. Past ``last_page`` that page again: every key of a
+    round then is one the row holds (finite, where a product meets it)."""
+    kpb = landed.shape[1]
+
+    def copies(buf, r):
+        return [pltpu.make_async_copy(
+            k_hbm.at[layer, table_ref[b, jnp.minimum(r * kpb + t,
+                                                     last_page)], 0],
+            landed.at[buf, t], sem.at[buf, t]) for t in range(kpb)]
+
+    return copies
+
+
+def _paged_index_kernel(table_ref, lens_ref, layer_ref, q_ref, w_ref, k_hbm,
+                        o_ref, landed, sem, *, tq, heads):
+    # table_ref [rows, pages a row], lens_ref [rows], layer_ref [1] (SMEM);
+    # q_ref [1, tq * heads, width], w_ref [1, tq * heads, 1]; k_hbm the pool
+    # [layers, pages, 1, page_size, width], left in HBM; o_ref [1, tq, pages
+    # a row * page_size]; landed [2, kpb, page_size, width]: a round of the
+    # row's own pages, twice.
+    b = pl.program_id(0)
+    total = lens_ref[b]
+    _, kpb, page_size, width = landed.shape
+    keys = kpb * page_size
+    rounds = (total + keys - 1) // keys
+    copies = _round_copies(k_hbm, layer_ref[0], table_ref, b,
+                           (total - 1) // page_size, landed, sem)
+
+    def at(r):
+        return pl.ds(pl.multiple_of(r * keys, keys), keys)
+
+    @pl.when(total > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    @pl.loop(0, rounds)
+    def _(r):
+        buf = r % 2
+
+        @pl.when(r + 1 < rounds)
+        def _():
+            for c in copies(1 - buf, r + 1):
+                c.start()
+
+        for c in copies(buf, r):
+            c.wait()
+        dots = jax.lax.dot_general(
+            q_ref[0], landed[buf].reshape(keys, width).astype(q_ref.dtype),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [tq * heads, keys]
+        dots = jnp.maximum(dots, 0.0) * w_ref[0]
+        live = r * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (1, keys), 1) < total
+        for i in range(tq):
+            o_ref[0, i:i + 1, at(r)] = jnp.where(live, jnp.sum(
+                dots[i * heads:(i + 1) * heads], axis=0, keepdims=True), 0.0)
+
+    @pl.loop(rounds, o_ref.shape[2] // keys)
+    def _(r):
+        o_ref[0, :, at(r)] = jnp.zeros((tq, keys), jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dsa_index_scores_paged(q_idx: jax.Array, w_idx: jax.Array,
+                           idx_stack: jax.Array, layer_idx,
+                           page_table: jax.Array, lens: jax.Array, *,
+                           interpret: bool = False) -> jax.Array:
+    """``dsa_index_scores`` over ``gather_index_keys(idx_stack, layer_idx,
+    page_table)`` without the gather: the keys are scored where they lie.
+    ``idx_stack [layers, pages, 1, page_size, width]`` stays in HBM, and a
+    program (grid: row, query tile) streams its row's own ``ceil(lens /
+    page_size)`` pages through VMEM, a round of 1024 keys at a time, twice
+    buffered, each page looked up in the row's line of ``page_table [rows,
+    pages a row]`` on the way (``gather_by_product``'s round). A round is
+    scored as ``dsa_index_scores`` scores a block (the same products in the
+    same types: below ``lens`` the scores are that kernel's bit for bit),
+    and everything from ``lens`` on is 0. A row of ``lens`` 0 starts no
+    copy: a decode step's padded rows, and its rows of at most
+    ``index_topk`` keys, cost the zeros written for them."""
+    batch, q_seq, heads, width = q_idx.shape
+    pages = page_table.shape[1]
+    page_size = idx_stack.shape[-2]
+    tq = _tile(q_seq, 16)
+    kpb = _tile(pages, _ROUND_KEYS // page_size)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(batch, q_seq // tq),
+        in_specs=[
+            pl.BlockSpec((1, tq * heads, width),
+                         lambda b, qt, *_p: (b, qt, 0)),
+            pl.BlockSpec((1, tq * heads, 1), lambda b, qt, *_p: (b, qt, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, tq, pages * page_size),
+                               lambda b, qt, *_p: (b, qt, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, kpb, page_size, idx_stack.shape[-1]),
+                       idx_stack.dtype),
+            pltpu.SemaphoreType.DMA((2, kpb))],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_index_kernel, tq=tq, heads=heads),
+        out_shape=jax.ShapeDtypeStruct((batch, q_seq, pages * page_size),
+                                       jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), lens.astype(jnp.int32),
+      jnp.asarray(layer_idx, jnp.int32).reshape(1),
+      q_idx.reshape(batch, q_seq * heads, width),
+      w_idx.astype(jnp.float32).reshape(batch, q_seq * heads, 1), idx_stack)
 
 
 def _ordered_bits(x: jax.Array) -> jax.Array:
@@ -571,10 +701,8 @@ def select_topk(scores: jax.Array, total_lens: jax.Array, topk: int
     return picked, jnp.minimum(total_lens, topk)
 
 
-# Slots of the chosen pool a product fills at a time, and keys of a round:
-# one MXU pass wide, and the decode kernel's superblock.
+# Slots of the chosen pool a product fills at a time: one MXU pass wide.
 _SLOT_TILE = 128
-_ROUND_KEYS = 1024
 
 
 def _gather_kernel(table_ref, count_ref, layer_ref, pos_ref, k_hbm, o_hbm,
@@ -629,13 +757,10 @@ def _gather_kernel(table_ref, count_ref, layer_ref, pos_ref, k_hbm, o_hbm,
     def _():
         last_page = reach // page_size
 
-        def copies(buf, r):
-            # Past the row's last page that page again (finite, and no
-            # slot's): a product may not meet what VMEM held before.
-            return [pltpu.make_async_copy(
-                k_hbm.at[layer, table_ref[b, jnp.minimum(r * kpb + t,
-                                                         last_page)], 0],
-                landed.at[buf, t], sem.at[buf, t]) for t in range(kpb)]
+        # A product may not meet what VMEM held before: past the row's
+        # last page the copies bring that page again (no slot's).
+        copies = _round_copies(k_hbm, layer, table_ref, b, last_page,
+                               landed, sem)
 
         for c in copies(0, 0):
             c.start()

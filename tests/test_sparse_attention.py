@@ -313,6 +313,12 @@ class TestSelection:
         """A device trace names a kernel's ops after its jitted wrapper."""
         assert (sparse_index.dsa_index_scores.__name__
                 == sparse_index.KERNEL_INDEX == "dsa_index_scores")
+        # The decode step's form of it, which reads the pool: the same
+        # kernel to the benchmark's reader of the scoring's roofline.
+        from kvbench.metrics import dsa_index_roofline
+        for kernel in (sparse_index.dsa_index_scores,
+                       sparse_index.dsa_index_scores_paged):
+            assert re.match(dsa_index_roofline.KERNEL, kernel.__name__)
         assert (sparse_index.dsa_keep_bias.__name__
                 == sparse_index.KERNEL_KEEP == "dsa_keep_bias")
         assert (sparse_index.topk_by_count.__name__
@@ -601,6 +607,76 @@ class TestSelection:
         np.testing.assert_allclose(got[0], want[0], rtol=2e-2, atol=2e-2)
         np.testing.assert_allclose(got[1, :, :70], want[1, :, :70],
                                    rtol=2e-2, atol=2e-2)
+
+    # (page size, heads, queries a row, layers, layer_idx, pages a row, each
+    # row's ``lens``, the order of a row's pages in the pool). A round is
+    # 1024 keys, or all of a row's pages where they hold fewer (a power of
+    # two of them): 128 keys at 24 pages of 16.
+    PAGED_CASES = {
+        "rows-of-0-keys": (16, 4, 1, 1, 0, 24, [0, 0], "shuffled"),
+        "a-row-ends-inside-a-page": (16, 4, 1, 1, 0, 24, [301], "shuffled"),
+        "a-row-ends-on-a-rounds-edge": (16, 4, 1, 2, 1, 24, [256, 128],
+                                        "shuffled"),
+        "one-key": (16, 4, 1, 1, 0, 24, [1, 0], "shuffled"),
+        "every-page-of-a-row": (16, 4, 1, 1, 0, 24, [384, 383], "shuffled"),
+        "live-and-dead-rows-in-any-order": (
+            16, 4, 1, 2, 0, 24, [0, 300, 0, 0, 77, 384, 0, 129], "shuffled"),
+        "pages-out-of-order": (16, 4, 1, 2, 0, 24, [380, 50], "descending"),
+        "pages-in-order": (16, 4, 1, 2, 1, 24, [380, 50], "ascending"),
+        "another-layer": (16, 8, 1, 3, 2, 40, [333, 0, 65], "shuffled"),
+        "rounds-of-1024-keys": (64, 4, 1, 1, 0, 48, [3000, 0, 2048, 1025],
+                                "shuffled"),
+        "the-cells-528-pages": (64, 4, 1, 1, 0, 528, [0, 33792, 0, 25000],
+                                "shuffled"),
+        "a-chunks-queries": (16, 4, 32, 1, 0, 24, [0, 300, 384],
+                             "shuffled"),
+    }
+
+    @pytest.mark.parametrize("case", PAGED_CASES)
+    def test_the_paged_scoring_kernel_is_the_function(self, case):
+        """``dsa_index_scores_paged`` (interpreted), which reads the pool
+        through the page table, against ``dsa_index_scores`` and
+        ``index_scores_xla`` over ``gather_index_keys``: below a row's
+        ``lens`` the kernel's scores bit for bit, zeros from there on. And
+        it reads what a row holds and no more: with NaN in every page that
+        no row names below its ``lens`` (the garbage page 0 among them: all
+        a dead row's line names) the scores are the same, so finite."""
+        (page_size, heads, q_seq, layers, layer_idx, row_pages, lens,
+         order) = self.PAGED_CASES[case]
+        width = 128
+        pool, table, _, _ = self.gather_inputs(
+            case, page_size, width, layers, row_pages, 1, lens, order)
+        rng = np.random.default_rng(len(case))
+        q = jnp.asarray(rng.standard_normal(
+            (len(lens), q_seq, heads, width)), jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((len(lens), q_seq, heads)),
+                        jnp.float32)
+        n = jnp.asarray(lens, jnp.int32)
+        sound = jnp.asarray(pool, jnp.bfloat16)
+        keys = sparse_index.gather_index_keys(sound, layer_idx,
+                                              jnp.asarray(table))
+        kernel = np.asarray(sparse_index.dsa_index_scores(
+            q, w, keys, n, interpret=True))
+        xla = np.asarray(sparse_index.index_scores_xla(q, w, keys))
+        got = np.asarray(sparse_index.dsa_index_scores_paged(
+            q, w, sound, layer_idx, jnp.asarray(table), n, interpret=True))
+        assert got.shape == (len(lens), q_seq, row_pages * page_size)
+        assert got.dtype == np.float32
+        for row, k in enumerate(lens):
+            assert got[row, :, :k].tobytes() == kernel[row, :, :k].tobytes()
+            np.testing.assert_allclose(got[row, :, :k], xla[row, :, :k],
+                                       rtol=1e-5, atol=1e-5)
+            assert (got[row, :, k:] == 0).all()
+            assert k == 0 or np.abs(got[row, :, :k]).max() > 0
+        pool[:, np.setdiff1d(np.arange(pool.shape[1]), table[table > 0])
+             ] = np.nan
+        pool[np.arange(layers) != layer_idx] = np.nan
+        assert np.isnan(pool[:, 0]).all()
+        poisoned = np.asarray(sparse_index.dsa_index_scores_paged(
+            q, w, jnp.asarray(pool, jnp.bfloat16), layer_idx,
+            jnp.asarray(table), n, interpret=True))
+        assert np.isfinite(poisoned).all()
+        assert poisoned.tobytes() == got.tobytes()
 
 
 class TestExpertLayer:

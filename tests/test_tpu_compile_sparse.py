@@ -69,6 +69,16 @@ def test_index_scores(shaped, rows, q_seq):
              shaped((rows, KEYS, 128)), shaped((rows,), jnp.int32))
 
 
+def test_index_scores_paged(shaped):
+    """A decode step's scores from the pool itself: 528 page ids a row as
+    scalars, a round of 16 pages of index keys twice in fast memory, a
+    row's 33,792 scores as one block."""
+    compiles(sparse_index.dsa_index_scores_paged,
+             shaped((ROWS, 1, 64, 128)), shaped((ROWS, 1, 64), jnp.float32),
+             shaped((LAYERS, PAGES, 1, PAGE, 128)), shaped((), jnp.int32),
+             shaped((ROWS, ROW_PAGES), jnp.int32), shaped((ROWS,), jnp.int32))
+
+
 def test_keep_bias(shaped):
     """A chunk's thresholds: 16 queries x 33 blocks of 1024 scores a
     program in fast memory, twice (as they land, as they are counted)."""
