@@ -21,7 +21,12 @@ chip (``chiprun -- python3 hack/kvbench_probe_readings.py ...``) or, with
   --set KEY=VALUE      a published key of the configuration replaced
   --serve KEY=VALUE    a key replaced in what the engine SERVES only (the
                        reference keeps the configuration's): a planted
-                       fault, e.g. ``swiglu_limit=0``
+                       fault, e.g. ``swiglu_limit=0``; a model of window
+                       and full layers' three controls: ``rope_parameters=``
+                       with the yarn rule under both kinds, or the plain
+                       one under both, and ``sliding_window=131072`` (the
+                       window ignored: both pools, a window that never
+                       binds)
   --fault NAME         a fault planted in the serving program
                        (``FAULTS``); it has to come out not ok
                        (``verify-mask``: a drafting model's first verified

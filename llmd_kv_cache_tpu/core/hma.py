@@ -46,13 +46,34 @@ class GroupCatalog:
         # ``mamba``). Replaced whole when it changes, so a scorer reads it
         # without the lock; empty while no pod has such a group.
         self.state_groups: dict[str, int] = {}
+        # pod -> (group, blocks of its window) for the pods that keep a
+        # window group (kind ``sliding_window``) BESIDE a group of another
+        # kind: a pool of its own whose pages are reclaimed behind the
+        # window, so a resume needs the trailing window of it. A pod whose
+        # only group is a window (a uniform window, one pool, nothing
+        # reclaimed) resumes by longest prefix and is not listed. Replaced
+        # whole when it changes, as ``state_groups``.
+        self.window_groups: dict[str, tuple[int, int]] = {}
 
     def learn(self, pod_id: str, group_idx: int, meta: GroupMetadata) -> None:
         with self._lock:
-            self._entries.setdefault(pod_id, {})[group_idx] = meta
+            groups = self._entries.setdefault(pod_id, {})
+            known = groups.get(group_idx) == meta
+            groups[group_idx] = meta
             if (meta.kind == SPEC_MAMBA
                     and self.state_groups.get(pod_id) != group_idx):
                 self.state_groups = {**self.state_groups, pod_id: group_idx}
+            if known or all(m.kind == SPEC_SLIDING_WINDOW
+                            for m in groups.values()):
+                return
+            window = next(
+                ((g, -(-m.sliding_window_size // max(m.block_size, 1)))
+                 for g, m in sorted(groups.items())
+                 if m.kind == SPEC_SLIDING_WINDOW and m.sliding_window_size),
+                None)
+            if window is not None and self.window_groups.get(
+                    pod_id) != window:
+                self.window_groups = {**self.window_groups, pod_id: window}
 
     def get(self, pod_id: str, group_idx: int) -> Optional[GroupMetadata]:
         with self._lock:

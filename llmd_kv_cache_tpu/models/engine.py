@@ -69,6 +69,7 @@ from ..telemetry.tracing import (
     PHASE_STEP_OFFLOAD_POLL,
     PHASE_STEP_SCHEDULE,
     PHASE_STEP_SNAPSHOT,
+    PHASE_STEP_WINDOW,
     NOOP_SPAN,
     SPAN_ENGINE_DECODE_STEP,
     EnginePhases,
@@ -91,6 +92,8 @@ from .llama import (
     step_decode_pallas_state,
     step_forward,
     step_forward_hybrid,
+    step_decode_pallas_pools,
+    step_prefill_pallas_pools,
     step_forward_paged_state,
     step_forward_state,
     step_prefill_pallas,
@@ -171,7 +174,7 @@ class EngineConfig:
     model: LlamaConfig = field(default_factory=LlamaConfig.tiny)
     num_pages: int = 512
     # Hybrid models: size of the SWA group's separate page pool (None →
-    # num_pages). SWA pages are allocated just-in-time and reclaimed as
+    # the model's ``window_pages``, else num_pages). SWA pages are allocated just-in-time and reclaimed as
     # slots fall out of the window, so per-request peak demand is
     # window + prefill-chunk pages (+ the decode page), not prompt length
     # — the memory win of hybrid attention.
@@ -425,6 +428,12 @@ class BlockManager:
         # beside these pages: snapshots stand on blocks, so an eviction
         # here takes the snapshots on or after its victims, in its batch.
         self.state_pool: Optional[StatePool] = None
+        # A two-pool engine's window pool's manager, on the global pool's:
+        # ``pool_stats`` then tells both. ``reclaimed`` counts, on the
+        # window pool's own, the pages a request gave back behind its
+        # window (``MiniEngine._swa_reclaim``).
+        self.window_manager: Optional["BlockManager"] = None
+        self.reclaimed = 0
         if spec_kind is not None:
             self.spec_kind = spec_kind
             self.spec_window = spec_window
@@ -481,6 +490,10 @@ class BlockManager:
             "evictions": self.evictions,
             **(self.state_pool.stats() if self.state_pool is not None
                else {}),
+            **({"window_evictions": self.window_manager.evictions,
+                "window_reclaimed": self.window_manager.reclaimed,
+                "window_free": len(self.window_manager.free_pages)}
+               if self.window_manager is not None else {}),
         }
 
     def _emit(self, events: list[GenericEvent]) -> None:
@@ -870,7 +883,8 @@ class MiniEngine:
             if device is not None:
                 self.state = jax.device_put(self.state, device)
         if self.hybrid:
-            num_swa = self.cfg.num_swa_pages or self.cfg.num_pages
+            num_swa = (self.cfg.num_swa_pages or mcfg.window_pages
+                       or self.cfg.num_pages)
             self.block_manager = BlockManager(
                 self.cfg, self.processor, event_sink, group_idx=0,
                 spec_kind=SPEC_FULL_ATTENTION, spec_window=None,
@@ -880,6 +894,9 @@ class MiniEngine:
                 num_pages=num_swa, spec_kind=SPEC_SLIDING_WINDOW,
                 spec_window=mcfg.sliding_window,
             )
+            # The one manager a caller reads (``pool_stats``) tells the
+            # window pool's counts with its own.
+            self.block_manager.window_manager = self.swa_manager
             with jax.default_device(device):
                 pools = init_kv_cache_hybrid(mcfg, self.cfg.num_pages,
                                              num_swa, dtype=kv_dtype)
@@ -1018,11 +1035,10 @@ class MiniEngine:
                     "using XLA attention",
                     mcfg.kv_cache_heads, self._tp, mcfg.page_size)
                 use_pallas = False
-        if self.hybrid:
-            # A two-pool (window + global) model steps through the XLA
-            # grouped forward over both pools: the kernels take one page
-            # table a call, and no step program hands each layer its own
-            # group's (ROADMAP queue 2, M5).
+        if self.hybrid and mesh is not None:
+            # A two-pool (window + global) model's kernel forms
+            # (``llama.step_decode_pallas_pools``) are not sharded: under a
+            # mesh it steps through the XLA grouped forward over both pools.
             use_pallas = False
         rows = max(1, self.cfg.decode_batch_rows)
         if mcfg.kv_cache_heads == 1:
@@ -1042,6 +1058,10 @@ class MiniEngine:
                 step_decode_pallas, interpret=interpret,
                 mesh=pallas_mesh, batch_rows=rows,
             )
+            if self.hybrid:
+                self._decode_forward = functools.partial(
+                    step_decode_pallas_pools, interpret=interpret,
+                    batch_rows=rows)
         else:
             pallas_mesh = None
             self._decode_forward = step_forward
@@ -1068,17 +1088,24 @@ class MiniEngine:
             self._prefill_forward = functools.partial(
                 step_prefill_pallas, interpret=interpret, mesh=pallas_mesh
             )
+            if self.hybrid:
+                self._prefill_forward = functools.partial(
+                    step_prefill_pallas_pools, interpret=interpret)
         else:
             if self.cfg.use_pallas_prefill and not use_pallas:
                 logger.warning(
                     "use_pallas_prefill=True ignored: the Pallas backend is "
-                    "inactive (platform/head-dim/hybrid gating above); using "
+                    "inactive (platform/head-dim/mesh gating above); using "
                     "XLA prefill")
             self._prefill_forward = step_forward
         if self.hybrid:
-            # Single-token hybrid steps and hybrid prefill run the XLA
-            # grouped forward over both pools.
-            self._decode_forward = self._prefill_forward = step_forward_hybrid
+            # Without the kernels a two-pool model's steps and chunks run
+            # the XLA grouped forward over both pools (the form the CPU
+            # tests hold the kernel forms to).
+            if self._decode_forward is step_forward:
+                self._decode_forward = step_forward_hybrid
+            if self._prefill_forward is step_forward:
+                self._prefill_forward = step_forward_hybrid
         if self.state_pool is not None:
             self._decode_forward = _with_state(
                 self._decode_forward, bool(mcfg.parallel_layers))
@@ -1145,11 +1172,13 @@ class MiniEngine:
         # (``_bound_chunks``).
         self._chunk_ahead: Optional[_Unread] = None
         # Not a sharded engine (its tokens come back laid out over a mesh
-        # and ``prev`` would be a second form of every program), not a
-        # hybrid one (its window pages are ensured and reclaimed from
-        # ``computed_len``, a token at a time): those read each program
-        # before the next is built, and take no ``prev``.
-        self._defers = mesh is None and not self.hybrid
+        # and ``prev`` would be a second form of every program): that one
+        # reads each program before the next is built, and takes no
+        # ``prev``. A two-pool engine defers like any other: a program
+        # launched ahead has its window page ensured from the context it
+        # will write at (``_window_tables``), and what the read of the
+        # program before it reclaims lies behind both programs' windows.
+        self._defers = mesh is None
         # (Every padded decode form counts what the model counts.)
         self._prev = jax.device_put(
             np.zeros((self.cfg.max_batch + len(mcfg.step_counters),),
@@ -1440,6 +1469,13 @@ class MiniEngine:
         if named and self.cfg.model.is_mla:
             named["expanded_keys"] = self._expanded_keys(
                 program, req.prefill_pos, tokens, padded)
+        if named and self.hybrid:
+            # What the chunk's kernel streams a layer: every key in a full
+            # layer, the chunk's own and the window before them in a
+            # window layer.
+            named["full_keys"] = req.prefill_pos + tokens
+            named["window_keys"] = tokens + min(
+                req.prefill_pos, self.cfg.model.sliding_window - 1)
         return phase(ph, PHASE_STEP_DISPATCH, traceparent, programs=1,
                      rows=rows, tokens=tokens, padded=padded,
                      request_id=req.request_id, prefill_pos=req.prefill_pos,
@@ -1666,10 +1702,15 @@ class MiniEngine:
             sp.set_attribute("hit_blocks", req.hbm_hit_blocks)
             sp.set_attribute(
                 "evicted", self.block_manager.evictions - evictions)
-            if self.state_pool is not None:
+            if self.state_pool is not None or self.hybrid:
+                # What the pages' chain matched, and what was kept of it:
+                # cut back to the deepest snapshot, or (two pools) to the
+                # deepest depth whose trailing window still stands.
                 page = page_size * req.page_hit_blocks
                 sp.set_attribute("page_hit_tokens", page)
-                sp.set_attribute("state_hit_tokens", req.cached_len)
+                sp.set_attribute(
+                    "window_hit_tokens" if self.hybrid
+                    else "state_hit_tokens", req.cached_len)
         return req
 
     def _acquire_pages(self, req: Request, total_needed: int,
@@ -1687,7 +1728,7 @@ class MiniEngine:
             # map to the garbage page (attention masks them anyway).
             page_sz = self.cfg.model.page_size
             window = self.cfg.model.sliding_window
-            d = len(cached_pages)
+            req.page_hit_blocks = d = len(cached_pages)
             swa_map: dict[int, int] = {}
             start_blk = 0
             while d > 0:
@@ -2331,15 +2372,44 @@ class MiniEngine:
         table[: len(req.swa_pages)] = req.swa_pages
         return table
 
+    def _window_tables(self, chunk: list[Request], ctx) -> tuple:
+        """The window pool's page table of a decode step of ``chunk``,
+        padded as the global pool's: each row's window pages, with a live
+        page under the block its new token's keys and values are written to
+        (``ctx[i]``: ``computed_len``, or one more where the row's last
+        token is still unread on the device)."""
+        table = np.zeros((len(ctx), self.cfg.max_pages_per_seq), np.int32)
+        for i, req in enumerate(chunk):
+            self._swa_ensure(req, int(ctx[i]) // self.cfg.model.page_size)
+            table[i] = self._swa_table_for(req)
+        return (table,)
+
+    def _pool_keys(self, sp, keys) -> None:
+        """Onto a two-pool model's decode dispatch phase ``sp``: the keys
+        a full layer attends over the step's live rows (``keys``: each
+        row's) and the keys a window layer does. (A chunk's two sums are
+        ``_dispatch_phase``'s.)"""
+        keys = np.asarray(keys)
+        sp.set_attribute("full_keys", int(keys.sum()))
+        sp.set_attribute("window_keys", int(np.minimum(
+            keys, self.cfg.model.sliding_window).sum()))
+
     def _swa_ensure(self, req: Request, upto_block: int) -> None:
         """Lazily extend the request's SWA page list through ``upto_block``
         (inclusive). SWA pages are allocated just-in-time so peak pool
         demand is window + chunk, not prompt length."""
-        while len(req.swa_pages) <= upto_block:
-            page = self.swa_manager.allocate_page()
-            if page is None:
+        lacking = upto_block + 1 - len(req.swa_pages)
+        if lacking <= 0:
+            return
+        with phase(self._phases, PHASE_STEP_WINDOW) as sp:
+            evictions = self.swa_manager.evictions
+            pages = self.swa_manager.allocate_pages(lacking)
+            sp.set_attribute("ensured", len(pages))
+            sp.set_attribute(
+                "evicted", self.swa_manager.evictions - evictions)
+            req.swa_pages.extend(pages)
+            if len(pages) < lacking:
                 raise RuntimeError("out of SWA KV pages")
-            req.swa_pages.append(page)
 
     def _swa_reclaim(self, req: Request) -> None:
         """Return the request's out-of-window SWA pages to the pool.
@@ -2363,19 +2433,25 @@ class MiniEngine:
         if limit <= start:
             return
         committed: list[int] = []
-        for i in range(start, limit):
-            page = req.swa_pages[i]
-            if not page:
-                continue
-            h = req.block_hashes[i] if i < len(req.block_hashes) else None
-            info = self.swa_manager.blocks.get(h) if h is not None else None
-            if info is not None and info.page == page:
-                committed.append(h)
-            else:
-                self.swa_manager.free_pages.append(page)
-            req.swa_pages[i] = 0
-        if committed:
-            self.swa_manager.release(committed, [])
+        with phase(self._phases, PHASE_STEP_WINDOW) as sp:
+            freed = 0
+            for i in range(start, limit):
+                page = req.swa_pages[i]
+                if not page:
+                    continue
+                h = req.block_hashes[i] if i < len(req.block_hashes) else None
+                info = (self.swa_manager.blocks.get(h) if h is not None
+                        else None)
+                if info is not None and info.page == page:
+                    committed.append(h)
+                else:
+                    self.swa_manager.free_pages.append(page)
+                    freed += 1
+                req.swa_pages[i] = 0
+            if committed:
+                self.swa_manager.release(committed, [])
+            self.swa_manager.reclaimed += freed + len(committed)
+            sp.set_attribute("reclaimed", freed + len(committed))
         req.swa_acquired_from = limit
 
     def _prefill(self, req: Request) -> _Unread:
@@ -2685,8 +2761,8 @@ class MiniEngine:
             cur, self._unread = self._unread, None
             one = len(active) <= self.cfg.max_batch
             if cur is None and not (self._defers and one):
-                # Sharded or hybrid engines (``_defers``), several
-                # chunks: each program is read before the next is built.
+                # Sharded engines (``_defers``), several chunks: each
+                # program is read before the next is built.
                 emitted.update(self._read_first(first))
                 for at in range(0, len(active), self.cfg.max_batch):
                     chunk = active[at:at + self.cfg.max_batch]
@@ -3075,15 +3151,8 @@ class MiniEngine:
         with phase(ph, PHASE_STEP_INPUTS):
             last, ctx, tables = self._decode_batch_arrays(chunk, rows=b)
             new_lens = np.zeros((b,), np.int32)
-            swa_tables = [np.zeros_like(tables)] if self.hybrid else []
-            for i, req in enumerate(chunk):
+            for i in range(len(chunk)):
                 new_lens[i] = 1
-                if self.hybrid:
-                    # The new token's KV writes at block computed_len//page
-                    # — make sure that SWA slot has a live page.
-                    self._swa_ensure(
-                        req, req.computed_len // self.cfg.model.page_size)
-                    swa_tables[0][i] = self._swa_table_for(req)
             state_args = ()
             if self.state_pool is not None:
                 slots = np.zeros((b,), np.int32)  # rows without: the spare
@@ -3101,6 +3170,7 @@ class MiniEngine:
                     ctx[src >= 0] += 1
                 state_args += (src,)
                 operand["prev"] = self._prev
+            swa_tables = self._window_tables(chunk, ctx) if self.hybrid else ()
             packed, shapes = pack_inputs(
                 (last[:, None], tables, *swa_tables, ctx, new_lens,
                  *state_args))
@@ -3119,6 +3189,8 @@ class MiniEngine:
                 sp.set_attribute("ahead", int(ahead))
             if self.state_pool is not None:
                 sp.set_attribute("state_rows", len(chunk))
+            if self.hybrid and sp is not NOOP_SPAN:
+                self._pool_keys(sp, ctx[:len(chunk)] + 1)
             topk = self.cfg.model.index_topk
             if topk and sp is not NOOP_SPAN:
                 # What the step's selection reads a layer, from the rows'

@@ -50,7 +50,13 @@ def _convert_rope_scaling(hf_cfg: Any) -> tuple:
     the modern ``rope_type`` and legacy ``type`` key spellings) refuses:
     converting would silently change every position's frequencies vs the
     checkpoint's training."""
-    rope_scaling = getattr(hf_cfg, "rope_scaling", None)
+    return _rope_rule(getattr(hf_cfg, "rope_scaling", None),
+                      getattr(hf_cfg, "max_position_embeddings", None))
+
+
+def _rope_rule(rope_scaling: Any, max_positions: Any = None) -> tuple:
+    """One HF rope dict (``rope_scaling``, or one kind's entry of
+    ``rope_parameters``) as ``LlamaConfig.rope_scaling``."""
     if not rope_scaling:
         return ()
     kind = rope_scaling.get("rope_type", rope_scaling.get("type", "default"))
@@ -77,7 +83,7 @@ def _convert_rope_scaling(hf_cfg: Any) -> tuple:
             else:
                 att = _yarn_get_mscale(factor)
         orig = (rope_scaling.get("original_max_position_embeddings")
-                or hf_cfg.max_position_embeddings)
+                or max_positions)
         return ("yarn", factor,
                 float(rope_scaling.get("beta_fast") or 32),
                 float(rope_scaling.get("beta_slow") or 1),
@@ -105,7 +111,7 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
     supported = ("llama", "mistral", "mixtral", "qwen2", "qwen3",
                  "qwen3_moe", "deepseek_v2", "deepseek_v3", "deepseek_v32",
                  "gigachat3_5", "solar_open2", "pangu_ultra_moe",
-                 "granitemoehybrid", "falcon_h1")
+                 "granitemoehybrid", "falcon_h1", "mellum")
     if hf_cfg.model_type not in supported:
         raise NotImplementedError(
             f"model_type {hf_cfg.model_type!r} is not supported "
@@ -122,7 +128,10 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
             f"mamba_rms_norm false, the norm before the gate, a bias on any "
             f"projection, attn_layer_indices, rope_scaling, an "
             f"attention_in_multiplier other than 1 and a checkpoint's "
-            f"tensors")
+            f"tensors; of the Mellum line window and full layers with a "
+            f"rotary rule a kind are built (mellum) and what is still "
+            f"refused is a theta a kind, a dense feed-forward, a bias and "
+            f"a checkpoint's tensors")
     act = getattr(hf_cfg, "hidden_act", "silu")
     if act not in ("silu", "swish"):
         raise NotImplementedError(
@@ -135,6 +144,8 @@ def config_from_hf(hf_cfg: Any, page_size: int = 16,
         return _config_from_granite(hf_cfg, page_size, dtype)
     if hf_cfg.model_type == "falcon_h1":
         return _config_from_falcon_h1(hf_cfg, page_size, dtype)
+    if hf_cfg.model_type == "mellum":
+        return _config_from_mellum(hf_cfg, page_size, dtype)
     if hf_cfg.model_type.startswith("deepseek"):
         return _config_from_deepseek(hf_cfg, page_size, dtype,
                                      rope_scaling)
@@ -678,6 +689,90 @@ def _config_from_falcon_h1(hf_cfg: Any, page_size: int,
         ssm_out_multiplier=float(hf_cfg.ssm_out_multiplier),
         attention_out_multiplier=float(hf_cfg.attention_out_multiplier),
         mlp_multipliers=tuple(float(m) for m in hf_cfg.mlp_multipliers),
+    )
+
+
+def _config_from_mellum(hf_cfg: Any, page_size: int,
+                        dtype: Any) -> LlamaConfig:
+    """Mellum 2 (``model_type: mellum``): ``layer_types`` names each layer
+    ``sliding_attention`` (a window of ``sliding_window`` keys) or
+    ``full_attention``, two cache groups with a page pool each;
+    ``rope_parameters`` gives the rotary rule BY THAT KIND (the full
+    layers' is ``rope_scaling``, the window layers' ``swa_rope_scaling``;
+    one theta for both, another is refused); GQA of ``head_dim`` a head
+    with the per-head norm on q and k of the ``qwen3_moe`` config class,
+    whose keys the config carries (``qk_norm`` false at the top level
+    serves the model without: the config itself has no key for it); every
+    ``mlp_layer_types`` entry ``sparse``: ``num_experts_per_tok`` of
+    ``num_experts`` experts of ``moe_intermediate_size`` by the softmax
+    over the chosen logits (``norm_topk_prob``), no shared expert, served
+    by the exact grouped dispatch. A top-level ``layer_share`` is one
+    chip's share of each layer as ``_config_from_granite`` reads it
+    (``num_experts`` is then what the chip holds). What the published
+    config does not give and a top-level key may: ``window_pages`` (the
+    window pool's size, ``LlamaConfig.window_pages``), ``embed_init_scale``.
+    Each form that is not built is refused by its key."""
+    _refuse_unbuilt(hf_cfg, (("attention_bias", False), ("mlp_bias", False),
+                             ("use_sliding_window", True),
+                             ("norm_topk_prob", True)))
+    n_layers = hf_cfg.num_hidden_layers
+    kinds = list(hf_cfg.layer_types)
+    if (len(kinds) != n_layers
+            or set(kinds) - {"sliding_attention", "full_attention"}):
+        raise NotImplementedError(
+            f"layer_types {kinds!r}: 'sliding_attention' or "
+            f"'full_attention' for each of the {n_layers} layers")
+    mlps = list(getattr(hf_cfg, "mlp_layer_types", None)
+                or ["sparse"] * n_layers)
+    if len(mlps) != n_layers or set(mlps) != {"sparse"}:
+        raise NotImplementedError(
+            f"mlp_layer_types {mlps!r}: a dense feed-forward beside the "
+            f"routed ones is not built for this family")
+    rules = dict(hf_cfg.rope_parameters)
+    if set(rules) - {"sliding_attention", "full_attention"} or set(
+            kinds) - set(rules):
+        raise NotImplementedError(
+            f"rope_parameters {sorted(rules)}: one rule for each kind in "
+            f"layer_types")
+    thetas = {float(rules[kind]["rope_theta"]) for kind in set(kinds)}
+    if len(thetas) != 1:
+        raise NotImplementedError(
+            f"rope_parameters: rope_theta {sorted(thetas)} differs by "
+            f"layer kind; one theta serves every layer")
+
+    def rule(kind):
+        return _rope_rule(rules.get(kind), hf_cfg.max_position_embeddings)
+
+    swa = tuple(i for i, kind in enumerate(kinds)
+                if kind == "sliding_attention")
+    full_rule = rule("full_attention") if len(swa) < n_layers else ()
+    swa_rule = rule("sliding_attention") if swa else ()
+    return LlamaConfig(
+        vocab_size=hf_cfg.vocab_size,
+        hidden_size=hf_cfg.hidden_size,
+        num_layers=n_layers,
+        num_heads=hf_cfg.num_attention_heads,
+        num_kv_heads=hf_cfg.num_key_value_heads,
+        head_dim=int(getattr(hf_cfg, "head_dim", None)
+                     or hf_cfg.hidden_size // hf_cfg.num_attention_heads),
+        intermediate_size=hf_cfg.intermediate_size,
+        rope_theta=thetas.pop(),
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        page_size=page_size,
+        dtype=dtype,
+        sliding_window=hf_cfg.sliding_window if swa else None,
+        swa_layers=swa,
+        window_pages=int(getattr(hf_cfg, "window_pages", 0)),
+        qk_norm=bool(getattr(hf_cfg, "qk_norm", True)),
+        rope_scaling=full_rule,
+        swa_rope_scaling=(() if not swa or swa_rule == full_rule
+                          else swa_rule or ("default",)),
+        embed_init_scale=float(getattr(hf_cfg, "embed_init_scale", 0.02)),
+        num_experts_per_token=int(hf_cfg.num_experts_per_tok),
+        moe_intermediate_size=int(hf_cfg.moe_intermediate_size),
+        moe_router=("softmax_topk", 1),
+        moe_dispatch="grouped",
+        **_layer_share(hf_cfg, int(hf_cfg.num_experts)),
     )
 
 

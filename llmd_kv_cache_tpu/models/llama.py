@@ -152,6 +152,11 @@ class LlamaConfig:
     # full attention. Both unset → pure full attention.
     sliding_window: Any = None  # Optional[int]
     swa_layers: tuple = ()
+    # Pages of a two-pool model's window pool, where the engine's own
+    # configuration gives none (``EngineConfig.num_swa_pages``): a
+    # deployment's file sizes the pool with the model, as ``state_slots``
+    # sizes a state pool. 0: as many as the global pool.
+    window_pages: int = 0
     # Per-head RMSNorm on Q and K before RoPE (Qwen3-style QK-norm).
     # With GQA this makes the family cover Qwen3; False = plain Llama.
     qk_norm: bool = False
@@ -255,6 +260,12 @@ class LlamaConfig:
     # (see _rope). Tuples so the frozen config stays hashable for jit
     # static args.
     rope_scaling: tuple = ()
+    # The window layers' rule where it differs from the full layers' (a
+    # model that gives ``rope_parameters`` by layer kind): of
+    # ``rope_scaling``'s form, ``("default",)`` for plain RoPE, () = the
+    # same as ``rope_scaling``. The shared body takes a layer's rule from
+    # ``layer_rope``.
+    swa_rope_scaling: tuple = ()
     # DeepSeek yarn couples mscale into the ATTENTION SCALE (in-tree
     # transformers: scaling = qk_head_dim^-0.5 * mscale(factor,
     # mscale_all_dim)^2) on top of the generic cos/sin factor; this
@@ -510,17 +521,21 @@ class LlamaConfig:
                     "index_head_dim >= qk_rope_head_dim (its rope dims)")
         if self.q_lora_rank and not self.is_mla:
             raise ValueError("q_lora_rank is an MLA knob")
-        if self.rope_scaling:
-            ok = (self.rope_scaling[0] == "llama3"
-                  and len(self.rope_scaling) == 5) or (
-                 self.rope_scaling[0] == "yarn"
-                 and len(self.rope_scaling) == 6)
+        for rule in (self.rope_scaling, self.swa_rope_scaling):
+            ok = not rule or (rule[0] == "llama3" and len(rule) == 5) or (
+                rule[0] == "yarn" and len(rule) == 6) or (
+                rule is self.swa_rope_scaling and rule == ("default",))
             if not ok:
                 raise ValueError(
                     "rope_scaling must be ('llama3', factor, low_freq_factor,"
                     " high_freq_factor, original_max) or ('yarn', factor, "
-                    "beta_fast, beta_slow, original_max, attention_factor); "
-                    f"got {self.rope_scaling!r}")
+                    "beta_fast, beta_slow, original_max, attention_factor), "
+                    "swa_rope_scaling one of those or ('default',); "
+                    f"got {rule!r}")
+        if self.swa_rope_scaling and (self.is_mla or not self.swa_layers):
+            raise ValueError(
+                "swa_rope_scaling is the window layers' rule of a GQA model "
+                "with swa_layers")
         if self.softmax_scale_mult != 1.0 and not self.is_mla:
             raise ValueError(
                 "softmax_scale_mult is a DeepSeek-yarn (MLA) knob")
@@ -588,6 +603,14 @@ class LlamaConfig:
 
     def layer_group(self, layer_idx: int) -> int:
         return 1 if (self.is_hybrid and layer_idx in self.swa_layers) else 0
+
+    def layer_rope(self, layer_idx: int) -> tuple:
+        """The layer's rotary rule, by its kind: ``swa_rope_scaling`` in a
+        window layer of a model that has one, else ``rope_scaling``."""
+        rule = self.rope_scaling
+        if self.swa_rope_scaling and self.layer_window(layer_idx):
+            rule = self.swa_rope_scaling
+        return () if rule == ("default",) else rule
 
     @property
     def is_mla(self) -> bool:
@@ -2342,8 +2365,8 @@ def _forward_impl_grouped(params, cfg, tokens, k_caches, v_caches, tables,
                                   cfg.norm_offset)
                     k = _rms_norm(k, layer["k_norm"], cfg.norm_eps,
                                   cfg.norm_offset)
-                q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-                k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+                q = _rope(q, positions, cfg.rope_theta, cfg.layer_rope(li))
+                k = _rope(k, positions, cfg.rope_theta, cfg.layer_rope(li))
 
             k_caches[g] = write_layer(k_caches[g], g, lj, k)
             v_caches[g] = write_layer(v_caches[g], g, lj, v)
@@ -2472,12 +2495,14 @@ def forward_hybrid(
     ctx_lens: jax.Array,
     new_lens: jax.Array,
     last_only: bool = False,
+    counters: dict | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """One model step for a hybrid (mixed full/SWA) model over two
     separately-paged cache groups. XLA attention backend."""
     logits, ks, vs = _forward_impl_grouped(
         params, cfg, tokens, (k0, k1), (v0, v1), (table0, table1),
         ctx_lens, new_lens, _xla_attention(cfg), last_only=last_only,
+        counters=counters,
     )
     return logits, ks[0], vs[0], ks[1], vs[1]
 
@@ -3402,3 +3427,67 @@ DRAFTING_PROGRAMS = {
     (True, True): drafting_step_program(PROGRAM_PREFILL, "prefill", True),
     (True, False): drafting_step_program(PROGRAM_DECODE, "decode", False),
 }
+
+
+# -- a window pool beside a global pool, through the kernels ----------------
+# A model of window layers and full layers (``cfg.is_hybrid``) keeps two
+# page pools, each with its own page table; the kernels take one pool, one
+# table and one window a call, and the shared body already hands each layer
+# its own group's. These are the two programs that join them, as forms of
+# their own: ``forward_decode_pallas`` / ``forward_prefill_pallas`` and the
+# frames under every other model's programs stay what they are (their
+# ``attention_fn``s' kernel calls stand here again, without the mesh, the
+# sinks and the latent forms a two-pool model is refused with). A slot of
+# the window pool's table that fell out of the window points at the garbage
+# page; the kernels never attend it. Under the pinned programs' names: a
+# trace tells programs by them.
+
+
+def forward_decode_pallas_pools(params, cfg, tokens, k0, v0, k1, v1, table0,
+                                table1, ctx_lens, new_lens, interpret=False,
+                                batch_rows=1, counters=None):
+    """``forward_decode_pallas`` over group 0's pool (``k0``, ``v0``,
+    ``table0``: the full layers) and group 1's (the window layers)."""
+    from ..ops.pallas_paged_attention import pallas_paged_decode_attention
+
+    def attention_fn(q, k_stack, v_stack, layer_idx, table, _positions,
+                     total_lens, window):
+        return pallas_paged_decode_attention(
+            q[:, 0], k_stack, v_stack, table, total_lens,
+            sliding_window=window, layer_idx=layer_idx,
+            batch_rows=batch_rows, interpret=interpret)[:, None]
+
+    logits, ks, vs = _forward_impl_grouped(
+        params, cfg, tokens, (k0, k1), (v0, v1), (table0, table1), ctx_lens,
+        new_lens, attention_fn, kernel={"interpret": interpret},
+        counters=counters)
+    return logits, ks[0], vs[0], ks[1], vs[1]
+
+
+def forward_prefill_pallas_pools(params, cfg, tokens, k0, v0, k1, v1, table0,
+                                 table1, ctx_lens, new_lens, interpret=False,
+                                 last_only=False, counters=None):
+    """``forward_prefill_pallas`` over the two pools, as above."""
+    from ..ops.pallas_paged_attention import pallas_paged_prefill_attention
+
+    q_tile = _prefill_q_tile(cfg, tokens.shape[1])
+
+    def attention_fn(q, k_stack, v_stack, layer_idx, table, _positions,
+                     total_lens, window):
+        return pallas_paged_prefill_attention(
+            q, k_stack, v_stack, table, ctx_lens, total_lens, q_tile=q_tile,
+            sliding_window=window, layer_idx=layer_idx, interpret=interpret)
+
+    logits, ks, vs = _forward_impl_grouped(
+        params, cfg, tokens, (k0, k1), (v0, v1), (table0, table1), ctx_lens,
+        new_lens, attention_fn, last_only=last_only,
+        kernel={"interpret": interpret}, counters=counters)
+    return logits, ks[0], vs[0], ks[1], vs[1]
+
+
+forward_decode_pallas_pools.__name__ = PROGRAM_DECODE
+forward_prefill_pallas_pools.__name__ = PROGRAM_PREFILL
+step_decode_pallas_pools = step_program(
+    forward_decode_pallas_pools, ("interpret", "batch_rows"))
+step_prefill_pallas_pools = step_program(
+    forward_prefill_pallas_pools, ("interpret", "last_only"))

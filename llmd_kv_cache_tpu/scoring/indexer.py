@@ -452,8 +452,9 @@ class Indexer:
                 dl.check("scoring.index_lookup")
 
             # The fused native paths count every entry as pages; where a
-            # pod keeps sequence states the Python scorer reads the groups.
-            native = not self.scorer.state_groups()
+            # pod keeps sequence states, or a window pool beside a global
+            # one, the Python scorer reads the groups.
+            native = not self.scorer.reads_groups()
             if native and self._native_score_chunked is not None:
                 return self._score_native_chunked(
                     keys_arr if keys_arr is not None else block_keys,

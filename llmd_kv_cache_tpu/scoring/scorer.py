@@ -87,7 +87,8 @@ class LongestPrefixScorer:
         self.liveness = None
         # The index the scored entries come from (``Indexer`` sets it): its
         # ``group_catalog`` says which pods keep sequence states beside
-        # their pages, and in which group (``state_groups``).
+        # their pages and which a window pool beside a global one, and in
+        # which group (``state_groups``, ``window_groups``).
         self.index = None
 
     @property
@@ -115,34 +116,56 @@ class LongestPrefixScorer:
         catalog = getattr(self.index, "group_catalog", None)
         return catalog.state_groups if catalog is not None else {}
 
-    def _score_with_states(
+    def window_groups(self) -> dict[str, tuple[int, int]]:
+        """pod -> (the cache group that is a window pool beside a global
+        one, its window in blocks), for the pods that have one
+        (``GroupCatalog.window_groups``)."""
+        catalog = getattr(self.index, "group_catalog", None)
+        return catalog.window_groups if catalog is not None else {}
+
+    def reads_groups(self) -> bool:
+        """Whether any pod's entries have to be read by group: the fused
+        native paths count every entry as a page."""
+        return bool(self.state_groups() or self.window_groups())
+
+    def _score_with_groups(
         self,
         keys: Sequence[BlockHash],
         key_to_pods: dict[BlockHash, list[PodEntry]],
         state_groups: dict[str, int],
+        window_groups: dict[str, tuple[int, int]],
     ) -> dict[str, float]:
-        """The longest-prefix rule where some pods keep a sequence state
-        beside their pages: such a pod can resume only where a snapshot
-        stands, so its score is its pages' weights up to the deepest block
-        that has a snapshot and all of whose predecessors have pages;
-        pages beyond it would be computed again. An entry without a group
-        (a router's speculative one, a tier update) speaks for both kinds.
-        The other pods score as ``score`` scores them."""
+        """The longest-prefix rule where some pods' hits need more than
+        pages. A pod that keeps a sequence state beside its pages can
+        resume only where a snapshot stands, so its score is its pages'
+        weights up to the deepest block that has a snapshot and all of
+        whose predecessors have pages; pages beyond it would be computed
+        again. A pod that keeps a window pool beside a global one can
+        resume at depth ``d`` only where the window group's trailing
+        ``min(window blocks, d)`` blocks below ``d`` are all present (the
+        engine's own walk, ``MiniEngine._acquire_pages``): its score is its
+        global chain's weights up to the deepest such block. An entry of
+        such a group is no page; an entry without a group (a router's
+        speculative one, a tier update) speaks for both kinds. The other
+        pods score as ``score`` scores them."""
         sums: dict[str, float] = {}
         usable: dict[str, float] = {}
+        run: dict[str, int] = {}  # a pod's window blocks in a row, to here
         active: set = set()
         for i, key in enumerate(keys):
             pages: dict[str, float] = {}
-            states = set()
+            extras = set()  # pods whose other group stands at this block
             for e in key_to_pods.get(key, []):
                 pod = e.pod_identifier
                 group = state_groups.get(pod)
+                if group is None and pod in window_groups:
+                    group = window_groups[pod][0]
                 if group is not None:
                     if e.has_group and e.group_idx == group:
-                        states.add(pod)
-                        continue  # a snapshot is no page
+                        extras.add(pod)
+                        continue  # a snapshot or a window block is no page
                     if not e.has_group:
-                        states.add(pod)
+                        extras.add(pod)
                 w = self.medium_weights.get(e.device_tier, 1.0)
                 if w > pages.get(pod, -1.0):
                     pages[pod] = w
@@ -155,11 +178,17 @@ class LongestPrefixScorer:
                         active.discard(pod)
                     else:
                         sums[pod] += w
-            for pod in active & states:
-                usable[pod] = sums[pod]
+            for pod in active:
+                if pod in window_groups:
+                    run[pod] = run.get(pod, 0) + 1 if pod in extras else 0
+                    if run[pod] >= min(window_groups[pod][1], i + 1):
+                        usable[pod] = sums[pod]
+                elif pod in extras:
+                    usable[pod] = sums[pod]
             if not active:
                 break
-        return {pod: (usable.get(pod, 0.0) if pod in state_groups else s)
+        return {pod: (usable.get(pod, 0.0)
+                      if pod in state_groups or pod in window_groups else s)
                 for pod, s in sums.items()}
 
     def _fill_max_weights(
@@ -180,10 +209,11 @@ class LongestPrefixScorer:
     ) -> dict[str, float]:
         if not keys:
             return {}
-        state_groups = self.state_groups()
-        if state_groups:
-            return self._apply_liveness(
-                self._score_with_states(keys, key_to_pods, state_groups))
+        state_groups, window_groups = (self.state_groups(),
+                                       self.window_groups())
+        if state_groups or window_groups:
+            return self._apply_liveness(self._score_with_groups(
+                keys, key_to_pods, state_groups, window_groups))
 
         cur_weights = self._fill_max_weights(key_to_pods.get(keys[0], []))
         pod_scores = dict(cur_weights)
@@ -219,6 +249,15 @@ class HybridAwareScorer(LongestPrefixScorer):
     usable trailing window (full-attention pods fall back to the exact
     longest-prefix accumulation). Requires the pool's ``GroupCatalog`` to
     know the pod's group spec; unknown pods score as full attention.
+
+    Which scorer knows what: this strategy values a window group by the
+    window itself (its saving capped there, a uniform-window pod's one
+    group included) and takes the minimum over a pod's groups; it does not
+    know sequence states. The DEFAULT ``LongestPrefixScorer`` reads the
+    same catalog from the index and keeps the longest-prefix value: a pod
+    that keeps states, or a window pool BESIDE a global one, scores its
+    global chain up to the deepest block it can resume on (a snapshot; a
+    standing trailing window), and every other pod as ever.
     """
 
     def __init__(self, medium_weights=None, group_catalog=None,

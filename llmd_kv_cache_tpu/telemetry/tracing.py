@@ -760,6 +760,7 @@ PHASE_STEP_COMMIT = "step.commit"          # _commit_full_blocks → commit_bloc
 PHASE_STEP_EMIT = "step.emit"              # event batch → sink → Pool/index (nests in commit)
 PHASE_STEP_FINISH = "step.finish"          # release of finished requests; carries the step's counters
 PHASE_STEP_SNAPSHOT = "step.snapshot"      # a prefill chunk's snapshots planned: slots reserved in the state pool, what they evicted
+PHASE_STEP_WINDOW = "step.window"          # a two-pool model's window pages: ensured for what a program writes, reclaimed behind the window
 PHASE_ROUTE_DECIDE = "route.decide"        # all of KVAwareRouter.route (nests the five below)
 PHASE_ROUTE_EXPIRE = "route.expire"        # speculative entries past their TTL dropped
 PHASE_ROUTE_HASH = "route.hash"            # prompt tokens → block keys
@@ -774,7 +775,7 @@ PHASE_NAMES = (
     PHASE_STEP_OFFLOAD_POLL, PHASE_STEP_SCHEDULE, PHASE_STEP_INPUTS,
     PHASE_STEP_DISPATCH, PHASE_STEP_FETCH,
     PHASE_STEP_COMMIT, PHASE_STEP_EMIT, PHASE_STEP_FINISH,
-    PHASE_STEP_SNAPSHOT,
+    PHASE_STEP_SNAPSHOT, PHASE_STEP_WINDOW,
     PHASE_ROUTE_EXPIRE, PHASE_ROUTE_HASH, PHASE_ROUTE_LOOKUP,
     PHASE_ROUTE_SCORE, PHASE_ROUTE_SPECULATE, PHASE_ROUTE_DECIDE,
     PHASE_INGEST, PHASE_REQUEST_FIRST_TOKEN,

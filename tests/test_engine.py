@@ -559,7 +559,9 @@ def _served_model(kind):
     steps through the padded path: dense (XLA and the Pallas kernels), a
     routed one whose programs count on the device behind their tokens, one
     that keeps a sequence state in a pool beside its pages, and one whose
-    prediction module drafts a token that every decode step verifies."""
+    prediction module drafts a token that every decode step verifies; and
+    one that keeps a window pool beside a global pool (``pools``: the XLA
+    form; the kernel forms are held to it in ``tests/test_mellum2.py``)."""
     if kind in ("dense", "dense-pallas"):
         pallas = True if kind == "dense-pallas" else None
         return LlamaConfig.tiny(), None, dict(
@@ -569,7 +571,8 @@ def _served_model(kind):
     conf = names.config_for_run(names.benchmark(), {
         "counters": "deepseek-v3.2-exp-ep16-l5",
         "state": "gigachat3.5-ep16-l5",
-        "drafting": "openpangu-ultra-ep32-l5"}[kind], rehearse=True)
+        "drafting": "openpangu-ultra-ep32-l5",
+        "pools": "mellum2-12b-ep4"}[kind], rehearse=True)
     cfg, params = fleet.build_model(conf, 11)
     return cfg, params, {}
 
@@ -687,7 +690,8 @@ class TestLookAhead:
         return {rid: list(req.output) for rid, req in reqs.items()}
 
     @pytest.mark.parametrize(
-        "kind", ["dense", "dense-pallas", "counters", "state", "drafting"])
+        "kind", ["dense", "dense-pallas", "counters", "state", "drafting",
+                 "pools"])
     def test_ahead_gives_the_synchronous_order_token_for_token(
             self, kind, monkeypatch):
         eng, other, seen, cfg = self._pair(kind, monkeypatch)
@@ -700,7 +704,9 @@ class TestLookAhead:
         assert tel == {"launched_ahead": sum(d["ahead"] for d in decodes),
                        "drained": {}}
         chunks, decodes_deep, chunks_deep = _queue_depths(seen)
-        assert chunks >= 9 and decodes_deep == chunks_deep == 1
+        # (Pages of 32 tokens: the plan's prompts make 7 chunks of 64.)
+        assert chunks >= (7 if kind == "pools" else 9)
+        assert decodes_deep == chunks_deep == 1
         assert eng._unread is None
 
         held, other, seen, _ = self._pair(kind, monkeypatch)
@@ -712,7 +718,7 @@ class TestLookAhead:
         assert (held.block_manager.pool_stats()
                 == eng.block_manager.pool_stats())
 
-    @pytest.mark.parametrize("kind", ["dense", "counters", "state"])
+    @pytest.mark.parametrize("kind", ["dense", "counters", "state", "pools"])
     def test_a_rows_last_token_is_not_launched_past(self, kind, monkeypatch):
         """A row stops by count: a program launched ahead leaves out the
         row whose unread token is its last, so no row runs for nothing and
@@ -876,10 +882,11 @@ class TestLookAhead:
     @pytest.mark.parametrize("kind", ["sharded", "hybrid"])
     def test_an_engine_that_cannot_defer_takes_no_prev(self, kind,
                                                        monkeypatch):
-        """A mesh and window pages keep the synchronous
-        order and the programs they had: no ``prev`` operand, no ``src``
-        in the packed inputs, no ``ahead`` on a dispatch, nothing unread
-        between steps."""
+        """A mesh keeps the synchronous order and the programs it had,
+        over one pool or (``hybrid``) over a window pool beside a global
+        one, which without a mesh defers like any other engine: no ``prev``
+        operand, no ``src`` in the packed inputs, no ``ahead`` on a
+        dispatch, nothing unread between steps."""
         import jax
         from jax.sharding import Mesh
 
@@ -889,12 +896,15 @@ class TestLookAhead:
 
         monkeypatch.setattr(engine_module, "_launch_counts", {})
         tiny = LlamaConfig.tiny()
-        mesh = None
-        if kind == "sharded":
-            mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("tp",))
-        else:
+        mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("tp",))
+        if kind == "hybrid":
             tiny = dataclasses.replace(tiny, sliding_window=8,
                                        swa_layers=(1,))
+            alone = MiniEngine(EngineConfig(
+                model=tiny, num_pages=64, num_swa_pages=64,
+                max_pages_per_seq=16, max_batch=2, pod_identifier="q"),
+                seed=0)
+            assert alone._defers is True and alone._prev is not None
         eng = MiniEngine(EngineConfig(
             model=tiny, num_pages=64, num_swa_pages=64,
             max_pages_per_seq=16, max_batch=2, pod_identifier="p",

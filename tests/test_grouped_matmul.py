@@ -23,6 +23,7 @@ WIDTHS = {
     "solar-open2-ep16-l8": (4096, 1280, 128, 4096),
     "openpangu-ultra-ep32-l5": (7680, 2048, 128, 4096),
     "granite-4.0-h-small-ep2-l10": (4096, 768, 256, 5120),
+    "mellum2-12b-ep4": (2304, 896, 128, 4096),
 }
 CASES = [pytest.param(m, k, n, id=f"{name}-{matrix}-{what}")
          for name, (hidden, width, step, chunk) in WIDTHS.items()

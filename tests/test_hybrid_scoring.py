@@ -317,3 +317,138 @@ class TestStateGroups:
         assert indexer.score_tokens(tokens, "m") == {"pod-0": 0.0,
                                                      "pod-1": 4.0}
         assert router.route(tokens, "m") == "pod-1"
+
+
+# -- pods that keep a window pool beside a global pool ------------------------
+
+
+def windowed_scorer(*pods, window_blocks=2, uniform=()):
+    """The DEFAULT scorer over a catalog as an event pool leaves it: each of
+    ``pods`` keeps group 0 (``full_attention``) and group 1
+    (``sliding_window`` of ``window_blocks`` blocks); each of ``uniform``
+    one group, a window (one pool: a uniform-window model)."""
+    from types import SimpleNamespace
+
+    from llmd_kv_cache_tpu.scoring.scorer import LongestPrefixScorer
+
+    catalog = GroupCatalog()
+    for pod in pods:
+        catalog.learn(pod, 1, GroupMetadata(
+            "sliding_window", BLOCK, window_blocks * BLOCK))
+        catalog.learn(pod, 0, GroupMetadata("full_attention", BLOCK))
+    for pod in uniform:
+        catalog.learn(pod, 0, GroupMetadata(
+            "sliding_window", BLOCK, window_blocks * BLOCK))
+    scorer = LongestPrefixScorer({"tpu-hbm": 1.0, "cpu": 0.8})
+    scorer.index = SimpleNamespace(group_catalog=catalog)
+    return scorer
+
+
+class TestWindowGroups:
+    """``LongestPrefixScorer`` reads a window group from the index's
+    catalog: a pod's score is its global chain up to the deepest block
+    whose trailing window of group 1 still stands
+    (``MiniEngine._acquire_pages``' walk)."""
+
+    KEYS = [1, 2, 3, 4, 5, 6]
+
+    def test_the_catalog_lists_a_window_beside_another_group_only(self):
+        s = windowed_scorer("two", uniform=("one",))
+        assert s.window_groups() == {"two": (1, 2)} and s.reads_groups()
+        assert not windowed_scorer(uniform=("one",)).reads_groups()
+        odd = GroupCatalog()                 # 40 tokens over blocks of 16
+        odd.learn("p", 0, GroupMetadata("full_attention", 16))
+        odd.learn("p", 1, GroupMetadata("sliding_window", 16, 40))
+        assert odd.window_groups == {"p": (1, 3)}
+
+    def test_the_pod_that_kept_its_tail_beats_the_longer_chain(self):
+        """``lost`` holds all six global blocks and its window blocks were
+        evicted under other sessions: sent there, the turn is computed from
+        its first token. ``kept`` holds five and the two below the fifth."""
+        s = windowed_scorer("kept", "lost")
+        key_to_pods = {k: [page("lost")] for k in self.KEYS}
+        for k in self.KEYS[:5]:
+            key_to_pods[k].append(page("kept"))
+        for k in (4, 5):
+            key_to_pods[k].append(state("kept"))   # group 1: window blocks
+        assert s.score(self.KEYS, key_to_pods) == {"kept": 5.0, "lost": 0.0}
+
+    @pytest.mark.parametrize("window_at,score", [
+        ((1, 2, 3, 4, 5, 6), 6.0),   # nothing reclaimed yet
+        ((5, 6), 6.0),               # the trailing window alone
+        ((4, 5), 5.0),               # the tail's last block evicted
+        ((3, 4, 6), 4.0),            # a hole: the deepest whole window
+        ((6,), 0.0),                 # half a window is no window
+        ((1,), 1.0),                 # depth 1 needs one block
+    ])
+    def test_the_score_is_the_chain_to_the_deepest_whole_window(
+            self, window_at, score):
+        s = windowed_scorer("p")
+        key_to_pods = {k: [page("p")] for k in self.KEYS}
+        for k in window_at:
+            key_to_pods[k].append(state("p"))
+        assert s.score(self.KEYS, key_to_pods) == {"p": score}
+
+    def test_a_window_behind_a_missing_global_block_is_out_of_reach(self):
+        s = windowed_scorer("p")
+        key_to_pods = {k: [page("p"), state("p")] for k in (1, 2, 4, 5)}
+        assert s.score(self.KEYS, key_to_pods) == {"p": 2.0}
+
+    def test_an_entry_without_a_group_speaks_for_both_pools(self):
+        s = windowed_scorer("p")
+        spec = PodEntry("p", "tpu-hbm", speculative=True)
+        assert s.score(self.KEYS, {k: [spec] for k in self.KEYS[:3]}) == {
+            "p": 3.0}
+
+    def test_a_fleet_without_window_groups_scores_as_before(self):
+        """A uniform-window pod's one group is a window and resumes by
+        longest prefix; a pod with no group at all likewise."""
+        s = windowed_scorer(uniform=("uniform",))
+        key_to_pods = {k: [page("uniform"), PodEntry("bare", "tpu-hbm")]
+                       for k in self.KEYS[:4]}
+        assert s.score(self.KEYS, key_to_pods) == {"uniform": 4.0,
+                                                   "bare": 4.0}
+        mixed = windowed_scorer("two", uniform=("uniform",))
+        key_to_pods[1].append(page("two"))
+        assert mixed.score(self.KEYS, key_to_pods) == {
+            "uniform": 4.0, "bare": 4.0, "two": 0.0}
+
+    def test_as_the_harness_wires_it_the_router_follows_the_tail(self):
+        """``Indexer``, ``Pool`` and ``KVAwareRouter`` with defaults, the
+        events a two-pool engine sends: group 0 ``full_attention``, group 1
+        ``sliding_window`` with its size."""
+        from llmd_kv_cache_tpu.events.model import BlockRemovedEvent
+        from llmd_kv_cache_tpu.scoring.router import KVAwareRouter
+
+        indexer = Indexer(IndexerConfig(
+            token_processor_config=TokenProcessorConfig(
+                block_size_tokens=BLOCK)))
+        pool = Pool(PoolConfig(concurrency=1), indexer.kv_block_index,
+                    indexer.token_processor)
+        tokens = list(range(1, 1 + 6 * BLOCK))
+        keys = indexer.compute_block_keys(tokens, "m")
+
+        def stored(pod, first, count, group):
+            pool.process_event_batch(EventBatch(0.0, [BlockStoredEvent(
+                block_hashes=keys[first:first + count],
+                tokens=tokens[first * BLOCK:(first + count) * BLOCK],
+                parent_hash=keys[first - 1] if first else 0,
+                block_size=BLOCK, group_idx=group,
+                kv_cache_spec_kind=("sliding_window" if group
+                                    else "full_attention"),
+                kv_cache_spec_sliding_window=2 * BLOCK if group else None,
+            )]), pod, "m")
+
+        for pod, blocks in (("pod-0", 6), ("pod-1", 4)):
+            stored(pod, 0, blocks, 0)
+            stored(pod, blocks - 2, 2, 1)
+        router = KVAwareRouter(indexer, ["pod-0", "pod-1"])
+        assert indexer.score_tokens(tokens, "m") == {"pod-0": 6.0,
+                                                     "pod-1": 4.0}
+        # pod-0's window pool evicts the tail's last block; every global
+        # block stays.
+        pool.process_event_batch(EventBatch(0.0, [BlockRemovedEvent(
+            block_hashes=[keys[5]], group_idx=1)]), "pod-0", "m")
+        assert indexer.score_tokens(tokens, "m") == {"pod-0": 0.0,
+                                                     "pod-1": 4.0}
+        assert router.route(tokens, "m") == "pod-1"

@@ -766,10 +766,13 @@ class TestEnginePhases:
                 if n == tracing.PHASE_STEP_EMIT:
                     assert names[at - 1] == tracing.PHASE_STEP_COMMIT
         # ``step.snapshot`` is opened for a model that keeps a sequence
-        # state beside its pages only (tests/test_gated_deltanet.py).
+        # state beside its pages only (tests/test_gated_deltanet.py),
+        # ``step.window`` for one that keeps a window pool beside a global
+        # one (tests/test_mellum2.py).
         assert names_seen == {n for n in tracing.PHASE_NAMES
                               if n.startswith("step.")
-                              } - {tracing.PHASE_STEP_SNAPSHOT} | {
+                              } - {tracing.PHASE_STEP_SNAPSHOT,
+                                   tracing.PHASE_STEP_WINDOW} | {
                                   tracing.PHASE_REQUEST_FIRST_TOKEN}
         assert events                  # the sink did receive the batches
         commits = [a for n, a, _ in seen if n == tracing.PHASE_STEP_COMMIT]

@@ -191,6 +191,8 @@ def test_draft_acceptance(shaped):
     pytest.param(4096, 4096, 1280, 20, id="solar-up-of-a-chunk"),
     pytest.param(128, 1280, 4096, 20, id="solar-down-of-a-step"),
     pytest.param(128, 2048, 7680, 8, id="openpangu-down-of-a-step"),
+    pytest.param(4096, 2304, 896, 16, id="mellum-up-of-a-chunk"),
+    pytest.param(128, 896, 2304, 16, id="mellum-down-of-a-step"),
 ])
 def test_grouped_matmul(shaped, m, k, n, held):
     """The tiles ``llama.gmm_tiling`` gives each configuration's experts:
@@ -348,3 +350,37 @@ def test_mamba2_step_of_two_groups_at_one_head_a_tile(shaped):
              shaped((FH_ROWS, FH_GROUPS, FH_STATE)),
              shaped((FH_ROWS, FH_HEADS), f32), shaped((FH_HEADS,), f32),
              shaped((FH_HEADS,), f32))
+
+
+# ``mellum2-12b-ep4``: 32 query and 4 key/value heads of 128; the window
+# pool's 21 layers of 576 pages and the global pool's 7 of 2048, 520 pages
+# a row, 16 decoding rows, a window of 1024.
+@pytest.mark.parametrize("layers,pages,window", [(21, 576, 1024),
+                                                 (7, 2048, None)],
+                         ids=["window-pool", "global-pool"])
+@pytest.mark.parametrize("q_seq", [1, CHUNK], ids=["decode", "prefill"])
+def test_two_pool_gqa_attention(shaped, layers, pages, window, q_seq):
+    """The paged kernels as ``llama.forward_decode_pallas_pools`` and
+    ``forward_prefill_pallas_pools`` call them, a pool at a time."""
+    pool = shaped((layers, pages, 4, PAGE, 128))
+    layer = shaped((), jnp.int32)
+
+    def decode(q, k, v, table, lens, li):
+        return pallas_paged_decode_attention(
+            q, k, v, table, lens, sliding_window=window, layer_idx=li)
+
+    def prefill(q, k, v, table, ctx, total, li):
+        return pallas_paged_prefill_attention(
+            q, k, v, table, ctx, total,
+            q_tile=llama._prefill_q_tile(llama.LlamaConfig(
+                num_heads=32, num_kv_heads=4, head_dim=128), q_seq),
+            sliding_window=window, layer_idx=li)
+
+    if q_seq == 1:
+        compiles(decode, shaped((16, 32, 128)), pool, pool,
+                 shaped((16, 520), jnp.int32), shaped((16,), jnp.int32),
+                 layer)
+    else:
+        compiles(prefill, shaped((1, q_seq, 32, 128)), pool, pool,
+                 shaped((1, 520), jnp.int32), shaped((1,), jnp.int32),
+                 shaped((1,), jnp.int32), layer)
